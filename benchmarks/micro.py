@@ -1531,7 +1531,7 @@ def _tensor_replay_child() -> None:
 
     from lakesoul_tpu import LakeSoulCatalog
     from lakesoul_tpu.tensorplane import tensor_field
-    from lakesoul_tpu.tensorplane.smoke import run_smoke
+    from lakesoul_tpu.tensorplane.smoke import uncovered_kernels
 
     devices = jax.devices()
     assert len(devices) >= 8, f"mesh leg needs 8 devices, got {len(devices)}"
@@ -1610,7 +1610,6 @@ def _tensor_replay_child() -> None:
         assert rows_h == n_rows
         assert epoch_hashes(it_sp) == stream_sha, "hybrid epoch diverged"
 
-        smoke = run_smoke()
         print(json.dumps({
             "rows": n_rows,
             "tensor_width": width,
@@ -1624,21 +1623,18 @@ def _tensor_replay_child() -> None:
             "hybrid_rows_per_s": round(hybrid_rps, 1),
             "hybrid_over_stream": round(hybrid_rps / spill_stream_rps, 2),
             "byte_identity": True,
-            "tpu_smoke": {
-                "platform": smoke["platform"],
-                "ok": smoke["ok"],
-                "untested_on_tpu": smoke["untested_on_tpu"],
-                "uncovered_kernels": smoke["kernel_enumeration"]["uncovered"],
-            },
+            "platform": devices[0].platform,
+            "uncovered_kernels": uncovered_kernels(),
         }))
 
 
 def bench_tensor_replay() -> None:
     """Epoch-1 streaming delivery vs epoch-2 device-resident replay on the
     8-device CPU mesh (tensorplane/replay.py), with byte-identity asserted
-    per batch, a budget-spill hybrid variant, and the TPU-smoke fallback
-    record published.  FAILS when replay does not beat streaming by
-    ``TENSOR_REPLAY_FLOOR``."""
+    per batch, a budget-spill hybrid variant, and the smoke register's
+    kernel-coverage check.  FAILS when replay does not beat streaming by
+    ``TENSOR_REPLAY_FLOOR``.  The register itself runs elsewhere: compiled
+    on the chip by ``chip_smoke.py``, interpreted in tier-1."""
     import subprocess
 
     env = dict(os.environ)
@@ -1662,7 +1658,7 @@ def bench_tensor_replay() -> None:
         f" declared {TENSOR_REPLAY_FLOOR} floor"
     )
     assert result["byte_identity"]
-    assert result["tpu_smoke"]["ok"], "smoke register failed on fallback"
+    assert not result["uncovered_kernels"], result["uncovered_kernels"]
 
 
 # obs_fleet overhead budget: fleet telemetry (member/recorder flushes during
@@ -2225,7 +2221,6 @@ def bench_fleet(
                             f"rank {rank}/{world} diverged from the"
                             " single-process shard scan"
                         )
-                        assert doc["local_devices"] == total_devices // world
                     window = max(o["ended_unix"] for o in outs) \
                         - min(o["started_unix"] for o in outs)
                     rates[world] = total_rows / window

@@ -15,19 +15,11 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from lakesoul_tpu.utils import honor_platform_env
-
-honor_platform_env()
-
 import numpy as np
 import pyarrow as pa
 
 
 def main() -> None:
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -35,7 +27,9 @@ def main() -> None:
     from lakesoul_tpu.models.bert import BertConfig
     from lakesoul_tpu.models.train import make_bert_train_state, make_bert_train_step
     from lakesoul_tpu.parallel.mesh import make_mesh
+    from lakesoul_tpu.utils.compile_cache import configure_compile_cache
 
+    configure_compile_cache()
     plan = make_mesh(jax.devices())
     print(f"mesh: dp={plan.dp} tp={plan.tp} sp={plan.sp}")
 

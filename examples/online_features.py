@@ -17,10 +17,6 @@ import tempfile
 import threading
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from lakesoul_tpu.utils import honor_platform_env
-
-honor_platform_env()
-
 import numpy as np
 
 
@@ -39,7 +35,9 @@ def main() -> None:
     )
     from lakesoul_tpu.meta.entity import now_millis
     from lakesoul_tpu.streaming import DebeziumJsonConsumer
+    from lakesoul_tpu.utils.compile_cache import configure_compile_cache
 
+    configure_compile_cache()
     catalog = LakeSoulCatalog(wh)
     consumer = DebeziumJsonConsumer(catalog, primary_keys={"user_features": ["uid"]})
 

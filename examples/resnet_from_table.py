@@ -15,10 +15,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from lakesoul_tpu.utils import honor_platform_env
-
-honor_platform_env()
-
 import numpy as np
 import pyarrow as pa
 
@@ -27,10 +23,6 @@ NUM_CLASSES = 10
 
 
 def main() -> None:
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -39,7 +31,9 @@ def main() -> None:
     from lakesoul_tpu.models.resnet import ResNetConfig, init_resnet_params
     from lakesoul_tpu.models.train import make_resnet_train_step
     from lakesoul_tpu.parallel.mesh import make_mesh
+    from lakesoul_tpu.utils.compile_cache import configure_compile_cache
 
+    configure_compile_cache()
     plan = make_mesh(jax.devices())
     B = 4 * plan.dp  # data-parallel batch
 

@@ -9,12 +9,9 @@ from __future__ import annotations
 import argparse
 import os
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from lakesoul_tpu.utils import honor_platform_env
-
-honor_platform_env()
-import tempfile
 
 import numpy as np
 import pyarrow as pa
@@ -53,7 +50,9 @@ def main() -> None:
     from lakesoul_tpu import LakeSoulCatalog
     from lakesoul_tpu.models.mlp import init_mlp_params, mlp_forward
     from lakesoul_tpu.models.train import make_mlp_train_step
+    from lakesoul_tpu.utils.compile_cache import configure_compile_cache
 
+    configure_compile_cache()
     warehouse = args.warehouse or tempfile.mkdtemp(prefix="lakesoul_titanic_")
     catalog = LakeSoulCatalog(warehouse)
 

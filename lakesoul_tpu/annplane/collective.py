@@ -30,8 +30,6 @@ def _merge_fn(n_dev: int, k: int):
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from lakesoul_tpu.parallel._compat import shard_map
-
     devices = jax.devices()[:n_dev]
     mesh = Mesh(np.array(devices), (AXIS,))
 
@@ -48,7 +46,7 @@ def _merge_fn(n_dev: int, k: int):
         rows = gr.reshape(-1)[idx]
         return (-neg)[None], rows[None], src[None], slot[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None)),

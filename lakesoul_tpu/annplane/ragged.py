@@ -87,19 +87,21 @@ def fold_cluster(norms, factors, code_dot_c, *, d: int, ex: bool):
 
 
 def _ragged_score_kernel(
-    item_q_ref, item_tile_ref, q_ref, csq_ref, csum_ref,
+    item_q_ref, item_tile_ref, csq_ref, csum_ref, q_ref,
     codes_ref, a_ref, b_ref, h_ref, out_ref,
 ):
     """codes block [TILE, d] x this item's query row [1, d] → one MXU
     matvec, fused with the affine correction into estimated sq-distances.
-    The scalar-prefetch refs (item_q/item_tile) are consumed by the
-    BlockSpec index maps, not the body."""
+    All four per-item tables are scalar-prefetched into SMEM: item_q and
+    item_tile are consumed by the BlockSpec index maps, csq and csum are
+    read here as this step's scalars."""
     del item_q_ref, item_tile_ref
+    i = pl.program_id(0)
     g = jnp.dot(codes_ref[:], q_ref[:].T, preferred_element_type=jnp.float32)[:, 0]
     out_ref[0, :] = (
         b_ref[0, :]
-        + csq_ref[0, 0]
-        - h_ref[0, :] * csum_ref[0, 0]
+        + csq_ref[i]
+        - h_ref[0, :] * csum_ref[i]
         - a_ref[0, :] * g
     )
 
@@ -111,31 +113,42 @@ def _ragged_score_pallas_call(
 ):
     m = item_q.shape[0]
     d = codes.shape[1]
+    # Mosaic wants a block's last two dims divisible by (8, 128) or equal to
+    # the array's: a one-row block of a [rows, d] array is neither, so the
+    # query table and the output carry a unit middle axis and the row index
+    # moves to a squeezed leading dim
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(m,),
         in_specs=[
             # this item's query row: the prefetched item table IS the index map
-            pl.BlockSpec((1, d), lambda i, iq, it: (iq[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, iq, it: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, iq, it: (i, 0)),
+            pl.BlockSpec((None, 1, d), lambda i, iq, it, cq, cs: (iq[i], 0, 0)),
             # this item's cluster tile
-            pl.BlockSpec((tile, d), lambda i, iq, it: (it[i], 0)),
-            pl.BlockSpec((1, tile), lambda i, iq, it: (0, it[i])),
-            pl.BlockSpec((1, tile), lambda i, iq, it: (0, it[i])),
-            pl.BlockSpec((1, tile), lambda i, iq, it: (0, it[i])),
+            pl.BlockSpec((tile, d), lambda i, iq, it, cq, cs: (it[i], 0)),
+            pl.BlockSpec((1, tile), lambda i, iq, it, cq, cs: (0, it[i])),
+            pl.BlockSpec((1, tile), lambda i, iq, it, cq, cs: (0, it[i])),
+            pl.BlockSpec((1, tile), lambda i, iq, it, cq, cs: (0, it[i])),
         ],
-        out_specs=pl.BlockSpec((1, tile), lambda i, iq, it: (i, 0)),
+        out_specs=pl.BlockSpec(
+            (None, 1, tile), lambda i, iq, it, cq, cs: (i, 0, 0)
+        ),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _ragged_score_kernel,
-        out_shape=jax.ShapeDtypeStruct((m, tile), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, 1, tile), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
     )(
-        item_q, item_tile, q_glob, csq, csum,
+        item_q, item_tile, csq, csum, q_glob[:, None, :],
         codes, a.reshape(1, -1), b.reshape(1, -1), h.reshape(1, -1),
     )
+    return out[:, 0, :]
+
+
+# the four prefetched item tables live in SMEM for the whole call: 4 x 4 B x
+# 32768 items is half of the 1 MiB a v5e core has (65536 items overflow it by
+# 1.1 KiB at compile time), so a larger micro-batch runs as several calls
+MAX_ITEMS = 32768
 
 
 def ragged_score_pallas(
@@ -144,27 +157,30 @@ def ragged_score_pallas(
 ):
     """Item scores [M, tile] via the Pallas grid.  M and Q are pow2-bucketed
     so repeated micro-batches of varying raggedness reuse compiled shapes;
-    pad items point at tile 0 / query 0 and are dropped by the caller."""
+    pad items point at tile 0 / query 0 and are dropped here."""
     m = len(item_q)
-    m_pad = _pow2(m)
     q_pad = _pow2(q_glob.shape[0])
 
-    def pad1(x, n, const=0):
-        x = np.asarray(x)
-        return np.pad(x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1),
-                      constant_values=const)
+    def pad1(x, n, dtype):
+        x = np.asarray(x, dtype)
+        return np.pad(x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
 
-    out = _ragged_score_pallas_call(
-        jnp.asarray(pad1(item_q, m_pad), jnp.int32),
-        jnp.asarray(pad1(item_tile, m_pad), jnp.int32),
-        jnp.asarray(pad1(np.asarray(csq, np.float32).reshape(-1, 1), m_pad)),
-        jnp.asarray(pad1(np.asarray(csum, np.float32).reshape(-1, 1), m_pad)),
-        jnp.asarray(pad1(np.asarray(q_glob, np.float32), q_pad)),
-        jnp.asarray(codes),
-        jnp.asarray(a), jnp.asarray(b), jnp.asarray(h),
-        tile=tile, interpret=interpret,
-    )
-    return np.asarray(out)[:m]
+    q_dev = jnp.asarray(pad1(q_glob, q_pad, np.float32))
+    resident = (jnp.asarray(codes), jnp.asarray(a), jnp.asarray(b), jnp.asarray(h))
+    out = np.empty((m, tile), np.float32)
+    for lo in range(0, m, MAX_ITEMS):
+        hi = min(m, lo + MAX_ITEMS)
+        m_pad = _pow2(hi - lo)
+        chunk = _ragged_score_pallas_call(
+            jnp.asarray(pad1(item_q[lo:hi], m_pad, np.int32)),
+            jnp.asarray(pad1(item_tile[lo:hi], m_pad, np.int32)),
+            jnp.asarray(pad1(csq[lo:hi], m_pad, np.float32)),
+            jnp.asarray(pad1(csum[lo:hi], m_pad, np.float32)),
+            q_dev, *resident,
+            tile=tile, interpret=interpret,
+        )
+        out[lo:hi] = np.asarray(chunk)[: hi - lo]
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("tile",))

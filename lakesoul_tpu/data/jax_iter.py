@@ -313,7 +313,7 @@ class _Window:
             if buf is None or buf.shape != shape or buf.dtype != proto.dtype:
                 # 64-byte-aligned output buffers (tensorplane.dlpack): the
                 # XLA CPU client only zero-copies aligned host buffers, so
-                # alignment is what makes the DLPack/device_put hand-off
+                # alignment is what makes the device_put hand-off
                 # provably copy-free instead of malloc-luck-dependent
                 buf = aligned_empty(shape, proto.dtype)
                 if buffers is not None:
@@ -891,24 +891,21 @@ class JaxBatchIterator:
                 yield host_batch
             return produced_all
 
-        # delivery rides the tensor plane's DLPack hand-off: dtype-preserved
-        # contiguous leaves import zero-copy (the collate buffers are
-        # 64-byte aligned for exactly this) and only the device placement
-        # remains — on CPU nothing copies, on TPU only the H2D DMA does;
-        # demoted dtypes fall back to plain device_put (the cast IS the
-        # copy).  Aliasing semantics are identical to raw device_put, so
-        # the ring probe's verdict governs this path unchanged.
-        from lakesoul_tpu.tensorplane.dlpack import deliver as dlpack_deliver
+        # the tensor plane places each batch on the sharding (else the
+        # default device) and checks it landed there.  The collate buffers
+        # are 64-byte aligned so that on CPU nothing copies; on TPU only
+        # the H2D DMA does (demoted dtypes pay the cast).  The ring probe
+        # measures the same device_put, so its verdict governs this path.
+        from lakesoul_tpu.tensorplane.dlpack import deliver
 
         sharding = self._sharding
-        raw_put = lambda b: dlpack_deliver(b, sharding)  # noqa: E731
         h_put = self._h_device_put
 
         def put(b):
             # dispatch cost only: the H2D copy itself overlaps the
             # training step (that's the double buffering's point)
             t0 = time.perf_counter()
-            r = raw_put(b)
+            r = deliver(b, sharding)
             h_put.observe(time.perf_counter() - t0)
             return r
 

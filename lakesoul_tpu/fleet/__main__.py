@@ -82,12 +82,16 @@ def _cmd_train(args) -> int:
     scan = catalog.scan(args.table, args.namespace).batch_size(args.batch_size)
     if args.location:
         scan = scan.via_scanplane(args.location)
-    try:
+    # JAX is touched only by a rank that will deliver to a device: a host-
+    # array rank must neither claim the chip nor pay the backend start-up
+    local_devices = None
+    if args.device_put:
         import jax
 
+        from lakesoul_tpu.utils.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
         local_devices = jax.local_device_count()
-    except Exception:
-        local_devices = 0
     digest = hashlib.sha256()
     rows = 0
     batches = 0

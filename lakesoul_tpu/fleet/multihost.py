@@ -35,7 +35,8 @@ ENV_COUNT = "LAKESOUL_FLEET_PROCESS_COUNT"
 def process_axis() -> "tuple[int, int]":
     """(process_index, process_count) for the data axis: the env override
     when set (both vars required together, validated), else jax's view of
-    the mesh, else a single process."""
+    the mesh.  A backend that fails to start raises: calling that "a single
+    process" would have every host train on the whole table."""
     raw_idx = os.environ.get(ENV_INDEX)
     raw_cnt = os.environ.get(ENV_COUNT)
     if raw_idx is not None or raw_cnt is not None:
@@ -55,12 +56,9 @@ def process_axis() -> "tuple[int, int]":
                 f"invalid process axis index={idx} count={cnt}"
             )
         return idx, cnt
-    try:
-        import jax
+    import jax
 
-        return jax.process_index(), jax.process_count()
-    except Exception:  # jax absent or uninitialised: single-host
-        return 0, 1
+    return jax.process_index(), jax.process_count()
 
 
 def digest_batch(digest, batch: dict) -> int:

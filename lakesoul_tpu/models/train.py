@@ -56,10 +56,17 @@ def _jit_step_pinning_opt_shardings(step_fn, param_shardings, batch_shardings,
     Leaving the output unspecified lets GSPMD re-shard a replicated leaf (the
     observed "aliased input/output size" failure), so the shardings are
     captured from the first call's arrays and pinned identically on input and
-    output."""
+    output.
+
+    The batch is placed on its pinned shardings before the call.  Under jax
+    0.9 a batch the loader delivered uncommitted (``sharding=None``) and one
+    delivered on the pinned sharding are different abstract values, and the
+    step would trace and compile once for each; placing is free when the
+    batch is already there."""
     box: dict = {}
 
     def call(params, opt_state, *batch):
+        batch = jax.device_put(batch, batch_shardings)
         fn = box.get("fn")
         if fn is None:
             opt_shardings = jax.tree.map(

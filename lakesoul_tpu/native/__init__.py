@@ -1,42 +1,71 @@
 """Native C++ core loader.
 
-Builds (once, cached) and loads ``liblakesoul_native.so`` via ctypes; every
+Builds (once, cached) and loads the shared library via ctypes; every
 consumer has a pure-numpy fallback, so the package works without a compiler
-(set ``LAKESOUL_TPU_DISABLE_NATIVE=1`` to force fallbacks)."""
+(set ``LAKESOUL_TPU_DISABLE_NATIVE=1`` to force fallbacks).
+
+The library file is named after the SHA-256 of ``src/lakesoul_native.cc``,
+so the only binary this loader will open is one built from the source as it
+stands in the checkout: a copied tree, a stale build or a binary from
+another commit has another name and is rebuilt, never loaded."""
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
 import subprocess
 import threading
 
 import numpy as np
 
+logger = logging.getLogger(__name__)
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "lakesoul_native.cc")
-_LIB_PATH = os.path.join(_HERE, "liblakesoul_native.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"liblakesoul_native-{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
+    # compile beside the target and rename into place: a concurrent process
+    # (tests spawn many) must never dlopen a half-written file
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         # no -march=native: the .so may travel with the package tree to a
         # different CPU (container image, shared venv) where native ISA
         # extensions would SIGILL; these kernels vectorize fine at -O3
         subprocess.run(  # lakelint: ignore[raw-process] one-shot compiler invocation at import bootstrap (timeout-bounded, reaped); not a managed service process
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-             _SRC, "-o", _LIB_PATH],
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
+        os.replace(tmp, lib_path)
+    except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        logger.warning(
+            "native library build failed (%s); numpy fallbacks in use\n%s",
+            e, detail.decode(errors="replace")[-2000:],
+        )
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return False
+    # builds of other source revisions can never be loaded again
+    for old in glob.glob(os.path.join(_HERE, "liblakesoul_native*.so")):
+        if old != lib_path:
+            os.remove(old)
+    return True
 
 
 def _bind(lib) -> None:
@@ -85,27 +114,26 @@ def get_lib():
         return _lib
     if _tried:
         return _lib
+    # hash the source before taking the lock: file IO does not belong under it
+    lib_path = _lib_path() if os.path.exists(_SRC) else None
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        have_src = os.path.exists(_SRC)
-        stale = (
-            not os.path.exists(_LIB_PATH)
-            or (have_src and os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC))
-        )
-        if stale:
-            if not have_src or not _build():
-                return None
+        if lib_path is None:
+            return None
+        if not os.path.exists(lib_path) and not _build(lib_path):
+            return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            _bind(lib)
-            _lib = lib
-        except (OSError, AttributeError):
-            # AttributeError: a stale prebuilt .so missing newer symbols whose
-            # mtime defeated the staleness check — fall back to numpy rather
-            # than crash the first hash/merge call
-            _lib = None
+            lib = ctypes.CDLL(lib_path)
+        except OSError as e:
+            logger.warning(
+                "native library %s failed to load (%s); numpy fallbacks in use",
+                lib_path, e,
+            )
+            return None
+        _bind(lib)
+        _lib = lib
     return _lib
 
 

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from lakesoul_tpu.parallel._compat import axis_size, shard_map
-
 
 def _index_pytree(tree, i, n):
     """tree leaves [M, ...] → leaves [...] at clamped index i."""
@@ -47,7 +45,7 @@ def pipeline_apply(stage_params, micro, *, stage_fn, axis_name: str = "pp"):
     device.  Returns the same pytree shape holding the LAST stage's outputs
     (zeros elsewhere — the caller psums over the pp axis)."""
     idx = lax.axis_index(axis_name)
-    pp = axis_size(axis_name)
+    pp = lax.axis_size(axis_name)
     M = jax.tree.leaves(micro)[0].shape[0]
     perm = [(j, (j + 1) % pp) for j in range(pp)]
 
@@ -99,7 +97,7 @@ def make_pipeline(mesh, stage_fn, *, axis_name: str = "pp", micro_spec: P = P())
         return stage_fn(local, inp)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis_name), micro_spec),
         out_specs=micro_spec,

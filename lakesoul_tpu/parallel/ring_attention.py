@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from lakesoul_tpu.parallel._compat import axis_size, shard_map
-
 
 def _block_attn(q, k, v, scale, mask=None):
     """One Q-block × K-block attention contribution.
@@ -43,7 +41,7 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", kv_mask=None):
     Shapes (per device): q/k/v [B, H, T_local, D]; kv_mask [B, T_local] bool
     (True = attend) travels with K/V around the ring.  Returns [B, H, T_local, D]
     in q's dtype."""
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     scale = 1.0 / (q.shape[-1] ** 0.5)
 
     def mask_for(blk_mask):
@@ -82,7 +80,7 @@ def make_ring_attention(mesh, *, axis_name: str = "sp"):
     (batch over dp, heads over tp, sequence over sp)."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P("dp", "tp", "sp", None),

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from lakesoul_tpu.parallel._compat import axis_size, shard_map
-
 
 def _full_attention(q, k, v, scale, kv_mask=None):
     """Plain softmax attention: q/k/v [B, h, T, D] → [B, h, T, D]."""
@@ -49,7 +47,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = "sp", kv_mask=None):
     q/k/v: [B, H, T_local, D] with T_local = T/sp; H must be divisible by
     sp.  kv_mask: [B, T_local] bool (True = attend).  Returns
     [B, H, T_local, D]."""
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     if sp == 1:
         return _full_attention(q, k, v, scale, kv_mask)
@@ -82,7 +80,7 @@ def make_ulysses_attention(mesh, *, axis_name: str = "sp"):
     the trainer (models/train.py attention_fn)."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P("dp", "tp", "sp", None),

@@ -1,15 +1,19 @@
-"""Zero-copy DLPack delivery + the empirical device-put aliasing probe.
+"""Host-buffer → device delivery + the empirical device-put aliasing probe.
 
 Two exports, both about the same question — *where does the copy happen
 when a collated host buffer becomes a jax.Array?*
 
-- :func:`deliver` moves a collated pytree to device.  When the dtype
-  survives jax's canonicalization unchanged, each leaf rides the DLPack
-  protocol (``jax.dlpack.from_dlpack``) so the host-side import is
-  zero-copy — on TPU the only copy left is the H2D DMA itself, on CPU
-  there is no copy at all.  Leaves whose dtype jax would demote
-  (int64/float64 under disabled x64) take plain ``device_put`` — the cast
-  IS a real copy, there is nothing to save.
+- :func:`deliver` places a collated pytree on the delivery target (the
+  given sharding, else JAX's default device) and checks that every leaf
+  landed there.  It is plain ``jax.device_put``: for a 64-byte-aligned
+  buffer whose dtype jax keeps, the CPU backend aliases the host bytes (no
+  copy anywhere) and an accelerator pays the H2D DMA and nothing else;
+  demoted dtypes (int64/float64 under disabled x64) pay the cast, a real
+  copy.  (The module keeps its name from the ``jax.dlpack.from_dlpack``
+  import it used to ride.  That import returns an array *committed to the
+  host CPU device*, so on an accelerator host the batch never left the
+  CPU backend, and on the CPU backend it aliased nothing ``device_put``
+  does not alias already.)
 - :func:`device_put_copies` / :func:`delivery_copies` measure, per
   (dtype, target backend), whether ``jax.device_put`` of a host array is
   a REAL copy or an alias of the host buffer.  PR 9 found the collate
@@ -54,32 +58,41 @@ def aligned_empty(shape, dtype) -> np.ndarray:
     return raw[off:off + nbytes].view(dt).reshape(shape)
 
 
+def default_device():
+    """The device an untargeted ``jax.device_put`` lands on: the
+    ``jax.default_device`` setting when one is active (a device, or a
+    platform name), else the first local device of the default backend."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.local_devices()[0]
+    if isinstance(dev, str):
+        return jax.local_devices(backend=dev)[0]
+    return dev
+
+
 def _probe_device(sharding=None):
     """The single device a probe targets: aliasing is a per-backend
     property, so one device of the sharding's set stands for all of it."""
-    import jax
-
     if sharding is not None:
         devices = getattr(sharding, "device_set", None)
         if devices:
             return sorted(devices, key=lambda d: d.id)[0]
-    return jax.devices()[0]
+    return default_device()
 
 
 def device_put_copies(dtype, sharding=None) -> bool:
     """True when ``jax.device_put`` of a host numpy array of ``dtype``
     onto the delivery target is a REAL copy (the produced jax.Array owns
-    bytes disjoint from the source buffer); False when it aliases.  Any
-    probe failure reports False — "assume aliasing" is the safe answer
-    for every caller (the ring stays down, the replay cache makes a
-    defensive copy)."""
+    bytes disjoint from the source buffer); False when it aliases.  A
+    probe that fails on a live backend reports False — "assume aliasing"
+    is the safe answer for every caller (the ring stays down, the replay
+    cache makes a defensive copy); a backend that fails to start raises."""
     import jax
 
     dt = np.dtype(dtype)
-    try:
-        device = _probe_device(sharding)
-    except Exception:
-        return False
+    device = _probe_device(sharding)
     key = (dt.str, getattr(device, "platform", "unknown"))
     hit = _COPY_CACHE.get(key)
     if hit is not None:
@@ -116,40 +129,27 @@ def delivery_copies(dtypes, sharding=None) -> bool:
     return all(device_put_copies(dt, sharding) for dt in dtypes)
 
 
-def _canonical_dtype(dt: np.dtype):
-    """What jax will store for a host array of ``dt`` (x64 demotion)."""
-    import jax.numpy as jnp
-
-    return jnp.asarray(np.zeros(0, dtype=dt)).dtype
-
-
 def deliver(batch, sharding=None):
-    """Collated host pytree → device pytree, avoiding every avoidable host
-    copy.
+    """Collated host pytree → device pytree on the delivery target.
 
-    Dtype-preserved leaves are imported through DLPack first — a zero-copy
-    view of the collate buffer — then placed with ``device_put``: on CPU
-    placement is the identity (no copy anywhere), on TPU/GPU it is the H2D
-    DMA and nothing else.  Demoted dtypes skip the import (the cast is the
-    copy).  The caller owns the lifetime question: an aliased delivery
-    borrows the collate buffer, which is exactly what
-    :func:`delivery_copies` lets it check."""
+    With a ``sharding`` every leaf is laid out by it; without one the batch
+    goes to the default device uncommitted, exactly as a bare
+    ``jax.device_put`` would, so a jitted step may still move it to where
+    its params live.  Either way the placement is verified leaf by leaf —
+    a batch left on another backend would train on the host, or fail
+    inside the step, with nothing pointing back here.  The caller owns the
+    lifetime question: an aliased delivery borrows the collate buffer,
+    which is exactly what :func:`delivery_copies` lets it check."""
     import jax
 
-    def put_leaf(x):
-        if isinstance(x, np.ndarray) and x.flags.c_contiguous:
-            try:
-                if _canonical_dtype(x.dtype) == x.dtype:
-                    imported = jax.dlpack.from_dlpack(x)
-                    # placement still runs: on CPU it is the identity (the
-                    # imported alias passes through), on TPU/GPU it is the
-                    # H2D transfer — from_dlpack alone would leave the
-                    # leaf committed to the host backend
-                    if sharding is None:
-                        return jax.device_put(imported)
-                    return jax.device_put(imported, sharding)
-            except Exception:
-                pass  # protocol/backend gap: plain device_put is correct
-        return jax.device_put(x, sharding) if sharding is not None else jax.device_put(x)
+    from lakesoul_tpu.errors import IOError_
 
-    return jax.tree_util.tree_map(put_leaf, batch)
+    want = {default_device()} if sharding is None else sharding.device_set
+    out = jax.device_put(batch, sharding)
+    for leaf in jax.tree_util.tree_leaves(out):
+        if leaf.devices() != want:
+            raise IOError_(
+                f"delivered leaf is on {sorted(map(str, leaf.devices()))},"
+                f" expected {sorted(map(str, want))}"
+            )
+    return out

@@ -26,10 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 # --------------------------------------------------------------------------
@@ -64,9 +61,8 @@ def packed_scan_pallas(
     interpret: bool = False,
 ):
     """Pallas packed-code scan over one cluster: returns estimated sq-dists
-    [N].  ``interpret=True`` runs the kernel in the Pallas interpreter — the
-    pinned JAX has no ``force_tpu_interpret_mode``, so differential tests on
-    CPU opt in per call."""
+    [N].  ``interpret=True`` runs the kernel in the Pallas interpreter, which
+    is how differential tests on CPU opt in per call."""
     n, d8 = packed_codes.shape
     n_pad = ((n + tile - 1) // tile) * tile
     if n_pad != n:

@@ -383,6 +383,21 @@ class TestRaggedKernels:
         )
         np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-3)
 
+    def test_pallas_item_chunks_match_one_call(self, monkeypatch):
+        """The prefetched item tables must fit SMEM, so a micro-batch past
+        MAX_ITEMS runs as several calls: same scores, item for item."""
+        p = self._plan(seed=11, n_rows=1_024, nlist=6, nq=4)
+        items = ragged.plan_items(
+            p["pairs_q"], p["pairs_c"], p["csq"], p["csum"],
+            p["tile_start"], p["tile_count"],
+        )
+        args = (*items, p["q_glob"], p["codes"], p["a"], p["b"], p["h"])
+        whole = ragged.ragged_score_pallas(*args, tile=p["tile"], interpret=True)
+        assert len(whole) > 5
+        monkeypatch.setattr(ragged, "MAX_ITEMS", 5)
+        chunked = ragged.ragged_score_pallas(*args, tile=p["tile"], interpret=True)
+        np.testing.assert_array_equal(chunked, whole)
+
     def test_fold_cluster_matches_reference_estimator(self):
         """The folded (a, b, h) form reproduces the kernels' estimator: an
         est-only plane search equals IvfRabitqIndex.search(rerank=False)."""

@@ -14,9 +14,8 @@ PROVENANCE CAVEAT (VERDICT r4 item 10): the fixture generator is an
 independent *re-implementation*, but both sides of this cross-check share
 an author.  A table actually written by the reference's Rust writer would
 be stronger evidence; no Rust toolchain exists in this image, so the
-standing request to the driver/operator for one reference-written table
-(any size, produced offline) is recorded in ROUND_NOTES.md.  Until it
-lands, these tests prove convention-compatibility, not wire-compatibility
+request to the driver/operator for one reference-written table (any
+size, produced offline) is still open.  Until it lands, these tests prove convention-compatibility, not wire-compatibility
 with the reference binary."""
 
 import json

@@ -1,0 +1,79 @@
+"""Every Pallas kernel must lower for the ``tpu`` platform from the CPU.
+
+Lowering runs Pallas' own checks of the kernel against Mosaic's rules —
+block shapes whose last two dimensions are neither divisible by (8, 128)
+nor equal to the array's, unsupported memory spaces — without a chip, so
+that class of refusal is caught here and not on the chip budget.  It is not
+a compile and says nothing about agreement; ``chip_smoke.py`` owns both.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from lakesoul_tpu.annplane import ragged
+from lakesoul_tpu.tensorplane.smoke import enumerate_pallas_kernels
+from lakesoul_tpu.vector import kernels
+
+ROWS = 65_536
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _packed_scan(d):
+    return kernels.packed_scan_pallas.trace(
+        _sds((ROWS, d // 8), jnp.uint8), _sds((ROWS,)), _sds((ROWS,)), _sds((d,)),
+        d=d, interpret=False,
+    )
+
+
+def _packed_dot(d):
+    return kernels.packed_dot_pallas.trace(
+        _sds((ROWS, d // 8), jnp.uint8), _sds((d,)), interpret=False
+    )
+
+
+def _packed_dot_batch(d):
+    return kernels.packed_dot_batch_pallas.trace(
+        _sds((ROWS, d // 8), jnp.uint8), _sds((64, d)), interpret=False
+    )
+
+
+def _bruteforce(d):
+    return kernels.bruteforce_distances_pallas.trace(
+        _sds((ROWS, d)), _sds((d,)), interpret=False
+    )
+
+
+def _ragged_score(d):
+    m, nq = 4096, 64
+    return ragged._ragged_score_pallas_call.trace(
+        _sds((m,), jnp.int32), _sds((m,), jnp.int32), _sds((m,)), _sds((m,)),
+        _sds((nq, d)), _sds((ROWS, d)), _sds((ROWS,)), _sds((ROWS,)), _sds((ROWS,)),
+        tile=ragged.TILE, interpret=False,
+    )
+
+
+# keyed by lakelint device-index qname, like the smoke register
+TRACERS = {
+    "lakesoul_tpu/vector/kernels.py::_packed_scan_kernel": _packed_scan,
+    "lakesoul_tpu/vector/kernels.py::_packed_dot_kernel": _packed_dot,
+    "lakesoul_tpu/vector/kernels.py::_packed_dot_batch_kernel": _packed_dot_batch,
+    "lakesoul_tpu/vector/kernels.py::_bruteforce_kernel": _bruteforce,
+    "lakesoul_tpu/annplane/ragged.py::_ragged_score_kernel": _ragged_score,
+}
+
+
+def test_every_enumerated_kernel_has_a_lowering_case():
+    assert sorted(TRACERS) == enumerate_pallas_kernels()
+
+
+@pytest.mark.parametrize("d", [128, 768])
+@pytest.mark.parametrize("kernel", sorted(TRACERS))
+def test_kernel_lowers_for_tpu(kernel, d):
+    lowered = TRACERS[kernel](d).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
