@@ -34,8 +34,11 @@ The script has no CPU mode: it exits 2 at once, printing no result, unless
 ``jax.devices()[0].platform`` is ``tpu``, and it never sets
 ``JAX_PLATFORMS``.  The stage functions take explicit sizes and an explicit
 ``interpret`` so that tier-1 can run them tiny on the CPU
-(``tests/test_chip_smoke.py``).  The last stdout line is one JSON object.
-The timings in it are smoke timings, not a metric.
+(``tests/test_chip_smoke.py``).  The last stdout line is the result,
+``{"ok": true, "device": {"platform", "kind", "count"}}`` as JAX reports the
+device; the line before it is the report: one JSON object with each stage's
+status and seconds, the compile-cache directory and ``"claim": null``.  The
+timings in it are smoke timings, not a metric.
 """
 
 from __future__ import annotations
@@ -477,9 +480,9 @@ def main() -> int:
     else:
         multichip = f"not run: {len(devices)} device(s)"
 
-    print(json.dumps({
-        "ok": True,
-        "device": device,
+    # the report, then the result: the last stdout line carries exactly
+    # "ok" and "device", which is all the driver's check reads
+    print(json.dumps({"report": {
         "jax": jax.__version__,
         "compile_cache_dir": cache_dir,
         "native_built": True,
@@ -487,7 +490,8 @@ def main() -> int:
         "stages": stages,
         "note": "seconds are smoke timings, not a metric",
         "claim": None,
-    }))
+    }}))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -42,6 +42,37 @@ def test_script_refuses_a_cpu(tmp_path):
     assert proc.stdout.strip() == ""
 
 
+def test_result_line_is_exactly_ok_and_device(chip_smoke, monkeypatch, capsys):
+    """The last stdout line carries ``ok`` and ``device`` (platform, kind,
+    count) and nothing else; the report is the line before it.  The stages
+    are stubbed: only ``main``'s framing is under test."""
+    from lakesoul_tpu import native
+    from lakesoul_tpu.utils import compile_cache
+
+    class FakeChip:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda: [FakeChip()])
+    monkeypatch.setattr(native, "available", lambda: True)
+    monkeypatch.setattr(compile_cache, "configure_compile_cache", lambda: "/nowhere")
+    for stage in ("stage_trainer", "stage_ann_server", "stage_kernels"):
+        monkeypatch.setattr(chip_smoke, stage, lambda *a, **k: {})
+
+    assert chip_smoke.main() == 0
+    report, result = (json.loads(line) for line in capsys.readouterr().out.splitlines()[-2:])
+    assert result == {
+        "ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    assert isinstance(result["device"]["count"], int)
+    report = report["report"]
+    assert report["claim"] is None
+    assert report["multichip"] == "not run: 1 device(s)"
+    assert {name: s["status"] for name, s in report["stages"].items()} == {
+        "trainer": "pass", "ann_server": "pass", "kernels": "pass",
+    }
+
+
 def test_trainer_stage_tiny(chip_smoke):
     """All three loader modes into one step function on a one-device mesh:
     placement, finiteness, the float32 reference and exactly one compile."""
