@@ -1,0 +1,9 @@
+"""Device idle milliseconds a step, outside any device program, while the
+consumer thread was inside ``lakesoul.loader.queue``: waiting on the host
+pipeline (``chipbench/program_spans.py``)."""
+
+from chipbench import program_spans
+
+
+def read(sample):
+    return program_spans.owner_ms_step(sample, "lakesoul.loader.queue")

@@ -13,7 +13,7 @@ rule can distinguish from an inline string.
 
 Flagged: a string-literal or f-string value for an identity keyword in a
 call to a metric factory (``counter``/``gauge``/``histogram``) or a stage
-helper (``stage_merge``/``stage_observe``/``stage_histogram``).  Values
+helper (``stage``/``stage_merge``/``stage_histogram``).  Values
 read from a variable, attribute, or call pass — they trace back to a
 single assignment a reviewer can audit.  ``obs/fleet.py`` itself is
 exempt: it is the implementation these labels must come from.
@@ -30,7 +30,7 @@ _IDENTITY_KEYS = ("role", "worker", "service_id")
 
 _FACTORIES = (
     "counter", "gauge", "histogram",
-    "stage_merge", "stage_observe", "stage_histogram",
+    "stage", "stage_merge", "stage_histogram",
 )
 
 _EXEMPT = ("lakesoul_tpu/obs/fleet.py",)

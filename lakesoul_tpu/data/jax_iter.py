@@ -36,7 +36,7 @@ import numpy as np
 import pyarrow as pa
 
 from lakesoul_tpu.obs import registry
-from lakesoul_tpu.obs.stages import stage_histogram
+from lakesoul_tpu.obs.stages import stage
 from lakesoul_tpu.runtime import pipeline as rt_pipeline
 from lakesoul_tpu.tensorplane.dlpack import aligned_empty, delivery_copies
 
@@ -633,13 +633,9 @@ class JaxBatchIterator:
             self._ring = _BufferRing(
                 max(1, prefetch) + max(1, device_prefetch) + 2
             )
-        # stage-attribution handles, fetched once (the obs hot-path
-        # contract); the queue series carries this loader's consumer tag so
-        # multi-client stall is attributable per client
-        self._h_rebatch = stage_histogram("rebatch")
-        self._h_collate = stage_histogram("collate")
-        self._h_queue = stage_histogram("queue", consumer=consumer or "local")
-        self._h_device_put = stage_histogram("device_put")
+        # the queue stage carries this loader's consumer tag so multi-client
+        # stall is attributable per client
+        self._consumer = consumer or "local"
         self._transform = transform
         self._device_put = device_put
         self._sharding = sharding
@@ -724,7 +720,6 @@ class JaxBatchIterator:
             capture_views=self._collate is _default_collate,
             tensor_shapes=self._tensor_shapes,
         )
-        h = self._h_rebatch
         # the batch-source seam: in-process decode, a scan-plane fleet
         # (scan.via_scanplane) OR a continuous follow stream (follow=) —
         # everything downstream (rebatch, collate, prefetch, device_put,
@@ -739,9 +734,8 @@ class JaxBatchIterator:
         for arrow_batch in source.iter_batches(
             num_threads=self._io_threads, skip_rows=skip
         ):
-            t0 = time.perf_counter()
-            windows = rb.push(arrow_batch)
-            h.observe(time.perf_counter() - t0)
+            with stage("rebatch"):
+                windows = rb.push(arrow_batch)
             yield from windows
         if not self._drop_remainder:
             tail = rb.tail()
@@ -760,22 +754,21 @@ class JaxBatchIterator:
         )
 
     def _host_batch(self, window):
-        t0 = time.perf_counter()
-        if isinstance(window, _Window):
-            if window.fast and self._collate is _default_collate:
-                # fused zero-copy path: views → output buffers, no
-                # intermediate table, no per-column combine_chunks
-                slot = self._ring.next_slot() if self._ring is not None else None
-                batch = window.collate(slot)
-            elif self._collate is _default_collate:
-                batch = _default_collate(window.to_table(), self._tensor_shapes)
+        with stage("collate"):
+            if isinstance(window, _Window):
+                if window.fast and self._collate is _default_collate:
+                    # fused zero-copy path: views → output buffers, no
+                    # intermediate table, no per-column combine_chunks
+                    slot = self._ring.next_slot() if self._ring is not None else None
+                    batch = window.collate(slot)
+                elif self._collate is _default_collate:
+                    batch = _default_collate(window.to_table(), self._tensor_shapes)
+                else:
+                    batch = self._collate(window.to_table())
             else:
-                batch = self._collate(window.to_table())
-        else:
-            batch = self._collate(window)
-        if self._transform is not None:
-            batch = self._transform(batch)
-        self._h_collate.observe(time.perf_counter() - t0)
+                batch = self._collate(window)
+            if self._transform is not None:
+                batch = self._transform(batch)
         return batch
 
     def _fresh_containers(self, batch):
@@ -857,17 +850,15 @@ class JaxBatchIterator:
             nonlocal produced_all
             try:
                 while True:
-                    waited = time.perf_counter()
                     try:
-                        item = next(pipe)
+                        with stage("queue", consumer=self._consumer) as waited:
+                            item = next(pipe)
                     except StopIteration:
                         produced_all = True
                         return
-                    stall = time.perf_counter() - waited
                     # telemetry at the host hand-off: this is the loader's
                     # produced throughput and how long the consumer starved
-                    self._h_queue.observe(stall)
-                    self._stats.delivered(item[0], stall, pipe.queue_depth())
+                    self._stats.delivered(item[0], waited.elapsed, pipe.queue_depth())
                     yield item
             finally:
                 # quiesce, don't just signal: an abandoned producer that
@@ -899,15 +890,12 @@ class JaxBatchIterator:
         from lakesoul_tpu.tensorplane.dlpack import deliver
 
         sharding = self._sharding
-        h_put = self._h_device_put
 
         def put(b):
             # dispatch cost only: the H2D copy itself overlaps the
             # training step (that's the double buffering's point)
-            t0 = time.perf_counter()
-            r = deliver(b, sharding)
-            h_put.observe(time.perf_counter() - t0)
-            return r
+            with stage("device_put"):
+                return deliver(b, sharding)
 
         def emit(r, b):
             delivered(r)

@@ -24,14 +24,12 @@ CanCastSchemaBuilder, stream/default_column.rs).
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
 
 from lakesoul_tpu.errors import IOError_
-from lakesoul_tpu.obs.stages import stage_histogram
+from lakesoul_tpu.obs.stages import stage
 
 MERGE_OPERATORS = {
     "UseLast",
@@ -132,25 +130,19 @@ def merge_sorted_tables(
     encodes file version order)."""
     from lakesoul_tpu.obs import registry
 
-    started = time.perf_counter()
-    acc = {"fill": 0.0}
-    out = _merge_sorted_tables(
-        tables,
-        primary_keys,
-        merge_operators=merge_operators,
-        target_schema=target_schema,
-        defaults=defaults,
-        _stage_acc=acc,
-    )
-    total = time.perf_counter() - started
-    registry().histogram("lakesoul_io_merge_seconds").observe(total)
+    # stage attribution: the schema-uniform (cast/null-fill) leg inside is a
+    # "fill" stage of its own and everything else — sort/loser-tree/gather —
+    # is "merge" self time, so the two stay additive in the scan breakdown
+    with stage("merge") as whole:
+        out = _merge_sorted_tables(
+            tables,
+            primary_keys,
+            merge_operators=merge_operators,
+            target_schema=target_schema,
+            defaults=defaults,
+        )
+    registry().histogram("lakesoul_io_merge_seconds").observe(whole.elapsed)
     registry().counter("lakesoul_io_merge_rows_total").inc(len(out))
-    # stage attribution: the schema-uniform (cast/null-fill) leg counts as
-    # "fill", everything else — sort/loser-tree/gather — as "merge", so the
-    # two stages stay additive in the scan breakdown
-    if acc["fill"]:
-        stage_histogram("fill").observe(acc["fill"])
-    stage_histogram("merge").observe(max(0.0, total - acc["fill"]))
     return out
 
 
@@ -161,7 +153,6 @@ def _merge_sorted_tables(
     merge_operators: dict[str, str] | None = None,
     target_schema: pa.Schema | None = None,
     defaults: dict | None = None,
-    _stage_acc: dict | None = None,
 ) -> pa.Table:
     merge_operators = merge_operators or {}
     for colname, op in merge_operators.items():
@@ -172,10 +163,8 @@ def _merge_sorted_tables(
 
     if target_schema is None:
         target_schema = tables[0].schema
-    fill0 = time.perf_counter()
-    uniformed = [uniform_table(t, target_schema, defaults) for t in tables]
-    if _stage_acc is not None:
-        _stage_acc["fill"] = time.perf_counter() - fill0
+    with stage("fill"):
+        uniformed = [uniform_table(t, target_schema, defaults) for t in tables]
     # chunk-list concat only (zero-copy): the fast paths below gather
     # straight from the concatenated runs' chunks, so the combine_chunks
     # copy — once the single largest merge-apply cost per window — is

@@ -33,7 +33,7 @@ from lakesoul_tpu.io.filters import Filter, filter_column_names, zone_conjuncts
 from lakesoul_tpu.io.formats import format_for
 from lakesoul_tpu.io.merge import apply_cdc_filter, merge_sorted_tables, uniform_table
 from lakesoul_tpu.obs import registry
-from lakesoul_tpu.obs.stages import stage_histogram
+from lakesoul_tpu.obs.stages import stage
 from lakesoul_tpu.runtime import pipeline as rt_pipeline
 
 
@@ -41,14 +41,12 @@ def timed_decode_iter(it: Iterator) -> Iterator:
     """Wrap a format reader's batch iterator so every pull is attributed to
     the ``decode`` scan stage (runs on whatever thread actually decodes —
     the prefetch pump when the iterator sits behind one)."""
-    h = stage_histogram("decode")
     while True:
-        t0 = time.perf_counter()
         try:
-            item = next(it)
+            with stage("decode"):  # the pull only: never open across the yield
+                item = next(it)
         except StopIteration:
             return
-        h.observe(time.perf_counter() - t0)
         yield item
 
 
@@ -167,21 +165,20 @@ def _postprocess(
     # post-merge filter may reference partition columns that the final
     # projection drops)
     if partition_values and schema is not None:
-        fill0 = time.perf_counter()
-        n = len(merged)
-        arrays, names = [], []
-        for fld in schema:
-            if fld.name in merged.column_names:
-                arrays.append(merged.column(fld.name))
-                names.append(fld.name)
-            elif fld.name in partition_values:
-                val = partition_values[fld.name]
-                scalar = None if val == "__NULL__" else val
-                arr = pa.array([scalar] * n, type=pa.string()).cast(fld.type)
-                arrays.append(arr)
-                names.append(fld.name)
-        merged = pa.table(dict(zip(names, arrays)))
-        stage_histogram("fill").observe(time.perf_counter() - fill0)
+        with stage("fill"):
+            n = len(merged)
+            arrays, names = [], []
+            for fld in schema:
+                if fld.name in merged.column_names:
+                    arrays.append(merged.column(fld.name))
+                    names.append(fld.name)
+                elif fld.name in partition_values:
+                    val = partition_values[fld.name]
+                    scalar = None if val == "__NULL__" else val
+                    arr = pa.array([scalar] * n, type=pa.string()).cast(fld.type)
+                    arrays.append(arr)
+                    names.append(fld.name)
+            merged = pa.table(dict(zip(names, arrays)))
 
     if cdc_column and drop_cdc_deletes:
         merged = apply_cdc_filter(merged, cdc_column)
@@ -227,19 +224,17 @@ def read_scan_unit(
     )
 
     def _fetch_decode(path: str) -> pa.Table:
-        t0 = time.perf_counter()
-        t = _read_one_file(
-            path,
-            columns=plan.read_columns,
-            arrow_filter=plan.file_filter,
-            storage_options=storage_options,
-            zone_predicates=plan.zone_predicates,
-        )
-        stage_histogram("decode").observe(time.perf_counter() - t0)
+        with stage("decode"):
+            t = _read_one_file(
+                path,
+                columns=plan.read_columns,
+                arrow_filter=plan.file_filter,
+                storage_options=storage_options,
+                zone_predicates=plan.zone_predicates,
+            )
         if plan.file_schema is not None:
-            t0 = time.perf_counter()
-            t = uniform_table(t, plan.file_schema, defaults)
-            stage_histogram("fill").observe(time.perf_counter() - t0)
+            with stage("fill"):
+                t = uniform_table(t, plan.file_schema, defaults)
         return t
 
     if len(files) > 1:
@@ -483,9 +478,8 @@ def iter_scan_unit_batches(
                     continue
                 t = pa.Table.from_batches([batch])
                 if plan.file_schema is not None:
-                    fill0 = time.perf_counter()
-                    t = uniform_table(t, plan.file_schema, defaults)
-                    stage_histogram("fill").observe(time.perf_counter() - fill0)
+                    with stage("fill"):
+                        t = uniform_table(t, plan.file_schema, defaults)
                 t = post(t)
                 if len(t):
                     out_rows += len(t)

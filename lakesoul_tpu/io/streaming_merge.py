@@ -26,7 +26,6 @@ read/compaction time, bounded, instead of an ad-hoc spill file format.
 
 from __future__ import annotations
 
-import time
 from typing import Iterator
 
 import numpy as np
@@ -34,7 +33,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from lakesoul_tpu.io.merge import merge_sorted_tables, uniform_table
-from lakesoul_tpu.obs.stages import stage_histogram
+from lakesoul_tpu.obs.stages import stage
 from lakesoul_tpu.runtime import pipeline as rt_pipeline
 
 # rows per load step per stream; the byte budget divides down from this
@@ -150,9 +149,8 @@ class _SortedFileStream:
             return False
         t = pa.table(pa.Table.from_batches([batch]) if isinstance(batch, pa.RecordBatch) else batch)
         if self._file_schema is not None:
-            fill0 = time.perf_counter()
-            t = uniform_table(t, self._file_schema, self._defaults)
-            stage_histogram("fill").observe(time.perf_counter() - fill0)
+            with stage("fill"):
+                t = uniform_table(t, self._file_schema, self._defaults)
         elif not self._primed:
             # no declared schema: adopt the first batch's schema
             self._file_schema = t.schema

@@ -37,10 +37,10 @@ from lakesoul_tpu.obs.logging import JsonLogFormatter, configure_logging
 from lakesoul_tpu.obs.stages import (
     SCAN_STAGES,
     queue_seconds_by_consumer,
+    stage,
     stage_counts,
     stage_histogram,
     stage_merge,
-    stage_observe,
     stage_seconds,
 )
 from lakesoul_tpu.obs.metrics import (
@@ -93,9 +93,9 @@ __all__ = [
     "serve_prometheus",
     "SCAN_STAGES",
     "queue_seconds_by_consumer",
+    "stage",
     "stage_counts",
     "stage_histogram",
     "stage_merge",
-    "stage_observe",
     "stage_seconds",
 ]

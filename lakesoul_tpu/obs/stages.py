@@ -36,11 +36,23 @@ across ALL series of a stage regardless of extra labels — the degeneracy
 budgets and bench breakdowns see one number per stage, the labeled series
 stay queryable for attribution.
 
-Handles are memoized module-level (the registry is a process singleton);
-hot loops fetch a histogram once and pay only ``observe``.
+Handles are memoized module-level (the registry is a process singleton).
+
+:func:`stage` is the one seam every scan and loader call site goes through:
+``with stage("merge"):`` observes the stage's SELF time into the family
+above and, for as long as it is open, holds a
+``jax.profiler.TraceAnnotation`` named ``lakesoul.scan.<stage>`` (decode,
+merge, fill) or ``lakesoul.loader.<stage>`` (the rest).  The annotation
+lands on the profiler's clock, the one the device planes use, so a reader of
+the trace can say which stage was open while the device sat idle; outside a
+profiler session it records nothing.  The session is the only switch.
 """
 
 from __future__ import annotations
+
+import sys
+import threading
+import time
 
 from lakesoul_tpu.obs.metrics import Histogram, registry
 
@@ -65,8 +77,67 @@ def stage_histogram(stage: str, **labels: str) -> Histogram:
     return h
 
 
-def stage_observe(stage: str, seconds: float, **labels: str) -> None:
-    stage_histogram(stage, **labels).observe(seconds)
+# the profiler span of each stage; any other name is ``lakesoul.<name>``
+_SPAN_NAMES = {
+    s: ("lakesoul.scan." if s in ("decode", "merge", "fill") else "lakesoul.loader.") + s
+    for s in SCAN_STAGES
+}
+_annotation = None  # jax.profiler.TraceAnnotation, once this process has jax
+_open = threading.local()  # .stack: the stages open on this thread
+
+
+def _trace_annotation():
+    # never force the jax import: a process that has not imported jax has
+    # no profiler session to record into, and a scan worker must not pay
+    # XLA start-up for telemetry
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class stage:
+    """``with stage(name, **labels):`` — time one stage call.
+
+    On exit the stage's self time (its duration less the stages opened
+    inside it on the same thread: ``merge`` contains ``fill``) goes into
+    ``lakesoul_scan_stage_seconds{stage=name, **labels}``, so the stages
+    stay additive; the profiler span is the whole interval.  ``elapsed`` is
+    that whole interval in seconds, for a caller that also feeds another
+    series.  Never keep one open across a ``yield``: the stack is the
+    thread's."""
+
+    __slots__ = ("_hist", "_span", "_t0", "_inner", "elapsed")
+
+    def __init__(self, name: str, **labels: str):
+        self._hist = stage_histogram(name, **labels)
+        annotation = _trace_annotation()
+        self._span = None if annotation is None else annotation(
+            _SPAN_NAMES.get(name) or "lakesoul." + name
+        )
+
+    def __enter__(self) -> "stage":
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        stack.append(self)
+        self._inner = 0.0
+        if self._span is not None:
+            self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.elapsed = elapsed = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        stack = _open.stack
+        stack.pop()
+        if stack:
+            stack[-1]._inner += elapsed
+        self._hist.observe(max(0.0, elapsed - self._inner))
 
 
 def stage_merge(stage: str, seconds: float, count: int, **labels: str) -> None:

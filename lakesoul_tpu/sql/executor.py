@@ -18,7 +18,7 @@ from lakesoul_tpu.sql import parser as ast
 from lakesoul_tpu.sql.parser import SqlError, parse
 
 
-def _stage_observe(stage: str, started: float) -> None:
+def _observe_sql_stage(stage: str, started: float) -> None:
     """Per-stage executor latency: lakesoul_sql_stage_seconds{stage=...}."""
     registry().histogram("lakesoul_sql_stage_seconds", stage=stage).observe(
         time.perf_counter() - started
@@ -703,7 +703,7 @@ class SqlSession:
     def execute(self, sql: str) -> pa.Table:
         started = time.perf_counter()
         stmt = parse(sql)
-        _stage_observe("parse", started)
+        _observe_sql_stage("parse", started)
         target = getattr(stmt, "table", None)
         if target in self._externals and isinstance(
             stmt,
@@ -719,7 +719,7 @@ class SqlSession:
             with span("sql.execute", statement=type(stmt).__name__):
                 return self._execute_stmt(stmt)
         finally:
-            _stage_observe("execute", started)
+            _observe_sql_stage("execute", started)
             # a fetched external snapshot must not stay pinned past the
             # statement on a long-lived session
             self._ext_memo = None
@@ -1166,7 +1166,7 @@ class SqlSession:
         else:
             started = time.perf_counter()
             scan, residual_nodes = self._plan_base(stmt, has_aggs)
-            _stage_observe("plan", started)
+            _observe_sql_stage("plan", started)
             started = time.perf_counter()
             # parallel scan stage on the shared runtime: join right-side
             # base tables start scanning on the pool WHILE the base table
@@ -1188,7 +1188,7 @@ class SqlSession:
                 # a DROP TABLE issued right after
                 concurrent.futures.wait(list(join_futs.values()))
                 raise
-            _stage_observe("scan", started)
+            _observe_sql_stage("scan", started)
 
         emit_started = time.perf_counter()
         # ---- joins (hash joins on Arrow compute; right side may be derived)
@@ -1309,7 +1309,7 @@ class SqlSession:
         if hidden:
             out = out.drop_columns(hidden)
         out = _slice_limit_offset(out, stmt)
-        _stage_observe("emit", emit_started)
+        _observe_sql_stage("emit", emit_started)
         return out
 
     def _needed_columns(self, stmt: ast.Select, residual_nodes: list) -> set[str]:

@@ -34,6 +34,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from lakesoul_tpu.obs import registry
+
 # (np dtype str, device platform) -> device_put makes a real copy
 _COPY_CACHE: dict[tuple[str, str], bool] = {}
 
@@ -146,10 +148,15 @@ def deliver(batch, sharding=None):
 
     want = {default_device()} if sharding is None else sharding.device_set
     out = jax.device_put(batch, sharding)
+    placed = 0
     for leaf in jax.tree_util.tree_leaves(out):
         if leaf.devices() != want:
             raise IOError_(
                 f"delivered leaf is on {sorted(map(str, leaf.devices()))},"
                 f" expected {sorted(map(str, want))}"
             )
+        placed += leaf.nbytes
+    # the bytes as they lie on the device (after any dtype demotion): what
+    # the link carried, counted where it was dispatched
+    registry().counter("lakesoul_tensorplane_h2d_bytes_total").inc(placed)
     return out

@@ -1,0 +1,280 @@
+"""The ``stage()`` seam of ``obs/stages.py`` under a real profiler session.
+
+A small merge-on-read table goes through ``to_jax_iter`` three times: outside
+any session, then not at all during an empty session, then inside a session
+set up as the chip benchmark's ``Tracer`` sets it (host tracer level 2, the
+Python tracer off).  Per stage: the span is in the host plane on the right
+thread's line, the number of spans is the histogram's count delta, and the
+spans' self time is its sum delta; outside a session the histograms move and
+nothing is recorded.  The last test pins the two names the benchmark's device
+readers search a trace for.
+"""
+
+from __future__ import annotations
+
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from lakesoul_tpu.obs import SCAN_STAGES, registry, stage, stage_counts, stage_seconds
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONSUMER_STAGES = ("queue", "device_put")
+H2D_COUNTER = "lakesoul_tensorplane_h2d_bytes_total"
+
+
+def _span_name(stage_name: str) -> str:
+    layer = "scan" if stage_name in ("decode", "merge", "fill") else "loader"
+    return f"lakesoul.{layer}.{stage_name}"
+
+
+def _snapshot() -> dict:
+    return {"seconds": stage_seconds(), "counts": stage_counts(),
+            "h2d": registry().counter(H2D_COUNTER).value}
+
+
+def _delta(before: dict, after: dict) -> dict:
+    return {
+        "seconds": {s: after["seconds"][s] - before["seconds"][s] for s in SCAN_STAGES},
+        "counts": {s: after["counts"][s] - before["counts"][s] for s in SCAN_STAGES},
+        "h2d": after["h2d"] - before["h2d"],
+    }
+
+
+def _session(logdir: str, body) -> list[list[tuple[str, float, float]]]:
+    """Run ``body`` inside a profiler session; returns the host plane's lines,
+    each the ``(name, start_ns, duration_ns)`` of its ``lakesoul.*`` and
+    ``test.*`` events."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    lines = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            events = [(e.name, e.start_ns, e.duration_ns) for e in line.events
+                      if e.name.startswith(("lakesoul.", "test."))]
+            if events:
+                lines.append(events)
+    return lines
+
+
+def _self_ns(events) -> list[tuple[str, float]]:
+    """``(name, self time)`` of each span of one line: its duration less the
+    spans nested directly inside it."""
+    out: list[list] = []
+    open_: list[tuple[float, int]] = []  # (end, index into out)
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while open_ and start >= open_[-1][0]:
+            open_.pop()
+        if open_:
+            out[open_[-1][1]][1] -= dur
+        out.append([name, dur])
+        open_.append((start + dur, len(out) - 1))
+    return [(name, ns) for name, ns in out]
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    from lakesoul_tpu import LakeSoulCatalog
+
+    root = tmp_path_factory.mktemp("stage_spans")
+    schema = pa.schema([("id", pa.int64()), ("x", pa.float32())])
+    table = LakeSoulCatalog(str(root / "warehouse")).create_table(
+        "rows", schema, primary_keys=["id"], hash_bucket_num=2
+    )
+    rng = np.random.default_rng(0)
+    rows = 32768
+    for part in np.split(np.arange(rows, dtype=np.int64), 4):
+        table.write_arrow(pa.table({"id": part, "x": rng.random(len(part), np.float32)}, schema=schema))
+    ids = np.sort(rng.choice(rows, rows // 4, replace=False)).astype(np.int64)
+    table.upsert(pa.table({"id": ids, "x": rng.random(len(ids), np.float32)}, schema=schema))
+
+    delivered = []
+
+    def epoch():
+        n = nbytes = 0
+        for batch in table.scan().batch_size(512).to_jax_iter():
+            n += int(batch["id"].shape[0])
+            nbytes += sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(batch))
+        delivered.append((n, nbytes))
+
+    def consume():
+        with jax.profiler.TraceAnnotation("test.consume"):
+            epoch()
+
+    epoch()  # the backend, the native library, the pool's threads
+    before = _snapshot()
+    epoch()
+    outside = _delta(before, _snapshot())
+    quiet_lines = _session(str(root / "quiet"), lambda: None)
+    before = _snapshot()
+    lines = _session(str(root / "traced"), consume)
+    inside = _delta(before, _snapshot())
+    assert {n for n, _ in delivered} == {rows}
+    return {"outside": outside, "quiet_lines": quiet_lines, "lines": lines, "inside": inside,
+            "bytes_epoch": delivered[-1][1]}
+
+
+def _consumer_line(lines) -> int:
+    (index,) = [i for i, events in enumerate(lines) if any(n == "test.consume" for n, _, _ in events)]
+    return index
+
+
+@pytest.mark.parametrize("stage_name", SCAN_STAGES)
+def test_span_is_on_the_right_threads_line(recorded, stage_name):
+    lines, name = recorded["lines"], _span_name(stage_name)
+    holding = {i for i, events in enumerate(lines) if any(n == name for n, _, _ in events)}
+    assert holding, f"no {name} span in the host plane"
+    consumer = _consumer_line(lines)
+    if stage_name in CONSUMER_STAGES:
+        assert holding == {consumer}
+    else:
+        assert consumer not in holding
+
+
+@pytest.mark.parametrize("stage_name", SCAN_STAGES)
+def test_spans_agree_with_the_histogram(recorded, stage_name):
+    name = _span_name(stage_name)
+    selfs = [ns for events in recorded["lines"] for n, ns in _self_ns(events) if n == name]
+    assert len(selfs) == recorded["inside"]["counts"][stage_name]
+    seconds = recorded["inside"]["seconds"][stage_name]
+    assert abs(sum(selfs) / 1e9 - seconds) <= 0.05 * seconds + 1e-3
+
+
+def test_merge_self_time_excludes_its_fill_children(recorded):
+    whole = nested_fill = 0.0
+    for events in recorded["lines"]:
+        merges = [(s, s + d) for n, s, d in events if n == "lakesoul.scan.merge"]
+        whole += sum(e - s for s, e in merges)
+        nested_fill += sum(d for n, s, d in events if n == "lakesoul.scan.fill"
+                           and any(lo <= s and s + d <= hi for lo, hi in merges))
+    assert nested_fill > 0, "every merge uniforms its runs: a fill inside"
+    seconds = recorded["inside"]["seconds"]["merge"]
+    assert abs((whole - nested_fill) / 1e9 - seconds) <= 0.05 * seconds + 1e-3
+    assert seconds < whole / 1e9
+
+
+@pytest.mark.parametrize("stage_name", SCAN_STAGES)
+def test_outside_a_session_the_histogram_moves_and_nothing_is_recorded(recorded, stage_name):
+    assert recorded["outside"]["counts"][stage_name] > 0
+    assert recorded["outside"]["seconds"][stage_name] > 0
+    # the same work as the traced epoch (queue: one more or less wait on the pump)
+    slack = 1 if stage_name == "queue" else 0
+    assert abs(recorded["outside"]["counts"][stage_name] - recorded["inside"]["counts"][stage_name]) <= slack
+    assert not [n for events in recorded["quiet_lines"] for n, _, _ in events if n.startswith("lakesoul.")]
+
+
+def test_merge_and_fill_stay_additive(monkeypatch):
+    """``merge`` contains ``fill``: with the uniform step slowed to something a
+    clock can see, the two stages still sum to the whole merge call."""
+    import time
+
+    from lakesoul_tpu.io import merge
+
+    real = merge.uniform_table
+
+    def slow_uniform(*args, **kwargs):
+        time.sleep(0.02)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(merge, "uniform_table", slow_uniform)
+    runs = [pa.table({"id": pa.array([1, 2, 3], pa.int64()), "x": pa.array([v, v, v], pa.float32())})
+            for v in (0.0, 1.0)]
+    whole = registry().histogram("lakesoul_io_merge_seconds")
+    before = _snapshot(), whole.value["sum"]
+    merged = merge.merge_sorted_tables(runs, ["id"])
+    stages, total = _delta(before[0], _snapshot())["seconds"], whole.value["sum"] - before[1]
+    assert merged.column("x").to_pylist() == [1.0, 1.0, 1.0]
+    assert stages["fill"] >= 0.04
+    assert stages["merge"] + stages["fill"] == pytest.approx(total, abs=1e-4)
+    assert stages["merge"] < total - 0.04 + 1e-4
+
+
+def test_deliver_counts_the_bytes_it_placed(recorded):
+    assert recorded["inside"]["h2d"] == recorded["outside"]["h2d"] == recorded["bytes_epoch"] > 0
+
+
+def test_nested_stages_are_additive_and_any_name_is_taken():
+    """Self time by nesting, on this thread's stack, and a name outside the
+    seven (an ANN phase, later) goes through the same seam."""
+    import time
+
+    before = registry().histogram("lakesoul_scan_stage_seconds", stage="ann.upload").value
+    with stage("ann.upload") as outer:
+        time.sleep(0.02)
+        with stage("ann.upload") as inner:
+            time.sleep(0.03)
+    after = registry().histogram("lakesoul_scan_stage_seconds", stage="ann.upload").value
+    assert after["count"] - before["count"] == 2
+    assert outer.elapsed >= inner.elapsed >= 0.03
+    assert after["sum"] - before["sum"] == pytest.approx(outer.elapsed, abs=1e-4)
+
+
+def _step_module() -> str:
+    """The step as a training job gets it, ``make_bert_train_step``'s return
+    value called once; the factory jits on that first call, inside a closure,
+    so ``jax.jit`` is watched for what it was handed."""
+    from lakesoul_tpu.models.bert import BertConfig
+    from lakesoul_tpu.models.train import make_bert_train_state, make_bert_train_step
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    cfg = BertConfig.tiny()
+    params, opt_state, tx, shardings = make_bert_train_state(cfg, plan, lr=1e-3)
+    step = make_bert_train_step(cfg, plan, tx, shardings)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    lowered: list[str] = []
+    real_jit = jax.jit
+
+    def watching_jit(fn, **kwargs):
+        jitted = real_jit(fn, **kwargs)
+
+        def call(*args):
+            lowered.append(jitted.lower(*args).as_text())
+            return jitted(*args)
+
+        return call
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "jit", watching_jit)
+        step(params, opt_state, ids, ids, jnp.ones((2, 16), jnp.bool_))
+    (text,) = lowered
+    return text
+
+
+def _kernel_module() -> str:
+    from lakesoul_tpu.annplane import ragged
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    m, rows, d = 256, 1024, 128
+    return ragged._ragged_score_pallas_call.trace(
+        i32(m), i32(m), f32(m), f32(m), f32(8, d), f32(rows, d), f32(rows), f32(rows), f32(rows),
+        tile=ragged.TILE, interpret=False,
+    ).lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("lower, module, reader, constant", [
+    (_step_module, "jit_train_step", "consumers/bert_mlm.py", 'STEP_MODULE = "jit_train_step"'),
+    (_kernel_module, "jit__ragged_score_pallas_call", "layer_metrics/ragged_dev_ms.py", 'KERNEL = "ragged_score"'),
+])
+def test_names_the_device_readers_search_for(lower, module, reader, constant):
+    """``chipbench/trace.py`` finds the step program and the ragged kernel in a
+    device trace by these names and nothing else pins them: a rename here has
+    to be a rename there, which only a benchmark PR may make."""
+    assert f"module @{module} " in lower()
+    with open(os.path.join(REPO, "benchmarks", "chip", reader)) as f:
+        assert constant in f.read()
