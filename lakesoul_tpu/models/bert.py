@@ -14,6 +14,7 @@ All matmuls run in bfloat16 with float32 accumulation (MXU-native).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -190,11 +191,41 @@ def bert_embed(params, input_ids, *, cfg: BertConfig) -> jax.Array:
 
 
 def bert_head(params, x) -> jax.Array:
+    """MLM logits of hidden states ``[..., h]`` → ``[..., vocab]``."""
     x = _layer_norm(x, params["mlm_ln"]["scale"], params["mlm_ln"]["bias"])
     # weight-tied MLM head
     return jnp.einsum(
-        "bth,vh->btv", x.astype(jnp.float32), params["tok_emb"], preferred_element_type=jnp.float32
+        "...h,vh->...v", x.astype(jnp.float32), params["tok_emb"], preferred_element_type=jnp.float32
     ) + params["mlm_bias"]
+
+
+def bert_encode(
+    params: dict,
+    input_ids: jax.Array,
+    attn_mask: jax.Array | None = None,
+    *,
+    cfg: BertConfig,
+    attention_fn=None,
+    moe_ep_sharding=None,
+):
+    """Encoder forward → (final hidden states [B, T, h], summed MoE
+    load-balancing loss)."""
+    B, T = input_ids.shape
+    if attn_mask is None:
+        attn_mask = jnp.ones((B, T), dtype=bool)
+    else:
+        attn_mask = attn_mask.astype(bool)
+
+    x = bert_embed(params, input_ids, cfg=cfg)
+
+    def layer(carry, lp):
+        x, aux = carry
+        x, a = bert_layer(x, lp, attn_mask, cfg=cfg, attention_fn=attention_fn,
+                          moe_ep_sharding=moe_ep_sharding)
+        return (x, aux + a), None
+
+    (x, aux), _ = jax.lax.scan(layer, (x, jnp.float32(0.0)), params["layers"])
+    return x, aux
 
 
 def bert_forward(
@@ -212,35 +243,142 @@ def bert_forward(
 
     ``attention_fn(q, k, v, mask)`` defaults to plain full attention;
     pass ``make_ring_attention(mesh)`` for sequence parallelism."""
-    B, T = input_ids.shape
-    if attn_mask is None:
-        attn_mask = jnp.ones((B, T), dtype=bool)
-    else:
-        attn_mask = attn_mask.astype(bool)
-
-    x = bert_embed(params, input_ids, cfg=cfg)
-
-    def layer(carry, lp):
-        x, aux = carry
-        x, a = bert_layer(x, lp, attn_mask, cfg=cfg, attention_fn=attention_fn,
-                          moe_ep_sharding=moe_ep_sharding)
-        return (x, aux + a), None
-
-    (x, aux), _ = jax.lax.scan(layer, (x, jnp.float32(0.0)), params["layers"])
+    x, aux = bert_encode(
+        params, input_ids, attn_mask, cfg=cfg, attention_fn=attention_fn,
+        moe_ep_sharding=moe_ep_sharding,
+    )
     logits = bert_head(params, x)
     return (logits, aux) if with_aux else logits
 
 
-def masked_nll(logits, labels):
-    """Mean NLL over positions with labels >= 0 (-100 = ignore) — shared by
-    the scan-encoder loss and the pipelined loss so the two can never drift
-    (their exact equality is pinned in tests)."""
-    valid = labels >= 0
-    safe_labels = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, safe_labels[..., None], axis=-1)[..., 0]
-    nll = jnp.where(valid, nll, 0.0)
-    return jnp.sum(nll) / jnp.maximum(jnp.sum(valid), 1)
+# ---------------------------------------------------------------- MLM loss
+# The loss needs logits only where labels >= 0 (15% of positions in MLM), so
+# the head runs over those rows only: each shard of the batch moves its
+# labelled rows to the front and runs the head one fixed-size tile of them at
+# a time, for as many tiles as its labels fill.  A loop whose length depends
+# on the data has no reverse-mode derivative, so each tile's gradients are
+# taken inside the loop and handed to autodiff through one custom_vjp: no
+# [B*T, vocab] array exists in either pass.
+
+
+def head_tile(n: int) -> int:
+    """Rows the head runs at a time for a shard of ``n`` positions: a twelfth
+    of them, rounded up to a multiple of 8.  MLM labels 15%, so two tiles
+    are the usual case, and twelve cover every position with next to none
+    over.  (Measured on a v5e at 8,192 positions: PERF.md section 6, PR 26.)"""
+    return min(n, -(-n // 96) * 8)
+
+
+def _tile_nll(head, x, labels, scale):
+    """``scale`` x the summed NLL of one tile's labelled rows, in float32."""
+    logp = jax.nn.log_softmax(bert_head(head, x), axis=-1)
+    picked = jnp.take_along_axis(logp, jnp.maximum(labels, 0)[:, None], axis=-1)[:, 0]
+    return -scale * jnp.sum(jnp.where(labels >= 0, picked, 0.0))
+
+
+def _head_over_labelled(head, x, labels, axes, with_grads: bool):
+    """One shard's share of the loss: ``x`` [..., h] and ``labels`` [...] are
+    the rows this device holds, ``axes`` the mesh axes the batch is split
+    over.  → (loss, positions the head ran at), both summed over ``axes``,
+    and with ``with_grads`` the loss's gradients (head summed over ``axes``,
+    x for this shard's rows)."""
+    x2, lab = x.reshape(-1, x.shape[-1]), labels.reshape(-1)
+    n = lab.shape[0]
+    tile = head_tile(n)
+    slots = -(-n // tile) * tile
+    order = jnp.argsort(lab < 0, stable=True)  # labelled rows first
+    # slots past n read row 0 and carry no label
+    rows = jnp.pad(order, (0, slots - n))
+    row_labels = jnp.pad(lab[order], (0, slots - n), constant_values=-100)
+    count = jnp.sum(lab >= 0)
+    total = jax.lax.psum(count, axes) if axes else count
+    scale = 1.0 / jnp.maximum(total, 1).astype(jnp.float32)
+
+    def run_tile(carry):
+        k, loss, grads = carry
+        at = k * tile
+        xt = x2[jax.lax.dynamic_slice(rows, (at,), (tile,))]
+        lt = jax.lax.dynamic_slice(row_labels, (at,), (tile,))
+        if with_grads:
+            part, (g_head, g_x) = jax.value_and_grad(_tile_nll, argnums=(0, 1))(head, xt, lt, scale)
+            acc_head, acc_x = grads
+            grads = (
+                jax.tree.map(jnp.add, acc_head, g_head),
+                jax.lax.dynamic_update_slice(acc_x, g_x, (at, 0)),
+            )
+        else:
+            part = _tile_nll(head, xt, lt, scale)
+        return k + 1, loss + part, grads
+
+    grads = (
+        (jax.tree.map(jnp.zeros_like, head), jnp.zeros((slots, x2.shape[1]), x2.dtype))
+        if with_grads else ()
+    )
+    tiles = (count + tile - 1) // tile
+    _, loss, grads = jax.lax.while_loop(
+        lambda carry: carry[0] < tiles, run_tile, (jnp.int32(0), jnp.float32(0.0), grads)
+    )
+    positions = tiles * tile
+    if axes:
+        loss, positions = jax.lax.psum((loss, positions), axes)
+    if not with_grads:
+        return loss, positions
+    g_head, g_rows = grads
+    if axes:
+        g_head = jax.lax.psum(g_head, axes)
+    # back from labelled-first order; rows of tiles that never ran are zero
+    g_x = g_rows[jnp.argsort(order)].reshape(x.shape)
+    return loss, positions, g_head, g_x
+
+
+def _sharded_head(head, x, labels, batch_sharding, with_grads: bool):
+    if batch_sharding is None:
+        return _head_over_labelled(head, x, labels, (), with_grads)
+    spec = batch_sharding.spec
+    axes = tuple(
+        a for part in spec if part is not None
+        for a in (part if isinstance(part, tuple) else (part,))
+    )
+    out_specs = (P(), P(), P(), spec) if with_grads else (P(), P())
+    # every device gathers among its own rows; only sums cross the mesh
+    return jax.shard_map(
+        lambda head, x, labels: _head_over_labelled(head, x, labels, axes, with_grads),
+        mesh=batch_sharding.mesh, in_specs=(P(), spec, spec), out_specs=out_specs,
+        check_vma=False,
+    )(head, x, labels)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _labelled_nll(head, x, labels, batch_sharding):
+    return _sharded_head(head, x, labels, batch_sharding, False)
+
+
+def _labelled_nll_fwd(head, x, labels, batch_sharding):
+    loss, positions, g_head, g_x = _sharded_head(head, x, labels, batch_sharding, True)
+    return (loss, positions), (g_head, g_x)
+
+
+def _labelled_nll_bwd(batch_sharding, grads, cotangents):
+    g_head, g_x = grads
+    ct = cotangents[0]
+    return jax.tree.map(lambda g: ct * g, g_head), ct.astype(g_x.dtype) * g_x, None
+
+
+_labelled_nll.defvjp(_labelled_nll_fwd, _labelled_nll_bwd)
+
+
+def mlm_head_loss(params, x, labels, *, batch_sharding=None):
+    """Final hidden states [B, T, h] and labels [B, T] → (mean NLL over the
+    positions with labels >= 0 (-100 = ignore), positions the head ran at).
+
+    The one head-and-loss of the scan-encoder loss and the pipelined loss, so
+    the two can never drift.  Per labelled position it is ``bert_head``'s
+    arithmetic and a float32 log-softmax, whatever the number of labels;
+    what depends on ``labels`` is how many tiles run.  ``batch_sharding`` is
+    the ``NamedSharding`` of ``labels`` inside a sharded step, so that no
+    hidden state leaves its device."""
+    head = {k: params[k] for k in ("mlm_ln", "tok_emb", "mlm_bias")}
+    return _labelled_nll(head, x, labels, batch_sharding)
 
 
 def bert_mlm_loss(
@@ -252,14 +390,17 @@ def bert_mlm_loss(
     cfg: BertConfig,
     attention_fn=None,
     moe_ep_sharding=None,
-) -> jax.Array:
+    batch_sharding=None,
+    with_head_positions: bool = False,
+):
     """Masked-LM loss: labels == -100 are ignored.  With MoE configs the
-    Switch load-balancing auxiliary joins at cfg.moe_aux_weight."""
-    logits, aux = bert_forward(
+    Switch load-balancing auxiliary joins at cfg.moe_aux_weight.  With
+    ``with_head_positions`` → (loss, positions the head ran at)."""
+    x, aux = bert_encode(
         params, input_ids, attn_mask, cfg=cfg, attention_fn=attention_fn,
-        moe_ep_sharding=moe_ep_sharding, with_aux=True,
+        moe_ep_sharding=moe_ep_sharding,
     )
-    loss = masked_nll(logits, labels)
+    loss, positions = mlm_head_loss(params, x, labels, batch_sharding=batch_sharding)
     if cfg.n_experts:
         loss = loss + cfg.moe_aux_weight * aux
-    return loss
+    return (loss, positions) if with_head_positions else loss

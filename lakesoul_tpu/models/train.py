@@ -9,9 +9,12 @@ constraints — no hand-written collectives outside the ring-attention kernel.
 from __future__ import annotations
 
 import functools
+import threading
+import weakref
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -21,6 +24,7 @@ from lakesoul_tpu.models.bert import (
     init_bert_params,
     param_sharding_rules,
 )
+from lakesoul_tpu.obs import registry
 from lakesoul_tpu.parallel.mesh import MeshPlan
 from lakesoul_tpu.parallel.ring_attention import make_ring_attention
 
@@ -33,26 +37,76 @@ def _specs_to_shardings(mesh, rules):
     )
 
 
-def _place_opt_state(opt_state, mesh):
-    """Put every optimizer leaf on the mesh: zeros_like moments inherit their
-    param's NamedSharding from ``tx.init``, but fresh scalars (adam's
-    ``count``) land committed to a single device — mixing the two in one
-    jitted step is rejected outright."""
-    return jax.tree.map(
-        lambda x: x
-        if isinstance(x, jax.Array) and isinstance(x.sharding, NamedSharding)
-        else jax.device_put(x, NamedSharding(mesh, P())),
-        opt_state,
+def _init_train_state(cfg: BertConfig, mesh, shardings, lr: float, seed: int):
+    """(params, opt_state, tx, shardings) for AdamW at ``lr``: params and
+    optimizer state come from the seed as ONE jitted program that leaves
+    every leaf on the mesh: an optimizer leaf that mirrors a parameter (adam's
+    moments; its tree path ends in the parameter's) takes that parameter's
+    sharding, any other (adam's ``count``) is replicated.  Built eagerly,
+    ``jax.random.key``, ``tx.init``'s zeros and the placement of its scalars
+    were a dozen small programs, each lowered and looked up in the compile
+    cache on every start."""
+    tx = optax.adamw(lr)
+
+    def init(seed):
+        params = init_bert_params(cfg, jax.random.key(seed))
+        return params, tx.init(params)
+
+    by_path = dict(jax.tree_util.tree_leaves_with_path(shardings))
+    replicated = NamedSharding(mesh, P())
+    opt_shardings = jax.tree_util.tree_map_with_path(
+        lambda path, _: next(
+            (by_path[path[i:]] for i in range(len(path)) if path[i:] in by_path), replicated
+        ),
+        jax.eval_shape(init, np.uint32(0))[1],
     )
+    # the low 32 bits, as ``jax.random.key`` takes of a Python int
+    params, opt_state = jax.jit(init, out_shardings=(shardings, opt_shardings))(
+        np.uint32(seed & 0xFFFFFFFF)
+    )
+    return params, opt_state, tx, shardings
 
 
-def _jit_step_pinning_opt_shardings(step_fn, param_shardings, batch_shardings,
-                                    loss_sharding):
-    """jit a (params, opt_state, *batch) step with both donated carries pinned.
+HEAD_POSITIONS_FAMILY = "lakesoul_train_head_positions_total"
+
+# live steps, and what the collected ones had counted: the family is a
+# counter and must not fall when a step is dropped
+_live_steps: "weakref.WeakSet[_CountedStep]" = weakref.WeakSet()
+_retired = {"computed": 0, "all": 0}
+# re-entrant: a finalizer can run wherever this thread allocates, the collector included
+_retired_lock = threading.RLock()
+
+
+def _head_positions(state: dict) -> dict:
+    # one copy to the host, which waits for the last step dispatched
+    high, low = (int(v) for v in np.asarray(state["counted"]))
+    return {"computed": (high << 30) + low, "all": state["all"]}
+
+
+def _retire(state: dict) -> None:
+    counts = _head_positions(state)
+    with _retired_lock:
+        for kind, n in counts.items():
+            _retired[kind] += n
+
+
+def _collect_head_positions() -> list:
+    with _retired_lock:
+        totals = dict(_retired)
+    for step in list(_live_steps):
+        for kind, n in step.head_positions().items():
+            totals[kind] += n
+    return [(HEAD_POSITIONS_FAMILY, "counter", n, {"kind": kind}) for kind, n in totals.items()]
+
+
+class _CountedStep:
+    """A jitted ``(params, opt_state, *batch)`` step with both donated carries
+    pinned, that counts the positions its MLM head ran at.
 
     opt_state is donated, and donation requires the output buffer to alias the
     input one exactly — but its leaves' shardings only exist on the concrete
-    arrays ``tx.init`` built, not in any spec the factory could precompute.
+    arrays the caller holds (built here, or restored), not in any spec the
+    factory is given.
     Leaving the output unspecified lets GSPMD re-shard a replicated leaf (the
     observed "aliased input/output size" failure), so the shardings are
     captured from the first call's arrays and pinned identically on input and
@@ -62,38 +116,73 @@ def _jit_step_pinning_opt_shardings(step_fn, param_shardings, batch_shardings,
     0.9 a batch the loader delivered uncommitted (``sharding=None``) and one
     delivered on the pinned sharding are different abstract values, and the
     step would trace and compile once for each; placing is free when the
-    batch is already there."""
-    box: dict = {}
+    batch is already there.
 
-    def call(params, opt_state, *batch):
-        batch = jax.device_put(batch, batch_shardings)
-        fn = box.get("fn")
-        if fn is None:
+    ``step_fn`` returns ``(params, opt_state, loss, head_positions)``.  The
+    last depends on the labels, so the step adds it to a count that stays on
+    the device beside the carries (two int32 limbs of 30 bits) and is read
+    only when the registry is scraped: the step loop reads nothing from the
+    device for ``lakesoul_train_head_positions_total{kind="computed"}``.
+    ``kind="all"`` is every position of the batches, counted on the host."""
+
+    def __init__(self, step_fn, param_shardings, batch_shardings, loss_sharding):
+        self._step_fn = step_fn
+        self._param_shardings = param_shardings
+        self._batch_shardings = batch_shardings
+        self._replicated = loss_sharding
+        self._fn = None
+        self._state = {"counted": jax.device_put(np.zeros(2, np.int32), loss_sharding), "all": 0}
+        _live_steps.add(self)
+        # the finalizer holds the state, not the step; not at exit, when the
+        # device may be gone
+        weakref.finalize(self, _retire, self._state).atexit = False
+        registry().register_collector(_collect_head_positions)  # idempotent
+
+    def _jitted(self, opt_state):
+        if self._fn is None:
             opt_shardings = jax.tree.map(
                 lambda x: x.sharding if isinstance(x, jax.Array) else None,
                 opt_state,
             )
-            fn = box["fn"] = jax.jit(
-                step_fn,
-                in_shardings=(param_shardings, opt_shardings) + batch_shardings,
-                out_shardings=(param_shardings, opt_shardings, loss_sharding),
+            step_fn = self._step_fn
+
+            def train_step(params, opt_state, counted, *batch):
+                params, opt_state, loss, positions = step_fn(params, opt_state, *batch)
+                low = counted[1] + positions
+                counted = jnp.stack([counted[0] + (low >> 30), low & ((1 << 30) - 1)])
+                return params, opt_state, loss, counted
+
+            carries = (self._param_shardings, opt_shardings)
+            self._fn = jax.jit(
+                train_step,
+                in_shardings=carries + (self._replicated,) + self._batch_shardings,
+                out_shardings=carries + (self._replicated, self._replicated),
                 donate_argnums=(0, 1),
             )
-        return fn(params, opt_state, *batch)
+        return self._fn
 
-    return call
+    def __call__(self, params, opt_state, *batch):
+        batch = jax.device_put(batch, self._batch_shardings)
+        state = self._state
+        params, opt_state, loss, state["counted"] = self._jitted(opt_state)(
+            params, opt_state, state["counted"], *batch
+        )
+        state["all"] += batch[0].size
+        return params, opt_state, loss
+
+    def lower(self, params, opt_state, *batch):
+        """The step lowered for these arguments, as ``jax.jit(...).lower``."""
+        return self._jitted(opt_state).lower(params, opt_state, self._state["counted"], *batch)
+
+    def head_positions(self) -> dict:
+        """{"computed", "all"} over every step dispatched so far."""
+        return _head_positions(self._state)
 
 
 def make_bert_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0):
     """Initialize (params, opt_state) laid out on the mesh."""
     rules = param_sharding_rules(plan, n_experts=cfg.n_experts)
-    shardings = _specs_to_shardings(plan.mesh, rules)
-    init_fn = jax.jit(functools.partial(init_bert_params, cfg), out_shardings=shardings)
-    params = init_fn(jax.random.key(seed))
-    tx = optax.adamw(lr)
-    # moments mirror param sharding via zeros_like; scalars get replicated
-    opt_state = _place_opt_state(tx.init(params), plan.mesh)
-    return params, opt_state, tx, shardings
+    return _init_train_state(cfg, plan.mesh, _specs_to_shardings(plan.mesh, rules), lr, seed)
 
 
 def make_bert_train_step(
@@ -130,19 +219,26 @@ def make_bert_train_step(
         moe_ep_sharding=(
             NamedSharding(plan.mesh, P("ep", None, None)) if plan.ep > 1 else None
         ),
+        batch_sharding=batch_sharding, with_head_positions=True,
     )
-
-    def train_step(params, opt_state, input_ids, labels, mask):
-        loss, grads = jax.value_and_grad(loss_fn)(params, input_ids, labels, mask)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return _jit_step_pinning_opt_shardings(
-        train_step, param_shardings,
+    return _CountedStep(
+        _adamw_step(loss_fn, tx), param_shardings,
         (batch_sharding, batch_sharding, batch_sharding),
         NamedSharding(plan.mesh, P()),
     )
+
+
+def _adamw_step(loss_fn, tx):
+    """``loss_fn(params, *batch) → (loss, head positions)`` as one optimizer
+    step → (params, opt_state, loss, head positions)."""
+
+    def step(params, opt_state, *batch):
+        (loss, positions), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, *batch)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        params = optax.apply_updates(params, updates)
+        return params, opt_state, loss, positions
+
+    return step
 
 
 def make_bert_pipeline_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0):
@@ -163,11 +259,7 @@ def make_bert_pipeline_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float
             rules["layers"][leaf] = P("pp", *spec[1:])
     for ln in ("ln1", "ln2"):
         rules["layers"][ln] = {"scale": P("pp", None), "bias": P("pp", None)}
-    shardings = _specs_to_shardings(plan.mesh, rules)
-    init_fn = jax.jit(functools.partial(init_bert_params, cfg), out_shardings=shardings)
-    params = init_fn(jax.random.key(seed))
-    tx = optax.adamw(lr)
-    return params, _place_opt_state(tx.init(params), plan.mesh), tx, shardings
+    return _init_train_state(cfg, plan.mesh, _specs_to_shardings(plan.mesh, rules), lr, seed)
 
 
 def make_bert_pipeline_train_step(
@@ -178,7 +270,7 @@ def make_bert_pipeline_train_step(
     (parallel/pipeline.py) and autodiff through scan+ppermute is the reverse
     pipeline.  Batch arrives sharded P('dp') and is split into n_micro
     microbatches inside the step."""
-    from lakesoul_tpu.models.bert import bert_embed, bert_head, bert_layer, masked_nll
+    from lakesoul_tpu.models.bert import bert_embed, bert_layer, mlm_head_loss
     from lakesoul_tpu.parallel.pipeline import (
         make_pipeline,
         merge_microbatches,
@@ -209,16 +301,10 @@ def make_bert_pipeline_train_step(
         stages = split_stages(params["layers"], pp)
         out = pipeline(stages, micro)
         x = merge_microbatches(out, B)["x"]
-        return masked_nll(bert_head(params, x), labels)
+        return mlm_head_loss(params, x, labels, batch_sharding=batch_sharding)
 
-    def train_step(params, opt_state, input_ids, labels, mask):
-        loss, grads = jax.value_and_grad(loss_fn)(params, input_ids, labels, mask)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return _jit_step_pinning_opt_shardings(
-        train_step, param_shardings,
+    return _CountedStep(
+        _adamw_step(loss_fn, tx), param_shardings,
         (batch_sharding, batch_sharding, batch_sharding),
         NamedSharding(plan.mesh, P()),
     )
