@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from lakesoul_tpu.parallel.mesh import spec_axes
+
 
 @dataclass(frozen=True)
 class BertConfig:
@@ -269,17 +271,17 @@ def head_tile(n: int) -> int:
     return min(n, -(-n // 96) * 8)
 
 
-def _tile_nll(head, x, labels, scale):
+def _tile_nll(head_fn, head, x, labels, scale):
     """``scale`` x the summed NLL of one tile's labelled rows, in float32."""
-    logp = jax.nn.log_softmax(bert_head(head, x), axis=-1)
+    logp = jax.nn.log_softmax(head_fn(head, x), axis=-1)
     picked = jnp.take_along_axis(logp, jnp.maximum(labels, 0)[:, None], axis=-1)[:, 0]
     return -scale * jnp.sum(jnp.where(labels >= 0, picked, 0.0))
 
 
-def _head_over_labelled(head, x, labels, axes, with_grads: bool):
+def _head_over_labelled(head_fn, head, x, labels, axes, with_grads: bool):
     """One shard's share of the loss: ``x`` [..., h] and ``labels`` [...] are
     the rows this device holds, ``axes`` the mesh axes the batch is split
-    over.  → (loss, positions the head ran at), both summed over ``axes``,
+    over, ``head_fn(head, x)`` the float32 logits of rows ``x``.  → (loss, positions the head ran at), both summed over ``axes``,
     and with ``with_grads`` the loss's gradients (head summed over ``axes``,
     x for this shard's rows)."""
     x2, lab = x.reshape(-1, x.shape[-1]), labels.reshape(-1)
@@ -300,14 +302,16 @@ def _head_over_labelled(head, x, labels, axes, with_grads: bool):
         xt = x2[jax.lax.dynamic_slice(rows, (at,), (tile,))]
         lt = jax.lax.dynamic_slice(row_labels, (at,), (tile,))
         if with_grads:
-            part, (g_head, g_x) = jax.value_and_grad(_tile_nll, argnums=(0, 1))(head, xt, lt, scale)
+            part, (g_head, g_x) = jax.value_and_grad(
+                functools.partial(_tile_nll, head_fn), argnums=(0, 1)
+            )(head, xt, lt, scale)
             acc_head, acc_x = grads
             grads = (
                 jax.tree.map(jnp.add, acc_head, g_head),
                 jax.lax.dynamic_update_slice(acc_x, g_x, (at, 0)),
             )
         else:
-            part = _tile_nll(head, xt, lt, scale)
+            part = _tile_nll(head_fn, head, xt, lt, scale)
         return k + 1, loss + part, grads
 
     grads = (
@@ -331,40 +335,42 @@ def _head_over_labelled(head, x, labels, axes, with_grads: bool):
     return loss, positions, g_head, g_x
 
 
-def _sharded_head(head, x, labels, batch_sharding, with_grads: bool):
+def _sharded_head(head_fn, head, x, labels, batch_sharding, with_grads: bool):
     if batch_sharding is None:
-        return _head_over_labelled(head, x, labels, (), with_grads)
+        return _head_over_labelled(head_fn, head, x, labels, (), with_grads)
     spec = batch_sharding.spec
-    axes = tuple(
-        a for part in spec if part is not None
-        for a in (part if isinstance(part, tuple) else (part,))
-    )
+    axes = spec_axes(spec)
     out_specs = (P(), P(), P(), spec) if with_grads else (P(), P())
     # every device gathers among its own rows; only sums cross the mesh
     return jax.shard_map(
-        lambda head, x, labels: _head_over_labelled(head, x, labels, axes, with_grads),
+        lambda head, x, labels: _head_over_labelled(head_fn, head, x, labels, axes, with_grads),
         mesh=batch_sharding.mesh, in_specs=(P(), spec, spec), out_specs=out_specs,
         check_vma=False,
     )(head, x, labels)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _labelled_nll(head, x, labels, batch_sharding):
-    return _sharded_head(head, x, labels, batch_sharding, False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 4))
+def labelled_nll(head_fn, head, x, labels, batch_sharding=None):
+    """Hidden states ``x`` [..., h] and ``labels`` [...] → (mean NLL over the
+    positions with labels >= 0, positions the head ran at), the logits of rows
+    being ``head_fn(head, rows)`` in float32.  The one tile loop of every
+    head-and-loss here: masked-LM runs it over the 15% it labels, a causal LM
+    over every position."""
+    return _sharded_head(head_fn, head, x, labels, batch_sharding, False)
 
 
-def _labelled_nll_fwd(head, x, labels, batch_sharding):
-    loss, positions, g_head, g_x = _sharded_head(head, x, labels, batch_sharding, True)
+def _labelled_nll_fwd(head_fn, head, x, labels, batch_sharding):
+    loss, positions, g_head, g_x = _sharded_head(head_fn, head, x, labels, batch_sharding, True)
     return (loss, positions), (g_head, g_x)
 
 
-def _labelled_nll_bwd(batch_sharding, grads, cotangents):
+def _labelled_nll_bwd(head_fn, batch_sharding, grads, cotangents):
     g_head, g_x = grads
     ct = cotangents[0]
     return jax.tree.map(lambda g: ct * g, g_head), ct.astype(g_x.dtype) * g_x, None
 
 
-_labelled_nll.defvjp(_labelled_nll_fwd, _labelled_nll_bwd)
+labelled_nll.defvjp(_labelled_nll_fwd, _labelled_nll_bwd)
 
 
 def mlm_head_loss(params, x, labels, *, batch_sharding=None):
@@ -378,7 +384,7 @@ def mlm_head_loss(params, x, labels, *, batch_sharding=None):
     the ``NamedSharding`` of ``labels`` inside a sharded step, so that no
     hidden state leaves its device."""
     head = {k: params[k] for k in ("mlm_ln", "tok_emb", "mlm_bias")}
-    return _labelled_nll(head, x, labels, batch_sharding)
+    return labelled_nll(bert_head, head, x, labels, batch_sharding)
 
 
 def bert_mlm_loss(

@@ -1,0 +1,473 @@
+"""Hybrid causal LM of the Qwen3-Next family: Gated DeltaNet layers and gated
+softmax-attention layers in one stack, a dropless mixture of experts after
+every mixer, written functionally like ``models/bert.py``.
+
+Layer ``i`` is gated attention where ``(i + 1) % full_attention_interval == 0``
+and Gated DeltaNet otherwise; nothing here branches on a model's name.  A layer
+is ``x = x + mixer(norm(x)); x = x + moe(norm(x))`` with zero-centred RMS norms
+in float32, then a final norm and an untied head.  Matrix products run in
+``cfg.dtype`` (bfloat16) with float32 accumulation; norms, the router's
+softmax, the DeltaNet decay's running sum and its state are float32.
+
+The model may be one chip's share of an expert-parallel job: ``experts_held``
+says which of the ``num_experts`` live here (``parallel/moe.py: held_experts``)
+and ``vocab_size`` is the slice of the vocabulary the embedding, the head and
+the loss are over.  Parallelism: dp over rows; everything else is replicated.
+
+Departures from the published model: no multi-token-prediction module, no
+router auxiliary loss, no document boundaries (a row is one packed sequence).
+Within ``in_proj_qkvz`` the columns are ``[q | k | v | z]`` by kind, heads in
+order inside each, not interleaved per key head as the published checkpoint
+stores them; with weights from a seed the two are the same model.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from lakesoul_tpu.models.bert import labelled_nll
+from lakesoul_tpu.parallel.moe import ROUTE_SCOPE, held_experts, route_top_k, shared_expert
+from lakesoul_tpu.parallel.ring_attention import block_attn
+
+GDN_SCOPE = "lakesoul.lm.gdn"
+ATTN_SCOPE = "lakesoul.lm.attn"
+HEAD_SCOPE = "lakesoul.lm.head"
+GDN_CHUNK = 128    # tokens a DeltaNet chunk holds: a v5e matrix unit is 128 wide (the family's public kernels use 64)
+ATTN_BAND = 1024   # queries that share one static slice of the keys
+ATTN_ROWS = 128    # queries whose scores live at once
+
+
+@dataclass(frozen=True)
+class Qwen3NextConfig:
+    """The published ``config.json`` keys the layers read, under their
+    published names, and what this chip holds of the model."""
+
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    full_attention_interval: int = 4
+    # gated attention
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    # Gated DeltaNet
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    # experts
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    rms_norm_eps: float = 1e-6
+    # this chip's share: (first expert, how many) of ``num_experts``
+    experts_held: tuple[int, int] = (0, 512)
+    dtype: str = "bfloat16"
+
+    @staticmethod
+    def from_published(model: dict, **share) -> "Qwen3NextConfig":
+        """From a dict with the published keys (others are ignored)."""
+        names = Qwen3NextConfig.__dataclass_fields__
+        return Qwen3NextConfig(**{k: v for k, v in model.items() if k in names}, **share)
+
+    def layer_kinds(self) -> tuple[str, ...]:
+        return tuple(
+            "attn" if (i + 1) % self.full_attention_interval == 0 else "gdn"
+            for i in range(self.num_hidden_layers)
+        )
+
+    @property
+    def key_dim(self) -> int:
+        return self.linear_num_key_heads * self.linear_key_head_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.linear_num_value_heads * self.linear_value_head_dim
+
+
+def init_lm_params(cfg: Qwen3NextConfig, key: jax.Array) -> dict:
+    """Weights from a key: matrices normal(0, 0.02); ``A_log = log U(0, 16)``,
+    ``dt_bias = 1``; zero-centred norm weights 0, the DeltaNet output norm 1."""
+    h, f = cfg.hidden_size, cfg.moe_intermediate_size
+    count = cfg.experts_held[1]
+
+    def normal(key, *shape):
+        return (jax.random.normal(key, shape) * 0.02).astype(jnp.float32)
+
+    def layer(key, kind):
+        ks = jax.random.split(key, 12)
+        if kind == "gdn":
+            hv = cfg.linear_num_value_heads
+            mixer = {
+                "w_qkvz": normal(ks[0], h, 2 * cfg.key_dim + 2 * cfg.value_dim),
+                "w_ba": normal(ks[1], h, 2 * hv),
+                "conv": normal(ks[2], 2 * cfg.key_dim + cfg.value_dim, cfg.linear_conv_kernel_dim),
+                "A_log": jnp.log(jax.random.uniform(ks[3], (hv,), minval=0.0, maxval=16.0)),
+                "dt_bias": jnp.ones((hv,)),
+                "norm": jnp.ones((cfg.linear_value_head_dim,)),
+                "w_o": normal(ks[4], cfg.value_dim, h),
+            }
+        else:
+            heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+            mixer = {
+                "w_q": normal(ks[0], h, heads * 2 * d),  # per head: query, then gate
+                "w_k": normal(ks[1], h, kv * d),
+                "w_v": normal(ks[2], h, kv * d),
+                "w_o": normal(ks[3], heads * d, h),
+                "q_norm": jnp.zeros((d,)),
+                "k_norm": jnp.zeros((d,)),
+            }
+        fs = cfg.shared_expert_intermediate_size
+        moe = {
+            "router": normal(ks[5], h, cfg.num_experts),
+            "w_gate": normal(ks[6], count, h, f),
+            "w_up": normal(ks[7], count, h, f),
+            "w_down": normal(ks[8], count, f, h),
+            "shared": {
+                "w_gate": normal(ks[9], h, fs),
+                "w_up": normal(ks[10], h, fs),
+                "w_down": normal(ks[11], fs, h),
+                "gate": normal(jax.random.fold_in(key, 12), h),
+            },
+        }
+        return {"norm1": jnp.zeros((h,)), kind: mixer, "norm2": jnp.zeros((h,)), "moe": moe}
+
+    k_emb, k_head, k_layers = jax.random.split(key, 3)
+    kinds = cfg.layer_kinds()
+    return {
+        "embed": normal(k_emb, cfg.vocab_size, h),
+        "layers": [layer(k, kind) for k, kind in zip(jax.random.split(k_layers, len(kinds)), kinds)],
+        "final_norm": jnp.zeros((h,)),
+        "head": normal(k_head, h, cfg.vocab_size),
+    }
+
+
+def _rms_norm(x, w, eps, *, centred: bool = True):
+    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` in float32 (``* w`` where the
+    weight is not zero-centred); float32 out."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return y * ((1.0 + w) if centred else w)
+
+
+# -------------------------------------------------------- Gated DeltaNet
+
+
+def _causal_conv_silu(x, w):
+    """Depthwise causal convolution, no bias, then SiLU: x [B, T, C], w [C, K];
+    tap ``K-1`` sits on the current token."""
+    taps = w.shape[1]
+    t = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = sum(xp[:, j:j + t].astype(jnp.float32) * w[:, j] for j in range(taps)).astype(x.dtype)
+    return jax.nn.silu(y.astype(jnp.float32)).astype(x.dtype)
+
+
+def _mm_high(a, b):
+    # three bfloat16 passes: float32 products to about 2**-16
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGH)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def unit_lower_inverse(a, dtype=jnp.float32):
+    """``(I + A)^-1`` in float32 for ``A`` [..., C, C] strictly lower
+    triangular, ``C`` a power of two, by block inversion from the diagonal
+    outwards: with the inverses ``X1, X2`` of two neighbouring diagonal blocks
+    known, the block ``[[D1, 0], [L, D2]]`` has the inverse ``[[X1, 0],
+    [-X2 L X1, X2]]``.  Each doubling is two products over the whole matrix
+    (``X - X L X`` with ``L`` masked to the lower-left blocks), which is what a
+    matrix unit is for; their factors are rounded to ``dtype`` (one bfloat16
+    pass each on a TPU), and one Newton step ``X + X (I - M X)`` at three passes
+    squares the error that leaves.  (The compiler's own triangular solve took
+    21 ms a call here on a v5e, and the doublings at three passes each 9 ms:
+    PERF.md section 6, PR 28.)"""
+    c = a.shape[-1]
+    if c & (c - 1):
+        raise ValueError(f"chunk {c} is not a power of two")
+
+    def mm(x, y):
+        return jnp.matmul(x.astype(dtype), y.astype(dtype), preferred_element_type=jnp.float32)
+
+    at = jnp.arange(c)
+    eye = jnp.eye(c, dtype=jnp.float32)
+    x = jnp.broadcast_to(eye, a.shape)
+    s = 1
+    while s < c:
+        lower_left = (
+            (at[:, None] // (2 * s) == at[None, :] // (2 * s))
+            & ((at[:, None] // s) % 2 == 1) & ((at[None, :] // s) % 2 == 0)
+        )
+        x = x - mm(mm(x, jnp.where(lower_left, a, 0.0)), x)
+        s *= 2
+    return x + _mm_high(x, eye - _mm_high(eye + a, x))
+
+
+def _unit_lower_inverse_fwd(a, dtype):
+    x = unit_lower_inverse(a, dtype)
+    return x, x
+
+
+def _unit_lower_inverse_bwd(dtype, x, g):
+    # d(M^-1) = -M^-1 dM M^-1, and only the strictly lower part of M moves
+    xt = jnp.swapaxes(x, -1, -2)
+    return (-jnp.tril(_mm_high(_mm_high(xt, g), xt), -1),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
+    """The gated delta rule, a chunk of tokens at a time.
+
+    q, k [B, T, H, dk] (normalised and scaled), v [B, T, H, dv], g [B, T, H]
+    float32 log decay, beta [B, T, H] float32 → o [B, T, H, dv], equal to the
+    recurrence ``S' = exp(g_t) S; u = beta_t (v_t - S'^T k_t); S = S' + k_t u^T;
+    o_t = S^T q_t`` from ``S_0 = 0``.  Inside a chunk the tokens' updates are
+    solved together (a unit lower-triangular system, inverted for every chunk at
+    once); across chunks the float32 state is carried by a scan whose backward
+    pass keeps one state a chunk and computes the rest of the chunk again."""
+    chunk = chunk or GDN_CHUNK
+    lo = q.dtype
+    f32 = jnp.float32
+    b, t, h, dk = q.shape
+    pad = -t % chunk
+    if pad:  # a token with k = 0 and beta = 0 leaves the state as it is
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
+    n = (t + pad) // chunk
+    # chunks first, for the scan: [n, B, chunk, H, ...]
+    q, k, v, g, beta = (
+        jnp.moveaxis(a.reshape(b, n, chunk, *a.shape[2:]), 1, 0) for a in (q, k, v, g, beta)
+    )
+    gc = jnp.cumsum(g.astype(f32), axis=2)  # the decay's running sum inside the chunk
+    beta = beta.astype(f32)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def decay_of(gc):
+        """[.., chunk, H] → [.., H, chunk, chunk]: exp(gc_i - gc_j) for i >= j, else 0.
+        Masked before exp: above the diagonal the difference is positive and may overflow."""
+        gh = jnp.swapaxes(gc, -1, -2)
+        return jnp.exp(jnp.where(lower, gh[..., :, None] - gh[..., None, :], -jnp.inf))
+
+    kb = (k.astype(f32) * beta[..., None]).astype(lo)
+    kk = jnp.einsum("nbihd,nbjhd->nbhij", kb, k, preferred_element_type=f32)
+    # (I + A)^-1, A the strictly lower part: every token's update given the ones before it
+    inv = unit_lower_inverse(jnp.tril(kk * decay_of(gc), -1), lo).astype(lo)
+
+    @jax.checkpoint  # the backward pass keeps the carried state of each chunk and nothing else
+    def step(state, xs):
+        q_i, k_i, v_i, gc_i, beta_i, inv_i = xs
+        s_lo = state.astype(lo)
+        grow = jnp.exp(gc_i)[..., None]
+        g_last = gc_i[:, -1]  # [B, H]
+        vb = (v_i.astype(f32) * beta_i[..., None]).astype(lo)
+        kbg = (k_i.astype(f32) * (beta_i[..., None] * grow)).astype(lo)
+        u = jnp.einsum("bhij,bjhd->bihd", inv_i, vb, preferred_element_type=f32)
+        w = jnp.einsum("bhij,bjhd->bihd", inv_i, kbg)
+        v_new = (u - jnp.einsum("bihk,bhkv->bihv", w, s_lo, preferred_element_type=f32)).astype(lo)
+        local = jnp.einsum("bihd,bjhd->bhij", q_i, k_i, preferred_element_type=f32) * decay_of(gc_i)
+        q_in = (q_i.astype(f32) * grow).astype(lo)
+        o_i = (jnp.einsum("bihk,bhkv->bihv", q_in, s_lo, preferred_element_type=f32)
+               + jnp.einsum("bhij,bjhv->bihv", local.astype(lo), v_new, preferred_element_type=f32))
+        k_out = (k_i.astype(f32) * jnp.exp(g_last[:, None] - gc_i)[..., None]).astype(lo)
+        state = state * jnp.exp(g_last)[..., None, None] + jnp.einsum(
+            "bihk,bihv->bhkv", k_out, v_new, preferred_element_type=f32
+        )
+        return state, o_i.astype(lo)
+
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), f32), (q, k, v, gc, beta, inv))
+    return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t]
+
+
+def gated_delta_net(x, p, *, cfg: Qwen3NextConfig, chunk: int | None = None):
+    """The Gated DeltaNet mixer: x [B, T, h] (normed) → [B, T, h]."""
+    dtype = x.dtype
+    f32 = jnp.float32
+    b, t, _ = x.shape
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    qkvz = x @ p["w_qkvz"].astype(dtype)
+    ba = jnp.dot(x, p["w_ba"].astype(dtype), preferred_element_type=f32)
+    conv_dim = 2 * cfg.key_dim + cfg.value_dim
+    qkv = _causal_conv_silu(qkvz[..., :conv_dim], p["conv"])
+    z = qkvz[..., conv_dim:].reshape(b, t, hv, dv)
+    q = qkv[..., : cfg.key_dim].reshape(b, t, hk, dk).astype(f32)
+    k = qkv[..., cfg.key_dim: 2 * cfg.key_dim].reshape(b, t, hk, dk).astype(f32)
+    v = qkv[..., 2 * cfg.key_dim:].reshape(b, t, hv, dv)
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk**-0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+    # each key head serves hv // hk value heads
+    q, k = (jnp.repeat(a.astype(dtype), hv // hk, axis=2) for a in (q, k))
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
+    o = chunk_gated_delta_rule(q, k, v, g, beta, chunk=chunk)  # [B, T, hv, dv]
+    o = _rms_norm(o, p["norm"], cfg.rms_norm_eps, centred=False) * jax.nn.silu(z.astype(f32))
+    return o.reshape(b, t, hv * dv).astype(dtype) @ p["w_o"].astype(dtype)
+
+
+# ------------------------------------------------------- gated attention
+
+
+def _rotary(x, positions, rotary_dim: int, theta: float):
+    """Rotate the first ``rotary_dim`` channels of x [B, T, H, D] (float32):
+    halves ``[x1 | x2]`` → ``[x1 cos - x2 sin | x2 cos + x1 sin]``."""
+    half = rotary_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq  # [T, half]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
+def causal_attention(q, k, v, *, band: int | None = None, rows: int | None = None):
+    """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
+    (scaled), k, v [B, Hkv, T, D] → [B, Hkv, G, T, D].
+
+    The queries go a band at a time against the keys up to the band's last
+    position (a static slice, so the keys after it cost nothing), and inside a
+    band ``rows`` queries at a time, each block rematerialised: no more than
+    ``rows`` rows of scores live at once, in either pass."""
+    band, rows = band or ATTN_BAND, rows or ATTN_ROWS
+    b, hkv, groups, t, d = q.shape
+
+    def block(q_blk, k_seen, v_seen, first):
+        """q_blk [B, Hkv, G, n, D] at positions first.. against the keys seen."""
+        n = q_blk.shape[3]
+        pos = jnp.tile(first + jnp.arange(n), groups)
+        mask = pos[:, None] >= jnp.arange(k_seen.shape[2])[None, :]
+        _, l, o = block_attn(q_blk.reshape(b, hkv, groups * n, d), k_seen, v_seen, 1.0, mask)
+        return (o / l[..., None]).astype(v.dtype).reshape(b, hkv, groups, n, d)
+
+    out = []
+    for start in range(0, t, band):
+        end = min(start + band, t)
+        q_band, k_seen, v_seen = q[:, :, :, start:end], k[:, :, :end], v[:, :, :end]
+        if (end - start) % rows or end - start == rows:
+            out.append(jax.checkpoint(block)(q_band, k_seen, v_seen, start))
+            continue
+        blocks = (end - start) // rows
+        q_rows = jnp.moveaxis(q_band.reshape(b, hkv, groups, blocks, rows, d), 3, 0)
+        firsts = start + rows * jnp.arange(blocks)
+        o = jax.lax.map(
+            lambda xs: jax.checkpoint(block)(xs[0], k_seen, v_seen, xs[1]), (q_rows, firsts)
+        )
+        out.append(jnp.moveaxis(o, 0, 3).reshape(b, hkv, groups, end - start, d))
+    return jnp.concatenate(out, axis=3)
+
+
+def gated_attention(x, p, *, cfg: Qwen3NextConfig):
+    """The gated softmax-attention mixer: x [B, T, h] (normed) → [B, T, h]."""
+    dtype = x.dtype
+    f32 = jnp.float32
+    b, t, _ = x.shape
+    heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    qg = (x @ p["w_q"].astype(dtype)).reshape(b, t, heads, 2 * d)
+    q, gate = qg[..., :d], qg[..., d:]
+    k = (x @ p["w_k"].astype(dtype)).reshape(b, t, kv, d)
+    v = (x @ p["w_v"].astype(dtype)).reshape(b, t, kv, d)
+    rotary_dim = int(d * cfg.partial_rotary_factor)
+    positions = jnp.arange(t)
+    q = _rotary(_rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, rotary_dim, cfg.rope_theta)
+    k = _rotary(_rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, rotary_dim, cfg.rope_theta)
+    q = (q * d**-0.5).astype(dtype)
+    # [B, T, heads, D] → [B, kv, heads // kv, T, D]: each key-value head serves a group
+    q = q.reshape(b, t, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)
+    k, v = (a.transpose(0, 2, 1, 3) for a in (k.astype(dtype), v))
+    o = causal_attention(q, k, v)
+    o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
+    o = (o.astype(f32) * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
+    return o.reshape(b, t, heads * d) @ p["w_o"].astype(dtype)
+
+
+# ----------------------------------------------------------------- model
+
+
+def _row_by_row(mixer, x, p, batch_sharding):
+    """``mixer(x, p)`` one row of ``x`` [B, T, h] at a time, each row
+    rematerialised: a mixer's intermediates at 8k tokens are gigabytes a row
+    and no row needs another's.  On a mesh every device takes its own rows."""
+
+    def local(x, p):
+        return jax.lax.map(jax.checkpoint(lambda row: mixer(row[None], p)[0]), x)
+
+    if batch_sharding is None:
+        return local(x, p)
+    spec = batch_sharding.spec
+    return jax.shard_map(
+        local, mesh=batch_sharding.mesh, in_specs=(spec, P()), out_specs=spec, check_vma=False
+    )(x, p)
+
+
+def lm_layer(x, lp, *, kind: str, cfg: Qwen3NextConfig, batch_sharding=None):
+    """One layer: x [B, T, h] → (x, the expert layer's counts).  The mixer is
+    rematerialised a row at a time, and norm, routing and the shared expert
+    together; the held experts' tile loop is not (its backward pass needs its
+    inputs alone).  What the backward pass keeps of a layer: its input, the
+    mixer's output, the experts' normed input and the routing."""
+    dtype = x.dtype
+    mixer, scope = (gated_delta_net, GDN_SCOPE) if kind == "gdn" else (gated_attention, ATTN_SCOPE)
+
+    def mix(x, p):
+        y = _rms_norm(x, p["norm"], cfg.rms_norm_eps).astype(dtype)
+        return x + mixer(y, p["mixer"], cfg=cfg)
+
+    @jax.checkpoint
+    def routed(x, norm, router, shared):
+        """Norm, routing and the shared expert: cheap to compute again."""
+        with jax.named_scope(ROUTE_SCOPE):
+            y32 = _rms_norm(x, norm, cfg.rms_norm_eps)
+        top_e, w = route_top_k(y32, router, top_k=cfg.num_experts_per_tok)
+        y = y32.astype(dtype)
+        return y, top_e, w, shared_expert(y, shared)
+
+    with jax.named_scope(scope):
+        x = _row_by_row(mix, x, {"norm": lp["norm1"], "mixer": lp[kind]}, batch_sharding)
+    p = lp["moe"]
+    y, top_e, w, shared = routed(x, lp["norm2"], p["router"], p["shared"])
+    out, counts = held_experts(
+        y, top_e, w, p, n_experts=cfg.num_experts, held=cfg.experts_held, batch_sharding=batch_sharding
+    )
+    return x + out + shared, counts
+
+
+def lm_hidden(params, ids, *, cfg: Qwen3NextConfig, batch_sharding=None):
+    """ids [B, T] → (final hidden states [B, T, h] before the final norm,
+    counts summed over the layers)."""
+    x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
+    totals = None
+    for lp, kind in zip(params["layers"], cfg.layer_kinds()):
+        x, counts = lm_layer(x, lp, kind=kind, cfg=cfg, batch_sharding=batch_sharding)
+        totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
+    return x, totals
+
+
+def lm_head(head, x, *, cfg: Qwen3NextConfig):
+    """Logits over the held vocabulary, float32: x [..., h] → [..., vocab]."""
+    dtype = jnp.dtype(cfg.dtype)
+    with jax.named_scope(HEAD_SCOPE):
+        y = _rms_norm(x, head["final_norm"], cfg.rms_norm_eps).astype(dtype)
+        return jnp.dot(y, head["head"].astype(dtype), preferred_element_type=jnp.float32)
+
+
+def lm_logits(params, ids, *, cfg: Qwen3NextConfig):
+    x, _ = lm_hidden(params, ids, cfg=cfg)
+    return lm_head({k: params[k] for k in ("final_norm", "head")}, x, cfg=cfg)
+
+
+def lm_loss(params, ids, labels, *, cfg: Qwen3NextConfig, batch_sharding=None):
+    """Next-token cross-entropy, float32, mean over the positions with
+    ``labels >= 0`` (-100 elsewhere) → (loss, counts).  ``counts``: the expert
+    layers' (summed over layers) and ``tokens``, int32."""
+    x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
+    head = {k: params[k] for k in ("final_norm", "head")}
+    loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head, x, labels, batch_sharding)
+    return loss, dict(counts, tokens=jnp.int32(ids.size))

@@ -37,19 +37,20 @@ def _specs_to_shardings(mesh, rules):
     )
 
 
-def _init_train_state(cfg: BertConfig, mesh, shardings, lr: float, seed: int):
-    """(params, opt_state, tx, shardings) for AdamW at ``lr``: params and
-    optimizer state come from the seed as ONE jitted program that leaves
-    every leaf on the mesh: an optimizer leaf that mirrors a parameter (adam's
-    moments; its tree path ends in the parameter's) takes that parameter's
-    sharding, any other (adam's ``count``) is replicated.  Built eagerly,
-    ``jax.random.key``, ``tx.init``'s zeros and the placement of its scalars
-    were a dozen small programs, each lowered and looked up in the compile
-    cache on every start."""
+def _init_train_state(init_params, mesh, shardings, lr: float, seed: int):
+    """(params, opt_state, tx, shardings) for AdamW at ``lr``, the weights
+    being ``init_params(key)``: params and optimizer state come from the seed
+    as ONE jitted program that leaves every leaf on the mesh: an optimizer
+    leaf that mirrors a parameter (adam's moments; its tree path ends in the
+    parameter's) takes that parameter's sharding, any other (adam's
+    ``count``) is replicated.  Built eagerly, ``jax.random.key``,
+    ``tx.init``'s zeros and the placement of its scalars were a dozen small
+    programs, each lowered and looked up in the compile cache on every
+    start."""
     tx = optax.adamw(lr)
 
     def init(seed):
-        params = init_bert_params(cfg, jax.random.key(seed))
+        params = init_params(jax.random.key(seed))
         return params, tx.init(params)
 
     by_path = dict(jax.tree_util.tree_leaves_with_path(shardings))
@@ -68,40 +69,51 @@ def _init_train_state(cfg: BertConfig, mesh, shardings, lr: float, seed: int):
 
 
 HEAD_POSITIONS_FAMILY = "lakesoul_train_head_positions_total"
+TOKENS_FAMILY = "lakesoul_train_tokens_total"
+MOE_ASSIGNMENTS_FAMILY = "lakesoul_train_moe_assignments_total"
+MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
 
-# live steps, and what the collected ones had counted: the family is a
-# counter and must not fall when a step is dropped
+# live steps, and what the collected ones had counted: the families are
+# counters and must not fall when a step is dropped
 _live_steps: "weakref.WeakSet[_CountedStep]" = weakref.WeakSet()
-_retired = {"computed": 0, "all": 0}
+_retired: dict[tuple, float] = {}  # (family, labels as sorted items) -> value
 # re-entrant: a finalizer can run wherever this thread allocates, the collector included
 _retired_lock = threading.RLock()
 
 
-def _head_positions(state: dict) -> dict:
+def _counts(state: dict) -> dict:
     # one copy to the host, which waits for the last step dispatched
-    high, low = (int(v) for v in np.asarray(state["counted"]))
-    return {"computed": (high << 30) + low, "all": state["all"]}
+    limbs = np.asarray(state["counted"])
+    return {key: (int(high) << 30) + int(low) for key, (high, low) in zip(state["keys"], limbs)}
+
+
+def _series_values(state: dict) -> dict[tuple, float]:
+    counts = _counts(state)
+    return {
+        (family, tuple(sorted(labels.items()))): counts[key] * scale
+        for key, family, labels, scale in state["series"]
+    }
 
 
 def _retire(state: dict) -> None:
-    counts = _head_positions(state)
+    values = _series_values(state)
     with _retired_lock:
-        for kind, n in counts.items():
-            _retired[kind] += n
+        for series, n in values.items():
+            _retired[series] = _retired.get(series, 0) + n
 
 
-def _collect_head_positions() -> list:
+def _collect_step_counts() -> list:
     with _retired_lock:
         totals = dict(_retired)
     for step in list(_live_steps):
-        for kind, n in step.head_positions().items():
-            totals[kind] += n
-    return [(HEAD_POSITIONS_FAMILY, "counter", n, {"kind": kind}) for kind, n in totals.items()]
+        for series, n in _series_values(step._state).items():
+            totals[series] = totals.get(series, 0) + n
+    return [(family, "counter", n, dict(labels)) for (family, labels), n in totals.items()]
 
 
 class _CountedStep:
     """A jitted ``(params, opt_state, *batch)`` step with both donated carries
-    pinned, that counts the positions its MLM head ran at.
+    pinned, that counts what its loss says it did.
 
     opt_state is donated, and donation requires the output buffer to alias the
     input one exactly — but its leaves' shardings only exist on the concrete
@@ -118,25 +130,31 @@ class _CountedStep:
     step would trace and compile once for each; placing is free when the
     batch is already there.
 
-    ``step_fn`` returns ``(params, opt_state, loss, head_positions)``.  The
-    last depends on the labels, so the step adds it to a count that stays on
-    the device beside the carries (two int32 limbs of 30 bits) and is read
-    only when the registry is scraped: the step loop reads nothing from the
-    device for ``lakesoul_train_head_positions_total{kind="computed"}``.
-    ``kind="all"`` is every position of the batches, counted on the host."""
+    ``step_fn`` returns ``(params, opt_state, loss, counts)``, ``counts`` a
+    small dict of int32 scalars below 2**30 (positions the head ran at,
+    assignments that landed on held experts, ...).  They depend on the data,
+    so the step adds each to a count that stays on the device beside the
+    carries (two int32 limbs of 30 bits) and is read only when the registry is
+    scraped: the step loop reads nothing from the device for them.  ``series``
+    says which registry series a count feeds: ``(count key, family, labels,
+    scale)``, the series' value being the count times ``scale``."""
 
-    def __init__(self, step_fn, param_shardings, batch_shardings, loss_sharding):
+    def __init__(self, step_fn, param_shardings, batch_shardings, loss_sharding, series):
         self._step_fn = step_fn
         self._param_shardings = param_shardings
         self._batch_shardings = batch_shardings
         self._replicated = loss_sharding
         self._fn = None
-        self._state = {"counted": jax.device_put(np.zeros(2, np.int32), loss_sharding), "all": 0}
+        keys = tuple(sorted({key for key, *_ in series}))
+        self._state = {
+            "counted": jax.device_put(np.zeros((len(keys), 2), np.int32), loss_sharding),
+            "keys": keys, "series": tuple(series),
+        }
         _live_steps.add(self)
         # the finalizer holds the state, not the step; not at exit, when the
         # device may be gone
         weakref.finalize(self, _retire, self._state).atexit = False
-        registry().register_collector(_collect_head_positions)  # idempotent
+        registry().register_collector(_collect_step_counts)  # idempotent
 
     def _jitted(self, opt_state):
         if self._fn is None:
@@ -146,10 +164,12 @@ class _CountedStep:
             )
             step_fn = self._step_fn
 
+            keys = self._state["keys"]
+
             def train_step(params, opt_state, counted, *batch):
-                params, opt_state, loss, positions = step_fn(params, opt_state, *batch)
-                low = counted[1] + positions
-                counted = jnp.stack([counted[0] + (low >> 30), low & ((1 << 30) - 1)])
+                params, opt_state, loss, counts = step_fn(params, opt_state, *batch)
+                low = counted[:, 1] + jnp.stack([counts[key] for key in keys]).astype(jnp.int32)
+                counted = jnp.stack([counted[:, 0] + (low >> 30), low & ((1 << 30) - 1)], axis=1)
                 return params, opt_state, loss, counted
 
             carries = (self._param_shardings, opt_shardings)
@@ -167,22 +187,41 @@ class _CountedStep:
         params, opt_state, loss, state["counted"] = self._jitted(opt_state)(
             params, opt_state, state["counted"], *batch
         )
-        state["all"] += batch[0].size
         return params, opt_state, loss
 
     def lower(self, params, opt_state, *batch):
         """The step lowered for these arguments, as ``jax.jit(...).lower``."""
         return self._jitted(opt_state).lower(params, opt_state, self._state["counted"], *batch)
 
-    def head_positions(self) -> dict:
-        """{"computed", "all"} over every step dispatched so far."""
-        return _head_positions(self._state)
+    def counts(self) -> dict:
+        """{count key: total} over every step dispatched so far."""
+        return _counts(self._state)
 
 
 def make_bert_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0):
     """Initialize (params, opt_state) laid out on the mesh."""
     rules = param_sharding_rules(plan, n_experts=cfg.n_experts)
-    return _init_train_state(cfg, plan.mesh, _specs_to_shardings(plan.mesh, rules), lr, seed)
+    return _init_train_state(
+        functools.partial(init_bert_params, cfg), plan.mesh,
+        _specs_to_shardings(plan.mesh, rules), lr, seed,
+    )
+
+
+# every position of the batches, and those the MLM head ran at
+_HEAD_SERIES = (
+    ("computed", HEAD_POSITIONS_FAMILY, {"kind": "computed"}, 1),
+    ("all", HEAD_POSITIONS_FAMILY, {"kind": "all"}, 1),
+)
+
+
+def _with_head_counts(loss_fn):
+    """``loss_fn → (loss, positions the head ran at)`` as ``→ (loss, counts)``."""
+
+    def counted(params, input_ids, labels, *rest):
+        loss, positions = loss_fn(params, input_ids, labels, *rest)
+        return loss, {"computed": positions, "all": jnp.int32(labels.size)}
+
+    return counted
 
 
 def make_bert_train_step(
@@ -222,23 +261,69 @@ def make_bert_train_step(
         batch_sharding=batch_sharding, with_head_positions=True,
     )
     return _CountedStep(
-        _adamw_step(loss_fn, tx), param_shardings,
+        _adamw_step(_with_head_counts(loss_fn), tx), param_shardings,
         (batch_sharding, batch_sharding, batch_sharding),
-        NamedSharding(plan.mesh, P()),
+        NamedSharding(plan.mesh, P()), _HEAD_SERIES,
     )
 
 
 def _adamw_step(loss_fn, tx):
-    """``loss_fn(params, *batch) → (loss, head positions)`` as one optimizer
-    step → (params, opt_state, loss, head positions)."""
+    """``loss_fn(params, *batch) → (loss, counts)`` as one optimizer step →
+    (params, opt_state, loss, counts)."""
 
     def step(params, opt_state, *batch):
-        (loss, positions), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, *batch)
+        (loss, counts), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, *batch)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        return params, opt_state, loss, positions
+        return params, opt_state, loss, counts
 
     return step
+
+
+def _lm_plan(plan: MeshPlan) -> None:
+    if plan.tp * plan.sp * plan.pp * plan.ep != 1:
+        # the expert exchange over ep, and the mixers over tp or sp, are not written
+        raise NotImplementedError(
+            f"the causal-LM step runs on dp only; got tp={plan.tp} sp={plan.sp} pp={plan.pp} ep={plan.ep}"
+        )
+
+
+def make_lm_train_state(cfg, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0):
+    """(params, opt_state, tx, shardings) of the causal LM ``cfg`` describes
+    (``models/qwen3_next.py``), every leaf replicated over the mesh."""
+    from lakesoul_tpu.models.qwen3_next import init_lm_params
+
+    _lm_plan(plan)
+    init = functools.partial(init_lm_params, cfg)
+    shardings = jax.tree.map(lambda _: NamedSharding(plan.mesh, P()), jax.eval_shape(init, jax.random.key(0)))
+    return _init_train_state(init, plan.mesh, shardings, lr, seed)
+
+
+def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
+    """Jitted next-token train step: (params, opt_state, input_ids, labels) →
+    (params, opt_state, loss); rows arrive sharded P('dp').  Feeds
+    ``lakesoul_train_tokens_total``, ``lakesoul_train_moe_assignments_total
+    {kind="held"|"all"}`` and ``lakesoul_train_moe_expert_load
+    {stat="max"|"mean"}`` (the fullest and the mean held expert's assignments,
+    summed over steps and layers)."""
+    from lakesoul_tpu.models.qwen3_next import lm_loss
+
+    _lm_plan(plan)
+    batch_sharding = NamedSharding(plan.mesh, P("dp"))
+    loss_fn = functools.partial(
+        lm_loss, cfg=cfg, batch_sharding=batch_sharding if plan.dp > 1 else None
+    )
+    series = (
+        ("tokens", TOKENS_FAMILY, {}, 1),
+        ("moe_held", MOE_ASSIGNMENTS_FAMILY, {"kind": "held"}, 1),
+        ("moe_all", MOE_ASSIGNMENTS_FAMILY, {"kind": "all"}, 1),
+        ("moe_load_max", MOE_LOAD_FAMILY, {"stat": "max"}, 1),
+        ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / cfg.experts_held[1]),
+    )
+    return _CountedStep(
+        _adamw_step(loss_fn, tx), param_shardings, (batch_sharding, batch_sharding),
+        NamedSharding(plan.mesh, P()), series,
+    )
 
 
 def make_bert_pipeline_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0):
@@ -259,7 +344,10 @@ def make_bert_pipeline_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float
             rules["layers"][leaf] = P("pp", *spec[1:])
     for ln in ("ln1", "ln2"):
         rules["layers"][ln] = {"scale": P("pp", None), "bias": P("pp", None)}
-    return _init_train_state(cfg, plan.mesh, _specs_to_shardings(plan.mesh, rules), lr, seed)
+    return _init_train_state(
+        functools.partial(init_bert_params, cfg), plan.mesh,
+        _specs_to_shardings(plan.mesh, rules), lr, seed,
+    )
 
 
 def make_bert_pipeline_train_step(
@@ -304,9 +392,9 @@ def make_bert_pipeline_train_step(
         return mlm_head_loss(params, x, labels, batch_sharding=batch_sharding)
 
     return _CountedStep(
-        _adamw_step(loss_fn, tx), param_shardings,
+        _adamw_step(_with_head_counts(loss_fn), tx), param_shardings,
         (batch_sharding, batch_sharding, batch_sharding),
-        NamedSharding(plan.mesh, P()),
+        NamedSharding(plan.mesh, P()), _HEAD_SERIES,
     )
 
 

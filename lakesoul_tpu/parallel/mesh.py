@@ -52,6 +52,14 @@ class MeshPlan:
         return self.sharding()
 
 
+def spec_axes(spec: P) -> tuple[str, ...]:
+    """The mesh axes a PartitionSpec splits over, in order."""
+    return tuple(
+        a for part in spec if part is not None
+        for a in (part if isinstance(part, tuple) else (part,))
+    )
+
+
 def _factor(n: int) -> tuple[int, int, int]:
     """Split n devices into (dp, tp, sp) with dp ≥ 2 preserved: data
     parallelism is the default axis for a data-loading framework, so tp/sp

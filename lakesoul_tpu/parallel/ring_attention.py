@@ -22,7 +22,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
-def _block_attn(q, k, v, scale, mask=None):
+def block_attn(q, k, v, scale, mask=None):
     """One Q-block × K-block attention contribution.
     q: [B, H, Tq, D], k/v: [B, H, Tk, D] → (scores-max, exp-sum, weighted-V)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
@@ -49,7 +49,7 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", kv_mask=None):
             return None
         return blk_mask[:, None, None, :]  # [B,1,1,Tk]
 
-    m, l, o = _block_attn(q, k, v, scale, mask_for(kv_mask))
+    m, l, o = block_attn(q, k, v, scale, mask_for(kv_mask))
 
     def body(i, carry):
         m, l, o, k, v, kv_mask = carry
@@ -58,7 +58,7 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", kv_mask=None):
         v = lax.ppermute(v, axis_name, perm)
         if kv_mask is not None:
             kv_mask = lax.ppermute(kv_mask, axis_name, perm)
-        m_new, l_new, o_new = _block_attn(q, k, v, scale, mask_for(kv_mask))
+        m_new, l_new, o_new = block_attn(q, k, v, scale, mask_for(kv_mask))
         m_tot = jnp.maximum(m, m_new)
         a = jnp.exp(m - m_tot)
         b = jnp.exp(m_new - m_tot)

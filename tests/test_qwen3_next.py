@@ -1,0 +1,363 @@
+"""The hybrid causal LM (``models/qwen3_next.py``), its dropless expert layer
+(``parallel/moe.py``: ``route_top_k``, ``held_experts``, ``shared_expert``)
+and its train step (``models/train.py``) against the plain float32 reference in ``tests/reference/qwen3_next_f32.py``.
+
+Small on purpose (hidden 64) with the published ratios kept: three DeltaNet
+layers to one attention layer, 2 value heads a key head, 8 query heads a
+key-value head, a quarter of the channels rotated, 16 experts top-4 of which 4
+are held, one shared expert.  The program runs with ``dtype="float32"`` here so
+that the comparison is of the algorithms (chunks against tokens, tiles against
+a masked loop), not of bfloat16.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from lakesoul_tpu.models import qwen3_next as lm
+from lakesoul_tpu.models.train import (
+    MOE_ASSIGNMENTS_FAMILY,
+    MOE_LOAD_FAMILY,
+    TOKENS_FAMILY,
+    make_lm_train_state,
+    make_lm_train_step,
+)
+from lakesoul_tpu.obs import registry
+from lakesoul_tpu.parallel import moe
+from lakesoul_tpu.parallel.mesh import make_mesh
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+_spec = importlib.util.spec_from_file_location(
+    "qwen3_next_f32", os.path.join(HERE, "reference", "qwen3_next_f32.py")
+)
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+
+MODEL = dict(
+    vocab_size=96, hidden_size=64, num_hidden_layers=4, full_attention_interval=4,
+    num_attention_heads=8, num_key_value_heads=1, head_dim=16, partial_rotary_factor=0.25,
+    rope_theta=1e7, linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16,
+    linear_value_head_dim=16, linear_conv_kernel_dim=4, num_experts=16, num_experts_per_tok=4,
+    moe_intermediate_size=32, shared_expert_intermediate_size=32, rms_norm_eps=1e-6,
+)
+HELD = (4, 4)
+CFG = lm.Qwen3NextConfig.from_published(MODEL, experts_held=HELD, dtype="float32")
+B, T = 2, 150  # 150 is two chunks of 64 and a part of a third
+
+
+@pytest.fixture(autouse=True)
+def _full_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def params():
+    # five times the family's 0.02, so that no path's signal is lost in the residual
+    tree = lm.init_lm_params(CFG, jax.random.key(0))
+    return jax.tree.map(lambda a: a * 5 if a.ndim >= 2 else a, tree)
+
+
+def tokens(seed=0, rows=B, length=T):
+    rng = np.random.default_rng(seed)
+    ids = jnp.asarray(rng.integers(0, MODEL["vocab_size"], (rows, length)), jnp.int32)
+    labels = jnp.concatenate([ids[:, 1:], jnp.full((rows, 1), -100, jnp.int32)], axis=1)
+    return ids, labels
+
+
+def hidden(seed, length=T):
+    return jax.random.normal(jax.random.key(seed), (B, length, MODEL["hidden_size"]))
+
+
+def assert_close(got, want, tol=2e-4):
+    """Every leaf within ``tol`` of the reference by relative norm."""
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        err = float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+        assert err < tol, f"{jax.tree_util.keystr(path)}: {err}"
+
+
+def test_layer_kinds_come_from_the_interval():
+    assert CFG.layer_kinds() == ("gdn", "gdn", "gdn", "attn")
+    six = lm.Qwen3NextConfig.from_published(dict(MODEL, num_hidden_layers=6, full_attention_interval=2))
+    assert six.layer_kinds() == ("gdn", "attn") * 3
+    assert ref.layer_kinds(MODEL) == list(CFG.layer_kinds())
+
+
+# ------------------------------------------------------------------ mixers
+
+
+@pytest.mark.parametrize("length, chunk", [(150, 64), (48, 64), (64, 16)],
+                         ids=["part-of-a-chunk-over", "a-single-chunk", "whole-chunks"])
+def test_gated_delta_net_chunks_equal_the_token_recurrence(params, length, chunk):
+    p = params["layers"][0]["gdn"]
+    x = hidden(1, length)
+    weigh = jax.random.normal(jax.random.key(2), x.shape)
+
+    def program(p, x):
+        return jnp.sum(weigh * lm.gated_delta_net(x, p, cfg=CFG, chunk=chunk))
+
+    def plain(p, x):
+        return jnp.sum(weigh * ref.gated_delta_net(x, p, MODEL))
+
+    assert_close(lm.gated_delta_net(x, p, cfg=CFG, chunk=chunk), ref.gated_delta_net(x, p, MODEL))
+    assert_close(jax.grad(program, argnums=(0, 1))(p, x), jax.grad(plain, argnums=(0, 1))(p, x))
+
+
+@pytest.mark.parametrize("size", [16, 128])
+def test_unit_lower_inverse_is_the_inverse_with_its_gradient(size):
+    a = jnp.tril(jax.random.normal(jax.random.key(11), (3, size, size)) * 0.3, -1)
+    eye = jnp.eye(size)
+    np.testing.assert_allclose(lm.unit_lower_inverse(a) @ (eye + a), jnp.broadcast_to(eye, a.shape), atol=2e-4)
+    # the doublings in bfloat16, as on the chip: the Newton step brings them back
+    # (at 0.3 the inverse's entries reach 80: far worse conditioned than normalised keys make it)
+    assert_close(lm.unit_lower_inverse(a, jnp.bfloat16), jnp.linalg.inv(eye + a), tol=1e-3)
+    weigh = jax.random.normal(jax.random.key(12), a.shape)
+    got = jax.grad(lambda a: jnp.sum(weigh * lm.unit_lower_inverse(a)))(a)
+    want = jax.grad(lambda a: jnp.sum(weigh * jnp.linalg.inv(eye + jnp.tril(a, -1))))(a)
+    assert_close(got, want, tol=1e-3)
+    with pytest.raises(ValueError, match="power of two"):
+        lm.unit_lower_inverse(jnp.zeros((24, 24)))
+
+
+@pytest.mark.parametrize("band, rows", [(1024, 128), (64, 16), (64, 64)],
+                         ids=["one-block", "bands-of-row-blocks", "bands"])
+def test_gated_attention_blocks_equal_the_masked_softmax(params, monkeypatch, band, rows):
+    monkeypatch.setattr(lm, "ATTN_BAND", band)
+    monkeypatch.setattr(lm, "ATTN_ROWS", rows)
+    p = params["layers"][3]["attn"]
+    x = hidden(3)
+    weigh = jax.random.normal(jax.random.key(4), x.shape)
+
+    def program(p, x):
+        return jnp.sum(weigh * lm.gated_attention(x, p, cfg=CFG))
+
+    def plain(p, x):
+        return jnp.sum(weigh * ref.gated_attention(x, p, MODEL))
+
+    assert_close(lm.gated_attention(x, p, cfg=CFG), ref.gated_attention(x, p, MODEL))
+    assert_close(jax.grad(program, argnums=(0, 1))(p, x), jax.grad(plain, argnums=(0, 1))(p, x))
+
+
+def test_attention_is_causal_and_rotates_a_quarter_of_the_channels(params):
+    p = params["layers"][3]["attn"]
+    x = hidden(5)
+    later = x.at[:, 100:].set(0.0)
+    np.testing.assert_allclose(
+        lm.gated_attention(x, p, cfg=CFG)[:, :100], lm.gated_attention(later, p, cfg=CFG)[:, :100],
+        atol=1e-5,
+    )
+    q = jax.random.normal(jax.random.key(6), (1, 8, 2, 16))
+    turned = lm._rotary(q, jnp.arange(8), 4, 1e7)
+    np.testing.assert_array_equal(turned[..., 4:], q[..., 4:])
+    assert not np.allclose(turned[:, 1:, :, :4], q[:, 1:, :, :4])
+
+
+# ------------------------------------------------------------ expert layer
+
+
+def _route_to(router, experts):
+    """A router that sends every token whose first channel is 10 to ``experts``
+    (its top-k): their scores stand 50 above the rest."""
+    return (router * 1e-3).at[0, jnp.asarray(experts)].add(5.0)
+
+
+def _expert_layer(x, p, *, held, tile=None):
+    """The three pieces of ``parallel/moe.py`` as ``lm_layer`` puts them
+    together (there with its norm and its rematerialisation around them)."""
+    top_e, w = moe.route_top_k(x, p["router"], top_k=4)
+    y, counts = moe.held_experts(x, top_e, w, p, n_experts=16, held=held, tile=tile)
+    return y + moe.shared_expert(x, p["shared"]), counts
+
+
+@pytest.mark.parametrize("routing", ["even", "all-on-one-held", "none-held"])
+def test_expert_layer_equals_the_loop_over_experts(params, routing):
+    p = dict(params["layers"][0]["moe"])
+    if routing == "all-on-one-held":  # expert 5 takes every token, its three companions are not held
+        p["router"] = _route_to(p["router"], [5, 0, 1, 2])
+    elif routing == "none-held":
+        p["router"] = _route_to(p["router"], [0, 1, 2, 3])
+    x = hidden(7).at[..., 0].set(10.0)
+    weigh = jax.random.normal(jax.random.key(8), x.shape)
+
+    def program(p, x):
+        return jnp.sum(weigh * _expert_layer(x, p, held=HELD, tile=16)[0])
+
+    def plain(p, x):
+        return jnp.sum(weigh * ref.moe(x, p, MODEL, HELD))
+
+    y, counts = _expert_layer(x, p, held=HELD, tile=16)
+    assert_close(y, ref.moe(x, p, MODEL, HELD))
+    assert_close(jax.grad(program, argnums=(0, 1))(p, x), jax.grad(plain, argnums=(0, 1))(p, x))
+    n = B * T
+    assert int(counts["moe_all"]) == 4 * n
+    if routing == "all-on-one-held":
+        assert (int(counts["moe_held"]), int(counts["moe_load_max"])) == (n, n)
+    elif routing == "none-held":
+        assert (int(counts["moe_held"]), int(counts["moe_load_max"])) == (0, 0)
+        assert_close(y, ref.shared_expert(x, p["shared"]))
+    else:
+        assert 0 < int(counts["moe_load_max"]) < int(counts["moe_held"]) < 4 * n
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer(params):
+    """16 experts over four chips of 4: the routed parts of the four shares and
+    the shared expert, counted once, are the whole layer of the reference."""
+    whole = dict(params["layers"][1]["moe"])
+    keys = jax.random.split(jax.random.key(9), 3)
+    for name, key in zip(("w_gate", "w_up", "w_down"), keys):  # all 16 experts' weights
+        whole[name] = jax.random.normal(key, (16,) + whole[name].shape[1:]) * 0.1
+    x = hidden(10)
+    want = ref.moe(x, whole, MODEL, (0, 16))
+    top_e, w = moe.route_top_k(x, whole["router"], top_k=4)
+    routed = 0.0
+    for first in range(0, 16, 4):
+        share = {k: whole[k][first:first + 4] for k in ("w_gate", "w_up", "w_down")}
+        y, _ = moe.held_experts(x, top_e, w, share, n_experts=16, held=(first, 4), tile=32)
+        routed = routed + y
+    assert_close(routed + ref.shared_expert(x, whole["shared"]), want)
+    # and the program's own shared expert is the reference's
+    assert_close(moe.shared_expert(x, whole["shared"]), ref.shared_expert(x, whole["shared"]))
+
+
+def test_expert_layer_refuses_a_share_that_does_not_fit(params):
+    p = params["layers"][0]["moe"]
+    with pytest.raises(ValueError, match="held"):
+        _expert_layer(hidden(0), p, held=(14, 4))
+    with pytest.raises(ValueError, match="held"):
+        _expert_layer(hidden(0), p, held=(0, 8))
+
+
+# ------------------------------------------------------------- whole model
+
+
+def test_loss_and_every_gradient_leaf_equal_the_reference(params):
+    ids, labels = tokens()
+    (loss, counts), grads = jax.jit(jax.value_and_grad(
+        lambda p: lm.lm_loss(p, ids, labels, cfg=CFG), has_aux=True
+    ))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: ref.lm_loss(p, ids, labels, cfg=MODEL, held=HELD)
+    ))(params)
+    np.testing.assert_allclose(float(loss), float(want), rtol=2e-6)
+    assert_close(grads, want_grads)
+    assert all(float(jnp.linalg.norm(g)) > 0 for g in jax.tree.leaves(grads))
+    assert int(counts["tokens"]) == B * T and int(counts["moe_all"]) == 4 * 4 * B * T
+    logits = lm.lm_logits(params, ids, cfg=CFG)
+    assert_close(logits, ref.lm_logits(params, ids, cfg=MODEL, held=HELD))
+
+
+def test_bfloat16_program_stays_near_the_reference(params):
+    """The dtype the chip runs: products in bfloat16, float32 accumulation."""
+    ids, labels = tokens(1)
+    cfg = lm.Qwen3NextConfig.from_published(MODEL, experts_held=HELD)
+    loss, _ = lm.lm_loss(params, ids, labels, cfg=cfg)
+    want = ref.lm_loss(params, ids, labels, cfg=MODEL, held=HELD)
+    assert abs(float(loss) - float(want)) < 0.02
+
+
+def _series(family, **labels) -> float:
+    inner = ",".join(f'{k}="{v}"' for k, v in sorted(labels.items()))
+    return registry().snapshot().get(family + ("{" + inner + "}" if inner else ""), 0)
+
+
+@pytest.fixture(scope="module")
+def stepped():
+    """One optimizer step on one device and the same on a dp=2 mesh, from one
+    seed, with the counters read before and after the first."""
+    ids, labels = tokens(2)
+    out = {}
+    for dp in (1, 2):
+        plan = make_mesh(jax.devices()[:dp], dp=dp, tp=1, sp=1)
+        with jax.default_matmul_precision("highest"):
+            state, opt_state, tx, shardings = make_lm_train_state(CFG, plan, lr=1e-2, seed=3)
+            before = jax.device_get(state)
+            step = make_lm_train_step(CFG, plan, tx, shardings)
+            counted = {
+                "tokens": _series(TOKENS_FAMILY),
+                "held": _series(MOE_ASSIGNMENTS_FAMILY, kind="held"),
+                "all": _series(MOE_ASSIGNMENTS_FAMILY, kind="all"),
+                "max": _series(MOE_LOAD_FAMILY, stat="max"),
+                "mean": _series(MOE_LOAD_FAMILY, stat="mean"),
+            }
+            after, _, loss = step(state, opt_state, ids, labels)
+            out[dp] = dict(before=before, after=jax.device_get(after), loss=float(loss),
+                           counted=counted, step=step)
+    return ids, labels, out
+
+
+def assert_moves_agree(run, want, lr=1e-2):
+    """A first AdamW step moves a weight by ``lr * g / (|g| + 1e-8)``: by
+    ``lr`` whatever the gradient's size, unless the gradient is of the size of
+    AdamW's epsilon, where its rounding shows in the move (a DeltaNet head that
+    forgets fast has such an ``A_log``).  So: where the reference moved by
+    nearly ``lr`` the program moved the same way, which is where a wrong sign
+    or a missed leaf shows; elsewhere it moved by no more than ``lr``."""
+    for (path, after), before, target in zip(
+        jax.tree_util.tree_leaves_with_path(run["after"]), jax.tree.leaves(run["before"]),
+        jax.tree.leaves(want),
+    ):
+        name = jax.tree_util.keystr(path)
+        moved, wanted = (after - before) / lr, (target - before) / lr
+        decisive = np.abs(wanted) > 0.9
+        assert decisive.any(), name
+        np.testing.assert_allclose(moved[decisive], wanted[decisive], atol=2e-2, err_msg=name)
+        assert float(np.max(np.abs(moved))) < 1.02, name
+
+
+def test_one_step_is_the_references_adamw_step(stepped):
+    ids, labels, out = stepped
+    run = out[1]
+    loss, grads = jax.value_and_grad(
+        lambda p: ref.lm_loss(p, ids, labels, cfg=MODEL, held=HELD)
+    )(run["before"])
+    zeros = jax.tree.map(jnp.zeros_like, run["before"])
+    want, _, _ = ref.adamw_step(run["before"], grads, zeros, zeros, 0, lr=1e-2)
+    np.testing.assert_allclose(run["loss"], float(loss), rtol=2e-6)
+    assert_moves_agree(run, want)
+
+
+def test_step_on_a_dp2_mesh_equals_one_device(stepped):
+    _, _, out = stepped
+    np.testing.assert_allclose(out[2]["loss"], out[1]["loss"], rtol=1e-5)
+    assert_moves_agree(out[2], out[1]["after"])
+
+
+def test_counters_for_a_known_routing(stepped):
+    ids, labels, out = stepped
+    run = out[1]
+    # what the step must have counted, from the reference's routing of the same weights
+    held = load_max = 0
+    x = jnp.asarray(run["before"]["embed"])[ids]
+    for lp, kind in zip(run["before"]["layers"], CFG.layer_kinds()):
+        y = ref.rms_norm(x, lp["norm1"], 1e-6)
+        x = x + (ref.gated_delta_net(y, lp["gdn"], MODEL) if kind == "gdn"
+                 else ref.gated_attention(y, lp["attn"], MODEL))
+        y = ref.rms_norm(x, lp["norm2"], 1e-6)
+        top_e, _ = ref.route(y.reshape(-1, y.shape[-1]), lp["moe"]["router"], 4)
+        loads = np.bincount(np.asarray(top_e).ravel(), minlength=16)[HELD[0]:HELD[0] + HELD[1]]
+        held += int(loads.sum())
+        load_max += int(loads.max())
+        x = x + ref.moe(y, lp["moe"], MODEL, HELD)
+    got = run["step"].counts()
+    assert got == {"tokens": B * T, "moe_all": 4 * 4 * B * T, "moe_held": held, "moe_load_max": load_max}
+    before = run["counted"]
+    # the dp=2 step of the fixture ran after this read and counted the same batch again
+    again = 2
+    assert _series(TOKENS_FAMILY) - before["tokens"] == again * B * T
+    assert _series(MOE_ASSIGNMENTS_FAMILY, kind="all") - before["all"] == again * 16 * B * T
+    assert _series(MOE_ASSIGNMENTS_FAMILY, kind="held") - before["held"] == again * held
+    assert _series(MOE_LOAD_FAMILY, stat="mean") - before["mean"] == pytest.approx(again * held / HELD[1])
+    assert _series(MOE_LOAD_FAMILY, stat="max") - before["max"] >= load_max
+    assert out[2]["step"].counts()["moe_held"] == held
+
+
+def test_lm_step_runs_on_dp_only():
+    plan = make_mesh(jax.devices()[:2], dp=1, tp=2, sp=1)
+    with pytest.raises(NotImplementedError, match="dp only"):
+        make_lm_train_state(CFG, plan)

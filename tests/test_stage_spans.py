@@ -278,3 +278,55 @@ def test_names_the_device_readers_search_for(lower, module, reader, constant):
     assert f"module @{module} " in lower()
     with open(os.path.join(REPO, "benchmarks", "chip", reader)) as f:
         assert constant in f.read()
+
+
+LM_SCOPES = {
+    "lakesoul.lm.gdn": ("layer_metrics/gdn_step_share_pct.py", '"gdn"'),
+    "lakesoul.lm.attn": ("layer_metrics/attn_step_share_pct.py", '"attn"'),
+    "lakesoul.lm.moe.route": ("layer_metrics/moe_step_share_pct.py", '"moe.route"'),
+    "lakesoul.lm.moe.experts": ("layer_metrics/moe_step_share_pct.py", '"moe.experts"'),
+    "lakesoul.lm.moe.shared": ("layer_metrics/moe_step_share_pct.py", '"moe.shared"'),
+    "lakesoul.lm.head": ("chipbench/scopes.py", 'PREFIX = "lakesoul.lm."'),
+}
+
+
+@pytest.fixture(scope="module")
+def lm_step_module() -> str:
+    """The causal-LM step as a training job gets it, lowered with the
+    locations that carry ``jax.named_scope``."""
+    from lakesoul_tpu.models.qwen3_next import Qwen3NextConfig
+    from lakesoul_tpu.models.train import make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    cfg = Qwen3NextConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=4, num_attention_heads=8,
+        num_key_value_heads=1, head_dim=8, linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16, experts_held=(0, 4),
+    )
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    step = make_lm_train_step(cfg, plan, tx, shardings)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    return step.lower(params, opt_state, ids, ids).as_text(debug_info=True)
+
+
+def test_lm_step_program_name_the_device_readers_search_for(lm_step_module):
+    """``step_device_ms`` and ``chipbench/scopes.py`` find the causal-LM step in
+    a device trace by the name its adaptor pins."""
+    assert "module @jit_train_step " in lm_step_module
+    with open(os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")) as f:
+        assert 'STEP_MODULE = "jit_train_step"' in f.read()
+
+
+@pytest.mark.parametrize("scope", sorted(LM_SCOPES))
+def test_lm_scope_names_the_share_readers_search_for(lm_step_module, scope):
+    """The three step-share readers charge device time by these
+    ``jax.named_scope`` names (through ``consumers/qwen3_next_clm.py:
+    scopes_of``): a rename in the program has to be a rename there."""
+    assert f"{scope}/" in lm_step_module or f"{scope})" in lm_step_module or f'{scope}"' in lm_step_module
+    reader, constant = LM_SCOPES[scope]
+    with open(os.path.join(REPO, "benchmarks", "chip", reader)) as f:
+        assert constant in f.read()
+    with open(os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")) as f:
+        assert r'lakesoul\.lm\.' in f.read()
