@@ -21,8 +21,8 @@ Stages, each of which fails the run if it fails (nothing is caught):
   ``IvfRabitqIndex.batch_search`` on its device-resident path.  Recall@10
   against ``vector/oracle.py`` must hold the floor the CPU tests use.
 - ``kernels``: the smoke register (``lakesoul_tpu/tensorplane/smoke.py``):
-  all five Pallas kernels compiled (``interpret=False``) at a deployed
-  size, d = 128 and 768, against their ``jnp`` twins, plus the delivery
+  every Pallas kernel compiled (``interpret=False``) at a deployed
+  size, d = 128 and 768, against its ``jnp`` twin, plus the delivery
   and replay cases.
 - ``multichip`` (four or more devices): the trainer again over
   ``make_mesh(jax.devices()[:4])`` with batch 64 under ``P("dp", "sp")``,

@@ -28,11 +28,14 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from lakesoul_tpu.models.bert import labelled_nll
 from lakesoul_tpu.parallel.moe import ROUTE_SCOPE, held_experts, route_top_k, shared_expert
 from lakesoul_tpu.parallel.ring_attention import block_attn
+from lakesoul_tpu.vector.kernels import _on_tpu
 
 GDN_SCOPE = "lakesoul.lm.gdn"
 ATTN_SCOPE = "lakesoul.lm.attn"
@@ -177,22 +180,31 @@ def _mm_high(a, b):
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGH)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def unit_lower_inverse(a, dtype=jnp.float32):
-    """``(I + A)^-1`` in float32 for ``A`` [..., C, C] strictly lower
-    triangular, ``C`` a power of two, by block inversion from the diagonal
-    outwards: with the inverses ``X1, X2`` of two neighbouring diagonal blocks
-    known, the block ``[[D1, 0], [L, D2]]`` has the inverse ``[[X1, 0],
-    [-X2 L X1, X2]]``.  Each doubling is two products over the whole matrix
-    (``X - X L X`` with ``L`` masked to the lower-left blocks), which is what a
-    matrix unit is for; their factors are rounded to ``dtype`` (one bfloat16
-    pass each on a TPU), and one Newton step ``X + X (I - M X)`` at three passes
-    squares the error that leaves.  (The compiler's own triangular solve took
-    21 ms a call here on a v5e, and the doublings at three passes each 9 ms:
-    PERF.md section 6, PR 28.)"""
+def _lower_left(row, col, s):
+    """Level ``s`` of the block inversion: inside each diagonal block of
+    ``2 s`` the lower-left ``s x s`` quarter."""
+    return (row // (2 * s) == col // (2 * s)) & ((row // s) % 2 == 1) & ((col // s) % 2 == 0)
+
+
+def _chunk_decay(g):
+    """g [..., C] float32, a chunk's running log decay → [..., C, C]:
+    ``exp(g_i - g_j)`` for ``i >= j``, else 0.  Masked before the exponential:
+    above the diagonal the difference is positive and may overflow."""
+    c = g.shape[-1]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    return jnp.exp(jnp.where(lower, g[..., :, None] - g[..., None, :], -jnp.inf))
+
+
+def _chunk_system(a, log_decay):
+    """The ``A`` of :func:`unit_lower_inverse`."""
+    return a if log_decay is None else jnp.tril(a * _chunk_decay(log_decay), -1)
+
+
+def _unit_lower_inverse_jnp(a, dtype, log_decay=None):
+    """The doubling as whole-array ``jnp`` operations: what a chunk narrower
+    than a lane tile runs, and the kernel's twin and reference."""
+    a = _chunk_system(a, log_decay)
     c = a.shape[-1]
-    if c & (c - 1):
-        raise ValueError(f"chunk {c} is not a power of two")
 
     def mm(x, y):
         return jnp.matmul(x.astype(dtype), y.astype(dtype), preferred_element_type=jnp.float32)
@@ -202,24 +214,110 @@ def unit_lower_inverse(a, dtype=jnp.float32):
     x = jnp.broadcast_to(eye, a.shape)
     s = 1
     while s < c:
-        lower_left = (
-            (at[:, None] // (2 * s) == at[None, :] // (2 * s))
-            & ((at[:, None] // s) % 2 == 1) & ((at[None, :] // s) % 2 == 0)
-        )
-        x = x - mm(mm(x, jnp.where(lower_left, a, 0.0)), x)
+        x = x - mm(mm(x, jnp.where(_lower_left(at[:, None], at[None, :], s), a, 0.0)), x)
         s *= 2
     return x + _mm_high(x, eye - _mm_high(eye + a, x))
 
 
-def _unit_lower_inverse_fwd(a, dtype):
-    x = unit_lower_inverse(a, dtype)
-    return x, x
+def _unit_lower_inverse_kernel(a_ref, *refs, dtype):
+    """A block of systems [G, C, C], in VMEM from ``A``'s making to the Newton
+    step; ``refs`` are the log decay [G, 1, C], where the system has one, and
+    the result.  ``X`` is block diagonal at every level, so ``X L X`` is
+    ``X A X`` inside the level's lower-left quarters with the same terms in
+    every sum: the mask moves from the factor to the result, ``A`` is rounded
+    once, and level 1 (``X = I``) needs no product."""
+    *g_ref, x_ref = refs
+    a = a_ref[...]
+    c = a.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = (row == col).astype(jnp.float32)
+    if g_ref:
+        g_j = jnp.broadcast_to(g_ref[0][...], a.shape)  # [G, i, j] = g_j; its transpose g_i
+        a = a * jnp.exp(jnp.where(row > col, jnp.swapaxes(g_j, 1, 2) - g_j, -jnp.inf))
+
+    def mm(x, y, **kwargs):
+        return jnp.einsum("gij,gjk->gik", x, y, preferred_element_type=jnp.float32, **kwargs)
+
+    a_lo = a.astype(dtype)
+    x = eye - jnp.where(_lower_left(row, col, 1), a_lo.astype(jnp.float32), 0.0)
+    s = 2
+    while s < c:
+        x_lo = x.astype(dtype)
+        x = jnp.where(_lower_left(row, col, s), x - mm(mm(x_lo, a_lo).astype(dtype), x_lo), x)
+        s *= 2
+    highest = jax.lax.Precision.HIGHEST  # Mosaic has no three-pass product: six
+    x_ref[...] = x + mm(x, eye - x - mm(a, x, precision=highest), precision=highest)
 
 
-def _unit_lower_inverse_bwd(dtype, x, g):
+INVERSE_BLOCK = 8  # systems a grid step holds: 64 KB each, in and out, twice for the pipeline
+
+
+def _unit_lower_inverse_pallas(a, dtype, log_decay=None, *, interpret: bool):
+    c = a.shape[-1]
+    flat = a.reshape(-1, c, c)
+
+    def block(*shape):
+        return pl.BlockSpec((INVERSE_BLOCK, *shape), lambda i: (i, 0, 0))
+
+    decay = [] if log_decay is None else [log_decay.reshape(-1, 1, c)]
+    x = pl.pallas_call(
+        functools.partial(_unit_lower_inverse_kernel, dtype=dtype),
+        out_shape=jax.ShapeDtypeStruct(flat.shape, jnp.float32),
+        grid=(pl.cdiv(flat.shape[0], INVERSE_BLOCK),),
+        in_specs=[block(c, c)] + [block(1, c) for _ in decay],
+        out_specs=block(c, c),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="unit_lower_inverse",
+        interpret=interpret,
+    )(flat, *decay)
+    return x.reshape(a.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def unit_lower_inverse(a, dtype=jnp.float32, log_decay=None):
+    """``(I + A)^-1`` in float32 for ``A`` [..., C, C] strictly lower
+    triangular, ``C`` a power of two; with ``log_decay`` g [..., C], ``A`` is
+    the strictly lower part of ``a_ij exp(g_i - g_j)`` (a DeltaNet chunk's
+    system: ``a`` the keys' products, ``g`` the running log decay).
+
+    By block inversion from the diagonal outwards: with the inverses ``X1, X2``
+    of two neighbouring diagonal blocks known, the block ``[[D1, 0], [L, D2]]``
+    has the inverse ``[[X1, 0], [-X2 L X1, X2]]``.  Each doubling is two
+    products over the whole matrix (``X - X L X`` with ``L`` masked to the
+    lower-left blocks), which is what a matrix unit is for; their factors are
+    rounded to ``dtype`` (one bfloat16 pass each on a TPU), and one Newton step
+    ``X + X (I - M X)`` at three passes or more squares the error that leaves.
+
+    Where a chunk fills a lane tile (``C % 128 == 0``) one Pallas kernel does
+    all of it, a block of systems at a time: a 128 x 128 system is 64 KB and
+    lives in VMEM from ``A``'s making to the Newton step, so HBM sees ``a``
+    once and ``X`` once (as eight whole-array fusions the levels were bound by
+    HBM traffic: PERF.md section 6, PR 29).  It runs compiled on a TPU and in
+    the Pallas interpreter elsewhere.  A narrower chunk takes the same doubling
+    as ``jnp`` operations, the kernel's twin.  (The compiler's own triangular
+    solve took 21 ms a call here on a v5e: PERF.md section 6, PR 28.)"""
+    c = a.shape[-1]
+    if c & (c - 1):
+        raise ValueError(f"chunk {c} is not a power of two")
+    if c % 128:
+        return _unit_lower_inverse_jnp(a, dtype, log_decay)
+    return _unit_lower_inverse_pallas(a, dtype, log_decay, interpret=not _on_tpu())
+
+
+def _unit_lower_inverse_fwd(a, dtype, log_decay):
+    x = unit_lower_inverse(a, dtype, log_decay)
+    return x, (x, None if log_decay is None else (a, log_decay))
+
+
+def _unit_lower_inverse_bwd(dtype, saved, g):
     # d(M^-1) = -M^-1 dM M^-1, and only the strictly lower part of M moves
+    x, decayed = saved
     xt = jnp.swapaxes(x, -1, -2)
-    return (-jnp.tril(_mm_high(_mm_high(xt, g), xt), -1),)
+    d_system = -jnp.tril(_mm_high(_mm_high(xt, g), xt), -1)
+    if decayed is None:
+        return d_system, None
+    return jax.vjp(_chunk_system, *decayed)[1](d_system)
 
 
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
@@ -232,8 +330,11 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
     float32 log decay, beta [B, T, H] float32 → o [B, T, H, dv], equal to the
     recurrence ``S' = exp(g_t) S; u = beta_t (v_t - S'^T k_t); S = S' + k_t u^T;
     o_t = S^T q_t`` from ``S_0 = 0``.  Inside a chunk the tokens' updates are
-    solved together (a unit lower-triangular system, inverted for every chunk at
-    once); across chunks the float32 state is carried by a scan whose backward
+    solved together: a unit lower-triangular system, made from the keys'
+    products and the decay and inverted for every chunk at once by
+    :func:`unit_lower_inverse` (at ``GDN_CHUNK`` = 128 one Pallas kernel with
+    each system in VMEM; a chunk under 128, as the tests use, takes its ``jnp``
+    twin).  Across chunks the float32 state is carried by a scan whose backward
     pass keeps one state a chunk and computes the rest of the chunk again."""
     chunk = chunk or GDN_CHUNK
     lo = q.dtype
@@ -250,18 +351,10 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
     )
     gc = jnp.cumsum(g.astype(f32), axis=2)  # the decay's running sum inside the chunk
     beta = beta.astype(f32)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-
-    def decay_of(gc):
-        """[.., chunk, H] → [.., H, chunk, chunk]: exp(gc_i - gc_j) for i >= j, else 0.
-        Masked before exp: above the diagonal the difference is positive and may overflow."""
-        gh = jnp.swapaxes(gc, -1, -2)
-        return jnp.exp(jnp.where(lower, gh[..., :, None] - gh[..., None, :], -jnp.inf))
-
     kb = (k.astype(f32) * beta[..., None]).astype(lo)
     kk = jnp.einsum("nbihd,nbjhd->nbhij", kb, k, preferred_element_type=f32)
     # (I + A)^-1, A the strictly lower part: every token's update given the ones before it
-    inv = unit_lower_inverse(jnp.tril(kk * decay_of(gc), -1), lo).astype(lo)
+    inv = unit_lower_inverse(kk, lo, jnp.swapaxes(gc, -1, -2)).astype(lo)
 
     @jax.checkpoint  # the backward pass keeps the carried state of each chunk and nothing else
     def step(state, xs):
@@ -274,7 +367,8 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
         u = jnp.einsum("bhij,bjhd->bihd", inv_i, vb, preferred_element_type=f32)
         w = jnp.einsum("bhij,bjhd->bihd", inv_i, kbg)
         v_new = (u - jnp.einsum("bihk,bhkv->bihv", w, s_lo, preferred_element_type=f32)).astype(lo)
-        local = jnp.einsum("bihd,bjhd->bhij", q_i, k_i, preferred_element_type=f32) * decay_of(gc_i)
+        decay = _chunk_decay(jnp.swapaxes(gc_i, -1, -2))  # [B, H, chunk, chunk]
+        local = jnp.einsum("bihd,bjhd->bhij", q_i, k_i, preferred_element_type=f32) * decay
         q_in = (q_i.astype(f32) * grow).astype(lo)
         o_i = (jnp.einsum("bihk,bhkv->bihv", q_in, s_lo, preferred_element_type=f32)
                + jnp.einsum("bhij,bjhv->bihv", local.astype(lo), v_new, preferred_element_type=f32))

@@ -233,6 +233,29 @@ def _run_ragged(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"items": m, "tile": TILE, "d": d, "rows": rows, **_agree(got, want)}
 
 
+def _run_unit_lower_inverse(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.models.qwen3_next import (
+        GDN_CHUNK,
+        _unit_lower_inverse_jnp,
+        _unit_lower_inverse_pallas,
+    )
+
+    # one chunk system a work item (not a multiple of the kernel's block at
+    # tiny sizes): small products, as normalised keys make them, under a
+    # running log decay, as the DeltaNet layers call it
+    n, c = sizes.items, GDN_CHUNK
+    rng = _rng(7)
+    a = jnp.asarray(rng.standard_normal((n, c, c), dtype=np.float32)) * 0.05
+    g = jnp.cumsum(-jnp.asarray(rng.random((n, c), dtype=np.float32)), axis=-1)
+    got = _unit_lower_inverse_pallas(a, jnp.bfloat16, g, interpret=interpret)
+    want = _twin_in_chunks(
+        lambda lo, hi: _unit_lower_inverse_jnp(a[lo:hi], jnp.bfloat16, g[lo:hi]), n, chunk=1024
+    )
+    return {"systems": n, "chunk": c, **_agree(got, want)}
+
+
 # --------------------------------------------------------------- multichip
 
 
@@ -369,6 +392,10 @@ def smoke_cases() -> list[SmokeCase]:
         SmokeCase(
             "annplane.ragged_score", "pallas", _run_ragged,
             kernels=("lakesoul_tpu/annplane/ragged.py::_ragged_score_kernel",),
+        ),
+        SmokeCase(
+            "models.unit_lower_inverse", "pallas", _run_unit_lower_inverse,
+            kernels=("lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel",),
         ),
         SmokeCase(
             "annplane.cross_chip_topk", "multichip", _run_cross_chip_topk,

@@ -114,7 +114,7 @@ def test_ann_stage_tiny_interpreted(chip_smoke):
 
 
 def test_kernel_stage_tiny_interpreted(chip_smoke):
-    from lakesoul_tpu.tensorplane.smoke import TINY
+    from lakesoul_tpu.tensorplane.smoke import TINY, smoke_cases
 
     out = chip_smoke.stage_kernels(
         dims=(64, 128), sizes_for=lambda d: dataclasses.replace(TINY, d=d),
@@ -123,7 +123,7 @@ def test_kernel_stage_tiny_interpreted(chip_smoke):
     assert set(out) == {"d64", "d128", "tensorplane"}
     for group in out.values():
         assert all(case["status"] == "pass" for case in group.values())
-    assert len(out["d128"]) == 5
+    assert len(out["d128"]) == sum(case.kind == "pallas" for case in smoke_cases())
 
 
 _CACHE_PROBE = (

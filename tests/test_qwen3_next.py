@@ -92,8 +92,8 @@ def test_layer_kinds_come_from_the_interval():
 # ------------------------------------------------------------------ mixers
 
 
-@pytest.mark.parametrize("length, chunk", [(150, 64), (48, 64), (64, 16)],
-                         ids=["part-of-a-chunk-over", "a-single-chunk", "whole-chunks"])
+@pytest.mark.parametrize("length, chunk", [(150, 64), (48, 64), (64, 16), (300, 128)],
+                         ids=["part-of-a-chunk-over", "a-single-chunk", "whole-chunks", "chunks-in-the-kernel"])
 def test_gated_delta_net_chunks_equal_the_token_recurrence(params, length, chunk):
     p = params["layers"][0]["gdn"]
     x = hidden(1, length)
@@ -109,9 +109,17 @@ def test_gated_delta_net_chunks_equal_the_token_recurrence(params, length, chunk
     assert_close(jax.grad(program, argnums=(0, 1))(p, x), jax.grad(plain, argnums=(0, 1))(p, x))
 
 
-@pytest.mark.parametrize("size", [16, 128])
-def test_unit_lower_inverse_is_the_inverse_with_its_gradient(size):
-    a = jnp.tril(jax.random.normal(jax.random.key(11), (3, size, size)) * 0.3, -1)
+LEADS = [(3,), (2, 5), (2, lm.INVERSE_BLOCK)]  # the last alone fills the kernel's blocks
+
+
+def lower_systems(lead, size, seed=11):
+    return jnp.tril(jax.random.normal(jax.random.key(seed), (*lead, size, size)) * 0.3, -1)
+
+
+@pytest.mark.parametrize("lead, size", [((3,), 16), *((lead, 128) for lead in LEADS)],
+                         ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_unit_lower_inverse_is_the_inverse_with_its_gradient(lead, size):
+    a = lower_systems(lead, size)
     eye = jnp.eye(size)
     np.testing.assert_allclose(lm.unit_lower_inverse(a) @ (eye + a), jnp.broadcast_to(eye, a.shape), atol=2e-4)
     # the doublings in bfloat16, as on the chip: the Newton step brings them back
@@ -123,6 +131,41 @@ def test_unit_lower_inverse_is_the_inverse_with_its_gradient(size):
     assert_close(got, want, tol=1e-3)
     with pytest.raises(ValueError, match="power of two"):
         lm.unit_lower_inverse(jnp.zeros((24, 24)))
+
+
+@pytest.mark.parametrize("decayed", [False, True], ids=["plain", "decayed"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lead", LEADS, ids=lambda lead: "x".join(map(str, lead)))
+def test_inverse_kernel_equals_its_jnp_twin(lead, dtype, decayed):
+    """A chunk of 128 runs the Pallas kernel (in the interpreter here); the
+    ``jnp`` doubling is the same arithmetic as whole-array operations.  With a
+    log decay the kernel makes ``A`` itself, from the whole of ``a``."""
+    a, decay = lower_systems(lead, 128, seed=13), None
+    if decayed:  # a running sum of negative steps, as a chunk's is; a's upper part must not matter
+        a = a + jnp.triu(jax.random.normal(jax.random.key(15), a.shape))
+        decay = jnp.cumsum(-jnp.exp(jax.random.normal(jax.random.key(16), a.shape[:-1])), axis=-1)
+    got = lm._unit_lower_inverse_pallas(a, dtype, decay, interpret=True)
+    assert_close(got, lm._unit_lower_inverse_jnp(a, dtype, decay), tol=1e-6)
+    system = jnp.eye(128) + lm._chunk_system(a, decay)
+    assert_close(got, jnp.linalg.inv(system), tol=2e-4 if dtype == jnp.float32 else 1e-3)
+    # the inverse's own backward rule on the kernel's result against autodiff through the twin
+    # (of a, the strictly lower part is all that moves either system)
+    weigh = jax.random.normal(jax.random.key(14), a.shape)
+    wrt = (0, 1) if decayed else 0
+    got = jax.grad(lambda a, g: jnp.sum(weigh * lm.unit_lower_inverse(a, dtype, g)), argnums=wrt)(a, decay)
+    want = jax.grad(
+        lambda a, g: jnp.sum(weigh * lm._unit_lower_inverse_jnp(jnp.tril(a, -1), dtype, g)), argnums=wrt
+    )(a, decay)
+    assert_close(got, want, tol=1e-3)
+
+
+@pytest.mark.parametrize("size, kernel_calls", [(16, 0), (64, 0), (128, 1)])
+def test_only_a_chunk_that_fills_a_lane_tile_takes_the_kernel(monkeypatch, size, kernel_calls):
+    calls = []
+    kernel = lm._unit_lower_inverse_pallas
+    monkeypatch.setattr(lm, "_unit_lower_inverse_pallas", lambda *a, **k: calls.append(k) or kernel(*a, **k))
+    lm.unit_lower_inverse(lower_systems((2,), size))
+    assert calls == [{"interpret": True}] * kernel_calls  # no TPU here: the interpreter
 
 
 @pytest.mark.parametrize("band, rows", [(1024, 128), (64, 16), (64, 64)],

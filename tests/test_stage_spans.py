@@ -12,8 +12,10 @@ readers search a trace for.
 
 from __future__ import annotations
 
+import functools
 import glob
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from lakesoul_tpu.models.qwen3_next import Qwen3NextConfig
 from lakesoul_tpu.obs import SCAN_STAGES, registry, stage, stage_counts, stage_seconds
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -290,25 +293,84 @@ LM_SCOPES = {
 }
 
 
+LM_CFG = Qwen3NextConfig(
+    vocab_size=64, hidden_size=32, num_hidden_layers=4, num_attention_heads=8,
+    num_key_value_heads=1, head_dim=8, linear_num_key_heads=2, linear_num_value_heads=4,
+    linear_key_head_dim=8, linear_value_head_dim=8, num_experts=8, num_experts_per_tok=2,
+    moe_intermediate_size=16, shared_expert_intermediate_size=16, experts_held=(0, 4),
+)
+
+
 @pytest.fixture(scope="module")
 def lm_step_module() -> str:
     """The causal-LM step as a training job gets it, lowered with the
     locations that carry ``jax.named_scope``."""
-    from lakesoul_tpu.models.qwen3_next import Qwen3NextConfig
     from lakesoul_tpu.models.train import make_lm_train_state, make_lm_train_step
     from lakesoul_tpu.parallel.mesh import make_mesh
 
-    cfg = Qwen3NextConfig(
-        vocab_size=64, hidden_size=32, num_hidden_layers=4, num_attention_heads=8,
-        num_key_value_heads=1, head_dim=8, linear_num_key_heads=2, linear_num_value_heads=4,
-        linear_key_head_dim=8, linear_value_head_dim=8, num_experts=8, num_experts_per_tok=2,
-        moe_intermediate_size=16, shared_expert_intermediate_size=16, experts_held=(0, 4),
-    )
     plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
-    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
-    step = make_lm_train_step(cfg, plan, tx, shardings)
+    params, opt_state, tx, shardings = make_lm_train_state(LM_CFG, plan)
+    step = make_lm_train_step(LM_CFG, plan, tx, shardings)
     ids = jnp.zeros((2, 16), jnp.int32)
     return step.lower(params, opt_state, ids, ids).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def lm_step_compiled_for_a_v5e() -> str:
+    """The causal-LM step compiled for a described v5e (no chip: the TPU's
+    compiler is installed here), as the text the adaptor's ``scopes_of``
+    reads on the chip: the compiler inlines the calls, so an instruction's
+    ``op_name`` carries the scopes of its callers."""
+    import optax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from lakesoul_tpu.models import qwen3_next, train
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    tx = optax.adamw(1e-3)
+
+    def init(seed):
+        params = qwen3_next.init_lm_params(LM_CFG, jax.random.key(seed))
+        return params, tx.init(params)
+
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(init, np.uint32(0)),
+    )
+    ids = jax.ShapeDtypeStruct((2, 16), jnp.int32, sharding=one_chip)
+    step = train._adamw_step(functools.partial(qwen3_next.lm_loss, cfg=LM_CFG), tx)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(qwen3_next, "_on_tpu", lambda: True)  # the branch the chip takes
+        return jax.jit(step).lower(*state, ids, ids).compile().as_text()
+
+
+def test_lm_step_holds_the_chunk_inverse_kernel_under_the_gdn_scope(lm_step_compiled_for_a_v5e):
+    """A device trace names the kernel's events by its instruction, which
+    takes the kernel's name: twice a DeltaNet layer in the program (forward,
+    and the row's rematerialisation), each run once a row.
+    ``gdn_step_share_pct`` counts its time only if the step's scope map (the
+    adaptor's ``scopes_of``) charges it to ``lakesoul.lm.gdn``: the reader
+    sums that exact name, so a scope of the kernel's own would take the
+    kernel out of the metric."""
+    import importlib.util
+
+    text = lm_step_compiled_for_a_v5e
+    calls = re.findall(r"^\s*%?([\w.\-]+) = \S+ custom-call\(.*tpu_custom_call", text, re.MULTILINE)
+    assert len(calls) == 2 * LM_CFG.layer_kinds().count("gdn")
+    assert all(name.startswith("unit_lower_inverse") for name in calls), calls
+    spec = importlib.util.spec_from_file_location(
+        "qwen3_next_clm", os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")
+    )
+    adaptor = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(adaptor)
+    scope_of = adaptor.scopes_of(text)
+    assert {scope_of.get(name) for name in calls} == {"lakesoul.lm.gdn"}
+    assert sorted(set(scope_of.values())) == sorted(LM_SCOPES)  # and no scope the readers do not know
 
 
 def test_lm_step_program_name_the_device_readers_search_for(lm_step_module):

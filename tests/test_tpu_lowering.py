@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from lakesoul_tpu.annplane import ragged
+from lakesoul_tpu.models import qwen3_next
 from lakesoul_tpu.tensorplane.smoke import enumerate_pallas_kernels
 from lakesoul_tpu.vector import kernels
 
@@ -58,6 +59,15 @@ def _ragged_score(d):
     )
 
 
+def _unit_lower_inverse(d):
+    # one DeltaNet layer's chunk systems for an 8,192-token row: d is not a shape of this kernel
+    del d
+    lead, c = (64, 1, 32), qwen3_next.GDN_CHUNK
+    return jax.jit(
+        lambda a, g: qwen3_next._unit_lower_inverse_pallas(a, jnp.bfloat16, g, interpret=False)
+    ).trace(_sds((*lead, c, c)), _sds((*lead, c)))
+
+
 # keyed by lakelint device-index qname, like the smoke register
 TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_packed_scan_kernel": _packed_scan,
@@ -65,6 +75,7 @@ TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_packed_dot_batch_kernel": _packed_dot_batch,
     "lakesoul_tpu/vector/kernels.py::_bruteforce_kernel": _bruteforce,
     "lakesoul_tpu/annplane/ragged.py::_ragged_score_kernel": _ragged_score,
+    "lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel": _unit_lower_inverse,
 }
 
 
