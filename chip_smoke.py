@@ -27,7 +27,7 @@ Stages, each of which fails the run if it fails (nothing is caught):
 - ``multichip`` (four or more devices): the trainer again over
   ``make_mesh(jax.devices()[:4])`` with batch 64 under ``P("dp", "sp")``,
   then the register's multichip cases (cross-chip top-k, and the
-  dp×tp×sp / dp×pp / dp×ep dryrun).  On fewer devices the JSON says
+  dp×tp×sp / dp×pp dryrun).  On fewer devices the JSON says
   ``"not run: N device(s)"``, which is not a pass.
 
 The script has no CPU mode: it exits 2 at once, printing no result, unless

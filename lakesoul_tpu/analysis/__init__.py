@@ -36,8 +36,8 @@ Four complementary layers:
   runtime leak detector — ``LAKESOUL_LEAKCHECK=1`` patches the creation
   seams (``Thread.start``, ``Popen``, ``mkdtemp``, atomicio staging) and
   diffs per-scope fd/thread/child/artifact/heap inventories, reporting
-  each leak with its creation stack; the ``benchmarks/micro.py soak``
-  leg gates on flat slopes over repeated open→scan→serve→close cycles.
+  each leak with its creation stack; tests/test_leakcheck.py holds the
+  counts flat over repeated open→scan→serve→close cycles.
 - :mod:`lockgraph` / :mod:`tracecheck` / :mod:`racecheck` /
   :mod:`fscheck` / :mod:`txncheck` — the opt-in runtime detectors:
   ``LAKESOUL_LOCKCHECK=1`` instruments ``Lock``/``RLock`` to record the

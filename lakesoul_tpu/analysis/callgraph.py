@@ -31,8 +31,7 @@ lexical context (lock held, RBAC gate passed) being analyzed.
 
 The graph is built once per :class:`~lakesoul_tpu.analysis.engine.Project`
 and cached (``Project.callgraph()``); with ~90 files it costs one extra
-pass over the already-shared AST walks (~0.2 s, tracked by the
-``benchmarks/micro.py lint`` leg's 10 s budget).
+pass over the already-shared AST walks (~0.2 s).
 """
 
 from __future__ import annotations

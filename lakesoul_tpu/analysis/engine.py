@@ -34,7 +34,6 @@ from __future__ import annotations
 import ast
 import json
 import re
-import time
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Iterable, Iterator
@@ -267,14 +266,10 @@ def run(
     root: Path | str | None = None,
     rules: Iterable[Rule] | None = None,
     baseline: Baseline | None = None,
-    timings: "dict[str, float] | None" = None,
 ) -> tuple[list[Finding], Baseline]:
     """Analyse ``paths`` (default: the whole package) and return
     ``(unsuppressed findings, baseline)`` — the baseline is returned so
-    callers can ask it for stale entries.  Pass a dict as ``timings`` to
-    receive per-rule wall seconds (check + finalize; a shared index — call
-    graph, device index, thread roots — bills to the first rule that
-    builds it, which the lint bench leg notes when attributing cost)."""
+    callers can ask it for stale entries."""
     from lakesoul_tpu.analysis.rules import all_rules
 
     if paths is None:
@@ -289,29 +284,19 @@ def run(
         if mod is not None:
             project.modules.append(mod)
 
-    def clocked(rule_id: str, started: float) -> None:
-        if timings is not None:
-            timings[rule_id] = (
-                timings.get(rule_id, 0.0) + time.perf_counter() - started
-            )
-
     findings: list[Finding] = []
     for rule in rules:
-        t0 = time.perf_counter()
         for mod in project.modules:
             for finding in rule.check(mod):
                 if rule.id not in mod.pragma_rules(finding.line):
                     findings.append(finding)
-        clocked(rule.id, t0)
     by_rel = {m.relpath: m for m in project.modules}
     for rule in rules:
-        t0 = time.perf_counter()
         for finding in rule.finalize(project):
             mod = by_rel.get(finding.path)
             if mod is not None and rule.id in mod.pragma_rules(finding.line):
                 continue
             findings.append(finding)
-        clocked(rule.id, t0)
 
     findings = [f for f in findings if not baseline.suppresses(f)]
     findings.sort(key=lambda f: (f.path, f.line, f.rule))
@@ -320,13 +305,11 @@ def run(
 
 def run_repo(
     baseline_path: Path | str | None = "default",
-    *,
-    timings: "dict[str, float] | None" = None,
 ) -> tuple[list[Finding], Baseline]:
     """The CI-gate entry point: whole package, checked-in baseline."""
     if baseline_path == "default":
         baseline_path = default_baseline_path()
-    return run(baseline=Baseline.load(baseline_path), timings=timings)
+    return run(baseline=Baseline.load(baseline_path))
 
 
 # ----------------------------------------------------------- shared AST utils

@@ -22,8 +22,8 @@ a :class:`Violation` per leaked resource, each with its creation stack
 when the seam saw it.  The :class:`scope` context manager snapshots on
 enter and diffs on exit; the conftest autouse fixture wraps each armed
 test in one (test_runtime, test_scanplane, test_fleet, test_resilience,
-test_freshness), and the ``benchmarks/micro.py soak`` leg wraps whole
-open→scan→serve→close cycles.
+test_freshness), and tests/test_leakcheck.py holds fd, thread and child
+counts flat over repeated open→scan→serve→close cycles.
 
 Violations are *recorded*, not raised — same contract as lockgraph:
 instrumentation must not change data-path behavior; the fixture fails
@@ -203,7 +203,7 @@ class Snapshot:
     """One resource inventory.  ``fd_targets`` maps fd → readlink target
     for post-hoc attribution; ``heap`` is the tracemalloc-traced current
     bytes (None when tracing is off — tracing is the caller's choice, the
-    soak leg turns it on, the per-test fixture does not pay for it)."""
+    per-test fixture does not pay for it)."""
 
     fds: frozenset
     fd_targets: "dict[int, str]" = field(compare=False, default_factory=dict)

@@ -2,7 +2,8 @@
 inside the ordered data path.
 
 The runtime pipeline promises byte-identical output between serial and
-pipelined execution (benchmarks/micro.py asserts it).  ``time.time()`` is
+pipelined execution (tests/test_runtime.py::TestScanDeterminism asserts
+it).  ``time.time()`` is
 not monotonic (NTP steps break stage deadlines and latency math — use
 ``time.monotonic()`` / ``time.perf_counter()``) and the module-global
 ``random.*`` RNG draws depend on scheduling order across worker threads —

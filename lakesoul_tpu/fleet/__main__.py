@@ -7,15 +7,15 @@ what is tested is what deploys):
   backlog (plus the obs fleet's merged SLO view when armed) and sizes a
   scanplane worker fleet between ``--min/--max``.  Every action is one
   JSON line on stdout (``{"event": "spawn", "pid": ...}``) so a parent —
-  bench, chaos test, operator tooling — can watch spawns, takeovers and
+  chaos test, operator tooling — can watch spawns, takeovers and
   backfills without scraping logs.
 - ``train``: one emulated training host.  Resolves its position on the
   data axis (``LAKESOUL_FLEET_PROCESS_INDEX``/``_COUNT``, else jax's
   view), consumes its shard through ``to_jax_iter(multihost=True)`` —
   optionally via a scanplane gateway — and prints ``{rows, batches,
   sha256, ...}`` hashed over the collated host arrays, the per-rank
-  identity oracle the fleet bench compares against single-process shard
-  scans.
+  identity oracle tests/test_fleet_chaos.py compares against single-process
+  shard scans.
 """
 
 from __future__ import annotations
@@ -95,7 +95,6 @@ def _cmd_train(args) -> int:
     digest = hashlib.sha256()
     rows = 0
     batches = 0
-    started_unix = time.time()
     start = time.perf_counter()
     with span("fleet.train.consume", table=args.table, rank=index):
         it = scan.to_jax_iter(
@@ -110,20 +109,12 @@ def _cmd_train(args) -> int:
             # is the byte-identity oracle
             rows += digest_batch(digest, batch)
             batches += 1
-            if args.step_s:
-                # emulated per-batch training step: the host's devices are
-                # busy for a fixed wall slice, the realistic consumption
-                # shape the fleet bench scales against (N hosts each step
-                # over their OWN shard concurrently)
-                time.sleep(args.step_s)
     elapsed = time.perf_counter() - start
     print(json.dumps({
         "rows": rows,
         "batches": batches,
         "sha256": digest.hexdigest(),
         "elapsed_s": round(elapsed, 4),
-        "started_unix": started_unix,
-        "ended_unix": time.time(),
         "process_index": index,
         "process_count": count,
         "local_devices": local_devices,
@@ -164,9 +155,6 @@ def main(argv=None) -> int:
                     help="scanplane gateway; omit to decode in-process")
     pt.add_argument("--device-put", action="store_true",
                     help="move batches to device (default: host arrays)")
-    pt.add_argument("--step-s", type=float, default=0.0,
-                    help="emulated per-batch training-step seconds (bench"
-                         " knob: makes consumption device-bound)")
     pt.set_defaults(fn=_cmd_train)
 
     args = p.parse_args(argv)

@@ -440,5 +440,5 @@ class WorkerAutoscaler:
 
 def emit_jsonl(event: dict) -> None:
     """The ``__main__`` role's event sink: one JSON line per action, so a
-    bench/chaos parent can watch spawns and takeovers on stdout."""
+    chaos-suite or operator parent can watch spawns and takeovers on stdout."""
     print(json.dumps(event, sort_keys=True), flush=True)

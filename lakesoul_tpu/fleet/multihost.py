@@ -9,15 +9,15 @@ scanplane delivery, replay cache) sees a plain sharded scan:
 - ranks are **disjoint** and their union is **complete** (the unit
   assignment is round-robin over the deterministic plan order);
 - the per-rank stream is byte-identical to a single-process
-  ``scan.shard(rank, world)`` — the property the fleet bench asserts
-  per rank with sha256 oracles;
+  ``scan.shard(rank, world)`` — the property tests/test_fleet_chaos.py
+  asserts per rank with sha256 oracles;
 - the device-replay cache bills only the local shard (it meters via
   ``sharding.shard_shape``, which already accounts per-device slices).
 
 The axis comes from ``jax.process_index()/process_count()`` on a real
 multi-host mesh.  ``LAKESOUL_FLEET_PROCESS_INDEX`` /
 ``LAKESOUL_FLEET_PROCESS_COUNT`` override it — the emulation hook the
-bench and chaos suites use to run N "hosts" as N processes on one
+chaos suite uses to run N "hosts" as N processes on one
 machine, and an escape hatch for launchers that know the topology before
 jax does.
 """

@@ -27,7 +27,7 @@ full concurrent workload.  This package closes that gap:
   continuous training source.
 - ``python -m lakesoul_tpu.freshness writer`` — the real CDC-writer
   process role of the three-role chaos harness
-  (tests/test_freshness_chaos.py, ``benchmarks/micro.py freshness``):
+  (tests/test_freshness_chaos.py):
   writer + leased compactor + follower trainer run as real processes, the
   compactor is SIGKILLed mid-run and flaky-store faults injected, and the
   run must hold BOTH the freshness SLO and the throughput SLO with the

@@ -1,7 +1,7 @@
 """``python -m lakesoul_tpu.freshness`` — freshness-harness process roles.
 
 ``writer`` is the real CDC-ingest process of the three-role chaos harness
-(tests/test_freshness_chaos.py, ``benchmarks/micro.py freshness``): it
+(tests/test_freshness_chaos.py): it
 streams checkpointed upserts into a CDC table at a declared cadence and
 prints an **oracle** JSON line the follower's delivery is judged against —
 total rows, a sha256 over the sorted ``(seq, id, v)`` tuples (delivery

@@ -20,8 +20,8 @@ guess.  Two monitors:
 
 Percentiles: the registry histogram gives every /metrics consumer the
 bucket-estimated quantiles (``Histogram.quantile``); the monitor
-additionally keeps a bounded reservoir of RAW latencies so the bench/chaos
-legs publish exact p50/p99 for the committed BENCH trajectory.
+additionally keeps a bounded reservoir of RAW latencies so the chaos
+suite judges the SLO on exact p50/p99.
 """
 
 from __future__ import annotations

@@ -382,7 +382,7 @@ class DiskPageCache:
             return
 
         def _cleanup_if_cancelled(f) -> None:
-            # a pool shutdown (shutdown_pool between bench legs, tests) can
+            # a pool shutdown (shutdown_pool between tests) can
             # cancel the task before it runs: its finally never fires, so
             # the dedup entries and gauge must be released here or these
             # pages would never prefetch again

@@ -34,11 +34,6 @@ class BertConfig:
     ff: int = 3072
     max_len: int = 512
     dtype: str = "bfloat16"
-    # MoE: n_experts > 0 swaps every FFN for a top-1 Switch MoE layer
-    # (parallel/moe.py) with experts sharded over the 'ep' mesh axis
-    n_experts: int = 0
-    capacity_factor: float = 1.25
-    moe_aux_weight: float = 0.01
 
     @staticmethod
     def base() -> "BertConfig":
@@ -73,18 +68,11 @@ def init_bert_params(cfg: BertConfig, key: jax.Array) -> dict:
         "wo": norm(ks[3], (L, h, h)),
         "ln1": {"scale": jnp.ones((L, h)), "bias": jnp.zeros((L, h))},
         "ln2": {"scale": jnp.ones((L, h)), "bias": jnp.zeros((L, h))},
+        "w1": norm(ks[4], (L, h, f)),
+        "w2": norm(ks[5], (L, f, h)),
+        "b1": jnp.zeros((L, f)),
+        "b2": jnp.zeros((L, h)),
     }
-    if cfg.n_experts:
-        from lakesoul_tpu.parallel.moe import init_moe_ffn_params
-
-        layers["moe"] = init_moe_ffn_params(ks[4], L, h, f, cfg.n_experts, std=std)
-    else:
-        layers.update(
-            w1=norm(ks[4], (L, h, f)),
-            w2=norm(ks[5], (L, f, h)),
-            b1=jnp.zeros((L, f)),
-            b2=jnp.zeros((L, h)),
-        )
     params = {
         "tok_emb": norm(k_emb, (cfg.vocab_size, h)),
         "pos_emb": norm(k_pos, (cfg.max_len, h)),
@@ -96,10 +84,10 @@ def init_bert_params(cfg: BertConfig, key: jax.Array) -> dict:
     return params
 
 
-def param_sharding_rules(plan, *, n_experts: int = 0) -> dict:
+def param_sharding_rules(plan) -> dict:
     """PartitionSpecs per parameter path for a MeshPlan: FFN and QKV/out
     projections tensor-sharded over 'tp' (Megatron column/row split),
-    embeddings replicated; with MoE, expert weights sharded over 'ep'."""
+    embeddings replicated."""
     layers = {
         "wq": P(None, None, "tp"),
         "wk": P(None, None, "tp"),
@@ -107,18 +95,11 @@ def param_sharding_rules(plan, *, n_experts: int = 0) -> dict:
         "wo": P(None, "tp", None),
         "ln1": {"scale": P(), "bias": P()},
         "ln2": {"scale": P(), "bias": P()},
+        "w1": P(None, None, "tp"),
+        "w2": P(None, "tp", None),
+        "b1": P(None, "tp"),
+        "b2": P(None, None),
     }
-    if n_experts:
-        from lakesoul_tpu.parallel.moe import moe_param_rules
-
-        layers["moe"] = moe_param_rules()
-    else:
-        layers.update(
-            w1=P(None, None, "tp"),
-            w2=P(None, "tp", None),
-            b1=P(None, "tp"),
-            b2=P(None, None),
-        )
     rules = {
         "tok_emb": P(),
         "pos_emb": P(),
@@ -148,13 +129,12 @@ def default_attention(q, k, v, mask):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v, preferred_element_type=jnp.float32).astype(v.dtype)
 
 
-def bert_layer(x, lp, attn_mask, *, cfg: BertConfig, attention_fn=None,
-               moe_ep_sharding=None):
-    """One pre-LN transformer block: x [B, T, h] → (x, aux_loss).
+def bert_layer(x, lp, attn_mask, *, cfg: BertConfig, attention_fn=None):
+    """One pre-LN transformer block: x [B, T, h] → x.
 
     Module-level (not a closure) so the pipeline-parallel path
     (parallel/pipeline.py stages) applies the same block the lax.scan
-    encoder does.  aux_loss is the MoE load-balancing term (0 for dense)."""
+    encoder does."""
     dtype = jnp.dtype(cfg.dtype)
     B, T = x.shape[0], x.shape[1]
     H, D = cfg.heads, cfg.head_dim
@@ -168,21 +148,8 @@ def bert_layer(x, lp, attn_mask, *, cfg: BertConfig, attention_fn=None,
     a = a.transpose(0, 2, 1, 3).reshape(B, T, cfg.hidden)
     x = x + (a @ lp["wo"].astype(dtype))
     y = _layer_norm(x, lp["ln2"]["scale"], lp["ln2"]["bias"])
-    if cfg.n_experts:
-        from lakesoul_tpu.parallel.moe import moe_ffn
-
-        m = lp["moe"]
-        out, aux = moe_ffn(
-            y.reshape(B * T, cfg.hidden),
-            m["gate_w"], m["w1"], m["b1"], m["w2"], m["b2"],
-            capacity_factor=cfg.capacity_factor, ep_sharding=moe_ep_sharding,
-        )
-        x = x + out.reshape(B, T, cfg.hidden)
-    else:
-        hdn = jax.nn.gelu(y @ lp["w1"].astype(dtype) + lp["b1"].astype(dtype))
-        x = x + (hdn @ lp["w2"].astype(dtype) + lp["b2"].astype(dtype))
-        aux = jnp.float32(0.0)
-    return x, aux
+    hdn = jax.nn.gelu(y @ lp["w1"].astype(dtype) + lp["b1"].astype(dtype))
+    return x + (hdn @ lp["w2"].astype(dtype) + lp["b2"].astype(dtype))
 
 
 def bert_embed(params, input_ids, *, cfg: BertConfig) -> jax.Array:
@@ -208,10 +175,8 @@ def bert_encode(
     *,
     cfg: BertConfig,
     attention_fn=None,
-    moe_ep_sharding=None,
 ):
-    """Encoder forward → (final hidden states [B, T, h], summed MoE
-    load-balancing loss)."""
+    """Encoder forward → final hidden states [B, T, h]."""
     B, T = input_ids.shape
     if attn_mask is None:
         attn_mask = jnp.ones((B, T), dtype=bool)
@@ -220,14 +185,11 @@ def bert_encode(
 
     x = bert_embed(params, input_ids, cfg=cfg)
 
-    def layer(carry, lp):
-        x, aux = carry
-        x, a = bert_layer(x, lp, attn_mask, cfg=cfg, attention_fn=attention_fn,
-                          moe_ep_sharding=moe_ep_sharding)
-        return (x, aux + a), None
+    def layer(x, lp):
+        return bert_layer(x, lp, attn_mask, cfg=cfg, attention_fn=attention_fn), None
 
-    (x, aux), _ = jax.lax.scan(layer, (x, jnp.float32(0.0)), params["layers"])
-    return x, aux
+    x, _ = jax.lax.scan(layer, x, params["layers"])
+    return x
 
 
 def bert_forward(
@@ -237,20 +199,13 @@ def bert_forward(
     *,
     cfg: BertConfig,
     attention_fn=None,
-    moe_ep_sharding=None,
-    with_aux: bool = False,
 ):
-    """Encoder forward → MLM logits [B, T, vocab] (or (logits, aux) with
-    ``with_aux`` — aux is the summed MoE load-balancing loss).
+    """Encoder forward → MLM logits [B, T, vocab].
 
     ``attention_fn(q, k, v, mask)`` defaults to plain full attention;
     pass ``make_ring_attention(mesh)`` for sequence parallelism."""
-    x, aux = bert_encode(
-        params, input_ids, attn_mask, cfg=cfg, attention_fn=attention_fn,
-        moe_ep_sharding=moe_ep_sharding,
-    )
-    logits = bert_head(params, x)
-    return (logits, aux) if with_aux else logits
+    x = bert_encode(params, input_ids, attn_mask, cfg=cfg, attention_fn=attention_fn)
+    return bert_head(params, x)
 
 
 # ---------------------------------------------------------------- MLM loss
@@ -395,18 +350,11 @@ def bert_mlm_loss(
     *,
     cfg: BertConfig,
     attention_fn=None,
-    moe_ep_sharding=None,
     batch_sharding=None,
     with_head_positions: bool = False,
 ):
-    """Masked-LM loss: labels == -100 are ignored.  With MoE configs the
-    Switch load-balancing auxiliary joins at cfg.moe_aux_weight.  With
+    """Masked-LM loss: labels == -100 are ignored.  With
     ``with_head_positions`` → (loss, positions the head ran at)."""
-    x, aux = bert_encode(
-        params, input_ids, attn_mask, cfg=cfg, attention_fn=attention_fn,
-        moe_ep_sharding=moe_ep_sharding,
-    )
+    x = bert_encode(params, input_ids, attn_mask, cfg=cfg, attention_fn=attention_fn)
     loss, positions = mlm_head_loss(params, x, labels, batch_sharding=batch_sharding)
-    if cfg.n_experts:
-        loss = loss + cfg.moe_aux_weight * aux
     return (loss, positions) if with_head_positions else loss

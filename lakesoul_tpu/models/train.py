@@ -200,7 +200,7 @@ class _CountedStep:
 
 def make_bert_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0):
     """Initialize (params, opt_state) laid out on the mesh."""
-    rules = param_sharding_rules(plan, n_experts=cfg.n_experts)
+    rules = param_sharding_rules(plan)
     return _init_train_state(
         functools.partial(init_bert_params, cfg), plan.mesh,
         _specs_to_shardings(plan.mesh, rules), lr, seed,
@@ -253,11 +253,6 @@ def make_bert_train_step(
     batch_sharding = NamedSharding(plan.mesh, P("dp", "sp"))
     loss_fn = functools.partial(
         bert_mlm_loss, cfg=cfg, attention_fn=attention_fn,
-        # the ep constraint routes MoE dispatch over the expert axis; on an
-        # ep=1 mesh it is skipped (nothing to route)
-        moe_ep_sharding=(
-            NamedSharding(plan.mesh, P("ep", None, None)) if plan.ep > 1 else None
-        ),
         batch_sharding=batch_sharding, with_head_positions=True,
     )
     return _CountedStep(
@@ -332,16 +327,10 @@ def make_bert_pipeline_train_state(cfg: BertConfig, plan: MeshPlan, *, lr: float
     the memory win pipelining exists for), everything else as usual."""
     if cfg.layers % max(plan.pp, 1):
         raise ValueError(f"{cfg.layers} layers do not split over pp={plan.pp}")
-    if cfg.n_experts:
-        # MoE composes with dp/tp/sp/ep meshes (make_bert_train_state); a
-        # pipelined MoE stage would silently all-gather every expert into
-        # every stage, so reject rather than run the degraded layout
-        raise ValueError("pipeline layout does not support MoE configs")
-    rules = param_sharding_rules(plan, n_experts=cfg.n_experts)
+    rules = param_sharding_rules(plan)
     for leaf in ("wq", "wk", "wv", "wo", "w1", "w2", "b1", "b2"):
-        if leaf in rules["layers"]:
-            spec = rules["layers"][leaf]
-            rules["layers"][leaf] = P("pp", *spec[1:])
+        spec = rules["layers"][leaf]
+        rules["layers"][leaf] = P("pp", *spec[1:])
     for ln in ("ln1", "ln2"):
         rules["layers"][ln] = {"scale": P("pp", None), "bias": P("pp", None)}
     return _init_train_state(
@@ -370,8 +359,7 @@ def make_bert_pipeline_train_step(
 
     def stage_fn(stage_layers, inp):
         def one(x, lp):
-            x, _ = bert_layer(x, lp, inp["mask"] != 0, cfg=cfg, moe_ep_sharding=None)
-            return x, None
+            return bert_layer(x, lp, inp["mask"] != 0, cfg=cfg), None
 
         x, _ = jax.lax.scan(one, inp["x"], stage_layers)
         return {"x": x, "mask": inp["mask"]}

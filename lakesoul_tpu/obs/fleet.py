@@ -284,8 +284,7 @@ def _write_atomic(path: str, doc: dict) -> None:
     # lazy import — obs must stay importable before the runtime package
     # (runtime.pipeline imports the obs registry back).
     # serialize first, write once: json.dump's many small stream writes
-    # cost ~4x a single f.write on span-heavy recorder docs, and flush
-    # cost is budgeted against scan wall time (obs_fleet bench leg)
+    # cost ~4x a single f.write on span-heavy recorder docs
     from lakesoul_tpu.runtime import atomicio
 
     atomicio.publish_atomic(path, json.dumps(doc))
@@ -307,8 +306,7 @@ class FleetPublisher:
     arms), then flushes every ``flush_s`` from a daemon thread; ``stop()``
     (atexit-registered by :func:`arm`) takes a final flush so a clean exit
     publishes its last state.  Flush cost is metered into
-    ``lakesoul_obs_flush_seconds`` — the obs_fleet bench leg budgets it
-    against scan wall time."""
+    ``lakesoul_obs_flush_seconds``."""
 
     def __init__(
         self,

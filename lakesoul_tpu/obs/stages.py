@@ -17,15 +17,15 @@ a guess:
 =============  ==============================================================
 
 On a compacted no-PK table the contract is DEGENERACY: ``merge`` and
-``fill`` must report ~0 — the scan is a plain decode plan.  The
-``scan_stages`` micro-benchmark leg enforces that as a budget.
+``fill`` must report ~0 — the scan is a plain decode plan
+(tests/test_scan_stages.py::TestDegeneracy holds it).
 
 Two label dimensions beyond ``stage``:
 
 - ``consumer=`` on the ``queue`` stage: with several concurrent loaders in
-  one process (a trainer fleet on one host, the scanplane bench's client
-  swarm) an unlabeled stall histogram cannot say WHICH client starved —
-  every loader tags its queue series (default ``local``).
+  one process (a trainer fleet on one host) an unlabeled stall histogram
+  cannot say WHICH client starved — every loader tags its queue series
+  (default ``local``).
 - ``worker=`` on producer stages merged from another process: a scanplane
   worker ships its per-range (sum, count) deltas with each spooled range
   and the client folds them into its own registry via :func:`stage_merge`,
@@ -33,8 +33,8 @@ Two label dimensions beyond ``stage``:
 
 Aggregation helpers (:func:`stage_seconds` / :func:`stage_counts`) sum
 across ALL series of a stage regardless of extra labels — the degeneracy
-budgets and bench breakdowns see one number per stage, the labeled series
-stay queryable for attribution.
+tests and the chip benchmark's readers see one number per stage, the
+labeled series stay queryable for attribution.
 
 Handles are memoized module-level (the registry is a process singleton).
 
@@ -153,8 +153,8 @@ def _family_series() -> list[tuple[dict, Histogram]]:
 
 def stage_seconds() -> dict[str, float]:
     """Cumulative seconds per stage since process start, summed across all
-    labeled series of each stage (bench/test helper; subtract two snapshots
-    for a leg delta)."""
+    labeled series of each stage (subtract two snapshots for a window's
+    delta)."""
     out = {s: 0.0 for s in SCAN_STAGES}
     for labels, h in _family_series():
         stage = labels.get("stage")

@@ -8,7 +8,8 @@ by its north-star consumers (ResNet-50 / BERT training, BASELINE.json):
 - ``tp``  — tensor parallel over heads / ffn
 - ``sp``  — sequence parallel (ring attention / Ulysses) for long context
 - ``pp``  — pipeline parallel over the layer stack (parallel/pipeline.py)
-- ``ep``  — expert parallel over MoE experts (parallel/moe.py)
+- ``ep``  — expert parallel: the axis the expert exchange will run over
+  (parallel/moe.py holds one chip's share; no program uses ``ep > 1`` yet)
 
 Every mesh carries all five axis names (unused axes have size 1 — free, and
 it keeps PartitionSpecs valid across configurations).  Meshes are pure

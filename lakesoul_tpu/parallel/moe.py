@@ -1,159 +1,33 @@
-"""Mixture-of-Experts FFN with expert parallelism over an ``ep`` mesh axis.
+"""Dropless top-k expert layer: one chip's share of an expert-parallel layer.
 
-Switch-Transformer-style top-1 routing expressed entirely as dense einsums
-(one-hot dispatch/combine tensors) — the TPU-native formulation: routing
-becomes MXU matmuls with static shapes, and GSPMD inserts the token
-all-to-all from the sharding constraints alone (expert axis of the dispatched
-tensors sharded over ``ep``), the same way the dp/tp collectives appear in
-models/train.py.  No data-dependent gathers, no ragged shapes.
+The router scores all ``n_experts``, every token keeps its ``top_k``, and this
+chip computes the part of the result that the experts it holds give, for
+however many assignments land on them (none to all).  Nothing has a capacity
+and nothing is dropped; what the absent experts would add is left out.  On an
+``ep > 1`` mesh the token exchange in front of it is not written yet.
 
-The reference has no model-side MoE (it's a data framework); this exists
-because the task's parallelism inventory makes expert parallelism a
-first-class axis alongside dp/tp/sp/pp, and the framework's delivery path
-must feed models sharded this way.
+The layer is three pieces and the model composes them
+(``models/qwen3_next.py: lm_layer``): :func:`route_top_k`,
+:func:`held_experts` and :func:`shared_expert`.  They stay apart because the
+model rematerialises the first and the last with its norm and leaves the
+second outside (see :func:`held_experts`).
 
-Capacity semantics follow the Switch paper: each expert processes at most
-``capacity = ceil(tokens/experts · capacity_factor)`` tokens; overflow tokens
-are dropped from the expert path (their residual stream passes through) —
-load balancing is encouraged by the standard auxiliary loss returned next to
-the output.
+Assignments are sorted by held expert and the products run one fixed tile of
+one expert's rows at a time, for as many tiles as the held assignments fill:
+the work follows the routing while every shape stays static.  A loop whose
+length depends on the data has no reverse-mode derivative, so the backward
+pass is a second loop of the same tiles under one custom_vjp.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from lakesoul_tpu.parallel.mesh import spec_axes
-
-
-def moe_capacity(n_tokens: int, n_experts: int, capacity_factor: float) -> int:
-    return max(1, math.ceil(n_tokens / n_experts * capacity_factor))
-
-
-def moe_ffn(
-    x: jax.Array,
-    gate_w: jax.Array,
-    w1: jax.Array,
-    b1: jax.Array,
-    w2: jax.Array,
-    b2: jax.Array,
-    *,
-    capacity_factor: float = 1.25,
-    ep_sharding=None,
-) -> tuple[jax.Array, jax.Array]:
-    """Top-1 MoE FFN over flattened tokens.
-
-    Shapes: x [N, h]; gate_w [h, E]; w1 [E, h, f]; b1 [E, f]; w2 [E, f, h];
-    b2 [E, h].  Returns (out [N, h], aux_loss scalar).
-
-    ``ep_sharding`` is a ``NamedSharding`` (e.g. ``NamedSharding(mesh,
-    P("ep", None, None))``) constraining the expert axis of the dispatched
-    [E, C, h] activations; None skips the constraints (single-device tests /
-    CPU reference)."""
-    N, h = x.shape
-    E = gate_w.shape[1]
-    C = moe_capacity(N, E, capacity_factor)
-
-    # ---- router (f32: tiny, and argmax/softmax stability matters)
-    logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)  # [N]
-    gate = jnp.max(probs, axis=-1)  # [N]
-
-    onehot_i = jax.nn.one_hot(expert, E, dtype=jnp.int32)  # [N, E]
-    # rank of each token within its expert (0-based), in token order —
-    # deterministic tie-breaking, like the reference Switch implementation.
-    # int32 cumsum: a float32 cumsum loses integer exactness past ~2^24
-    # tokens routed to one expert, silently corrupting keep/drop decisions
-    # (ADVICE r2); exact up to 2^31 here, cast to float only for the einsum.
-    pos_i = jnp.cumsum(onehot_i, axis=0) * onehot_i - onehot_i  # [N, E]
-    onehot = onehot_i.astype(jnp.float32)
-    keep = (pos_i < C).astype(jnp.float32) * onehot  # beyond capacity drops
-    pos_c = jax.nn.one_hot(jnp.sum(pos_i * onehot_i, axis=-1), C,
-                           dtype=jnp.float32)  # [N, C]
-    dispatch = keep[:, :, None] * pos_c[:, None, :]  # [N, E, C] 0/1
-
-    # ---- dispatch: [N, h] → [E, C, h]; sharding the E axis over ep makes
-    # GSPMD materialize this einsum as the token all-to-all over ICI
-    xin = jnp.einsum("nec,nh->ech", dispatch, x.astype(jnp.float32))
-    if ep_sharding is not None:
-        xin = jax.lax.with_sharding_constraint(xin, ep_sharding)
-    xin = xin.astype(x.dtype)
-
-    # ---- expert FFN (batched over the ep-sharded expert axis: each device
-    # runs only its local experts)
-    hdn = jax.nn.gelu(
-        jnp.einsum("ech,ehf->ecf", xin, w1.astype(x.dtype)) + b1[:, None, :].astype(x.dtype)
-    )
-    out_e = jnp.einsum("ecf,efh->ech", hdn, w2.astype(x.dtype)) + b2[:, None, :].astype(x.dtype)
-    if ep_sharding is not None:
-        out_e = jax.lax.with_sharding_constraint(out_e, ep_sharding)
-
-    # ---- combine: weighted return all-to-all back to token order
-    combine = dispatch * gate[:, None, None]  # [N, E, C]
-    out = jnp.einsum("nec,ech->nh", combine, out_e.astype(jnp.float32))
-
-    # ---- Switch aux loss: E · Σ_e (token fraction_e · mean router prob_e)
-    frac_tokens = jnp.mean(onehot, axis=0)  # [E]
-    frac_probs = jnp.mean(probs, axis=0)  # [E]
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return out.astype(x.dtype), aux
-
-
-def init_moe_ffn_params(key, n_layers: int, hidden: int, ff: int, n_experts: int,
-                        std: float = 0.02) -> dict:
-    """Stacked-per-layer MoE FFN params (the lax.scan layout bert.py uses)."""
-    ks = jax.random.split(key, 3)
-    L, E = n_layers, n_experts
-
-    def norm(k, shape):
-        return (jax.random.normal(k, shape) * std).astype(jnp.float32)
-
-    return {
-        "gate_w": norm(ks[0], (L, hidden, E)),
-        "w1": norm(ks[1], (L, E, hidden, ff)),
-        "b1": jnp.zeros((L, E, ff)),
-        "w2": norm(ks[2], (L, E, ff, hidden)),
-        "b2": jnp.zeros((L, E, hidden)),
-    }
-
-
-def moe_param_rules() -> dict:
-    """PartitionSpecs for the stacked MoE params: experts sharded over ep
-    (weights live where their tokens are dispatched to)."""
-    return {
-        "gate_w": P(),
-        "w1": P(None, "ep", None, None),
-        "b1": P(None, "ep", None),
-        "w2": P(None, "ep", None, None),
-        "b2": P(None, "ep", None),
-    }
-
-
-# ----------------------------------------------------- dropless top-k layer
-# One chip's share of an expert-parallel layer: the router scores all
-# ``n_experts``, every token keeps its ``top_k``, and this chip computes the
-# part of the result that the experts it holds give, for however many
-# assignments land on them (none to all).  Nothing has a capacity and nothing
-# is dropped; what the absent experts would add is left out.  On an ``ep > 1``
-# mesh the token exchange in front of it is not written yet.
-#
-# The layer is three pieces and the model composes them
-# (``models/qwen3_next.py: lm_layer``): :func:`route_top_k`,
-# :func:`held_experts` and :func:`shared_expert`.  They stay apart because the
-# model rematerialises the first and the last with its norm and leaves the
-# second outside (see :func:`held_experts`).
-#
-# Assignments are sorted by held expert and the products run one fixed tile of
-# one expert's rows at a time, for as many tiles as the held assignments fill:
-# the work follows the routing while every shape stays static.  A loop whose
-# length depends on the data has no reverse-mode derivative, so the backward
-# pass is a second loop of the same tiles under one custom_vjp.
 
 ROUTE_SCOPE = "lakesoul.lm.moe.route"
 EXPERTS_SCOPE = "lakesoul.lm.moe.experts"

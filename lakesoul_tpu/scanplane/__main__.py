@@ -10,8 +10,8 @@ SIGKILLs — what is tested is what deploys):
   spawns these; chaos tests and operators can run extras by hand — any
   number of workers share one spool + store).
 - ``drive``: a verification client — stream one table shard through a
-  gateway and print ``{rows, batches, sha256, elapsed_s}`` (the bench's
-  per-client child, and an ops smoke test).
+  gateway and print ``{rows, batches, sha256, elapsed_s}`` (the chaos
+  suite's per-client child, and an ops smoke test).
 """
 
 from __future__ import annotations
@@ -99,10 +99,6 @@ def _cmd_drive(args) -> int:
     digest = hashlib.sha256()
     rows = 0
     batches = 0
-    # wall-clock start/end stamps ride the output so a bench parent can
-    # compute fleet-aggregate throughput across client processes (the
-    # clocks are one host's)
-    started_unix = time.time()
     start = time.perf_counter()
     # a root span here joins the spawning parent's trace via
     # LAKESOUL_TRACE_ID (ambient), so the fleet spool sees the DELIVERY
@@ -128,8 +124,6 @@ def _cmd_drive(args) -> int:
         "batches": batches,
         "sha256": digest.hexdigest(),
         "elapsed_s": round(elapsed, 4),
-        "started_unix": started_unix,
-        "ended_unix": time.time(),
     }), flush=True)
     return 0
 

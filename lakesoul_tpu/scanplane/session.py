@@ -13,7 +13,7 @@ order.  Everything downstream is deterministic from the manifest:
   wasted work, never wrong data;
 - a client at rank *r* of *w* consumes ranges ``k % w == r`` in order,
   which is exactly ``scan.shard(r, w).to_batches()`` — the byte-identity
-  contract the bench asserts.
+  contract tests/test_scanplane.py asserts.
 
 The manifest is JSON in the spool directory, written atomically
 (tmp + ``os.replace``); the session id hashes the canonical request plus

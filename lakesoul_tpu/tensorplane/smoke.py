@@ -4,7 +4,7 @@ tensorplane delivery path, each as one runnable check.
 - a **register** of :class:`SmokeCase`\\ s — one per Pallas kernel (each
   case names the kernel functions it compiles, by lakelint device-index
   qname), one per multichip shape (the annplane cross-chip top-k merge
-  and the parallel mesh/pipeline/moe dryrun), and one per tensorplane
+  and the parallel mesh/pipeline dryrun), and one per tensorplane
   delivery/replay path;
 - :func:`enumerate_pallas_kernels` — the ground truth: lakelint's device
   index re-parses the package and lists every ``pl.pallas_call`` kernel,
@@ -270,8 +270,8 @@ def _run_cross_chip_topk() -> dict:
 
 
 def _run_parallel_dryrun() -> dict:
-    """The three parallel multichip shapes (mesh scan→train, pipeline,
-    moe) via the repo's dryrun entry: tiny models, real collectives."""
+    """The two parallel multichip shapes (mesh scan→train, pipeline) via
+    the repo's dryrun entry: tiny models, real collectives."""
     import importlib.util
     import pathlib
 
@@ -402,7 +402,7 @@ def smoke_cases() -> list[SmokeCase]:
             min_devices=2,
         ),
         SmokeCase(
-            "parallel.mesh_pipeline_moe", "multichip", _run_parallel_dryrun,
+            "parallel.mesh_pipeline", "multichip", _run_parallel_dryrun,
             min_devices=2,
         ),
         SmokeCase("tensorplane.delivery", "tensorplane", _run_delivery),

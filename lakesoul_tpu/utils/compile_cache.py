@@ -1,7 +1,7 @@
 """Where this checkout keeps JAX's persistent compilation cache.
 
-Called by each entry point that owns a process (``chip_smoke.py``, a
-``bench.py`` leg, the examples, ``fleet train``, ``__graft_entry__``),
+Called by each entry point that owns a process (``chip_smoke.py``,
+``benchmarks/chip/run.py``, the examples, ``fleet train``, ``__graft_entry__``),
 never at package import: a library must not redirect its host's cache.
 """
 

@@ -1,15 +1,10 @@
-"""Exact brute-force recall oracle, shared by autotune, tests, and benches.
+"""Exact brute-force recall oracle, shared by autotune, tests and the chip smoke.
 
 One definition of ground truth for every recall@k claim in the repo: the
-``tune_nprobe`` autotuner, the single-shard vs multi-shard parity tests, and
-the ``ann_scale`` bench leg all measure against THIS oracle, so a recall
-number from any of them means the same thing.  Two shapes:
-
-- :func:`exact_topk` — in-memory corpora: one batched gram matmul for all
-  queries (the tune_nprobe formulation, hoisted here).
-- :class:`StreamingExactOracle` — corpora too large to hold: consume
-  (vectors, ids) chunks and keep a bounded per-query best-k, so exact truth
-  over a 10M x 128d stream costs O(Q * k) memory.
+``tune_nprobe`` autotuner, the single-shard vs multi-shard parity tests and
+``chip_smoke.py`` all measure against THIS oracle, so a recall number from
+any of them means the same thing.  :func:`exact_topk` is one batched gram
+matmul for all queries (the tune_nprobe formulation, hoisted here).
 
 Recall semantics match the autotuner's: the denominator is the *achievable*
 hit count (truth sets can be smaller than k on tiny or duplicate-id
@@ -56,48 +51,3 @@ def recall_at_k(truth: list[set], got_ids) -> float:
         len(truth[i] & {int(x) for x in got_ids[i]}) for i in range(len(truth))
     )
     return hits / max(1, sum(len(t) for t in truth))
-
-
-class StreamingExactOracle:
-    """Exact top-k over a corpus streamed in chunks (bounded memory).
-
-    Holds per-query running (distances, ids) of size ``k``; each consumed
-    chunk costs one [Q, chunk] gram matmul and a k-merge.  ``truth()``
-    returns the same ``list[set]`` shape as :func:`exact_topk`."""
-
-    def __init__(self, queries: np.ndarray, k: int):
-        self.queries = np.asarray(queries, np.float32)
-        self.k = int(k)
-        self._q_sq = np.sum(self.queries**2, axis=1, keepdims=True)
-        nq = len(self.queries)
-        self._best_d = np.full((nq, self.k), np.inf, np.float32)
-        self._best_i = np.zeros((nq, self.k), np.uint64)
-        self.rows = 0
-
-    def consume(self, vectors: np.ndarray, ids: np.ndarray) -> None:
-        vectors = np.asarray(vectors, np.float32)
-        ids = np.asarray(ids, np.uint64)
-        if not len(ids):
-            return
-        d2 = (
-            self._q_sq
-            - 2.0 * self.queries @ vectors.T
-            + np.sum(vectors**2, axis=1)[None, :]
-        ).astype(np.float32)
-        cand_d = np.concatenate([self._best_d, d2], axis=1)
-        cand_i = np.concatenate(
-            [self._best_i, np.broadcast_to(ids, (len(self.queries), len(ids)))],
-            axis=1,
-        )
-        part = np.argpartition(cand_d, self.k - 1, axis=1)[:, : self.k]
-        self._best_d = np.take_along_axis(cand_d, part, axis=1)
-        self._best_i = np.take_along_axis(cand_i, part, axis=1)
-        self.rows += len(ids)
-
-    def truth(self) -> list[set]:
-        k_eff = min(self.k, self.rows)
-        out = []
-        for qi in range(len(self.queries)):
-            order = np.argsort(self._best_d[qi])[:k_eff]
-            out.append({int(x) for x in self._best_i[qi][order]})
-        return out

@@ -80,9 +80,8 @@ class AnnEndpoint:
         self._c_requests = reg.counter("lakesoul_ann_requests_total")
         self._c_rejected = reg.counter("lakesoul_ann_rejected_total")
         # latency carries an endpoint= label so stats() quantiles stay
-        # per-endpoint: several endpoints in one process (serving + overload
-        # hammer + shard sweeps in the bench) must not contaminate each
-        # other's p50/p99 through the name-keyed registry
+        # per-endpoint: several endpoints in one process must not contaminate
+        # each other's p50/p99 through the name-keyed registry
         self._h_latency = reg.histogram(
             "lakesoul_ann_request_seconds", endpoint=name
         )

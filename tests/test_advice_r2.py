@@ -352,35 +352,6 @@ class TestAsOfTimezone:
             == 1700000000000
 
 
-class TestMoeIntRanks:
-    """low: token ranks within an expert were float32-cumsum'd; exactness is
-    now int32.  Pin exact capacity keep/drop at the boundary."""
-
-    def test_capacity_boundary_exact(self):
-        import jax.numpy as jnp
-
-        from lakesoul_tpu.parallel.moe import moe_capacity, moe_ffn
-
-        N, h, E = 64, 8, 2
-        rng = np.random.default_rng(0)
-        # positive activations so every row-sum is positive → the +100 gate
-        # column routes EVERY token to expert 0
-        x = jnp.asarray(np.abs(rng.normal(size=(N, h))) + 0.1, dtype=jnp.float32)
-        gate_w = jnp.concatenate(
-            [jnp.ones((h, 1)) * 100.0, jnp.zeros((h, E - 1))], axis=1
-        ).astype(jnp.float32)
-        w1 = jnp.asarray(rng.normal(size=(E, h, 4)), dtype=jnp.float32)
-        b1 = jnp.zeros((E, 4), jnp.float32)
-        w2 = jnp.asarray(rng.normal(size=(E, 4, h)), dtype=jnp.float32)
-        b2 = jnp.zeros((E, h), jnp.float32)
-        out, _ = moe_ffn(x, gate_w, w1, b1, w2, b2, capacity_factor=0.25)
-        C = moe_capacity(N, E, 0.25)
-        nz = np.abs(np.asarray(out)).sum(axis=1) > 0
-        # exactly the first C tokens (token-order rank) pass; the rest drop
-        assert nz[:C].all()
-        assert not nz[C:].any()
-
-
 class TestExplainPruneAccounting:
     """low: buckets_pruned counted scan units; now units_pruned counts units
     and buckets_pruned counts distinct bucket ids gone entirely."""

@@ -209,3 +209,33 @@ def test_boundedness_pack_clean_repo_wide_without_baseline():
     assert len(bound) == 5
     findings, _ = run(rules=bound, baseline=Baseline([]))
     assert findings == [], "\n".join(f.render() for f in findings)
+
+
+def test_readme_env_table_names_are_all_read():
+    """The reverse of ``undocumented-env``: every ``LAKESOUL_*`` name in the
+    README's environment table (a wildcard row by its prefix) is read
+    somewhere in the package, ``chip_smoke.py`` or ``examples/``, so a row
+    cannot outlive the code that read it."""
+    import re
+
+    from lakesoul_tpu.analysis.engine import package_root
+    from lakesoul_tpu.analysis.rules.conventions import _ENV_DOC_RE
+
+    root = package_root().parent
+    sources = [*package_root().rglob("*.py"), root / "chip_smoke.py",
+               *(root / "examples").rglob("*.py")]
+    read = set()
+    for path in sources:
+        read.update(re.findall(r"LAKESOUL_[A-Z0-9_]+", path.read_text()))
+    unread = []
+    for line in (root / "README.md").read_text().splitlines():
+        if not line.startswith("| `LAKESOUL_"):
+            continue
+        for name in _ENV_DOC_RE.findall(line.split("|")[1]):
+            if name.endswith("*"):
+                # a dynamic-prefix constant ("LAKESOUL_PROXY_S3_" + key) reads the family
+                if not any(r.startswith(name[:-1]) for r in read):
+                    unread.append(name)
+            elif name not in read:
+                unread.append(name)
+    assert unread == [], f"README rows for names nothing reads: {unread}"

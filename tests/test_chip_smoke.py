@@ -97,7 +97,7 @@ def test_multichip_stage_tiny(chip_smoke):
     assert trainer["tp_param_devices"] == 4
     assert trainer["train_step_lowerings"] == 1
     assert set(out["collectives"]) == {
-        "annplane.cross_chip_topk", "parallel.mesh_pipeline_moe",
+        "annplane.cross_chip_topk", "parallel.mesh_pipeline",
     }
 
 

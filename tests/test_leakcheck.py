@@ -231,6 +231,63 @@ def test_disabled_records_nothing():
         leakcheck.reset()
 
 
+# ------------------------------------------------------ lifecycle slopes
+
+
+def test_open_scan_serve_close_cycles_hold_counts_flat(armed, tmp_path):
+    """Repeated whole lifecycles (open a catalog, scan the table, serve one
+    real ``/metrics`` scrape, shut down): the first-third against
+    last-third averages of fds, threads and tracked children must be flat.
+    A lifecycle that leaks one of them a cycle climbs by ``cycles / 3`` and
+    fails; the allowances (2 fds, 1 thread) are for the pool's lazy start."""
+    import gc
+    import urllib.request
+
+    import numpy as np
+    import pyarrow as pa
+
+    from lakesoul_tpu import LakeSoulCatalog
+    from lakesoul_tpu.obs.exporter import serve_prometheus
+
+    wh = str(tmp_path / "wh")
+    n_rows, cycles = 4_000, 9
+    table = LakeSoulCatalog(wh).create_table(
+        "soak", pa.schema([("id", pa.int64()), ("v", pa.float64())])
+    )
+    table.write_arrow(pa.table({
+        "id": np.arange(n_rows, dtype=np.int64),
+        "v": np.random.default_rng(0).normal(size=n_rows),
+    }))
+    del table
+    samples = []
+    for _ in range(cycles):
+        cat = LakeSoulCatalog(wh)
+        assert len(cat.table("soak").to_arrow()) == n_rows
+        srv = serve_prometheus(port=0, host="127.0.0.1")
+        port = srv.server_address[1]
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/metrics", timeout=10
+        ) as resp:
+            assert resp.status == 200 and resp.read()
+        srv.shutdown()
+        srv.server_close()
+        del cat, srv
+        gc.collect()
+        snap = leakcheck.snapshot()
+        samples.append((snap.fd_count, snap.thread_count, len(snap.children)))
+
+    third = cycles // 3
+
+    def slope(idx: int) -> float:
+        first = [s[idx] for s in samples[:third]]
+        last = [s[idx] for s in samples[-third:]]
+        return sum(last) / third - sum(first) / third
+
+    assert slope(0) <= 2.0, samples
+    assert slope(1) <= 1.0, samples
+    assert slope(2) <= 0.0, samples
+
+
 # ------------------------------------------- regression pins (fixed leaks)
 
 

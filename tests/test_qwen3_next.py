@@ -12,6 +12,7 @@ a masked loop), not of bfloat16.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import os
 
@@ -210,42 +211,57 @@ def _route_to(router, experts):
     return (router * 1e-3).at[0, jnp.asarray(experts)].add(5.0)
 
 
-def _expert_layer(x, p, *, held, tile=None):
+def _expert_layer(x, p, *, held, tile=None, top_k=4, n_experts=16):
     """The three pieces of ``parallel/moe.py`` as ``lm_layer`` puts them
     together (there with its norm and its rematerialisation around them)."""
-    top_e, w = moe.route_top_k(x, p["router"], top_k=4)
-    y, counts = moe.held_experts(x, top_e, w, p, n_experts=16, held=held, tile=tile)
+    top_e, w = moe.route_top_k(x, p["router"], top_k=top_k)
+    y, counts = moe.held_experts(x, top_e, w, p, n_experts=n_experts, held=held, tile=tile)
     return y + moe.shared_expert(x, p["shared"]), counts
 
 
-@pytest.mark.parametrize("routing", ["even", "all-on-one-held", "none-held"])
+@pytest.mark.parametrize("routing", ["even", "all-on-one-held", "none-held", "top-1", "one-of-one"])
 def test_expert_layer_equals_the_loop_over_experts(params, routing):
     p = dict(params["layers"][0]["moe"])
+    model, held, top_k = MODEL, HELD, 4
     if routing == "all-on-one-held":  # expert 5 takes every token, its three companions are not held
         p["router"] = _route_to(p["router"], [5, 0, 1, 2])
     elif routing == "none-held":
         p["router"] = _route_to(p["router"], [0, 1, 2, 3])
+    elif routing == "top-1":  # a token's one expert, its weight 1 after the division
+        model, top_k = MODEL | {"num_experts_per_tok": 1}, 1
+    elif routing == "one-of-one":  # one expert, held: the layer is the dense SwiGLU
+        model, held, top_k = MODEL | {"num_experts": 1, "num_experts_per_tok": 1}, (0, 1), 1
+        p["router"] = p["router"][:, :1]
+        p.update({k: p[k][:1] for k in ("w_gate", "w_up", "w_down")})
     x = hidden(7).at[..., 0].set(10.0)
     weigh = jax.random.normal(jax.random.key(8), x.shape)
+    layer = functools.partial(_expert_layer, held=held, tile=16, top_k=top_k, n_experts=model["num_experts"])
 
     def program(p, x):
-        return jnp.sum(weigh * _expert_layer(x, p, held=HELD, tile=16)[0])
+        return jnp.sum(weigh * layer(x, p)[0])
 
     def plain(p, x):
-        return jnp.sum(weigh * ref.moe(x, p, MODEL, HELD))
+        return jnp.sum(weigh * ref.moe(x, p, model, held))
 
-    y, counts = _expert_layer(x, p, held=HELD, tile=16)
-    assert_close(y, ref.moe(x, p, MODEL, HELD))
-    assert_close(jax.grad(program, argnums=(0, 1))(p, x), jax.grad(plain, argnums=(0, 1))(p, x))
+    y, counts = layer(x, p)
+    assert_close(y, ref.moe(x, p, model, held))
+    got, want = jax.grad(program, argnums=(0, 1))(p, x), jax.grad(plain, argnums=(0, 1))(p, x)
+    if top_k == 1:  # a lone weight is p / p: the router's gradient is zero, not a number to compare
+        for grads in (got, want):
+            assert float(jnp.max(jnp.abs(grads[0].pop("router")))) < 1e-5
+    assert_close(got, want)
     n = B * T
-    assert int(counts["moe_all"]) == 4 * n
-    if routing == "all-on-one-held":
+    assert int(counts["moe_all"]) == top_k * n
+    if routing in ("all-on-one-held", "one-of-one"):
         assert (int(counts["moe_held"]), int(counts["moe_load_max"])) == (n, n)
     elif routing == "none-held":
         assert (int(counts["moe_held"]), int(counts["moe_load_max"])) == (0, 0)
         assert_close(y, ref.shared_expert(x, p["shared"]))
     else:
-        assert 0 < int(counts["moe_load_max"]) < int(counts["moe_held"]) < 4 * n
+        assert 0 < int(counts["moe_load_max"]) < int(counts["moe_held"]) < top_k * n
+    if routing == "one-of-one":
+        dense = ref.swiglu(x, p["w_gate"][0], p["w_up"][0], p["w_down"][0])
+        assert_close(y, dense + ref.shared_expert(x, p["shared"]))
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer(params):

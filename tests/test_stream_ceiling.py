@@ -4,7 +4,7 @@ Build and scan run in SEPARATE subprocesses: the scan process's own peak
 RSS is the measurement, so writer/generator buffers cannot pollute the
 read-path assertion.  If the read path ever regressed to materializing
 units, the scan subprocess footprint would blow straight past the
-ceiling.  (bench.py's `stream` leg runs the same check at ≥100M-row scale.)
+ceiling.
 
 Deflaked (PR 7 satellite).  The old flake — passed in isolation, tripped
 only during a busy full run — looked load-sensitive but was not: the scan

@@ -334,28 +334,54 @@ class TestDlpackDelivery:
 # ---------------------------------------------------------------- replay
 
 
+def _dp_sharding(n_dev: int):
+    """None for one device, else rows split over a ``dp`` axis of the CPU
+    mesh: the residency budget is per device, so each holds 1/n of a batch."""
+    if n_dev == 1:
+        return None
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    return NamedSharding(Mesh(np.array(jax.devices()[:n_dev]), ("dp",)), P("dp"))
+
+
 class TestReplayCache:
-    def test_epoch2_byte_identical_to_epoch1(self, tensor_lsf_table):
-        it = tensor_lsf_table.scan().batch_size(256).to_jax_iter(cache="device")
+    @pytest.mark.parametrize("n_dev", [1, 8], ids=["one-device", "dp8"])
+    def test_epoch2_byte_identical_to_epoch1(self, tensor_lsf_table, n_dev):
+        sharding = _dp_sharding(n_dev)
+        it = tensor_lsf_table.scan().batch_size(256).to_jax_iter(
+            cache="device", sharding=sharding
+        )
         e1 = read_epoch(it)
         st = it.stats()["replay"]
         assert st["ready"] and not st["spilled"]
         assert st["resident_rows"] == 2048 and st["resident_batches"] == 8
-        e2 = read_epoch(it)
+        replayed = list(it)
+        if sharding is not None:  # a replayed leaf stays where it was pinned
+            leaf = replayed[0]["emb"]
+            assert leaf.sharding.is_equivalent_to(sharding, leaf.ndim)
+        e2 = read_epoch(replayed)
         assert_epochs_byte_identical(e1, e2)
         assert e2[0]["emb"].shape == (256,) + SHAPE  # declared shape on device
         # epoch 3 still replays (and still matches)
         assert_epochs_byte_identical(e1, read_epoch(it))
+        # and equals a loader that never cached
+        assert_epochs_byte_identical(e1, read_epoch(
+            tensor_lsf_table.scan().batch_size(256).to_jax_iter(sharding=sharding)
+        ))
 
-    def test_budget_overflow_spills_typed_and_metered(self, tensor_lsf_table):
+    @pytest.mark.parametrize("n_dev", [1, 8], ids=["one-device", "dp8"])
+    def test_budget_overflow_spills_typed_and_metered(self, tensor_lsf_table, n_dev):
         from lakesoul_tpu.obs import registry
 
-        per_batch = 256 * (WIDTH * 4 + 4 + 4)  # f32 emb + demoted id + label
+        # f32 emb + demoted id + label, the share one device holds
+        per_batch = 256 * (WIDTH * 4 + 4 + 4) // n_dev
         spill_before = registry().counter(
             "lakesoul_replay_spilled_batches_total"
         ).value
         it = tensor_lsf_table.scan().batch_size(256).to_jax_iter(
-            cache="device", replay_budget_bytes=3 * per_batch + 64
+            cache="device", replay_budget_bytes=3 * per_batch + 64,
+            sharding=_dp_sharding(n_dev),
         )
         e1 = read_epoch(it)
         st = it.stats()["replay"]
