@@ -298,7 +298,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     """Jitted next-token train step: (params, opt_state, input_ids, labels) →
     (params, opt_state, loss); rows arrive sharded P('dp').  Feeds
     ``lakesoul_train_tokens_total``, ``lakesoul_train_moe_assignments_total
-    {kind="held"|"all"}`` and ``lakesoul_train_moe_expert_load
+    {kind="held"|"all"|"tile_rows"}`` (``tile_rows``: the slots of the expert
+    tiles run, of which ``held`` carried an assignment) and ``lakesoul_train_moe_expert_load
     {stat="max"|"mean"}`` (the fullest and the mean held expert's assignments,
     summed over steps and layers)."""
     from lakesoul_tpu.models.qwen3_next import lm_loss
@@ -312,6 +313,7 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("tokens", TOKENS_FAMILY, {}, 1),
         ("moe_held", MOE_ASSIGNMENTS_FAMILY, {"kind": "held"}, 1),
         ("moe_all", MOE_ASSIGNMENTS_FAMILY, {"kind": "all"}, 1),
+        ("moe_tile_rows", MOE_ASSIGNMENTS_FAMILY, {"kind": "tile_rows"}, 1),
         ("moe_load_max", MOE_LOAD_FAMILY, {"stat": "max"}, 1),
         ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / cfg.experts_held[1]),
     )

@@ -17,6 +17,25 @@ one expert's rows at a time, for as many tiles as the held assignments fill:
 the work follows the routing while every shape stays static.  A loop whose
 length depends on the data has no reverse-mode derivative, so the backward
 pass is a second loop of the same tiles under one custom_vjp.
+
+How a tile's rows move.  A tile reads the rows of its tokens out of ``x`` (and
+``dy``) by indexing: XLA's row gather runs near the memory's speed on a v5e
+(8.5 us for 512 rows of 4 KB).  Its scatter-add does not: adding a tile's rows
+into the float32 sums over all tokens took 134 us a tile, half the loop's time
+(PERF.md section 6, PR 31).  So where a row of the sums is whole lane tiles
+(``h % 128 == 0``) the sums are carried as ``[N, 1, h]`` and a tile is added by
+two Pallas kernels around a dense sum, :func:`take_rows` and :func:`put_rows`:
+one DMA a row from HBM to HBM, the index read from SMEM, all of a tile's copies
+in flight at once and only the slots that hold an assignment moved (22 us a
+tile for the read, the sum and the write).  The middle axis is what lets a DMA take one row: Mosaic refuses a
+one-row slice of a two-dimensional array on a v5e (a slice of the second to
+last axis must be a multiple of its tiling, 8 rows of 32-bit words), while
+XLA lays ``[N, 1, h]`` out a row a tile (``T(1,128)``), rows contiguous and
+unpadded.  16-bit rows would have to travel as pairs in 32-bit words (their
+tiling is 16 rows); nothing here moves any, the sums are float32.  Narrower
+rows, as most tests use, keep ``.at[].add``, which stays as the kernels' twin:
+on a CPU (the kernels in the Pallas interpreter) and on a v5e the two paths
+give the same bits.
 """
 
 from __future__ import annotations
@@ -25,9 +44,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from lakesoul_tpu.parallel.mesh import spec_axes
+from lakesoul_tpu.vector.kernels import _on_tpu
 
 ROUTE_SCOPE = "lakesoul.lm.moe.route"
 EXPERTS_SCOPE = "lakesoul.lm.moe.experts"
@@ -66,7 +88,7 @@ def _tile_plan(local, count: int, tile: int):
 
 def _tile_rows(t, plan, tile: int, k: int):
     """Tile ``t`` → (its expert, assignment of each row, token of each row,
-    which rows hold an assignment, first row in ``order``)."""
+    which rows hold an assignment: a prefix, first row in ``order``)."""
     order, sizes, starts, tile_ends = plan
     e = jnp.searchsorted(tile_ends, t, side="right").astype(jnp.int32)
     row0 = starts[e] + (t - (tile_ends[e] - (sizes[e] + tile - 1) // tile)) * tile
@@ -74,6 +96,89 @@ def _tile_rows(t, plan, tile: int, k: int):
     valid = rows < starts[e] + sizes[e]
     a = order[jnp.minimum(rows, order.shape[0] - 1)]
     return e, a, a // k, valid, row0
+
+
+# ---------------------------------------------------------- rows by index
+
+
+def _copy_rows(n, copy):
+    """Start ``copy(i)`` for every i < n, then wait for as many: the copies
+    share one semaphore and move a row's bytes each, whichever row.  Plain
+    loops: eight starts a pass took 3 us off a call of 300 rows (0.4% of the
+    LM cell's step) and cost 0.6 s of tracing and lowering in every process
+    that builds the step (PERF.md section 6, PR 31)."""
+    jax.lax.fori_loop(0, n, lambda i, carry: (copy(i).start(), carry)[1], 0)
+    jax.lax.fori_loop(0, n, lambda i, carry: (copy(0).wait(), carry)[1], 0)
+
+
+def _take_rows_kernel(idx_ref, n_ref, src_ref, out_ref, sem):
+    _copy_rows(n_ref[0], lambda i: pltpu.make_async_copy(
+        src_ref.at[pl.ds(idx_ref[i], 1)], out_ref.at[pl.ds(i, 1)], sem))
+
+
+def _put_rows_kernel(idx_ref, n_ref, dst_in_ref, rows_ref, dst_ref, sem):
+    del dst_in_ref  # the same buffer as dst_ref
+    _copy_rows(n_ref[0], lambda i: pltpu.make_async_copy(
+        rows_ref.at[pl.ds(i, 1)], dst_ref.at[pl.ds(idx_ref[i], 1)], sem))
+
+
+def _row_copy_grid(n_arrays: int):
+    """One grid step with the indices and the count in SMEM and every array
+    left where it is, in HBM, and one semaphore for all of a call's copies."""
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(1,), in_specs=[anywhere] * n_arrays, out_specs=anywhere,
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+    )
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def take_rows(src, idx, n, *, interpret: bool):
+    """``src`` [N, 1, w] float32, ``idx`` [tile] int32, ``n`` a scalar →
+    [tile, 1, w] whose first ``n`` rows are ``src[idx[:n]]``; the rest is
+    whatever the buffer held.  One DMA a row from HBM to HBM, all in flight."""
+    return pl.pallas_call(
+        _take_rows_kernel,
+        out_shape=jax.ShapeDtypeStruct((idx.shape[0], *src.shape[1:]), src.dtype),
+        grid_spec=_row_copy_grid(1), name="take_rows", interpret=interpret,
+    )(idx, jnp.reshape(n, (1,)).astype(jnp.int32), src)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def put_rows(dst, idx, n, rows, *, interpret: bool):
+    """``dst`` with ``dst[idx[:n]] = rows[:n]``, written in place where the
+    caller lets go of ``dst``; ``idx[:n]`` repeats no row.  The slots from
+    ``n`` on are not written, whatever their index."""
+    return pl.pallas_call(
+        _put_rows_kernel,
+        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
+        grid_spec=_row_copy_grid(2), input_output_aliases={2: 0}, name="put_rows", interpret=interpret,
+    )(idx, jnp.reshape(n, (1,)).astype(jnp.int32), dst, rows)
+
+
+def _rows_by_dma(x) -> bool:
+    """Whether a float32 row as wide as x's [N, h] is whole 128-lane tiles."""
+    return x.shape[-1] % 128 == 0
+
+
+def _row_accumulator(x):
+    """Zeros for the float32 sums over rows shaped like x [N, h]: [N, 1, h]
+    where :func:`_add_rows` moves rows by DMA, [N, h] where by indexing.  The
+    rank tells the two apart from there on; ``.reshape(x.shape)`` ends both."""
+    n, h = x.shape
+    return jnp.zeros((n, 1, h) if _rows_by_dma(x) else (n, h), jnp.float32)
+
+
+def _add_rows(acc, tok, valid, rows):
+    """``acc`` of :func:`_row_accumulator` with ``rows`` [tile, h] added to its
+    rows ``tok``, for the slots that are ``valid``: a prefix in which no token
+    repeats.  ``rows`` are zeros in the other slots."""
+    if acc.ndim == 2:
+        return acc.at[tok].add(rows)
+    interpret = not _on_tpu()
+    n = jnp.sum(valid, dtype=jnp.int32)
+    seen = take_rows(acc, tok, n, interpret=interpret)
+    return put_rows(acc, tok, n, seen + rows[:, None, :], interpret=interpret)
 
 
 def _swiglu(xt, wg, wu):
@@ -92,18 +197,25 @@ def _held_experts(x, w, plan, wg, wu, wd, tile):
     wg, wu, wd = (m.astype(x.dtype) for m in (wg, wu, wd))
 
     def run_tile(carry):
-        t, y = carry
+        # a tile's rows join ``y`` a turn late: carried through the loop the
+        # weighted rows are rounded before the sum on every backend (in one
+        # expression XLA's CPU backend contracts product and sum into one
+        # rounding, and the kernels would not equal their twin bit for bit)
+        t, y, late = carry
+        y = _add_rows(y, *late)
         e, a, tok, valid, _ = _tile_rows(t, plan, tile, k)
         _, _, mid = _swiglu(x[tok], wg[e], wu[e])
         yt = jnp.dot(mid.astype(x.dtype), wd[e], preferred_element_type=jnp.float32)
         yt = yt * jnp.where(valid, w_flat[a], 0.0)[:, None]
-        return t + 1, y.at[tok].add(yt)
+        return t + 1, y, (tok, valid, yt)
 
     tiles = plan[3][-1]
-    _, y = jax.lax.while_loop(
-        lambda c: c[0] < tiles, run_tile, (jnp.int32(0), jnp.zeros(x.shape, jnp.float32))
+    nothing = (jnp.zeros(tile, jnp.int32), jnp.zeros(tile, bool), jnp.zeros((tile, x.shape[1]), jnp.float32))
+    _, y, late = jax.lax.while_loop(
+        lambda c: c[0] < tiles, run_tile, (jnp.int32(0), _row_accumulator(x), nothing)
     )
-    return y.astype(x.dtype)
+    y = _add_rows(y, *late)
+    return y.reshape(x.shape).astype(x.dtype)
 
 
 def _held_experts_fwd(x, w, plan, wg, wu, wd, tile):
@@ -142,17 +254,17 @@ def _held_experts_bwd(tile, saved, dy):
         dwu = dwu.at[e].add(jnp.dot(xt.T, du, preferred_element_type=f32))
         dxt = (jnp.dot(dg, wg_lo[e].T, preferred_element_type=f32)
                + jnp.dot(du, wu_lo[e].T, preferred_element_type=f32))
-        return t + 1, dx.at[tok].add(dxt), dwg, dwu, dwd, dw_rows
+        return t + 1, _add_rows(dx, tok, valid, dxt), dwg, dwu, dwd, dw_rows
 
     init = (
-        jnp.int32(0), jnp.zeros(x.shape, f32),
+        jnp.int32(0), _row_accumulator(x),
         jnp.zeros(wg.shape, f32), jnp.zeros(wu.shape, f32), jnp.zeros(wd.shape, f32),
         jnp.zeros(n * k + tile, f32),  # a tile may reach past the last row
     )
     tiles = plan[3][-1]
     _, dx, dwg, dwu, dwd, dw_rows = jax.lax.while_loop(lambda c: c[0] < tiles, run_tile, init)
     dw = jnp.zeros(n * k, f32).at[plan[0]].set(dw_rows[: n * k]).reshape(n, k)
-    return (dx.astype(x.dtype), dw.astype(w.dtype), None,
+    return (dx.reshape(x.shape).astype(x.dtype), dw.astype(w.dtype), None,
             dwg.astype(wg.dtype), dwu.astype(wu.dtype), dwd.astype(wd.dtype))
 
 
@@ -161,7 +273,7 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def _routed_share(x, top_e, w, wg, wu, wd, *, held, tile, axes):
     """One shard's rows through the experts held here.  → (y, expert loads
-    [count] summed over ``axes``)."""
+    [count] and the tiles run, both summed over ``axes``)."""
     first, count = held
     shape = x.shape
     k = top_e.shape[-1]
@@ -170,10 +282,10 @@ def _routed_share(x, top_e, w, wg, wu, wd, *, held, tile, axes):
     tile = min(tile, -(-local.shape[0] // 8) * 8)
     plan = _tile_plan(local, count, tile)
     y = _held_experts(x.reshape(-1, shape[-1]), w.reshape(-1, k), plan, wg, wu, wd, tile)
-    loads = plan[1]
+    loads, tiles = plan[1], plan[3][-1]
     if axes:
-        loads = jax.lax.psum(loads, axes)
-    return y.reshape(shape), loads
+        loads, tiles = jax.lax.psum((loads, tiles), axes)
+    return y.reshape(shape), loads, tiles * tile
 
 
 def shared_expert(x, p):
@@ -200,17 +312,18 @@ def held_experts(x, top_e, w, p, *, n_experts: int, held: tuple[int, int],
     weights = (p["w_gate"], p["w_up"], p["w_down"])
     with jax.named_scope(EXPERTS_SCOPE):
         if batch_sharding is None:
-            y, loads = _routed_share(x, top_e, w, *weights, held=held, tile=tile, axes=())
+            y, loads, tile_rows = _routed_share(x, top_e, w, *weights, held=held, tile=tile, axes=())
         else:
             spec = batch_sharding.spec
-            y, loads = jax.shard_map(
+            y, loads, tile_rows = jax.shard_map(
                 functools.partial(_routed_share, held=held, tile=tile, axes=spec_axes(spec)),
                 mesh=batch_sharding.mesh, in_specs=(spec, spec, spec, P(), P(), P()),
-                out_specs=(spec, P()), check_vma=False,
+                out_specs=(spec, P(), P()), check_vma=False,
             )(x, top_e, w, *weights)
     counts = {
         "moe_all": jnp.int32(top_e.size),
         "moe_held": jnp.sum(loads),
         "moe_load_max": jnp.max(loads),
+        "moe_tile_rows": tile_rows,  # slots the tile loop moved and multiplied, forward
     }
     return y, counts

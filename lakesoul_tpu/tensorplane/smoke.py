@@ -256,6 +256,33 @@ def _run_unit_lower_inverse(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"systems": n, "chunk": c, **_agree(got, want)}
 
 
+def _run_row_copies(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.parallel.moe import EXPERT_TILE, put_rows, take_rows
+
+    # the expert tile loop's float32 sums over a step's tokens (16,384 rows at
+    # deployed sizes): tiles of distinct rows read, summed and written back,
+    # with none, some and all of a tile's slots valid.  Copies, so exact
+    n_rows = max(64, sizes.rows // 64)
+    width = -(-sizes.d // 128) * 128
+    tile = min(EXPERT_TILE, n_rows // 2)
+    rng = _rng(8)
+    acc = jnp.asarray(rng.standard_normal((n_rows, 1, width), dtype=np.float32))
+    want = np.array(acc)  # lakelint: ignore[replay-host-roundtrip] verification readback: the host copy the indexing twin updates
+    counts = [0, tile, *map(int, rng.integers(1, tile, size=6))]
+    for n in counts:
+        idx = rng.permutation(n_rows)[:tile].astype(np.int32)
+        idx[n:] = idx[:1] if n else 0  # slots past the prefix repeat a row of it: never written
+        rows = jnp.asarray(rng.standard_normal((tile, 1, width), dtype=np.float32))
+        seen = take_rows(acc, jnp.asarray(idx), jnp.int32(n), interpret=interpret)
+        np.testing.assert_array_equal(np.asarray(seen)[:n], want[idx[:n]])  # lakelint: ignore[replay-host-roundtrip] verification readback: rows fetched by DMA against indexing
+        acc = put_rows(acc, jnp.asarray(idx), jnp.int32(n), seen + rows, interpret=interpret)
+        want[idx[:n]] += np.asarray(rows)[:n]  # lakelint: ignore[replay-host-roundtrip] verification readback: the twin's sum on the host
+    np.testing.assert_array_equal(np.asarray(acc), want)  # lakelint: ignore[replay-host-roundtrip] verification readback: rows written by DMA against indexing
+    return {"rows": n_rows, "width": width, "tile": tile, "valid": counts}
+
+
 # --------------------------------------------------------------- multichip
 
 
@@ -396,6 +423,13 @@ def smoke_cases() -> list[SmokeCase]:
         SmokeCase(
             "models.unit_lower_inverse", "pallas", _run_unit_lower_inverse,
             kernels=("lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel",),
+        ),
+        SmokeCase(
+            "parallel.moe_row_copies", "pallas", _run_row_copies,
+            kernels=(
+                "lakesoul_tpu/parallel/moe.py::_take_rows_kernel",
+                "lakesoul_tpu/parallel/moe.py::_put_rows_kernel",
+            ),
         ),
         SmokeCase(
             "annplane.cross_chip_topk", "multichip", _run_cross_chip_topk,

@@ -219,20 +219,29 @@ def _expert_layer(x, p, *, held, tile=None, top_k=4, n_experts=16):
     return y + moe.shared_expert(x, p["shared"]), counts
 
 
-@pytest.mark.parametrize("routing", ["even", "all-on-one-held", "none-held", "top-1", "one-of-one"])
-def test_expert_layer_equals_the_loop_over_experts(params, routing):
-    p = dict(params["layers"][0]["moe"])
-    model, held, top_k = MODEL, HELD, 4
+ROUTINGS = ["even", "all-on-one-held", "none-held", "top-1", "one-of-one"]
+
+
+def _routed(p, model, routing):
+    """A layer's weights and its model under one of :data:`ROUTINGS` →
+    (weights, model, held, top_k)."""
+    p, held, top_k = dict(p), HELD, 4
     if routing == "all-on-one-held":  # expert 5 takes every token, its three companions are not held
         p["router"] = _route_to(p["router"], [5, 0, 1, 2])
     elif routing == "none-held":
         p["router"] = _route_to(p["router"], [0, 1, 2, 3])
     elif routing == "top-1":  # a token's one expert, its weight 1 after the division
-        model, top_k = MODEL | {"num_experts_per_tok": 1}, 1
+        model, top_k = model | {"num_experts_per_tok": 1}, 1
     elif routing == "one-of-one":  # one expert, held: the layer is the dense SwiGLU
-        model, held, top_k = MODEL | {"num_experts": 1, "num_experts_per_tok": 1}, (0, 1), 1
+        model, held, top_k = model | {"num_experts": 1, "num_experts_per_tok": 1}, (0, 1), 1
         p["router"] = p["router"][:, :1]
         p.update({k: p[k][:1] for k in ("w_gate", "w_up", "w_down")})
+    return p, model, held, top_k
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_expert_layer_equals_the_loop_over_experts(params, routing):
+    p, model, held, top_k = _routed(params["layers"][0]["moe"], MODEL, routing)
     x = hidden(7).at[..., 0].set(10.0)
     weigh = jax.random.normal(jax.random.key(8), x.shape)
     layer = functools.partial(_expert_layer, held=held, tile=16, top_k=top_k, n_experts=model["num_experts"])
@@ -292,6 +301,115 @@ def test_expert_layer_refuses_a_share_that_does_not_fit(params):
         _expert_layer(hidden(0), p, held=(0, 8))
 
 
+# ------------------------------------------------- a tile's rows moved by DMA
+
+WIDE = MODEL | {"hidden_size": 256}  # a float32 row of the sums is two lane tiles: rows move by DMA
+WIDE_CFG = lm.Qwen3NextConfig.from_published(WIDE, experts_held=HELD, dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    tree = lm.init_lm_params(WIDE_CFG, jax.random.key(0))
+    return jax.tree.map(lambda a: a * 5 if a.ndim >= 2 else a, tree)
+
+
+def _rows_and_slots(width, n, n_rows=40, tile=16):
+    """An accumulator as the tile loops carry it, a tile's slots and fresh rows
+    for them.  Slots 11 to 15 hold the indices of slots 0 to 4 and, for a
+    write, stale rows: written, they would land over the fresh rows of the
+    prefix.  A whole tile repeats no index, as a tile of one expert's
+    assignments does not."""
+    table = jax.random.normal(jax.random.key(11), (n_rows, 1, width))
+    fresh = jax.random.normal(jax.random.key(12), (tile, 1, width))
+    slots = np.random.default_rng(13).permutation(n_rows)[:tile].astype(np.int32)
+    if n < tile:
+        slots[-5:] = slots[:5]
+    return table, fresh, jnp.asarray(slots)
+
+
+ROW_COUNTS = {"none": 0, "a-prefix": 7, "up-to-the-repeats": 11, "a-whole-tile": 16}
+
+
+@pytest.mark.parametrize("n", ROW_COUNTS.values(), ids=ROW_COUNTS.keys())
+@pytest.mark.parametrize("width", [128, 384])
+def test_take_rows_equals_indexing(width, n):
+    table, _, slots = _rows_and_slots(width, n)
+    got = moe.take_rows(table, slots, jnp.int32(n), interpret=True)
+    assert got.shape == (16, 1, width)
+    np.testing.assert_array_equal(got[:n], table[slots[:n]])
+
+
+@pytest.mark.parametrize("n", ROW_COUNTS.values(), ids=ROW_COUNTS.keys())
+@pytest.mark.parametrize("width", [128, 384])
+def test_put_rows_writes_the_prefix_and_nothing_else(width, n):
+    table, fresh, slots = _rows_and_slots(width, n)
+    got = moe.put_rows(table, slots, jnp.int32(n), fresh, interpret=True)
+    np.testing.assert_array_equal(got, table.at[slots[:n]].set(fresh[:n]))
+
+
+@pytest.mark.parametrize("n", ROW_COUNTS.values(), ids=ROW_COUNTS.keys())
+def test_add_rows_by_dma_equals_the_scatter_add(n):
+    """The read, the sum and the write of a tile against ``.at[].add`` of its
+    valid rows; what the slots past them hold in ``rows`` must not matter."""
+    table, fresh, slots = _rows_and_slots(256, n)
+    valid = jnp.arange(16) < n
+    got = jax.jit(moe._add_rows)(table, slots, valid, fresh[:, 0])
+    np.testing.assert_array_equal(got, table.at[slots[:n]].add(fresh[:n]))
+    twin = moe._add_rows(table[:, 0], slots, valid, fresh[:, 0].at[n:].set(0.0))
+    np.testing.assert_array_equal(got[:, 0], twin)
+
+
+@pytest.mark.parametrize("hidden, dtype, by_dma", [
+    (64, jnp.float32, False), (96, jnp.bfloat16, False), (128, jnp.float32, True), (256, jnp.bfloat16, True),
+], ids=["64-float32", "96-bfloat16", "128-float32", "256-bfloat16"])
+def test_only_sums_over_rows_of_whole_lane_tiles_move_by_dma(monkeypatch, hidden, dtype, by_dma):
+    calls = {"take_rows": [], "put_rows": []}
+    for name in calls:
+        kernel = getattr(moe, name)
+        monkeypatch.setattr(
+            moe, name, lambda *a, _seen=calls[name], _kernel=kernel, **k: _seen.append(k) or _kernel(*a, **k)
+        )
+    x = jax.random.normal(jax.random.key(14), (48, hidden)).astype(dtype)
+    top_e = jnp.tile(jnp.arange(4, dtype=jnp.int32), (48, 1))
+    w = jnp.full((48, 4), 0.25)
+    p = {name: jax.random.normal(jax.random.key(15), shape) * 0.1 for name, shape in
+         (("w_gate", (2, hidden, 8)), ("w_up", (2, hidden, 8)), ("w_down", (2, 8, hidden)))}
+    jax.grad(lambda x: jnp.sum(moe.held_experts(x, top_e, w, p, n_experts=4, held=(1, 2), tile=16)[0]
+                               .astype(jnp.float32)))(x)
+    # traced once in the forward loop and once after it (the last tile's rows), once in the
+    # backward loop.  No TPU here: the interpreter
+    assert calls["take_rows"] == calls["put_rows"] == [{"interpret": True}] * (3 if by_dma else 0)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_expert_layer_by_dma_equals_the_indexing_bit_for_bit(wide_params, monkeypatch, routing, dtype):
+    p, model, held, top_k = _routed(wide_params["layers"][0]["moe"], WIDE, routing)
+    x = jax.random.normal(jax.random.key(7), (B, T, 256)).at[..., 0].set(10.0).astype(dtype)
+    weigh = jax.random.normal(jax.random.key(8), x.shape)
+    layer = functools.partial(_expert_layer, held=held, tile=16, top_k=top_k, n_experts=model["num_experts"])
+
+    def program(p, x):
+        y, counts = layer(x, p)
+        return jnp.sum(weigh * y), (y, counts)
+
+    run = jax.jit(jax.value_and_grad(program, argnums=(0, 1), has_aux=True))
+    (_, (y, counts)), grads = run(p, x)
+    assert moe._rows_by_dma(x.reshape(-1, 256))
+    monkeypatch.setattr(moe, "_rows_by_dma", lambda x: False)
+    (_, (y_twin, counts_twin)), grads_twin = jax.jit(jax.value_and_grad(program, argnums=(0, 1), has_aux=True))(p, x)
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path((y, grads, counts)),
+                                 jax.tree.leaves((y_twin, grads_twin, counts_twin))):
+        np.testing.assert_array_equal(got, want, err_msg=jax.tree_util.keystr(path))
+    if dtype == "float32":
+        assert_close(y, ref.moe(x, p, model, held))
+        want = jax.grad(lambda p, x: jnp.sum(weigh * ref.moe(x, p, model, held)), argnums=(0, 1))(p, x)
+        if top_k == 1:
+            for tree in (grads, want):  # zero but for rounding, which grows with the width
+                assert float(jnp.max(jnp.abs(tree[0].pop("router")))) < 1e-4
+        assert_close(grads, want)
+
+
 # ------------------------------------------------------------- whole model
 
 
@@ -341,6 +459,7 @@ def stepped():
                 "tokens": _series(TOKENS_FAMILY),
                 "held": _series(MOE_ASSIGNMENTS_FAMILY, kind="held"),
                 "all": _series(MOE_ASSIGNMENTS_FAMILY, kind="all"),
+                "tile_rows": _series(MOE_ASSIGNMENTS_FAMILY, kind="tile_rows"),
                 "max": _series(MOE_LOAD_FAMILY, stat="max"),
                 "mean": _series(MOE_LOAD_FAMILY, stat="mean"),
             }
@@ -391,7 +510,7 @@ def test_counters_for_a_known_routing(stepped):
     ids, labels, out = stepped
     run = out[1]
     # what the step must have counted, from the reference's routing of the same weights
-    held = load_max = 0
+    held = load_max = tile_rows = 0
     x = jnp.asarray(run["before"]["embed"])[ids]
     for lp, kind in zip(run["before"]["layers"], CFG.layer_kinds()):
         y = ref.rms_norm(x, lp["norm1"], 1e-6)
@@ -402,15 +521,21 @@ def test_counters_for_a_known_routing(stepped):
         loads = np.bincount(np.asarray(top_e).ravel(), minlength=16)[HELD[0]:HELD[0] + HELD[1]]
         held += int(loads.sum())
         load_max += int(loads.max())
+        # an expert's rows run in whole tiles: the slots moved and multiplied
+        tile_rows += sum(-(-int(load) // moe.EXPERT_TILE) * moe.EXPERT_TILE for load in loads)
         x = x + ref.moe(y, lp["moe"], MODEL, HELD)
     got = run["step"].counts()
-    assert got == {"tokens": B * T, "moe_all": 4 * 4 * B * T, "moe_held": held, "moe_load_max": load_max}
+    assert got == {"tokens": B * T, "moe_all": 4 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
+                   "moe_tile_rows": tile_rows}
+    assert held < tile_rows
     before = run["counted"]
     # the dp=2 step of the fixture ran after this read and counted the same batch again
     again = 2
     assert _series(TOKENS_FAMILY) - before["tokens"] == again * B * T
     assert _series(MOE_ASSIGNMENTS_FAMILY, kind="all") - before["all"] == again * 16 * B * T
     assert _series(MOE_ASSIGNMENTS_FAMILY, kind="held") - before["held"] == again * held
+    # each of the two shards rounds its own rows of an expert up to whole tiles
+    assert _series(MOE_ASSIGNMENTS_FAMILY, kind="tile_rows") - before["tile_rows"] >= again * tile_rows
     assert _series(MOE_LOAD_FAMILY, stat="mean") - before["mean"] == pytest.approx(again * held / HELD[1])
     assert _series(MOE_LOAD_FAMILY, stat="max") - before["max"] >= load_max
     assert out[2]["step"].counts()["moe_held"] == held

@@ -373,6 +373,52 @@ def test_lm_step_holds_the_chunk_inverse_kernel_under_the_gdn_scope(lm_step_comp
     assert sorted(set(scope_of.values())) == sorted(LM_SCOPES)  # and no scope the readers do not know
 
 
+def test_expert_sums_by_dma_stay_under_the_experts_scope():
+    """The held experts' layer alone, value and gradient, at a width whose
+    float32 sums move by DMA (the toy step above is narrower and indexes),
+    compiled for the described v5e.  A device trace names the two kernels'
+    events ``take_rows.<n>`` and ``put_rows.<n>``: in the forward loop, after
+    it (the last tile's rows) and in the backward loop.  ``moe_step_share_pct``
+    keeps counting them only if the adaptor's ``scopes_of`` charges them to
+    ``lakesoul.lm.moe.experts``, as a custom call's ``op_name`` lets it."""
+    import importlib.util
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from lakesoul_tpu.parallel import moe
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    n, h, f, k = 64, 256, 16, 2
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(x, w, p, top_e):
+        y, _ = moe.held_experts(x, top_e, w, p, n_experts=8, held=(0, 4), tile=16)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    p = {"w_gate": shape((4, h, f)), "w_up": shape((4, h, f)), "w_down": shape((4, f, h))}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "_on_tpu", lambda: True)  # the branch the chip takes
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            shape((n, h), jnp.bfloat16), shape((n, k)), p, shape((n, k), jnp.int32)
+        ).compile().as_text()
+    calls = re.findall(r"^\s*%?([\w.\-]+) = \S+ custom-call\(.*tpu_custom_call", text, re.MULTILINE)
+    assert sorted(name.split(".")[0] for name in calls) == ["put_rows"] * 3 + ["take_rows"] * 3, calls
+    spec = importlib.util.spec_from_file_location(
+        "qwen3_next_clm", os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")
+    )
+    adaptor = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(adaptor)
+    scope_of = adaptor.scopes_of(text)
+    assert {scope_of.get(name) for name in calls} == {"lakesoul.lm.moe.experts"}
+
+
 def test_lm_step_program_name_the_device_readers_search_for(lm_step_module):
     """``step_device_ms`` and ``chipbench/scopes.py`` find the causal-LM step in
     a device trace by the name its adaptor pins."""
@@ -392,3 +438,25 @@ def test_lm_scope_names_the_share_readers_search_for(lm_step_module, scope):
         assert constant in f.read()
     with open(os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")) as f:
         assert r'lakesoul\.lm\.' in f.read()
+
+
+@pytest.mark.parametrize("check", ["fill_of_hand_counts", "nothing_without_the_series"])
+def test_tile_fill_reader(check):
+    """``moe_tile_fill_pct`` through its own self-test, and the series it
+    divides by under the name the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import MOE_ASSIGNMENTS_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_tile_fill", os.path.join(REPO, "benchmarks", "chip", "selftest", "tile_fill.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_fill_of_hand_counts", "test_nothing_without_the_series"]
+    getattr(selftest, "test_" + check)()
+    assert selftest.FAMILY == MOE_ASSIGNMENTS_FAMILY
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "moe_tile_fill_pct.py")) as f:
+        assert 'kind="tile_rows"' in f.read()
+    with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
+        assert '{"kind": "tile_rows"}' in f.read()

@@ -15,6 +15,7 @@ import pytest
 
 from lakesoul_tpu.annplane import ragged
 from lakesoul_tpu.models import qwen3_next
+from lakesoul_tpu.parallel import moe
 from lakesoul_tpu.tensorplane.smoke import enumerate_pallas_kernels
 from lakesoul_tpu.vector import kernels
 
@@ -68,6 +69,22 @@ def _unit_lower_inverse(d):
     ).trace(_sds((*lead, c, c)), _sds((*lead, c)))
 
 
+def _expert_sums(d):
+    # the float32 sums over a step's 16,384 tokens at the LM cell's width, a tile of slots
+    del d
+    return _sds((16_384, 1, 2048)), _sds((moe.EXPERT_TILE,), jnp.int32), _sds((), jnp.int32)
+
+
+def _take_rows(d):
+    return jax.jit(lambda acc, idx, n: moe.take_rows(acc, idx, n, interpret=False)).trace(*_expert_sums(d))
+
+
+def _put_rows(d):
+    return jax.jit(lambda acc, idx, n, rows: moe.put_rows(acc, idx, n, rows, interpret=False)).trace(
+        *_expert_sums(d), _sds((moe.EXPERT_TILE, 1, 2048))
+    )
+
+
 # keyed by lakelint device-index qname, like the smoke register
 TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_packed_scan_kernel": _packed_scan,
@@ -76,6 +93,8 @@ TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_bruteforce_kernel": _bruteforce,
     "lakesoul_tpu/annplane/ragged.py::_ragged_score_kernel": _ragged_score,
     "lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel": _unit_lower_inverse,
+    "lakesoul_tpu/parallel/moe.py::_take_rows_kernel": _take_rows,
+    "lakesoul_tpu/parallel/moe.py::_put_rows_kernel": _put_rows,
 }
 
 
