@@ -1,6 +1,8 @@
 """Hybrid causal LM of the Qwen3-Next family: Gated DeltaNet layers and gated
 softmax-attention layers in one stack, a dropless mixture of experts after
-every mixer, written functionally like ``models/bert.py``.
+every mixer.  This file is the family: its configuration, its weights and its
+two mixers; the layer stack, the head, the loss and the attention under the
+gate are ``models/causal_lm.py``'s, shared with the other families.
 
 Layer ``i`` is gated attention where ``(i + 1) % full_attention_interval == 0``
 and Gated DeltaNet otherwise; nothing here branches on a model's name.  A layer
@@ -8,11 +10,6 @@ is ``x = x + mixer(norm(x)); x = x + moe(norm(x))`` with zero-centred RMS norms
 in float32, then a final norm and an untied head.  Matrix products run in
 ``cfg.dtype`` (bfloat16) with float32 accumulation; norms, the router's
 softmax, the DeltaNet decay's running sum and its state are float32.
-
-The model may be one chip's share of an expert-parallel job: ``experts_held``
-says which of the ``num_experts`` live here (``parallel/moe.py: held_experts``)
-and ``vocab_size`` is the slice of the vocabulary the embedding, the head and
-the loss are over.  Parallelism: dp over rows; everything else is replicated.
 
 Departures from the published model: no multi-token-prediction module, no
 router auxiliary loss, no document boundaries (a row is one packed sequence).
@@ -30,19 +27,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec as P
 
-from lakesoul_tpu.models.bert import labelled_nll
-from lakesoul_tpu.parallel.moe import ROUTE_SCOPE, held_experts, route_top_k, shared_expert
-from lakesoul_tpu.parallel.ring_attention import block_attn
+from lakesoul_tpu.models.causal_lm import (  # noqa: F401  (the stack, under the names this family's callers import)
+    ATTN_SCOPE,
+    _rms_norm,
+    causal_conv,
+    lm_head,
+    lm_hidden,
+    lm_logits,
+    lm_loss,
+    normal_init as normal,
+    softmax_attention,
+)
+from lakesoul_tpu.parallel.moe import route_top_k
 from lakesoul_tpu.vector.kernels import _on_tpu
 
 GDN_SCOPE = "lakesoul.lm.gdn"
-ATTN_SCOPE = "lakesoul.lm.attn"
-HEAD_SCOPE = "lakesoul.lm.head"
 GDN_CHUNK = 128    # tokens a DeltaNet chunk holds: a v5e matrix unit is 128 wide (the family's public kernels use 64)
-ATTN_BAND = 1024   # queries that share one static slice of the keys
-ATTN_ROWS = 128    # queries whose scores live at once
 
 
 @dataclass(frozen=True)
@@ -88,6 +89,27 @@ class Qwen3NextConfig:
             for i in range(self.num_hidden_layers)
         )
 
+    def ffn_kinds(self) -> tuple[str, ...]:
+        return ("moe",) * self.num_hidden_layers  # decoder_sparse_step 1, no mlp_only_layers
+
+    def mixer(self, kind: str):
+        if kind == "gdn":
+            return functools.partial(gated_delta_net, cfg=self), GDN_SCOPE
+        return functools.partial(gated_attention, cfg=self), ATTN_SCOPE
+
+    def norm(self, x, w):
+        return _rms_norm(x, w, self.rms_norm_eps)
+
+    def route(self, x, router_w, bias):
+        del bias  # the family has none: no assignment is moved
+        return *route_top_k(x, router_w, top_k=self.num_experts_per_tok), jnp.int32(0)
+
+    def init(self, key: jax.Array) -> dict:
+        return init_lm_params(self, key)
+
+    def loss(self, params, ids, labels, *, batch_sharding=None):
+        return lm_loss(params, ids, labels, cfg=self, batch_sharding=batch_sharding)
+
     @property
     def key_dim(self) -> int:
         return self.linear_num_key_heads * self.linear_key_head_dim
@@ -102,9 +124,6 @@ def init_lm_params(cfg: Qwen3NextConfig, key: jax.Array) -> dict:
     ``dt_bias = 1``; zero-centred norm weights 0, the DeltaNet output norm 1."""
     h, f = cfg.hidden_size, cfg.moe_intermediate_size
     count = cfg.experts_held[1]
-
-    def normal(key, *shape):
-        return (jax.random.normal(key, shape) * 0.02).astype(jnp.float32)
 
     def layer(key, kind):
         ks = jax.random.split(key, 12)
@@ -154,25 +173,7 @@ def init_lm_params(cfg: Qwen3NextConfig, key: jax.Array) -> dict:
     }
 
 
-def _rms_norm(x, w, eps, *, centred: bool = True):
-    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` in float32 (``* w`` where the
-    weight is not zero-centred); float32 out."""
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return y * ((1.0 + w) if centred else w)
-
-
 # -------------------------------------------------------- Gated DeltaNet
-
-
-def _causal_conv_silu(x, w):
-    """Depthwise causal convolution, no bias, then SiLU: x [B, T, C], w [C, K];
-    tap ``K-1`` sits on the current token."""
-    taps = w.shape[1]
-    t = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    y = sum(xp[:, j:j + t].astype(jnp.float32) * w[:, j] for j in range(taps)).astype(x.dtype)
-    return jax.nn.silu(y.astype(jnp.float32)).astype(x.dtype)
 
 
 def _mm_high(a, b):
@@ -392,7 +393,7 @@ def gated_delta_net(x, p, *, cfg: Qwen3NextConfig, chunk: int | None = None):
     qkvz = x @ p["w_qkvz"].astype(dtype)
     ba = jnp.dot(x, p["w_ba"].astype(dtype), preferred_element_type=f32)
     conv_dim = 2 * cfg.key_dim + cfg.value_dim
-    qkv = _causal_conv_silu(qkvz[..., :conv_dim], p["conv"])
+    qkv = jax.nn.silu(causal_conv(qkvz[..., :conv_dim], p["conv"]).astype(f32)).astype(dtype)
     z = qkvz[..., conv_dim:].reshape(b, t, hv, dv)
     q = qkv[..., : cfg.key_dim].reshape(b, t, hk, dk).astype(f32)
     k = qkv[..., cfg.key_dim: 2 * cfg.key_dim].reshape(b, t, hk, dk).astype(f32)
@@ -411,157 +412,10 @@ def gated_delta_net(x, p, *, cfg: Qwen3NextConfig, chunk: int | None = None):
 # ------------------------------------------------------- gated attention
 
 
-def _rotary(x, positions, rotary_dim: int, theta: float):
-    """Rotate the first ``rotary_dim`` channels of x [B, T, H, D] (float32):
-    halves ``[x1 | x2]`` → ``[x1 cos - x2 sin | x2 cos + x1 sin]``."""
-    half = rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq  # [T, half]
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-
-
-def causal_attention(q, k, v, *, band: int | None = None, rows: int | None = None):
-    """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
-    (scaled), k, v [B, Hkv, T, D] → [B, Hkv, G, T, D].
-
-    The queries go a band at a time against the keys up to the band's last
-    position (a static slice, so the keys after it cost nothing), and inside a
-    band ``rows`` queries at a time, each block rematerialised: no more than
-    ``rows`` rows of scores live at once, in either pass."""
-    band, rows = band or ATTN_BAND, rows or ATTN_ROWS
-    b, hkv, groups, t, d = q.shape
-
-    def block(q_blk, k_seen, v_seen, first):
-        """q_blk [B, Hkv, G, n, D] at positions first.. against the keys seen."""
-        n = q_blk.shape[3]
-        pos = jnp.tile(first + jnp.arange(n), groups)
-        mask = pos[:, None] >= jnp.arange(k_seen.shape[2])[None, :]
-        _, l, o = block_attn(q_blk.reshape(b, hkv, groups * n, d), k_seen, v_seen, 1.0, mask)
-        return (o / l[..., None]).astype(v.dtype).reshape(b, hkv, groups, n, d)
-
-    out = []
-    for start in range(0, t, band):
-        end = min(start + band, t)
-        q_band, k_seen, v_seen = q[:, :, :, start:end], k[:, :, :end], v[:, :, :end]
-        if (end - start) % rows or end - start == rows:
-            out.append(jax.checkpoint(block)(q_band, k_seen, v_seen, start))
-            continue
-        blocks = (end - start) // rows
-        q_rows = jnp.moveaxis(q_band.reshape(b, hkv, groups, blocks, rows, d), 3, 0)
-        firsts = start + rows * jnp.arange(blocks)
-        o = jax.lax.map(
-            lambda xs: jax.checkpoint(block)(xs[0], k_seen, v_seen, xs[1]), (q_rows, firsts)
-        )
-        out.append(jnp.moveaxis(o, 0, 3).reshape(b, hkv, groups, end - start, d))
-    return jnp.concatenate(out, axis=3)
-
-
 def gated_attention(x, p, *, cfg: Qwen3NextConfig):
     """The gated softmax-attention mixer: x [B, T, h] (normed) → [B, T, h]."""
-    dtype = x.dtype
-    f32 = jnp.float32
-    b, t, _ = x.shape
-    heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    qg = (x @ p["w_q"].astype(dtype)).reshape(b, t, heads, 2 * d)
-    q, gate = qg[..., :d], qg[..., d:]
-    k = (x @ p["w_k"].astype(dtype)).reshape(b, t, kv, d)
-    v = (x @ p["w_v"].astype(dtype)).reshape(b, t, kv, d)
-    rotary_dim = int(d * cfg.partial_rotary_factor)
-    positions = jnp.arange(t)
-    q = _rotary(_rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions, rotary_dim, cfg.rope_theta)
-    k = _rotary(_rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions, rotary_dim, cfg.rope_theta)
-    q = (q * d**-0.5).astype(dtype)
-    # [B, T, heads, D] → [B, kv, heads // kv, T, D]: each key-value head serves a group
-    q = q.reshape(b, t, kv, heads // kv, d).transpose(0, 2, 3, 1, 4)
-    k, v = (a.transpose(0, 2, 1, 3) for a in (k.astype(dtype), v))
-    o = causal_attention(q, k, v)
-    o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
-    o = (o.astype(f32) * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
-    return o.reshape(b, t, heads * d) @ p["w_o"].astype(dtype)
-
-
-# ----------------------------------------------------------------- model
-
-
-def _row_by_row(mixer, x, p, batch_sharding):
-    """``mixer(x, p)`` one row of ``x`` [B, T, h] at a time, each row
-    rematerialised: a mixer's intermediates at 8k tokens are gigabytes a row
-    and no row needs another's.  On a mesh every device takes its own rows."""
-
-    def local(x, p):
-        return jax.lax.map(jax.checkpoint(lambda row: mixer(row[None], p)[0]), x)
-
-    if batch_sharding is None:
-        return local(x, p)
-    spec = batch_sharding.spec
-    return jax.shard_map(
-        local, mesh=batch_sharding.mesh, in_specs=(spec, P()), out_specs=spec, check_vma=False
-    )(x, p)
-
-
-def lm_layer(x, lp, *, kind: str, cfg: Qwen3NextConfig, batch_sharding=None):
-    """One layer: x [B, T, h] → (x, the expert layer's counts).  The mixer is
-    rematerialised a row at a time, and norm, routing and the shared expert
-    together; the held experts' tile loop is not (its backward pass needs its
-    inputs alone).  What the backward pass keeps of a layer: its input, the
-    mixer's output, the experts' normed input and the routing."""
-    dtype = x.dtype
-    mixer, scope = (gated_delta_net, GDN_SCOPE) if kind == "gdn" else (gated_attention, ATTN_SCOPE)
-
-    def mix(x, p):
-        y = _rms_norm(x, p["norm"], cfg.rms_norm_eps).astype(dtype)
-        return x + mixer(y, p["mixer"], cfg=cfg)
-
-    @jax.checkpoint
-    def routed(x, norm, router, shared):
-        """Norm, routing and the shared expert: cheap to compute again."""
-        with jax.named_scope(ROUTE_SCOPE):
-            y32 = _rms_norm(x, norm, cfg.rms_norm_eps)
-        top_e, w = route_top_k(y32, router, top_k=cfg.num_experts_per_tok)
-        y = y32.astype(dtype)
-        return y, top_e, w, shared_expert(y, shared)
-
-    with jax.named_scope(scope):
-        x = _row_by_row(mix, x, {"norm": lp["norm1"], "mixer": lp[kind]}, batch_sharding)
-    p = lp["moe"]
-    y, top_e, w, shared = routed(x, lp["norm2"], p["router"], p["shared"])
-    out, counts = held_experts(
-        y, top_e, w, p, n_experts=cfg.num_experts, held=cfg.experts_held, batch_sharding=batch_sharding
+    return softmax_attention(
+        x, p, heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+        rotary_dim=int(cfg.head_dim * cfg.partial_rotary_factor), theta=cfg.rope_theta,
+        norm=cfg.norm, gated=True,
     )
-    return x + out + shared, counts
-
-
-def lm_hidden(params, ids, *, cfg: Qwen3NextConfig, batch_sharding=None):
-    """ids [B, T] → (final hidden states [B, T, h] before the final norm,
-    counts summed over the layers)."""
-    x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
-    totals = None
-    for lp, kind in zip(params["layers"], cfg.layer_kinds()):
-        x, counts = lm_layer(x, lp, kind=kind, cfg=cfg, batch_sharding=batch_sharding)
-        totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
-    return x, totals
-
-
-def lm_head(head, x, *, cfg: Qwen3NextConfig):
-    """Logits over the held vocabulary, float32: x [..., h] → [..., vocab]."""
-    dtype = jnp.dtype(cfg.dtype)
-    with jax.named_scope(HEAD_SCOPE):
-        y = _rms_norm(x, head["final_norm"], cfg.rms_norm_eps).astype(dtype)
-        return jnp.dot(y, head["head"].astype(dtype), preferred_element_type=jnp.float32)
-
-
-def lm_logits(params, ids, *, cfg: Qwen3NextConfig):
-    x, _ = lm_hidden(params, ids, cfg=cfg)
-    return lm_head({k: params[k] for k in ("final_norm", "head")}, x, cfg=cfg)
-
-
-def lm_loss(params, ids, labels, *, cfg: Qwen3NextConfig, batch_sharding=None):
-    """Next-token cross-entropy, float32, mean over the positions with
-    ``labels >= 0`` (-100 elsewhere) → (loss, counts).  ``counts``: the expert
-    layers' (summed over layers) and ``tokens``, int32."""
-    x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
-    head = {k: params[k] for k in ("final_norm", "head")}
-    loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head, x, labels, batch_sharding)
-    return loss, dict(counts, tokens=jnp.int32(ids.size))

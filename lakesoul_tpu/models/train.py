@@ -37,6 +37,17 @@ def _specs_to_shardings(mesh, rules):
     )
 
 
+BUFFERS = "buffers"  # the subtree of a model's parameters that is carried, not trained
+
+
+def _split_buffers(params: dict) -> tuple[dict, dict]:
+    """(what the optimizer trains, what it must not touch): ``params[BUFFERS]``,
+    where a model has it, is state its forward pass reads and no gradient, no
+    moment and no weight decay ever reaches (a routing bias)."""
+    return ({k: v for k, v in params.items() if k != BUFFERS},
+            {k: v for k, v in params.items() if k == BUFFERS})
+
+
 def _init_train_state(init_params, mesh, shardings, lr: float, seed: int):
     """(params, opt_state, tx, shardings) for AdamW at ``lr``, the weights
     being ``init_params(key)``: params and optimizer state come from the seed
@@ -51,7 +62,7 @@ def _init_train_state(init_params, mesh, shardings, lr: float, seed: int):
 
     def init(seed):
         params = init_params(jax.random.key(seed))
-        return params, tx.init(params)
+        return params, tx.init(_split_buffers(params)[0])
 
     by_path = dict(jax.tree_util.tree_leaves_with_path(shardings))
     replicated = NamedSharding(mesh, P())
@@ -264,13 +275,16 @@ def make_bert_train_step(
 
 def _adamw_step(loss_fn, tx):
     """``loss_fn(params, *batch) → (loss, counts)`` as one optimizer step →
-    (params, opt_state, loss, counts)."""
+    (params, opt_state, loss, counts).  ``params[BUFFERS]`` is outside the
+    gradient and the optimizer and comes back as it went in."""
 
     def step(params, opt_state, *batch):
-        (loss, counts), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, *batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss, counts
+        trained, carried = _split_buffers(params)
+        (loss, counts), grads = jax.value_and_grad(
+            lambda trained: loss_fn({**trained, **carried}, *batch), has_aux=True
+        )(trained)
+        updates, opt_state = tx.update(grads, opt_state, trained)
+        return {**optax.apply_updates(trained, updates), **carried}, opt_state, loss, counts
 
     return step
 
@@ -284,36 +298,34 @@ def _lm_plan(plan: MeshPlan) -> None:
 
 
 def make_lm_train_state(cfg, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0):
-    """(params, opt_state, tx, shardings) of the causal LM ``cfg`` describes
-    (``models/qwen3_next.py``), every leaf replicated over the mesh."""
-    from lakesoul_tpu.models.qwen3_next import init_lm_params
-
+    """(params, opt_state, tx, shardings) of the causal LM ``cfg`` describes,
+    every leaf replicated over the mesh.  ``cfg`` is a family's configuration
+    object (``models/causal_lm.py`` says what one offers): its ``init(key)``
+    makes the weights, and the buffers where the family has any."""
     _lm_plan(plan)
-    init = functools.partial(init_lm_params, cfg)
-    shardings = jax.tree.map(lambda _: NamedSharding(plan.mesh, P()), jax.eval_shape(init, jax.random.key(0)))
-    return _init_train_state(init, plan.mesh, shardings, lr, seed)
+    shardings = jax.tree.map(lambda _: NamedSharding(plan.mesh, P()), jax.eval_shape(cfg.init, jax.random.key(0)))
+    return _init_train_state(cfg.init, plan.mesh, shardings, lr, seed)
 
 
 def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
-    """Jitted next-token train step: (params, opt_state, input_ids, labels) →
-    (params, opt_state, loss); rows arrive sharded P('dp').  Feeds
-    ``lakesoul_train_tokens_total``, ``lakesoul_train_moe_assignments_total
-    {kind="held"|"all"|"tile_rows"}`` (``tile_rows``: the slots of the expert
-    tiles run, of which ``held`` carried an assignment) and ``lakesoul_train_moe_expert_load
-    {stat="max"|"mean"}`` (the fullest and the mean held expert's assignments,
-    summed over steps and layers)."""
-    from lakesoul_tpu.models.qwen3_next import lm_loss
-
+    """Jitted next-token train step of whichever family ``cfg`` is (its
+    ``loss``): (params, opt_state, input_ids, labels) → (params, opt_state,
+    loss); rows arrive sharded P('dp').  Feeds ``lakesoul_train_tokens_total``,
+    ``lakesoul_train_moe_assignments_total{kind="held"|"all"|"tile_rows"|
+    "bias_moved"}`` (``tile_rows``: the slots of the expert tiles run, of which
+    ``held`` carried an assignment; ``bias_moved``: the assignments whose
+    expert a routing bias brought into the top k, 0 for a family without one)
+    and ``lakesoul_train_moe_expert_load{stat="max"|"mean"}`` (the fullest and
+    the mean held expert's assignments, summed over steps and layers)."""
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
-    loss_fn = functools.partial(
-        lm_loss, cfg=cfg, batch_sharding=batch_sharding if plan.dp > 1 else None
-    )
+    loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
     series = (
         ("tokens", TOKENS_FAMILY, {}, 1),
         ("moe_held", MOE_ASSIGNMENTS_FAMILY, {"kind": "held"}, 1),
         ("moe_all", MOE_ASSIGNMENTS_FAMILY, {"kind": "all"}, 1),
         ("moe_tile_rows", MOE_ASSIGNMENTS_FAMILY, {"kind": "tile_rows"}, 1),
+        ("moe_bias_moved", MOE_ASSIGNMENTS_FAMILY, {"kind": "bias_moved"}, 1),
         ("moe_load_max", MOE_LOAD_FAMILY, {"stat": "max"}, 1),
         ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / cfg.experts_held[1]),
     )

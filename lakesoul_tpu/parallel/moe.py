@@ -6,11 +6,13 @@ however many assignments land on them (none to all).  Nothing has a capacity
 and nothing is dropped; what the absent experts would add is left out.  On an
 ``ep > 1`` mesh the token exchange in front of it is not written yet.
 
-The layer is three pieces and the model composes them
-(``models/qwen3_next.py: lm_layer``): :func:`route_top_k`,
-:func:`held_experts` and :func:`shared_expert`.  They stay apart because the
-model rematerialises the first and the last with its norm and leaves the
-second outside (see :func:`held_experts`).
+The layer is three pieces and the layer stack composes them
+(``models/causal_lm.py: lm_layer``): a routing rule (:func:`route_top_k`:
+softmax; :func:`route_sigmoid_top_k`: sigmoid scores picked under a per-expert
+bias), :func:`held_experts` and, where the family has one,
+:func:`shared_expert`.  They stay apart because the stack rematerialises the
+first and the last with its norm and leaves the second outside (see
+:func:`held_experts`).
 
 Assignments are sorted by held expert and the products run one fixed tile of
 one expert's rows at a time, for as many tiles as the held assignments fill:
@@ -54,11 +56,22 @@ from lakesoul_tpu.vector.kernels import _on_tpu
 ROUTE_SCOPE = "lakesoul.lm.moe.route"
 EXPERTS_SCOPE = "lakesoul.lm.moe.experts"
 SHARED_SCOPE = "lakesoul.lm.moe.shared"
-# rows of one expert a product takes at a time.  An expert under even routing
-# sees 320 assignments at 16,384 tokens, top-10 of 512: with 512 most experts
-# fill one tile whatever the seed, so a step's time follows the routing less
-# than with 256 (PERF.md section 6, PR 28), at 1% more time a step
+# rows of one expert a product takes at a time.  Two loads run it (PERF.md
+# section 4): 320 assignments an expert under even routing (16,384 tokens,
+# top-10 of 512), where with 512 most experts fill one tile whatever the seed
+# and a step's time follows the routing less than with 256, at 1% more time a
+# step (PERF.md section 6, PR 28); and about 2,000 (top-4 of 32), four tiles
+# or so an expert of which the last is part padding
 EXPERT_TILE = 512
+
+
+def _router_logits(x, router_w):
+    """Every expert's logit, float32: the product at ``HIGHEST``, since which
+    experts a token takes hangs on the last bits of its scores."""
+    return jnp.einsum(
+        "...h,he->...e", x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 def route_top_k(x, router_w, *, top_k: int):
@@ -66,12 +79,26 @@ def route_top_k(x, router_w, *, top_k: int):
     softmax over every expert in float32, the ``top_k`` largest, their
     weights divided by their sum."""
     with jax.named_scope(ROUTE_SCOPE):
-        logits = jnp.einsum(
-            "...h,he->...e", x.astype(jnp.float32), router_w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        top_p, top_e = jax.lax.top_k(jax.nn.softmax(_router_logits(x, router_w), axis=-1), top_k)
         return top_e.astype(jnp.int32), top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+def route_sigmoid_top_k(x, router_w, bias, *, top_k: int, scale: float = 1.0):
+    """Tokens ``x`` [..., h] → (experts [..., k] int32, weights [..., k] f32,
+    assignments the bias moved, int32): every expert's score is the sigmoid of
+    its logit, float32; the ``top_k`` largest of ``score + bias`` are picked
+    (``bias`` [experts] float32 steers the selection and carries no gradient),
+    and their weights are the unbiased scores over their sum plus 1e-6, times
+    ``scale``.  An assignment is moved where its expert is among the ``top_k``
+    of ``score + bias`` and not of ``score``."""
+    with jax.named_scope(ROUTE_SCOPE):
+        score = jax.nn.sigmoid(_router_logits(x, router_w))
+        _, top_e = jax.lax.top_k(score + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        picked = jnp.take_along_axis(score, top_e, axis=-1)
+        w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6) * scale
+        unbiased, _ = jax.lax.top_k(score, top_k)
+        moved = jnp.sum(picked < unbiased[..., -1:], dtype=jnp.int32)
+        return top_e.astype(jnp.int32), w, moved
 
 
 def _tile_plan(local, count: int, tile: int):
@@ -301,7 +328,7 @@ def shared_expert(x, p):
 def held_experts(x, top_e, w, p, *, n_experts: int, held: tuple[int, int],
                  batch_sharding=None, tile: int | None = None):
     """The held experts' part of a routed layer: x [..., h], the routing
-    ``top_e``, ``w`` [..., k] of :func:`route_top_k` → (y [..., h], counts).
+    ``top_e``, ``w`` [..., k] of a routing rule → (y [..., h], counts).
     Its backward pass needs ``x``, the routing and the weights and nothing it
     computed, so a caller that rematerialises its layer can leave this call
     outside: the tile loop then runs once forward, not twice."""

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lakesoul_tpu.models import qwen3_next as lm
+from lakesoul_tpu.models import causal_lm, qwen3_next as lm
 from lakesoul_tpu.models.train import (
     MOE_ASSIGNMENTS_FAMILY,
     MOE_LOAD_FAMILY,
@@ -172,8 +172,8 @@ def test_only_a_chunk_that_fills_a_lane_tile_takes_the_kernel(monkeypatch, size,
 @pytest.mark.parametrize("band, rows", [(1024, 128), (64, 16), (64, 64)],
                          ids=["one-block", "bands-of-row-blocks", "bands"])
 def test_gated_attention_blocks_equal_the_masked_softmax(params, monkeypatch, band, rows):
-    monkeypatch.setattr(lm, "ATTN_BAND", band)
-    monkeypatch.setattr(lm, "ATTN_ROWS", rows)
+    monkeypatch.setattr(causal_lm, "ATTN_BAND", band)
+    monkeypatch.setattr(causal_lm, "ATTN_ROWS", rows)
     p = params["layers"][3]["attn"]
     x = hidden(3)
     weigh = jax.random.normal(jax.random.key(4), x.shape)
@@ -197,7 +197,7 @@ def test_attention_is_causal_and_rotates_a_quarter_of_the_channels(params):
         atol=1e-5,
     )
     q = jax.random.normal(jax.random.key(6), (1, 8, 2, 16))
-    turned = lm._rotary(q, jnp.arange(8), 4, 1e7)
+    turned = causal_lm._rotary(q, jnp.arange(8), 4, 1e7)
     np.testing.assert_array_equal(turned[..., 4:], q[..., 4:])
     assert not np.allclose(turned[:, 1:, :, :4], q[:, 1:, :, :4])
 
@@ -526,7 +526,7 @@ def test_counters_for_a_known_routing(stepped):
         x = x + ref.moe(y, lp["moe"], MODEL, HELD)
     got = run["step"].counts()
     assert got == {"tokens": B * T, "moe_all": 4 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
-                   "moe_tile_rows": tile_rows}
+                   "moe_tile_rows": tile_rows, "moe_bias_moved": 0}
     assert held < tile_rows
     before = run["counted"]
     # the dp=2 step of the fixture ran after this read and counted the same batch again

@@ -460,3 +460,124 @@ def test_tile_fill_reader(check):
         assert 'kind="tile_rows"' in f.read()
     with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
         assert '{"kind": "tile_rows"}' in f.read()
+
+
+# ------------------------------------------- the second causal-LM family
+
+LFM2_SCOPES = {
+    "lakesoul.lm.conv": ("layer_metrics/conv_step_share_pct.py", 'SCOPE = "conv"'),
+    "lakesoul.lm.mlp": ("layer_metrics/mlp_step_share_pct.py", 'SCOPE = "mlp"'),
+}
+
+
+def _lfm2_cfg():
+    from lakesoul_tpu.models.lfm2_moe import Lfm2MoeConfig
+
+    return Lfm2MoeConfig(
+        vocab_size=64, hidden_size=32, layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
+        intermediate_size=48, num_attention_heads=4, num_key_value_heads=1, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=16, experts_held=(0, 4),
+    )
+
+
+def _adaptor(name: str):
+    import importlib.util
+    import sys
+
+    bench = os.path.join(REPO, "benchmarks", "chip")
+    sys.path[:0] = [p for p in (bench,) if p not in sys.path]  # the adaptor imports ``chipbench``
+    spec = importlib.util.spec_from_file_location(name, os.path.join(bench, "consumers", name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def lfm2_step_compiled_for_a_v5e() -> str:
+    """The LFM2-MoE step compiled for a described v5e, as
+    ``lm_step_compiled_for_a_v5e`` compiles the other family's: the text the
+    adaptor's ``scopes_of`` reads on the chip."""
+    import optax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from lakesoul_tpu.models import train
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = _lfm2_cfg()
+    tx = optax.adamw(1e-3)
+
+    def init(seed):
+        params = cfg.init(jax.random.key(seed))
+        return params, tx.init(train._split_buffers(params)[0])
+
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(init, np.uint32(0)),
+    )
+    ids = jax.ShapeDtypeStruct((2, 16), jnp.int32, sharding=one_chip)
+    return jax.jit(train._adamw_step(cfg.loss, tx)).lower(*state, ids, ids).compile().as_text()
+
+
+def test_lfm2_step_carries_its_two_scopes_on_a_v5e(lfm2_step_compiled_for_a_v5e):
+    """``conv_step_share_pct`` and ``mlp_step_share_pct`` sum the self time of
+    the instructions the adaptor's scope map charges to these exact names;
+    attention, routing, experts and head stay under the scopes the other
+    family's readers know, and the step has no scope that no reader sums."""
+    scope_of = _adaptor("lfm2_moe_clm").scopes_of(lfm2_step_compiled_for_a_v5e)
+    shared = set(LM_SCOPES) - {"lakesoul.lm.gdn", "lakesoul.lm.moe.shared"}
+    assert set(scope_of.values()) == shared | set(LFM2_SCOPES)
+    dots = re.findall(r"^\s*%?([\w.\-]+) = \S+ (?:convolution|fusion)\(", lfm2_step_compiled_for_a_v5e, re.MULTILINE)
+    for scope in LFM2_SCOPES:  # products among them: forward, rematerialised and backward
+        assert sum(scope_of.get(name) == scope for name in dots) >= 3, scope
+
+
+@pytest.mark.parametrize("scope", sorted(LFM2_SCOPES))
+def test_lfm2_scope_names_the_share_readers_search_for(scope):
+    from lakesoul_tpu.models.train import make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    cfg = _lfm2_cfg()
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    text = make_lm_train_step(cfg, plan, tx, shardings).lower(params, opt_state, ids, ids).as_text(debug_info=True)
+    assert "module @jit_train_step " in text
+    assert f"{scope}/" in text or f"{scope})" in text or f'{scope}"' in text
+    reader, constant = LFM2_SCOPES[scope]
+    with open(os.path.join(REPO, "benchmarks", "chip", reader)) as f:
+        assert constant in f.read()
+    with open(os.path.join(REPO, "benchmarks", "chip", "consumers", "lfm2_moe_clm.py")) as f:
+        assert 'STEP_MODULE = "jit_train_step"' in f.read()
+
+
+LFM2_READER_CHECKS = [
+    "scope_shares_of_a_hand_step", "scope_readers_give_nothing_without_their_scope",
+    "scopes_of_reads_the_two_new_scopes", "bias_moved_of_hand_counts", "bias_moved_gives_nothing_without_the_series",
+]
+
+
+@pytest.mark.parametrize("check", LFM2_READER_CHECKS)
+def test_lfm2_readers(check):
+    """The three readers the LFM2-MoE cell added through their own self-test,
+    and the series one of them divides under the name the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import MOE_ASSIGNMENTS_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_lfm2_readers", os.path.join(REPO, "benchmarks", "chip", "selftest", "lfm2_readers.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_" + name for name in LFM2_READER_CHECKS]
+    getattr(selftest, "test_" + check)()
+    assert selftest.FAMILY == MOE_ASSIGNMENTS_FAMILY
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "moe_bias_moved_pct.py")) as f:
+        assert 'kind="bias_moved"' in f.read()
+    with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
+        assert '{"kind": "bias_moved"}' in f.read()
