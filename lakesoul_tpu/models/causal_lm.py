@@ -23,6 +23,16 @@ else the embedding (a tied head).  ``params["buffers"]``, where a family has
 it, is state that no gradient and no optimizer touches (``models/train.py:
 _adamw_step``); ``buffers["layers"][i]`` belongs to layer ``i``.
 
+Softmax attention (:func:`causal_attention`, both families') is two Pallas
+kernels under one ``custom_vjp``, compiled on a TPU and in the Pallas
+interpreter elsewhere, for the head sizes and row lengths :func:`_flash_tiles`
+takes (a head of 64, 128 or 256 channels, a row of whole 128-key tiles: both
+published models at 8,192 tokens); any other shape runs the blockwise ``jnp``
+path, the kernels' twin.  Of the scores nothing leaves VMEM in either pass;
+the backward pass keeps the output and the log-sum-exp, which
+:func:`_row_by_row`'s checkpoint holds on to by name (:data:`ATTN_KEPT`), so a
+step runs the forward kernel once a row and the backward kernel once.
+
 The model may be one chip's share of an expert-parallel job: ``experts_held``
 says which of the ``num_experts`` live here (``parallel/moe.py: held_experts``)
 and ``vocab_size`` is the slice of the vocabulary the embedding, the head and
@@ -35,17 +45,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from lakesoul_tpu.models.bert import labelled_nll
 from lakesoul_tpu.parallel.moe import ROUTE_SCOPE, held_experts, shared_expert
 from lakesoul_tpu.parallel.ring_attention import block_attn
+from lakesoul_tpu.vector.kernels import _on_tpu
 
 ATTN_SCOPE = "lakesoul.lm.attn"
 MLP_SCOPE = "lakesoul.lm.mlp"
 HEAD_SCOPE = "lakesoul.lm.head"
-ATTN_BAND = 1024   # queries that share one static slice of the keys
-ATTN_ROWS = 128    # queries whose scores live at once
+ATTN_KEPT = ("attn_out", "attn_lse")  # ``checkpoint_name``s of what the flash kernels' backward pass keeps
+ATTN_BAND = 1024   # blockwise: queries that share one static slice of the keys
+ATTN_ROWS = 128    # blockwise: queries whose scores live at once
+FLASH_HEADS = (64, 128, 256)  # head sizes the flash kernels take: half a lane tile, one, two
+FLASH_KEYS = 512   # keys a tile holds, where the row has as many
+FLASH_ROWS = 1024  # score rows a tile holds: a group's heads x queries, 128 queries at least
+FLASH_ROW_ELEMENTS = 8192 * 256  # T x D at most: a row's float32 dK and dV are 8 MB each at that
+FLASH_VMEM_BYTES = 96 * 2**20    # of a v5e's 128 MiB
+MASKED = -1e30
+_NT = (((1,), (1,)), ((), ()))  # x y^T
+_TN = (((0,), (0,)), ((), ()))  # x^T y
 
 
 def normal_init(key, *shape):
@@ -84,15 +107,14 @@ def _rotary(x, positions, rotary_dim: int, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
-def causal_attention(q, k, v, *, band: int | None = None, rows: int | None = None):
-    """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
-    (scaled), k, v [B, Hkv, T, D] → [B, Hkv, G, T, D].
-
-    The queries go a band at a time against the keys up to the band's last
-    position (a static slice, so the keys after it cost nothing), and inside a
-    band ``rows`` queries at a time, each block rematerialised: no more than
-    ``rows`` rows of scores live at once, in either pass."""
-    band, rows = band or ATTN_BAND, rows or ATTN_ROWS
+def _blockwise_attention(q, k, v, band: int, rows: int):
+    """:func:`causal_attention` as whole-array ``jnp`` operations: the queries
+    go a band at a time against the keys up to the band's last position (a
+    static slice, so the keys after it cost nothing), and inside a band
+    ``rows`` queries at a time, each block rematerialised: no more than
+    ``rows`` rows of scores live at once, in either pass, and every one of
+    them in HBM.  What a shape the kernel does not take runs, and the kernel's
+    twin and reference."""
     b, hkv, groups, t, d = q.shape
 
     def block(q_blk, k_seen, v_seen, first):
@@ -118,6 +140,243 @@ def causal_attention(q, k, v, *, band: int | None = None, rows: int | None = Non
         )
         out.append(jnp.moveaxis(o, 0, 3).reshape(b, hkv, groups, end - start, d))
     return jnp.concatenate(out, axis=3)
+
+
+# The flash kernels.  A tile is a key-value head's whole group: ``G`` query
+# heads x ``bq`` queries as the rows of one score tile against ``bk`` keys, so
+# K and V are fetched once a group and dK, dV sum over it inside the kernel.
+# The grid walks the (query tile, key tile) pairs on or under the diagonal,
+# listed in two tables in SMEM: a key tile wholly after a query tile is not in
+# the list, so it is neither fetched nor multiplied, and only a query tile's
+# last key tile (the one the diagonal crosses) builds a mask.
+
+
+def _flash_tiles(t: int, groups: int, d: int):
+    """(queries, keys) a tile holds for rows of ``t`` tokens, or None where
+    the kernels do not take the shape: a head of :data:`FLASH_HEADS`, a row
+    that is whole tiles of 128 keys, and a row's float32 dK and dV held in VMEM
+    through the backward kernel (twice: the pipeline's two buffers)."""
+    if d not in FLASH_HEADS or t % 128 or t * d > FLASH_ROW_ELEMENTS:
+        return None
+    bk = next(n for n in (512, 256, 128) if n <= FLASH_KEYS and t % n == 0)
+    return max(128, min(bk, FLASH_ROWS // groups)), bk
+
+
+def _flash_steps(t: int, bq: int, bk: int):
+    """The grid's second axis: for every query tile its key tiles in order,
+    the last the one that holds the tile's diagonal → (query tile, key tile)
+    of each step, int32."""
+    pairs = [(i, j) for i in range(t // bq) for j in range((i * bq + bq - 1) // bk + 1)]
+    return tuple(jnp.asarray(a, jnp.int32) for a in zip(*pairs, strict=True))
+
+
+def _lanes(x, n: int):
+    """x [rows, 128], every lane of a row the same, as [rows, n]."""
+    return x[:, :n] if n <= 128 else jnp.tile(x, (1, n // 128))
+
+
+def _seen(i, j, bq: int, bk: int, shape, *, queries: int):
+    """Whether a score's key is at or before its query, over a tile of
+    ``shape`` whose axis ``queries`` runs over the group's rows (head-major:
+    row ``r`` is query ``r % bq`` of the tile) and whose other axis over keys."""
+    pos = i * bq + (jax.lax.broadcasted_iota(jnp.int32, shape, queries) & (bq - 1))
+    return pos >= j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - queries)
+
+
+def _flash_step(qi_ref, kj_ref, bq: int, bk: int):
+    """(query tile, key tile, the query tile's last key tile) of this grid step."""
+    i, j = qi_ref[pl.program_id(1)], kj_ref[pl.program_id(1)]
+    return i, j, (i * bq + bq - 1) // bk
+
+
+def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, bq, bk):
+    """One step: a group's query tile [G, bq, D] against a key tile [bk, D].
+    Running maximum and sum [G*bq, 128] (every lane the same) and the weighted
+    values [G*bq, D] stay in VMEM over a query tile's steps; the last of them
+    divides and writes the output and the log-sum-exp [G, 1, bq]."""
+    i, j, last = _flash_step(qi_ref, kj_ref, bq, bk)
+    groups, _, d = q_ref.shape
+    rows = groups * bq
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(masked: bool):
+        v = v_ref[...]
+        s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], _NT, preferred_element_type=f32)
+        if masked:
+            s = jnp.where(_seen(i, j, bq, bk, s.shape, queries=0), s, MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, bk))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jnp.dot(p.astype(v.dtype), v, preferred_element_type=f32)
+
+    pl.when(j < last)(functools.partial(tile, False))
+
+    @pl.when(j == last)
+    def _():
+        tile(True)
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / _lanes(l, d)).reshape(groups, bq, d).astype(o_ref.dtype)
+        lse = (m_ref[...] + jnp.log(l)).T[:1]  # [1, G*bq]: a row's queries along the lanes
+        for g in range(groups):
+            lse_ref[g] = lse[:, g * bq:(g + 1) * bq]
+
+
+def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, *, bq, bk):
+    """One step of the backward pass, on the forward kernel's grid: the scores
+    of the tile again from q, k and the log-sum-exp, keys down the sublanes
+    ([bk, G*bq]: the log-sum-exp and ``delta = sum(o * do)`` are rows, and dV
+    and dK plain products), their share of dQ into VMEM until the query tile's
+    last step, of dK and dV into the row's whole float32 dK, dV [T, D], which
+    stay in VMEM over all of a key-value head's steps."""
+    i, j, last = _flash_step(qi_ref, kj_ref, bq, bk)
+    groups, _, d = q_ref.shape
+    rows = groups * bq
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def tile(masked: bool):
+        q, do = q_ref[...].reshape(rows, d), do_ref[...].reshape(rows, d)
+        k, v = k_ref[...], v_ref[...]
+        lse = jnp.concatenate([lse_ref[g] for g in range(groups)], axis=1)
+        delta = jnp.concatenate([delta_ref[g] for g in range(groups)], axis=1)
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
+        if masked:
+            s = jnp.where(_seen(i, j, bq, bk, s.shape, queries=1), s, MASKED)
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
+        ds = (p * (dp - delta)).astype(q.dtype)
+        keys = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        dv_ref[keys, :] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=f32)
+        dk_ref[keys, :] += jnp.dot(ds, q, preferred_element_type=f32)
+        dq_acc[...] += jax.lax.dot_general(ds, k, _TN, preferred_element_type=f32)
+
+    pl.when(j < last)(functools.partial(tile, False))
+
+    @pl.when(j == last)
+    def _():
+        tile(True)
+        dq_ref[...] = dq_acc[...].reshape(groups, bq, d).astype(dq_ref.dtype)
+
+
+def _flash_grid(q, bq: int, bk: int, *, in_specs, out_specs, scratch_shapes):
+    """What the two kernels' ``pallas_call``s share, over q's [N, G, T, D]:
+    the grid (key-value heads, steps of :func:`_flash_steps`) with the two
+    tables in SMEM, and block specs by what a block follows: a query tile's
+    [G, bq, D], its per-query floats [G, 1, bq], a key tile's [bk, D], a
+    key-value head's whole [T, D]; ``in_specs`` and ``out_specs`` name those.
+    Returns (the tables, the call's keyword arguments)."""
+    n, groups, t, d = q.shape
+    tables = _flash_steps(t, bq, bk)
+    specs = {
+        "query": pl.BlockSpec((None, groups, bq, d), lambda h, s, qi, kj: (h, 0, qi[s], 0)),
+        "per_query": pl.BlockSpec((None, groups, 1, bq), lambda h, s, qi, kj: (h, 0, 0, qi[s])),
+        "keys": pl.BlockSpec((None, bk, d), lambda h, s, qi, kj: (h, kj[s], 0)),
+        "whole_row": pl.BlockSpec((None, t, d), lambda h, s, qi, kj: (h, 0, 0)),
+    }
+    return tables, dict(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n, tables[0].shape[0]), scratch_shapes=scratch_shapes,
+            in_specs=[specs[s] for s in in_specs], out_specs=[specs[s] for s in out_specs],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=FLASH_VMEM_BYTES
+        ),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
+def _flash_forward(q, k, v, *, bq: int, bk: int, interpret: bool):
+    """q [N, G, T, D], k, v [N, T, D] → (o [N, G, T, D], log-sum-exp
+    [N, G, 1, T] float32)."""
+    n, groups, t, d = q.shape
+    rows = groups * bq
+    tables, grid = _flash_grid(
+        q, bq, bk, in_specs=("query", "keys", "keys"), out_specs=("query", "per_query"),
+        scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32)] * 2 + [pltpu.VMEM((rows, d), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, bq=bq, bk=bk),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, v.dtype), jax.ShapeDtypeStruct((n, groups, 1, t), jnp.float32)),
+        name="flash_attention_fwd", interpret=interpret, **grid,
+    )(*tables, q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
+def _flash_backward(q, k, v, o, lse, do, *, bq: int, bk: int, interpret: bool):
+    """The three gradients, dK and dV summed over the group."""
+    _, groups, _, d = q.shape
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, :, None, :]
+    tables, grid = _flash_grid(
+        q, bq, bk, in_specs=("query", "keys", "keys", "query", "per_query", "per_query"),
+        out_specs=("query", "whole_row", "whole_row"), scratch_shapes=[pltpu.VMEM((groups * bq, d), jnp.float32)],
+    )
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, bq=bq, bk=bk),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), *[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2),
+        name="flash_attention_bwd", interpret=interpret, **grid,
+    )(*tables, q, k, v, do, lse, delta)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_attention(q, k, v, bq, bk):
+    return _flash_attention_fwd(q, k, v, bq, bk)[0]
+
+
+def _flash_attention_fwd(q, k, v, bq, bk):
+    o, lse = _flash_forward(q, k, v, bq=bq, bk=bk, interpret=not _on_tpu())
+    # a checkpoint around the caller may keep these two and run no second forward kernel
+    o, lse = checkpoint_name(o, ATTN_KEPT[0]), checkpoint_name(lse, ATTN_KEPT[1])
+    return o, (q, k, v, o, lse)
+
+
+def _flash_attention_bwd(bq, bk, kept, do):
+    return _flash_backward(*kept, do, bq=bq, bk=bk, interpret=not _on_tpu())
+
+
+_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
+def causal_attention(q, k, v):
+    """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
+    (scaled), k, v [B, Hkv, T, D] → [B, Hkv, G, T, D].
+
+    Operands in their own type (bfloat16 in a model), scores, maximum, sum and
+    accumulators in float32, the probabilities cast only as the second
+    product's operand, the division by the sum after the accumulation.
+
+    Where :func:`_flash_tiles` takes the shape (a head of 64, 128 or 256
+    channels, a row of whole 128-key tiles) two Pallas kernels under one
+    ``custom_vjp`` do all of it, compiled on a TPU and in the Pallas
+    interpreter elsewhere: no score leaves VMEM in either pass.  The backward
+    pass keeps the output and the log-sum-exp, named :data:`ATTN_KEPT` for a
+    checkpoint around the caller, and computes the scores again from q, k and
+    the log-sum-exp in float32.  Every other shape runs
+    :func:`_blockwise_attention`."""
+    b, hkv, groups, t, d = q.shape
+    tiles = _flash_tiles(t, groups, d)
+    if tiles is None:
+        return _blockwise_attention(q, k, v, ATTN_BAND, ATTN_ROWS)
+    o = _flash_attention(q.reshape(b * hkv, groups, t, d), *(a.reshape(b * hkv, t, d) for a in (k, v)), *tiles)
+    return o.reshape(q.shape)
 
 
 def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_dim: int,
@@ -156,10 +415,14 @@ def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_
 def _row_by_row(mixer, x, p, batch_sharding):
     """``mixer(x, p)`` one row of ``x`` [B, T, h] at a time, each row
     rematerialised: a mixer's intermediates at 8k tokens are gigabytes a row
-    and no row needs another's.  On a mesh every device takes its own rows."""
+    and no row needs another's.  Of a row the backward pass keeps its input
+    and what the attention kernels name (:data:`ATTN_KEPT`: 34 MB a row at 32
+    heads of 64).  On a mesh every device takes its own rows."""
+
+    keep = jax.checkpoint_policies.save_only_these_names(*ATTN_KEPT)
 
     def local(x, p):
-        return jax.lax.map(jax.checkpoint(lambda row: mixer(row[None], p)[0]), x)
+        return jax.lax.map(jax.checkpoint(lambda row: mixer(row[None], p)[0], policy=keep), x)
 
     if batch_sharding is None:
         return local(x, p)

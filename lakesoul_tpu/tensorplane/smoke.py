@@ -283,6 +283,50 @@ def _run_row_copies(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"rows": n_rows, "width": width, "tile": tile, "valid": counts}
 
 
+def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.models.causal_lm import (
+        ATTN_BAND,
+        ATTN_ROWS,
+        _blockwise_attention,
+        _flash_backward,
+        _flash_forward,
+        _flash_tiles,
+    )
+
+    # a row of each causal-LM cell's attention layer (key-value heads, query
+    # heads each serves, head size), 8,192 tokens at deployed sizes (one
+    # key-value head of 256 at tiny ones): output
+    # and the three gradients against the blockwise twin, operands bfloat16
+    # as the models pass them.  Both sides round the same float32 softmax to
+    # bfloat16 (the probabilities, the output), each with its own maximum at
+    # the time of rounding, so they agree to a few bfloat16 roundings by
+    # norm (measured on a v5e at 8,192 tokens: output 1.2e-3, gradients
+    # 4.0e-3 to 4.9e-3), not element by element
+    t = min(8192, max(256, sizes.rows // 128))
+    detail = {"tokens": t}
+    for hkv, groups, d in ((8, 4, 64), (2, 8, 256)):
+        hkv = hkv if t == 8192 else 1
+        keys = jax.random.split(jax.random.key(9), 4)
+        q = (jax.random.normal(keys[0], (hkv, groups, t, d)) * d**-0.5).astype(jnp.bfloat16)
+        k, v = (jax.random.normal(key, (hkv, t, d)).astype(jnp.bfloat16) for key in keys[1:3])
+        do = jax.random.normal(keys[3], q.shape).astype(jnp.bfloat16)
+        tiles = dict(zip(("bq", "bk"), _flash_tiles(t, groups, d), strict=True))
+        o, lse = _flash_forward(q, k, v, **tiles, interpret=interpret)
+        got = (o, *_flash_backward(q, k, v, o, lse, do, **tiles, interpret=interpret))
+        o_twin, pull = jax.vjp(lambda *qkv: _blockwise_attention(*(a[None] for a in qkv), ATTN_BAND, ATTN_ROWS)[0], q, k, v)
+        errors = []
+        for a, b in zip(got, (o_twin, *pull(do)), strict=True):
+            a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernels' results against the blockwise twin's
+            errors.append(float(np.linalg.norm(a - b) / np.linalg.norm(b)))
+        if not max(errors) < 1e-2:
+            raise AssertionError(f"flash attention at {(hkv, groups, d)}: o, dq, dk, dv off by {errors}")
+        detail[f"head{d}"] = {"tiles": list(tiles.values()), "rel_err": [round(e, 5) for e in errors]}
+    return detail
+
+
 # --------------------------------------------------------------- multichip
 
 
@@ -423,6 +467,13 @@ def smoke_cases() -> list[SmokeCase]:
         SmokeCase(
             "models.unit_lower_inverse", "pallas", _run_unit_lower_inverse,
             kernels=("lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel",),
+        ),
+        SmokeCase(
+            "models.causal_attention", "pallas", _run_causal_attention,
+            kernels=(
+                "lakesoul_tpu/models/causal_lm.py::_flash_fwd_kernel",
+                "lakesoul_tpu/models/causal_lm.py::_flash_bwd_kernel",
+            ),
         ),
         SmokeCase(
             "parallel.moe_row_copies", "pallas", _run_row_copies,

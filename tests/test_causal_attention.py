@@ -1,0 +1,140 @@
+"""``models/causal_lm.py: causal_attention``: the flash kernels (in the Pallas
+interpreter here) against the plain masked softmax and against their blockwise
+twin, forward and every gradient, at the two published group and head sizes;
+which shapes take the kernels; what a checkpoint around the caller keeps.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from lakesoul_tpu.models import causal_lm
+
+PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256)}  # query heads a key-value head, head size
+# (tokens, FLASH_KEYS, FLASH_ROWS as a multiple of the group) → the (query, key) tiles of a row
+TILINGS = {
+    "one-tile": (128, 512, 128),            # 1 x 1: the diagonal tile alone
+    "three-by-three": (384, 128, 128),      # skipped, diagonal and full tiles: 6 steps of 9
+    "two-query-tiles-a-key-tile": (512, 256, 128),  # 4 x 2: the diagonal crosses a key tile twice
+}
+
+
+def plain_attention(q, k, v):
+    """The whole score matrix, masked, in float32."""
+    f32 = jnp.float32
+    t = q.shape[3]
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", q.astype(f32), k.astype(f32))
+    s = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None, :], s, -jnp.inf)
+    return jnp.einsum("bhgqk,bhkd->bhgqd", jax.nn.softmax(s, axis=-1), v.astype(f32))
+
+
+def operands(groups, d, t, dtype, *, kv_heads=1):
+    keys = jax.random.split(jax.random.key(t + d), 4)
+    q = (jax.random.normal(keys[0], (1, kv_heads, groups, t, d)) * d**-0.5).astype(dtype)
+    k, v = (jax.random.normal(key, (1, kv_heads, t, d)).astype(dtype) for key in keys[1:3])
+    return q, k, v, jax.random.normal(keys[3], q.shape)
+
+
+def out_and_grads(fn, q, k, v, weigh):
+    """(output, (dQ, dK, dV) of ``sum(weigh * output)``)."""
+    out, pull = jax.vjp(fn, q, k, v)
+    return out, pull(weigh.astype(out.dtype))
+
+
+def assert_close(got, want, tol):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30)) < tol
+
+
+@pytest.fixture(autouse=True)
+def _full_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 1e-2)], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("tiling", sorted(TILINGS))
+@pytest.mark.parametrize("family", sorted(PUBLISHED))
+def test_flash_kernels_equal_the_masked_softmax_and_their_twin(monkeypatch, family, tiling, dtype, tol):
+    """Output and dQ, dK, dV.  In float32 within the tolerance the families'
+    tests hold the blockwise path to; in bfloat16 (operands and output rounded,
+    everything between in float32) within a bfloat16 rounding of the float32
+    softmax over the same rounded operands, and no further from it than the
+    twin is."""
+    (groups, d), (t, keys, rows) = PUBLISHED[family], TILINGS[tiling]
+    monkeypatch.setattr(causal_lm, "FLASH_KEYS", keys)
+    monkeypatch.setattr(causal_lm, "FLASH_ROWS", rows * groups)
+    bq, bk = causal_lm._flash_tiles(t, groups, d)
+    assert (bq, bk) == (min(rows, t), min(keys, t))
+    q, k, v, weigh = operands(groups, d, t, dtype)
+    got = out_and_grads(causal_lm.causal_attention, q, k, v, weigh)
+    want = out_and_grads(plain_attention, q, k, v, weigh)
+    twin = out_and_grads(lambda *qkv: causal_lm._blockwise_attention(*qkv, 256, 64), q, k, v, weigh)
+    assert_close(got, want, tol)
+    assert_close(got, twin, tol)
+    assert got[0].dtype == v.dtype and got[1][0].dtype == q.dtype and got[1][1].dtype == k.dtype
+
+
+def test_a_key_tile_after_the_query_tile_is_no_step():
+    """The grid is the list of tiles on or under the diagonal: 6 of 8 where two
+    query tiles share a key tile, each query tile's last step its diagonal."""
+    qi, kj = causal_lm._flash_steps(512, 128, 256)
+    assert list(zip(qi.tolist(), kj.tolist(), strict=True)) == [(0, 0), (1, 0), (2, 0), (2, 1), (3, 0), (3, 1)]
+    qi, kj = causal_lm._flash_steps(8192, 256, 512)
+    assert qi.shape[0] == sum(i // 2 + 1 for i in range(32)) == 272  # of 32 x 16 = 512
+
+
+@pytest.mark.parametrize("t, groups, d, tiles", [
+    (8192, 4, 64, (256, 512)),    # the LFM2 cell's layer
+    (8192, 8, 256, (128, 512)),   # the Qwen cell's
+    (8192, 1, 128, (512, 512)),
+    (256, 4, 64, (256, 256)),
+    (128, 16, 64, (128, 128)),    # 128 queries at least: the log-sum-exp's lane tile
+    (150, 4, 64, None),           # not whole tiles of 128 keys: the families' tests' length
+    (256, 4, 8, None), (256, 4, 96, None), (256, 4, 512, None),  # heads the kernels were not compiled for
+    (16384, 8, 256, None),        # a row's dK and dV outgrow VMEM
+    (16384, 4, 64, (256, 512)),
+])
+def test_which_shapes_take_the_kernels(monkeypatch, t, groups, d, tiles):
+    assert causal_lm._flash_tiles(t, groups, d) == tiles
+    if t > 256:
+        return
+    calls = []
+    kernel = causal_lm._flash_forward
+    monkeypatch.setattr(causal_lm, "_flash_forward", lambda *a, **k: calls.append(k) or kernel(*a, **k))
+    q, k, v, _ = operands(groups, d, t, jnp.float32)
+    causal_lm.causal_attention(q, k, v)
+    # no TPU here: the interpreter
+    assert calls == ([] if tiles is None else [{"bq": tiles[0], "bk": tiles[1], "interpret": True}])
+
+
+def test_a_row_checkpoint_keeps_the_output_and_the_log_sum_exp():
+    """``_row_by_row`` rematerialises a mixer a row at a time; of the attention
+    kernels' residuals it keeps the two that are named, so the backward pass
+    runs the forward kernel for no row again: once in the program, where a
+    plain ``jax.checkpoint`` has it twice."""
+    groups, d, t = 4, 64, 128
+    q, k, v, weigh = operands(groups, d, t, jnp.float32)
+    x = jnp.stack([q[0], 2 * q[0]])
+
+    def mixer(row, p):
+        return causal_lm.causal_attention(row * p, k, v)
+
+    def grad(rows):
+        return jax.grad(lambda p: jnp.sum(weigh * rows(mixer, x, p)))
+
+    def forward_kernels(rows):
+        return str(jax.make_jaxpr(grad(rows))(jnp.float32(1.0))).count("name=flash_attention_fwd")
+
+    def plain_rows(mixer, x, p):
+        return jax.lax.map(jax.checkpoint(lambda row: mixer(row[None], p)[0]), x)
+
+    def kept_rows(mixer, x, p):
+        return causal_lm._row_by_row(mixer, x, p, None)
+
+    assert forward_kernels(plain_rows) == 2
+    assert forward_kernels(kept_rows) == 1
+    assert_close(grad(kept_rows)(jnp.float32(1.0)), grad(plain_rows)(jnp.float32(1.0)), 1e-6)

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from lakesoul_tpu.annplane import ragged
-from lakesoul_tpu.models import qwen3_next
+from lakesoul_tpu.models import causal_lm, qwen3_next
 from lakesoul_tpu.parallel import moe
 from lakesoul_tpu.tensorplane.smoke import enumerate_pallas_kernels
 from lakesoul_tpu.vector import kernels
@@ -85,6 +85,28 @@ def _put_rows(d):
     )
 
 
+def _attention_row(d):
+    # a row's attention at a published group and head size: the LFM2 cell's layer at head 64 wherever d is not the
+    # Qwen cell's (d is a vector width, not a shape of these kernels)
+    hkv, groups, d = (2, 8, 256) if d == 768 else (8, 4, 64)
+    t = 8192
+    bf16 = jnp.bfloat16
+    return _sds((hkv, groups, t, d), bf16), _sds((hkv, t, d), bf16), dict(zip(("bq", "bk"), causal_lm._flash_tiles(t, groups, d)))
+
+
+def _flash_forward(d):
+    q, k, tiles = _attention_row(d)
+    return jax.jit(lambda q, k, v: causal_lm._flash_forward(q, k, v, **tiles, interpret=False)).trace(q, k, k)
+
+
+def _flash_backward(d):
+    q, k, tiles = _attention_row(d)
+    lse = _sds((*q.shape[:2], 1, q.shape[2]))
+    return jax.jit(
+        lambda q, k, v, o, lse, do: causal_lm._flash_backward(q, k, v, o, lse, do, **tiles, interpret=False)
+    ).trace(q, k, k, q, lse, q)
+
+
 # keyed by lakelint device-index qname, like the smoke register
 TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_packed_scan_kernel": _packed_scan,
@@ -92,6 +114,8 @@ TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_packed_dot_batch_kernel": _packed_dot_batch,
     "lakesoul_tpu/vector/kernels.py::_bruteforce_kernel": _bruteforce,
     "lakesoul_tpu/annplane/ragged.py::_ragged_score_kernel": _ragged_score,
+    "lakesoul_tpu/models/causal_lm.py::_flash_fwd_kernel": _flash_forward,
+    "lakesoul_tpu/models/causal_lm.py::_flash_bwd_kernel": _flash_backward,
     "lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel": _unit_lower_inverse,
     "lakesoul_tpu/parallel/moe.py::_take_rows_kernel": _take_rows,
     "lakesoul_tpu/parallel/moe.py::_put_rows_kernel": _put_rows,
