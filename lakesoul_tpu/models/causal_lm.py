@@ -2,11 +2,13 @@
 and the pieces more than one family's mixers are made of.
 
 A family is a module with a configuration object (``models/qwen3_next.py``,
-``models/lfm2_moe.py``); nothing here or in ``models/train.py`` names one.  The
-stack reads a layer's kinds from the configuration and asks it for the rest:
+``models/lfm2_moe.py``, ``models/glm4_moe_lite.py``); nothing here or in
+``models/train.py`` names one.  The stack reads a layer's kinds from the
+configuration and asks it for the rest:
 
-- ``layer_kinds()``: each layer's mixer (``"gdn"``, ``"attn"``, ``"conv"``),
-  which is also the key of the mixer's weights in the layer;
+- ``layer_kinds()``: each layer's mixer (``"gdn"``, ``"attn"``, ``"conv"``,
+  ``"mla"``: latent attention, :func:`latent_attention`), which is also the
+  key of the mixer's weights in the layer;
 - ``ffn_kinds()``: each layer's feed-forward, ``"dense"`` (SwiGLU, weights
   under ``"mlp"``) or ``"moe"`` (routed experts, under ``"moe"``);
 - ``mixer(kind)`` → (``fn(y, p)`` on the normed input, the ``named_scope`` its
@@ -15,19 +17,29 @@ stack reads a layer's kinds from the configuration and asks it for the rest:
 - ``route(y32, router, bias)`` → (experts, weights, assignments the bias
   moved): its routing rule over ``parallel/moe.py``;
 - ``num_experts``, ``experts_held``, ``dtype``; and for ``models/train.py``
-  ``init(key)`` and ``loss(params, ids, labels, batch_sharding=)``.
+  ``init(key)`` and ``loss(params, ids, labels, batch_sharding=)``;
+- ``mtp_loss_weight``, read only where the weights hold a prediction module.
+
+The loss (:func:`lm_loss`) is the next-token cross-entropy, and where the
+weights hold a multi-token-prediction module (``params["mtp"]``) that module's
+loss times ``cfg.mtp_loss_weight`` on top (:func:`mtp_loss`): the final hidden
+states and the next token's embedding through one more layer of the stack's
+last kind, with its own weights, to the token after next, through the main
+model's embedding and head matrix.  The head then runs twice a step.
 
 A layer is ``h = x + mixer(norm1(x)); x' = h + ffn(norm2(h))``; after the last
 a final norm and the head: ``params["head"]`` [h, vocab] where there is one,
 else the embedding (a tied head).  ``params["buffers"]``, where a family has
 it, is state that no gradient and no optimizer touches (``models/train.py:
-_adamw_step``); ``buffers["layers"][i]`` belongs to layer ``i``.
+_adamw_step``); ``buffers["layers"][i]`` belongs to layer ``i`` and
+``buffers["mtp"]`` to the prediction module's layer.
 
-Softmax attention (:func:`causal_attention`, both families') is two Pallas
+Softmax attention (:func:`causal_attention`, every family's) is two Pallas
 kernels under one ``custom_vjp``, compiled on a TPU and in the Pallas
 interpreter elsewhere, for the head sizes and row lengths :func:`_flash_tiles`
-takes (a head of 64, 128 or 256 channels, a row of whole 128-key tiles: both
-published models at 8,192 tokens); any other shape runs the blockwise ``jnp``
+takes (a head of 64, 128 or 256 channels, a row of whole 128-key tiles: the
+three published models at 8,192 tokens, groups of 4, 8 and 1 query heads a
+key-value head); any other shape runs the blockwise ``jnp``
 path, the kernels' twin.  Of the scores nothing leaves VMEM in either pass;
 the backward pass keeps the output and the log-sum-exp, which
 :func:`_row_by_row`'s checkpoint holds on to by name (:data:`ATTN_KEPT`), so a
@@ -56,6 +68,8 @@ from lakesoul_tpu.parallel.ring_attention import block_attn
 from lakesoul_tpu.vector.kernels import _on_tpu
 
 ATTN_SCOPE = "lakesoul.lm.attn"
+MLA_SCOPE = "lakesoul.lm.mla"  # inside ATTN_SCOPE: what latent attention adds around the kernels
+MTP_SCOPE = "lakesoul.lm.mtp"  # the whole prediction module, its layer's and its head's scopes inside
 MLP_SCOPE = "lakesoul.lm.mlp"
 HEAD_SCOPE = "lakesoul.lm.head"
 ATTN_KEPT = ("attn_out", "attn_lse")  # ``checkpoint_name``s of what the flash kernels' backward pass keeps
@@ -409,6 +423,44 @@ def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_
     return o.reshape(b, t, heads * d) @ p["w_o"].astype(dtype)
 
 
+def latent_attention(x, p, *, heads: int, nope_dim: int, rope_dim: int, theta: float, norm):
+    """The latent-attention mixer (multi-head latent attention, unabsorbed: a
+    training step's form, whose gradients reach the up-projections): x
+    [B, T, h] (normed) → [B, T, h].
+
+    ``c_q = norm(x W_dq)``, a head's query ``[q_nope | q_rope] = c_q W_uq``;
+    ``[c_kv | k_r] = x W_dkv``, ``c_kv`` normed, a head's ``[k_nope | v] = c_kv
+    W_ukv``; ``q_rope`` and ``k_r`` rotated over all their ``rope_dim``
+    channels, and ``k_r`` is ONE head that every query head's key ends in.
+    Scores over ``nope_dim + rope_dim`` channels, scaled by their root; values
+    as wide (``w_ukv`` says how wide: :func:`causal_attention` is one head
+    size).  No norm over a head, no gate.  ``norm(a, w)`` is the family's RMS
+    norm (``q_norm``, ``kv_norm`` over the latents)."""
+    dtype = x.dtype
+    f32 = jnp.float32
+    b, t, _ = x.shape
+    d = nope_dim + rope_dim
+    with jax.named_scope(MLA_SCOPE):
+        c_q = norm(x @ p["w_dq"].astype(dtype), p["q_norm"]).astype(dtype)
+        q = (c_q @ p["w_uq"].astype(dtype)).reshape(b, t, heads, d)
+        down = x @ p["w_dkv"].astype(dtype)
+        latent = p["kv_norm"].shape[0]
+        c_kv = norm(down[..., :latent], p["kv_norm"]).astype(dtype)
+        kv = (c_kv @ p["w_ukv"].astype(dtype)).reshape(b, t, heads, -1)
+        k_nope, v = kv[..., :nope_dim], kv[..., nope_dim:]
+        positions = jnp.arange(t)
+        q = q.astype(f32)
+        q = jnp.concatenate([q[..., :nope_dim], _rotary(q[..., nope_dim:], positions, rope_dim, theta)], axis=-1)
+        q = (q * d**-0.5).astype(dtype)
+        k_rope = _rotary(down[..., None, latent:].astype(f32), positions, rope_dim, theta).astype(dtype)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, t, heads, rope_dim))], axis=-1)
+    # [B, T, heads, D] → [B, heads, 1, T, D]: every head has its own keys and values
+    q = q.transpose(0, 2, 1, 3)[:, :, None]
+    k, v = (a.transpose(0, 2, 1, 3) for a in (k, v))
+    o = causal_attention(q, k, v)
+    return o[:, :, 0].transpose(0, 2, 1, 3).reshape(b, t, -1) @ p["w_o"].astype(dtype)
+
+
 # ------------------------------------------------------------- the stack
 
 
@@ -518,10 +570,69 @@ def lm_logits(params, ids, *, cfg):
     return lm_head(head_params(params), x, cfg=cfg)
 
 
+def mtp_hidden(params, x, labels, *, cfg, batch_sharding=None):
+    """The multi-token-prediction module up to its head (one module, depth 1:
+    DeepSeek-V3 report, arXiv:2412.19437, section 2.2): ``x`` [B, T, h] the
+    main stack's final hidden states, ``labels`` the row's next tokens (-100
+    where there is none) → (the module's hidden states [B, T, h] before its
+    head norm, its expert layer's counts or None).
+
+    ``h'_i = [norm_e(Emb(t_{i+1})) ; norm_h(final_norm(h_i))] W_eh``, then one
+    layer of the stack's last kind with the module's own weights and buffers.
+    The embedding is the main model's.  The next token is read off ``labels``;
+    where a row has none (its last position) token 0 stands in, and that
+    position has no label two ahead either."""
+    p = params["mtp"]
+    dtype = jnp.dtype(cfg.dtype)
+
+    @jax.checkpoint
+    def merged(x, embed, final_norm, p):
+        e = cfg.norm(embed[jnp.maximum(labels, 0)].astype(dtype), p["enorm"])
+        h = cfg.norm(cfg.norm(x, final_norm).astype(dtype), p["hnorm"])
+        return jnp.concatenate([e, h], axis=-1).astype(dtype) @ p["eh_proj"].astype(dtype)
+
+    h = merged(x, params["embed"], params["final_norm"], {k: p[k] for k in ("enorm", "hnorm", "eh_proj")})
+    return lm_layer(
+        h, p["layer"], params.get("buffers", {}).get("mtp"), kind=cfg.layer_kinds()[-1],
+        ffn=cfg.ffn_kinds()[-1], cfg=cfg, batch_sharding=batch_sharding,
+    )
+
+
+def mtp_head_params(params) -> dict:
+    """:func:`head_params` of the prediction module: its own norm and the
+    main model's head matrix (one copy, gradients from both uses)."""
+    return dict(head_params(params), final_norm=params["mtp"]["shared_head_norm"])
+
+
+def mtp_loss(params, x, labels, *, cfg, batch_sharding=None):
+    """The prediction module's loss: the mean cross-entropy of the token after
+    next over the positions that have one → (loss, the module's expert
+    layer's counts or None, those positions' count)."""
+    after_next = jnp.concatenate([labels[:, 1:], jnp.full_like(labels[:, :1], -100)], axis=1)
+    with jax.named_scope(MTP_SCOPE):
+        h, counts = mtp_hidden(params, x, labels, cfg=cfg, batch_sharding=batch_sharding)
+        loss, _ = labelled_nll(
+            functools.partial(lm_head, cfg=cfg), mtp_head_params(params), h, after_next, batch_sharding
+        )
+    return loss, counts, jnp.sum(after_next >= 0, dtype=jnp.int32)
+
+
 def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     """Next-token cross-entropy, float32, mean over the positions with
-    ``labels >= 0`` (-100 elsewhere) → (loss, counts).  ``counts``: the expert
-    layers' (summed over layers) and ``tokens``, int32."""
+    ``labels >= 0`` (-100 elsewhere), and where the weights hold a prediction
+    module ``cfg.mtp_loss_weight`` times :func:`mtp_loss` on top → (loss,
+    counts).  ``counts``: the expert layers' (summed over layers, the
+    module's among them), ``tokens``, and the positions with a label,
+    ``head_all`` over both losses and ``head_mtp`` the module's (0 without
+    one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
     loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)
-    return loss, dict(counts, tokens=jnp.int32(ids.size))
+    labelled = jnp.sum(labels >= 0, dtype=jnp.int32)
+    terms = {"loss_main": loss, "loss_mtp": jnp.float32(0.0)}
+    second = jnp.int32(0)
+    if "mtp" in params:
+        terms["loss_mtp"], more, second = mtp_loss(params, x, labels, cfg=cfg, batch_sharding=batch_sharding)
+        loss = loss + cfg.mtp_loss_weight * terms["loss_mtp"]
+        if more is not None:
+            counts = more if counts is None else jax.tree.map(jnp.add, counts, more)
+    return loss, dict(counts, **terms, tokens=jnp.int32(ids.size), head_all=labelled + second, head_mtp=second)

@@ -79,6 +79,11 @@ def _init_train_state(init_params, mesh, shardings, lr: float, seed: int):
     return params, opt_state, tx, shardings
 
 
+# ``{kind="all"}`` is the denominator of whichever step feeds the family, and the two kinds of step
+# count it differently: the MLM step counts every position of the batch, labelled or not, beside
+# ``{kind="computed"}``, the positions its head ran over; an LM step counts the positions that carry a
+# label, summed over every loss term it adds (next token, and the prediction module's where there is
+# one), beside ``{kind="mtp"}``, the module's term alone.  A process that runs both kinds sums both.
 HEAD_POSITIONS_FAMILY = "lakesoul_train_head_positions_total"
 TOKENS_FAMILY = "lakesoul_train_tokens_total"
 MOE_ASSIGNMENTS_FAMILY = "lakesoul_train_moe_assignments_total"
@@ -311,6 +316,9 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     """Jitted next-token train step of whichever family ``cfg`` is (its
     ``loss``): (params, opt_state, input_ids, labels) → (params, opt_state,
     loss); rows arrive sharded P('dp').  Feeds ``lakesoul_train_tokens_total``,
+    ``lakesoul_train_head_positions_total{kind="all"|"mtp"}`` (the positions
+    with a label, over every loss the step sums and over its multi-token-
+    prediction module's alone, 0 for a family without one),
     ``lakesoul_train_moe_assignments_total{kind="held"|"all"|"tile_rows"|
     "bias_moved"}`` (``tile_rows``: the slots of the expert tiles run, of which
     ``held`` carried an assignment; ``bias_moved``: the assignments whose
@@ -322,6 +330,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
     series = (
         ("tokens", TOKENS_FAMILY, {}, 1),
+        ("head_mtp", HEAD_POSITIONS_FAMILY, {"kind": "mtp"}, 1),
+        ("head_all", HEAD_POSITIONS_FAMILY, {"kind": "all"}, 1),
         ("moe_held", MOE_ASSIGNMENTS_FAMILY, {"kind": "held"}, 1),
         ("moe_all", MOE_ASSIGNMENTS_FAMILY, {"kind": "all"}, 1),
         ("moe_tile_rows", MOE_ASSIGNMENTS_FAMILY, {"kind": "tile_rows"}, 1),

@@ -10,7 +10,12 @@ The layer is three pieces and the layer stack composes them
 (``models/causal_lm.py: lm_layer``): a routing rule (:func:`route_top_k`:
 softmax; :func:`route_sigmoid_top_k`: sigmoid scores picked under a per-expert
 bias), :func:`held_experts` and, where the family has one,
-:func:`shared_expert`.  They stay apart because the stack rematerialises the
+:func:`shared_expert`.  What each family passes: ``models/qwen3_next.py``
+:func:`route_top_k` and a shared expert under its gate; ``models/lfm2_moe.py``
+:func:`route_sigmoid_top_k` at ``scale`` 1 with the default ``eps`` 1e-6 and no
+shared expert; ``models/glm4_moe_lite.py`` :func:`route_sigmoid_top_k` at
+``scale`` 1.8 and ``eps`` 1e-20 and a shared expert with no gate (its weights
+hold no ``"gate"``).  They stay apart because the stack rematerialises the
 first and the last with its norm and leaves the second outside (see
 :func:`held_experts`).
 
@@ -83,19 +88,19 @@ def route_top_k(x, router_w, *, top_k: int):
         return top_e.astype(jnp.int32), top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
 
-def route_sigmoid_top_k(x, router_w, bias, *, top_k: int, scale: float = 1.0):
+def route_sigmoid_top_k(x, router_w, bias, *, top_k: int, scale: float = 1.0, eps: float = 1e-6):
     """Tokens ``x`` [..., h] → (experts [..., k] int32, weights [..., k] f32,
     assignments the bias moved, int32): every expert's score is the sigmoid of
     its logit, float32; the ``top_k`` largest of ``score + bias`` are picked
     (``bias`` [experts] float32 steers the selection and carries no gradient),
-    and their weights are the unbiased scores over their sum plus 1e-6, times
-    ``scale``.  An assignment is moved where its expert is among the ``top_k``
-    of ``score + bias`` and not of ``score``."""
+    and their weights are the unbiased scores over their sum plus ``eps``,
+    times ``scale``.  An assignment is moved where its expert is among the
+    ``top_k`` of ``score + bias`` and not of ``score``."""
     with jax.named_scope(ROUTE_SCOPE):
         score = jax.nn.sigmoid(_router_logits(x, router_w))
         _, top_e = jax.lax.top_k(score + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
         picked = jnp.take_along_axis(score, top_e, axis=-1)
-        w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6) * scale
+        w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps) * scale
         unbiased, _ = jax.lax.top_k(score, top_k)
         moved = jnp.sum(picked < unbiased[..., -1:], dtype=jnp.int32)
         return top_e.astype(jnp.int32), w, moved
@@ -316,11 +321,14 @@ def _routed_share(x, top_e, w, wg, wu, wd, *, held, tile, axes):
 
 
 def shared_expert(x, p):
-    """The expert every token takes, under its sigmoid gate: x [..., h]."""
+    """The expert every token takes: x [..., h] → [..., h]; under a sigmoid
+    gate of its own where the weights hold one (``p["gate"]`` [h])."""
     dtype = x.dtype
     with jax.named_scope(SHARED_SCOPE):
         mid = jax.nn.silu(x @ p["w_gate"].astype(dtype)) * (x @ p["w_up"].astype(dtype))
         out = mid @ p["w_down"].astype(dtype)
+        if "gate" not in p:
+            return out
         gate = jax.nn.sigmoid(jnp.einsum("...h,h->...", x.astype(jnp.float32), p["gate"].astype(jnp.float32)))
         return (out * gate[..., None]).astype(dtype)
 

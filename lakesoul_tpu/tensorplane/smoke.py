@@ -307,7 +307,7 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     # 4.0e-3 to 4.9e-3), not element by element
     t = min(8192, max(256, sizes.rows // 128))
     detail = {"tokens": t}
-    for hkv, groups, d in ((8, 4, 64), (2, 8, 256)):
+    for hkv, groups, d in ((8, 4, 64), (2, 8, 256), (20, 1, 256)):
         hkv = hkv if t == 8192 else 1
         keys = jax.random.split(jax.random.key(9), 4)
         q = (jax.random.normal(keys[0], (hkv, groups, t, d)) * d**-0.5).astype(jnp.bfloat16)
@@ -323,7 +323,7 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
             errors.append(float(np.linalg.norm(a - b) / np.linalg.norm(b)))
         if not max(errors) < 1e-2:
             raise AssertionError(f"flash attention at {(hkv, groups, d)}: o, dq, dk, dv off by {errors}")
-        detail[f"head{d}"] = {"tiles": list(tiles.values()), "rel_err": [round(e, 5) for e in errors]}
+        detail[f"group{groups}.head{d}"] = {"tiles": list(tiles.values()), "rel_err": [round(e, 5) for e in errors]}
     return detail
 
 

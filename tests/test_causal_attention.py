@@ -1,6 +1,6 @@
 """``models/causal_lm.py: causal_attention``: the flash kernels (in the Pallas
 interpreter here) against the plain masked softmax and against their blockwise
-twin, forward and every gradient, at the two published group and head sizes;
+twin, forward and every gradient, at the three published group and head sizes;
 which shapes take the kernels; what a checkpoint around the caller keeps.
 """
 
@@ -12,7 +12,7 @@ import pytest
 
 from lakesoul_tpu.models import causal_lm
 
-PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256)}  # query heads a key-value head, head size
+PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256), "glm-4.7-flash": (1, 256)}  # query heads a key-value head, head size
 # (tokens, FLASH_KEYS, FLASH_ROWS as a multiple of the group) → the (query, key) tiles of a row
 TILINGS = {
     "one-tile": (128, 512, 128),            # 1 x 1: the diagonal tile alone
@@ -91,6 +91,7 @@ def test_a_key_tile_after_the_query_tile_is_no_step():
     (8192, 4, 64, (256, 512)),    # the LFM2 cell's layer
     (8192, 8, 256, (128, 512)),   # the Qwen cell's
     (8192, 1, 128, (512, 512)),
+    (8192, 1, 256, (512, 512)),   # the GLM cell's: latent attention, 20 key-value heads of one query head each
     (256, 4, 64, (256, 256)),
     (128, 16, 64, (128, 128)),    # 128 queries at least: the log-sum-exp's lane tile
     (150, 4, 64, None),           # not whole tiles of 128 keys: the families' tests' length

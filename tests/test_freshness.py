@@ -6,6 +6,7 @@ and the ``to_jax_iter(follow=...)`` training-source seam."""
 from __future__ import annotations
 
 import os
+import random
 import threading
 import time
 
@@ -274,9 +275,12 @@ class TestFollowerExactlyOnce:
 
 
 class TestFollowerResilience:
-    def test_transient_faults_absorbed_with_seeded_schedule(self, catalog):
+    def test_transient_faults_absorbed_with_seeded_schedule(self, catalog, monkeypatch):
         """p=0.4 flaky faults on the poll + store reads: the stream
         retries on the shared policy and delivers byte-identically."""
+        # the schedule is this test's own: the process-wide stream stands wherever the files that ran
+        # before on this worker left it, and one state in twenty draws no poll fault in six polls
+        monkeypatch.setattr(faults, "_RNG", random.Random(0))
         t = catalog.create_table("f5", SCHEMA, primary_keys=["id"], hash_bucket_num=2)
         start = now_millis() - 1
         for c in range(3):

@@ -513,11 +513,10 @@ def _adaptor(name: str):
     return module
 
 
-@pytest.fixture(scope="module")
-def lfm2_step_compiled_for_a_v5e() -> str:
-    """The LFM2-MoE step compiled for a described v5e, as
-    ``lm_step_compiled_for_a_v5e`` compiles the other family's: the text the
-    adaptor's ``scopes_of`` reads on the chip."""
+def _family_step_compiled_for_a_v5e(cfg) -> str:
+    """A family's step (``cfg.init``, ``cfg.loss``) compiled for a described
+    v5e, as ``lm_step_compiled_for_a_v5e`` compiles the first family's: the
+    text its adaptor's ``scopes_of`` reads on the chip."""
     import optax
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -530,7 +529,6 @@ def lfm2_step_compiled_for_a_v5e() -> str:
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     one_chip = SingleDeviceSharding(topo.devices[0])
-    cfg = _lfm2_cfg()
     tx = optax.adamw(1e-3)
 
     def init(seed):
@@ -546,6 +544,11 @@ def lfm2_step_compiled_for_a_v5e() -> str:
         for module in (causal_lm, moe):
             patch.setattr(module, "_on_tpu", lambda: True)  # the branch the chip takes
         return jax.jit(train._adamw_step(cfg.loss, tx)).lower(*state, ids, ids).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def lfm2_step_compiled_for_a_v5e() -> str:
+    return _family_step_compiled_for_a_v5e(_lfm2_cfg())
 
 
 @pytest.mark.parametrize("family", ["qwen3_next_clm", "lfm2_moe_clm"])
@@ -622,3 +625,105 @@ def test_lfm2_readers(check):
         assert 'kind="bias_moved"' in f.read()
     with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
         assert '{"kind": "bias_moved"}' in f.read()
+
+
+# -------------------------------------------- the third causal-LM family
+
+GLM_SCOPES = {
+    "lakesoul.lm.mla": ("layer_metrics/mla_step_share_pct.py", 'SCOPE = "mla"'),
+    "lakesoul.lm.mtp": ("layer_metrics/mtp_step_share_pct.py", 'SCOPE = "mtp"'),
+}
+
+
+def _glm_cfg():
+    from lakesoul_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
+
+    return Glm4MoeLiteConfig(  # two heads of the published 192 + 64 | 256: the mixers take their kernels at 128 tokens
+        vocab_size=64, hidden_size=256, num_hidden_layers=2, first_k_dense_replace=1, intermediate_size=48,
+        num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16, n_routed_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=16, experts_held=(0, 4),
+    )
+
+
+@pytest.fixture(scope="module")
+def glm_step_compiled_for_a_v5e() -> str:
+    """Two layers and the prediction module."""
+    return _family_step_compiled_for_a_v5e(_glm_cfg())
+
+
+def test_glm_step_charges_the_module_whole_and_the_main_stack_by_its_innermost_scope(glm_step_compiled_for_a_v5e):
+    """Three latent-attention mixers (two layers and the module's), each with
+    the forward and the backward kernel once: ``flash_attention_fwd.<n>`` and
+    ``flash_attention_bwd.<n>`` in a device trace.  The adaptor's scope map
+    charges the main stack's to ``lakesoul.lm.attn`` and the module's to
+    ``lakesoul.lm.mtp``, and the step has no scope that no reader sums."""
+    text = glm_step_compiled_for_a_v5e
+    calls = [name for name in _kernel_calls(text) if name.startswith("flash_attention")]
+    assert sorted(name.rsplit(".", 1)[0] for name in calls) == ["flash_attention_bwd"] * 3 + ["flash_attention_fwd"] * 3
+    scope_of = _adaptor("glm4_moe_lite_clm").scopes_of(text)
+    charged = sorted(scope_of.get(name) for name in calls)
+    assert charged == ["lakesoul.lm.attn"] * 4 + ["lakesoul.lm.mtp"] * 2, charged
+    shared = set(LM_SCOPES) - {"lakesoul.lm.gdn"}
+    assert set(scope_of.values()) == shared | set(GLM_SCOPES) | {"lakesoul.lm.mlp"}
+    # by the other adaptors' innermost rule nothing would be the module's but its norms and ``eh_proj``
+    innermost = _adaptor("qwen3_next_clm").scopes_of(text)
+    assert {innermost.get(name) for name in calls} == {"lakesoul.lm.attn"}
+    moved = {name for name, scope in scope_of.items() if scope == "lakesoul.lm.mtp" and innermost.get(name) != scope}
+    assert {innermost[name] for name in moved} >= {
+        "lakesoul.lm.attn", "lakesoul.lm.mla", "lakesoul.lm.moe.experts", "lakesoul.lm.head"
+    }
+    dots = re.findall(r"^\s*%?([\w.\-]+) = \S+ (?:convolution|fusion)\(", text, re.MULTILINE)
+    for scope in GLM_SCOPES:  # products among them: forward, rematerialised and backward
+        assert sum(scope_of.get(name) == scope for name in dots) >= 3, scope
+
+
+@pytest.mark.parametrize("scope", sorted(GLM_SCOPES))
+def test_glm_scope_names_the_share_readers_search_for(scope):
+    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models.train import make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    cfg = _glm_cfg()
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    text = make_lm_train_step(cfg, plan, tx, shardings).lower(params, opt_state, ids, ids).as_text(debug_info=True)
+    assert "module @jit_train_step " in text
+    assert f"{scope}/" in text or f"{scope})" in text or f'{scope}"' in text
+    assert scope in (causal_lm.MLA_SCOPE, causal_lm.MTP_SCOPE)
+    reader, constant = GLM_SCOPES[scope]
+    with open(os.path.join(REPO, "benchmarks", "chip", reader)) as f:
+        assert constant in f.read()
+    with open(os.path.join(REPO, "benchmarks", "chip", "consumers", "glm4_moe_lite_clm.py")) as f:
+        adaptor = f.read()
+    assert 'STEP_MODULE = "jit_train_step"' in adaptor and f'MTP_SCOPE = "{causal_lm.MTP_SCOPE}"' in adaptor
+
+
+GLM_READER_CHECKS = [
+    "the_module_is_charged_wherever_its_scope_stands", "scope_shares_of_a_hand_step",
+    "scope_readers_give_nothing_without_their_scope", "head_positions_of_hand_counts",
+    "head_positions_give_nothing_without_the_series",
+]
+
+
+@pytest.mark.parametrize("check", GLM_READER_CHECKS)
+def test_glm_readers(check):
+    """The three readers the GLM-4.7-Flash cell added and its adaptor's
+    charging rule through their own self-test, and the series one of them
+    divides under the name the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import HEAD_POSITIONS_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_glm4_readers", os.path.join(REPO, "benchmarks", "chip", "selftest", "glm4_readers.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_" + name for name in GLM_READER_CHECKS]
+    getattr(selftest, "test_" + check)()
+    assert selftest.FAMILY == HEAD_POSITIONS_FAMILY
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "mtp_head_positions_pct.py")) as f:
+        assert 'kind="mtp"' in f.read()
+    with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
+        assert '{"kind": "mtp"}' in f.read()

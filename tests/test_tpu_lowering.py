@@ -85,10 +85,15 @@ def _put_rows(d):
     )
 
 
+ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads, query heads each serves, head size
+    "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256),
+}
+
+
 def _attention_row(d):
     # a row's attention at a published group and head size: the LFM2 cell's layer at head 64 wherever d is not the
-    # Qwen cell's (d is a vector width, not a shape of these kernels)
-    hkv, groups, d = (2, 8, 256) if d == 768 else (8, 4, 64)
+    # Qwen cell's (d is a vector width, not a shape of these kernels), or a family of ATTENTION_ROWS by name
+    hkv, groups, d = ATTENTION_ROWS.get(d) or ATTENTION_ROWS["qwen3-next" if d == 768 else "lfm2"]
     t = 8192
     bf16 = jnp.bfloat16
     return _sds((hkv, groups, t, d), bf16), _sds((hkv, t, d), bf16), dict(zip(("bq", "bk"), causal_lm._flash_tiles(t, groups, d)))
@@ -130,4 +135,16 @@ def test_every_enumerated_kernel_has_a_lowering_case():
 @pytest.mark.parametrize("kernel", sorted(TRACERS))
 def test_kernel_lowers_for_tpu(kernel, d):
     lowered = TRACERS[kernel](d).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.mark.parametrize("family", sorted(ATTENTION_ROWS))
+@pytest.mark.parametrize("kernel", ["_flash_fwd_kernel", "_flash_bwd_kernel"])
+def test_attention_kernels_lower_at_every_published_shape(kernel, family):
+    """The three families' rows: groups of 4 at head 64, of 8 at head 256, and
+    latent attention's 20 key-value heads of one query head each at head 256
+    (tiles of 512 queries x 512 keys)."""
+    if family == "glm-4.7-flash":
+        assert causal_lm._flash_tiles(8192, 1, 256) == (512, 512)
+    lowered = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](family).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
