@@ -458,7 +458,9 @@ class DiskPageCache:
     def _store_page(self, key: str, idx: int, data: bytes) -> None:
         d = os.path.join(self.cache_dir, key)
         os.makedirs(d, exist_ok=True)
-        tmp = self._page_path(key, idx) + ".tmp"
+        # a name of this writer's own: two readers that miss the same page store it at once, and
+        # on one shared name the second's open() truncates what the first is about to rename
+        tmp = f"{self._page_path(key, idx)}.{threading.get_ident()}.tmp"
         try:
             with open(tmp, "wb") as f:
                 f.write(data)

@@ -324,63 +324,339 @@ def _unit_lower_inverse_bwd(dtype, saved, g):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _mm(eq, a, b):
+    """``einsum(eq, a, b)`` of two factors of one type, summed in float32.  Its
+    own rule so that the backward pass's products are of the forward's kind:
+    the cotangent rounded to the factors' type (what XLA's default precision
+    does to a float32 operand on a TPU; Mosaic runs a product with a float32
+    factor in several passes) and each product written so that its result
+    needs no transpose after it."""
+    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+
+
+def _mm_bwd(eq, kept, d):
+    a, b = kept
+    factors, z = eq.split("->")
+    x, y = factors.split(",")
+    d = d.astype(a.dtype)
+    da = jnp.einsum(f"{z},{y}->{x}", d, b, preferred_element_type=jnp.float32)
+    db = jnp.einsum(f"{x},{z}->{y}", a, d, preferred_element_type=jnp.float32)
+    return da.astype(a.dtype), db.astype(b.dtype)
+
+
+_mm.defvjp(lambda eq, a, b: (_mm(eq, a, b), (a, b)), _mm_bwd)
+
+
+@jax.custom_vjp
+def _decayed(state, g_last):
+    """state [H, dk, dv] under a chunk's whole decay, g_last [H, 1, 1].  Its own
+    rule only because autodiff sums g_last's cotangent over both axes at once,
+    which Mosaic does not lower."""
+    return state * jnp.exp(g_last)
+
+
+def _decayed_bwd(kept, d):
+    state, g_last = kept
+    d_g = jnp.sum(jnp.sum(d * state, axis=2, keepdims=True), axis=1, keepdims=True)
+    return d * jnp.exp(g_last), d_g * jnp.exp(g_last)
+
+
+_decayed.defvjp(lambda state, g_last: (_decayed(state, g_last), (state, g_last)), _decayed_bwd)
+
+
+def _unit_heads(x, scale: float = 1.0):
+    """x [..., d] in float32, each head's vector over its length, times ``scale``."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6) * scale
+
+
+def _delta_chunk(q, k, v, inv, g_col, g_row, g_last, beta, state, *, eps: float):
+    """One chunk of the gated delta rule for a batch of heads, the scan's body
+    and the kernels': q, k [H, C, dk] as the convolution leaves them (each
+    head's vector is normalised here, q scaled by ``dk**-0.5`` too), v
+    [H, C, dv], inv [H, C, C] in the factors' type; a token's running log
+    decay three ways, g_col [H, C, 1], g_row [H, 1, C] and the chunk's last
+    g_last [H, 1, 1], and beta [H, C, 1], float32; state [H, dk, dv] float32 →
+    (o [H, C, dv] float32: each head's output, rounded to the factors' type,
+    over its root mean square under ``eps``; the state after the chunk).
+    Factors in ``q.dtype``, norms, sums and the state in float32."""
+    lo, f32 = q.dtype, jnp.float32
+    q, k = _unit_heads(q, q.shape[-1] ** -0.5).astype(lo), _unit_heads(k).astype(lo)
+    s_lo = state.astype(lo)
+    grow = jnp.exp(g_col)
+    vb = (v.astype(f32) * beta).astype(lo)
+    kbg = (k.astype(f32) * (beta * grow)).astype(lo)
+    u = _mm("hij,hjd->hid", inv, vb)
+    w = _mm("hij,hjd->hid", inv, kbg).astype(lo)
+    v_new = (u - _mm("hik,hkv->hiv", w, s_lo)).astype(lo)
+    c = g_col.shape[1]
+    lower = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0) >= jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # masked before the exponential: above the diagonal the difference is positive and may overflow
+    decay = jnp.exp(jnp.where(lower, g_col - g_row, -jnp.inf))
+    local = _mm("hid,hjd->hij", q, k) * decay
+    q_in = (q.astype(f32) * grow).astype(lo)
+    o = _mm("hik,hkv->hiv", q_in, s_lo) + _mm("hij,hjv->hiv", local.astype(lo), v_new)
+    k_out = (k.astype(f32) * jnp.exp(g_last - g_col)).astype(lo)
+    o = o.astype(lo).astype(f32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o, _decayed(state, g_last) + _mm("hik,hiv->hkv", k_out, v_new)
+
+
+def _gated_delta_scan(q, k, v, gc, beta, inv, eps):
+    """The recurrence across chunks as a ``lax.scan`` over :func:`_delta_chunk`
+    with the state in HBM, every (row, head) a batch entry: what a chunk
+    narrower than a lane tile runs, and the kernels' twin and reference.
+    Arguments and result as :func:`_gated_delta`'s.  The backward pass keeps
+    the carried state of each chunk and computes the rest of the chunk again."""
+    b, _, hk, dk = q.shape
+    _, n, hv, c = gc.shape
+
+    def chunks_first(a, serves=1):  # [B, T, h, d] → [n, B h serves, C, d]
+        a = jnp.repeat(a.reshape(b, n, c, a.shape[2], 1, -1), serves, axis=4)
+        return a.transpose(1, 0, 3, 4, 2, 5).reshape(n, b * hv, c, -1)
+
+    def scalars_first(a):  # [B, n, hv, ...] → [n, B hv, ...]
+        return jnp.moveaxis(a, 1, 0).reshape(n, b * hv, *a.shape[3:])
+
+    @jax.checkpoint
+    def step(state, xs):
+        q_i, k_i, v_i, inv_i, gc_i, beta_i = xs
+        o_i, state = _delta_chunk(
+            q_i, k_i, v_i, inv_i, gc_i[..., None], gc_i[:, None], gc_i[:, -1:, None], beta_i[..., None], state,
+            eps=eps,
+        )
+        return state, o_i
+
+    xs = (chunks_first(q, hv // hk), chunks_first(k, hv // hk), chunks_first(v), *map(scalars_first, (inv, gc, beta)))
+    _, o = jax.lax.scan(step, jnp.zeros((b * hv, dk, v.shape[-1]), jnp.float32), xs)
+    return o.reshape(n, b, hv, c, -1).transpose(1, 0, 3, 2, 4).reshape(v.shape)
+
+
+GDN_HEADS = 8  # value heads a grid step of the recurrence's kernels holds, where a row has as many
+GDN_VMEM_BYTES = 64 * 2**20  # of a v5e's 128 MiB
+
+
+def _turned(x):
+    """A token's scalar along the lanes, [H, 1, C], down the sublanes, [H, C, 1],
+    or the other way."""
+    h, c = x.shape[0], max(x.shape[1:])
+    square = jnp.swapaxes(jnp.broadcast_to(x, (h, c, c)), 1, 2)
+    return square[:, :, :1] if x.shape[1] == 1 else square[:, :1]
+
+
+def _heads_first(ref, heads: int, d: int):
+    """A block with its heads of ``d`` channels along the lanes, as the
+    projections write them → [heads, C, d]; where the block has fewer, each
+    of them serves as many of the ``heads`` in a row."""
+    serves = heads * d // ref.shape[1]
+    return jnp.stack([ref[:, j // serves * d:(j // serves + 1) * d] for j in range(heads)])
+
+
+def _heads_along_lanes(ref, x):
+    """x [heads, C, d] into a block with its heads along the lanes:
+    :func:`_heads_first` the other way, the heads that one head of the block
+    serves summed in float32."""
+    heads, _, d = x.shape
+    serves = heads * d // ref.shape[1]
+    for j in range(heads // serves):
+        total = sum(x[j * serves + r].astype(jnp.float32) for r in range(serves))
+        ref[:, j * d:(j + 1) * d] = total.astype(ref.dtype)
+
+
+def _chunk_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref, state_shape):
+    """A grid step's blocks as :func:`_delta_chunk`'s arguments before the
+    state [H, dk, dv]: q, k [C, Hk dk] (each key head serves ``H / Hk`` value
+    heads), v [C, H dv], g, beta [H, 1, C], inv [H, C, C]."""
+    heads, dk, dv = state_shape
+    g_row = g_ref[...]
+    return (
+        _heads_first(q_ref, heads, dk), _heads_first(k_ref, heads, dk), _heads_first(v_ref, heads, dv),
+        inv_ref[...], _turned(g_row), g_row, g_row[:, :, -1:], _turned(beta_ref[...]),
+    )
+
+
+def _gated_delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref, o_ref, kept_ref, state_ref, *, eps):
+    """One chunk of a block of heads.  The float32 state [H, dk, dv] stays in
+    VMEM from a row's first chunk to its last (the grid's last axis, in
+    order); HBM sees it once a chunk, the state the chunk starts from, kept
+    for the backward kernel."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    kept_ref[...] = state_ref[...]
+    o, state_ref[...] = _delta_chunk(
+        *_chunk_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref, state_ref.shape), state_ref[...], eps=eps
+    )
+    _heads_along_lanes(o_ref, o)
+
+
+def _gated_delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref, kept_ref, do_ref,
+                            dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dinv_ref, dstate_ref, *, eps):
+    """One chunk of a block of heads, the chunks from last to first: the
+    chunk's intermediates again from the state it started from, the cotangent
+    of the state [H, dk, dv] in VMEM between chunks (nothing follows the last
+    chunk's state: zero), every cotangent by :func:`_delta_chunk`'s own
+    ``jax.vjp``; dq and dk summed over the value heads a key head serves."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    heads, _, width = dstate_ref.shape  # dv, which names v's cotangent below
+    operands = _chunk_operands(q_ref, k_ref, v_ref, g_ref, beta_ref, inv_ref, dstate_ref.shape)
+    _, pull = jax.vjp(functools.partial(_delta_chunk, eps=eps), *operands, kept_ref[...])
+    dq, dk, dv, dinv_ref[...], dg_col, dg_row, dg_last, dbeta, dstate_ref[...] = pull(
+        (_heads_first(do_ref, heads, width), dstate_ref[...])
+    )
+    _heads_along_lanes(dq_ref, dq)
+    _heads_along_lanes(dk_ref, dk)
+    _heads_along_lanes(dv_ref, dv)
+    last = jax.lax.broadcasted_iota(jnp.int32, dg_row.shape, 2) == dg_row.shape[2] - 1
+    dg_ref[...] = dg_row + _turned(dg_col) + jnp.where(last, dg_last, 0.0)
+    dbeta_ref[...] = _turned(dbeta)
+
+
+def _gated_delta_grid(q, v, gc, *, back: bool, ins, outs):
+    """What the two kernels' ``pallas_call``s share, over q [B, T, Hk, dk], v
+    [B, T, H, dv] and gc [B, n, H, C]: the grid (row, block of heads, chunk: a
+    row's chunks in order, from the last where ``back``), and block specs by
+    what a block follows, which ``ins`` and ``outs`` name: of a chunk its keys
+    [C, Hk dk] and values [C, H dv] as the projections write them, its tokens'
+    scalars [H, 1, C], its system [H, C, C] and its state [H, dk, dv].
+    Returns the call's keyword arguments."""
+    b, _, hk, dk = q.shape
+    _, n, hv, c = gc.shape
+    dv = v.shape[-1]
+    serves = hv // hk
+    heads = next(h for h in range(max(GDN_HEADS, serves), 0, -1) if hv % h == 0 and h % serves == 0)
+
+    def by_lanes(width):
+        return pl.BlockSpec((None, c, width), lambda r, h, i: (r, n - 1 - i if back else i, h))
+
+    def by_head(*block):
+        return pl.BlockSpec((None, None, heads, *block), lambda r, h, i: (r, n - 1 - i if back else i, h, 0, 0))
+
+    specs = {
+        "keys": by_lanes(heads // serves * dk), "values": by_lanes(heads * dv),
+        "tokens": by_head(1, c), "system": by_head(c, c), "state": by_head(dk, dv),
+    }
+    return dict(
+        grid=(b, hv // heads, n), in_specs=[specs[s] for s in ins], out_specs=[specs[s] for s in outs],
+        scratch_shapes=[pltpu.VMEM((heads, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=GDN_VMEM_BYTES
+        ),
+    )
+
+
+def _kernel_layout(q, k, v, gc, beta):
+    """The kernels' views of their operands: heads x channels in one axis, as
+    the projections write them, and a chunk's scalars as rows [1, C]."""
+    return *(a.reshape(*a.shape[:2], -1) for a in (q, k, v)), gc[..., None, :], beta[..., None, :]
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _gated_delta_forward(q, k, v, gc, beta, inv, *, eps: float, interpret: bool):
+    """→ (o as v in float32, the state each chunk starts from [B, n, H, dk, dv] float32)."""
+    grid = _gated_delta_grid(
+        q, v, gc, back=False, ins=("keys", "keys", "values", "tokens", "tokens", "system"), outs=("values", "state")
+    )
+    flat = jax.ShapeDtypeStruct((*v.shape[:2], v.shape[2] * v.shape[3]), jnp.float32)
+    states = jax.ShapeDtypeStruct((*inv.shape[:3], q.shape[-1], v.shape[-1]), jnp.float32)
+    o, states = pl.pallas_call(
+        functools.partial(_gated_delta_fwd_kernel, eps=eps), out_shape=(flat, states),
+        name="gated_delta_fwd", interpret=interpret, **grid,
+    )(*_kernel_layout(q, k, v, gc, beta), inv)
+    return o.reshape(v.shape), states
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _gated_delta_backward(q, k, v, gc, beta, inv, states, do, *, eps: float, interpret: bool):
+    """The six cotangents, in their arguments' shapes and types."""
+    grid = _gated_delta_grid(
+        q, v, gc, back=True, ins=("keys", "keys", "values", "tokens", "tokens", "system", "state", "values"),
+        outs=("keys", "keys", "values", "tokens", "tokens", "system"),
+    )
+    flat = _kernel_layout(q, k, v, gc, beta)
+    dq, dk, dv, dg, dbeta, dinv = pl.pallas_call(
+        functools.partial(_gated_delta_bwd_kernel, eps=eps),
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (*flat, inv)],
+        name="gated_delta_bwd", interpret=interpret, **grid,
+    )(*flat, inv, states, do.reshape(flat[2].shape))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), dg[..., 0, :], dbeta[..., 0, :], dinv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _gated_delta(q, k, v, gc, beta, inv, eps):
+    """The recurrence across chunks as two Pallas kernels: q, k [B, T, Hk, dk],
+    v [B, T, H, dv] (``T = n C``, each key head serving ``H / Hk`` value
+    heads), a chunk's running log decay gc and beta [B, n, H, C] float32, the
+    chunk systems' inverses inv [B, n, H, C, C] → o [B, T, H, dv] float32, as
+    :func:`_delta_chunk` leaves it."""
+    return _gated_delta_fwd(q, k, v, gc, beta, inv, eps)[0]
+
+
+def _gated_delta_fwd(q, k, v, gc, beta, inv, eps):
+    o, states = _gated_delta_forward(q, k, v, gc, beta, inv, eps=eps, interpret=not _on_tpu())
+    return o, (q, k, v, gc, beta, inv, states)
+
+
+def _gated_delta_bwd(eps, kept, do):
+    return _gated_delta_backward(*kept, do, eps=eps, interpret=not _on_tpu())
+
+
+_gated_delta.defvjp(_gated_delta_fwd, _gated_delta_bwd)
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, *, eps: float, chunk: int | None = None):
     """The gated delta rule, a chunk of tokens at a time.
 
-    q, k [B, T, H, dk] (normalised and scaled), v [B, T, H, dv], g [B, T, H]
-    float32 log decay, beta [B, T, H] float32 → o [B, T, H, dv], equal to the
-    recurrence ``S' = exp(g_t) S; u = beta_t (v_t - S'^T k_t); S = S' + k_t u^T;
-    o_t = S^T q_t`` from ``S_0 = 0``.  Inside a chunk the tokens' updates are
-    solved together: a unit lower-triangular system, made from the keys'
-    products and the decay and inverted for every chunk at once by
-    :func:`unit_lower_inverse` (at ``GDN_CHUNK`` = 128 one Pallas kernel with
-    each system in VMEM; a chunk under 128, as the tests use, takes its ``jnp``
-    twin).  Across chunks the float32 state is carried by a scan whose backward
-    pass keeps one state a chunk and computes the rest of the chunk again."""
+    q, k [B, T, Hk, dk] as the convolution leaves them, v [B, T, H, dv] (each
+    key head serves ``H / Hk`` value heads in a row), g [B, T, H] float32 log
+    decay, beta [B, T, H] float32 → o [B, T, H, dv] float32: the recurrence
+    ``S' = exp(g_t) S; u = beta_t (v_t - S'^T k_t); S = S' + k_t u^T; o_t = S^T
+    q_t`` from ``S_0 = 0`` over each head's q and k normalised
+    (:func:`_unit_heads`; q scaled by ``dk**-0.5``), each ``o_t`` rounded to
+    q's type and then over its root mean square under ``eps`` (the mixer's
+    output norm before its weight).  The chunk body does both norms on the
+    rows it holds, so the normalised q and the unnormalised o are never
+    written, and the normalised k only for the chunk systems.  Inside a chunk
+    the tokens' updates are solved together: a unit lower-triangular system,
+    made from the keys' products and the decay and inverted for every chunk at
+    once by :func:`unit_lower_inverse`.  Across chunks the float32 state
+    [dk, dv] of a head is carried through :func:`_delta_chunk`.
+
+    What runs where: with whole lane tiles (``chunk``, ``dk`` and ``dv``
+    multiples of 128: ``GDN_CHUNK`` at the published head sizes) the inverse is
+    one Pallas kernel and the recurrence two under one ``custom_vjp``
+    (:func:`_gated_delta`: a block of heads' state in VMEM through a row's
+    chunks, q, k, v read and o written where the projections' layout has them,
+    no key head repeated; the backward kernel walks the chunks from the last
+    with the state's cotangent in VMEM and computes each chunk again from the
+    state the forward kernel kept for it), compiled on a TPU and in the Pallas
+    interpreter elsewhere.  Any other shape, as the tests' chunks under 128,
+    takes the kernels' ``jnp`` twins: the doubling as whole-array operations
+    and :func:`_gated_delta_scan`."""
     chunk = chunk or GDN_CHUNK
-    lo = q.dtype
-    f32 = jnp.float32
-    b, t, h, dk = q.shape
+    lo, f32 = q.dtype, jnp.float32
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
     pad = -t % chunk
     if pad:  # a token with k = 0 and beta = 0 leaves the state as it is
         q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
         g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
     n = (t + pad) // chunk
-    # chunks first, for the scan: [n, B, chunk, H, ...]
-    q, k, v, g, beta = (
-        jnp.moveaxis(a.reshape(b, n, chunk, *a.shape[2:]), 1, 0) for a in (q, k, v, g, beta)
-    )
-    gc = jnp.cumsum(g.astype(f32), axis=2)  # the decay's running sum inside the chunk
-    beta = beta.astype(f32)
-    kb = (k.astype(f32) * beta[..., None]).astype(lo)
-    kk = jnp.einsum("nbihd,nbjhd->nbhij", kb, k, preferred_element_type=f32)
+    # a chunk's scalars with its tokens last: [B, n, H, chunk]
+    g, beta = (jnp.swapaxes(a.astype(f32).reshape(b, n, chunk, hv), 2, 3) for a in (g, beta))
+    gc = jnp.cumsum(g, axis=-1)  # the decay's running sum inside the chunk
+    k_n = jnp.swapaxes(_unit_heads(k).astype(lo).reshape(b, n, chunk, hk, dk), 2, 3)  # [B, n, Hk, chunk, dk]
+    kb = (k_n[:, :, :, None].astype(f32) * beta.reshape(b, n, hk, hv // hk, chunk, 1)).astype(lo)
+    kk = jnp.einsum("bnhsid,bnhjd->bnhsij", kb, k_n, preferred_element_type=f32)
     # (I + A)^-1, A the strictly lower part: every token's update given the ones before it
-    inv = unit_lower_inverse(kk, lo, jnp.swapaxes(gc, -1, -2)).astype(lo)
-
-    @jax.checkpoint  # the backward pass keeps the carried state of each chunk and nothing else
-    def step(state, xs):
-        q_i, k_i, v_i, gc_i, beta_i, inv_i = xs
-        s_lo = state.astype(lo)
-        grow = jnp.exp(gc_i)[..., None]
-        g_last = gc_i[:, -1]  # [B, H]
-        vb = (v_i.astype(f32) * beta_i[..., None]).astype(lo)
-        kbg = (k_i.astype(f32) * (beta_i[..., None] * grow)).astype(lo)
-        u = jnp.einsum("bhij,bjhd->bihd", inv_i, vb, preferred_element_type=f32)
-        w = jnp.einsum("bhij,bjhd->bihd", inv_i, kbg)
-        v_new = (u - jnp.einsum("bihk,bhkv->bihv", w, s_lo, preferred_element_type=f32)).astype(lo)
-        decay = _chunk_decay(jnp.swapaxes(gc_i, -1, -2))  # [B, H, chunk, chunk]
-        local = jnp.einsum("bihd,bjhd->bhij", q_i, k_i, preferred_element_type=f32) * decay
-        q_in = (q_i.astype(f32) * grow).astype(lo)
-        o_i = (jnp.einsum("bihk,bhkv->bihv", q_in, s_lo, preferred_element_type=f32)
-               + jnp.einsum("bhij,bjhv->bihv", local.astype(lo), v_new, preferred_element_type=f32))
-        k_out = (k_i.astype(f32) * jnp.exp(g_last[:, None] - gc_i)[..., None]).astype(lo)
-        state = state * jnp.exp(g_last)[..., None, None] + jnp.einsum(
-            "bihk,bihv->bhkv", k_out, v_new, preferred_element_type=f32
-        )
-        return state, o_i.astype(lo)
-
-    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), f32), (q, k, v, gc, beta, inv))
-    return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, -1)[:, :t]
+    inv = unit_lower_inverse(kk.reshape(b, n, hv, chunk, chunk), lo, gc).astype(lo)
+    in_kernels = chunk % 128 == 0 and dk % 128 == 0 and dv % 128 == 0
+    return (_gated_delta if in_kernels else _gated_delta_scan)(q, k, v, gc, beta, inv, eps)[:, :t]
 
 
 def gated_delta_net(x, p, *, cfg: Qwen3NextConfig, chunk: int | None = None):
@@ -395,17 +671,14 @@ def gated_delta_net(x, p, *, cfg: Qwen3NextConfig, chunk: int | None = None):
     conv_dim = 2 * cfg.key_dim + cfg.value_dim
     qkv = jax.nn.silu(causal_conv(qkvz[..., :conv_dim], p["conv"]).astype(f32)).astype(dtype)
     z = qkvz[..., conv_dim:].reshape(b, t, hv, dv)
-    q = qkv[..., : cfg.key_dim].reshape(b, t, hk, dk).astype(f32)
-    k = qkv[..., cfg.key_dim: 2 * cfg.key_dim].reshape(b, t, hk, dk).astype(f32)
+    q = qkv[..., : cfg.key_dim].reshape(b, t, hk, dk)
+    k = qkv[..., cfg.key_dim: 2 * cfg.key_dim].reshape(b, t, hk, dk)
     v = qkv[..., 2 * cfg.key_dim:].reshape(b, t, hv, dv)
-    q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk**-0.5
-    k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
-    # each key head serves hv // hk value heads
-    q, k = (jnp.repeat(a.astype(dtype), hv // hk, axis=2) for a in (q, k))
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
-    o = chunk_gated_delta_rule(q, k, v, g, beta, chunk=chunk)  # [B, T, hv, dv]
-    o = _rms_norm(o, p["norm"], cfg.rms_norm_eps, centred=False) * jax.nn.silu(z.astype(f32))
+    # each key head serves hv // hk value heads; q, k and each head's output are normalised inside
+    o = chunk_gated_delta_rule(q, k, v, g, beta, eps=cfg.rms_norm_eps, chunk=chunk)  # [B, T, hv, dv]
+    o = o * p["norm"] * jax.nn.silu(z.astype(f32))
     return o.reshape(b, t, hv * dv).astype(dtype) @ p["w_o"].astype(dtype)
 
 

@@ -256,6 +256,46 @@ def _run_unit_lower_inverse(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"systems": n, "chunk": c, **_agree(got, want)}
 
 
+def _run_gated_delta(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.models.qwen3_next import (
+        GDN_CHUNK,
+        _gated_delta_backward,
+        _gated_delta_forward,
+        _gated_delta_scan,
+    )
+
+    # a row of the Qwen cell's DeltaNet layer, 16 key heads serving 32 value
+    # heads of 128 over 8,192 tokens at deployed sizes (one key head and two
+    # chunks at tiny ones): the output and the six cotangents of the kernel
+    # pair against the scan over the same chunk body, operands bfloat16 as
+    # the model passes them.  The two run the same products on the same
+    # roundings; XLA and Mosaic order a sum's terms differently, so they agree
+    # to a few bfloat16 roundings by norm, not element by element
+    t, (hk, hv) = (8192, (16, 32)) if sizes.rows >= 1 << 20 else (2 * GDN_CHUNK, (1, 2))
+    n, c, d, lo = t // GDN_CHUNK, GDN_CHUNK, 128, jnp.bfloat16
+    keys = jax.random.split(jax.random.key(10), 7)
+    q, k = (jax.random.normal(key, (1, t, hk, d)).astype(lo) for key in keys[:2])
+    v, do = (jax.random.normal(key, (1, t, hv, d)) for key in keys[2:4])
+    v = v.astype(lo)
+    gc = jnp.cumsum(-jnp.exp(jax.random.normal(keys[4], (1, n, hv, c)) - 2), axis=-1)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, n, hv, c)))
+    inv = (jnp.eye(c) + 0.05 * jnp.tril(jax.random.normal(keys[6], (1, n, hv, c, c)), -1)).astype(lo)
+    operands, eps = (q, k, v, gc, beta, inv), 1e-6
+    o, states = _gated_delta_forward(*operands, eps=eps, interpret=interpret)
+    got = (o, *_gated_delta_backward(*operands, states, do, eps=eps, interpret=interpret))
+    o_twin, pull = jax.vjp(lambda *operands: _gated_delta_scan(*operands, eps), *operands)
+    errors = []
+    for a, b in zip(got, (o_twin, *pull(do)), strict=True):
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernels' results against the scan twin's
+        errors.append(float(np.linalg.norm(a - b) / np.linalg.norm(b)))
+    if not max(errors) < 1e-2:
+        raise AssertionError(f"gated delta rule at {(hk, hv, t)}: o, dq, dk, dv, dg, dbeta, dinv off by {errors}")
+    return {"tokens": t, "heads": [hk, hv], "rel_err": [round(e, 6) for e in errors]}
+
+
 def _run_row_copies(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax.numpy as jnp
 
@@ -467,6 +507,13 @@ def smoke_cases() -> list[SmokeCase]:
         SmokeCase(
             "models.unit_lower_inverse", "pallas", _run_unit_lower_inverse,
             kernels=("lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel",),
+        ),
+        SmokeCase(
+            "models.gated_delta_rule", "pallas", _run_gated_delta,
+            kernels=(
+                "lakesoul_tpu/models/qwen3_next.py::_gated_delta_fwd_kernel",
+                "lakesoul_tpu/models/qwen3_next.py::_gated_delta_bwd_kernel",
+            ),
         ),
         SmokeCase(
             "models.causal_attention", "pallas", _run_causal_attention,

@@ -169,6 +169,58 @@ def test_only_a_chunk_that_fills_a_lane_tile_takes_the_kernel(monkeypatch, size,
     assert calls == [{"interpret": True}] * kernel_calls  # no TPU here: the interpreter
 
 
+def delta_rule_operands(hk, hv, length, d=128, seed=21):
+    """q, k, v, log decay and beta of one row, as the mixer hands them to the rule."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q, k = (jax.random.normal(key, (1, length, hk, d)) for key in ks[:2])
+    v = jax.random.normal(ks[2], (1, length, hv, d))
+    g = -jnp.exp(jax.random.normal(ks[3], (1, length, hv)) - 2)
+    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (1, length, hv)))
+
+
+@pytest.mark.parametrize("length", [256, 200], ids=["whole-chunks", "part-of-a-chunk-over"])
+@pytest.mark.parametrize("serves", [1, 2], ids=["a-value-head-a-key-head", "two-value-heads-a-key-head"])
+def test_recurrence_kernels_equal_their_scan_twin(monkeypatch, serves, length):
+    """At a chunk of 128 and heads of 128 the recurrence across chunks runs as
+    two Pallas kernels (in the interpreter here); the ``lax.scan`` over the
+    same chunk body is their twin.  Values and the gradients of all five
+    inputs through the whole rule (a length that is not whole chunks is
+    padded), and the six cotangents of the pair alone, the inverse's among
+    them."""
+    operands = delta_rule_operands(2, 2 * serves, length)
+    weigh = jax.random.normal(jax.random.key(22), operands[2].shape)
+
+    def rule(*operands):
+        return jnp.sum(weigh * lm.chunk_gated_delta_rule(*operands, eps=1e-6))
+
+    got = jax.value_and_grad(rule, argnums=(0, 1, 2, 3, 4))(*operands)
+    calls = []
+    kernels = lm._gated_delta
+    with monkeypatch.context() as patch:
+        patch.setattr(lm, "_gated_delta", lambda *a: calls.append(a) or lm._gated_delta_scan(*a))
+        want = jax.value_and_grad(rule, argnums=(0, 1, 2, 3, 4))(*operands)
+        rule(*operands)  # outside a gradient: the pair's operands as arrays
+    assert len(calls) == 2
+    assert_close(got, want, tol=1e-5)
+    *made, eps = calls[1]
+    pair, twin = (jax.vjp(lambda *made: path(*made, eps), *made) for path in (kernels, lm._gated_delta_scan))
+    assert_close(pair[0], twin[0], tol=1e-6)
+    weigh = jax.random.normal(jax.random.key(23), pair[0].shape)
+    assert_close(pair[1](weigh), twin[1](weigh), tol=1e-5)
+
+
+@pytest.mark.parametrize("chunk, head, kernel_calls", [(64, 128, 0), (128, 64, 0), (128, 128, 1)],
+                         ids=["chunk-under-a-lane-tile", "head-under-a-lane-tile", "whole-lane-tiles"])
+def test_only_whole_lane_tiles_take_the_recurrence_kernels(monkeypatch, chunk, head, kernel_calls):
+    calls = []
+    kernel = lm._gated_delta_forward
+    monkeypatch.setattr(lm, "_gated_delta_forward", lambda *a, **k: calls.append(k) or kernel(*a, **k))
+    operands = delta_rule_operands(1, 2, 128, d=head)
+    got = lm.chunk_gated_delta_rule(*operands, eps=1e-6, chunk=chunk)
+    assert calls == [{"eps": 1e-6, "interpret": True}] * kernel_calls  # no TPU here: the interpreter
+    assert got.shape == operands[2].shape
+
+
 @pytest.mark.parametrize("band, rows", [(1024, 128), (64, 16), (64, 64)],
                          ids=["one-block", "bands-of-row-blocks", "bands"])
 def test_gated_attention_blocks_equal_the_masked_softmax(params, monkeypatch, band, rows):

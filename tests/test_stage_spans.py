@@ -308,10 +308,10 @@ LM_SCOPES = {
 
 
 LM_TOKENS = 128  # a row in the steps compiled for a v5e: one tile of the attention kernels
-LM_CFG = Qwen3NextConfig(  # heads of 64: the attention layer takes its kernels at 128 tokens
+LM_CFG = Qwen3NextConfig(  # heads of 64, DeltaNet heads of 128: both mixers take their kernels at 128 tokens
     vocab_size=64, hidden_size=32, num_hidden_layers=4, num_attention_heads=8,
     num_key_value_heads=1, head_dim=64, linear_num_key_heads=2, linear_num_value_heads=4,
-    linear_key_head_dim=8, linear_value_head_dim=8, num_experts=8, num_experts_per_tok=2,
+    linear_key_head_dim=128, linear_value_head_dim=128, num_experts=8, num_experts_per_tok=2,
     moe_intermediate_size=16, shared_expert_intermediate_size=16, experts_held=(0, 4),
 )
 
@@ -371,19 +371,24 @@ def _kernel_calls(compiled_text: str) -> list[str]:
     return re.findall(r"^\s*%?([\w.\-]+) = .*? custom-call\(.*tpu_custom_call", compiled_text, re.MULTILINE)
 
 
-def test_lm_step_holds_the_chunk_inverse_kernel_under_the_gdn_scope(lm_step_compiled_for_a_v5e):
-    """A device trace names the kernel's events by its instruction, which
-    takes the kernel's name: twice a DeltaNet layer in the program (forward,
-    and the row's rematerialisation), each run once a row.
-    ``gdn_step_share_pct`` counts its time only if the step's scope map (the
-    adaptor's ``scopes_of``) charges it to ``lakesoul.lm.gdn``: the reader
-    sums that exact name, so a scope of the kernel's own would take the
-    kernel out of the metric."""
+def test_lm_step_holds_the_deltanet_kernels_under_the_gdn_scope(lm_step_compiled_for_a_v5e):
+    """A device trace names a kernel's events by its instruction, which takes
+    the kernel's name.  The chunk inverse and the recurrence's forward kernel
+    stand twice a DeltaNet layer in the program (forward, and the row's
+    rematerialisation, where the forward kernel also keeps each chunk's
+    state), the recurrence's backward kernel once; each runs once a row.
+    ``gdn_step_share_pct`` counts their time only if the step's scope map (the
+    adaptor's ``scopes_of``) charges them to ``lakesoul.lm.gdn``: the reader
+    sums that exact name, so a scope of a kernel's own would take the kernel
+    out of the metric."""
     import importlib.util
 
     text = lm_step_compiled_for_a_v5e
-    calls = [name for name in _kernel_calls(text) if name.startswith("unit_lower_inverse")]
-    assert len(calls) == 2 * LM_CFG.layer_kinds().count("gdn")
+    layers = LM_CFG.layer_kinds().count("gdn")
+    calls = [name for name in _kernel_calls(text) if not name.startswith("flash_attention")]
+    assert sorted(name.rsplit(".", 1)[0] for name in calls) == (
+        ["gated_delta_bwd"] * layers + ["gated_delta_fwd"] * 2 * layers + ["unit_lower_inverse"] * 2 * layers
+    ), calls
     spec = importlib.util.spec_from_file_location(
         "qwen3_next_clm", os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")
     )

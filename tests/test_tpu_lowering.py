@@ -69,6 +69,30 @@ def _unit_lower_inverse(d):
     ).trace(_sds((*lead, c, c)), _sds((*lead, c)))
 
 
+def _delta_row(d):
+    # one DeltaNet layer's row at the published shape: 16 key heads serving 32 value heads of 128, 64 chunks of 128
+    del d
+    t, hk, hv, dh, c = 8192, 16, 32, 128, qwen3_next.GDN_CHUNK
+    bf16 = jnp.bfloat16
+    tokens = _sds((1, t // c, hv, c))
+    return (_sds((1, t, hk, dh), bf16), _sds((1, t, hk, dh), bf16), _sds((1, t, hv, dh), bf16), tokens, tokens,
+            _sds((1, t // c, hv, c, c), bf16))
+
+
+def _gated_delta_forward(d):
+    return jax.jit(
+        lambda *operands: qwen3_next._gated_delta_forward(*operands, eps=1e-6, interpret=False)
+    ).trace(*_delta_row(d))
+
+
+def _gated_delta_backward(d):
+    q, k, v, gc, beta, inv = _delta_row(d)
+    states = _sds((*inv.shape[:3], q.shape[-1], v.shape[-1]))
+    return jax.jit(
+        lambda *operands: qwen3_next._gated_delta_backward(*operands, eps=1e-6, interpret=False)
+    ).trace(q, k, v, gc, beta, inv, states, _sds(v.shape))
+
+
 def _expert_sums(d):
     # the float32 sums over a step's 16,384 tokens at the LM cell's width, a tile of slots
     del d
@@ -122,6 +146,8 @@ TRACERS = {
     "lakesoul_tpu/models/causal_lm.py::_flash_fwd_kernel": _flash_forward,
     "lakesoul_tpu/models/causal_lm.py::_flash_bwd_kernel": _flash_backward,
     "lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel": _unit_lower_inverse,
+    "lakesoul_tpu/models/qwen3_next.py::_gated_delta_fwd_kernel": _gated_delta_forward,
+    "lakesoul_tpu/models/qwen3_next.py::_gated_delta_bwd_kernel": _gated_delta_backward,
     "lakesoul_tpu/parallel/moe.py::_take_rows_kernel": _take_rows,
     "lakesoul_tpu/parallel/moe.py::_put_rows_kernel": _put_rows,
 }
