@@ -63,7 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from lakesoul_tpu.models.bert import labelled_nll
-from lakesoul_tpu.parallel.moe import ROUTE_SCOPE, held_experts, shared_expert
+from lakesoul_tpu.parallel.moe import EXPERTS_SCOPE, ROUTE_SCOPE, SHARED_SCOPE, held_experts, shared_expert
 from lakesoul_tpu.parallel.ring_attention import block_attn
 from lakesoul_tpu.vector.kernels import _on_tpu
 
@@ -72,6 +72,7 @@ MLA_SCOPE = "lakesoul.lm.mla"  # inside ATTN_SCOPE: what latent attention adds a
 MTP_SCOPE = "lakesoul.lm.mtp"  # the whole prediction module, its layer's and its head's scopes inside
 MLP_SCOPE = "lakesoul.lm.mlp"
 HEAD_SCOPE = "lakesoul.lm.head"
+EMBED_SCOPE = "lakesoul.lm.embed"  # the token lookup and, through its transpose, the scatter-add of its gradient
 ATTN_KEPT = ("attn_out", "attn_lse")  # ``checkpoint_name``s of what the flash kernels' backward pass keeps
 ATTN_BAND = 1024   # blockwise: queries that share one static slice of the keys
 ATTN_ROWS = 128    # blockwise: queries whose scores live at once
@@ -508,44 +509,52 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
 
     @jax.checkpoint
     def dense(x, norm, p):
-        with jax.named_scope(MLP_SCOPE):
-            return dense_mlp(cfg.norm(x, norm).astype(dtype), p)
+        return dense_mlp(cfg.norm(x, norm).astype(dtype), p)
 
     @jax.checkpoint
     def routed(x, norm, router, bias, shared):
         """Norm, routing and the shared expert: cheap to compute again."""
-        with jax.named_scope(ROUTE_SCOPE):
-            y32 = cfg.norm(x, norm)
+        y32 = cfg.norm(x, norm)
         top_e, w, moved = cfg.route(y32, router, bias)
         y = y32.astype(dtype)
         return y, top_e, w, moved, None if shared is None else shared_expert(y, shared)
 
+    # a scope stands around the call, not inside the checkpointed function: what the checkpoint itself
+    # writes (the copies it keeps its inputs in) and each residual add are then the feed-forward's too
     with jax.named_scope(scope):
         x = _row_by_row(mix, x, {"norm": lp["norm1"], "mixer": lp[kind]}, batch_sharding)
     if ffn == "dense":
-        return x + dense(x, lp["norm2"], lp["mlp"]), None
+        with jax.named_scope(MLP_SCOPE):
+            return x + dense(x, lp["norm2"], lp["mlp"]), None
     p = lp["moe"]
-    y, top_e, w, moved, shared = routed(
-        x, lp["norm2"], p["router"], (buffers or {}).get("expert_bias"), p.get("shared")
-    )
+    with jax.named_scope(ROUTE_SCOPE):  # the norm and the routing; the shared expert's scope is inside
+        y, top_e, w, moved, shared = routed(
+            x, lp["norm2"], p["router"], (buffers or {}).get("expert_bias"), p.get("shared")
+        )
     out, counts = held_experts(
         y, top_e, w, p, n_experts=cfg.num_experts, held=cfg.experts_held, batch_sharding=batch_sharding
     )
-    x = x + out
-    return (x if shared is None else x + shared), dict(counts, moe_bias_moved=moved)
+    with jax.named_scope(EXPERTS_SCOPE):
+        x = x + out
+    if shared is not None:
+        with jax.named_scope(SHARED_SCOPE):
+            x = x + shared
+    return x, dict(counts, moe_bias_moved=moved)
 
 
 def lm_hidden(params, ids, *, cfg, batch_sharding=None):
     """ids [B, T] → (final hidden states [B, T, h] before the final norm,
     counts summed over the routed layers)."""
-    x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope(EMBED_SCOPE):
+        x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
     kinds, ffns = cfg.layer_kinds(), cfg.ffn_kinds()
     buffers = params.get("buffers", {}).get("layers", [None] * len(kinds))
     totals = None
     for lp, held, kind, ffn in zip(params["layers"], buffers, kinds, ffns, strict=True):
         x, counts = lm_layer(x, lp, held, kind=kind, ffn=ffn, cfg=cfg, batch_sharding=batch_sharding)
         if counts is not None:
-            totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
+            with jax.named_scope(EXPERTS_SCOPE):
+                totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
     return x, totals
 
 
@@ -608,13 +617,13 @@ def mtp_loss(params, x, labels, *, cfg, batch_sharding=None):
     """The prediction module's loss: the mean cross-entropy of the token after
     next over the positions that have one → (loss, the module's expert
     layer's counts or None, those positions' count)."""
-    after_next = jnp.concatenate([labels[:, 1:], jnp.full_like(labels[:, :1], -100)], axis=1)
     with jax.named_scope(MTP_SCOPE):
+        after_next = jnp.concatenate([labels[:, 1:], jnp.full_like(labels[:, :1], -100)], axis=1)
         h, counts = mtp_hidden(params, x, labels, cfg=cfg, batch_sharding=batch_sharding)
         loss, _ = labelled_nll(
             functools.partial(lm_head, cfg=cfg), mtp_head_params(params), h, after_next, batch_sharding
         )
-    return loss, counts, jnp.sum(after_next >= 0, dtype=jnp.int32)
+        return loss, counts, jnp.sum(after_next >= 0, dtype=jnp.int32)
 
 
 def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
@@ -626,13 +635,16 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     ``head_all`` over both losses and ``head_mtp`` the module's (0 without
     one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
-    loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)
-    labelled = jnp.sum(labels >= 0, dtype=jnp.int32)
+    with jax.named_scope(HEAD_SCOPE):  # the loss's own loop over tiles of positions around the head
+        loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)
+        labelled = jnp.sum(labels >= 0, dtype=jnp.int32)
     terms = {"loss_main": loss, "loss_mtp": jnp.float32(0.0)}
     second = jnp.int32(0)
     if "mtp" in params:
         terms["loss_mtp"], more, second = mtp_loss(params, x, labels, cfg=cfg, batch_sharding=batch_sharding)
-        loss = loss + cfg.mtp_loss_weight * terms["loss_mtp"]
-        if more is not None:
-            counts = more if counts is None else jax.tree.map(jnp.add, counts, more)
-    return loss, dict(counts, **terms, tokens=jnp.int32(ids.size), head_all=labelled + second, head_mtp=second)
+        with jax.named_scope(MTP_SCOPE):
+            loss = loss + cfg.mtp_loss_weight * terms["loss_mtp"]
+            labelled = labelled + second
+            if more is not None:
+                counts = more if counts is None else jax.tree.map(jnp.add, counts, more)
+    return loss, dict(counts, **terms, tokens=jnp.int32(ids.size), head_all=labelled, head_mtp=second)

@@ -24,7 +24,7 @@ from lakesoul_tpu.models.bert import (
     init_bert_params,
     param_sharding_rules,
 )
-from lakesoul_tpu.obs import registry
+from lakesoul_tpu.obs import registry, stage
 from lakesoul_tpu.parallel.mesh import MeshPlan
 from lakesoul_tpu.parallel.ring_attention import make_ring_attention
 
@@ -38,6 +38,8 @@ def _specs_to_shardings(mesh, rules):
 
 
 BUFFERS = "buffers"  # the subtree of a model's parameters that is carried, not trained
+# the optimizer's update of every trained leaf and the step's own counts: what a step runs after its gradients
+OPTIM_SCOPE = "lakesoul.lm.optim"
 
 
 def _split_buffers(params: dict) -> tuple[dict, dict]:
@@ -184,8 +186,9 @@ class _CountedStep:
 
             def train_step(params, opt_state, counted, *batch):
                 params, opt_state, loss, counts = step_fn(params, opt_state, *batch)
-                low = counted[:, 1] + jnp.stack([counts[key] for key in keys]).astype(jnp.int32)
-                counted = jnp.stack([counted[:, 0] + (low >> 30), low & ((1 << 30) - 1)], axis=1)
+                with jax.named_scope(OPTIM_SCOPE):
+                    low = counted[:, 1] + jnp.stack([counts[key] for key in keys]).astype(jnp.int32)
+                    counted = jnp.stack([counted[:, 0] + (low >> 30), low & ((1 << 30) - 1)], axis=1)
                 return params, opt_state, loss, counted
 
             carries = (self._param_shardings, opt_shardings)
@@ -198,11 +201,13 @@ class _CountedStep:
         return self._fn
 
     def __call__(self, params, opt_state, *batch):
-        batch = jax.device_put(batch, self._batch_shardings)
+        with stage("train.place"):
+            batch = jax.device_put(batch, self._batch_shardings)
         state = self._state
-        params, opt_state, loss, state["counted"] = self._jitted(opt_state)(
-            params, opt_state, state["counted"], *batch
-        )
+        with stage("train.dispatch"):
+            params, opt_state, loss, state["counted"] = self._jitted(opt_state)(
+                params, opt_state, state["counted"], *batch
+            )
         return params, opt_state, loss
 
     def lower(self, params, opt_state, *batch):
@@ -288,8 +293,10 @@ def _adamw_step(loss_fn, tx):
         (loss, counts), grads = jax.value_and_grad(
             lambda trained: loss_fn({**trained, **carried}, *batch), has_aux=True
         )(trained)
-        updates, opt_state = tx.update(grads, opt_state, trained)
-        return {**optax.apply_updates(trained, updates), **carried}, opt_state, loss, counts
+        with jax.named_scope(OPTIM_SCOPE):
+            updates, opt_state = tx.update(grads, opt_state, trained)
+            trained = optax.apply_updates(trained, updates)
+        return {**trained, **carried}, opt_state, loss, counts
 
     return step
 

@@ -36,7 +36,6 @@ from lakesoul_tpu.obs.fleet import (
 from lakesoul_tpu.obs.logging import JsonLogFormatter, configure_logging
 from lakesoul_tpu.obs.stages import (
     SCAN_STAGES,
-    queue_seconds_by_consumer,
     stage,
     stage_counts,
     stage_histogram,
@@ -92,7 +91,6 @@ __all__ = [
     "configure_logging",
     "serve_prometheus",
     "SCAN_STAGES",
-    "queue_seconds_by_consumer",
     "stage",
     "stage_counts",
     "stage_histogram",

@@ -1,4 +1,5 @@
-"""Scan-path stage attribution.
+"""Stage attribution of the scan→train path, from a file's bytes to the
+train step's dispatch.
 
 One histogram family — ``lakesoul_scan_stage_seconds{stage=...}`` — shared
 by every leg of the scan→train path, so the per-stage cost breakdown the
@@ -6,15 +7,26 @@ hot-path work is judged against (arxiv 2604.21275's discipline: measure per
 stage, then delete what the measurement exposes) is a queryable series, not
 a guess:
 
-=============  ==============================================================
-``decode``     file bytes → Arrow batches (format readers)
-``merge``      MOR merge-apply: loser tree / argsort + row gather
-``fill``       schema-evolution uniform (cast/null-fill) + partition columns
-``rebatch``    fixed-size window assembly in the loader
-``collate``    Arrow window → numpy pytree (+ user transform)
-``queue``      consumer stall on the loader's prefetch queue
-``device_put`` host batch → device transfer dispatch
-=============  ==============================================================
+==================  =========================================================
+``decode``          file bytes → Arrow batches (format readers)
+``merge``           MOR merge-apply: loser tree / argsort + row gather
+``fill``            schema-evolution uniform (cast/null-fill) + partition columns
+``rebatch``         fixed-size window assembly in the loader
+``collate``         Arrow window → numpy pytree (+ user transform)
+``queue``           consumer stall on the loader's prefetch queue
+``device_put``      host batch → device transfer dispatch
+``train.place``     the step's wrapper placing the batch on its pinned
+                    shardings (``models/train.py: _CountedStep.__call__``)
+``train.dispatch``  the jitted step's dispatch: the host side of the call,
+                    which returns before the device is done (the first call
+                    of a shape traces and compiles inside it)
+==================  =========================================================
+
+The family is the data path's up to and through the step's door: the two
+``train.*`` stages are the last host time a batch costs before the device has
+it, so they sit beside ``device_put`` and not in a family of their own
+(:data:`SCAN_STAGES`, what :func:`stage_seconds` sums, stays the seven the
+loader owns).
 
 On a compacted no-PK table the contract is DEGENERACY: ``merge`` and
 ``fill`` must report ~0 — the scan is a plain decode plan
@@ -38,11 +50,12 @@ labeled series stay queryable for attribution.
 
 Handles are memoized module-level (the registry is a process singleton).
 
-:func:`stage` is the one seam every scan and loader call site goes through:
+:func:`stage` is the one seam every scan, loader and step-wrapper call site goes through:
 ``with stage("merge"):`` observes the stage's SELF time into the family
 above and, for as long as it is open, holds a
 ``jax.profiler.TraceAnnotation`` named ``lakesoul.scan.<stage>`` (decode,
-merge, fill) or ``lakesoul.loader.<stage>`` (the rest).  The annotation
+merge, fill), ``lakesoul.loader.<stage>`` (the loader's four) or
+``lakesoul.<name>`` (any other: ``lakesoul.train.place``).  The annotation
 lands on the profiler's clock, the one the device planes use, so a reader of
 the trace can say which stage was open while the device sat idle; outside a
 profiler session it records nothing.  The session is the only switch.
@@ -169,16 +182,4 @@ def stage_counts() -> dict[str, int]:
         stage = labels.get("stage")
         if stage in out:
             out[stage] += h.value["count"]
-    return out
-
-
-def queue_seconds_by_consumer() -> dict[str, float]:
-    """Per-consumer queue-stall split (the multi-client attribution view):
-    ``{consumer: seconds}`` across every tagged queue series."""
-    out: dict[str, float] = {}
-    for labels, h in _family_series():
-        if labels.get("stage") != "queue":
-            continue
-        consumer = labels.get("consumer", "local")
-        out[consumer] = out.get(consumer, 0.0) + h.value["sum"]
     return out

@@ -355,10 +355,10 @@ def held_experts(x, top_e, w, p, *, n_experts: int, held: tuple[int, int],
                 mesh=batch_sharding.mesh, in_specs=(spec, spec, spec, P(), P(), P()),
                 out_specs=(spec, P(), P()), check_vma=False,
             )(x, top_e, w, *weights)
-    counts = {
-        "moe_all": jnp.int32(top_e.size),
-        "moe_held": jnp.sum(loads),
-        "moe_load_max": jnp.max(loads),
-        "moe_tile_rows": tile_rows,  # slots the tile loop moved and multiplied, forward
-    }
+        counts = {
+            "moe_all": jnp.int32(top_e.size),
+            "moe_held": jnp.sum(loads),
+            "moe_load_max": jnp.max(loads),
+            "moe_tile_rows": tile_rows,  # slots the tile loop moved and multiplied, forward
+        }
     return y, counts

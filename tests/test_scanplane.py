@@ -39,7 +39,8 @@ import pytest
 
 from lakesoul_tpu import LakeSoulCatalog
 from lakesoul_tpu.errors import ConfigError
-from lakesoul_tpu.obs import queue_seconds_by_consumer, registry
+from lakesoul_tpu.obs import registry
+from lakesoul_tpu.obs.stages import STAGE_FAMILY
 from lakesoul_tpu.scanplane.client import ScanPlaneClient
 from lakesoul_tpu.scanplane.delivery import ScanPlaneDelivery
 from lakesoul_tpu.scanplane.session import ScanSession
@@ -623,7 +624,11 @@ class TestBatchSourceSeam:
             assert stats["rows"] == remote_rows and stats["batches"] > 0
             assert stats["rows_per_sec"] > 0
             # per-client queue attribution (the consumer= satellite)
-            assert "trainer-0" in queue_seconds_by_consumer()
+            queue_consumers = {
+                labels.get("consumer") for labels, _ in registry().series(STAGE_FAMILY)
+                if labels.get("stage") == "queue"
+            }
+            assert "trainer-0" in queue_consumers
             # byte-identity through the full loader: collate output equals
             # the local loader's
             local_it = t.scan().batch_size(2048).to_jax_iter(

@@ -240,6 +240,58 @@ def test_nested_stages_are_additive_and_any_name_is_taken():
     assert after["sum"] - before["sum"] == pytest.approx(outer.elapsed, abs=1e-4)
 
 
+TRAIN_STAGES = ("train.place", "train.dispatch")  # ``models/train.py: _CountedStep.__call__``
+
+
+@pytest.fixture(scope="module")
+def recorded_steps(tmp_path_factory):
+    """Five calls of a train step as a training job gets it (``make_lm_train_step``'s return
+    value), compiled before the session: the host plane's lines, and what the two stages'
+    histograms moved by meanwhile."""
+    from lakesoul_tpu.models.train import make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    params, opt_state, tx, shardings = make_lm_train_state(LM_CFG, plan)
+    step = make_lm_train_step(LM_CFG, plan, tx, shardings)
+    ids = np.zeros((2, 16), np.int32)
+    state = [params, opt_state]
+
+    def steps(n=5):
+        with jax.profiler.TraceAnnotation("test.consume"):
+            for _ in range(n):
+                *state[:], loss = step(*state, ids, ids)
+            float(loss)
+
+    steps(1)  # traces and compiles inside ``train.dispatch``: not what the session holds
+    histograms = {s: registry().histogram("lakesoul_scan_stage_seconds", stage=s) for s in TRAIN_STAGES}
+    for attempt in ("traced", "traced_again"):  # as ``recorded``: one descheduled span is the recording's accident
+        before = {s: dict(h.value) for s, h in histograms.items()}
+        lines = _session(str(tmp_path_factory.mktemp("step_spans") / attempt), steps)
+        moved = {s: {k: h.value[k] - before[s][k] for k in ("sum", "count")} for s, h in histograms.items()}
+        selfs = {s: [ns for events in lines for n, ns in _self_ns(events) if n == "lakesoul." + s] for s in TRAIN_STAGES}
+        if all(abs(sum(selfs[s]) / 1e9 - moved[s]["sum"]) <= 0.05 * moved[s]["sum"] + 1e-3 for s in TRAIN_STAGES):
+            break
+    return {"lines": lines, "moved": moved, "selfs": selfs}
+
+
+@pytest.mark.parametrize("stage_name", TRAIN_STAGES)
+def test_step_wrapper_spans_agree_with_the_histogram(recorded_steps, stage_name):
+    """``idle_place_ms_step`` and ``idle_dispatch_ms_step`` read the spans ``lakesoul.train.place``
+    and ``lakesoul.train.dispatch`` off the line that holds the benchmark's ``bench.next_batch``:
+    the thread that calls the step.  One span a call, on that line only, and its time is the
+    histogram's."""
+    lines, name = recorded_steps["lines"], "lakesoul." + stage_name
+    holding = {i for i, events in enumerate(lines) if any(n == name for n, _, _ in events)}
+    assert holding == {_consumer_line(lines)}
+    selfs, moved = recorded_steps["selfs"][stage_name], recorded_steps["moved"][stage_name]
+    assert len(selfs) == moved["count"] == 5
+    assert abs(sum(selfs) / 1e9 - moved["sum"]) <= 0.05 * moved["sum"] + 1e-3, (sum(selfs) / 1e9, moved["sum"])
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", f"idle_{stage_name[6:]}_ms_step.py")) as f:
+        assert f'SPAN = "{name}"' in f.read()
+    assert stage_name not in SCAN_STAGES  # ``stage_seconds()`` and the loader's readers keep summing the seven
+
+
 def _step_module() -> str:
     """The step as a training job gets it, ``make_bert_train_step``'s return
     value called once; the factory jits on that first call, inside a closure,
@@ -303,7 +355,9 @@ LM_SCOPES = {
     "lakesoul.lm.moe.route": ("layer_metrics/moe_step_share_pct.py", '"moe.route"'),
     "lakesoul.lm.moe.experts": ("layer_metrics/moe_step_share_pct.py", '"moe.experts"'),
     "lakesoul.lm.moe.shared": ("layer_metrics/moe_step_share_pct.py", '"moe.shared"'),
-    "lakesoul.lm.head": ("chipbench/scopes.py", 'PREFIX = "lakesoul.lm."'),
+    "lakesoul.lm.head": ("layer_metrics/head_step_share_pct.py", 'SCOPE = "head"'),
+    "lakesoul.lm.optim": ("layer_metrics/optim_step_share_pct.py", 'SCOPE = "optim"'),
+    "lakesoul.lm.embed": ("layer_metrics/embed_step_share_pct.py", 'SCOPE = "embed"'),
 }
 
 
@@ -732,3 +786,99 @@ def test_glm_readers(check):
         assert 'kind="mtp"' in f.read()
     with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
         assert '{"kind": "mtp"}' in f.read()
+
+
+# ---------------------------------------- the step named whole (PR 38)
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(?:\(.*?\)|\S+)\s+([\w\-]+)\(")
+_HLO_CALLED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+_NO_EVENT = {"parameter", "tuple", "get-tuple-element", "bitcast", "constant"}
+
+
+def _unscoped_written_by_the_program(text: str, scope_of: dict) -> list[str]:
+    """The ``op_name`` of every instruction of a compiled module that a device trace shows as an
+    event of its own (one of a computation that no fusion and no reduction calls), that carries the
+    program's metadata (``op_name="jit(step)/..."``) and that the scope map charges to nothing.
+    What the compiler inserts bears no such ``op_name`` (copies between memory spaces, async slices,
+    layout copies; a parameter's copy is named after the parameter) and is not the program's to name."""
+    computations: dict[str, list] = {}
+    called, current = set(), None
+    for line in text.splitlines():
+        if not line.startswith((" ", "\t")):
+            opened = _HLO_COMPUTATION.match(line)
+            current = opened.group(1) if opened else current
+            computations.setdefault(current, [])
+            continue
+        found = _HLO_INSTRUCTION.match(line)
+        if found and current is not None:
+            computations[current].append((found.group(1), found.group(2), line))
+            if found.group(2) not in ("while", "conditional", "call"):  # their computations run as events
+                called.update(_HLO_CALLED.findall(line))
+    out = []
+    for name, instructions in computations.items():
+        if name in called:
+            continue
+        for instruction, opcode, line in instructions:
+            op_name = re.search(r'op_name="(jit\([^"]*)"', line)
+            if op_name and opcode not in _NO_EVENT and instruction not in scope_of:
+                out.append(op_name.group(1))
+    return out
+
+
+# family → (its step compiled for a v5e, the operations the program wrote into it that stand under no scope)
+UNSCOPED = {
+    "qwen3_next_clm": ("lm_step_compiled_for_a_v5e", 14),
+    "lfm2_moe_clm": ("lfm2_step_compiled_for_a_v5e", 4),
+    "glm4_moe_lite_clm": ("glm_step_compiled_for_a_v5e", 0),
+}
+
+
+@pytest.mark.parametrize("family", sorted(UNSCOPED))
+def test_the_program_writes_nothing_more_into_the_step_under_no_scope(family, request):
+    """At the values found.  What is left is computed from weights and positions alone (a norm's
+    ``1 + w``, the rotary angles and ``theta ** ...``, the DeltaNet decay's constants): JAX's partial
+    evaluation of ``jax.checkpoint`` and ``lax.map`` under ``value_and_grad`` stages such an operation
+    out at the top of the step, where the scope it was written under no longer stands; kilobytes each
+    (``PERF.md`` section 5).  The parent of the PR that named the optimizer, the embedding, the loss's
+    loop, the checkpoints' own copies and the residual adds has over a hundred in each step.  A PR
+    that drops a scope, or writes an operation into the step under none, fails here on a CPU and
+    shows in no ledger."""
+    fixture, bound = UNSCOPED[family]
+    text = request.getfixturevalue(fixture)
+    scope_of = _adaptor(family).scopes_of(text)
+    unscoped = _unscoped_written_by_the_program(text, scope_of)
+    assert len(unscoped) <= bound, sorted(unscoped)
+    assert all(name.count("/") == 1 or ";" in name for name in unscoped), sorted(unscoped)  # staged out at the top
+    # and the two new scopes hold what they are for: the optimizer's updates, the lookup and its scatter-add
+    by_scope = {s: sum(v == s for v in scope_of.values()) for s in ("lakesoul.lm.optim", "lakesoul.lm.embed")}
+    assert by_scope["lakesoul.lm.optim"] >= 10 and by_scope["lakesoul.lm.embed"] >= 2, by_scope
+
+
+TRAIN_STEP_READER_CHECKS = [
+    "shares_of_a_step_named_whole", "scope_readers_on_the_parent", "recorded_step_from_before_the_scopes",
+    "scopes_of_reads_the_two_new_scopes", "new_owners_by_hand", "recorded_trace_with_the_two_owners",
+    "span_readers_on_the_parent",
+]
+
+
+@pytest.mark.parametrize("check", TRAIN_STEP_READER_CHECKS)
+def test_train_step_readers(check):
+    """The six readers that name the step whole through their own self-test, and the scope and span
+    names they search for under the constants the program opens them by."""
+    import importlib.util
+
+    from lakesoul_tpu.models import causal_lm, train
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_train_step_readers",
+        os.path.join(REPO, "benchmarks", "chip", "selftest", "train_step_readers.py"),
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_" + name for name in TRAIN_STEP_READER_CHECKS]
+    getattr(selftest, "test_" + check)()
+    assert (train.OPTIM_SCOPE, causal_lm.EMBED_SCOPE, causal_lm.HEAD_SCOPE) == (
+        "lakesoul.lm.optim", "lakesoul.lm.embed", "lakesoul.lm.head"
+    )
+    assert (selftest.PLACE, selftest.DISPATCH) == ("lakesoul.train.place", "lakesoul.train.dispatch")
