@@ -327,9 +327,13 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     with a label, over every loss the step sums and over its multi-token-
     prediction module's alone, 0 for a family without one),
     ``lakesoul_train_moe_assignments_total{kind="held"|"all"|"tile_rows"|
-    "bias_moved"}`` (``tile_rows``: the slots of the expert tiles run, of which
-    ``held`` carried an assignment; ``bias_moved``: the assignments whose
-    expert a routing bias brought into the top k, 0 for a family without one)
+    "bias_moved"|"dw_writes"}`` (``tile_rows``: the slots of the expert tiles
+    run, of which ``held`` carried an assignment; ``bias_moved``: the
+    assignments whose expert a routing bias brought into the top k, 0 for a
+    family without one; ``dw_writes``: the times the backward pass writes an
+    expert's weight-gradient sum, for one of the three matrices: once a tile
+    where the sums ride the tile loop, once an expert and segment of tiles
+    where ``parallel/moe.py: expert_dw`` keeps them in VMEM)
     and ``lakesoul_train_moe_expert_load{stat="max"|"mean"}`` (the fullest and
     the mean held expert's assignments, summed over steps and layers)."""
     _lm_plan(plan)
@@ -343,6 +347,7 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("moe_all", MOE_ASSIGNMENTS_FAMILY, {"kind": "all"}, 1),
         ("moe_tile_rows", MOE_ASSIGNMENTS_FAMILY, {"kind": "tile_rows"}, 1),
         ("moe_bias_moved", MOE_ASSIGNMENTS_FAMILY, {"kind": "bias_moved"}, 1),
+        ("moe_dw_writes", MOE_ASSIGNMENTS_FAMILY, {"kind": "dw_writes"}, 1),
         ("moe_load_max", MOE_LOAD_FAMILY, {"stat": "max"}, 1),
         ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / cfg.experts_held[1]),
     )

@@ -323,6 +323,43 @@ def _run_row_copies(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"rows": n_rows, "width": width, "tile": tile, "valid": counts}
 
 
+def _run_expert_dw(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.parallel.moe import DW_SEGMENT, EXPERT_TILE, _expert_dw_twin, expert_dw, put_tiles
+
+    # two segments of the backward loop's rows into the held experts' float32
+    # weight-gradient sums (an LFM2 expert's [2048, 1792] at deployed sizes,
+    # 64 tiles of 512 rows a segment): an expert no tile names, one of a single
+    # tile, one that the segments' boundary splits, a last segment that stops
+    # short of its buffers.  bfloat16 products summed in float32 tile by tile
+    # on both sides: the same bits
+    wide = sizes.rows >= 1 << 16
+    (a, b), tile, span = ((2048, 1792), EXPERT_TILE, DW_SEGMENT) if wide else ((256, 128), 128, 4)
+    tiles_of = [span // 2 - 1, 0, 1, span - 1, span // 4]  # the fourth expert begins in the first segment and ends in the second
+    experts = np.repeat(np.arange(len(tiles_of), dtype=np.int32), tiles_of)
+    keys = jax.random.split(jax.random.key(9), 2)
+    lhs, rhs = (jax.random.normal(key, (2 * span * tile, width)).astype(jnp.bfloat16) for key, width in zip(keys, (a, b)))
+    # the rows reach their buffers as the loop leaves them there, a tile at a time by DMA: copies, so exact
+    held = (jnp.zeros_like(lhs), jnp.zeros_like(rhs))
+    for t in range(2 * span):
+        rows = slice(t * tile, (t + 1) * tile)
+        held = put_tiles(held, (lhs[rows], rhs[rows]), jnp.int32(t * tile), interpret=interpret)
+    for m, want in zip(held, (lhs, rhs)):
+        np.testing.assert_array_equal(np.asarray(m), np.asarray(want))  # lakelint: ignore[replay-host-roundtrip] verification readback: tiles written by DMA against the rows they came from
+    lhs, rhs = held
+    got = want = jnp.zeros((len(tiles_of), a, b), jnp.float32)
+    for t0 in range(0, len(experts), span):
+        n = min(span, len(experts) - t0)
+        held = jnp.asarray(np.concatenate([experts[t0:t0 + n], np.full(span - n, len(tiles_of), np.int32)]))
+        rows = slice(t0 * tile, (t0 + span) * tile)
+        got = expert_dw(got, lhs[rows], rhs[rows], held, jnp.int32(n), interpret=interpret)
+        want = _expert_dw_twin(want, lhs[rows], rhs[rows], held, jnp.int32(n))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernel's sums against the indexing twin's
+    return {"sums": [len(tiles_of), a, b], "tile": tile, "segment": span, "tiles": int(len(experts))}
+
+
 def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax
     import jax.numpy as jnp
@@ -527,6 +564,13 @@ def smoke_cases() -> list[SmokeCase]:
             kernels=(
                 "lakesoul_tpu/parallel/moe.py::_take_rows_kernel",
                 "lakesoul_tpu/parallel/moe.py::_put_rows_kernel",
+            ),
+        ),
+        SmokeCase(
+            "parallel.moe_expert_dw", "pallas", _run_expert_dw,
+            kernels=(
+                "lakesoul_tpu/parallel/moe.py::_expert_dw_kernel",
+                "lakesoul_tpu/parallel/moe.py::_put_tiles_kernel",
             ),
         ),
         SmokeCase(

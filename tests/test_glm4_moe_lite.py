@@ -517,10 +517,10 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
     before = run["states"][0]
     # what the first step must have counted, from the reference's routing of the same weights: the
     # stack's two routed layers, then the module's on the module's input
-    held = load_max = tile_rows = bias_moved = 0
+    held = load_max = tile_rows = dw_writes = bias_moved = 0
 
     def count(x, lp, buffers):
-        nonlocal held, load_max, tile_rows, bias_moved
+        nonlocal held, load_max, tile_rows, dw_writes, bias_moved
         mixed = x + ref.attention(ref.rms_norm(x, lp["norm1"], 1e-5), lp["mla"], MODEL)
         y = ref.rms_norm(mixed, lp["norm2"], 1e-5).reshape(-1, MODEL["hidden_size"])
         s = np.asarray(ref.scores(y, lp["moe"]["router"]))
@@ -531,6 +531,8 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
         held += int(loads.sum())
         load_max += int(loads.max())
         tile_rows += sum(-(-int(load) // moe.EXPERT_TILE) * moe.EXPERT_TILE for load in loads)
+        # so few rows an expert that the weight-gradient sums ride the backward loop: written once a tile
+        dw_writes += sum(-(-int(load) // moe.EXPERT_TILE) for load in loads)
 
     x = jnp.asarray(before["embed"])[ids]
     for lp, buffers in zip(before["layers"], before["buffers"]["layers"]):
@@ -551,7 +553,7 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
         once(state, opt_state, ids, labels)
     main, second = B * (T - 1), B * (T - 2)
     assert once.counts() == {"tokens": B * T, "moe_all": 3 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
-                             "moe_tile_rows": tile_rows, "moe_bias_moved": bias_moved,
+                             "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
                              "head_mtp": second, "head_all": main + second}
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]

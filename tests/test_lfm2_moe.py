@@ -420,7 +420,7 @@ def test_counters_for_a_known_routing(stepped):
     run = out[1]
     before = run["states"][0]
     # what the first step must have counted, from the reference's routing of the same weights
-    held = load_max = tile_rows = bias_moved = 0
+    held = load_max = tile_rows = dw_writes = bias_moved = 0
     x = jnp.asarray(before["embed"])[ids]
     for i, (lp, buffers, kind) in enumerate(zip(before["layers"], before["buffers"]["layers"], MODEL["layer_types"])):
         if i >= MODEL["num_dense_layers"]:
@@ -435,6 +435,8 @@ def test_counters_for_a_known_routing(stepped):
             held += int(loads.sum())
             load_max += int(loads.max())
             tile_rows += sum(-(-int(load) // moe.EXPERT_TILE) * moe.EXPERT_TILE for load in loads)
+            # so few rows an expert that the weight-gradient sums ride the backward loop: written once a tile
+            dw_writes += sum(-(-int(load) // moe.EXPERT_TILE) for load in loads)
         x = ref.layer(x, lp, buffers, ref.KINDS[kind], i < MODEL["num_dense_layers"], MODEL, HELD)
     assert bias_moved > 0
     # the step's own counts are over its three steps; the weights move, so only the first step's
@@ -445,7 +447,7 @@ def test_counters_for_a_known_routing(stepped):
         once = make_lm_train_step(CFG, plan, tx, shardings)
         once(state, opt_state, ids, labels)
     assert once.counts() == {"tokens": B * T, "moe_all": 4 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
-                             "moe_tile_rows": tile_rows, "moe_bias_moved": bias_moved,
+                             "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
                              "head_all": B * (T - 1), "head_mtp": 0}  # one loss, no prediction module
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]

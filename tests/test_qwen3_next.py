@@ -562,7 +562,7 @@ def test_counters_for_a_known_routing(stepped):
     ids, labels, out = stepped
     run = out[1]
     # what the step must have counted, from the reference's routing of the same weights
-    held = load_max = tile_rows = 0
+    held = load_max = tile_rows = dw_writes = 0
     x = jnp.asarray(run["before"]["embed"])[ids]
     for lp, kind in zip(run["before"]["layers"], CFG.layer_kinds()):
         y = ref.rms_norm(x, lp["norm1"], 1e-6)
@@ -575,10 +575,12 @@ def test_counters_for_a_known_routing(stepped):
         load_max += int(loads.max())
         # an expert's rows run in whole tiles: the slots moved and multiplied
         tile_rows += sum(-(-int(load) // moe.EXPERT_TILE) * moe.EXPERT_TILE for load in loads)
+        # so few rows an expert that the weight-gradient sums ride the backward loop: written once a tile
+        dw_writes += sum(-(-int(load) // moe.EXPERT_TILE) for load in loads)
         x = x + ref.moe(y, lp["moe"], MODEL, HELD)
     got = run["step"].counts()
     assert got == {"tokens": B * T, "moe_all": 4 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
-                   "moe_tile_rows": tile_rows, "moe_bias_moved": 0,
+                   "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": 0,
                    "head_all": B * (T - 1), "head_mtp": 0}  # one loss, no prediction module
     assert held < tile_rows
     before = run["counted"]

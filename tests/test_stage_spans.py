@@ -453,14 +453,21 @@ def test_lm_step_holds_the_deltanet_kernels_under_the_gdn_scope(lm_step_compiled
     assert sorted(set(scope_of.values())) == sorted(LM_SCOPES)  # and no scope the readers do not know
 
 
-def test_expert_sums_by_dma_stay_under_the_experts_scope():
+@pytest.mark.parametrize("n, f, tile, sums_by_kernel", [(64, 16, 16, 0), (1024, 128, 128, 3)],
+                         ids=["narrow-experts", "experts-of-whole-lane-tiles-and-two-tiles-of-rows"])
+def test_expert_sums_by_dma_stay_under_the_experts_scope(n, f, tile, sums_by_kernel):
     """The held experts' layer alone, value and gradient, at a width whose
     float32 sums move by DMA (the toy step above is narrower and indexes),
     compiled for the described v5e.  A device trace names the two kernels'
     events ``take_rows.<n>`` and ``put_rows.<n>``: in the forward loop, after
-    it (the last tile's rows) and in the backward loop.  ``moe_step_share_pct``
-    keeps counting them only if the adaptor's ``scopes_of`` charges them to
-    ``lakesoul.lm.moe.experts``, as a custom call's ``op_name`` lets it."""
+    it (the last tile's rows) and in the backward loop; and, where the experts'
+    matrices and the tile are whole lane tiles and its rows fill two tiles,
+    the weight-gradient sums' ``expert_dw.<n>``, once a matrix after the
+    backward loop's tiles, and ``put_tiles.<n>``, which leaves a tile's
+    operands for them.
+    ``moe_step_share_pct`` keeps counting them only if the adaptor's
+    ``scopes_of`` charges them to ``lakesoul.lm.moe.experts``, as a custom
+    call's ``op_name`` lets it."""
     import importlib.util
 
     from jax.experimental import topologies
@@ -473,13 +480,13 @@ def test_expert_sums_by_dma_stay_under_the_experts_scope():
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     one_chip = SingleDeviceSharding(topo.devices[0])
-    n, h, f, k = 64, 256, 16, 2
+    h, k = 256, 2
 
     def shape(dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def loss(x, w, p, top_e):
-        y, _ = moe.held_experts(x, top_e, w, p, n_experts=8, held=(0, 4), tile=16)
+        y, _ = moe.held_experts(x, top_e, w, p, n_experts=8, held=(0, 4), tile=tile)
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
     p = {"w_gate": shape((4, h, f)), "w_up": shape((4, h, f)), "w_down": shape((4, f, h))}
@@ -489,7 +496,9 @@ def test_expert_sums_by_dma_stay_under_the_experts_scope():
             shape((n, h), jnp.bfloat16), shape((n, k)), p, shape((n, k), jnp.int32)
         ).compile().as_text()
     calls = _kernel_calls(text)
-    assert sorted(name.split(".")[0] for name in calls) == ["put_rows"] * 3 + ["take_rows"] * 3, calls
+    assert sorted(name.split(".")[0] for name in calls) == (
+        ["expert_dw"] * sums_by_kernel + ["put_rows"] * 3 + ["put_tiles"] * (sums_by_kernel // 3) + ["take_rows"] * 3
+    ), calls
     spec = importlib.util.spec_from_file_location(
         "qwen3_next_clm", os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")
     )
@@ -540,6 +549,28 @@ def test_tile_fill_reader(check):
         assert 'kind="tile_rows"' in f.read()
     with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
         assert '{"kind": "tile_rows"}' in f.read()
+
+
+@pytest.mark.parametrize("check", ["rows_a_write_of_hand_counts", "nothing_without_the_series"])
+def test_rows_per_dw_write_reader(check):
+    """``moe_rows_per_dw_write`` through its own self-test, and the series it
+    divides by under the name the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import MOE_ASSIGNMENTS_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_dw_writes", os.path.join(REPO, "benchmarks", "chip", "selftest", "dw_writes.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_rows_a_write_of_hand_counts", "test_nothing_without_the_series"]
+    getattr(selftest, "test_" + check)()
+    assert selftest.FAMILY == MOE_ASSIGNMENTS_FAMILY
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "moe_rows_per_dw_write.py")) as f:
+        assert 'kind="dw_writes"' in f.read()
+    with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
+        assert '{"kind": "dw_writes"}' in f.read()
 
 
 # ------------------------------------------- the second causal-LM family

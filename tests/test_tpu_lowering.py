@@ -109,6 +109,27 @@ def _put_rows(d):
     )
 
 
+def _expert_dw(d):
+    # a segment of the backward loop at the LFM2 cell's experts: 64 tiles of rows into eight float32 sums of [2048, 1792]
+    del d
+    rows, (h, f) = moe.DW_SEGMENT * moe.EXPERT_TILE, (2048, 1792)
+    return jax.jit(lambda *operands: moe.expert_dw(*operands, interpret=False)).trace(
+        _sds((8, h, f)), _sds((rows, h), jnp.bfloat16), _sds((rows, f), jnp.bfloat16),
+        _sds((moe.DW_SEGMENT,), jnp.int32), _sds((), jnp.int32),
+    )
+
+
+def _put_tiles(d):
+    # a tile's five operands into the row buffers of the same segment
+    del d
+    rows, (h, f), bf16 = moe.DW_SEGMENT * moe.EXPERT_TILE, (2048, 1792), jnp.bfloat16
+    widths = (h, f, h, f, f)
+    return jax.jit(lambda *operands: moe.put_tiles(*operands, interpret=False)).trace(
+        tuple(_sds((rows, w), bf16) for w in widths), tuple(_sds((moe.EXPERT_TILE, w), bf16) for w in widths),
+        _sds((), jnp.int32),
+    )
+
+
 ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads, query heads each serves, head size
     "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256),
 }
@@ -150,6 +171,8 @@ TRACERS = {
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_bwd_kernel": _gated_delta_backward,
     "lakesoul_tpu/parallel/moe.py::_take_rows_kernel": _take_rows,
     "lakesoul_tpu/parallel/moe.py::_put_rows_kernel": _put_rows,
+    "lakesoul_tpu/parallel/moe.py::_expert_dw_kernel": _expert_dw,
+    "lakesoul_tpu/parallel/moe.py::_put_tiles_kernel": _put_tiles,
 }
 
 
