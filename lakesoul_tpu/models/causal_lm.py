@@ -2,12 +2,14 @@
 and the pieces more than one family's mixers are made of.
 
 A family is a module with a configuration object (``models/qwen3_next.py``,
-``models/lfm2_moe.py``, ``models/glm4_moe_lite.py``); nothing here or in
+``models/lfm2_moe.py``, ``models/glm4_moe_lite.py``, ``models/afmoe.py``);
+nothing here or in
 ``models/train.py`` names one.  The stack reads a layer's kinds from the
 configuration and asks it for the rest:
 
 - ``layer_kinds()``: each layer's mixer (``"gdn"``, ``"attn"``, ``"conv"``,
-  ``"mla"``: latent attention, :func:`latent_attention`), which is also the
+  ``"mla"``: latent attention, :func:`latent_attention`; ``"swa"``:
+  :func:`softmax_attention` under a window), which is also the
   key of the mixer's weights in the layer;
 - ``ffn_kinds()``: each layer's feed-forward, ``"dense"`` (SwiGLU, weights
   under ``"mlp"``) or ``"moe"`` (routed experts, under ``"moe"``);
@@ -18,7 +20,9 @@ configuration and asks it for the rest:
   moved): its routing rule over ``parallel/moe.py``;
 - ``num_experts``, ``experts_held``, ``dtype``; and for ``models/train.py``
   ``init(key)`` and ``loss(params, ids, labels, batch_sharding=)``;
-- ``mtp_loss_weight``, read only where the weights hold a prediction module.
+- ``mtp_loss_weight``, read only where the weights hold a prediction module;
+  ``embed_scale``, where the family has one: what the embedding's output is
+  multiplied by.
 
 The loss (:func:`lm_loss`) is the next-token cross-entropy, and where the
 weights hold a multi-token-prediction module (``params["mtp"]``) that module's
@@ -27,7 +31,11 @@ states and the next token's embedding through one more layer of the stack's
 last kind, with its own weights, to the token after next, through the main
 model's embedding and head matrix.  The head then runs twice a step.
 
-A layer is ``h = x + mixer(norm1(x)); x' = h + ffn(norm2(h))``; after the last
+A layer is ``h = x + mixer(norm1(x)); x' = h + ffn(norm2(h))``, and where its
+weights hold ``norm1_out`` and ``norm2_out`` it has four norms, each
+sublayer's output normed before it is added: ``h = x + norm1_out(mixer(
+norm1(x))); x' = h + norm2_out(ffn(norm2(h)))``, the last over the routed
+experts' share and the shared expert summed.  After the last layer
 a final norm and the head: ``params["head"]`` [h, vocab] where there is one,
 else the embedding (a tied head).  ``params["buffers"]``, where a family has
 it, is state that no gradient and no optimizer touches (``models/train.py:
@@ -38,9 +46,14 @@ Softmax attention (:func:`causal_attention`, every family's) is two Pallas
 kernels under one ``custom_vjp``, compiled on a TPU and in the Pallas
 interpreter elsewhere, for the head sizes and row lengths :func:`_flash_tiles`
 takes (a head of 64, 128 or 256 channels, a row of whole 128-key tiles: the
-three published models at 8,192 tokens, groups of 4, 8 and 1 query heads a
+four published models at 8,192 tokens, groups of 4, 8, 1 and 8 query heads a
 key-value head); any other shape runs the blockwise ``jnp``
-path, the kernels' twin.  Of the scores nothing leaves VMEM in either pass;
+path, the kernels' twin.  It has two masks: causal, and under a ``window`` the
+band of a query's own position and the ``window - 1`` before it; the kernels'
+grid is the list of (query tile, key tile) pairs either mask lets anything
+through (:func:`_flash_pairs`), so a key tile a window hides whole is neither
+fetched nor multiplied, and :func:`key_tile_steps` counts the list for the
+step's counters.  Of the scores nothing leaves VMEM in either pass;
 the backward pass keeps the output and the log-sum-exp, which
 :func:`_row_by_row`'s checkpoint holds on to by name (:data:`ATTN_KEPT`), so a
 step runs the forward kernel once a row and the backward kernel once.
@@ -54,6 +67,7 @@ the loss are over.  Parallelism: dp over rows; everything else is replicated.
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -122,37 +136,43 @@ def _rotary(x, positions, rotary_dim: int, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
-def _blockwise_attention(q, k, v, band: int, rows: int):
+def _blockwise_attention(q, k, v, band: int, rows: int, window: int | None = None):
     """:func:`causal_attention` as whole-array ``jnp`` operations: the queries
     go a band at a time against the keys up to the band's last position (a
-    static slice, so the keys after it cost nothing), and inside a band
+    static slice, so the keys after it cost nothing; under a ``window`` from
+    the first key the band's first query sees), and inside a band
     ``rows`` queries at a time, each block rematerialised: no more than
     ``rows`` rows of scores live at once, in either pass, and every one of
     them in HBM.  What a shape the kernel does not take runs, and the kernel's
     twin and reference."""
     b, hkv, groups, t, d = q.shape
 
-    def block(q_blk, k_seen, v_seen, first):
-        """q_blk [B, Hkv, G, n, D] at positions first.. against the keys seen."""
+    def block(q_blk, k_seen, v_seen, first, key0=0):
+        """q_blk [B, Hkv, G, n, D] at positions first.. against the keys seen,
+        which start at position ``key0``."""
         n = q_blk.shape[3]
         pos = jnp.tile(first + jnp.arange(n), groups)
-        mask = pos[:, None] >= jnp.arange(k_seen.shape[2])[None, :]
+        if window is None:
+            mask = pos[:, None] >= jnp.arange(k_seen.shape[2])[None, :]
+        else:
+            back = pos[:, None] - (key0 + jnp.arange(k_seen.shape[2]))[None, :]
+            mask = (back >= 0) & (back < window)
         _, l, o = block_attn(q_blk.reshape(b, hkv, groups * n, d), k_seen, v_seen, 1.0, mask)
         return (o / l[..., None]).astype(v.dtype).reshape(b, hkv, groups, n, d)
 
     out = []
     for start in range(0, t, band):
         end = min(start + band, t)
-        q_band, k_seen, v_seen = q[:, :, :, start:end], k[:, :, :end], v[:, :, :end]
+        key0 = 0 if window is None else max(0, start - window + 1)
+        q_band, k_seen, v_seen = q[:, :, :, start:end], k[:, :, key0:end], v[:, :, key0:end]
+        band_block = jax.checkpoint(functools.partial(block, key0=key0))
         if (end - start) % rows or end - start == rows:
-            out.append(jax.checkpoint(block)(q_band, k_seen, v_seen, start))
+            out.append(band_block(q_band, k_seen, v_seen, start))
             continue
         blocks = (end - start) // rows
         q_rows = jnp.moveaxis(q_band.reshape(b, hkv, groups, blocks, rows, d), 3, 0)
         firsts = start + rows * jnp.arange(blocks)
-        o = jax.lax.map(
-            lambda xs: jax.checkpoint(block)(xs[0], k_seen, v_seen, xs[1]), (q_rows, firsts)
-        )
+        o = jax.lax.map(lambda xs: band_block(xs[0], k_seen, v_seen, xs[1]), (q_rows, firsts))
         out.append(jnp.moveaxis(o, 0, 3).reshape(b, hkv, groups, end - start, d))
     return jnp.concatenate(out, axis=3)
 
@@ -160,10 +180,14 @@ def _blockwise_attention(q, k, v, band: int, rows: int):
 # The flash kernels.  A tile is a key-value head's whole group: ``G`` query
 # heads x ``bq`` queries as the rows of one score tile against ``bk`` keys, so
 # K and V are fetched once a group and dK, dV sum over it inside the kernel.
-# The grid walks the (query tile, key tile) pairs on or under the diagonal,
-# listed in two tables in SMEM: a key tile wholly after a query tile is not in
-# the list, so it is neither fetched nor multiplied, and only a query tile's
-# last key tile (the one the diagonal crosses) builds a mask.
+# The grid walks the (query tile, key tile) pairs the mask lets anything
+# through, listed in two tables in SMEM: a key tile wholly after a query tile,
+# or under a window wholly before the first key the tile's first query sees,
+# is not in the list, so it is neither fetched nor multiplied.  Only the tiles
+# an edge of the mask crosses build one: a query tile's last key tile (the
+# diagonal's) and, under a window, the key tiles that hold a key the tile's
+# last query no longer sees (the first of the list; the first two where the
+# window is no multiple of the query tile).  One tile may be both.
 
 
 def _flash_tiles(t: int, groups: int, d: int):
@@ -177,12 +201,37 @@ def _flash_tiles(t: int, groups: int, d: int):
     return max(128, min(bk, FLASH_ROWS // groups)), bk
 
 
-def _flash_steps(t: int, bq: int, bk: int):
-    """The grid's second axis: for every query tile its key tiles in order,
-    the last the one that holds the tile's diagonal → (query tile, key tile)
-    of each step, int32."""
-    pairs = [(i, j) for i in range(t // bq) for j in range((i * bq + bq - 1) // bk + 1)]
-    return tuple(jnp.asarray(a, jnp.int32) for a in zip(*pairs, strict=True))
+def _first_key_tile(i, bq: int, bk: int, window: int | None):
+    """The first key tile of query tile ``i`` (a Python or a traced integer):
+    the one that holds the first key the tile's first query sees."""
+    if window is None:
+        return 0
+    seen_from = i * bq - (window - 1)
+    return (max(seen_from, 0) if isinstance(i, int) else jnp.maximum(seen_from, 0)) // bk
+
+
+def _flash_pairs(t: int, bq: int, bk: int, window: int | None = None) -> list[tuple[int, int]]:
+    """The (query tile, key tile) pairs of the grid's second axis: for every
+    query tile its key tiles in order, from :func:`_first_key_tile` to the one
+    that holds the tile's diagonal."""
+    return [(i, j) for i in range(t // bq)
+            for j in range(_first_key_tile(i, bq, bk, window), (i * bq + bq - 1) // bk + 1)]
+
+
+def _flash_steps(t: int, bq: int, bk: int, window: int | None = None):
+    """:func:`_flash_pairs` as the two int32 tables the kernels prefetch →
+    (query tile, key tile) of each step."""
+    return tuple(jnp.asarray(a, jnp.int32) for a in zip(*_flash_pairs(t, bq, bk, window), strict=True))
+
+
+def key_tile_steps(t: int, groups: int, d: int, window: int | None = None) -> tuple[int, int]:
+    """(steps the kernels' list holds for one key-value head of one row, steps
+    a causal list alone would hold): host integers off :func:`_flash_pairs`;
+    (0, 0) for a shape the kernels do not take."""
+    tiles = _flash_tiles(t, groups, d)
+    if tiles is None:
+        return 0, 0
+    return len(_flash_pairs(t, *tiles, window)), len(_flash_pairs(t, *tiles))  # a window of t or more hides no tile
 
 
 def _lanes(x, n: int):
@@ -190,41 +239,69 @@ def _lanes(x, n: int):
     return x[:, :n] if n <= 128 else jnp.tile(x, (1, n // 128))
 
 
-def _seen(i, j, bq: int, bk: int, shape, *, queries: int):
-    """Whether a score's key is at or before its query, over a tile of
-    ``shape`` whose axis ``queries`` runs over the group's rows (head-major:
-    row ``r`` is query ``r % bq`` of the tile) and whose other axis over keys."""
+def _seen(i, j, bq: int, bk: int, shape, *, queries: int, causal: bool = True, window: int | None = None):
+    """Whether a score's key is at or before its query (``causal``) and, under
+    a ``window``, among the query's own position and the ``window - 1`` before
+    it, over a tile of ``shape`` whose axis ``queries`` runs over the group's
+    rows (head-major: row ``r`` is query ``r % bq`` of the tile) and whose
+    other axis over keys."""
     pos = i * bq + (jax.lax.broadcasted_iota(jnp.int32, shape, queries) & (bq - 1))
-    return pos >= j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - queries)
+    key = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - queries)
+    if window is None:
+        return pos >= key
+    inside = pos - key < window
+    return (pos >= key) & inside if causal else inside
 
 
-def _flash_step(qi_ref, kj_ref, bq: int, bk: int):
-    """(query tile, key tile, the query tile's last key tile) of this grid step."""
+def _flash_step(qi_ref, kj_ref, bq: int, bk: int, window: int | None = None):
+    """(query tile, key tile, the query tile's first and last key tiles) of
+    this grid step."""
     i, j = qi_ref[pl.program_id(1)], kj_ref[pl.program_id(1)]
-    return i, j, (i * bq + bq - 1) // bk
+    return i, j, _first_key_tile(i, bq, bk, window), (i * bq + bq - 1) // bk
 
 
-def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, bq, bk):
+def _flash_tile_kinds(tile, i, j, last, bq: int, bk: int, window: int | None, finish):
+    """Run ``tile(mask)`` for this step, ``mask(shape, queries)`` being None
+    on a tile no edge of the mask crosses; after a query tile's last key tile
+    ``finish()``.  Without a window: the last tile alone is masked."""
+    def edge(causal):
+        return lambda shape, queries: _seen(i, j, bq, bk, shape, queries=queries, causal=causal, window=window)
+
+    if window is None:
+        pl.when(j < last)(functools.partial(tile, None))
+    else:
+        hidden = j * bk < i * bq + bq - window  # the tile holds a key the tile's last query no longer sees
+        pl.when((j < last) & jnp.logical_not(hidden))(functools.partial(tile, None))
+        pl.when((j < last) & hidden)(functools.partial(tile, edge(False)))
+
+    @pl.when(j == last)
+    def _():
+        tile(edge(True))
+        finish()
+
+
+def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, bq, bk,
+                      window=None):
     """One step: a group's query tile [G, bq, D] against a key tile [bk, D].
     Running maximum and sum [G*bq, 128] (every lane the same) and the weighted
     values [G*bq, D] stay in VMEM over a query tile's steps; the last of them
     divides and writes the output and the log-sum-exp [G, 1, bq]."""
-    i, j, last = _flash_step(qi_ref, kj_ref, bq, bk)
+    i, j, first, last = _flash_step(qi_ref, kj_ref, bq, bk, window)
     groups, _, d = q_ref.shape
     rows = groups * bq
     f32 = jnp.float32
 
-    @pl.when(j == 0)
+    @pl.when(j == first)
     def _():
         m_ref[...] = jnp.full_like(m_ref, MASKED)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def tile(masked: bool):
+    def tile(mask):
         v = v_ref[...]
         s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], _NT, preferred_element_type=f32)
-        if masked:
-            s = jnp.where(_seen(i, j, bq, bk, s.shape, queries=0), s, MASKED)
+        if mask is not None:
+            s = jnp.where(mask(s.shape, 0), s, MASKED)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_next, bk))
@@ -233,27 +310,25 @@ def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref
         m_ref[...] = m_next
         acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jnp.dot(p.astype(v.dtype), v, preferred_element_type=f32)
 
-    pl.when(j < last)(functools.partial(tile, False))
-
-    @pl.when(j == last)
-    def _():
-        tile(True)
+    def finish():
         l = l_ref[...]
         o_ref[...] = (acc_ref[...] / _lanes(l, d)).reshape(groups, bq, d).astype(o_ref.dtype)
         lse = (m_ref[...] + jnp.log(l)).T[:1]  # [1, G*bq]: a row's queries along the lanes
         for g in range(groups):
             lse_ref[g] = lse[:, g * bq:(g + 1) * bq]
 
+    _flash_tile_kinds(tile, i, j, last, bq, bk, window, finish)
+
 
 def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, *, bq, bk):
+                      dq_ref, dk_ref, dv_ref, dq_acc, *, bq, bk, window=None):
     """One step of the backward pass, on the forward kernel's grid: the scores
     of the tile again from q, k and the log-sum-exp, keys down the sublanes
     ([bk, G*bq]: the log-sum-exp and ``delta = sum(o * do)`` are rows, and dV
     and dK plain products), their share of dQ into VMEM until the query tile's
     last step, of dK and dV into the row's whole float32 dK, dV [T, D], which
     stay in VMEM over all of a key-value head's steps."""
-    i, j, last = _flash_step(qi_ref, kj_ref, bq, bk)
+    i, j, first, last = _flash_step(qi_ref, kj_ref, bq, bk, window)
     groups, _, d = q_ref.shape
     rows = groups * bq
     f32 = jnp.float32
@@ -263,18 +338,18 @@ def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delt
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    @pl.when(j == 0)
+    @pl.when(j == first)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def tile(masked: bool):
+    def tile(mask):
         q, do = q_ref[...].reshape(rows, d), do_ref[...].reshape(rows, d)
         k, v = k_ref[...], v_ref[...]
         lse = jnp.concatenate([lse_ref[g] for g in range(groups)], axis=1)
         delta = jnp.concatenate([delta_ref[g] for g in range(groups)], axis=1)
         s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
-        if masked:
-            s = jnp.where(_seen(i, j, bq, bk, s.shape, queries=1), s, MASKED)
+        if mask is not None:
+            s = jnp.where(mask(s.shape, 1), s, MASKED)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
         ds = (p * (dp - delta)).astype(q.dtype)
@@ -283,15 +358,13 @@ def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delt
         dk_ref[keys, :] += jnp.dot(ds, q, preferred_element_type=f32)
         dq_acc[...] += jax.lax.dot_general(ds, k, _TN, preferred_element_type=f32)
 
-    pl.when(j < last)(functools.partial(tile, False))
-
-    @pl.when(j == last)
-    def _():
-        tile(True)
+    def finish():
         dq_ref[...] = dq_acc[...].reshape(groups, bq, d).astype(dq_ref.dtype)
 
+    _flash_tile_kinds(tile, i, j, last, bq, bk, window, finish)
 
-def _flash_grid(q, bq: int, bk: int, *, in_specs, out_specs, scratch_shapes):
+
+def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_shapes):
     """What the two kernels' ``pallas_call``s share, over q's [N, G, T, D]:
     the grid (key-value heads, steps of :func:`_flash_steps`) with the two
     tables in SMEM, and block specs by what a block follows: a query tile's
@@ -299,7 +372,7 @@ def _flash_grid(q, bq: int, bk: int, *, in_specs, out_specs, scratch_shapes):
     key-value head's whole [T, D]; ``in_specs`` and ``out_specs`` name those.
     Returns (the tables, the call's keyword arguments)."""
     n, groups, t, d = q.shape
-    tables = _flash_steps(t, bq, bk)
+    tables = _flash_steps(t, bq, bk, window)
     specs = {
         "query": pl.BlockSpec((None, groups, bq, d), lambda h, s, qi, kj: (h, 0, qi[s], 0)),
         "per_query": pl.BlockSpec((None, groups, 1, bq), lambda h, s, qi, kj: (h, 0, 0, qi[s])),
@@ -317,62 +390,83 @@ def _flash_grid(q, bq: int, bk: int, *, in_specs, out_specs, scratch_shapes):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
-def _flash_forward(q, k, v, *, bq: int, bk: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "interpret"))
+def _flash_forward(q, k, v, *, bq: int, bk: int, window: int | None = None, interpret: bool):
     """q [N, G, T, D], k, v [N, T, D] → (o [N, G, T, D], log-sum-exp
     [N, G, 1, T] float32)."""
     n, groups, t, d = q.shape
     rows = groups * bq
     tables, grid = _flash_grid(
-        q, bq, bk, in_specs=("query", "keys", "keys"), out_specs=("query", "per_query"),
+        q, bq, bk, window, in_specs=("query", "keys", "keys"), out_specs=("query", "per_query"),
         scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32)] * 2 + [pltpu.VMEM((rows, d), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, bq=bq, bk=bk),
+        functools.partial(_flash_fwd_kernel, bq=bq, bk=bk, window=window),
         out_shape=(jax.ShapeDtypeStruct(q.shape, v.dtype), jax.ShapeDtypeStruct((n, groups, 1, t), jnp.float32)),
         name="flash_attention_fwd", interpret=interpret, **grid,
     )(*tables, q, k, v)
 
 
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
-def _flash_backward(q, k, v, o, lse, do, *, bq: int, bk: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "interpret"))
+def _flash_backward(q, k, v, o, lse, do, *, bq: int, bk: int, window: int | None = None, interpret: bool):
     """The three gradients, dK and dV summed over the group."""
     _, groups, _, d = q.shape
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, :, None, :]
     tables, grid = _flash_grid(
-        q, bq, bk, in_specs=("query", "keys", "keys", "query", "per_query", "per_query"),
+        q, bq, bk, window, in_specs=("query", "keys", "keys", "query", "per_query", "per_query"),
         out_specs=("query", "whole_row", "whole_row"), scratch_shapes=[pltpu.VMEM((groups * bq, d), jnp.float32)],
     )
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, bq=bq, bk=bk),
+        functools.partial(_flash_bwd_kernel, bq=bq, bk=bk, window=window),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), *[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2),
         name="flash_attention_bwd", interpret=interpret, **grid,
     )(*tables, q, k, v, do, lse, delta)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention(q, k, v, bq, bk):
-    return _flash_attention_fwd(q, k, v, bq, bk)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attention(q, k, v, bq, bk, window):
+    return _flash_attention_fwd(q, k, v, bq, bk, window)[0]
 
 
-def _flash_attention_fwd(q, k, v, bq, bk):
-    o, lse = _flash_forward(q, k, v, bq=bq, bk=bk, interpret=not _on_tpu())
+def _flash_attention_fwd(q, k, v, bq, bk, window):
+    o, lse = _flash_forward(q, k, v, bq=bq, bk=bk, window=window, interpret=not _on_tpu())
     # a checkpoint around the caller may keep these two and run no second forward kernel
     o, lse = checkpoint_name(o, ATTN_KEPT[0]), checkpoint_name(lse, ATTN_KEPT[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_attention_bwd(bq, bk, kept, do):
-    return _flash_backward(*kept, do, bq=bq, bk=bk, interpret=not _on_tpu())
+def _flash_attention_bwd(bq, bk, window, kept, do):
+    return _flash_backward(*kept, do, bq=bq, bk=bk, window=window, interpret=not _on_tpu())
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-def causal_attention(q, k, v):
+_traced_tiles = threading.local()  # ``.steps``: what :func:`mixer_key_tiles` collects while it traces a mixer
+
+
+def mixer_key_tiles(mixer, x, p) -> tuple[int, int]:
+    """(the (query tile, key tile) steps the attention kernels' lists hold for
+    ``mixer(x, p)``, the steps causal lists alone would hold), summed over
+    every :func:`causal_attention` the mixer calls and over its rows and
+    key-value heads: host integers off :func:`key_tile_steps`, from one
+    abstract trace of the mixer (nothing runs).  (0, 0) for a mixer without
+    attention or a shape the kernels do not take."""
+    _traced_tiles.steps = steps = []
+    try:
+        jax.eval_shape(mixer, x, p)
+    finally:
+        del _traced_tiles.steps
+    return (sum(n for n, _ in steps), sum(n for _, n in steps))
+
+
+def causal_attention(q, k, v, window: int | None = None):
     """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
-    (scaled), k, v [B, Hkv, T, D] → [B, Hkv, G, T, D].
+    (scaled), k, v [B, Hkv, T, D] → [B, Hkv, G, T, D].  Two masks: key ``j`` is
+    visible to query ``i`` iff ``j <= i`` and, under a ``window``,
+    ``i - j < window`` (the query's own position and the ``window - 1`` before
+    it); a window of the row's length or more is no window.
 
     Operands in their own type (bfloat16 in a model), scores, maximum, sum and
     accumulators in float32, the probabilities cast only as the second
@@ -381,45 +475,62 @@ def causal_attention(q, k, v):
     Where :func:`_flash_tiles` takes the shape (a head of 64, 128 or 256
     channels, a row of whole 128-key tiles) two Pallas kernels under one
     ``custom_vjp`` do all of it, compiled on a TPU and in the Pallas
-    interpreter elsewhere: no score leaves VMEM in either pass.  The backward
-    pass keeps the output and the log-sum-exp, named :data:`ATTN_KEPT` for a
-    checkpoint around the caller, and computes the scores again from q, k and
-    the log-sum-exp in float32.  Every other shape runs
-    :func:`_blockwise_attention`."""
+    interpreter elsewhere: no score leaves VMEM in either pass, and a key tile
+    the mask hides whole is no step of the grid (:func:`_flash_pairs`).  The
+    backward pass keeps the output and the log-sum-exp, named
+    :data:`ATTN_KEPT` for a checkpoint around the caller, and computes the
+    scores again from q, k and the log-sum-exp in float32.  Every other shape
+    runs :func:`_blockwise_attention`."""
     b, hkv, groups, t, d = q.shape
+    if window is not None and window >= t:
+        window = None
+    if hasattr(_traced_tiles, "steps"):  # :func:`mixer_key_tiles` is tracing the caller
+        _traced_tiles.steps.append([b * hkv * n for n in key_tile_steps(t, groups, d, window)])
     tiles = _flash_tiles(t, groups, d)
     if tiles is None:
-        return _blockwise_attention(q, k, v, ATTN_BAND, ATTN_ROWS)
-    o = _flash_attention(q.reshape(b * hkv, groups, t, d), *(a.reshape(b * hkv, t, d) for a in (k, v)), *tiles)
+        return _blockwise_attention(q, k, v, ATTN_BAND, ATTN_ROWS, window)
+    o = _flash_attention(
+        q.reshape(b * hkv, groups, t, d), *(a.reshape(b * hkv, t, d) for a in (k, v)), *tiles, window
+    )
     return o.reshape(q.shape)
 
 
-def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_dim: int,
-                      theta: float, norm, gated: bool):
+def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_dim: int | None,
+                      theta: float, norm, gated: bool, window: int | None = None):
     """The grouped-query softmax-attention mixer: x [B, T, h] (normed) →
     [B, T, h].  ``norm(a, w)`` is the family's RMS norm over a head's channels
-    (``q_norm``, ``k_norm``); ``rotary_dim`` of them are rotated.  ``gated``:
-    ``w_q`` holds per head the query, then a gate whose sigmoid scales the
-    head's output (the Qwen3-Next family); else the query alone."""
+    (``q_norm``, ``k_norm``); ``rotary_dim`` of them are rotated, none where it
+    is None (a layer that sees no positions).  ``window``:
+    :func:`causal_attention`'s.  A gate's sigmoid scales each head's output
+    before ``w_o``: ``gated``: ``w_q`` holds per head the query, then the gate
+    (the Qwen3-Next family); else a matrix of the gate's own where the weights
+    hold one (``w_gate`` [h, heads x D]), else none."""
     dtype = x.dtype
     f32 = jnp.float32
     b, t, _ = x.shape
     d = head_dim
     q = (x @ p["w_q"].astype(dtype)).reshape(b, t, heads, (2 if gated else 1) * d)
+    gate = None
     if gated:
         q, gate = q[..., :d], q[..., d:]
+    elif "w_gate" in p:
+        gate = (x @ p["w_gate"].astype(dtype)).reshape(b, t, heads, d)
     k = (x @ p["w_k"].astype(dtype)).reshape(b, t, kv_heads, d)
     v = (x @ p["w_v"].astype(dtype)).reshape(b, t, kv_heads, d)
     positions = jnp.arange(t)
-    q = _rotary(norm(q, p["q_norm"]), positions, rotary_dim, theta)
-    k = _rotary(norm(k, p["k_norm"]), positions, rotary_dim, theta)
+
+    def turned(a):
+        return a if rotary_dim is None else _rotary(a, positions, rotary_dim, theta)
+
+    q = turned(norm(q, p["q_norm"]))
+    k = turned(norm(k, p["k_norm"]))
     q = (q * d**-0.5).astype(dtype)
     # [B, T, heads, D] → [B, kv, heads // kv, T, D]: each key-value head serves a group
     q = q.reshape(b, t, kv_heads, heads // kv_heads, d).transpose(0, 2, 3, 1, 4)
     k, v = (a.transpose(0, 2, 1, 3) for a in (k.astype(dtype), v))
-    o = causal_attention(q, k, v)
+    o = causal_attention(q, k, v, window)
     o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
-    if gated:
+    if gate is not None:
         o = (o.astype(f32) * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
     return o.reshape(b, t, heads * d) @ p["w_o"].astype(dtype)
 
@@ -500,16 +611,24 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
     shared expert together, and the held experts' tile loop not (its backward
     pass needs its inputs alone).  What the backward pass keeps of a layer:
     its input, the mixer's output, and of a routed layer the experts' normed
-    input and the routing."""
+    input and the routing.
+
+    Where the weights hold ``norm1_out`` and ``norm2_out`` the layer has four
+    norms: each sublayer's output is normed before it is added,
+    ``h = x + norm(mixer(norm(x)))``, ``x' = h + norm(ffn(norm(h)))``, the
+    second over the routed experts' share and the shared expert summed."""
     dtype = x.dtype
     mixer, scope = cfg.mixer(kind)
 
+    def normed_out(out, w):
+        return out if w is None else cfg.norm(out, w).astype(dtype)
+
     def mix(x, p):
-        return x + mixer(cfg.norm(x, p["norm"]).astype(dtype), p["mixer"])
+        return x + normed_out(mixer(cfg.norm(x, p["norm"]).astype(dtype), p["mixer"]), p.get("out"))
 
     @jax.checkpoint
-    def dense(x, norm, p):
-        return dense_mlp(cfg.norm(x, norm).astype(dtype), p)
+    def dense(x, norm, p, out):
+        return normed_out(dense_mlp(cfg.norm(x, norm).astype(dtype), p), out)
 
     @jax.checkpoint
     def routed(x, norm, router, bias, shared):
@@ -519,13 +638,16 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
         y = y32.astype(dtype)
         return y, top_e, w, moved, None if shared is None else shared_expert(y, shared)
 
+    mixed = {"norm": lp["norm1"], "mixer": lp[kind]}
+    if "norm1_out" in lp:
+        mixed["out"] = lp["norm1_out"]
     # a scope stands around the call, not inside the checkpointed function: what the checkpoint itself
     # writes (the copies it keeps its inputs in) and each residual add are then the feed-forward's too
     with jax.named_scope(scope):
-        x = _row_by_row(mix, x, {"norm": lp["norm1"], "mixer": lp[kind]}, batch_sharding)
+        x = _row_by_row(mix, x, mixed, batch_sharding)
     if ffn == "dense":
         with jax.named_scope(MLP_SCOPE):
-            return x + dense(x, lp["norm2"], lp["mlp"]), None
+            return x + dense(x, lp["norm2"], lp["mlp"], lp.get("norm2_out")), None
     p = lp["moe"]
     with jax.named_scope(ROUTE_SCOPE):  # the norm and the routing; the shared expert's scope is inside
         y, top_e, w, moved, shared = routed(
@@ -534,28 +656,57 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
     out, counts = held_experts(
         y, top_e, w, p, n_experts=cfg.num_experts, held=cfg.experts_held, batch_sharding=batch_sharding
     )
+    counts = dict(counts, moe_bias_moved=moved)
+    if "norm2_out" in lp:  # the two shares meet before their norm
+        with jax.named_scope(EXPERTS_SCOPE):
+            return x + jax.checkpoint(normed_out)(out if shared is None else out + shared, lp["norm2_out"]), counts
     with jax.named_scope(EXPERTS_SCOPE):
         x = x + out
     if shared is not None:
         with jax.named_scope(SHARED_SCOPE):
             x = x + shared
-    return x, dict(counts, moe_bias_moved=moved)
+    return x, counts
+
+
+def layer_key_tiles(cfg, kind: str, x, p) -> dict:
+    """:func:`mixer_key_tiles` of one layer's mixer (its weights ``p``) over
+    the batch ``x`` [B, T, h], as the two counts a loss returns."""
+    row = jax.ShapeDtypeStruct((1, *x.shape[1:]), x.dtype)
+    run, causal = (x.shape[0] * n for n in mixer_key_tiles(cfg.mixer(kind)[0], row, p))
+    return {"attn_tiles_run": run, "attn_tiles_causal": causal}
+
+
+def _sum_counts(totals: dict | None, counts: dict | None) -> dict:
+    """``totals`` with ``counts`` added, key by key."""
+    totals = dict(totals or {})
+    for key, n in (counts or {}).items():
+        totals[key] = totals[key] + n if key in totals else n
+    return totals
 
 
 def lm_hidden(params, ids, *, cfg, batch_sharding=None):
     """ids [B, T] → (final hidden states [B, T, h] before the final norm,
-    counts summed over the routed layers)."""
+    counts: the expert layers' summed over the routed layers, and the
+    attention kernels' grid steps over every layer, :func:`layer_key_tiles`,
+    traced once a kind: Python integers).  The embedding's output is
+    multiplied by ``cfg.embed_scale`` where the configuration has one."""
     with jax.named_scope(EMBED_SCOPE):
-        x = params["embed"][ids].astype(jnp.dtype(cfg.dtype))
+        x = params["embed"][ids]
+        if getattr(cfg, "embed_scale", None) is not None:
+            x = x * cfg.embed_scale
+        x = x.astype(jnp.dtype(cfg.dtype))
     kinds, ffns = cfg.layer_kinds(), cfg.ffn_kinds()
     buffers = params.get("buffers", {}).get("layers", [None] * len(kinds))
-    totals = None
+    totals, tiles, of_kind = None, None, {}
     for lp, held, kind, ffn in zip(params["layers"], buffers, kinds, ffns, strict=True):
+        if kind not in of_kind:  # a kind's layers have one shape
+            of_kind[kind] = layer_key_tiles(cfg, kind, x, lp[kind])
+        tiles = _sum_counts(tiles, of_kind[kind])
         x, counts = lm_layer(x, lp, held, kind=kind, ffn=ffn, cfg=cfg, batch_sharding=batch_sharding)
         if counts is not None:
             with jax.named_scope(EXPERTS_SCOPE):
                 totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
-    return x, totals
+    return x, _sum_counts(totals, tiles)
 
 
 def head_params(params) -> dict:
@@ -615,11 +766,14 @@ def mtp_head_params(params) -> dict:
 
 def mtp_loss(params, x, labels, *, cfg, batch_sharding=None):
     """The prediction module's loss: the mean cross-entropy of the token after
-    next over the positions that have one → (loss, the module's expert
-    layer's counts or None, those positions' count)."""
+    next over the positions that have one → (loss, the module's layer's
+    counts: its expert layer's, its attention kernels' grid steps; those
+    positions' count)."""
     with jax.named_scope(MTP_SCOPE):
         after_next = jnp.concatenate([labels[:, 1:], jnp.full_like(labels[:, :1], -100)], axis=1)
         h, counts = mtp_hidden(params, x, labels, cfg=cfg, batch_sharding=batch_sharding)
+        kind = cfg.layer_kinds()[-1]
+        counts = _sum_counts(counts, layer_key_tiles(cfg, kind, x, params["mtp"]["layer"][kind]))
         loss, _ = labelled_nll(
             functools.partial(lm_head, cfg=cfg), mtp_head_params(params), h, after_next, batch_sharding
         )
@@ -633,7 +787,11 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     counts).  ``counts``: the expert layers' (summed over layers, the
     module's among them), ``tokens``, and the positions with a label,
     ``head_all`` over both losses and ``head_mtp`` the module's (0 without
-    one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32."""
+    one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32; and
+    two Python integers, known when the step is traced and no operation of it:
+    ``attn_tiles_run`` and ``attn_tiles_causal`` (the attention kernels' grid
+    steps and what causal lists alone would hold, over rows, layers and
+    key-value heads: equal without a window)."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
     with jax.named_scope(HEAD_SCOPE):  # the loss's own loop over tiles of positions around the head
         loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)
@@ -645,6 +803,5 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
         with jax.named_scope(MTP_SCOPE):
             loss = loss + cfg.mtp_loss_weight * terms["loss_mtp"]
             labelled = labelled + second
-            if more is not None:
-                counts = more if counts is None else jax.tree.map(jnp.add, counts, more)
+            counts = _sum_counts(counts, more)
     return loss, dict(counts, **terms, tokens=jnp.int32(ids.size), head_all=labelled, head_mtp=second)

@@ -90,6 +90,7 @@ HEAD_POSITIONS_FAMILY = "lakesoul_train_head_positions_total"
 TOKENS_FAMILY = "lakesoul_train_tokens_total"
 MOE_ASSIGNMENTS_FAMILY = "lakesoul_train_moe_assignments_total"
 MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
+ATTN_KEY_TILES_FAMILY = "lakesoul_train_attn_key_tiles_total"
 
 # live steps, and what the collected ones had counted: the families are
 # counters and must not fall when a step is dropped
@@ -102,7 +103,8 @@ _retired_lock = threading.RLock()
 def _counts(state: dict) -> dict:
     # one copy to the host, which waits for the last step dispatched
     limbs = np.asarray(state["counted"])
-    return {key: (int(high) << 30) + int(low) for key, (high, low) in zip(state["keys"], limbs)}
+    counted = {key: (int(high) << 30) + int(low) for key, (high, low) in zip(state["keys"], limbs)}
+    return {**counted, **state["host"]}
 
 
 def _series_values(state: dict) -> dict[tuple, float]:
@@ -155,18 +157,22 @@ class _CountedStep:
     carries (two int32 limbs of 30 bits) and is read only when the registry is
     scraped: the step loop reads nothing from the device for them.  ``series``
     says which registry series a count feeds: ``(count key, family, labels,
-    scale)``, the series' value being the count times ``scale``."""
+    scale)``, the series' value being the count times ``scale``.  The
+    ``host_keys`` among the counts are Python integers that do not depend on
+    the data (what the shapes make the kernels' grids): known when the step is
+    traced, no operation of the program, and added on the host a call."""
 
-    def __init__(self, step_fn, param_shardings, batch_shardings, loss_sharding, series):
+    def __init__(self, step_fn, param_shardings, batch_shardings, loss_sharding, series, host_keys=()):
         self._step_fn = step_fn
         self._param_shardings = param_shardings
         self._batch_shardings = batch_shardings
         self._replicated = loss_sharding
         self._fn = None
-        keys = tuple(sorted({key for key, *_ in series}))
+        keys = tuple(sorted({key for key, *_ in series} - set(host_keys)))
         self._state = {
             "counted": jax.device_put(np.zeros((len(keys), 2), np.int32), loss_sharding),
             "keys": keys, "series": tuple(series),
+            "host": dict.fromkeys(host_keys, 0), "a_step": dict.fromkeys(host_keys, 0),
         }
         _live_steps.add(self)
         # the finalizer holds the state, not the step; not at exit, when the
@@ -184,8 +190,11 @@ class _CountedStep:
 
             keys = self._state["keys"]
 
+            a_step = self._state["a_step"]
+
             def train_step(params, opt_state, counted, *batch):
                 params, opt_state, loss, counts = step_fn(params, opt_state, *batch)
+                a_step.update({key: int(counts[key]) for key in a_step})  # while tracing: one batch shape a step
                 with jax.named_scope(OPTIM_SCOPE):
                     low = counted[:, 1] + jnp.stack([counts[key] for key in keys]).astype(jnp.int32)
                     counted = jnp.stack([counted[:, 0] + (low >> 30), low & ((1 << 30) - 1)], axis=1)
@@ -208,6 +217,8 @@ class _CountedStep:
             params, opt_state, loss, state["counted"] = self._jitted(opt_state)(
                 params, opt_state, state["counted"], *batch
             )
+        for key, n in state["a_step"].items():
+            state["host"][key] += n
         return params, opt_state, loss
 
     def lower(self, params, opt_state, *batch):
@@ -334,8 +345,15 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     expert's weight-gradient sum, for one of the three matrices: once a tile
     where the sums ride the tile loop, once an expert and segment of tiles
     where ``parallel/moe.py: expert_dw`` keeps them in VMEM)
-    and ``lakesoul_train_moe_expert_load{stat="max"|"mean"}`` (the fullest and
-    the mean held expert's assignments, summed over steps and layers)."""
+    ``lakesoul_train_moe_expert_load{stat="max"|"mean"}`` (the fullest and
+    the mean held expert's assignments, summed over steps and layers) and
+    ``lakesoul_train_attn_key_tiles_total{kind="run"|"causal"}`` (the (query
+    tile, key tile) steps the attention kernels' lists hold, over the step's
+    rows, attention layers and key-value heads, and the steps causal lists
+    alone would hold: equal for a family without a window, 0 where no shape
+    takes the kernels; Python integers off ``models/causal_lm.py:
+    key_tile_steps`` when the step is traced, added on the host a call: they
+    are no operation of the step)."""
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
@@ -350,10 +368,12 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("moe_dw_writes", MOE_ASSIGNMENTS_FAMILY, {"kind": "dw_writes"}, 1),
         ("moe_load_max", MOE_LOAD_FAMILY, {"stat": "max"}, 1),
         ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / cfg.experts_held[1]),
+        ("attn_tiles_run", ATTN_KEY_TILES_FAMILY, {"kind": "run"}, 1),
+        ("attn_tiles_causal", ATTN_KEY_TILES_FAMILY, {"kind": "causal"}, 1),
     )
     return _CountedStep(
         _adamw_step(loss_fn, tx), param_shardings, (batch_sharding, batch_sharding),
-        NamedSharding(plan.mesh, P()), series,
+        NamedSharding(plan.mesh, P()), series, host_keys=("attn_tiles_run", "attn_tiles_causal"),
     )
 
 

@@ -15,7 +15,9 @@ bias), :func:`held_experts` and, where the family has one,
 :func:`route_sigmoid_top_k` at ``scale`` 1 with the default ``eps`` 1e-6 and no
 shared expert; ``models/glm4_moe_lite.py`` :func:`route_sigmoid_top_k` at
 ``scale`` 1.8 and ``eps`` 1e-20 and a shared expert with no gate (its weights
-hold no ``"gate"``).  They stay apart because the stack rematerialises the
+hold no ``"gate"``); ``models/afmoe.py`` the same rule at ``scale`` 2.826 and
+``eps`` 1e-20 over 128 experts, top 8, and a shared expert with no gate, the
+two summed before the layer's last norm.  They stay apart because the stack rematerialises the
 first and the last with its norm and leaves the second outside (see
 :func:`held_experts`).
 

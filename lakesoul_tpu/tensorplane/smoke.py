@@ -384,23 +384,30 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     # 4.0e-3 to 4.9e-3), not element by element
     t = min(8192, max(256, sizes.rows // 128))
     detail = {"tokens": t}
-    for hkv, groups, d in ((8, 4, 64), (2, 8, 256), (20, 1, 256)):
+    # and a Trinity-Mini row's window layer: 4 key-value heads of 8 query heads at head 128 under a window of
+    # 2,048 (half the row at tiny sizes), the twin's bands cut the same way
+    for hkv, groups, d, window in ((8, 4, 64, None), (2, 8, 256, None), (20, 1, 256, None), (4, 8, 128, 2048)):
         hkv = hkv if t == 8192 else 1
+        window = window and min(window, t // 2)
         keys = jax.random.split(jax.random.key(9), 4)
         q = (jax.random.normal(keys[0], (hkv, groups, t, d)) * d**-0.5).astype(jnp.bfloat16)
         k, v = (jax.random.normal(key, (hkv, t, d)).astype(jnp.bfloat16) for key in keys[1:3])
         do = jax.random.normal(keys[3], q.shape).astype(jnp.bfloat16)
         tiles = dict(zip(("bq", "bk"), _flash_tiles(t, groups, d), strict=True))
-        o, lse = _flash_forward(q, k, v, **tiles, interpret=interpret)
-        got = (o, *_flash_backward(q, k, v, o, lse, do, **tiles, interpret=interpret))
-        o_twin, pull = jax.vjp(lambda *qkv: _blockwise_attention(*(a[None] for a in qkv), ATTN_BAND, ATTN_ROWS)[0], q, k, v)
+        o, lse = _flash_forward(q, k, v, **tiles, window=window, interpret=interpret)
+        got = (o, *_flash_backward(q, k, v, o, lse, do, **tiles, window=window, interpret=interpret))
+        o_twin, pull = jax.vjp(
+            lambda *qkv: _blockwise_attention(*(a[None] for a in qkv), ATTN_BAND, ATTN_ROWS, window)[0], q, k, v
+        )
         errors = []
         for a, b in zip(got, (o_twin, *pull(do)), strict=True):
             a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernels' results against the blockwise twin's
             errors.append(float(np.linalg.norm(a - b) / np.linalg.norm(b)))
         if not max(errors) < 1e-2:
-            raise AssertionError(f"flash attention at {(hkv, groups, d)}: o, dq, dk, dv off by {errors}")
-        detail[f"group{groups}.head{d}"] = {"tiles": list(tiles.values()), "rel_err": [round(e, 5) for e in errors]}
+            raise AssertionError(f"flash attention at {(hkv, groups, d, window)}: o, dq, dk, dv off by {errors}")
+        detail[f"group{groups}.head{d}" + (f".window{window}" if window else "")] = {
+            "tiles": list(tiles.values()), "rel_err": [round(e, 5) for e in errors]
+        }
     return detail
 
 

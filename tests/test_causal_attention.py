@@ -1,7 +1,8 @@
 """``models/causal_lm.py: causal_attention``: the flash kernels (in the Pallas
 interpreter here) against the plain masked softmax and against their blockwise
-twin, forward and every gradient, at the three published group and head sizes;
-which shapes take the kernels; what a checkpoint around the caller keeps.
+twin, forward and every gradient, at the four published group and head sizes,
+under the causal mask and under a window; the tile lists; which shapes take
+the kernels; what a checkpoint around the caller keeps.
 """
 
 from __future__ import annotations
@@ -12,7 +13,8 @@ import pytest
 
 from lakesoul_tpu.models import causal_lm
 
-PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256), "glm-4.7-flash": (1, 256)}  # query heads a key-value head, head size
+# query heads a key-value head, head size
+PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256), "glm-4.7-flash": (1, 256), "trinity-mini": (8, 128)}
 # (tokens, FLASH_KEYS, FLASH_ROWS as a multiple of the group) → the (query, key) tiles of a row
 TILINGS = {
     "one-tile": (128, 512, 128),            # 1 x 1: the diagonal tile alone
@@ -21,12 +23,14 @@ TILINGS = {
 }
 
 
-def plain_attention(q, k, v):
-    """The whole score matrix, masked, in float32."""
+def plain_attention(q, k, v, window=None):
+    """The whole score matrix, masked, in float32: key ``j`` is visible to
+    query ``i`` iff ``0 <= i - j`` and, under a window, ``i - j < window``."""
     f32 = jnp.float32
     t = q.shape[3]
     s = jnp.einsum("bhgqd,bhkd->bhgqk", q.astype(f32), k.astype(f32))
-    s = jnp.where(jnp.arange(t)[:, None] >= jnp.arange(t)[None, :], s, -jnp.inf)
+    back = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    s = jnp.where((back >= 0) if window is None else (back >= 0) & (back < window), s, -jnp.inf)
     return jnp.einsum("bhgqk,bhkd->bhgqd", jax.nn.softmax(s, axis=-1), v.astype(f32))
 
 
@@ -78,6 +82,88 @@ def test_flash_kernels_equal_the_masked_softmax_and_their_twin(monkeypatch, fami
     assert got[0].dtype == v.dtype and got[1][0].dtype == q.dtype and got[1][1].dtype == k.dtype
 
 
+WINDOWS = {"one": 1, "one-tile": 128, "two-tiles": 256, "2048": 2048, "no-multiple-of-a-tile": 200}
+
+
+@pytest.mark.parametrize("window", sorted(WINDOWS))
+@pytest.mark.parametrize("family", sorted(PUBLISHED))
+def test_window_kernels_equal_the_masked_softmax_and_their_twin(monkeypatch, family, window):
+    """Output and dQ, dK, dV under a window, at each published group and head
+    size, over 4 x 4 tiles of 128 (a window of 2,048 is the 512-token row's
+    causal mask): the banded tile list, the lower edge's mask on a query
+    tile's first key tiles, both edges on one tile (a window of 1: a query
+    sees itself alone), against the whole mask and against the twin, whose
+    bands slice the keys from the first a band sees."""
+    (groups, d), t, window = PUBLISHED[family], 512, WINDOWS[window]
+    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
+    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 128 * groups)
+    assert causal_lm._flash_tiles(t, groups, d) == (128, 128)
+    q, k, v, weigh = operands(groups, d, t, jnp.float32)
+    got = out_and_grads(lambda *qkv: causal_lm.causal_attention(*qkv, window), q, k, v, weigh)
+    want = out_and_grads(lambda *qkv: plain_attention(*qkv, window), q, k, v, weigh)
+    twin = out_and_grads(lambda *qkv: causal_lm._blockwise_attention(*qkv, 256, 64, window), q, k, v, weigh)
+    if window == 1:  # every query sees its own key alone: the output is v's rows, and no score has a gradient
+        assert_close(got[0], jnp.broadcast_to(v[:, :, None], got[0].shape), 1e-6)
+        for found in (got, twin):
+            assert max(float(jnp.max(jnp.abs(g))) for g in found[1][:2]) < 1e-3  # dQ, dK: 0 but for rounding, where dV is of order 1
+        got, want, twin = ((found[0], found[1][2]) for found in (got, want, twin))
+    assert_close(got, want, 2e-4)
+    assert_close(twin, want, 2e-4)
+
+
+@pytest.mark.parametrize("family", sorted(PUBLISHED))
+def test_a_window_of_the_row_length_is_the_causal_path_bit_for_bit(family):
+    """No window, a window of the row's length and a longer one are one
+    program: the same tables, the same kernels, the same bits, forward and
+    gradients, in the kernels (256 tokens) and in the twin (150)."""
+    groups, d = PUBLISHED[family]
+    for t in (256, 150):
+        q, k, v, weigh = operands(groups, d, t, jnp.bfloat16)
+        want = out_and_grads(causal_lm.causal_attention, q, k, v, weigh)
+        for window in (t, t + 1, 10 * t):
+            got = out_and_grads(lambda *qkv, w=window: causal_lm.causal_attention(*qkv, w), q, k, v, weigh)
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+                assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+    assert causal_lm.key_tile_steps(256, groups, d, 256) == causal_lm.key_tile_steps(256, groups, d)
+
+
+def test_without_a_window_the_tile_tables_are_what_they_were():
+    """``_flash_steps(t, bq, bk)`` element for element as the list of every
+    (query tile, key tile) on or under the diagonal, which is what the kernels
+    walked before they knew a window; ``window=None`` names the same."""
+    for t, bq, bk in ((8192, 128, 512), (8192, 256, 512), (8192, 512, 512), (512, 128, 256), (384, 128, 128)):
+        before = [(i, j) for i in range(t // bq) for j in range((i * bq + bq - 1) // bk + 1)]
+        for tables in (causal_lm._flash_steps(t, bq, bk), causal_lm._flash_steps(t, bq, bk, None)):
+            assert [a.dtype for a in tables] == [jnp.int32] * 2
+            assert list(zip(*(a.tolist() for a in tables), strict=True)) == before
+        assert causal_lm._flash_pairs(t, bq, bk, t) == before  # a window of the row's length hides no tile
+
+
+def test_a_windows_tile_list_is_the_band():
+    """At the Trinity-Mini cell's shape (8,192 tokens, groups of 8 at head 128:
+    tiles of 128 queries x 512 keys) a causal list holds 544 steps a key-value
+    head and a window of 2,048 holds 280: for every query tile the key tiles
+    from the one that holds the first key its first query sees to the
+    diagonal's, and no other."""
+    assert causal_lm._flash_tiles(8192, 8, 128) == (128, 512)
+    assert causal_lm.key_tile_steps(8192, 8, 128) == (544, 544)
+    assert causal_lm.key_tile_steps(8192, 8, 128, 2048) == (280, 544)
+    assert causal_lm.key_tile_steps(8192, 8, 128, 8192) == (544, 544)
+    assert causal_lm.key_tile_steps(150, 8, 128, 40) == (0, 0)  # the twin lists no tile
+    pairs = causal_lm._flash_pairs(8192, 128, 512, 2048)
+    assert len(pairs) == 280 and pairs[:3] == [(0, 0), (1, 0), (2, 0)] and pairs[-5:] == [(63, j) for j in (11, 12, 13, 14, 15)]
+    for i in range(64):
+        keys = [j for q, j in pairs if q == i]
+        seen = set(range(max(0, 128 * i - 2047), 128 * i + 128))  # the keys any query of the tile sees
+        assert keys == sorted({key // 512 for key in seen})
+    # the pairs the mask lets through: 43.7% of the triangle, on 51.5% of its tiles
+    assert sum(min(i + 1, 2048) for i in range(8192)) == 14_681_088
+    qi, kj = causal_lm._flash_steps(512, 128, 128, 200)
+    assert list(zip(qi.tolist(), kj.tolist(), strict=True)) == [
+        (0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2), (3, 1), (3, 2), (3, 3)
+    ]
+
+
 def test_a_key_tile_after_the_query_tile_is_no_step():
     """The grid is the list of tiles on or under the diagonal: 6 of 8 where two
     query tiles share a key tile, each query tile's last step its diagonal."""
@@ -91,6 +177,7 @@ def test_a_key_tile_after_the_query_tile_is_no_step():
     (8192, 4, 64, (256, 512)),    # the LFM2 cell's layer
     (8192, 8, 256, (128, 512)),   # the Qwen cell's
     (8192, 1, 128, (512, 512)),
+    (8192, 8, 128, (128, 512)),   # the Trinity-Mini cell's: window and full layers alike
     (8192, 1, 256, (512, 512)),   # the GLM cell's: latent attention, 20 key-value heads of one query head each
     (256, 4, 64, (256, 256)),
     (128, 16, 64, (128, 128)),    # 128 queries at least: the log-sum-exp's lane tile
@@ -109,7 +196,7 @@ def test_which_shapes_take_the_kernels(monkeypatch, t, groups, d, tiles):
     q, k, v, _ = operands(groups, d, t, jnp.float32)
     causal_lm.causal_attention(q, k, v)
     # no TPU here: the interpreter
-    assert calls == ([] if tiles is None else [{"bq": tiles[0], "bk": tiles[1], "interpret": True}])
+    assert calls == ([] if tiles is None else [{"bq": tiles[0], "bk": tiles[1], "window": None, "interpret": True}])
 
 
 def test_a_row_checkpoint_keeps_the_output_and_the_log_sum_exp():

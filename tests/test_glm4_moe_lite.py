@@ -194,7 +194,7 @@ def test_latent_mixer_at_the_published_head_takes_the_flash_kernels(monkeypatch)
     x = hidden(3, 128)[:1]
     weigh = jax.random.normal(jax.random.key(4), x.shape)
     assert_close(mixer(cfg)(x, p), ref.attention(x, p, PUBLISHED_HEADS))
-    assert calls == [((2, 1, 128, 256), {"bq": 128, "bk": 128, "interpret": True})]  # [heads, group of 1, T, 256]
+    assert calls == [((2, 1, 128, 256), {"bq": 128, "bk": 128, "window": None, "interpret": True})]  # [heads, group of 1, T, 256]
     assert_close(
         jax.jit(jax.grad(lambda p, x: jnp.sum(weigh * mixer(cfg)(x, p)), argnums=(0, 1)))(p, x),
         jax.jit(jax.grad(lambda p, x: jnp.sum(weigh * ref.attention(x, p, PUBLISHED_HEADS)), argnums=(0, 1)))(p, x),
@@ -554,7 +554,8 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
     main, second = B * (T - 1), B * (T - 2)
     assert once.counts() == {"tokens": B * T, "moe_all": 3 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
                              "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
-                             "head_mtp": second, "head_all": main + second}
+                             "head_mtp": second, "head_all": main + second,
+                             "attn_tiles_run": 0, "attn_tiles_causal": 0}  # 150 tokens: the kernels list no tile
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]
     steps = 2 * STEPS + 1

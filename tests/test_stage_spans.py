@@ -819,6 +819,149 @@ def test_glm_readers(check):
         assert '{"kind": "mtp"}' in f.read()
 
 
+# ------------------------------------------- the fourth causal-LM family
+
+AFMOE_SCOPES = {
+    "lakesoul.lm.swa": ("layer_metrics/swa_step_share_pct.py", 'SCOPE = "swa"'),
+}
+
+
+def _afmoe_cfg(**changed):
+    from lakesoul_tpu.models.afmoe import AfmoeConfig
+
+    # the held cut's pattern (a dense window layer, then window, full, window, window), four heads of 64 on one
+    # key-value head: every mixer takes its kernels at 128 tokens, the window layers under a window of 64
+    sizes = dict(
+        vocab_size=64, hidden_size=256, num_hidden_layers=5, num_dense_layers=1, intermediate_size=48,
+        layer_types=("sliding_attention", "sliding_attention", "full_attention", "sliding_attention", "sliding_attention"),
+        num_attention_heads=4, num_key_value_heads=1, head_dim=64, sliding_window=64, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=16, experts_held=(0, 4),
+    )
+    return AfmoeConfig(**(sizes | changed))
+
+
+@pytest.fixture(scope="module")
+def afmoe_step_compiled_for_a_v5e() -> str:
+    return _family_step_compiled_for_a_v5e(_afmoe_cfg())
+
+
+def test_afmoe_step_holds_the_kernels_under_swa_and_attn_and_every_scope_a_reader_sums(afmoe_step_compiled_for_a_v5e):
+    """Five attention layers, each with the forward and the backward kernel
+    once (``flash_attention_fwd.<n>`` and ``flash_attention_bwd.<n>`` in a
+    device trace): four under ``lakesoul.lm.swa`` and one under
+    ``lakesoul.lm.attn`` by the adaptor's scope map, which is how
+    ``swa_step_share_pct`` and the two roofline readers tell a window layer
+    from a full one.  The four norms and the gate bring no scope of their own:
+    the step's scopes are the thirteen's subset a reader sums."""
+    text = afmoe_step_compiled_for_a_v5e
+    calls = [name for name in _kernel_calls(text) if name.startswith("flash_attention")]
+    assert sorted(name.rsplit(".", 1)[0] for name in calls) == ["flash_attention_bwd"] * 5 + ["flash_attention_fwd"] * 5
+    scope_of = _adaptor("afmoe_clm").scopes_of(text)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
+        charged = sorted(scope_of.get(name) for name in calls if name.startswith(kernel))
+        assert charged == ["lakesoul.lm.attn"] + ["lakesoul.lm.swa"] * 4, charged
+    shared = set(LM_SCOPES) - {"lakesoul.lm.gdn"}
+    assert set(scope_of.values()) == shared | set(AFMOE_SCOPES) | {"lakesoul.lm.mlp"}
+    assert len(set(LM_SCOPES) | set(LFM2_SCOPES) | set(GLM_SCOPES) | set(AFMOE_SCOPES)) == 13
+    dots = re.findall(r"^\s*%?([\w.\-]+) = \S+ (?:convolution|fusion)\(", text, re.MULTILINE)
+    assert sum(scope_of.get(name) == "lakesoul.lm.swa" for name in dots) >= 3  # forward, rematerialised, backward
+
+
+@pytest.mark.parametrize("scope", sorted(AFMOE_SCOPES))
+def test_afmoe_scope_names_the_share_readers_search_for(scope):
+    from lakesoul_tpu.models import afmoe
+    from lakesoul_tpu.models.train import make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    cfg = _afmoe_cfg()
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    text = make_lm_train_step(cfg, plan, tx, shardings).lower(params, opt_state, ids, ids).as_text(debug_info=True)
+    assert "module @jit_train_step " in text
+    assert f"{scope}/" in text or f"{scope})" in text or f'{scope}"' in text
+    assert scope == afmoe.SWA_SCOPE
+    reader, constant = AFMOE_SCOPES[scope]
+    with open(os.path.join(REPO, "benchmarks", "chip", reader)) as f:
+        assert constant in f.read()
+    with open(os.path.join(REPO, "benchmarks", "chip", "consumers", "afmoe_clm.py")) as f:
+        assert 'STEP_MODULE = "jit_train_step"' in f.read()
+    with open(os.path.join(REPO, "benchmarks", "chip", "chipbench", "flash_roofline.py")) as f:
+        assert 'scopes.PREFIX + "swa", scopes.PREFIX + "attn"' in f.read() and scope == "lakesoul.lm." + "swa"
+
+
+def test_attn_key_tiles_counter_is_the_tile_tables(monkeypatch):
+    """``lakesoul_train_attn_key_tiles_total{kind="run"|"causal"}`` after one
+    step against ``_flash_pairs``' own tables: 384 tokens as 3 x 3 tiles of
+    128 (the kernels in the interpreter), a window of 100, one window layer
+    and one full: 5 of a causal list's 6 steps and all 6, over 2 rows and 2
+    key-value heads.  And the host count at the Trinity-Mini cell's shapes,
+    from an abstract trace of the mixers: 280 and 544 steps a key-value head,
+    61.2% over four window layers and a full one."""
+    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models.train import ATTN_KEY_TILES_FAMILY, make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.obs import registry
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    def series(kind):
+        return registry().snapshot().get(f'{ATTN_KEY_TILES_FAMILY}{{kind="{kind}"}}', 0)
+
+    whole = _afmoe_cfg(
+        hidden_size=2048, num_attention_heads=32, num_key_value_heads=4, head_dim=128, sliding_window=2048, dtype="bfloat16"
+    )
+    weights = jax.eval_shape(whole.init, jax.random.key(0))["layers"]
+    rows = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16)
+    found = {kind: causal_lm.mixer_key_tiles(whole.mixer(kind)[0], rows, weights[layer][kind])
+             for kind, layer in (("swa", 0), ("attn", 2))}
+    assert found == {"swa": (2 * 4 * 280, 2 * 4 * 544), "attn": (2 * 4 * 544, 2 * 4 * 544)}
+    run, causal = (4 * found["swa"][i] + found["attn"][i] for i in (0, 1))
+    assert round(100 * run / causal, 1) == 61.2
+
+    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
+    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 256)
+    cfg = _afmoe_cfg(
+        hidden_size=64, num_hidden_layers=2, layer_types=("sliding_attention", "full_attention"),
+        num_attention_heads=4, num_key_value_heads=2, sliding_window=100,
+    )
+    assert len(causal_lm._flash_pairs(384, 128, 128, 100)) == 5 and len(causal_lm._flash_pairs(384, 128, 128)) == 6
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    step = make_lm_train_step(cfg, plan, tx, shardings)
+    before = {kind: series(kind) for kind in ("run", "causal")}
+    ids = jnp.zeros((2, 384), jnp.int32)
+    step(params, opt_state, ids, ids)
+    assert (step.counts()["attn_tiles_run"], step.counts()["attn_tiles_causal"]) == (2 * 2 * (5 + 6), 2 * 2 * (6 + 6))
+    assert {kind: series(kind) - before[kind] for kind in before} == {"run": 44, "causal": 48}
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "attn_tiles_run_pct.py")) as f:
+        assert f'COUNTER = "{ATTN_KEY_TILES_FAMILY}"' in f.read()
+
+
+AFMOE_READER_CHECKS = [
+    "window_share_of_a_hand_step", "window_share_gives_nothing_without_its_scope", "tiles_run_of_hand_counts",
+    "tiles_run_gives_nothing_without_the_series", "a_window_of_the_row_length_counts_as_the_causal_mask",
+    "roofline_shares_of_hand_events", "roofline_readers_give_nothing_without_their_events",
+]
+
+
+@pytest.mark.parametrize("check", AFMOE_READER_CHECKS)
+def test_afmoe_readers(check):
+    """The four readers the Trinity-Mini cell added and the kernels' cost
+    functions through their own self-test, and the series one of them divides
+    under the name the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import ATTN_KEY_TILES_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_afmoe_readers", os.path.join(REPO, "benchmarks", "chip", "selftest", "afmoe_readers.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_" + name for name in AFMOE_READER_CHECKS]
+    getattr(selftest, "test_" + check)()
+    assert selftest.FAMILY == ATTN_KEY_TILES_FAMILY
+
+
 # ---------------------------------------- the step named whole (PR 38)
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
@@ -862,6 +1005,7 @@ UNSCOPED = {
     "qwen3_next_clm": ("lm_step_compiled_for_a_v5e", 14),
     "lfm2_moe_clm": ("lfm2_step_compiled_for_a_v5e", 4),
     "glm4_moe_lite_clm": ("glm_step_compiled_for_a_v5e", 0),
+    "afmoe_clm": ("afmoe_step_compiled_for_a_v5e", 7),
 }
 
 

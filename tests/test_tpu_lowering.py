@@ -131,7 +131,7 @@ def _put_tiles(d):
 
 
 ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads, query heads each serves, head size
-    "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256),
+    "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256), "trinity-mini": (4, 8, 128),
 }
 
 
@@ -144,16 +144,20 @@ def _attention_row(d):
     return _sds((hkv, groups, t, d), bf16), _sds((hkv, t, d), bf16), dict(zip(("bq", "bk"), causal_lm._flash_tiles(t, groups, d)))
 
 
-def _flash_forward(d):
+def _flash_forward(d, window=None):
     q, k, tiles = _attention_row(d)
-    return jax.jit(lambda q, k, v: causal_lm._flash_forward(q, k, v, **tiles, interpret=False)).trace(q, k, k)
+    return jax.jit(
+        lambda q, k, v: causal_lm._flash_forward(q, k, v, **tiles, window=window, interpret=False)
+    ).trace(q, k, k)
 
 
-def _flash_backward(d):
+def _flash_backward(d, window=None):
     q, k, tiles = _attention_row(d)
     lse = _sds((*q.shape[:2], 1, q.shape[2]))
     return jax.jit(
-        lambda q, k, v, o, lse, do: causal_lm._flash_backward(q, k, v, o, lse, do, **tiles, interpret=False)
+        lambda q, k, v, o, lse, do: causal_lm._flash_backward(
+            q, k, v, o, lse, do, **tiles, window=window, interpret=False
+        )
     ).trace(q, k, k, q, lse, q)
 
 
@@ -190,10 +194,27 @@ def test_kernel_lowers_for_tpu(kernel, d):
 @pytest.mark.parametrize("family", sorted(ATTENTION_ROWS))
 @pytest.mark.parametrize("kernel", ["_flash_fwd_kernel", "_flash_bwd_kernel"])
 def test_attention_kernels_lower_at_every_published_shape(kernel, family):
-    """The three families' rows: groups of 4 at head 64, of 8 at head 256, and
+    """The four families' rows: groups of 4 at head 64, of 8 at head 256,
     latent attention's 20 key-value heads of one query head each at head 256
-    (tiles of 512 queries x 512 keys)."""
+    (tiles of 512 queries x 512 keys), and groups of 8 at head 128."""
     if family == "glm-4.7-flash":
         assert causal_lm._flash_tiles(8192, 1, 256) == (512, 512)
     lowered = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](family).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window-2048"])
+@pytest.mark.parametrize("kernel", ["_flash_fwd_kernel", "_flash_bwd_kernel"])
+def test_attention_kernels_lower_with_and_without_the_window(kernel, window):
+    """A Trinity-Mini row's two kinds of layer: 4 key-value heads of 8 query
+    heads at head 128, 8,192 tokens in tiles of 128 queries x 512 keys, under
+    the causal mask (544 steps a head) and under a window of 2,048 (280: the
+    lower edge's mask and the grid's first key tile read off the step)."""
+    assert causal_lm._flash_tiles(8192, 8, 128) == (128, 512)
+    lowered = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel]("trinity-mini", window).lower(
+        lowering_platforms=("tpu",)
+    )
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text
+    steps = causal_lm.key_tile_steps(8192, 8, 128, window)[0]
+    assert steps == (280 if window else 544) and f"tensor<{steps}xi32>" in text  # the tables the call prefetches
