@@ -132,7 +132,7 @@ class AfmoeConfig:
         return functools.partial(
             softmax_attention, heads=self.num_attention_heads, kv_heads=self.num_key_value_heads,
             head_dim=self.head_dim, rotary_dim=self.head_dim if local else None, theta=self.rope_theta,
-            norm=self.norm, gated=False, window=self.sliding_window if local else None,
+            eps=self.rms_norm_eps, centred=False, gated=False, window=self.sliding_window if local else None,
         ), SWA_SCOPE if local else ATTN_SCOPE
 
     def norm(self, x, w):

@@ -95,6 +95,8 @@ FLASH_KEYS = 512   # keys a tile holds, where the row has as many
 FLASH_ROWS = 1024  # score rows a tile holds: a group's heads x queries, 128 queries at least
 FLASH_ROW_ELEMENTS = 8192 * 256  # T x D at most: a row's float32 dK and dV are 8 MB each at that
 FLASH_VMEM_BYTES = 96 * 2**20    # of a v5e's 128 MiB
+OPERAND_ELEMENTS = 512 * 1024    # tokens x a group's channels a block of the operand kernels holds at most: 1 MB
+OPERAND_VMEM_BYTES = 64 * 2**20
 MASKED = -1e30
 _NT = (((1,), (1,)), ((), ()))  # x y^T
 _TN = (((0,), (0,)), ((), ()))  # x^T y
@@ -125,12 +127,17 @@ def causal_conv(x, w):
 # ------------------------------------------------------ softmax attention
 
 
+def _rotary_angles(positions, rotary_dim: int, theta: float):
+    """The positions' angles [T, rotary_dim // 2], float32."""
+    inv_freq = theta ** (-jnp.arange(rotary_dim // 2, dtype=jnp.float32) * 2.0 / rotary_dim)
+    return positions.astype(jnp.float32)[:, None] * inv_freq
+
+
 def _rotary(x, positions, rotary_dim: int, theta: float):
     """Rotate the first ``rotary_dim`` channels of x [B, T, H, D] (float32):
     halves ``[x1 | x2]`` → ``[x1 cos - x2 sin | x2 cos + x1 sin]``."""
     half = rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq  # [T, half]
+    angle = _rotary_angles(positions, rotary_dim, theta)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
@@ -443,22 +450,243 @@ def _flash_attention_bwd(bq, bk, window, kept, do):
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-_traced_tiles = threading.local()  # ``.steps``: what :func:`mixer_key_tiles` collects while it traces a mixer
+# The operand kernels.  Between the projections and the flash kernels a mixer
+# norms each query and key head, turns it by its position, scales the query,
+# casts, and lays heads before tokens.  Where :func:`_operand_tiles` takes the
+# shape, one kernel does that in one pass over the projections' outputs and a
+# second the transpose of it: a block is ``bt`` tokens of one key-value head's
+# group, a head one row of whole lane tiles a token, and the permutation is
+# the block specs' (no transpose inside).  Float32 inside, one rounding at the
+# end, as the ``jnp`` lines they stand for (:func:`_xla_operands`, their twin).
 
 
-def mixer_key_tiles(mixer, x, p) -> tuple[int, int]:
-    """(the (query tile, key tile) steps the attention kernels' lists hold for
-    ``mixer(x, p)``, the steps causal lists alone would hold), summed over
-    every :func:`causal_attention` the mixer calls and over its rows and
-    key-value heads: host integers off :func:`key_tile_steps`, from one
-    abstract trace of the mixer (nothing runs).  (0, 0) for a mixer without
-    attention or a shape the kernels do not take."""
-    _traced_tiles.steps = steps = []
+def _operand_tiles(t: int, heads: int, kv_heads: int, d: int, rotary_dim: int | None):
+    """Tokens a block of the operand kernels holds, or None where they do not
+    take the shape: one the flash kernels take (:func:`_flash_tiles`), a head
+    of whole 128-lane tiles, and positions over the whole head or none (a turn
+    is then a roll by half a head)."""
+    if heads % kv_heads or _flash_tiles(t, heads // kv_heads, d) is None or d % 128 or rotary_dim not in (None, d):
+        return None
+    width = heads // kv_heads * d
+    return next(n for n in (512, 256, 128) if t % n == 0 and (n == 128 or n * width <= OPERAND_ELEMENTS))
+
+
+def _head_operand(x, w, turn, eps: float):
+    """x [n, D] float32, a head's raw channels a token → normed
+    (:func:`_rms_norm` by the weight as it multiplies) and turned: ``[x1 cos -
+    x2 sin | x2 cos + x1 sin]`` as ``y * [cos | cos] + roll(y) * [-sin | sin]``."""
+    y = _rms_norm(x, w, eps, centred=False)
+    if turn is None:
+        return y
+    return y * turn[0] + pltpu.roll(y, y.shape[1] // 2, 1) * turn[1]
+
+
+def _head_operand_grads(x, w, turn, dz, eps: float):
+    """:func:`_head_operand` transposed: the cotangent ``dz`` [n, D] → (the
+    raw channels', the weight's summed over every eighth token: [8, D])."""
+    r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    y = x * r
+    if turn is not None:  # the turn by the negative angle
+        dz = dz * turn[0] - pltpu.roll(dz, dz.shape[1] // 2, 1) * turn[1]
+    dy = dz * w
+    dx = r * (dy - y * jnp.mean(dy * y, axis=-1, keepdims=True))
+    return dx, jnp.sum((dz * y).reshape(-1, 8, x.shape[1]), axis=0)
+
+
+def _operands_fwd_kernel(*refs, groups: int, eps: float, turned: bool):
+    """One block: ``bt`` tokens of a key-value head's ``groups`` query heads
+    [bt, G*D], its key and value [bt, D] → the query [G, bt, D] scaled, the
+    key and the value [bt, D]."""
+    q_ref, k_ref, v_ref, wq_ref, wk_ref, *turn, qo_ref, ko_ref, vo_ref = refs
+    d = k_ref.shape[1]
+    f32 = jnp.float32
+    turn = tuple(a[...] for a in turn) if turned else None
+    for g in range(groups):
+        q = _head_operand(q_ref[:, g * d:(g + 1) * d].astype(f32), wq_ref[...], turn, eps)
+        qo_ref[g] = (q * d**-0.5).astype(qo_ref.dtype)
+    ko_ref[...] = _head_operand(k_ref[...].astype(f32), wk_ref[...], turn, eps).astype(ko_ref.dtype)
+    vo_ref[...] = v_ref[...]
+
+
+def _operands_bwd_kernel(*refs, groups: int, eps: float, turned: bool):
+    """:func:`_operands_fwd_kernel` transposed, on its grid: the operands'
+    cotangents and the raw query and key → the raw cotangents in the
+    projections' layout and this block's share of the two norm weights'
+    gradients [8, D] float32."""
+    dqo_ref, dko_ref, dvo_ref, q_ref, k_ref, wq_ref, wk_ref, *turn, dq_ref, dk_ref, dv_ref, dwq_ref, dwk_ref = refs
+    d = k_ref.shape[1]
+    f32 = jnp.float32
+    turn = tuple(a[...] for a in turn) if turned else None
+    dwq = jnp.zeros((8, d), f32)
+    for g in range(groups):
+        heads = slice(g * d, (g + 1) * d)
+        dq, dw = _head_operand_grads(
+            q_ref[:, heads].astype(f32), wq_ref[...], turn, dqo_ref[g].astype(f32) * d**-0.5, eps
+        )
+        dq_ref[:, heads] = dq.astype(dq_ref.dtype)
+        dwq += dw
+    dwq_ref[...] = dwq
+    dk, dwk_ref[...] = _head_operand_grads(k_ref[...].astype(f32), wk_ref[...], turn, dko_ref[...].astype(f32), eps)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dvo_ref[...]
+
+
+def _operand_grid(q, k, d: int, bt: int, turned: bool, *, in_specs, out_specs):
+    """What the two kernels' ``pallas_call``s share, over the raw q [B, T,
+    heads*D] and k [B, T, kv*D]: the grid (rows, token blocks, key-value
+    heads: the heads innermost, so a block of the position tables is fetched
+    once) and block specs by what a block follows: the ``raw`` query's [bt,
+    G*D] and key's or value's ``raw_kv`` [bt, D], the flash kernels' ``laid``
+    [G, bt, D] and ``laid_kv`` [bt, D], a weight gradient's ``share`` [8, D];
+    after ``in_specs`` come the two norm weights [1, D] and, where the layer
+    turns, the two position tables' [bt, D].  Returns (key-value heads, query
+    heads each serves, the call's keyword arguments)."""
+    b, t, width = k.shape
+    kv, groups = width // d, q.shape[2] // width
+    specs = {
+        "raw": pl.BlockSpec((None, bt, groups * d), lambda r, i, h: (r, i, h)),
+        "raw_kv": pl.BlockSpec((None, bt, d), lambda r, i, h: (r, i, h)),
+        "laid": pl.BlockSpec((None, None, groups, bt, d), lambda r, i, h: (r, h, 0, i, 0)),
+        "laid_kv": pl.BlockSpec((None, None, bt, d), lambda r, i, h: (r, h, i, 0)),
+        "share": pl.BlockSpec((None, None, None, 8, d), lambda r, i, h: (r, i, h, 0, 0)),
+    }
+    weight = pl.BlockSpec((1, d), lambda r, i, h: (0, 0))
+    table = pl.BlockSpec((bt, d), lambda r, i, h: (i, 0))
+    return kv, groups, dict(
+        grid=(b, t // bt, kv),
+        in_specs=[specs[s] for s in in_specs] + [weight] * 2 + [table] * (2 * turned),
+        out_specs=[specs[s] for s in out_specs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3, vmem_limit_bytes=OPERAND_VMEM_BYTES
+        ),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "bt", "interpret"))
+def _operands_forward(q, k, v, wq, wk, turn, *, eps: float, bt: int, interpret: bool):
+    """The raw q [B, T, heads*D], k, v [B, T, kv*D] as the products leave
+    them, the norm weights [D] as they multiply, ``turn`` None or the position
+    tables ``([cos | cos], [-sin | sin])`` [T, D] float32 → the flash kernels'
+    q [B, kv, G, T, D] (scaled), k, v [B, kv, T, D]."""
+    b, t, _ = q.shape
+    d = wq.shape[0]
+    kv, groups, grid = _operand_grid(
+        q, k, d, bt, turn is not None, in_specs=("raw", "raw_kv", "raw_kv"), out_specs=("laid", "laid_kv", "laid_kv")
+    )
+    return pl.pallas_call(
+        functools.partial(_operands_fwd_kernel, groups=groups, eps=eps, turned=turn is not None),
+        out_shape=(jax.ShapeDtypeStruct((b, kv, groups, t, d), q.dtype),
+                   *(jax.ShapeDtypeStruct((b, kv, t, d), a.dtype) for a in (k, v))),
+        name="attn_operands_fwd", interpret=interpret, **grid,
+    )(q, k, v, wq[None], wk[None], *(turn or ()))
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "bt", "interpret"))
+def _operands_backward(dq, dk, dv, q, k, wq, wk, turn, *, eps: float, bt: int, interpret: bool):
+    """The cotangents of :func:`_operands_forward`'s results, and its raw q
+    and k again → the cotangents of the raw q, k, v and of the two norm
+    weights (float32, summed here over the blocks' shares)."""
+    b, t, _ = q.shape
+    d = wq.shape[0]
+    kv, groups, grid = _operand_grid(
+        q, k, d, bt, turn is not None, in_specs=("laid", "laid_kv", "laid_kv", "raw", "raw_kv"),
+        out_specs=("raw", "raw_kv", "raw_kv", "share", "share"),
+    )
+    share = jax.ShapeDtypeStruct((b, t // bt, kv, 8, d), jnp.float32)
+    dq, dk, dv, dwq, dwk = pl.pallas_call(
+        functools.partial(_operands_bwd_kernel, groups=groups, eps=eps, turned=turn is not None),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(k.shape, dv.dtype), share, share),
+        name="attn_operands_bwd", interpret=interpret, **grid,
+    )(dq, dk, dv, q, k, wq[None], wk[None], *(turn or ()))
+    return dq, dk, dv, dwq.sum((0, 1, 2, 3)).astype(wq.dtype), dwk.sum((0, 1, 2, 3)).astype(wk.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _attention_operands(q, k, v, wq, wk, turn, eps, bt):
+    return _operands_forward(q, k, v, wq, wk, turn, eps=eps, bt=bt, interpret=not _on_tpu())
+
+
+def _attention_operands_fwd(q, k, v, wq, wk, turn, eps, bt):
+    # nothing new is kept: a checkpoint around the caller computes the raw q and k again, as it did
+    return _attention_operands(q, k, v, wq, wk, turn, eps, bt), (q, k, wq, wk, turn)
+
+
+def _attention_operands_bwd(eps, bt, kept, cotangents):
+    q, k, wq, wk, turn = kept
+    grads = _operands_backward(*cotangents, q, k, wq, wk, turn, eps=eps, bt=bt, interpret=not _on_tpu())
+    return *grads, jax.tree.map(jnp.zeros_like, turn)  # the tables come from positions alone
+
+
+_attention_operands.defvjp(_attention_operands_fwd, _attention_operands_bwd)
+
+
+def _xla_operands(q, k, v, wq, wk, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):
+    """The raw q [B, T, heads, D], k, v [B, T, kv, D] and the two head norms'
+    weights → the flash kernels' q [B, kv, G, T, D] (normed, turned over
+    ``rotary_dim`` channels, scaled), k (normed, turned), v [B, kv, T, D], as
+    whole-array ``jnp`` operations in float32: what a shape the operand
+    kernels do not take runs, and the kernels' twin."""
+    b, t, heads, d = q.shape
+    kv_heads = k.shape[2]
+    dtype = v.dtype
+    positions = jnp.arange(t)
+
+    def turned(a):
+        return a if rotary_dim is None else _rotary(a, positions, rotary_dim, theta)
+
+    q = turned(_rms_norm(q, wq, eps, centred=centred))
+    k = turned(_rms_norm(k, wk, eps, centred=centred))
+    q = (q * d**-0.5).astype(dtype)
+    # [B, T, heads, D] → [B, kv, heads // kv, T, D]: each key-value head serves a group
+    q = q.reshape(b, t, kv_heads, heads // kv_heads, d).transpose(0, 2, 3, 1, 4)
+    k, v = (a.transpose(0, 2, 1, 3) for a in (k.astype(dtype), v))
+    return q, k, v
+
+
+def _turn_tables(t: int, d: int, theta: float):
+    """The operand kernels' position tables for a row of ``t`` tokens turned
+    over a whole head: ``([cos | cos], [-sin | sin])`` [T, D] float32."""
+    angle = _rotary_angles(jnp.arange(t), d, theta)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
+
+
+def _kernel_operands(q, k, v, wq, wk, bt: int, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):
+    """:func:`_xla_operands` by the operand kernels, ``bt`` tokens a block
+    (:func:`_operand_tiles`): the same arguments, the same results."""
+    b, t, _, _ = q.shape
+    turn = None if rotary_dim is None else _turn_tables(t, rotary_dim, theta)
+    wq, wk = ((1.0 + w) if centred else w for w in (wq, wk))
+    # the reshapes undo the caller's: the kernels read the products' own [B, T, heads x D]
+    q, k, v = (a.reshape(b, t, -1) for a in (q, k, v))
+    return _attention_operands(q, k, v, wq.astype(jnp.float32), wk.astype(jnp.float32), turn, eps, bt)
+
+
+_traced_tiles = threading.local()  # ``.steps``, ``.operands``: what :func:`mixer_counts` collects while it traces a mixer
+
+
+def mixer_counts(mixer, x, p) -> dict:
+    """What ``mixer(x, p)``'s shapes make its attention run, as host integers
+    from one abstract trace of the mixer (nothing runs), summed over every
+    :func:`causal_attention` and :func:`softmax_attention` it calls:
+    ``attn_tiles_run`` and ``attn_tiles_causal``, the (query tile, key tile)
+    steps the attention kernels' lists hold over its rows and key-value heads
+    and the steps causal lists alone would hold (:func:`key_tile_steps`; 0 for
+    a shape the kernels do not take); ``attn_operands_kernel`` and
+    ``attn_operands_xla``, its rows by what made the flash kernels' operands,
+    the operand kernels or the ``jnp`` lines (:func:`_operand_tiles`).  All 0
+    for a mixer without attention."""
+    _traced_tiles.steps, _traced_tiles.operands = steps, operands = [], []
     try:
-        jax.eval_shape(mixer, x, p)
+        jax.eval_shape(lambda x, p: mixer(x, p), x, p)  # a function of its own: a trace cached for ``mixer`` collects nothing
     finally:
-        del _traced_tiles.steps
-    return (sum(n for n, _ in steps), sum(n for _, n in steps))
+        del _traced_tiles.steps, _traced_tiles.operands
+    return {
+        "attn_tiles_run": sum(n for n, _ in steps), "attn_tiles_causal": sum(n for _, n in steps),
+        "attn_operands_kernel": sum(rows for rows, fused in operands if fused),
+        "attn_operands_xla": sum(rows for rows, fused in operands if not fused),
+    }
 
 
 def causal_attention(q, k, v, window: int | None = None):
@@ -484,7 +712,7 @@ def causal_attention(q, k, v, window: int | None = None):
     b, hkv, groups, t, d = q.shape
     if window is not None and window >= t:
         window = None
-    if hasattr(_traced_tiles, "steps"):  # :func:`mixer_key_tiles` is tracing the caller
+    if hasattr(_traced_tiles, "steps"):  # :func:`mixer_counts` is tracing the caller
         _traced_tiles.steps.append([b * hkv * n for n in key_tile_steps(t, groups, d, window)])
     tiles = _flash_tiles(t, groups, d)
     if tiles is None:
@@ -496,15 +724,22 @@ def causal_attention(q, k, v, window: int | None = None):
 
 
 def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_dim: int | None,
-                      theta: float, norm, gated: bool, window: int | None = None):
+                      theta: float, eps: float, centred: bool, gated: bool, window: int | None = None):
     """The grouped-query softmax-attention mixer: x [B, T, h] (normed) →
-    [B, T, h].  ``norm(a, w)`` is the family's RMS norm over a head's channels
-    (``q_norm``, ``k_norm``); ``rotary_dim`` of them are rotated, none where it
+    [B, T, h].  ``eps`` and ``centred`` are the family's RMS norm's
+    (:func:`_rms_norm`), here over a head's channels (``q_norm``, ``k_norm``);
+    ``rotary_dim`` of them are rotated, none where it
     is None (a layer that sees no positions).  ``window``:
     :func:`causal_attention`'s.  A gate's sigmoid scales each head's output
     before ``w_o``: ``gated``: ``w_q`` holds per head the query, then the gate
     (the Qwen3-Next family); else a matrix of the gate's own where the weights
-    hold one (``w_gate`` [h, heads x D]), else none."""
+    hold one (``w_gate`` [h, heads x D]), else none.
+
+    Between the projections and the kernels the query and the key are normed,
+    turned, the query scaled, and all three laid out heads first: by the
+    operand kernels in one pass where :func:`_operand_tiles` takes the shape
+    (a head of whole lane tiles, positions over all of it or none), else by
+    :func:`_xla_operands`."""
     dtype = x.dtype
     f32 = jnp.float32
     b, t, _ = x.shape
@@ -517,17 +752,14 @@ def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_
         gate = (x @ p["w_gate"].astype(dtype)).reshape(b, t, heads, d)
     k = (x @ p["w_k"].astype(dtype)).reshape(b, t, kv_heads, d)
     v = (x @ p["w_v"].astype(dtype)).reshape(b, t, kv_heads, d)
-    positions = jnp.arange(t)
-
-    def turned(a):
-        return a if rotary_dim is None else _rotary(a, positions, rotary_dim, theta)
-
-    q = turned(norm(q, p["q_norm"]))
-    k = turned(norm(k, p["k_norm"]))
-    q = (q * d**-0.5).astype(dtype)
-    # [B, T, heads, D] → [B, kv, heads // kv, T, D]: each key-value head serves a group
-    q = q.reshape(b, t, kv_heads, heads // kv_heads, d).transpose(0, 2, 3, 1, 4)
-    k, v = (a.transpose(0, 2, 1, 3) for a in (k.astype(dtype), v))
+    recipe = dict(eps=eps, centred=centred, rotary_dim=rotary_dim, theta=theta)
+    bt = _operand_tiles(t, heads, kv_heads, d, rotary_dim)
+    if hasattr(_traced_tiles, "operands"):  # :func:`mixer_counts` is tracing the caller
+        _traced_tiles.operands.append((b, bt is not None))
+    if bt is None:
+        q, k, v = _xla_operands(q, k, v, p["q_norm"], p["k_norm"], **recipe)
+    else:
+        q, k, v = _kernel_operands(q, k, v, p["q_norm"], p["k_norm"], bt, **recipe)
     o = causal_attention(q, k, v, window)
     o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
     if gate is not None:
@@ -668,12 +900,11 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
     return x, counts
 
 
-def layer_key_tiles(cfg, kind: str, x, p) -> dict:
-    """:func:`mixer_key_tiles` of one layer's mixer (its weights ``p``) over
-    the batch ``x`` [B, T, h], as the two counts a loss returns."""
+def layer_attention_counts(cfg, kind: str, x, p) -> dict:
+    """:func:`mixer_counts` of one layer's mixer (its weights ``p``) over
+    the batch ``x`` [B, T, h], as the counts a loss returns."""
     row = jax.ShapeDtypeStruct((1, *x.shape[1:]), x.dtype)
-    run, causal = (x.shape[0] * n for n in mixer_key_tiles(cfg.mixer(kind)[0], row, p))
-    return {"attn_tiles_run": run, "attn_tiles_causal": causal}
+    return {key: x.shape[0] * n for key, n in mixer_counts(cfg.mixer(kind)[0], row, p).items()}
 
 
 def _sum_counts(totals: dict | None, counts: dict | None) -> dict:
@@ -687,8 +918,9 @@ def _sum_counts(totals: dict | None, counts: dict | None) -> dict:
 def lm_hidden(params, ids, *, cfg, batch_sharding=None):
     """ids [B, T] → (final hidden states [B, T, h] before the final norm,
     counts: the expert layers' summed over the routed layers, and the
-    attention kernels' grid steps over every layer, :func:`layer_key_tiles`,
-    traced once a kind: Python integers).  The embedding's output is
+    attention kernels' grid steps and the rows by what made their operands
+    over every layer, :func:`layer_attention_counts`, traced once a kind:
+    Python integers).  The embedding's output is
     multiplied by ``cfg.embed_scale`` where the configuration has one."""
     with jax.named_scope(EMBED_SCOPE):
         x = params["embed"][ids]
@@ -700,7 +932,7 @@ def lm_hidden(params, ids, *, cfg, batch_sharding=None):
     totals, tiles, of_kind = None, None, {}
     for lp, held, kind, ffn in zip(params["layers"], buffers, kinds, ffns, strict=True):
         if kind not in of_kind:  # a kind's layers have one shape
-            of_kind[kind] = layer_key_tiles(cfg, kind, x, lp[kind])
+            of_kind[kind] = layer_attention_counts(cfg, kind, x, lp[kind])
         tiles = _sum_counts(tiles, of_kind[kind])
         x, counts = lm_layer(x, lp, held, kind=kind, ffn=ffn, cfg=cfg, batch_sharding=batch_sharding)
         if counts is not None:
@@ -773,7 +1005,7 @@ def mtp_loss(params, x, labels, *, cfg, batch_sharding=None):
         after_next = jnp.concatenate([labels[:, 1:], jnp.full_like(labels[:, :1], -100)], axis=1)
         h, counts = mtp_hidden(params, x, labels, cfg=cfg, batch_sharding=batch_sharding)
         kind = cfg.layer_kinds()[-1]
-        counts = _sum_counts(counts, layer_key_tiles(cfg, kind, x, params["mtp"]["layer"][kind]))
+        counts = _sum_counts(counts, layer_attention_counts(cfg, kind, x, params["mtp"]["layer"][kind]))
         loss, _ = labelled_nll(
             functools.partial(lm_head, cfg=cfg), mtp_head_params(params), h, after_next, batch_sharding
         )
@@ -788,10 +1020,12 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     module's among them), ``tokens``, and the positions with a label,
     ``head_all`` over both losses and ``head_mtp`` the module's (0 without
     one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32; and
-    two Python integers, known when the step is traced and no operation of it:
-    ``attn_tiles_run`` and ``attn_tiles_causal`` (the attention kernels' grid
-    steps and what causal lists alone would hold, over rows, layers and
-    key-value heads: equal without a window)."""
+    four Python integers, known when the step is traced and no operation of it
+    (:func:`mixer_counts`): ``attn_tiles_run`` and ``attn_tiles_causal`` (the
+    attention kernels' grid steps and what causal lists alone would hold, over
+    rows, layers and key-value heads: equal without a window),
+    ``attn_operands_kernel`` and ``attn_operands_xla`` (softmax-attention
+    layer-rows by what made the kernels' operands)."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
     with jax.named_scope(HEAD_SCOPE):  # the loss's own loop over tiles of positions around the head
         loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)

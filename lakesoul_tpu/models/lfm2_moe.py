@@ -184,5 +184,5 @@ def attention(x, p, *, cfg: Lfm2MoeConfig):
     """The grouped-query attention mixer: x [B, T, h] (normed) → [B, T, h]."""
     return softmax_attention(
         x, p, heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-        rotary_dim=cfg.head_dim, theta=cfg.rope_theta, norm=cfg.norm, gated=False,
+        rotary_dim=cfg.head_dim, theta=cfg.rope_theta, eps=cfg.norm_eps, centred=False, gated=False,
     )

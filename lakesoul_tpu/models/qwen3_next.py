@@ -690,5 +690,5 @@ def gated_attention(x, p, *, cfg: Qwen3NextConfig):
     return softmax_attention(
         x, p, heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
         rotary_dim=int(cfg.head_dim * cfg.partial_rotary_factor), theta=cfg.rope_theta,
-        norm=cfg.norm, gated=True,
+        eps=cfg.rms_norm_eps, centred=True, gated=True,
     )

@@ -91,6 +91,7 @@ TOKENS_FAMILY = "lakesoul_train_tokens_total"
 MOE_ASSIGNMENTS_FAMILY = "lakesoul_train_moe_assignments_total"
 MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
 ATTN_KEY_TILES_FAMILY = "lakesoul_train_attn_key_tiles_total"
+ATTN_OPERAND_ROWS_FAMILY = "lakesoul_train_attn_operand_rows_total"
 
 # live steps, and what the collected ones had counted: the families are
 # counters and must not fall when a step is dropped
@@ -353,7 +354,12 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     alone would hold: equal for a family without a window, 0 where no shape
     takes the kernels; Python integers off ``models/causal_lm.py:
     key_tile_steps`` when the step is traced, added on the host a call: they
-    are no operation of the step)."""
+    are no operation of the step) and, by the same route,
+    ``lakesoul_train_attn_operand_rows_total{path="kernel"|"xla"}`` (the
+    step's softmax-attention layer-rows by what made the attention kernels'
+    operands: the operand kernels in one pass where ``models/causal_lm.py:
+    _operand_tiles`` takes the mixer's shape, else the ``jnp`` lines; both 0
+    for a family whose mixers are not ``softmax_attention``)."""
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
@@ -370,10 +376,13 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / cfg.experts_held[1]),
         ("attn_tiles_run", ATTN_KEY_TILES_FAMILY, {"kind": "run"}, 1),
         ("attn_tiles_causal", ATTN_KEY_TILES_FAMILY, {"kind": "causal"}, 1),
+        ("attn_operands_kernel", ATTN_OPERAND_ROWS_FAMILY, {"path": "kernel"}, 1),
+        ("attn_operands_xla", ATTN_OPERAND_ROWS_FAMILY, {"path": "xla"}, 1),
     )
     return _CountedStep(
         _adamw_step(loss_fn, tx), param_shardings, (batch_sharding, batch_sharding),
-        NamedSharding(plan.mesh, P()), series, host_keys=("attn_tiles_run", "attn_tiles_causal"),
+        NamedSharding(plan.mesh, P()), series,
+        host_keys=("attn_tiles_run", "attn_tiles_causal", "attn_operands_kernel", "attn_operands_xla"),
     )
 
 

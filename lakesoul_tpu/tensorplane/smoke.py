@@ -411,6 +411,60 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     return detail
 
 
+def _run_attention_operands(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.models.causal_lm import (
+        _operand_tiles,
+        _operands_backward,
+        _operands_forward,
+        _turn_tables,
+        _xla_operands,
+    )
+
+    # a Trinity-Mini row's two kinds of mixer at deployed sizes (8,192 tokens, 32 query heads over 4 key-value
+    # heads at head 128; 8 over 2 on 256 tokens at tiny ones), with positions over the whole head and without:
+    # the flash kernels' operands and every gradient (raw q, k, v, both norm weights) against the ``jnp`` lines
+    # the kernels stand for.  Both sides compute in float32 and round once, so the operands agree to one
+    # bfloat16 unit element by element (a sum in another order moves a float32 result by its last bits, and
+    # now and then across a rounding boundary); the gradients by norm
+    t = min(8192, max(256, sizes.rows // 128))
+    heads, kv, d = (32, 4, 128) if t == 8192 else (8, 2, 128)
+    eps, theta = 1e-5, 10000.0
+    keys = jax.random.split(jax.random.key(43), 8)
+    raw = [jax.random.normal(key, (1, t, n, d)).astype(jnp.bfloat16) for key, n in zip(keys, (heads, kv, kv))]
+    wq, wk = (1.0 + 0.1 * jax.random.normal(key, (d,)) for key in keys[3:5])
+    detail = {"tokens": t, "heads": [heads, kv, d]}
+    for rotary_dim in (d, None):
+        bt = _operand_tiles(t, heads, kv, d, rotary_dim)
+        want, pull = jax.vjp(
+            jax.jit(lambda *a: _xla_operands(*a, eps=eps, centred=False, rotary_dim=rotary_dim, theta=theta)), *raw, wq, wk  # noqa: B023
+        )
+        cots = [jax.random.normal(key, a.shape).astype(a.dtype) for key, a in zip(keys[5:], want)]
+        turn = None if rotary_dim is None else _turn_tables(t, d, theta)
+        flat = [a.reshape(1, t, -1) for a in raw]
+        got = _operands_forward(*flat, wq, wk, turn, eps=eps, bt=bt, interpret=interpret)
+        grads = _operands_backward(*cots, *flat[:2], wq, wk, turn, eps=eps, bt=bt, interpret=interpret)
+        units, errors = [], []
+        for a, b in zip(got, want, strict=True):
+            a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernel's operands against the jnp lines'
+            units.append(float(np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-30)) * 2**7))
+            errors.append(float(np.mean(a != b)))
+        if not (max(units) <= 1.0 and max(errors) < 1e-2):
+            raise AssertionError(f"attention operands, rotary {rotary_dim}: q, k, v off by {units} units, {errors} of the elements")
+        for a, b in zip(grads, pull(tuple(cots)), strict=True):
+            a, b = (np.asarray(x.astype(jnp.float32)).reshape(b.shape) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernel's gradients against the jnp lines'
+            errors.append(float(np.linalg.norm(a - b) / np.linalg.norm(b)))
+        if not max(errors[3:]) < 1e-3:
+            raise AssertionError(f"attention operands, rotary {rotary_dim}: dq, dk, dv, dw_q, dw_k off by {errors[3:]}")
+        detail["turned" if rotary_dim else "plain"] = {
+            "block": bt, "units": [round(u, 3) for u in units], "differ": [round(e, 6) for e in errors[:3]],
+            "rel_err": [float(f"{e:.3g}") for e in errors[3:]],
+        }
+    return detail
+
+
 # --------------------------------------------------------------- multichip
 
 
@@ -564,6 +618,13 @@ def smoke_cases() -> list[SmokeCase]:
             kernels=(
                 "lakesoul_tpu/models/causal_lm.py::_flash_fwd_kernel",
                 "lakesoul_tpu/models/causal_lm.py::_flash_bwd_kernel",
+            ),
+        ),
+        SmokeCase(
+            "models.attention_operands", "pallas", _run_attention_operands,
+            kernels=(
+                "lakesoul_tpu/models/causal_lm.py::_operands_fwd_kernel",
+                "lakesoul_tpu/models/causal_lm.py::_operands_bwd_kernel",
             ),
         ),
         SmokeCase(

@@ -383,7 +383,8 @@ def test_a_layer_norms_each_sublayers_output_before_the_residual_add(params, lay
     got, counts = run(x, lp, buffers)
     assert_close(got, ref.layer(x, lp, buffers, MODEL["layer_types"][layer], MODEL, HELD))
     assert (counts is not None) == (ffn == "moe")
-    assert causal_lm.layer_key_tiles(CFG, kind, x, lp[kind]) == {"attn_tiles_run": 0, "attn_tiles_causal": 0}  # no kernel
+    assert causal_lm.layer_attention_counts(CFG, kind, x, lp[kind]) == {  # 150 tokens: neither kernel pair takes the row
+        "attn_tiles_run": 0, "attn_tiles_causal": 0, "attn_operands_kernel": 0, "attn_operands_xla": x.shape[0]}
     for name in ("norm1_out", "norm2_out"):
         scaled = lp | {name: 2 * lp[name]}
         doubled, _ = run(x, scaled, buffers)

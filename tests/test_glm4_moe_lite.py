@@ -555,7 +555,8 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
     assert once.counts() == {"tokens": B * T, "moe_all": 3 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
                              "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
                              "head_mtp": second, "head_all": main + second,
-                             "attn_tiles_run": 0, "attn_tiles_causal": 0}  # 150 tokens: the kernels list no tile
+                             "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
+                             "attn_operands_kernel": 0, "attn_operands_xla": 0}  # latent attention makes its own operands
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]
     steps = 2 * STEPS + 1

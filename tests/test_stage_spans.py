@@ -829,12 +829,13 @@ AFMOE_SCOPES = {
 def _afmoe_cfg(**changed):
     from lakesoul_tpu.models.afmoe import AfmoeConfig
 
-    # the held cut's pattern (a dense window layer, then window, full, window, window), four heads of 64 on one
-    # key-value head: every mixer takes its kernels at 128 tokens, the window layers under a window of 64
+    # the held cut's pattern (a dense window layer, then window, full, window, window), four heads of 128 on one
+    # key-value head: every mixer takes its kernels (the flash pair and the operand pair) at 128 tokens, the
+    # window layers under a window of 64
     sizes = dict(
         vocab_size=64, hidden_size=256, num_hidden_layers=5, num_dense_layers=1, intermediate_size=48,
         layer_types=("sliding_attention", "sliding_attention", "full_attention", "sliding_attention", "sliding_attention"),
-        num_attention_heads=4, num_key_value_heads=1, head_dim=64, sliding_window=64, num_experts=8,
+        num_attention_heads=4, num_key_value_heads=1, head_dim=128, sliding_window=64, num_experts=8,
         num_experts_per_tok=2, moe_intermediate_size=16, experts_held=(0, 4),
     )
     return AfmoeConfig(**(sizes | changed))
@@ -860,6 +861,16 @@ def test_afmoe_step_holds_the_kernels_under_swa_and_attn_and_every_scope_a_reade
     for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
         charged = sorted(scope_of.get(name) for name in calls if name.startswith(kernel))
         assert charged == ["lakesoul.lm.attn"] + ["lakesoul.lm.swa"] * 4, charged
+    # the operand kernels at a head of 128 (``attn_operands_fwd.<n>``, ``attn_operands_bwd.<n>``): the forward
+    # one twice a layer (forward, and the row's rematerialisation), the backward one once, under the mixer's
+    # scope; no name of theirs holds ``flash_attention_``, which is what the two roofline readers search for
+    operands = [name for name in _kernel_calls(text) if name.startswith("attn_operands")]
+    assert sorted(name.rsplit(".", 1)[0] for name in operands) == ["attn_operands_bwd"] * 5 + ["attn_operands_fwd"] * 10
+    charged = sorted(scope_of.get(name) for name in operands if name.startswith("attn_operands_bwd"))
+    assert charged == ["lakesoul.lm.attn"] + ["lakesoul.lm.swa"] * 4, charged
+    assert sorted(_kernel_calls(text)) == sorted(
+        calls + operands + [n for n in _kernel_calls(text) if n.split("_")[0] in ("take", "put", "expert")]
+    )
     shared = set(LM_SCOPES) - {"lakesoul.lm.gdn"}
     assert set(scope_of.values()) == shared | set(AFMOE_SCOPES) | {"lakesoul.lm.mlp"}
     assert len(set(LM_SCOPES) | set(LFM2_SCOPES) | set(GLM_SCOPES) | set(AFMOE_SCOPES)) == 13
@@ -911,8 +922,9 @@ def test_attn_key_tiles_counter_is_the_tile_tables(monkeypatch):
     )
     weights = jax.eval_shape(whole.init, jax.random.key(0))["layers"]
     rows = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16)
-    found = {kind: causal_lm.mixer_key_tiles(whole.mixer(kind)[0], rows, weights[layer][kind])
-             for kind, layer in (("swa", 0), ("attn", 2))}
+    counts = {kind: causal_lm.mixer_counts(whole.mixer(kind)[0], rows, weights[layer][kind])
+              for kind, layer in (("swa", 0), ("attn", 2))}
+    found = {kind: (n["attn_tiles_run"], n["attn_tiles_causal"]) for kind, n in counts.items()}
     assert found == {"swa": (2 * 4 * 280, 2 * 4 * 544), "attn": (2 * 4 * 544, 2 * 4 * 544)}
     run, causal = (4 * found["swa"][i] + found["attn"][i] for i in (0, 1))
     assert round(100 * run / causal, 1) == 61.2
@@ -934,6 +946,63 @@ def test_attn_key_tiles_counter_is_the_tile_tables(monkeypatch):
     assert {kind: series(kind) - before[kind] for kind in before} == {"run": 44, "causal": 48}
     with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "attn_tiles_run_pct.py")) as f:
         assert f'COUNTER = "{ATTN_KEY_TILES_FAMILY}"' in f.read()
+
+
+@pytest.mark.parametrize("check", ["share_of_hand_counts", "nothing_without_the_series"])
+def test_attn_operand_rows_reader(check):
+    """``attn_operands_fused_pct`` through its own self-test, and the series
+    it reads under the name the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import ATTN_OPERAND_ROWS_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_operand_rows", os.path.join(REPO, "benchmarks", "chip", "selftest", "operand_rows.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_share_of_hand_counts", "test_nothing_without_the_series"]
+    getattr(selftest, "test_" + check)()
+    assert selftest.FAMILY == ATTN_OPERAND_ROWS_FAMILY
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "attn_operands_fused_pct.py")) as f:
+        assert f'COUNTER = "{ATTN_OPERAND_ROWS_FAMILY}"' in f.read()
+
+
+def test_attn_operand_rows_counter_is_the_rule(monkeypatch):
+    """``lakesoul_train_attn_operand_rows_total{path="kernel"|"xla"}`` from a
+    step: two rows through a window layer and a full one at a head of 128 (the
+    operand kernels, in the interpreter), then the same stack at a head of 64
+    (the ``jnp`` lines): the step's softmax-attention layer-rows by the path
+    :func:`_operand_tiles` picks, host integers like the tile counts.  And the
+    host count at the three cells' shapes that list the metric."""
+    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models.train import ATTN_OPERAND_ROWS_FAMILY, make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.obs import registry
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    def series():
+        found = registry().snapshot()
+        return {path: found.get(f'{ATTN_OPERAND_ROWS_FAMILY}{{path="{path}"}}', 0) for path in ("kernel", "xla")}
+
+    # Trinity-Mini (both kinds of layer), Qwen3-Next (64 of 256 channels turned), LFM2 (a head of 64)
+    assert causal_lm._operand_tiles(8192, 32, 4, 128, 128) == causal_lm._operand_tiles(8192, 32, 4, 128, None) == 512
+    assert causal_lm._operand_tiles(8192, 16, 2, 256, 64) is None and causal_lm._operand_tiles(8192, 32, 8, 64, 64) is None
+    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
+    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 256)
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    ids = jnp.zeros((2, 384), jnp.int32)
+    for head, want in ((128, {"kernel": 4, "xla": 0}), (64, {"kernel": 0, "xla": 4})):
+        cfg = _afmoe_cfg(
+            hidden_size=64, num_hidden_layers=2, layer_types=("sliding_attention", "full_attention"),
+            num_attention_heads=4, num_key_value_heads=2, head_dim=head, sliding_window=100,
+        )
+        params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+        step = make_lm_train_step(cfg, plan, tx, shardings)
+        before = series()
+        step(params, opt_state, ids, ids)
+        counts = step.counts()
+        assert {path: counts["attn_operands_" + path] for path in want} == want
+        assert {path: n - before[path] for path, n in series().items()} == want
 
 
 AFMOE_READER_CHECKS = [
@@ -1005,7 +1074,9 @@ UNSCOPED = {
     "qwen3_next_clm": ("lm_step_compiled_for_a_v5e", 14),
     "lfm2_moe_clm": ("lfm2_step_compiled_for_a_v5e", 4),
     "glm4_moe_lite_clm": ("glm_step_compiled_for_a_v5e", 0),
-    "afmoe_clm": ("afmoe_step_compiled_for_a_v5e", 7),
+    # the operand kernels read the positions as two whole-head tables, ``[cos | cos]`` and ``[-sin | sin]``: a
+    # negation and two concatenations more than the two angle constants the ``jnp`` lines had (7)
+    "afmoe_clm": ("afmoe_step_compiled_for_a_v5e", 10),
 }
 
 

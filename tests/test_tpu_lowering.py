@@ -161,6 +161,28 @@ def _flash_backward(d, window=None):
     ).trace(q, k, k, q, lse, q)
 
 
+def _operand_row(turned=True):
+    # a Trinity-Mini row's mixer (d is a vector width, not a shape of these kernels): the raw q, k, v as the
+    # products leave them, the norm weights, the position tables of a window layer (none on the full layer)
+    hkv, groups, d = ATTENTION_ROWS["trinity-mini"]
+    t, bf16 = 8192, jnp.bfloat16
+    raw = [_sds((1, t, n * d), bf16) for n in (hkv * groups, hkv, hkv)]
+    laid = [_sds((1, hkv, groups, t, d), bf16), _sds((1, hkv, t, d), bf16), _sds((1, hkv, t, d), bf16)]
+    turn = (_sds((t, d)), _sds((t, d))) if turned else None
+    recipe = dict(eps=1e-5, bt=causal_lm._operand_tiles(t, hkv * groups, hkv, d, d if turned else None), interpret=False)
+    return raw, laid, [_sds((d,)), _sds((d,)), turn], recipe
+
+
+def _operands_forward(d, turned=True):
+    raw, _, rest, recipe = _operand_row(turned)
+    return jax.jit(lambda *a: causal_lm._operands_forward(*a, **recipe)).trace(*raw, *rest)
+
+
+def _operands_backward(d, turned=True):
+    raw, laid, rest, recipe = _operand_row(turned)
+    return jax.jit(lambda *a: causal_lm._operands_backward(*a, **recipe)).trace(*laid, *raw[:2], *rest)
+
+
 # keyed by lakelint device-index qname, like the smoke register
 TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_packed_scan_kernel": _packed_scan,
@@ -170,6 +192,8 @@ TRACERS = {
     "lakesoul_tpu/annplane/ragged.py::_ragged_score_kernel": _ragged_score,
     "lakesoul_tpu/models/causal_lm.py::_flash_fwd_kernel": _flash_forward,
     "lakesoul_tpu/models/causal_lm.py::_flash_bwd_kernel": _flash_backward,
+    "lakesoul_tpu/models/causal_lm.py::_operands_fwd_kernel": _operands_forward,
+    "lakesoul_tpu/models/causal_lm.py::_operands_bwd_kernel": _operands_backward,
     "lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel": _unit_lower_inverse,
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_fwd_kernel": _gated_delta_forward,
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_bwd_kernel": _gated_delta_backward,
@@ -218,3 +242,17 @@ def test_attention_kernels_lower_with_and_without_the_window(kernel, window):
     assert "tpu_custom_call" in text
     steps = causal_lm.key_tile_steps(8192, 8, 128, window)[0]
     assert steps == (280 if window else 544) and f"tensor<{steps}xi32>" in text  # the tables the call prefetches
+
+
+@pytest.mark.parametrize("turned", [True, False], ids=["window-turned", "full-unturned"])
+@pytest.mark.parametrize("kernel", ["_operands_fwd_kernel", "_operands_bwd_kernel"])
+def test_operand_kernels_lower_with_and_without_positions(kernel, turned):
+    """A Trinity-Mini row's two kinds of layer, 32 heads over 4 key-value
+    heads at head 128: blocks of 512 tokens of a key-value head's group, a
+    grid of 16 token blocks by 4 heads; the window layer's call reads the two
+    position tables, the full layer's has none."""
+    assert causal_lm._operand_tiles(8192, 32, 4, 128, 128 if turned else None) == 512
+    traced = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](128, turned)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    assert ("tensor<8192x128xf32>" in text) == turned  # the tables
