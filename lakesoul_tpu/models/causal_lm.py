@@ -2,8 +2,8 @@
 and the pieces more than one family's mixers are made of.
 
 A family is a module with a configuration object (``models/qwen3_next.py``,
-``models/lfm2_moe.py``, ``models/glm4_moe_lite.py``, ``models/afmoe.py``);
-nothing here or in
+``models/lfm2_moe.py``, ``models/glm4_moe_lite.py``, ``models/afmoe.py``,
+``models/ouro.py``); nothing here or in
 ``models/train.py`` names one.  The stack reads a layer's kinds from the
 configuration and asks it for the rest:
 
@@ -22,7 +22,23 @@ configuration and asks it for the rest:
   ``init(key)`` and ``loss(params, ids, labels, batch_sharding=)``;
 - ``mtp_loss_weight``, read only where the weights hold a prediction module;
   ``embed_scale``, where the family has one: what the embedding's output is
-  multiplied by.
+  multiplied by;
+- ``loop_passes``, where the family has it: how many times the stack runs
+  over its ONE set of weights (a looped model; :func:`loop_hidden`), with
+  ``exit_beta``, the weight of the entropy term in its objective
+  (:func:`exit_loss`); ``models/train.py`` reads ``loop_passes`` too, for the
+  series a looped step feeds.  A family without it is walked once, by the
+  plain Python loop (:func:`lm_hidden`), and its step's program does not
+  change by what a looped family needs.
+
+A looped family's step (:func:`loop_loss`): the walk through the layers
+``loop_passes`` times as one ``lax.scan`` whose body ends in the final norm
+(the normed state is what the next pass starts from and what the head reads),
+the passes' states stacked; then ONE call of the head and the loss's tile loop
+over the stacked states with a weight a position (``models/bert.py:
+labelled_nll``'s weighted form: the exit distribution), the exit gate and the
+expected loss under :data:`EXIT_SCOPE`.  ``params["exit"]`` holds the gate
+(``w`` [h], ``b``): ordinary trained leaves.
 
 The loss (:func:`lm_loss`) is the next-token cross-entropy, and where the
 weights hold a multi-token-prediction module (``params["mtp"]``) that module's
@@ -46,8 +62,9 @@ Softmax attention (:func:`causal_attention`, every family's) is two Pallas
 kernels under one ``custom_vjp``, compiled on a TPU and in the Pallas
 interpreter elsewhere, for the head sizes and row lengths :func:`_flash_tiles`
 takes (a head of 64, 128 or 256 channels, a row of whole 128-key tiles: the
-four published models at 8,192 tokens, groups of 4, 8, 1 and 8 query heads a
-key-value head); any other shape runs the blockwise ``jnp``
+five published models at 8,192 tokens: groups of 4 at head 64, of 8 at 256, of
+1 at 256 on 20 key-value heads, of 8 at 128, and of 1 at 128 on 16 key-value
+heads); any other shape runs the blockwise ``jnp``
 path, the kernels' twin.  It has two masks: causal, and under a ``window`` the
 band of a query's own position and the ``window - 1`` before it; the kernels'
 grid is the list of (query tile, key tile) pairs either mask lets anything
@@ -57,6 +74,11 @@ step's counters.  Of the scores nothing leaves VMEM in either pass;
 the backward pass keeps the output and the log-sum-exp, which
 :func:`_row_by_row`'s checkpoint holds on to by name (:data:`ATTN_KEPT`), so a
 step runs the forward kernel once a row and the backward kernel once.
+Between its projections and the kernels :func:`softmax_attention` norms each
+head, turns it by its position, scales the query and lays heads before
+tokens, by the operand kernels where :func:`_operand_tiles` takes the shape;
+where the mixer's weights hold no ``q_norm`` and no ``k_norm`` (plain
+attention) neither path norms, a static flag of the kernels as ``turned`` is.
 
 The model may be one chip's share of an expert-parallel job: ``experts_held``
 says which of the ``num_experts`` live here (``parallel/moe.py: held_experts``)
@@ -87,6 +109,7 @@ MTP_SCOPE = "lakesoul.lm.mtp"  # the whole prediction module, its layer's and it
 MLP_SCOPE = "lakesoul.lm.mlp"
 HEAD_SCOPE = "lakesoul.lm.head"
 EMBED_SCOPE = "lakesoul.lm.embed"  # the token lookup and, through its transpose, the scatter-add of its gradient
+EXIT_SCOPE = "lakesoul.lm.exit"    # a looped model's exit gate, its distribution over the passes and the expected loss
 ATTN_KEPT = ("attn_out", "attn_lse")  # ``checkpoint_name``s of what the flash kernels' backward pass keeps
 ATTN_BAND = 1024   # blockwise: queries that share one static slice of the keys
 ATTN_ROWS = 128    # blockwise: queries whose scores live at once
@@ -98,6 +121,7 @@ FLASH_VMEM_BYTES = 96 * 2**20    # of a v5e's 128 MiB
 OPERAND_ELEMENTS = 512 * 1024    # tokens x a group's channels a block of the operand kernels holds at most: 1 MB
 OPERAND_VMEM_BYTES = 64 * 2**20
 MASKED = -1e30
+EXIT_MASS_UNIT = 1024  # :func:`exit_loss` counts the exit distribution's mass in this fraction of a position
 _NT = (((1,), (1,)), ((), ()))  # x y^T
 _TN = (((0,), (0,)), ((), ()))  # x^T y
 
@@ -473,49 +497,69 @@ def _operand_tiles(t: int, heads: int, kv_heads: int, d: int, rotary_dim: int | 
 
 def _head_operand(x, w, turn, eps: float):
     """x [n, D] float32, a head's raw channels a token → normed
-    (:func:`_rms_norm` by the weight as it multiplies) and turned: ``[x1 cos -
+    (:func:`_rms_norm` by the weight as it multiplies; not where ``w`` is
+    None: a mixer without head norms) and turned: ``[x1 cos -
     x2 sin | x2 cos + x1 sin]`` as ``y * [cos | cos] + roll(y) * [-sin | sin]``."""
-    y = _rms_norm(x, w, eps, centred=False)
+    y = x if w is None else _rms_norm(x, w, eps, centred=False)
     if turn is None:
         return y
     return y * turn[0] + pltpu.roll(y, y.shape[1] // 2, 1) * turn[1]
 
 
+def _turned_back(dz, turn):
+    """A turned head's cotangent [n, D] turned by the negative angle (as it is
+    where the layer sees no positions)."""
+    return dz if turn is None else dz * turn[0] - pltpu.roll(dz, dz.shape[1] // 2, 1) * turn[1]
+
+
 def _head_operand_grads(x, w, turn, dz, eps: float):
-    """:func:`_head_operand` transposed: the cotangent ``dz`` [n, D] → (the
-    raw channels', the weight's summed over every eighth token: [8, D])."""
+    """:func:`_head_operand` of a normed head transposed: the cotangent ``dz``
+    [n, D] → (the raw channels', the weight's summed over every eighth token:
+    [8, D])."""
     r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     y = x * r
-    if turn is not None:  # the turn by the negative angle
-        dz = dz * turn[0] - pltpu.roll(dz, dz.shape[1] // 2, 1) * turn[1]
+    dz = _turned_back(dz, turn)
     dy = dz * w
     dx = r * (dy - y * jnp.mean(dy * y, axis=-1, keepdims=True))
     return dx, jnp.sum((dz * y).reshape(-1, 8, x.shape[1]), axis=0)
 
 
-def _operands_fwd_kernel(*refs, groups: int, eps: float, turned: bool):
+def _operands_fwd_kernel(*refs, groups: int, eps: float, turned: bool, normed: bool = True):
     """One block: ``bt`` tokens of a key-value head's ``groups`` query heads
     [bt, G*D], its key and value [bt, D] → the query [G, bt, D] scaled, the
-    key and the value [bt, D]."""
-    q_ref, k_ref, v_ref, wq_ref, wk_ref, *turn, qo_ref, ko_ref, vo_ref = refs
+    key and the value [bt, D].  ``normed``: the two norm weights are among
+    the operands (after the value) and the heads are normed by them."""
+    q_ref, k_ref, v_ref, *given, qo_ref, ko_ref, vo_ref = refs
+    wq_ref, wk_ref = given[:2] if normed else (None, None)
     d = k_ref.shape[1]
     f32 = jnp.float32
-    turn = tuple(a[...] for a in turn) if turned else None
+    turn = tuple(a[...] for a in given[2 * normed:]) if turned else None
     for g in range(groups):
-        q = _head_operand(q_ref[:, g * d:(g + 1) * d].astype(f32), wq_ref[...], turn, eps)
+        q = _head_operand(q_ref[:, g * d:(g + 1) * d].astype(f32), wq_ref[...] if normed else None, turn, eps)
         qo_ref[g] = (q * d**-0.5).astype(qo_ref.dtype)
-    ko_ref[...] = _head_operand(k_ref[...].astype(f32), wk_ref[...], turn, eps).astype(ko_ref.dtype)
+    ko_ref[...] = _head_operand(k_ref[...].astype(f32), wk_ref[...] if normed else None, turn, eps).astype(ko_ref.dtype)
     vo_ref[...] = v_ref[...]
 
 
-def _operands_bwd_kernel(*refs, groups: int, eps: float, turned: bool):
+def _operands_bwd_kernel(*refs, groups: int, eps: float, turned: bool, normed: bool = True):
     """:func:`_operands_fwd_kernel` transposed, on its grid: the operands'
     cotangents and the raw query and key → the raw cotangents in the
     projections' layout and this block's share of the two norm weights'
-    gradients [8, D] float32."""
+    gradients [8, D] float32.  Not ``normed``: the cotangents alone in, the
+    raw cotangents alone out (turning back needs neither the raw query nor
+    the raw key)."""
+    f32 = jnp.float32
+    if not normed:
+        dqo_ref, dko_ref, dvo_ref, *turn, dq_ref, dk_ref, dv_ref = refs
+        d = dko_ref.shape[1]
+        turn = tuple(a[...] for a in turn) if turned else None
+        for g in range(groups):
+            dq_ref[:, g * d:(g + 1) * d] = _turned_back(dqo_ref[g].astype(f32) * d**-0.5, turn).astype(dq_ref.dtype)
+        dk_ref[...] = _turned_back(dko_ref[...].astype(f32), turn).astype(dk_ref.dtype)
+        dv_ref[...] = dvo_ref[...]
+        return
     dqo_ref, dko_ref, dvo_ref, q_ref, k_ref, wq_ref, wk_ref, *turn, dq_ref, dk_ref, dv_ref, dwq_ref, dwk_ref = refs
     d = k_ref.shape[1]
-    f32 = jnp.float32
     turn = tuple(a[...] for a in turn) if turned else None
     dwq = jnp.zeros((8, d), f32)
     for g in range(groups):
@@ -531,15 +575,15 @@ def _operands_bwd_kernel(*refs, groups: int, eps: float, turned: bool):
     dv_ref[...] = dvo_ref[...]
 
 
-def _operand_grid(q, k, d: int, bt: int, turned: bool, *, in_specs, out_specs):
+def _operand_grid(q, k, d: int, bt: int, turned: bool, normed: bool, *, in_specs, out_specs):
     """What the two kernels' ``pallas_call``s share, over the raw q [B, T,
     heads*D] and k [B, T, kv*D]: the grid (rows, token blocks, key-value
     heads: the heads innermost, so a block of the position tables is fetched
     once) and block specs by what a block follows: the ``raw`` query's [bt,
     G*D] and key's or value's ``raw_kv`` [bt, D], the flash kernels' ``laid``
     [G, bt, D] and ``laid_kv`` [bt, D], a weight gradient's ``share`` [8, D];
-    after ``in_specs`` come the two norm weights [1, D] and, where the layer
-    turns, the two position tables' [bt, D].  Returns (key-value heads, query
+    after ``in_specs`` come, where the heads are ``normed``, the two norm
+    weights [1, D] and, where the layer turns, the two position tables' [bt, D].  Returns (key-value heads, query
     heads each serves, the call's keyword arguments)."""
     b, t, width = k.shape
     kv, groups = width // d, q.shape[2] // width
@@ -554,7 +598,7 @@ def _operand_grid(q, k, d: int, bt: int, turned: bool, *, in_specs, out_specs):
     table = pl.BlockSpec((bt, d), lambda r, i, h: (i, 0))
     return kv, groups, dict(
         grid=(b, t // bt, kv),
-        in_specs=[specs[s] for s in in_specs] + [weight] * 2 + [table] * (2 * turned),
+        in_specs=[specs[s] for s in in_specs] + [weight] * (2 * normed) + [table] * (2 * turned),
         out_specs=[specs[s] for s in out_specs],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3, vmem_limit_bytes=OPERAND_VMEM_BYTES
@@ -562,59 +606,66 @@ def _operand_grid(q, k, d: int, bt: int, turned: bool, *, in_specs, out_specs):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "bt", "interpret"))
-def _operands_forward(q, k, v, wq, wk, turn, *, eps: float, bt: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("d", "eps", "bt", "interpret"))
+def _operands_forward(q, k, v, wq, wk, turn, *, d: int, eps: float, bt: int, interpret: bool):
     """The raw q [B, T, heads*D], k, v [B, T, kv*D] as the products leave
-    them, the norm weights [D] as they multiply, ``turn`` None or the position
+    them, the norm weights [D] as they multiply (both None: heads that are not
+    normed), ``turn`` None or the position
     tables ``([cos | cos], [-sin | sin])`` [T, D] float32 → the flash kernels'
     q [B, kv, G, T, D] (scaled), k, v [B, kv, T, D]."""
     b, t, _ = q.shape
-    d = wq.shape[0]
+    normed = wq is not None
     kv, groups, grid = _operand_grid(
-        q, k, d, bt, turn is not None, in_specs=("raw", "raw_kv", "raw_kv"), out_specs=("laid", "laid_kv", "laid_kv")
+        q, k, d, bt, turn is not None, normed, in_specs=("raw", "raw_kv", "raw_kv"),
+        out_specs=("laid", "laid_kv", "laid_kv"),
     )
     return pl.pallas_call(
-        functools.partial(_operands_fwd_kernel, groups=groups, eps=eps, turned=turn is not None),
+        functools.partial(_operands_fwd_kernel, groups=groups, eps=eps, turned=turn is not None, normed=normed),
         out_shape=(jax.ShapeDtypeStruct((b, kv, groups, t, d), q.dtype),
                    *(jax.ShapeDtypeStruct((b, kv, t, d), a.dtype) for a in (k, v))),
         name="attn_operands_fwd", interpret=interpret, **grid,
-    )(q, k, v, wq[None], wk[None], *(turn or ()))
+    )(q, k, v, *((wq[None], wk[None]) if normed else ()), *(turn or ()))
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "bt", "interpret"))
-def _operands_backward(dq, dk, dv, q, k, wq, wk, turn, *, eps: float, bt: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("d", "eps", "bt", "interpret"))
+def _operands_backward(dq, dk, dv, q, k, wq, wk, turn, *, d: int, eps: float, bt: int, interpret: bool):
     """The cotangents of :func:`_operands_forward`'s results, and its raw q
     and k again → the cotangents of the raw q, k, v and of the two norm
-    weights (float32, summed here over the blocks' shares)."""
+    weights (float32, summed here over the blocks' shares; None where the
+    heads are not normed: the raw q and k then give their shapes alone)."""
     b, t, _ = q.shape
-    d = wq.shape[0]
+    normed = wq is not None
     kv, groups, grid = _operand_grid(
-        q, k, d, bt, turn is not None, in_specs=("laid", "laid_kv", "laid_kv", "raw", "raw_kv"),
-        out_specs=("raw", "raw_kv", "raw_kv", "share", "share"),
+        q, k, d, bt, turn is not None, normed,
+        in_specs=("laid", "laid_kv", "laid_kv", *(("raw", "raw_kv") if normed else ())),
+        out_specs=("raw", "raw_kv", "raw_kv", *(("share", "share") if normed else ())),
     )
     share = jax.ShapeDtypeStruct((b, t // bt, kv, 8, d), jnp.float32)
-    dq, dk, dv, dwq, dwk = pl.pallas_call(
-        functools.partial(_operands_bwd_kernel, groups=groups, eps=eps, turned=turn is not None),
+    dq, dk, dv, *shares = pl.pallas_call(
+        functools.partial(_operands_bwd_kernel, groups=groups, eps=eps, turned=turn is not None, normed=normed),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(k.shape, dv.dtype), share, share),
+                   jax.ShapeDtypeStruct(k.shape, dv.dtype), *([share, share] if normed else [])),
         name="attn_operands_bwd", interpret=interpret, **grid,
-    )(dq, dk, dv, q, k, wq[None], wk[None], *(turn or ()))
+    )(dq, dk, dv, *((q, k, wq[None], wk[None]) if normed else ()), *(turn or ()))
+    if not normed:
+        return dq, dk, dv, None, None
+    dwq, dwk = shares
     return dq, dk, dv, dwq.sum((0, 1, 2, 3)).astype(wq.dtype), dwk.sum((0, 1, 2, 3)).astype(wk.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _attention_operands(q, k, v, wq, wk, turn, eps, bt):
-    return _operands_forward(q, k, v, wq, wk, turn, eps=eps, bt=bt, interpret=not _on_tpu())
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _attention_operands(q, k, v, wq, wk, turn, d, eps, bt):
+    return _operands_forward(q, k, v, wq, wk, turn, d=d, eps=eps, bt=bt, interpret=not _on_tpu())
 
 
-def _attention_operands_fwd(q, k, v, wq, wk, turn, eps, bt):
+def _attention_operands_fwd(q, k, v, wq, wk, turn, d, eps, bt):
     # nothing new is kept: a checkpoint around the caller computes the raw q and k again, as it did
-    return _attention_operands(q, k, v, wq, wk, turn, eps, bt), (q, k, wq, wk, turn)
+    return _attention_operands(q, k, v, wq, wk, turn, d, eps, bt), (q, k, wq, wk, turn)
 
 
-def _attention_operands_bwd(eps, bt, kept, cotangents):
+def _attention_operands_bwd(d, eps, bt, kept, cotangents):
     q, k, wq, wk, turn = kept
-    grads = _operands_backward(*cotangents, q, k, wq, wk, turn, eps=eps, bt=bt, interpret=not _on_tpu())
+    grads = _operands_backward(*cotangents, q, k, wq, wk, turn, d=d, eps=eps, bt=bt, interpret=not _on_tpu())
     return *grads, jax.tree.map(jnp.zeros_like, turn)  # the tables come from positions alone
 
 
@@ -623,7 +674,8 @@ _attention_operands.defvjp(_attention_operands_fwd, _attention_operands_bwd)
 
 def _xla_operands(q, k, v, wq, wk, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):
     """The raw q [B, T, heads, D], k, v [B, T, kv, D] and the two head norms'
-    weights → the flash kernels' q [B, kv, G, T, D] (normed, turned over
+    weights (both None: a mixer whose heads are not normed, and ``eps`` and
+    ``centred`` are then not read) → the flash kernels' q [B, kv, G, T, D] (normed, turned over
     ``rotary_dim`` channels, scaled), k (normed, turned), v [B, kv, T, D], as
     whole-array ``jnp`` operations in float32: what a shape the operand
     kernels do not take runs, and the kernels' twin."""
@@ -635,8 +687,11 @@ def _xla_operands(q, k, v, wq, wk, *, eps: float, centred: bool, rotary_dim: int
     def turned(a):
         return a if rotary_dim is None else _rotary(a, positions, rotary_dim, theta)
 
-    q = turned(_rms_norm(q, wq, eps, centred=centred))
-    k = turned(_rms_norm(k, wk, eps, centred=centred))
+    def normed(a, w):
+        return a.astype(jnp.float32) if w is None else _rms_norm(a, w, eps, centred=centred)
+
+    q = turned(normed(q, wq))
+    k = turned(normed(k, wk))
     q = (q * d**-0.5).astype(dtype)
     # [B, T, heads, D] → [B, kv, heads // kv, T, D]: each key-value head serves a group
     q = q.reshape(b, t, kv_heads, heads // kv_heads, d).transpose(0, 2, 3, 1, 4)
@@ -655,12 +710,13 @@ def _turn_tables(t: int, d: int, theta: float):
 def _kernel_operands(q, k, v, wq, wk, bt: int, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):
     """:func:`_xla_operands` by the operand kernels, ``bt`` tokens a block
     (:func:`_operand_tiles`): the same arguments, the same results."""
-    b, t, _, _ = q.shape
+    b, t, _, d = q.shape
     turn = None if rotary_dim is None else _turn_tables(t, rotary_dim, theta)
-    wq, wk = ((1.0 + w) if centred else w for w in (wq, wk))
+    if wq is not None:
+        wq, wk = (((1.0 + w) if centred else w).astype(jnp.float32) for w in (wq, wk))
     # the reshapes undo the caller's: the kernels read the products' own [B, T, heads x D]
     q, k, v = (a.reshape(b, t, -1) for a in (q, k, v))
-    return _attention_operands(q, k, v, wq.astype(jnp.float32), wk.astype(jnp.float32), turn, eps, bt)
+    return _attention_operands(q, k, v, wq, wk, turn, d, eps, bt)
 
 
 _traced_tiles = threading.local()  # ``.steps``, ``.operands``: what :func:`mixer_counts` collects while it traces a mixer
@@ -727,7 +783,8 @@ def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_
                       theta: float, eps: float, centred: bool, gated: bool, window: int | None = None):
     """The grouped-query softmax-attention mixer: x [B, T, h] (normed) →
     [B, T, h].  ``eps`` and ``centred`` are the family's RMS norm's
-    (:func:`_rms_norm`), here over a head's channels (``q_norm``, ``k_norm``);
+    (:func:`_rms_norm`), here over a head's channels (``q_norm``, ``k_norm``;
+    where the weights hold neither, the heads are not normed: plain attention);
     ``rotary_dim`` of them are rotated, none where it
     is None (a layer that sees no positions).  ``window``:
     :func:`causal_attention`'s.  A gate's sigmoid scales each head's output
@@ -757,9 +814,9 @@ def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_
     if hasattr(_traced_tiles, "operands"):  # :func:`mixer_counts` is tracing the caller
         _traced_tiles.operands.append((b, bt is not None))
     if bt is None:
-        q, k, v = _xla_operands(q, k, v, p["q_norm"], p["k_norm"], **recipe)
+        q, k, v = _xla_operands(q, k, v, p.get("q_norm"), p.get("k_norm"), **recipe)
     else:
-        q, k, v = _kernel_operands(q, k, v, p["q_norm"], p["k_norm"], bt, **recipe)
+        q, k, v = _kernel_operands(q, k, v, p.get("q_norm"), p.get("k_norm"), bt, **recipe)
     o = causal_attention(q, k, v, window)
     o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
     if gate is not None:
@@ -915,30 +972,89 @@ def _sum_counts(totals: dict | None, counts: dict | None) -> dict:
     return totals
 
 
-def lm_hidden(params, ids, *, cfg, batch_sharding=None):
-    """ids [B, T] → (final hidden states [B, T, h] before the final norm,
-    counts: the expert layers' summed over the routed layers, and the
-    attention kernels' grid steps and the rows by what made their operands
-    over every layer, :func:`layer_attention_counts`, traced once a kind:
-    Python integers).  The embedding's output is
-    multiplied by ``cfg.embed_scale`` where the configuration has one."""
+def _embedded(params, ids, *, cfg):
+    """ids [B, T] → the stack's input [B, T, h] in ``cfg.dtype``: the
+    embedding's rows, multiplied by ``cfg.embed_scale`` where the
+    configuration has one."""
     with jax.named_scope(EMBED_SCOPE):
         x = params["embed"][ids]
         if getattr(cfg, "embed_scale", None) is not None:
             x = x * cfg.embed_scale
-        x = x.astype(jnp.dtype(cfg.dtype))
+        return x.astype(jnp.dtype(cfg.dtype))
+
+
+def _layers(params, x, *, cfg, batch_sharding=None):
+    """One walk through ``params["layers"]``: x [B, T, h] → (x, the expert
+    layers' counts summed over the routed layers, or None)."""
     kinds, ffns = cfg.layer_kinds(), cfg.ffn_kinds()
     buffers = params.get("buffers", {}).get("layers", [None] * len(kinds))
-    totals, tiles, of_kind = None, None, {}
+    totals = None
     for lp, held, kind, ffn in zip(params["layers"], buffers, kinds, ffns, strict=True):
-        if kind not in of_kind:  # a kind's layers have one shape
-            of_kind[kind] = layer_attention_counts(cfg, kind, x, lp[kind])
-        tiles = _sum_counts(tiles, of_kind[kind])
         x, counts = lm_layer(x, lp, held, kind=kind, ffn=ffn, cfg=cfg, batch_sharding=batch_sharding)
         if counts is not None:
             with jax.named_scope(EXPERTS_SCOPE):
                 totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
+    return x, totals
+
+
+def _attention_counts(params, x, *, cfg) -> dict:
+    """:func:`layer_attention_counts` summed over one walk through the layers
+    for the batch ``x`` [B, T, h], traced once a kind (a kind's layers have
+    one shape): Python integers, no operation of the program."""
+    of_kind, tiles = {}, None
+    for lp, kind in zip(params["layers"], cfg.layer_kinds(), strict=True):
+        if kind not in of_kind:
+            of_kind[kind] = layer_attention_counts(cfg, kind, x, lp[kind])
+        tiles = _sum_counts(tiles, of_kind[kind])
+    return tiles
+
+
+def lm_hidden(params, ids, *, cfg, batch_sharding=None):
+    """ids [B, T] → (final hidden states [B, T, h] before the final norm,
+    counts: the expert layers' summed over the routed layers, and the
+    attention kernels' grid steps and the rows by what made their operands
+    over every layer, :func:`_attention_counts`).  The embedding's output is
+    multiplied by ``cfg.embed_scale`` where the configuration has one."""
+    x = _embedded(params, ids, cfg=cfg)
+    tiles = _attention_counts(params, x, cfg=cfg)
+    x, totals = _layers(params, x, cfg=cfg, batch_sharding=batch_sharding)
     return x, _sum_counts(totals, tiles)
+
+
+def loop_hidden(params, ids, *, cfg, batch_sharding=None):
+    """The stack run ``cfg.loop_passes`` times over ONE set of weights (a
+    looped, weight-shared model): ids [B, T] → (the state after each pass
+    [R, B, T, h], every one AFTER the final norm, which is applied between
+    passes: pass ``t + 1`` starts from pass ``t``'s normed state, and that is
+    also what the head and the exit gate read; counts as :func:`lm_hidden`'s,
+    over all the passes, and ``loop_layers_run`` and ``loop_layers``: rows x
+    layers x passes and rows x layers, Python integers).
+
+    With more than one pass the passes are one ``lax.scan`` whose body is
+    :func:`_layers` and the norm: the step program holds each layer's body
+    once, the shared weights' gradients add up in the scan's transpose, and
+    the backward pass keeps of each pass what it keeps of a stack
+    (:func:`lm_layer`).  One pass is the plain walk."""
+    passes = cfg.loop_passes
+    dtype = jnp.dtype(cfg.dtype)
+
+    def one_pass(x, _=None):
+        x, totals = _layers(params, x, cfg=cfg, batch_sharding=batch_sharding)
+        with jax.named_scope(HEAD_SCOPE):
+            x = cfg.norm(x, params["final_norm"]).astype(dtype)
+        return x, (x, totals)
+
+    x = _embedded(params, ids, cfg=cfg)
+    tiles = {key: passes * n for key, n in _attention_counts(params, x, cfg=cfg).items()}
+    if passes == 1:
+        _, (state, totals) = one_pass(x)
+        states = state[None]
+    else:
+        _, (states, totals) = jax.lax.scan(one_pass, x, None, length=passes)
+        with jax.named_scope(EXPERTS_SCOPE):
+            totals = jax.tree.map(lambda n: jnp.sum(n, axis=0), totals)
+    layer_rows = ids.shape[0] * len(params["layers"])
+    return states, dict(_sum_counts(totals, tiles), loop_layers_run=passes * layer_rows, loop_layers=layer_rows)
 
 
 def head_params(params) -> dict:
@@ -948,10 +1064,12 @@ def head_params(params) -> dict:
 
 
 def lm_head(head, x, *, cfg):
-    """Logits over the held vocabulary, float32: x [..., h] → [..., vocab]."""
+    """Logits over the held vocabulary, float32: x [..., h] → [..., vocab];
+    the final norm first where ``head`` holds one."""
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope(HEAD_SCOPE):
-        y = cfg.norm(x, head["final_norm"]).astype(dtype)
+        # a looped model's states come normed (:func:`loop_hidden`): its head holds no norm
+        y = cfg.norm(x, head["final_norm"]).astype(dtype) if "final_norm" in head else x
         if "head" in head:
             return jnp.dot(y, head["head"].astype(dtype), preferred_element_type=jnp.float32)
         return jnp.einsum("...h,vh->...v", y, head["embed"].astype(dtype), preferred_element_type=jnp.float32)
@@ -1039,3 +1157,82 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
             labelled = labelled + second
             counts = _sum_counts(counts, more)
     return loss, dict(counts, **terms, tokens=jnp.int32(ids.size), head_all=labelled, head_mtp=second)
+
+
+def exit_distribution(gate, states):
+    """A looped model's exit gate over the passes' normed states [R, ..., h] →
+    the distribution over the pass a position exits after, [R, ...] float32
+    (Ouro, arXiv:2510.25741, section 3): ``lambda^(t) = sigmoid(z^(t) . w +
+    b)``; the survival ``S^(0) = 1``, ``S^(t) = S^(t-1) (1 - lambda^(t))``;
+    ``p(t) = lambda^(t) S^(t-1)`` before the last pass and ``p(R) = S^(R-1)``:
+    what is left exits there, so the ``R`` terms sum to 1.  Float32, the
+    product with ``w`` [h] a multiply and a sum (no matrix unit's rounding)."""
+    z = states.astype(jnp.float32)
+    lam = jax.nn.sigmoid(jnp.sum(z[:-1] * gate["w"], axis=-1) + gate["b"])
+    ones = jnp.ones_like(z[:1, ..., 0])
+    survived = jnp.concatenate([ones, jnp.cumprod(1.0 - lam, axis=0)])  # S^(0) .. S^(R-1)
+    return jnp.concatenate([lam * survived[:-1], survived[-1:]])
+
+
+def exit_loss(params, states, labels, *, cfg, batch_sharding=None):
+    """A looped model's objective over its passes' normed states [R, B, T, h]
+    (:func:`loop_hidden`) → (loss, counts): the expected next-token loss under
+    the exit distribution less ``cfg.exit_beta`` times that distribution's
+    entropy (a uniform prior), mean over the positions with a label,
+
+        ``mean_i [ sum_t p_i(t) nll_i^(t) + beta sum_t p_i(t) log p_i(t) ]``
+
+    with ``nll_i^(t)`` the float32 cross-entropy of pass ``t``'s logits
+    ``z^(t) W_head`` (one head matrix, no second norm: the states come
+    normed) and ``p`` :func:`exit_distribution` by ``params["exit"]``.
+
+    The ``R`` passes go through the head and the loss's tile loop as ONE call
+    over the stacked states (:func:`labelled_nll` with a weight a position:
+    one float32 weight-gradient accumulator of the head, not ``R``), and the
+    product rule gives both gradients from it: the weighted NLL with ``p``
+    held constant carries the gradient into the head and the stack, ``p``
+    times the returned NLL held constant the gradient into the gate (and
+    through ``z`` into the stack).  ``counts``: ``head_all`` the labelled
+    positions over all ``R`` losses, ``head_loop`` those of the passes
+    before the last and ``head_mtp`` 0 (no prediction module), int32; ``loss_pass`` [R] each pass's mean NLL and
+    ``exit_mass`` [R] the mean ``p(t)``, float32; ``exit_mass_<t>`` the summed
+    ``p(t)`` over the labelled positions in 1,024ths, int32 (what the step's
+    gauge counts); ``tokens``."""
+    passes = states.shape[0]
+    f32 = jnp.float32
+    with jax.named_scope(EXIT_SCOPE):
+        p = exit_distribution(params["exit"], states)
+        labelled = (labels >= 0).astype(f32)
+        n = jnp.maximum(jnp.sum(labelled), 1.0)
+        log_p = jnp.log(jnp.maximum(p, jnp.finfo(f32).tiny))  # p log p → 0 as p → 0
+        neg_entropy = jnp.sum(p * log_p * labelled) / n
+        weights = jax.lax.stop_gradient(p) * (labelled / n)
+    with jax.named_scope(HEAD_SCOPE):  # rows first: a mesh splits the stacked states by their rows
+        expected, _, nll = labelled_nll(
+            functools.partial(lm_head, cfg=cfg), {k: v for k, v in head_params(params).items() if k != "final_norm"},
+            jnp.moveaxis(states, 0, 1), jnp.broadcast_to(labels[:, None], (labels.shape[0], passes, labels.shape[1])),
+            batch_sharding, jnp.moveaxis(weights, 0, 1),
+        )
+    with jax.named_scope(EXIT_SCOPE):
+        nll = jax.lax.stop_gradient(jnp.moveaxis(nll, 1, 0))  # [R, B, T]
+        through_gate = jnp.sum(p * nll * labelled) / n  # equal to ``expected``; its gradient is the gate's
+        loss = expected + (through_gate - jax.lax.stop_gradient(through_gate)) + cfg.exit_beta * neg_entropy
+        mass = jnp.sum(p * labelled, axis=(1, 2))
+        count = jnp.sum(labels >= 0, dtype=jnp.int32)
+        counts = {
+            "loss_pass": jnp.sum(nll * labelled, axis=(1, 2)) / n, "exit_mass": mass / n,
+            "head_all": passes * count, "head_loop": (passes - 1) * count, "head_mtp": jnp.int32(0),
+            "tokens": jnp.int32(labels.size),
+            **{f"exit_mass_{t}": jnp.round(mass[t] * EXIT_MASS_UNIT).astype(jnp.int32) for t in range(passes)},
+        }
+    return loss, counts
+
+
+def loop_loss(params, ids, labels, *, cfg, batch_sharding=None):
+    """The loss of a looped model (``cfg.loop_passes`` passes of the stack
+    over one set of weights, a loss after every pass through one head, an exit
+    gate): :func:`exit_loss` over :func:`loop_hidden` → (loss, counts: both
+    functions')."""
+    states, counts = loop_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
+    loss, more = exit_loss(params, states, labels, cfg=cfg, batch_sharding=batch_sharding)
+    return loss, dict(counts, **more)

@@ -85,13 +85,21 @@ def _init_train_state(init_params, mesh, shardings, lr: float, seed: int):
 # count it differently: the MLM step counts every position of the batch, labelled or not, beside
 # ``{kind="computed"}``, the positions its head ran over; an LM step counts the positions that carry a
 # label, summed over every loss term it adds (next token, and the prediction module's where there is
-# one), beside ``{kind="mtp"}``, the module's term alone.  A process that runs both kinds sums both.
+# one; in a looped model every pass's loss), beside ``{kind="mtp"}``, the module's term alone, and
+# ``{kind="loop"}``, the losses of a looped model's passes before the last.  A process that runs both kinds
+# sums both.
 HEAD_POSITIONS_FAMILY = "lakesoul_train_head_positions_total"
 TOKENS_FAMILY = "lakesoul_train_tokens_total"
 MOE_ASSIGNMENTS_FAMILY = "lakesoul_train_moe_assignments_total"
 MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
 ATTN_KEY_TILES_FAMILY = "lakesoul_train_attn_key_tiles_total"
 ATTN_OPERAND_ROWS_FAMILY = "lakesoul_train_attn_operand_rows_total"
+# a looped model's step (``cfg.loop_passes``): ``{kind="run"}`` the layer passes it ran, rows x layers x
+# passes, over ``{kind="layers"}``, rows x layers: a change that skips a pass or exits early moves the ratio
+LOOP_LAYER_PASSES_FAMILY = "lakesoul_train_loop_layer_passes_total"
+# a gauge, ``{pass="1".."R"}``: the mean share of the exit distribution on each pass over the labelled
+# positions of every step so far (``models/causal_lm.py: exit_loss``); the ``R`` series sum to 1
+LOOP_EXIT_MASS_FAMILY = "lakesoul_train_loop_exit_mass"
 
 # live steps, and what the collected ones had counted: the families are
 # counters and must not fall when a step is dropped
@@ -108,11 +116,14 @@ def _counts(state: dict) -> dict:
     return {**counted, **state["host"]}
 
 
-def _series_values(state: dict) -> dict[tuple, float]:
+def _series_values(state: dict) -> dict[tuple, np.ndarray]:
+    """{(family, labels, whether a gauge): (the count x its scale, the count
+    it is over)}; a counter is over 1."""
     counts = _counts(state)
     return {
-        (family, tuple(sorted(labels.items()))): counts[key] * scale
-        for key, family, labels, scale in state["series"]
+        (family, tuple(sorted(labels.items())), bool(over)):
+            np.array([counts[key] * scale, counts[over[0]] if over else 1.0])
+        for key, family, labels, scale, *over in state["series"]
     }
 
 
@@ -129,7 +140,11 @@ def _collect_step_counts() -> list:
     for step in list(_live_steps):
         for series, n in _series_values(step._state).items():
             totals[series] = totals.get(series, 0) + n
-    return [(family, "counter", n, dict(labels)) for (family, labels), n in totals.items()]
+    # a gauge is a ratio of two sums over every step, live or collected
+    return [
+        (family, "gauge", n / max(over, 1.0), dict(labels)) if gauge else (family, "counter", n, dict(labels))
+        for (family, labels, gauge), (n, over) in totals.items()
+    ]
 
 
 class _CountedStep:
@@ -158,10 +173,13 @@ class _CountedStep:
     carries (two int32 limbs of 30 bits) and is read only when the registry is
     scraped: the step loop reads nothing from the device for them.  ``series``
     says which registry series a count feeds: ``(count key, family, labels,
-    scale)``, the series' value being the count times ``scale``.  The
+    scale)``, the series' value being the count times ``scale``; a fifth
+    entry, another count's key, makes the series a gauge: the scaled count
+    over that count.  The
     ``host_keys`` among the counts are Python integers that do not depend on
     the data (what the shapes make the kernels' grids): known when the step is
-    traced, no operation of the program, and added on the host a call."""
+    traced, no operation of the program, and added on the host a call; one a
+    loss does not return counts 0."""
 
     def __init__(self, step_fn, param_shardings, batch_shardings, loss_sharding, series, host_keys=()):
         self._step_fn = step_fn
@@ -169,7 +187,7 @@ class _CountedStep:
         self._batch_shardings = batch_shardings
         self._replicated = loss_sharding
         self._fn = None
-        keys = tuple(sorted({key for key, *_ in series} - set(host_keys)))
+        keys = tuple(sorted({key for entry in series for key in (entry[0], *entry[4:])} - set(host_keys)))
         self._state = {
             "counted": jax.device_put(np.zeros((len(keys), 2), np.int32), loss_sharding),
             "keys": keys, "series": tuple(series),
@@ -195,7 +213,7 @@ class _CountedStep:
 
             def train_step(params, opt_state, counted, *batch):
                 params, opt_state, loss, counts = step_fn(params, opt_state, *batch)
-                a_step.update({key: int(counts[key]) for key in a_step})  # while tracing: one batch shape a step
+                a_step.update({key: int(counts.get(key, 0)) for key in a_step})  # while tracing: one batch shape a step
                 with jax.named_scope(OPTIM_SCOPE):
                     low = counted[:, 1] + jnp.stack([counts[key] for key in keys]).astype(jnp.int32)
                     counted = jnp.stack([counted[:, 0] + (low >> 30), low & ((1 << 30) - 1)], axis=1)
@@ -359,10 +377,36 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     step's softmax-attention layer-rows by what made the attention kernels'
     operands: the operand kernels in one pass where ``models/causal_lm.py:
     _operand_tiles`` takes the mixer's shape, else the ``jnp`` lines; both 0
-    for a family whose mixers are not ``softmax_attention``)."""
+    for a family whose mixers are not ``softmax_attention``).
+
+    A looped family (``cfg.loop_passes``: the stack run that many times over
+    one set of weights, a loss after every pass) also feeds
+    ``lakesoul_train_loop_layer_passes_total{kind="run"|"layers"}`` (rows x
+    layers x passes and rows x layers, host integers: 4.0 apart at four
+    passes), ``lakesoul_train_head_positions_total{kind="loop"}`` (the
+    labelled positions of the passes before the last, which ``{kind="all"}``
+    counts with the last pass's: 75% of it at four; 0 in every other family)
+    and the gauge ``lakesoul_train_loop_exit_mass{pass="1".."R"}`` (the mean
+    share of the exit distribution on each pass: sums to 1)."""
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
+    host_keys = ("attn_tiles_run", "attn_tiles_causal", "attn_operands_kernel", "attn_operands_xla",
+                 "loop_layers_run", "loop_layers")
+    held = getattr(cfg, "experts_held", None)
+    if held is None:  # a family without experts: its loss returns none of their counts, and they count 0
+        host_keys += ("moe_held", "moe_all", "moe_tile_rows", "moe_bias_moved", "moe_dw_writes", "moe_load_max")
+    passes = getattr(cfg, "loop_passes", None)
+    if passes is None:
+        host_keys += ("head_loop",)  # no operation of a step that does not loop: its programs stay as they are
+        loop_series = ()
+    else:
+        from lakesoul_tpu.models.causal_lm import EXIT_MASS_UNIT
+
+        loop_series = tuple(
+            (f"exit_mass_{t}", LOOP_EXIT_MASS_FAMILY, {"pass": str(t + 1)}, passes / EXIT_MASS_UNIT, "head_all")
+            for t in range(passes)
+        )
     series = (
         ("tokens", TOKENS_FAMILY, {}, 1),
         ("head_mtp", HEAD_POSITIONS_FAMILY, {"kind": "mtp"}, 1),
@@ -373,16 +417,19 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("moe_bias_moved", MOE_ASSIGNMENTS_FAMILY, {"kind": "bias_moved"}, 1),
         ("moe_dw_writes", MOE_ASSIGNMENTS_FAMILY, {"kind": "dw_writes"}, 1),
         ("moe_load_max", MOE_LOAD_FAMILY, {"stat": "max"}, 1),
-        ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / cfg.experts_held[1]),
+        ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / held[1] if held else 0),
         ("attn_tiles_run", ATTN_KEY_TILES_FAMILY, {"kind": "run"}, 1),
         ("attn_tiles_causal", ATTN_KEY_TILES_FAMILY, {"kind": "causal"}, 1),
         ("attn_operands_kernel", ATTN_OPERAND_ROWS_FAMILY, {"path": "kernel"}, 1),
         ("attn_operands_xla", ATTN_OPERAND_ROWS_FAMILY, {"path": "xla"}, 1),
+        ("head_loop", HEAD_POSITIONS_FAMILY, {"kind": "loop"}, 1),
+        ("loop_layers_run", LOOP_LAYER_PASSES_FAMILY, {"kind": "run"}, 1),
+        ("loop_layers", LOOP_LAYER_PASSES_FAMILY, {"kind": "layers"}, 1),
+        *loop_series,
     )
     return _CountedStep(
         _adamw_step(loss_fn, tx), param_shardings, (batch_sharding, batch_sharding),
-        NamedSharding(plan.mesh, P()), series,
-        host_keys=("attn_tiles_run", "attn_tiles_causal", "attn_operands_kernel", "attn_operands_xla"),
+        NamedSharding(plan.mesh, P()), series, host_keys=host_keys,
     )
 
 

@@ -385,8 +385,10 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     t = min(8192, max(256, sizes.rows // 128))
     detail = {"tokens": t}
     # and a Trinity-Mini row's window layer: 4 key-value heads of 8 query heads at head 128 under a window of
-    # 2,048 (half the row at tiny sizes), the twin's bands cut the same way
-    for hkv, groups, d, window in ((8, 4, 64, None), (2, 8, 256, None), (20, 1, 256, None), (4, 8, 128, 2048)):
+    # 2,048 (half the row at tiny sizes), the twin's bands cut the same way; and an Ouro row: 16 key-value heads
+    # of one query head at head 128
+    shapes = ((8, 4, 64, None), (2, 8, 256, None), (20, 1, 256, None), (4, 8, 128, 2048), (16, 1, 128, None))
+    for hkv, groups, d, window in shapes:
         hkv = hkv if t == 8192 else 1
         window = window and min(window, t // 2)
         keys = jax.random.split(jax.random.key(9), 4)
@@ -424,19 +426,23 @@ def _run_attention_operands(interpret: bool, sizes: SmokeSizes) -> dict:
     )
 
     # a Trinity-Mini row's two kinds of mixer at deployed sizes (8,192 tokens, 32 query heads over 4 key-value
-    # heads at head 128; 8 over 2 on 256 tokens at tiny ones), with positions over the whole head and without:
+    # heads at head 128; 8 over 2 on 256 tokens at tiny ones), with positions over the whole head and without,
+    # and an Ouro row's (16 over 16; 4 over 4), with positions and WITHOUT head norms:
     # the flash kernels' operands and every gradient (raw q, k, v, both norm weights) against the ``jnp`` lines
     # the kernels stand for.  Both sides compute in float32 and round once, so the operands agree to one
     # bfloat16 unit element by element (a sum in another order moves a float32 result by its last bits, and
     # now and then across a rounding boundary); the gradients by norm
     t = min(8192, max(256, sizes.rows // 128))
-    heads, kv, d = (32, 4, 128) if t == 8192 else (8, 2, 128)
-    eps, theta = 1e-5, 10000.0
+    d, eps, theta = 128, 1e-5, 10000.0
     keys = jax.random.split(jax.random.key(43), 8)
-    raw = [jax.random.normal(key, (1, t, n, d)).astype(jnp.bfloat16) for key, n in zip(keys, (heads, kv, kv))]
-    wq, wk = (1.0 + 0.1 * jax.random.normal(key, (d,)) for key in keys[3:5])
-    detail = {"tokens": t, "heads": [heads, kv, d]}
-    for rotary_dim in (d, None):
+    detail = {"tokens": t}
+    recipes = {
+        "turned": ((32, 4) if t == 8192 else (8, 2), d, True), "plain": ((32, 4) if t == 8192 else (8, 2), None, True),
+        "turned_no_norm": ((16, 16) if t == 8192 else (4, 4), d, False),
+    }
+    for name, ((heads, kv), rotary_dim, normed) in recipes.items():
+        raw = [jax.random.normal(key, (1, t, n, d)).astype(jnp.bfloat16) for key, n in zip(keys, (heads, kv, kv))]
+        wq, wk = [1.0 + 0.1 * jax.random.normal(key, (d,)) for key in keys[3:5]] if normed else (None, None)
         bt = _operand_tiles(t, heads, kv, d, rotary_dim)
         want, pull = jax.vjp(
             jax.jit(lambda *a: _xla_operands(*a, eps=eps, centred=False, rotary_dim=rotary_dim, theta=theta)), *raw, wq, wk  # noqa: B023
@@ -444,22 +450,22 @@ def _run_attention_operands(interpret: bool, sizes: SmokeSizes) -> dict:
         cots = [jax.random.normal(key, a.shape).astype(a.dtype) for key, a in zip(keys[5:], want)]
         turn = None if rotary_dim is None else _turn_tables(t, d, theta)
         flat = [a.reshape(1, t, -1) for a in raw]
-        got = _operands_forward(*flat, wq, wk, turn, eps=eps, bt=bt, interpret=interpret)
-        grads = _operands_backward(*cots, *flat[:2], wq, wk, turn, eps=eps, bt=bt, interpret=interpret)
+        got = _operands_forward(*flat, wq, wk, turn, d=d, eps=eps, bt=bt, interpret=interpret)
+        grads = _operands_backward(*cots, *flat[:2], wq, wk, turn, d=d, eps=eps, bt=bt, interpret=interpret)
         units, errors = [], []
         for a, b in zip(got, want, strict=True):
             a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernel's operands against the jnp lines'
             units.append(float(np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-30)) * 2**7))
             errors.append(float(np.mean(a != b)))
         if not (max(units) <= 1.0 and max(errors) < 1e-2):
-            raise AssertionError(f"attention operands, rotary {rotary_dim}: q, k, v off by {units} units, {errors} of the elements")
-        for a, b in zip(grads, pull(tuple(cots)), strict=True):
+            raise AssertionError(f"attention operands, {name}: q, k, v off by {units} units, {errors} of the elements")
+        for a, b in zip(grads[:5 if normed else 3], pull(tuple(cots))[:5 if normed else 3], strict=True):
             a, b = (np.asarray(x.astype(jnp.float32)).reshape(b.shape) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernel's gradients against the jnp lines'
             errors.append(float(np.linalg.norm(a - b) / np.linalg.norm(b)))
         if not max(errors[3:]) < 1e-3:
-            raise AssertionError(f"attention operands, rotary {rotary_dim}: dq, dk, dv, dw_q, dw_k off by {errors[3:]}")
-        detail["turned" if rotary_dim else "plain"] = {
-            "block": bt, "units": [round(u, 3) for u in units], "differ": [round(e, 6) for e in errors[:3]],
+            raise AssertionError(f"attention operands, {name}: dq, dk, dv, dw_q, dw_k off by {errors[3:]}")
+        detail[name] = {
+            "heads": [heads, kv, d], "block": bt, "units": [round(u, 3) for u in units], "differ": [round(e, 6) for e in errors[:3]],
             "rel_err": [float(f"{e:.3g}") for e in errors[3:]],
         }
     return detail

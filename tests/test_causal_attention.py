@@ -1,8 +1,9 @@
 """``models/causal_lm.py: causal_attention``: the flash kernels (in the Pallas
 interpreter here) against the plain masked softmax and against their blockwise
-twin, forward and every gradient, at the four published group and head sizes,
+twin, forward and every gradient, at the five published group and head sizes,
 under the causal mask and under a window; the tile lists; which shapes take
-the kernels; what a checkpoint around the caller keeps.
+the kernels; what a checkpoint around the caller keeps; and the mixer's recipe
+WITHOUT head norms (the Ouro family's plain attention) on both operand paths.
 """
 
 from __future__ import annotations
@@ -14,7 +15,8 @@ import pytest
 from lakesoul_tpu.models import causal_lm
 
 # query heads a key-value head, head size
-PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256), "glm-4.7-flash": (1, 256), "trinity-mini": (8, 128)}
+PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256), "glm-4.7-flash": (1, 256), "trinity-mini": (8, 128),
+             "ouro": (1, 128)}
 # (tokens, FLASH_KEYS, FLASH_ROWS as a multiple of the group) → the (query, key) tiles of a row
 TILINGS = {
     "one-tile": (128, 512, 128),            # 1 x 1: the diagonal tile alone
@@ -226,3 +228,84 @@ def test_a_row_checkpoint_keeps_the_output_and_the_log_sum_exp():
     assert forward_kernels(plain_rows) == 2
     assert forward_kernels(kept_rows) == 1
     assert_close(grad(kept_rows)(jnp.float32(1.0)), grad(plain_rows)(jnp.float32(1.0)), 1e-6)
+
+
+# ------------------------------------------------- the recipe without head norms
+
+
+def _f32(x):
+    import numpy as np
+
+    return np.asarray(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("turned", [True, False], ids=["rotary-whole", "rotary-none"])
+@pytest.mark.parametrize("heads, kv", [(4, 4), (8, 2)], ids=["groups-of-1", "groups-of-4"])
+def test_the_operand_kernels_without_head_norms_are_the_xla_lines(heads, kv, turned):
+    """No ``q_norm`` and no ``k_norm``: the kernel pair turns, scales, casts
+    and lays out, and norms nothing; operands bit for bit or within one
+    bfloat16 unit of ``_xla_operands`` with both weights None, the raw q, k, v
+    cotangents by norm, and no gradient for a weight that is not there."""
+    import numpy as np
+
+    t, d = 256, 128
+    keys = jax.random.split(jax.random.key(11), 6)
+    q, k, v = (jax.random.normal(key, (2, t, n, d)).astype(jnp.bfloat16) for key, n in zip(keys, (heads, kv, kv)))
+    recipe = dict(eps=1e-6, centred=False, rotary_dim=d if turned else None, theta=1e6)
+    bt = causal_lm._operand_tiles(t, heads, kv, d, recipe["rotary_dim"])
+    assert bt == t
+    want, pull_want = jax.vjp(lambda *a: causal_lm._xla_operands(*a, None, None, **recipe), q, k, v)
+    got, pull_got = jax.vjp(lambda *a: causal_lm._kernel_operands(*a, None, None, bt, **recipe), q, k, v)
+    for a, b in zip(got, want, strict=True):
+        assert a.shape == b.shape and a.dtype == b.dtype == jnp.bfloat16
+        a, b = _f32(a), _f32(b)
+        # one bfloat16 unit; an element whose two turned halves cancel (1e-6 where each is 1) keeps float32's last bits
+        assert np.all(np.abs(a - b) <= np.abs(b) * 2.0**-7 + 1e-5) and np.mean(a != b) < 1e-3
+    # not normed: the raw query times its scale and, unturned, nothing else
+    if not turned:
+        scaled = (q.astype(jnp.float32) * d**-0.5).astype(jnp.bfloat16).reshape(2, t, kv, heads // kv, d)
+        np.testing.assert_array_equal(_f32(got[0]), _f32(scaled.transpose(0, 2, 3, 1, 4)))
+    cots = tuple(jax.random.normal(key, a.shape).astype(a.dtype) for key, a in zip(keys[3:], want))
+    for a, b, limit in zip(pull_got(cots), pull_want(cots), (2e-4, 2e-4, 0.0), strict=True):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.linalg.norm(_f32(a) - _f32(b)) <= limit * np.linalg.norm(_f32(b))
+
+
+@pytest.mark.parametrize("path", ["operand-kernels", "xla-lines"])
+def test_a_mixer_whose_weights_hold_no_head_norm_norms_no_head(path, monkeypatch):
+    """``softmax_attention`` over weights without ``q_norm`` and ``k_norm``,
+    through ``_row_by_row``'s checkpoint, value and every gradient, on either
+    operand path: equal to the plain masked softmax over the rotated raw
+    heads, and far from the same mixer with norms of weight 1."""
+    import functools
+
+    import numpy as np
+
+    h, heads, kv, d, t = 64, 2, 2, 128, 256
+    keys = jax.random.split(jax.random.key(13), 6)
+    x = jax.random.normal(keys[0], (1, t, h))
+    p = {name: causal_lm.normal_init(key, *shape) * 10 for name, key, shape in (
+        ("w_q", keys[1], (h, heads * d)), ("w_k", keys[2], (h, kv * d)), ("w_v", keys[3], (h, kv * d)),
+        ("w_o", keys[4], (heads * d, h)))}
+    cot = jax.random.normal(keys[5], x.shape)
+    mixer = functools.partial(
+        causal_lm.softmax_attention, heads=heads, kv_heads=kv, head_dim=d, rotary_dim=d, theta=1e6, eps=1e-6,
+        centred=False, gated=False,
+    )
+    if path == "xla-lines":
+        monkeypatch.setattr(causal_lm, "_operand_tiles", lambda *shape: None)
+    counts = causal_lm.mixer_counts(mixer, x, p)
+    assert (counts["attn_operands_kernel"], counts["attn_operands_xla"]) == ((1, 0) if path == "operand-kernels" else (0, 1))
+
+    def plain(x, p):
+        q, k, v = ((x @ p[w]).reshape(1, t, n, d) for w, n in (("w_q", heads), ("w_k", kv), ("w_v", kv)))
+        positions = jnp.arange(t)
+        q, k = (causal_lm._rotary(a, positions, d, 1e6) for a in (q, k))
+        o = plain_attention(q.transpose(0, 2, 1, 3)[:, :, None] * d**-0.5, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        return o[:, :, 0].transpose(0, 2, 1, 3).reshape(1, t, heads * d) @ p["w_o"]
+
+    got = out_and_grads(lambda x, p, _: causal_lm._row_by_row(mixer, x, p, None), x, p, None, cot)
+    want = out_and_grads(lambda x, p, _: plain(x, p), x, p, None, cot)
+    assert_close(got, want, 2e-4)
+    normed = mixer(x, dict(p, q_norm=jnp.ones((d,)), k_norm=jnp.ones((d,))))
+    assert float(jnp.linalg.norm(normed - got[0]) / jnp.linalg.norm(got[0])) > 0.1

@@ -556,7 +556,8 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
                              "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
                              "head_mtp": second, "head_all": main + second,
                              "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
-                             "attn_operands_kernel": 0, "attn_operands_xla": 0}  # latent attention makes its own operands
+                             "attn_operands_kernel": 0, "attn_operands_xla": 0,
+                             "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0}  # latent attention makes its own operands; no pass loop
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]
     steps = 2 * STEPS + 1

@@ -450,7 +450,8 @@ def test_counters_for_a_known_routing(stepped):
                              "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
                              "head_all": B * (T - 1), "head_mtp": 0,  # one loss, no prediction module
                              "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
-                             "attn_operands_kernel": 0, "attn_operands_xla": B}  # one attention layer, its operands the jnp lines'
+                             "attn_operands_kernel": 0, "attn_operands_xla": B,
+                             "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0}  # one attention layer, its operands the jnp lines'; no pass loop
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]
     steps = 2 * STEPS + 1

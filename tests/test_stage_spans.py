@@ -1031,6 +1031,188 @@ def test_afmoe_readers(check):
     assert selftest.FAMILY == ATTN_KEY_TILES_FAMILY
 
 
+# -------------------------------------------- the fifth causal-LM family
+
+OURO_SCOPES = {
+    "lakesoul.lm.exit": ("layer_metrics/exit_step_share_pct.py", 'SCOPE = "exit"'),
+}
+OURO_PASSES, OURO_LAYERS = 3, 2
+
+
+def _ouro_cfg(**changed):
+    from lakesoul_tpu.models.ouro import OuroConfig
+
+    # two layers run three times, two heads of 128 on two key-value heads: every mixer takes its kernels (the
+    # flash pair and the operand pair, without head norms) at 128 tokens
+    sizes = dict(
+        vocab_size=64, hidden_size=256, num_hidden_layers=OURO_LAYERS, intermediate_size=48, num_attention_heads=2,
+        num_key_value_heads=2, head_dim=128, total_ut_steps=OURO_PASSES,
+    )
+    return OuroConfig(**(sizes | changed))
+
+
+@pytest.fixture(scope="module")
+def ouro_step_compiled_for_a_v5e() -> str:
+    return _family_step_compiled_for_a_v5e(_ouro_cfg())
+
+
+def _computation_of(text: str) -> dict[str, str]:
+    """{instruction: the computation that holds it} of a compiled module."""
+    held, current = {}, None
+    for line in text.splitlines():
+        if not line.startswith((" ", "\t")):
+            opened = _HLO_COMPUTATION.match(line)
+            current = opened.group(1) if opened else current
+            continue
+        found = _HLO_INSTRUCTION.match(line)
+        if found:
+            held[found.group(1)] = current
+    return held
+
+
+def _loop_bodies(text: str) -> dict[str, str]:
+    """{a ``while``'s body computation: the computation that holds the ``while``}."""
+    held = _computation_of(text)
+    return {body: held[name] for name, body in re.findall(r"^\s*%?([\w.\-]+) = .*? while\(.*?body=%?([\w.\-]+)", text, re.MULTILINE)}
+
+
+def test_ouro_step_holds_each_layers_kernels_once_inside_the_pass_loops_body(ouro_step_compiled_for_a_v5e):
+    """The passes are one loop: the step program holds each layer's body ONCE
+    forward and once backward whatever the number of passes, so the flash pair
+    stands once a layer (``flash_attention_fwd.<n>``, ``flash_attention_bwd.<n>``)
+    and the operand pair beside it (forward twice: the row and its
+    rematerialisation), every one under ``lakesoul.lm.attn`` by the adaptor's
+    scope map and every one inside a ``while`` body nested in the pass loop's
+    (the loop over rows inside the loop over passes): which is what
+    ``flash_fwd_roofline_pct``, ``flash_bwd_roofline_pct`` and
+    ``attn_step_share_pct`` read in the cell.  The step's scopes are the
+    fourteen's subset a reader sums, ``lakesoul.lm.exit`` among them."""
+    text = ouro_step_compiled_for_a_v5e
+    calls = _kernel_calls(text)
+    names = sorted(name.rsplit(".", 1)[0] for name in calls)
+    assert names == (["attn_operands_bwd"] * OURO_LAYERS + ["attn_operands_fwd"] * 2 * OURO_LAYERS
+                     + ["flash_attention_bwd"] * OURO_LAYERS + ["flash_attention_fwd"] * OURO_LAYERS), names
+    scope_of = _adaptor("ouro_clm").scopes_of(text)
+    assert {scope_of.get(name) for name in calls} == {"lakesoul.lm.attn"}
+    held, bodies = _computation_of(text), _loop_bodies(text)
+    for name in calls:  # a kernel's computation is a loop's body, and that loop stands inside another loop's body
+        inner = held[name]
+        assert inner in bodies and bodies[inner] in bodies, (name, inner)
+    shared = set(LM_SCOPES) - {"lakesoul.lm.gdn", "lakesoul.lm.moe.route", "lakesoul.lm.moe.experts", "lakesoul.lm.moe.shared"}
+    assert set(scope_of.values()) == shared | set(OURO_SCOPES) | {"lakesoul.lm.mlp"}
+    assert len(set(LM_SCOPES) | set(LFM2_SCOPES) | set(GLM_SCOPES) | set(AFMOE_SCOPES) | set(OURO_SCOPES)) == 14
+    assert sum(scope == "lakesoul.lm.exit" for scope in scope_of.values()) >= 3  # the gate, the distribution, their transpose
+
+
+@pytest.mark.parametrize("scope", sorted(OURO_SCOPES))
+def test_ouro_scope_names_the_share_readers_search_for(scope):
+    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models.train import make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    cfg = _ouro_cfg()
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    text = make_lm_train_step(cfg, plan, tx, shardings).lower(params, opt_state, ids, ids).as_text(debug_info=True)
+    assert "module @jit_train_step " in text
+    assert f"{scope}/" in text or f"{scope})" in text or f'{scope}"' in text
+    assert scope == causal_lm.EXIT_SCOPE
+    reader, constant = OURO_SCOPES[scope]
+    with open(os.path.join(REPO, "benchmarks", "chip", reader)) as f:
+        assert constant in f.read()
+    with open(os.path.join(REPO, "benchmarks", "chip", "consumers", "ouro_clm.py")) as f:
+        assert 'STEP_MODULE = "jit_train_step"' in f.read()
+
+
+def test_loop_series_are_the_steps_shapes():
+    """``lakesoul_train_loop_layer_passes_total{kind="run"|"layers"}``,
+    ``lakesoul_train_head_positions_total{kind="loop"|"all"}`` and the gauge
+    ``lakesoul_train_loop_exit_mass{pass=...}`` after two steps of two rows:
+    rows x layers x passes over rows x layers, the labelled positions of the
+    passes before the last over those of every pass, a distribution; the
+    attention counters times the passes; under the names the three readers
+    of the cell divide."""
+    from lakesoul_tpu.models.train import (
+        ATTN_KEY_TILES_FAMILY,
+        ATTN_OPERAND_ROWS_FAMILY,
+        HEAD_POSITIONS_FAMILY,
+        LOOP_EXIT_MASS_FAMILY,
+        LOOP_LAYER_PASSES_FAMILY,
+        make_lm_train_state,
+        make_lm_train_step,
+    )
+    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.obs import registry
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    def series():
+        snapshot = registry().snapshot()
+        return {key: value for key, value in snapshot.items() if key.split("{")[0] in (
+            LOOP_LAYER_PASSES_FAMILY, HEAD_POSITIONS_FAMILY, ATTN_KEY_TILES_FAMILY, ATTN_OPERAND_ROWS_FAMILY)}
+
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    cfg = _ouro_cfg()
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    step = make_lm_train_step(cfg, plan, tx, shardings)
+    rows, steps = 2, 2
+    ids = jnp.zeros((rows, LM_TOKENS), jnp.int32)
+    labels = jnp.concatenate([ids[:, 1:], jnp.full((rows, 1), -100, jnp.int32)], axis=1)
+    before = series()
+    for _ in range(steps):
+        params, opt_state, _loss = step(params, opt_state, ids, labels)
+    moved = {key: value - before.get(key, 0) for key, value in series().items()}
+    layer_rows, labelled = steps * rows * OURO_LAYERS, steps * rows * (LM_TOKENS - 1)
+    tiles = causal_lm.key_tile_steps(LM_TOKENS, 1, 128)[0] * 2  # two key-value heads a layer-row
+    want = {
+        f'{LOOP_LAYER_PASSES_FAMILY}{{kind="run"}}': OURO_PASSES * layer_rows,
+        f'{LOOP_LAYER_PASSES_FAMILY}{{kind="layers"}}': layer_rows,
+        f'{HEAD_POSITIONS_FAMILY}{{kind="all"}}': OURO_PASSES * labelled,
+        f'{HEAD_POSITIONS_FAMILY}{{kind="loop"}}': (OURO_PASSES - 1) * labelled,
+        f'{HEAD_POSITIONS_FAMILY}{{kind="mtp"}}': 0,
+        f'{ATTN_KEY_TILES_FAMILY}{{kind="run"}}': OURO_PASSES * layer_rows * tiles,
+        f'{ATTN_KEY_TILES_FAMILY}{{kind="causal"}}': OURO_PASSES * layer_rows * tiles,
+        f'{ATTN_OPERAND_ROWS_FAMILY}{{path="kernel"}}': OURO_PASSES * layer_rows,
+        f'{ATTN_OPERAND_ROWS_FAMILY}{{path="xla"}}': 0,
+    }
+    assert {key: moved.get(key) for key in want} == want
+    mass = [registry().snapshot()[f'{LOOP_EXIT_MASS_FAMILY}{{pass="{t + 1}"}}'] for t in range(OURO_PASSES)]
+    assert abs(sum(mass) - 1.0) < 1e-4 and all(0.0 < m < 1.0 for m in mass), mass
+    for reader, constant in (("loop_passes_per_layer", f'COUNTER = "{LOOP_LAYER_PASSES_FAMILY}"'),
+                             ("loop_head_positions_pct", f'COUNTER = "{HEAD_POSITIONS_FAMILY}"')):
+        with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", reader + ".py")) as f:
+            assert constant in f.read()
+
+
+OURO_READER_CHECKS = [
+    "the_looped_steps_shares_are_the_step_whole", "exit_share_gives_nothing_without_its_scope",
+    "passes_per_layer_of_hand_counts", "passes_per_layer_gives_nothing_without_the_series",
+    "loop_head_positions_of_hand_counts", "loop_head_positions_gives_nothing_without_the_series",
+    "the_kernels_inside_the_pass_loops_body_are_charged_to_the_mixers_scope",
+    "the_flash_rooflines_read_this_cells_shape",
+]
+
+
+@pytest.mark.parametrize("check", OURO_READER_CHECKS)
+def test_ouro_readers(check):
+    """The three readers the looped cell added, the scope charging inside the
+    pass loop's body and the accepted rooflines at the cell's shape, through
+    their own self-test, and the series two of them divide under the names the
+    LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import HEAD_POSITIONS_FAMILY, LOOP_LAYER_PASSES_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_ouro_readers", os.path.join(REPO, "benchmarks", "chip", "selftest", "ouro_readers.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_" + name for name in OURO_READER_CHECKS]
+    getattr(selftest, "test_" + check)()
+    assert (selftest.PASSES, selftest.HEAD) == (LOOP_LAYER_PASSES_FAMILY, HEAD_POSITIONS_FAMILY)
+
+
 # ---------------------------------------- the step named whole (PR 38)
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
@@ -1128,3 +1310,35 @@ def test_train_step_readers(check):
         "lakesoul.lm.optim", "lakesoul.lm.embed", "lakesoul.lm.head"
     )
     assert (selftest.PLACE, selftest.DISPATCH) == ("lakesoul.train.place", "lakesoul.train.dispatch")
+
+
+_PASS_LOOP = re.compile(r"jit\(step\)/(?:jvp\(\)|transpose\(jvp\(\)\))/while(?:/(?:body|cond)/(\w+))?$")
+
+
+def test_ouro_step_under_no_scope_holds_the_staged_constants_and_the_pass_loops_own_bookkeeping(
+    ouro_step_compiled_for_a_v5e,
+):
+    """What the looped program writes into its step under no scope, counted
+    as the other families' 14 / 4 / 0 / 10 are.  At the top, 7: the rotary's
+    ``theta ** ...``, the two position tables' negation, concatenations and
+    cast, and the zeros the pass loop's transpose starts its weight-gradient
+    sums from.  And the pass loop's OWN bookkeeping, which no family had: the
+    two ``while``s (forward, and the transpose that adds the shared weights'
+    gradients up), their counters, the ``dynamic_update_slice`` that stacks
+    what the backward pass keeps of a pass and the ``squeeze`` that reads it
+    back, the calls around the checkpointed bodies.  Everything a layer, the
+    norm between passes, the head and the objective compute stands under its
+    scope inside the loop's body (the case above)."""
+    text = ouro_step_compiled_for_a_v5e
+    scope_of = _adaptor("ouro_clm").scopes_of(text)
+    unscoped = _unscoped_written_by_the_program(text, scope_of)
+    loops = [name for name in unscoped if _PASS_LOOP.match(name)]
+    top = sorted(set(unscoped) - set(loops))
+    assert len([name for name in unscoped if name in top]) <= 7 and all(name.count("/") == 1 or ";" in name or
+                                                                        name.endswith("/broadcast_in_dim") for name in top), top
+    kinds = {_PASS_LOOP.match(name).group(1) for name in loops}
+    assert kinds <= {None, "add", "sub", "lt", "closed_call", "dynamic_update_slice", "squeeze"}, kinds
+    assert sum(name.endswith("/while") for name in loops) == 2  # the passes forward, and their transpose
+    assert 0 < len(loops) <= 36, sorted(loops)
+    by_scope = {s: sum(v == s for v in scope_of.values()) for s in ("lakesoul.lm.optim", "lakesoul.lm.embed")}
+    assert by_scope["lakesoul.lm.optim"] >= 10 and by_scope["lakesoul.lm.embed"] >= 2, by_scope

@@ -132,6 +132,7 @@ def _put_tiles(d):
 
 ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads, query heads each serves, head size
     "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256), "trinity-mini": (4, 8, 128),
+    "ouro": (16, 1, 128),
 }
 
 
@@ -161,25 +162,27 @@ def _flash_backward(d, window=None):
     ).trace(q, k, k, q, lse, q)
 
 
-def _operand_row(turned=True):
-    # a Trinity-Mini row's mixer (d is a vector width, not a shape of these kernels): the raw q, k, v as the
-    # products leave them, the norm weights, the position tables of a window layer (none on the full layer)
-    hkv, groups, d = ATTENTION_ROWS["trinity-mini"]
+def _operand_row(turned=True, family="trinity-mini", normed=True):
+    # a row's mixer (d is a vector width, not a shape of these kernels), Trinity-Mini's wherever no family is named:
+    # the raw q, k, v as the products leave them, the norm weights (none where the heads are not normed), the
+    # position tables of a layer that sees positions (none on Trinity-Mini's full layer)
+    hkv, groups, d = ATTENTION_ROWS[family]
     t, bf16 = 8192, jnp.bfloat16
     raw = [_sds((1, t, n * d), bf16) for n in (hkv * groups, hkv, hkv)]
     laid = [_sds((1, hkv, groups, t, d), bf16), _sds((1, hkv, t, d), bf16), _sds((1, hkv, t, d), bf16)]
     turn = (_sds((t, d)), _sds((t, d))) if turned else None
-    recipe = dict(eps=1e-5, bt=causal_lm._operand_tiles(t, hkv * groups, hkv, d, d if turned else None), interpret=False)
-    return raw, laid, [_sds((d,)), _sds((d,)), turn], recipe
+    weights = [_sds((d,)), _sds((d,))] if normed else [None, None]
+    recipe = dict(d=d, eps=1e-5, bt=causal_lm._operand_tiles(t, hkv * groups, hkv, d, d if turned else None), interpret=False)
+    return raw, laid, [*weights, turn], recipe
 
 
-def _operands_forward(d, turned=True):
-    raw, _, rest, recipe = _operand_row(turned)
+def _operands_forward(d, turned=True, **row):
+    raw, _, rest, recipe = _operand_row(turned, **row)
     return jax.jit(lambda *a: causal_lm._operands_forward(*a, **recipe)).trace(*raw, *rest)
 
 
-def _operands_backward(d, turned=True):
-    raw, laid, rest, recipe = _operand_row(turned)
+def _operands_backward(d, turned=True, **row):
+    raw, laid, rest, recipe = _operand_row(turned, **row)
     return jax.jit(lambda *a: causal_lm._operands_backward(*a, **recipe)).trace(*laid, *raw[:2], *rest)
 
 
@@ -218,11 +221,13 @@ def test_kernel_lowers_for_tpu(kernel, d):
 @pytest.mark.parametrize("family", sorted(ATTENTION_ROWS))
 @pytest.mark.parametrize("kernel", ["_flash_fwd_kernel", "_flash_bwd_kernel"])
 def test_attention_kernels_lower_at_every_published_shape(kernel, family):
-    """The four families' rows: groups of 4 at head 64, of 8 at head 256,
+    """The five families' rows: groups of 4 at head 64, of 8 at head 256,
     latent attention's 20 key-value heads of one query head each at head 256
-    (tiles of 512 queries x 512 keys), and groups of 8 at head 128."""
-    if family == "glm-4.7-flash":
-        assert causal_lm._flash_tiles(8192, 1, 256) == (512, 512)
+    (tiles of 512 queries x 512 keys), groups of 8 at head 128, and 16
+    key-value heads of one query head each at head 128 (512 x 512 again:
+    ``FLASH_ROWS`` is then queries alone)."""
+    if family in ("glm-4.7-flash", "ouro"):
+        assert causal_lm._flash_tiles(8192, 1, ATTENTION_ROWS[family][2]) == (512, 512)
     lowered = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](family).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
 
@@ -256,3 +261,23 @@ def test_operand_kernels_lower_with_and_without_positions(kernel, turned):
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
     assert ("tensor<8192x128xf32>" in text) == turned  # the tables
+
+
+@pytest.mark.parametrize("kernel", ["_operands_fwd_kernel", "_operands_bwd_kernel"])
+def test_operand_kernels_lower_without_head_norms(kernel):
+    """An Ouro row's mixer, 16 heads on 16 key-value heads at head 128,
+    positions over the whole head and NO norm over a head: blocks of 512
+    tokens of one head, a grid of 16 token blocks by 16 heads; the call reads
+    the two position tables and no norm weight, and the backward call neither
+    the raw query nor the raw key (turning back needs neither) and writes no
+    weight gradient's share."""
+    assert causal_lm._operand_tiles(8192, 16, 16, 128, 128) == 512
+    traced = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](128, family="ouro", normed=False)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    call = next(line for line in text.splitlines() if "tpu_custom_call" in line)
+    assert "tensor<8192x128xf32>" in call and "tensor<1x128xf32>" not in call  # the tables, no norm weight
+    operands = call[call.index("tpu_custom_call(") : call.index(")")].count("%")
+    assert operands == 5  # three arrays and the two tables, in either direction
+    assert ("x8x128xf32>" in call) is False  # no share of a weight gradient
+    normed = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](128).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tensor<1x128xf32>" in next(line for line in normed.splitlines() if "tpu_custom_call" in line)
