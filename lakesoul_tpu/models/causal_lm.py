@@ -79,6 +79,17 @@ head, turns it by its position, scales the query and lays heads before
 tokens, by the operand kernels where :func:`_operand_tiles` takes the shape;
 where the mixer's weights hold no ``q_norm`` and no ``k_norm`` (plain
 attention) neither path norms, a static flag of the kernels as ``turned`` is.
+The two sides of the flash pair speak two layouts: the operands arrive heads
+first and the three gradients go back so, the output leaves token-major,
+``[B, T, heads x D]`` as the gate and ``w_o`` read it.  Where
+:func:`_token_major` takes the shape (a head of whole 128-lane tiles: every
+published head but 64) that is the kernels' own block spec: the forward
+kernel stores each head of a group at its column slice of a ``bq``-token
+block, the backward kernel loads the cotangent and the kept output from
+there (once a query tile, and sums ``delta = sum(o * do)`` from them then),
+and no layout copy stands between the kernels and ``w_o`` in either pass;
+elsewhere the output is written heads first, ``delta`` is XLA's, and the
+output is transposed with ``jnp`` inside :func:`causal_attention`.
 
 The model may be one chip's share of an expert-parallel job: ``experts_held``
 says which of the ``num_experts`` live here (``parallel/moe.py: held_experts``)
@@ -232,6 +243,16 @@ def _flash_tiles(t: int, groups: int, d: int):
     return max(128, min(bk, FLASH_ROWS // groups)), bk
 
 
+def _token_major(t: int, groups: int, d: int) -> bool:
+    """Whether the flash kernels write the output, and read its cotangent,
+    token-major, [B, T, heads x D] as the gate and ``w_o`` read it: a shape
+    they take (:func:`_flash_tiles`) whose head is whole 128-lane tiles.  A
+    block of that array, ``bq`` tokens by a key-value head's ``G x D`` lanes,
+    is then a query tile of the group, each head at a lane-aligned column
+    slice; two heads of 64 would share a lane tile."""
+    return _flash_tiles(t, groups, d) is not None and d % 128 == 0
+
+
 def _first_key_tile(i, bq: int, bk: int, window: int | None):
     """The first key tile of query tile ``i`` (a Python or a traced integer):
     the one that holds the first key the tile's first query sees."""
@@ -311,12 +332,31 @@ def _flash_tile_kinds(tile, i, j, last, bq: int, bk: int, window: int | None, fi
         finish()
 
 
+def _group_rows(ref, groups: int, d: int):
+    """A group's query tile as the kernels multiply it, [G*bq, D] head-major,
+    from a token-major block [bq, G*D]: the heads' column slices, whole lane
+    tiles each, one under the other."""
+    return jnp.concatenate([ref[:, g * d:(g + 1) * d] for g in range(groups)], axis=0)
+
+
+def _store_group_rows(ref, x, groups: int, d: int):
+    """x [G*bq, D] head-major into a query tile's block, cast: heads first
+    [G, bq, D], or token-major [bq, G*D] (:func:`_group_rows` the other way)."""
+    if len(ref.shape) == 3:
+        ref[...] = x.reshape(ref.shape).astype(ref.dtype)
+        return
+    bq = ref.shape[0]
+    for g in range(groups):
+        ref[:, g * d:(g + 1) * d] = x[g * bq:(g + 1) * bq].astype(ref.dtype)
+
+
 def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, bq, bk,
                       window=None):
     """One step: a group's query tile [G, bq, D] against a key tile [bk, D].
     Running maximum and sum [G*bq, 128] (every lane the same) and the weighted
     values [G*bq, D] stay in VMEM over a query tile's steps; the last of them
-    divides and writes the output and the log-sum-exp [G, 1, bq]."""
+    divides and writes the output (heads first, or token-major where its block
+    is: :func:`_store_group_rows`) and the log-sum-exp [G, 1, bq]."""
     i, j, first, last = _flash_step(qi_ref, kj_ref, bq, bk, window)
     groups, _, d = q_ref.shape
     rows = groups * bq
@@ -343,7 +383,7 @@ def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref
 
     def finish():
         l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / _lanes(l, d)).reshape(groups, bq, d).astype(o_ref.dtype)
+        _store_group_rows(o_ref, acc_ref[...] / _lanes(l, d), groups, d)
         lse = (m_ref[...] + jnp.log(l)).T[:1]  # [1, G*bq]: a row's queries along the lanes
         for g in range(groups):
             lse_ref[g] = lse[:, g * bq:(g + 1) * bq]
@@ -351,14 +391,22 @@ def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref
     _flash_tile_kinds(tile, i, j, last, bq, bk, window, finish)
 
 
-def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, *, bq, bk, window=None):
+def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, with_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                      *assembled, bq, bk, window=None):
     """One step of the backward pass, on the forward kernel's grid: the scores
     of the tile again from q, k and the log-sum-exp, keys down the sublanes
     ([bk, G*bq]: the log-sum-exp and ``delta = sum(o * do)`` are rows, and dV
     and dK plain products), their share of dQ into VMEM until the query tile's
     last step, of dK and dV into the row's whole float32 dK, dV [T, D], which
-    stay in VMEM over all of a key-value head's steps."""
+    stay in VMEM over all of a key-value head's steps.
+
+    Heads first, ``do_ref`` [G, bq, D] is the operand as it lies and
+    ``with_ref`` holds ``delta`` [G, 1, bq], an XLA reduction.  Token-major
+    (``assembled``: two more buffers in VMEM), ``do_ref`` and ``with_ref`` are
+    the cotangent's and the kept output's blocks [bq, G*D], and a query tile's
+    first step lays the cotangent's heads one under the other
+    (:func:`_group_rows`) and sums ``delta`` from the two, once for all of the
+    tile's steps."""
     i, j, first, last = _flash_step(qi_ref, kj_ref, bq, bk, window)
     groups, _, d = q_ref.shape
     rows = groups * bq
@@ -372,12 +420,19 @@ def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delt
     @pl.when(j == first)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if assembled:
+            do_rows, delta_row = assembled
+            do = _group_rows(do_ref, groups, d)
+            do_rows[...] = do
+            delta = jnp.sum(_group_rows(with_ref, groups, d).astype(f32) * do.astype(f32), axis=1, keepdims=True)
+            delta_row[...] = jnp.broadcast_to(delta, (rows, 128)).T[:1]  # [1, G*bq]: a row's queries along the lanes
 
     def tile(mask):
-        q, do = q_ref[...].reshape(rows, d), do_ref[...].reshape(rows, d)
+        q = q_ref[...].reshape(rows, d)
+        do = assembled[0][...] if assembled else do_ref[...].reshape(rows, d)
         k, v = k_ref[...], v_ref[...]
         lse = jnp.concatenate([lse_ref[g] for g in range(groups)], axis=1)
-        delta = jnp.concatenate([delta_ref[g] for g in range(groups)], axis=1)
+        delta = assembled[1][...] if assembled else jnp.concatenate([with_ref[g] for g in range(groups)], axis=1)
         s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
         if mask is not None:
             s = jnp.where(mask(s.shape, 1), s, MASKED)
@@ -395,12 +450,15 @@ def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delt
     _flash_tile_kinds(tile, i, j, last, bq, bk, window, finish)
 
 
-def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_shapes):
+def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_shapes, batch: int | None = None):
     """What the two kernels' ``pallas_call``s share, over q's [N, G, T, D]:
     the grid (key-value heads, steps of :func:`_flash_steps`) with the two
     tables in SMEM, and block specs by what a block follows: a query tile's
     [G, bq, D], its per-query floats [G, 1, bq], a key tile's [bk, D], a
     key-value head's whole [T, D]; ``in_specs`` and ``out_specs`` name those.
+    With ``batch`` (the ``N`` key-value heads are those of ``batch`` rows)
+    also ``tokens``, the query tile in a token-major array [batch, T, heads x
+    D]: [bq, G*D] at the row's tokens and the key-value head's columns.
     Returns (the tables, the call's keyword arguments)."""
     n, groups, t, d = q.shape
     tables = _flash_steps(t, bq, bk, window)
@@ -410,6 +468,9 @@ def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_sha
         "keys": pl.BlockSpec((None, bk, d), lambda h, s, qi, kj: (h, kj[s], 0)),
         "whole_row": pl.BlockSpec((None, t, d), lambda h, s, qi, kj: (h, 0, 0)),
     }
+    if batch is not None:
+        kv = n // batch
+        specs["tokens"] = pl.BlockSpec((None, bq, groups * d), lambda h, s, qi, kj: (h // kv, qi[s], h % kv))
     return tables, dict(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(n, tables[0].shape[0]), scratch_shapes=scratch_shapes,
@@ -421,53 +482,71 @@ def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_sha
     )
 
 
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "interpret"))
-def _flash_forward(q, k, v, *, bq: int, bk: int, window: int | None = None, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "batch", "interpret"))
+def _flash_forward(q, k, v, *, bq: int, bk: int, window: int | None = None, batch: int | None = None, interpret: bool):
     """q [N, G, T, D], k, v [N, T, D] → (o [N, G, T, D], log-sum-exp
-    [N, G, 1, T] float32)."""
+    [N, G, 1, T] float32).  With ``batch`` (:func:`_token_major` shapes: the
+    ``N`` key-value heads are ``batch`` rows') o is written token-major,
+    [batch, T, heads x D] with a key-value head's group side by side: the
+    same values at the addresses the gate and ``w_o`` read."""
     n, groups, t, d = q.shape
     rows = groups * bq
     tables, grid = _flash_grid(
-        q, bq, bk, window, in_specs=("query", "keys", "keys"), out_specs=("query", "per_query"),
+        q, bq, bk, window, in_specs=("query", "keys", "keys"), batch=batch,
+        out_specs=("query" if batch is None else "tokens", "per_query"),
         scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32)] * 2 + [pltpu.VMEM((rows, d), jnp.float32)],
     )
+    o_shape = q.shape if batch is None else (batch, t, n // batch * groups * d)
     return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, bq=bq, bk=bk, window=window),
-        out_shape=(jax.ShapeDtypeStruct(q.shape, v.dtype), jax.ShapeDtypeStruct((n, groups, 1, t), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct(o_shape, v.dtype), jax.ShapeDtypeStruct((n, groups, 1, t), jnp.float32)),
         name="flash_attention_fwd", interpret=interpret, **grid,
     )(*tables, q, k, v)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "interpret"))
 def _flash_backward(q, k, v, o, lse, do, *, bq: int, bk: int, window: int | None = None, interpret: bool):
-    """The three gradients, dK and dV summed over the group."""
+    """The three gradients, dK and dV summed over the group; ``o`` and ``do``
+    as :func:`_flash_forward` wrote ``o``.  Heads first, [N, G, T, D]:
+    ``delta = sum(o * do)`` is an XLA reduction and an operand of the kernel.
+    Token-major, [B, T, heads x D]: the kernel reads both through the output's
+    block spec and sums ``delta`` itself (as an XLA reduction over token-major
+    arrays its [T, heads] result wants relaying into [N, G, 1, T], and XLA
+    writes the float32 products out whole to do that)."""
     _, groups, _, d = q.shape
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, :, None, :]
+    rows = groups * bq
+    scratch = [pltpu.VMEM((rows, d), jnp.float32)]
+    if o.ndim == 3:
+        batch, given, do_spec, given_spec = o.shape[0], o, "tokens", "tokens"
+        scratch += [pltpu.VMEM((rows, d), do.dtype), pltpu.VMEM((1, rows), jnp.float32)]
+    else:
+        batch, do_spec, given_spec = None, "query", "per_query"
+        given = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, :, None, :]
     tables, grid = _flash_grid(
-        q, bq, bk, window, in_specs=("query", "keys", "keys", "query", "per_query", "per_query"),
-        out_specs=("query", "whole_row", "whole_row"), scratch_shapes=[pltpu.VMEM((groups * bq, d), jnp.float32)],
+        q, bq, bk, window, batch=batch, in_specs=("query", "keys", "keys", do_spec, "per_query", given_spec),
+        out_specs=("query", "whole_row", "whole_row"), scratch_shapes=scratch,
     )
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, bq=bq, bk=bk, window=window),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), *[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2),
         name="flash_attention_bwd", interpret=interpret, **grid,
-    )(*tables, q, k, v, do, lse, delta)
+    )(*tables, q, k, v, do, lse, given)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention(q, k, v, bq, bk, window):
-    return _flash_attention_fwd(q, k, v, bq, bk, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, bq, bk, window, batch):
+    return _flash_attention_fwd(q, k, v, bq, bk, window, batch)[0]
 
 
-def _flash_attention_fwd(q, k, v, bq, bk, window):
-    o, lse = _flash_forward(q, k, v, bq=bq, bk=bk, window=window, interpret=not _on_tpu())
+def _flash_attention_fwd(q, k, v, bq, bk, window, batch):
+    o, lse = _flash_forward(q, k, v, bq=bq, bk=bk, window=window, batch=batch, interpret=not _on_tpu())
     # a checkpoint around the caller may keep these two and run no second forward kernel
     o, lse = checkpoint_name(o, ATTN_KEPT[0]), checkpoint_name(lse, ATTN_KEPT[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_attention_bwd(bq, bk, window, kept, do):
+def _flash_attention_bwd(bq, bk, window, batch, kept, do):
     return _flash_backward(*kept, do, bq=bq, bk=bk, window=window, interpret=not _on_tpu())
 
 
@@ -729,7 +808,10 @@ def mixer_counts(mixer, x, p) -> dict:
     ``attn_tiles_run`` and ``attn_tiles_causal``, the (query tile, key tile)
     steps the attention kernels' lists hold over its rows and key-value heads
     and the steps causal lists alone would hold (:func:`key_tile_steps`; 0 for
-    a shape the kernels do not take); ``attn_operands_kernel`` and
+    a shape the kernels do not take); ``attn_out_tokens`` and
+    ``attn_out_heads``, its rows by where the attention wrote its output:
+    token-major through the kernels' block specs (:func:`_token_major`), or
+    heads first and transposed after; ``attn_operands_kernel`` and
     ``attn_operands_xla``, its rows by what made the flash kernels' operands,
     the operand kernels or the ``jnp`` lines (:func:`_operand_tiles`).  All 0
     for a mixer without attention."""
@@ -739,7 +821,9 @@ def mixer_counts(mixer, x, p) -> dict:
     finally:
         del _traced_tiles.steps, _traced_tiles.operands
     return {
-        "attn_tiles_run": sum(n for n, _ in steps), "attn_tiles_causal": sum(n for _, n in steps),
+        "attn_tiles_run": sum(run for run, *_ in steps), "attn_tiles_causal": sum(causal for _, causal, *_ in steps),
+        "attn_out_tokens": sum(rows for *_, rows, tokens in steps if tokens),
+        "attn_out_heads": sum(rows for *_, rows, tokens in steps if not tokens),
         "attn_operands_kernel": sum(rows for rows, fused in operands if fused),
         "attn_operands_xla": sum(rows for rows, fused in operands if not fused),
     }
@@ -747,7 +831,9 @@ def mixer_counts(mixer, x, p) -> dict:
 
 def causal_attention(q, k, v, window: int | None = None):
     """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
-    (scaled), k, v [B, Hkv, T, D] → [B, Hkv, G, T, D].  Two masks: key ``j`` is
+    (scaled), k, v [B, Hkv, T, D] → [B, T, heads, D], tokens before heads as
+    the output projection reads it, a key-value head's group side by side
+    (head ``kv * G + g``).  Two masks: key ``j`` is
     visible to query ``i`` iff ``j <= i`` and, under a ``window``,
     ``i - j < window`` (the query's own position and the ``window - 1`` before
     it); a window of the row's length or more is no window.
@@ -764,19 +850,32 @@ def causal_attention(q, k, v, window: int | None = None):
     backward pass keeps the output and the log-sum-exp, named
     :data:`ATTN_KEPT` for a checkpoint around the caller, and computes the
     scores again from q, k and the log-sum-exp in float32.  Every other shape
-    runs :func:`_blockwise_attention`."""
+    runs :func:`_blockwise_attention`.
+
+    The operands come heads first and the three gradients go back so.  The
+    output is the other way round: where :func:`_token_major` takes the shape
+    (a head of whole 128-lane tiles) the forward kernel's output block spec
+    writes it token-major and the backward kernel's reads its cotangent and
+    the kept output there (and sums ``delta`` from them), so no layout copy
+    stands between the kernels and ``w_o`` in either pass; a head of 64, and
+    the blockwise path, write heads first and the transpose here is a copy."""
     b, hkv, groups, t, d = q.shape
     if window is not None and window >= t:
         window = None
+    tokens = _token_major(t, groups, d)
     if hasattr(_traced_tiles, "steps"):  # :func:`mixer_counts` is tracing the caller
-        _traced_tiles.steps.append([b * hkv * n for n in key_tile_steps(t, groups, d, window)])
+        _traced_tiles.steps.append((*(b * hkv * n for n in key_tile_steps(t, groups, d, window)), b, tokens))
     tiles = _flash_tiles(t, groups, d)
     if tiles is None:
-        return _blockwise_attention(q, k, v, ATTN_BAND, ATTN_ROWS, window)
-    o = _flash_attention(
-        q.reshape(b * hkv, groups, t, d), *(a.reshape(b * hkv, t, d) for a in (k, v)), *tiles, window
-    )
-    return o.reshape(q.shape)
+        o = _blockwise_attention(q, k, v, ATTN_BAND, ATTN_ROWS, window)
+    else:
+        o = _flash_attention(
+            q.reshape(b * hkv, groups, t, d), *(a.reshape(b * hkv, t, d) for a in (k, v)), *tiles, window,
+            b if tokens else None,
+        )
+    if not tokens:  # heads first: laid out for the projection here, by copies
+        o = o.reshape(q.shape).transpose(0, 3, 1, 2, 4)
+    return o.reshape(b, t, hkv * groups, d)
 
 
 def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_dim: int | None,
@@ -817,11 +916,12 @@ def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_
         q, k, v = _xla_operands(q, k, v, p.get("q_norm"), p.get("k_norm"), **recipe)
     else:
         q, k, v = _kernel_operands(q, k, v, p.get("q_norm"), p.get("k_norm"), bt, **recipe)
-    o = causal_attention(q, k, v, window)
-    o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
+    # the gate and ``w_o`` over [B, T, heads x D], no head axis: on [.., heads, D] arrays XLA lays the gate's
+    # passes tokens-minor for the weight-gradient products and copies the kernels' row-major o and do across
+    o = causal_attention(q, k, v, window).reshape(b, t, heads * d)
     if gate is not None:
-        o = (o.astype(f32) * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
-    return o.reshape(b, t, heads * d) @ p["w_o"].astype(dtype)
+        o = (o.astype(f32) * jax.nn.sigmoid(gate.reshape(b, t, heads * d).astype(f32))).astype(dtype)
+    return o @ p["w_o"].astype(dtype)
 
 
 def latent_attention(x, p, *, heads: int, nope_dim: int, rope_dim: int, theta: float, norm):
@@ -858,8 +958,7 @@ def latent_attention(x, p, *, heads: int, nope_dim: int, rope_dim: int, theta: f
     # [B, T, heads, D] → [B, heads, 1, T, D]: every head has its own keys and values
     q = q.transpose(0, 2, 1, 3)[:, :, None]
     k, v = (a.transpose(0, 2, 1, 3) for a in (k, v))
-    o = causal_attention(q, k, v)
-    return o[:, :, 0].transpose(0, 2, 1, 3).reshape(b, t, -1) @ p["w_o"].astype(dtype)
+    return causal_attention(q, k, v).reshape(b, t, -1) @ p["w_o"].astype(dtype)
 
 
 # ------------------------------------------------------------- the stack
@@ -1138,12 +1237,14 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     module's among them), ``tokens``, and the positions with a label,
     ``head_all`` over both losses and ``head_mtp`` the module's (0 without
     one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32; and
-    four Python integers, known when the step is traced and no operation of it
+    six Python integers, known when the step is traced and no operation of it
     (:func:`mixer_counts`): ``attn_tiles_run`` and ``attn_tiles_causal`` (the
     attention kernels' grid steps and what causal lists alone would hold, over
     rows, layers and key-value heads: equal without a window),
-    ``attn_operands_kernel`` and ``attn_operands_xla`` (softmax-attention
-    layer-rows by what made the kernels' operands)."""
+    ``attn_out_tokens`` and ``attn_out_heads`` (attention layer-rows by where
+    the output was written), ``attn_operands_kernel`` and
+    ``attn_operands_xla`` (softmax-attention layer-rows by what made the
+    kernels' operands)."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
     with jax.named_scope(HEAD_SCOPE):  # the loss's own loop over tiles of positions around the head
         loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)

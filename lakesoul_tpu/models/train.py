@@ -94,6 +94,7 @@ MOE_ASSIGNMENTS_FAMILY = "lakesoul_train_moe_assignments_total"
 MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
 ATTN_KEY_TILES_FAMILY = "lakesoul_train_attn_key_tiles_total"
 ATTN_OPERAND_ROWS_FAMILY = "lakesoul_train_attn_operand_rows_total"
+ATTN_OUTPUT_ROWS_FAMILY = "lakesoul_train_attn_output_rows_total"
 # a looped model's step (``cfg.loop_passes``): ``{kind="run"}`` the layer passes it ran, rows x layers x
 # passes, over ``{kind="layers"}``, rows x layers: a change that skips a pass or exits early moves the ratio
 LOOP_LAYER_PASSES_FAMILY = "lakesoul_train_loop_layer_passes_total"
@@ -377,7 +378,13 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     step's softmax-attention layer-rows by what made the attention kernels'
     operands: the operand kernels in one pass where ``models/causal_lm.py:
     _operand_tiles`` takes the mixer's shape, else the ``jnp`` lines; both 0
-    for a family whose mixers are not ``softmax_attention``).
+    for a family whose mixers are not ``softmax_attention``) and
+    ``lakesoul_train_attn_output_rows_total{layout="tokens"|"heads"}`` (the
+    step's attention layer-rows by where the attention wrote its output:
+    token-major through the flash kernels' block specs where
+    ``models/causal_lm.py: _token_major`` takes the shape, a head of whole
+    128-lane tiles, so that nothing stands between the kernels and the output
+    projection; else heads first, and a transpose lays it out).
 
     A looped family (``cfg.loop_passes``: the stack run that many times over
     one set of weights, a loss after every pass) also feeds
@@ -391,8 +398,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
-    host_keys = ("attn_tiles_run", "attn_tiles_causal", "attn_operands_kernel", "attn_operands_xla",
-                 "loop_layers_run", "loop_layers")
+    host_keys = ("attn_tiles_run", "attn_tiles_causal", "attn_out_tokens", "attn_out_heads",
+                 "attn_operands_kernel", "attn_operands_xla", "loop_layers_run", "loop_layers")
     held = getattr(cfg, "experts_held", None)
     if held is None:  # a family without experts: its loss returns none of their counts, and they count 0
         host_keys += ("moe_held", "moe_all", "moe_tile_rows", "moe_bias_moved", "moe_dw_writes", "moe_load_max")
@@ -420,6 +427,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / held[1] if held else 0),
         ("attn_tiles_run", ATTN_KEY_TILES_FAMILY, {"kind": "run"}, 1),
         ("attn_tiles_causal", ATTN_KEY_TILES_FAMILY, {"kind": "causal"}, 1),
+        ("attn_out_tokens", ATTN_OUTPUT_ROWS_FAMILY, {"layout": "tokens"}, 1),
+        ("attn_out_heads", ATTN_OUTPUT_ROWS_FAMILY, {"layout": "heads"}, 1),
         ("attn_operands_kernel", ATTN_OPERAND_ROWS_FAMILY, {"path": "kernel"}, 1),
         ("attn_operands_xla", ATTN_OPERAND_ROWS_FAMILY, {"path": "xla"}, 1),
         ("head_loop", HEAD_POSITIONS_FAMILY, {"kind": "loop"}, 1),

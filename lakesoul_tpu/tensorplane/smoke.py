@@ -371,13 +371,17 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
         _flash_backward,
         _flash_forward,
         _flash_tiles,
+        _token_major,
     )
 
     # a row of each causal-LM cell's attention layer (key-value heads, query
     # heads each serves, head size), 8,192 tokens at deployed sizes (one
     # key-value head of 256 at tiny ones): output
     # and the three gradients against the blockwise twin, operands bfloat16
-    # as the models pass them.  Both sides round the same float32 softmax to
+    # as the models pass them; the output and its cotangent token-major,
+    # [1, T, heads x D] through the kernels' block specs, where the head is
+    # whole lane tiles (every shape here but the first), the twin's output
+    # transposed to that.  Both sides round the same float32 softmax to
     # bfloat16 (the probabilities, the output), each with its own maximum at
     # the time of rounding, so they agree to a few bfloat16 roundings by
     # norm (measured on a v5e at 8,192 tokens: output 1.2e-3, gradients
@@ -394,13 +398,17 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
         keys = jax.random.split(jax.random.key(9), 4)
         q = (jax.random.normal(keys[0], (hkv, groups, t, d)) * d**-0.5).astype(jnp.bfloat16)
         k, v = (jax.random.normal(key, (hkv, t, d)).astype(jnp.bfloat16) for key in keys[1:3])
-        do = jax.random.normal(keys[3], q.shape).astype(jnp.bfloat16)
         tiles = dict(zip(("bq", "bk"), _flash_tiles(t, groups, d), strict=True))
-        o, lse = _flash_forward(q, k, v, **tiles, window=window, interpret=interpret)
+        batch = 1 if _token_major(t, groups, d) else None
+        o, lse = _flash_forward(q, k, v, **tiles, window=window, batch=batch, interpret=interpret)
+        do = jax.random.normal(keys[3], o.shape).astype(jnp.bfloat16)
         got = (o, *_flash_backward(q, k, v, o, lse, do, **tiles, window=window, interpret=interpret))
-        o_twin, pull = jax.vjp(
-            lambda *qkv: _blockwise_attention(*(a[None] for a in qkv), ATTN_BAND, ATTN_ROWS, window)[0], q, k, v
-        )
+
+        def twin(*qkv):
+            out = _blockwise_attention(*(a[None] for a in qkv), ATTN_BAND, ATTN_ROWS, window)
+            return out[0] if batch is None else out.transpose(0, 3, 1, 2, 4).reshape(o.shape)
+
+        o_twin, pull = jax.vjp(twin, q, k, v)
         errors = []
         for a, b in zip(got, (o_twin, *pull(do)), strict=True):
             a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernels' results against the blockwise twin's
@@ -408,7 +416,8 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
         if not max(errors) < 1e-2:
             raise AssertionError(f"flash attention at {(hkv, groups, d, window)}: o, dq, dk, dv off by {errors}")
         detail[f"group{groups}.head{d}" + (f".window{window}" if window else "")] = {
-            "tiles": list(tiles.values()), "rel_err": [round(e, 5) for e in errors]
+            "tiles": list(tiles.values()), "output": "heads" if batch is None else "tokens",
+            "rel_err": [round(e, 5) for e in errors],
         }
     return detail
 

@@ -252,7 +252,7 @@ def test_the_window_mixer_at_a_head_of_64_takes_the_flash_kernels_on_a_banded_ti
     x = hidden(5, 512, rows=1)
     mixer = cfg.mixer("swa")[0]
     assert_close(mixer(x, p), ref.attention(x, p, KERNELS, SWA))
-    assert calls == [((2, 2, 512, 64), {"bq": 128, "bk": 128, "window": 200, "interpret": True})]
+    assert calls == [((2, 2, 512, 64), {"bq": 128, "bk": 128, "window": 200, "batch": None, "interpret": True})]  # a head of 64: heads first
     weigh = jax.random.normal(jax.random.key(6), x.shape)
     assert_close(
         jax.jit(jax.grad(lambda p, x: jnp.sum(weigh * mixer(x, p)), argnums=(0, 1)))(p, x),
@@ -384,7 +384,8 @@ def test_a_layer_norms_each_sublayers_output_before_the_residual_add(params, lay
     assert_close(got, ref.layer(x, lp, buffers, MODEL["layer_types"][layer], MODEL, HELD))
     assert (counts is not None) == (ffn == "moe")
     assert causal_lm.layer_attention_counts(CFG, kind, x, lp[kind]) == {  # 150 tokens: neither kernel pair takes the row
-        "attn_tiles_run": 0, "attn_tiles_causal": 0, "attn_operands_kernel": 0, "attn_operands_xla": x.shape[0]}
+        "attn_tiles_run": 0, "attn_tiles_causal": 0, "attn_out_tokens": 0, "attn_out_heads": x.shape[0],
+        "attn_operands_kernel": 0, "attn_operands_xla": x.shape[0]}
     for name in ("norm1_out", "norm2_out"):
         scaled = lp | {name: 2 * lp[name]}
         doubled, _ = run(x, scaled, buffers)

@@ -5,12 +5,16 @@ installed here).
 
 The configuration file's rule (``assumed.per_chip_batch``, PR 36's): the
 largest of 4, 3, 2, 1 rows of 8,192 tokens that leaves at least 0.5 GB of a
-v5e's 15.75.  Two rows read 14.26 GB and are taken; three read 15.81 and are
+v5e's 15.75.  Two rows read 14.43 GB and are taken; three read 15.83 and are
 refused (they do not fit the chip at all).  Before the operand kernels
 (``causal_lm.py: _operand_tiles``) the two read 14.86 and 16.01: the float32
 ``[8192, 32, 128]`` temporaries of the head norms and the rotary went, and
-nothing new is kept across the mixers' checkpoint.  The compile also holds both
-attention kernels, with and without the window, and both operand kernels, with
+nothing new is kept across the mixers' checkpoint.  14.26 while the gate's
+product ran over ``[.., heads, D]`` arrays: over ``[.., heads x D]``, the
+layout the flash kernels write the output in, XLA's schedule holds 0.18 GB
+more of the gate's temporaries at once (the chip's own peak, the benchmark's
+``peak_hbm_gb``, fell by 6 MB with the same change).  The compile also holds
+both attention kernels, with and without the window, and both operand kernels, with
 and without positions, to Mosaic's rules at the cell's shapes (groups of 8 at
 head 128, 8,192 tokens).  A file of its own: the
 suite runs ``--dist loadfile`` and each case compiles for most of a minute.
@@ -92,8 +96,8 @@ def _step_gb(rows: int) -> dict:
 
 @pytest.mark.parametrize("rows", [2, 3])
 def test_the_cells_batch_is_the_largest_that_leaves_half_a_gigabyte(rows):
-    """Two rows fit with room (14.26 GB: 8.466 of arguments, 5.564 of scratch,
-    0.229 of code: 1.49 GB free); three do not fit the chip (15.81: 7.112 of
+    """Two rows fit with room (14.43 GB: 8.466 of arguments, 5.745 of scratch,
+    0.222 of code: 1.32 GB free); three do not fit the chip (15.83: 7.134 of
     scratch).  The cell runs the batch the rule gives."""
     cell = _bench_file("workloads", "trinity_mini_clm_pk.seq8k_mor_stream")
     gb = _step_gb(rows)
@@ -101,7 +105,7 @@ def test_the_cells_batch_is_the_largest_that_leaves_half_a_gigabyte(rows):
     assert gb["outputs_not_aliased"] < 0.001                    # the state is donated
     fits = gb["total"] <= CHIP_GB - FREE_GB
     if rows == 2:
-        assert gb["total"] == pytest.approx(14.26, abs=0.15) and fits, gb
+        assert gb["total"] == pytest.approx(14.43, abs=0.15) and fits, gb
     else:
-        assert gb["total"] == pytest.approx(15.81, abs=0.15) and CHIP_GB < gb["total"] and not fits, gb
+        assert gb["total"] == pytest.approx(15.83, abs=0.15) and CHIP_GB < gb["total"] and not fits, gb
     assert (rows <= cell["per_chip_batch"]) == fits

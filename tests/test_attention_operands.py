@@ -163,3 +163,107 @@ def _mixer_weights(cfg, layer: int, kind: str):
     the family's ``init`` is traced for one leading slice of the stack."""
     shapes = jax.eval_shape(cfg.init, jax.random.key(0))
     return shapes["layers"][layer][kind]
+
+
+# ------------------------------------------------- the output side of the flash pair
+
+
+def _published(family):
+    from lakesoul_tpu.models import afmoe, glm4_moe_lite, lfm2_moe, ouro, qwen3_next
+
+    return {
+        "qwen3-next": (qwen3_next.Qwen3NextConfig, ("attn",)),
+        "glm-4.7-flash": (glm4_moe_lite.Glm4MoeLiteConfig, ("mla",)),
+        "lfm2": (lfm2_moe.Lfm2MoeConfig, ("attn",)),
+        "trinity-mini": (afmoe.AfmoeConfig, ("swa", "attn")),
+        "ouro": (ouro.OuroConfig, ("attn",)),
+    }[family]
+
+
+def _named(eqn, name: str) -> bool:
+    return eqn.primitive.name in ("pjit", "jit") and eqn.params.get("name") == name
+
+
+def _around_the_flash_pair(mixer, x, weights) -> tuple[set[str], set[str]]:
+    """The primitives of ``mixer``'s value-and-gradient jaxpr on the data's way
+    (from the forward kernels' output on to the first matrix product, the
+    output projection's and its transposes'; from the backward kernels'
+    cotangent operand back to the first matrix product)."""
+    def pulled(x, p, cot):
+        return jax.vjp(mixer, x, p)[1](cot)
+
+    eqns = jax.make_jaxpr(pulled)(x, weights, jax.eval_shape(mixer, x, weights)).jaxpr.eqns
+    (forward,) = [e for e in eqns if _named(e, "_flash_forward")]
+    (backward,) = [e for e in eqns if _named(e, "_flash_backward")]
+    after, reached = set(), {forward.outvars[0]}
+    for eqn in eqns[eqns.index(forward) + 1:]:
+        if not any(v in reached for v in eqn.invars if not hasattr(v, "val")):
+            continue
+        if eqn.primitive.name == "dot_general" or eqn is backward:
+            continue
+        after.add(eqn.primitive.name)
+        reached.update(eqn.outvars)
+    before, wanted = set(), {backward.invars[5]}  # q, k, v, o, the log-sum-exp, do
+    for eqn in reversed(eqns[:eqns.index(backward)]):
+        if not any(v in wanted for v in eqn.outvars) or eqn.primitive.name == "dot_general":
+            continue
+        before.add(eqn.primitive.name)
+        wanted.update(v for v in eqn.invars if not hasattr(v, "val"))
+    return after, before
+
+
+@pytest.mark.parametrize("family", ["qwen3-next", "glm-4.7-flash", "lfm2", "trinity-mini", "ouro"])
+def test_no_transpose_stands_between_the_flash_pair_and_the_output_projection(family):
+    """At the published widths and 8,192 tokens, from an abstract trace: where
+    a head is whole lane tiles (every family but LFM2's head of 64) the forward
+    kernels' output reaches ``w_o``, through the gate where there is one, and
+    the cotangent reaches the backward kernels from ``w_o``'s transpose,
+    through reshapes, casts and the gate's products alone; the LFM2 mixer keeps
+    its transposes on both ways."""
+    config, kinds = _published(family)
+    cfg = config()
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16)
+    for kind in kinds:
+        mixer = cfg.mixer(kind)[0]
+        weights = _mixer_weights(cfg, cfg.layer_kinds().index(kind), kind)
+        after, before = _around_the_flash_pair(mixer, x, weights)
+        counts = causal_lm.mixer_counts(mixer, x, weights)
+        if family == "lfm2":
+            assert "transpose" in after and "transpose" in before
+            assert (counts["attn_out_tokens"], counts["attn_out_heads"]) == (0, 1)
+        else:
+            assert "transpose" not in after | before, (kind, after, before)
+            assert "reshape" in after and "reshape" in before  # the walk did go from the kernels to the products
+            assert (counts["attn_out_tokens"], counts["attn_out_heads"]) == (1, 0)
+
+
+def test_the_lfm2_mixer_is_the_program_it_was():
+    """A head of 64: ``softmax_attention`` over ``causal_attention`` traces to
+    the operations it ran when the transpose was the caller's: the jaxpr of
+    value and gradient, at the published widths, equal as text to the mixer
+    written out the old way from the same pieces."""
+    config, _ = _published("lfm2")
+    cfg = config()
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16)
+    weights = _mixer_weights(cfg, cfg.layer_kinds().index("attn"), "attn")
+    heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    assert d == 64 and not causal_lm._token_major(8192, heads // kv, d)
+
+    def as_it_was(x, p):
+        dtype = x.dtype
+        b, t, _ = x.shape
+        q, k, v = ((x @ p[w].astype(dtype)).reshape(b, t, n, d) for w, n in (("w_q", heads), ("w_k", kv), ("w_v", kv)))
+        q, k, v = causal_lm._xla_operands(
+            q, k, v, p["q_norm"], p["k_norm"], eps=cfg.norm_eps, centred=False, rotary_dim=d, theta=cfg.rope_theta
+        )
+        o = causal_lm._flash_attention(
+            q.reshape(b * kv, heads // kv, t, d), *(a.reshape(b * kv, t, d) for a in (k, v)),
+            *causal_lm._flash_tiles(t, heads // kv, d), None, None,
+        ).reshape(q.shape)
+        o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
+        return o.reshape(b, t, heads * d) @ p["w_o"].astype(dtype)
+
+    def text(mixer):
+        return str(jax.make_jaxpr(lambda x, p, cot: jax.vjp(mixer, x, p)[1](cot))(x, weights, x))
+
+    assert text(cfg.mixer("attn")[0]) == text(as_it_was)

@@ -2,11 +2,16 @@
 interpreter here) against the plain masked softmax and against their blockwise
 twin, forward and every gradient, at the five published group and head sizes,
 under the causal mask and under a window; the tile lists; which shapes take
-the kernels; what a checkpoint around the caller keeps; and the mixer's recipe
+the kernels; where the output lives (token-major through the kernels' block
+specs where a head is whole lane tiles: the heads-first kernels' bits at other
+addresses); what a checkpoint around the caller keeps; and the mixer's recipe
 WITHOUT head norms (the Ouro family's plain attention) on both operand paths.
 """
 
 from __future__ import annotations
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,14 +38,32 @@ def plain_attention(q, k, v, window=None):
     s = jnp.einsum("bhgqd,bhkd->bhgqk", q.astype(f32), k.astype(f32))
     back = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
     s = jnp.where((back >= 0) if window is None else (back >= 0) & (back < window), s, -jnp.inf)
-    return jnp.einsum("bhgqk,bhkd->bhgqd", jax.nn.softmax(s, axis=-1), v.astype(f32))
+    return tokens_first(jnp.einsum("bhgqk,bhkd->bhgqd", jax.nn.softmax(s, axis=-1), v.astype(f32)))
 
 
-def operands(groups, d, t, dtype, *, kv_heads=1):
+def tokens_first(o):
+    """[B, Hkv, G, T, D] → [B, T, heads, D]: ``causal_attention``'s output
+    layout, by the transpose its callers ran before it wrote that itself."""
+    b, hkv, groups, t, d = o.shape
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, t, hkv * groups, d)
+
+
+def transposed_sizes(jaxpr_text: str) -> list[int]:
+    """The element counts of every array a ``transpose`` in the text writes."""
+    return [math.prod(int(n) for n in shape.split(",")) for shape in
+            re.findall(r"\w+:\w+\[([\d,]+)\] = transpose\[", jaxpr_text)]
+
+
+def blockwise(q, k, v, band=256, rows=64, window=None):
+    return tokens_first(causal_lm._blockwise_attention(q, k, v, band, rows, window))
+
+
+def operands(groups, d, t, dtype, *, kv_heads=1, rows=1):
+    """q, k, v heads first and a cotangent of the output, tokens first."""
     keys = jax.random.split(jax.random.key(t + d), 4)
-    q = (jax.random.normal(keys[0], (1, kv_heads, groups, t, d)) * d**-0.5).astype(dtype)
-    k, v = (jax.random.normal(key, (1, kv_heads, t, d)).astype(dtype) for key in keys[1:3])
-    return q, k, v, jax.random.normal(keys[3], q.shape)
+    q = (jax.random.normal(keys[0], (rows, kv_heads, groups, t, d)) * d**-0.5).astype(dtype)
+    k, v = (jax.random.normal(key, (rows, kv_heads, t, d)).astype(dtype) for key in keys[1:3])
+    return q, k, v, jax.random.normal(keys[3], (rows, t, kv_heads * groups, d))
 
 
 def out_and_grads(fn, q, k, v, weigh):
@@ -78,7 +101,7 @@ def test_flash_kernels_equal_the_masked_softmax_and_their_twin(monkeypatch, fami
     q, k, v, weigh = operands(groups, d, t, dtype)
     got = out_and_grads(causal_lm.causal_attention, q, k, v, weigh)
     want = out_and_grads(plain_attention, q, k, v, weigh)
-    twin = out_and_grads(lambda *qkv: causal_lm._blockwise_attention(*qkv, 256, 64), q, k, v, weigh)
+    twin = out_and_grads(blockwise, q, k, v, weigh)
     assert_close(got, want, tol)
     assert_close(got, twin, tol)
     assert got[0].dtype == v.dtype and got[1][0].dtype == q.dtype and got[1][1].dtype == k.dtype
@@ -103,9 +126,9 @@ def test_window_kernels_equal_the_masked_softmax_and_their_twin(monkeypatch, fam
     q, k, v, weigh = operands(groups, d, t, jnp.float32)
     got = out_and_grads(lambda *qkv: causal_lm.causal_attention(*qkv, window), q, k, v, weigh)
     want = out_and_grads(lambda *qkv: plain_attention(*qkv, window), q, k, v, weigh)
-    twin = out_and_grads(lambda *qkv: causal_lm._blockwise_attention(*qkv, 256, 64, window), q, k, v, weigh)
+    twin = out_and_grads(lambda *qkv: blockwise(*qkv, window=window), q, k, v, weigh)
     if window == 1:  # every query sees its own key alone: the output is v's rows, and no score has a gradient
-        assert_close(got[0], jnp.broadcast_to(v[:, :, None], got[0].shape), 1e-6)
+        assert_close(got[0], tokens_first(jnp.broadcast_to(v[:, :, None], q.shape)), 1e-6)
         for found in (got, twin):
             assert max(float(jnp.max(jnp.abs(g))) for g in found[1][:2]) < 1e-3  # dQ, dK: 0 but for rounding, where dV is of order 1
         got, want, twin = ((found[0], found[1][2]) for found in (got, want, twin))
@@ -198,7 +221,84 @@ def test_which_shapes_take_the_kernels(monkeypatch, t, groups, d, tiles):
     q, k, v, _ = operands(groups, d, t, jnp.float32)
     causal_lm.causal_attention(q, k, v)
     # no TPU here: the interpreter
-    assert calls == ([] if tiles is None else [{"bq": tiles[0], "bk": tiles[1], "window": None, "interpret": True}])
+    # no TPU here: the interpreter; ``batch``: the rows, where the output is written token-major
+    batch = 1 if d % 128 == 0 else None
+    assert calls == ([] if tiles is None else [
+        {"bq": tiles[0], "bk": tiles[1], "window": None, "batch": batch, "interpret": True}
+    ])
+
+
+def _bits_equal(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert a.shape == b.shape and a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+def _heads_first_kernels(q, k, v, weigh, window):
+    """The flash pair as it writes the output heads first (no ``batch``), its
+    output laid tokens first by a transpose and the cotangent heads first by
+    the transpose back: what ``causal_attention``'s callers ran before the
+    kernels' block specs wrote that layout themselves."""
+    b, hkv, groups, t, d = q.shape
+    bq, bk = causal_lm._flash_tiles(t, groups, d)
+    q4, k3, v3 = q.reshape(b * hkv, groups, t, d), k.reshape(b * hkv, t, d), v.reshape(b * hkv, t, d)
+    o, lse = causal_lm._flash_forward(q4, k3, v3, bq=bq, bk=bk, window=window, interpret=True)
+    assert o.shape == q4.shape
+    do = weigh.astype(o.dtype).reshape(b, t, hkv, groups, d).transpose(0, 2, 3, 1, 4).reshape(q4.shape)
+    dq, dk, dv = causal_lm._flash_backward(q4, k3, v3, o, lse, do, bq=bq, bk=bk, window=window, interpret=True)
+    return tokens_first(o.reshape(q.shape)), (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
+
+
+# query heads a key-value head, head size, window: a head of whole lane tiles
+TOKEN_MAJOR = {"trinity-mini": (8, 128, None), "trinity-mini-window": (8, 128, 200), "ouro": (1, 128, None),
+               "glm-4.7-flash": (1, 256, None), "qwen3-next": (8, 256, None)}
+
+
+@pytest.mark.parametrize("case", sorted(TOKEN_MAJOR))
+def test_the_token_major_output_is_the_heads_first_kernels_bit_for_bit(monkeypatch, case):
+    """Where a head is whole 128-lane tiles the forward kernel writes ``o``
+    token-major through its block spec and the backward kernel reads ``do``
+    and the kept ``o`` there (and sums ``delta`` from them once a query tile,
+    where the heads-first kernel is handed XLA's sum): the same arithmetic at
+    other addresses, so the output and dQ, dK, dV for a random cotangent are
+    the heads-first kernels' bit for bit (two rows of two key-value heads: the
+    block index is the row's and the head's; 3 x 3 tiles; bfloat16 as the
+    models pass them)."""
+    groups, d, window = TOKEN_MAJOR[case]
+    t = 384
+    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
+    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 128 * groups)
+    assert causal_lm._flash_tiles(t, groups, d) == (128, 128) and causal_lm._token_major(t, groups, d)
+    q, k, v, weigh = operands(groups, d, t, jnp.bfloat16, kv_heads=2, rows=2)
+    calls = []
+    kernel = causal_lm._flash_forward
+    monkeypatch.setattr(causal_lm, "_flash_forward", lambda *a, **kw: calls.append(kw["batch"]) or kernel(*a, **kw))
+    got = out_and_grads(lambda *qkv: causal_lm.causal_attention(*qkv, window), q, k, v, weigh)
+    assert calls == [2] and got[0].shape == (2, t, 2 * groups, d)
+    monkeypatch.setattr(causal_lm, "_flash_forward", kernel)
+    _bits_equal(got, _heads_first_kernels(q, k, v, weigh, window))
+    text = str(jax.make_jaxpr(lambda *qkv: jax.vjp(causal_lm.causal_attention, *qkv)[1](weigh.astype(q.dtype)))(q, k, v))
+    # the transposes left are inside the kernels (the interpreter shows them): the log-sum-exp's and ``delta``'s
+    # [G*bq, 128], a float a query and head along the lanes; nothing the size of a row's output
+    sizes = transposed_sizes(text)
+    assert sizes and set(sizes) == {128 * groups * 128}
+
+
+@pytest.mark.parametrize("groups, d, t", [(4, 64, 256), (8, 128, 150), (4, 96, 256)],
+                         ids=["head-64", "no-whole-tiles", "head-96"])
+def test_a_head_of_64_and_a_refused_shape_are_laid_out_by_the_transpose(groups, d, t):
+    """Two heads of 64 share a lane tile, and a shape the kernels refuse runs
+    the blockwise twin: both write heads first as before and
+    ``causal_attention`` lays the output tokens first by the transpose its
+    callers ran; values and gradients bit for bit today's."""
+    assert not causal_lm._token_major(t, groups, d)
+    q, k, v, weigh = operands(groups, d, t, jnp.bfloat16, kv_heads=2, rows=2)
+    got = out_and_grads(causal_lm.causal_attention, q, k, v, weigh)
+    if causal_lm._flash_tiles(t, groups, d) is None:
+        want = out_and_grads(lambda *qkv: blockwise(*qkv, causal_lm.ATTN_BAND, causal_lm.ATTN_ROWS), q, k, v, weigh.astype(q.dtype))
+    else:
+        want = _heads_first_kernels(q, k, v, weigh, None)
+    _bits_equal(got, want)
+    assert max(transposed_sizes(str(jax.make_jaxpr(causal_lm.causal_attention)(q, k, v)))) == q.size
 
 
 def test_a_row_checkpoint_keeps_the_output_and_the_log_sum_exp():
@@ -214,7 +314,7 @@ def test_a_row_checkpoint_keeps_the_output_and_the_log_sum_exp():
         return causal_lm.causal_attention(row * p, k, v)
 
     def grad(rows):
-        return jax.grad(lambda p: jnp.sum(weigh * rows(mixer, x, p)))
+        return jax.grad(lambda p: jnp.sum(weigh[0] * rows(mixer, x, p)))
 
     def forward_kernels(rows):
         return str(jax.make_jaxpr(grad(rows))(jnp.float32(1.0))).count("name=flash_attention_fwd")
@@ -302,7 +402,7 @@ def test_a_mixer_whose_weights_hold_no_head_norm_norms_no_head(path, monkeypatch
         positions = jnp.arange(t)
         q, k = (causal_lm._rotary(a, positions, d, 1e6) for a in (q, k))
         o = plain_attention(q.transpose(0, 2, 1, 3)[:, :, None] * d**-0.5, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-        return o[:, :, 0].transpose(0, 2, 1, 3).reshape(1, t, heads * d) @ p["w_o"]
+        return o.reshape(1, t, heads * d) @ p["w_o"]
 
     got = out_and_grads(lambda x, p, _: causal_lm._row_by_row(mixer, x, p, None), x, p, None, cot)
     want = out_and_grads(lambda x, p, _: plain(x, p), x, p, None, cot)

@@ -194,7 +194,8 @@ def test_latent_mixer_at_the_published_head_takes_the_flash_kernels(monkeypatch)
     x = hidden(3, 128)[:1]
     weigh = jax.random.normal(jax.random.key(4), x.shape)
     assert_close(mixer(cfg)(x, p), ref.attention(x, p, PUBLISHED_HEADS))
-    assert calls == [((2, 1, 128, 256), {"bq": 128, "bk": 128, "window": None, "interpret": True})]  # [heads, group of 1, T, 256]
+    # [heads, group of 1, T, 256]; ``batch``: a head of two lane tiles, the output written token-major, [1, T, 2 x 256]
+    assert calls == [((2, 1, 128, 256), {"bq": 128, "bk": 128, "window": None, "batch": 1, "interpret": True})]
     assert_close(
         jax.jit(jax.grad(lambda p, x: jnp.sum(weigh * mixer(cfg)(x, p)), argnums=(0, 1)))(p, x),
         jax.jit(jax.grad(lambda p, x: jnp.sum(weigh * ref.attention(x, p, PUBLISHED_HEADS)), argnums=(0, 1)))(p, x),
@@ -557,6 +558,7 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
                              "head_mtp": second, "head_all": main + second,
                              "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
                              "attn_operands_kernel": 0, "attn_operands_xla": 0,
+                             "attn_out_tokens": 0, "attn_out_heads": 4 * B,  # three layers and the module's, in the twin: heads first
                              "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0}  # latent attention makes its own operands; no pass loop
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]

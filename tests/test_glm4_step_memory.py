@@ -5,7 +5,8 @@ installed here).
 
 The configuration file's rule (``assumed.per_chip_batch``): the largest of 4,
 3, 2, 1 rows of 8,192 tokens that leaves at least 0.5 GB of a v5e's 15.75.
-One row reads 13.20 GB and is taken; two read 15.28 and are refused (15.52
+One row reads 12.98 GB and is taken (13.20 before the flash kernels wrote the
+attention output token-major); two read 15.28 and are refused (15.52
 before PR 39: at two rows an expert's 1,024 rows fill two tiles, so the
 weight-gradient sums leave the backward loop for ``parallel/moe.py:
 expert_dw`` and the loop's float32 products a tile go with them).  The
@@ -95,17 +96,18 @@ def _step_gb(rows: int, configuration: str = "glm47_flash_clm_pk") -> dict:
 
 @pytest.mark.parametrize("rows", [1, 2])
 def test_the_cells_batch_is_the_largest_that_leaves_half_a_gigabyte(rows):
-    """One row fits with room (13.20 GB: 8.478 of arguments, 4.487 of
-    scratch, 0.230 of code); two leave 0.47 GB (15.28: 6.505 of scratch, 0.295
-    of code), still under the rule's 0.5.  The cell runs the batch the rule
-    gives."""
+    """One row fits with room (12.98 GB: 8.478 of arguments, 4.270 of
+    scratch, 0.228 of code; 13.20 with 4.487 of scratch while the attention
+    output left the kernels heads first and copies laid it out for ``w_o``);
+    two leave 0.47 GB (15.28: 6.500 of scratch, 0.302 of code), still under
+    the rule's 0.5.  The cell runs the batch the rule gives."""
     cell = _bench_file("workloads", "glm47_flash_clm_pk.seq8k_mor_stream")
     gb = _step_gb(rows)
     assert gb["arguments"] == pytest.approx(8.478, abs=0.005)  # 706.5 M parameters x 12 B, the biases, the counts
     assert gb["outputs_not_aliased"] < 0.001                    # the state is donated
     fits = gb["total"] <= CHIP_GB - FREE_GB
     if rows == 1:
-        assert gb["total"] == pytest.approx(13.20, abs=0.15) and fits, gb
+        assert gb["total"] == pytest.approx(12.98, abs=0.15) and fits, gb
     else:
         assert gb["total"] == pytest.approx(15.28, abs=0.15) and not fits, gb
     assert (rows <= cell["per_chip_batch"]) == fits

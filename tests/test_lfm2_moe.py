@@ -451,6 +451,7 @@ def test_counters_for_a_known_routing(stepped):
                              "head_all": B * (T - 1), "head_mtp": 0,  # one loss, no prediction module
                              "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
                              "attn_operands_kernel": 0, "attn_operands_xla": B,
+                             "attn_out_tokens": 0, "attn_out_heads": B,  # the twin writes heads first
                              "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0}  # one attention layer, its operands the jnp lines'; no pass loop
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]

@@ -949,23 +949,29 @@ def test_attn_key_tiles_counter_is_the_tile_tables(monkeypatch):
 
 
 @pytest.mark.parametrize("check", ["share_of_hand_counts", "nothing_without_the_series"])
-def test_attn_operand_rows_reader(check):
-    """``attn_operands_fused_pct`` through its own self-test, and the series
-    it reads under the name the LM step feeds."""
+@pytest.mark.parametrize("reader", ["attn_operands_fused_pct", "attn_out_token_major_pct"])
+def test_attn_row_counter_readers(reader, check):
+    """``attn_operands_fused_pct`` and ``attn_out_token_major_pct`` through
+    their own self-tests, and the series each reads under the name the LM step
+    feeds."""
     import importlib.util
 
-    from lakesoul_tpu.models.train import ATTN_OPERAND_ROWS_FAMILY
+    from lakesoul_tpu.models import train
 
+    selftest_file, family = {
+        "attn_operands_fused_pct": ("operand_rows.py", train.ATTN_OPERAND_ROWS_FAMILY),
+        "attn_out_token_major_pct": ("output_rows.py", train.ATTN_OUTPUT_ROWS_FAMILY),
+    }[reader]
     spec = importlib.util.spec_from_file_location(
-        "chipbench_selftest_operand_rows", os.path.join(REPO, "benchmarks", "chip", "selftest", "operand_rows.py")
+        "chipbench_selftest_" + selftest_file[:-3], os.path.join(REPO, "benchmarks", "chip", "selftest", selftest_file)
     )
     selftest = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(selftest)
     assert [t.__name__ for t in selftest.TESTS] == ["test_share_of_hand_counts", "test_nothing_without_the_series"]
     getattr(selftest, "test_" + check)()
-    assert selftest.FAMILY == ATTN_OPERAND_ROWS_FAMILY
-    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "attn_operands_fused_pct.py")) as f:
-        assert f'COUNTER = "{ATTN_OPERAND_ROWS_FAMILY}"' in f.read()
+    assert selftest.FAMILY == family
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", reader + ".py")) as f:
+        assert f'COUNTER = "{family}"' in f.read()
 
 
 def test_attn_operand_rows_counter_is_the_rule(monkeypatch):
@@ -1003,6 +1009,44 @@ def test_attn_operand_rows_counter_is_the_rule(monkeypatch):
         counts = step.counts()
         assert {path: counts["attn_operands_" + path] for path in want} == want
         assert {path: n - before[path] for path, n in series().items()} == want
+
+
+def test_attn_output_rows_counter_is_the_rule(monkeypatch):
+    """``lakesoul_train_attn_output_rows_total{layout="tokens"|"heads"}`` from
+    a step: two rows through a window layer and a full one at a head of 128
+    (the flash kernels write the output token-major, in the interpreter), then
+    the same stack at a head of 64 (heads first, the transpose after): the
+    step's attention layer-rows by what :func:`_token_major` says, host
+    integers like the tile counts.  And the rule at the five cells' shapes."""
+    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models.train import ATTN_OUTPUT_ROWS_FAMILY, make_lm_train_state, make_lm_train_step
+    from lakesoul_tpu.obs import registry
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    def series():
+        found = registry().snapshot()
+        return {layout: found.get(f'{ATTN_OUTPUT_ROWS_FAMILY}{{layout="{layout}"}}', 0) for layout in ("tokens", "heads")}
+
+    # Trinity-Mini, Ouro, GLM (latent attention), Qwen3-Next: heads of one and two lane tiles; LFM2: half a tile
+    assert all(causal_lm._token_major(8192, groups, d) for groups, d in ((8, 128), (1, 128), (1, 256), (8, 256)))
+    assert not causal_lm._token_major(8192, 4, 64) and causal_lm._flash_tiles(8192, 4, 64) is not None
+    assert not causal_lm._token_major(150, 8, 128)  # a row the kernels refuse: the twin writes heads first
+    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
+    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 256)
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    ids = jnp.zeros((2, 384), jnp.int32)
+    for head, want in ((128, {"tokens": 4, "heads": 0}), (64, {"tokens": 0, "heads": 4})):
+        cfg = _afmoe_cfg(
+            hidden_size=64, num_hidden_layers=2, layer_types=("sliding_attention", "full_attention"),
+            num_attention_heads=4, num_key_value_heads=2, head_dim=head, sliding_window=100,
+        )
+        params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+        step = make_lm_train_step(cfg, plan, tx, shardings)
+        before = series()
+        step(params, opt_state, ids, ids)
+        counts = step.counts()
+        assert {layout: counts["attn_out_" + layout] for layout in want} == want
+        assert {layout: n - before[layout] for layout, n in series().items()} == want
 
 
 AFMOE_READER_CHECKS = [
@@ -1257,8 +1301,10 @@ UNSCOPED = {
     "lfm2_moe_clm": ("lfm2_step_compiled_for_a_v5e", 4),
     "glm4_moe_lite_clm": ("glm_step_compiled_for_a_v5e", 0),
     # the operand kernels read the positions as two whole-head tables, ``[cos | cos]`` and ``[-sin | sin]``: a
-    # negation and two concatenations more than the two angle constants the ``jnp`` lines had (7)
-    "afmoe_clm": ("afmoe_step_compiled_for_a_v5e", 10),
+    # negation and two concatenations more than the two angle constants the ``jnp`` lines had; 10 while the gate's
+    # product ran over ``[.., heads, D]`` arrays and the loop over rows hoisted each layer's ``w_gate`` cast to
+    # the top of the step: over ``[.., heads x D]`` the cast stays in the mixer, under its scope
+    "afmoe_clm": ("afmoe_step_compiled_for_a_v5e", 5),
 }
 
 
@@ -1319,7 +1365,7 @@ def test_ouro_step_under_no_scope_holds_the_staged_constants_and_the_pass_loops_
     ouro_step_compiled_for_a_v5e,
 ):
     """What the looped program writes into its step under no scope, counted
-    as the other families' 14 / 4 / 0 / 10 are.  At the top, 7: the rotary's
+    as the other families' 14 / 4 / 0 / 5 are.  At the top, 7: the rotary's
     ``theta ** ...``, the two position tables' negation, concatenations and
     cast, and the zeros the pass loop's transpose starts its weight-gradient
     sums from.  And the pass loop's OWN bookkeeping, which no family had: the

@@ -138,28 +138,33 @@ ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads,
 
 def _attention_row(d):
     # a row's attention at a published group and head size: the LFM2 cell's layer at head 64 wherever d is not the
-    # Qwen cell's (d is a vector width, not a shape of these kernels), or a family of ATTENTION_ROWS by name
+    # Qwen cell's (d is a vector width, not a shape of these kernels), or a family of ATTENTION_ROWS by name; the
+    # output (and its cotangent) token-major, [1, T, heads x D], where ``_token_major`` takes the shape: every
+    # published head but LFM2's 64
     hkv, groups, d = ATTENTION_ROWS.get(d) or ATTENTION_ROWS["qwen3-next" if d == 768 else "lfm2"]
     t = 8192
     bf16 = jnp.bfloat16
-    return _sds((hkv, groups, t, d), bf16), _sds((hkv, t, d), bf16), dict(zip(("bq", "bk"), causal_lm._flash_tiles(t, groups, d)))
+    q = _sds((hkv, groups, t, d), bf16)
+    o = _sds((1, t, hkv * groups * d), bf16) if causal_lm._token_major(t, groups, d) else q
+    return q, _sds((hkv, t, d), bf16), o, dict(zip(("bq", "bk"), causal_lm._flash_tiles(t, groups, d)))
 
 
 def _flash_forward(d, window=None):
-    q, k, tiles = _attention_row(d)
+    q, k, o, tiles = _attention_row(d)
+    batch = None if o is q else 1
     return jax.jit(
-        lambda q, k, v: causal_lm._flash_forward(q, k, v, **tiles, window=window, interpret=False)
+        lambda q, k, v: causal_lm._flash_forward(q, k, v, **tiles, window=window, batch=batch, interpret=False)
     ).trace(q, k, k)
 
 
 def _flash_backward(d, window=None):
-    q, k, tiles = _attention_row(d)
+    q, k, o, tiles = _attention_row(d)
     lse = _sds((*q.shape[:2], 1, q.shape[2]))
     return jax.jit(
         lambda q, k, v, o, lse, do: causal_lm._flash_backward(
             q, k, v, o, lse, do, **tiles, window=window, interpret=False
         )
-    ).trace(q, k, k, q, lse, q)
+    ).trace(q, k, k, o, lse, o)
 
 
 def _operand_row(turned=True, family="trinity-mini", normed=True):
@@ -225,11 +230,17 @@ def test_attention_kernels_lower_at_every_published_shape(kernel, family):
     latent attention's 20 key-value heads of one query head each at head 256
     (tiles of 512 queries x 512 keys), groups of 8 at head 128, and 16
     key-value heads of one query head each at head 128 (512 x 512 again:
-    ``FLASH_ROWS`` is then queries alone)."""
+    ``FLASH_ROWS`` is then queries alone); with the token-major output's
+    block spec at the four heads of whole lane tiles, the heads-first one at
+    head 64."""
     if family in ("glm-4.7-flash", "ouro"):
         assert causal_lm._flash_tiles(8192, 1, ATTENTION_ROWS[family][2]) == (512, 512)
     lowered = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](family).lower(lowering_platforms=("tpu",))
-    assert "tpu_custom_call" in lowered.as_text()
+    call = next(line for line in lowered.as_text().splitlines() if "tpu_custom_call" in line)
+    # where a head is whole lane tiles the call writes the output (reads its cotangent) token-major, a block of
+    # ``bq`` tokens by a group's lanes; at LFM2's head of 64 heads first, as before
+    hkv, groups, d = ATTENTION_ROWS[family]
+    assert (f"tensor<1x8192x{hkv * groups * d}xbf16>" in call) == (family != "lfm2")
 
 
 @pytest.mark.parametrize("window", [None, 2048], ids=["full", "window-2048"])
