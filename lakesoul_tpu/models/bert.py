@@ -238,7 +238,16 @@ def _tile_nll(head_fn, head, x, labels, scale, weights=None):
     return jnp.sum(weights * nll), nll
 
 
-def _head_over_labelled(head_fn, head, x, labels, axes, with_grads: bool, weights=None):
+def tile_grads(head_fn, head, x, labels, scale, *weights):
+    """:func:`_tile_nll` and its gradients into ``head`` and ``x`` by autodiff
+    → (what it returns, (g_head, g_x)): the compiler's body of a tile, and the
+    tile loop's default."""
+    return jax.value_and_grad(
+        functools.partial(_tile_nll, head_fn), argnums=(0, 1), has_aux=bool(weights)
+    )(head, x, labels, scale, *weights)
+
+
+def _head_over_labelled(head_fn, head, x, labels, axes, with_grads: bool, weights=None, tile_body=tile_grads):
     """One shard's share of the loss: ``x`` [..., h] and ``labels`` [...] are
     the rows this device holds, ``axes`` the mesh axes the batch is split
     over, ``head_fn(head, x)`` the float32 logits of rows ``x``.  → (loss, positions the head ran at), both summed over ``axes``,
@@ -246,7 +255,9 @@ def _head_over_labelled(head_fn, head, x, labels, axes, with_grads: bool, weight
     x for this shard's rows).  With ``weights`` [...] (float32, a position's
     own) the loss is the sum of weight x NLL over the labelled positions, not
     their mean, and each position's NLL [...] (float32, 0 without a label)
-    comes third: → (loss, positions, nll) and then the gradients."""
+    comes third: → (loss, positions, nll) and then the gradients.
+    ``tile_body`` makes one tile's loss and gradients, called as
+    :func:`tile_grads` is and returning what it returns."""
     x2, lab = x.reshape(-1, x.shape[-1]), labels.reshape(-1)
     n = lab.shape[0]
     tile = head_tile(n)
@@ -269,9 +280,7 @@ def _head_over_labelled(head_fn, head, x, labels, axes, with_grads: bool, weight
         lt = jax.lax.dynamic_slice(row_labels, (at,), (tile,))
         wt = (jax.lax.dynamic_slice(row_weights, (at,), (tile,)),) if weighted else ()
         if with_grads:
-            part, (g_head, g_x) = jax.value_and_grad(
-                functools.partial(_tile_nll, head_fn), argnums=(0, 1), has_aux=weighted
-            )(head, xt, lt, scale, *wt)
+            part, (g_head, g_x) = tile_body(head_fn, head, xt, lt, scale, *wt)
             acc_head, acc_x = grads
             grads = (
                 jax.tree.map(jnp.add, acc_head, g_head),
@@ -309,23 +318,25 @@ def _head_over_labelled(head_fn, head, x, labels, axes, with_grads: bool, weight
     return *out, g_head, g_x
 
 
-def _sharded_head(head_fn, head, x, labels, batch_sharding, with_grads: bool, weights=None):
+def _sharded_head(head_fn, head, x, labels, batch_sharding, with_grads: bool, weights=None, tile_body=tile_grads):
     if batch_sharding is None:
-        return _head_over_labelled(head_fn, head, x, labels, (), with_grads, weights)
+        return _head_over_labelled(head_fn, head, x, labels, (), with_grads, weights, tile_body)
     spec = batch_sharding.spec
     axes = spec_axes(spec)
     per_position = () if weights is None else (spec,)
     out_specs = (P(), P(), *per_position, P(), spec) if with_grads else (P(), P(), *per_position)
     # every device gathers among its own rows; only sums cross the mesh
     return jax.shard_map(
-        lambda head, x, labels, *weights: _head_over_labelled(head_fn, head, x, labels, axes, with_grads, *weights),
+        lambda head, x, labels, *weights: _head_over_labelled(
+            head_fn, head, x, labels, axes, with_grads, *weights, tile_body=tile_body
+        ),
         mesh=batch_sharding.mesh, in_specs=(P(), spec, spec, *per_position), out_specs=out_specs,
         check_vma=False,
     )(head, x, labels, *(() if weights is None else (weights,)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 4))
-def labelled_nll(head_fn, head, x, labels, batch_sharding=None, weights=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 4, 6))
+def labelled_nll(head_fn, head, x, labels, batch_sharding=None, weights=None, tile_body=tile_grads):
     """Hidden states ``x`` [..., h] and ``labels`` [...] → (mean NLL over the
     positions with labels >= 0, positions the head ran at), the logits of rows
     being ``head_fn(head, rows)`` in float32.  The one tile loop of every
@@ -351,16 +362,20 @@ def labelled_nll(head_fn, head, x, labels, batch_sharding=None, weights=None):
     a scalar cotangent: the backward pass only scales them by the loss's
     cotangent (:func:`_labelled_nll_bwd`), so nothing but the loss itself may
     be differentiated through, and no [positions, vocab] array exists in either
-    pass."""
+    pass.  What makes a tile's loss and gradients there is ``tile_body``: by
+    default :func:`tile_grads`, the compiler's (a float32 log-softmax, a
+    gather and autodiff: the masked-LM loss passes nothing); a causal LM's
+    losses pass ``models/causal_lm.py: fused_tile``.  Without gradients
+    (evaluation) a tile is :func:`_tile_nll` whatever the body."""
     return _sharded_head(head_fn, head, x, labels, batch_sharding, False, weights)
 
 
-def _labelled_nll_fwd(head_fn, head, x, labels, batch_sharding, weights=None):
-    *out, g_head, g_x = _sharded_head(head_fn, head, x, labels, batch_sharding, True, weights)
+def _labelled_nll_fwd(head_fn, head, x, labels, batch_sharding, weights=None, tile_body=tile_grads):
+    *out, g_head, g_x = _sharded_head(head_fn, head, x, labels, batch_sharding, True, weights, tile_body)
     return tuple(out), (g_head, g_x)
 
 
-def _labelled_nll_bwd(head_fn, batch_sharding, grads, cotangents):
+def _labelled_nll_bwd(head_fn, batch_sharding, tile_body, grads, cotangents):
     g_head, g_x = grads
     ct = cotangents[0]
     return jax.tree.map(lambda g: ct * g, g_head), ct.astype(g_x.dtype) * g_x, None, None

@@ -45,7 +45,14 @@ weights hold a multi-token-prediction module (``params["mtp"]``) that module's
 loss times ``cfg.mtp_loss_weight`` on top (:func:`mtp_loss`): the final hidden
 states and the next token's embedding through one more layer of the stack's
 last kind, with its own weights, to the token after next, through the main
-model's embedding and head matrix.  The head then runs twice a step.
+model's embedding and head matrix.  The head then runs twice a step.  Every
+loss here goes through ``models/bert.py: labelled_nll``'s tile loop and hands
+it :func:`fused_tile` as the tile's body: ``jax.vjp`` of the head, one Pallas
+kernel from the float32 logits to each row's NLL and the logits' cotangent
+(``models/loss_tile.py``), the head's pull-back; a tile smaller than any it
+is measured at runs the loop's default body, the compiler's log-softmax and
+autodiff, as the masked-LM loss always does (it passes no body, and ``models/bert.py`` imports
+no Pallas).
 
 A layer is ``h = x + mixer(norm1(x)); x' = h + ffn(norm2(h))``, and where its
 weights hold ``norm1_out`` and ``norm2_out`` it has four norms, each
@@ -100,6 +107,7 @@ the loss are over.  Parallelism: dp over rows; everything else is replicated.
 from __future__ import annotations
 
 import functools
+import math
 import threading
 
 import jax
@@ -109,7 +117,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from lakesoul_tpu.models.bert import labelled_nll
+from lakesoul_tpu.models.bert import head_tile, labelled_nll, tile_grads
+from lakesoul_tpu.models.loss_tile import loss_tile, tile_takes
+from lakesoul_tpu.parallel.mesh import spec_axes
 from lakesoul_tpu.parallel.moe import EXPERTS_SCOPE, ROUTE_SCOPE, SHARED_SCOPE, held_experts, shared_expert
 from lakesoul_tpu.parallel.ring_attention import block_attn
 from lakesoul_tpu.vector.kernels import _on_tpu
@@ -1174,6 +1184,57 @@ def lm_head(head, x, *, cfg):
         return jnp.einsum("...h,vh->...v", y, head["embed"].astype(dtype), preferred_element_type=jnp.float32)
 
 
+def _tile_fused(head_fn, head, x) -> bool:
+    """Whether a tile of rows ``x`` [tile, h] through ``head_fn`` makes logits
+    the loss kernel takes (``models/loss_tile.py: tile_takes``; shapes alone,
+    nothing runs)."""
+    return tile_takes(*jax.eval_shape(head_fn, head, x).shape)
+
+
+def fused_tile(head_fn, head, x, labels, scale, *weights):
+    """The tile body the causal-LM losses hand :func:`labelled_nll`
+    (``models/bert.py: tile_grads``'s arguments and results): ``jax.vjp`` of
+    whatever head it is given, ONE kernel from the float32 logits to each
+    row's NLL and the logits' cotangent (``models/loss_tile.py``), and the
+    head's pull-back, whose two products stay the compiler's.  The row's
+    coefficient is the loss's ``scale`` (the mean form) or its own weight
+    where it has a label, 0 where it has none.
+
+    The cotangent is written ONCE, in the rows' dtype: the dtype the head's
+    two gradient products take it in.  The compiler's body hands them none:
+    each product makes ``coef x (softmax - onehot)`` again in float32 from the
+    logits inside its operand fusion, and the matrix unit rounds that float32
+    operand to the other operand's bfloat16 at its input (default precision).
+    Rounding the kernel's float32 value to bfloat16 as it is written is that
+    same rounding, at that same place (``PERF.md`` section 6, PR 47: both
+    gradients bit-equal between a float32 and a bfloat16 cotangent on a v5e);
+    under float32 rows it stays float32.
+
+    A tile smaller than any the kernel is measured at (:func:`_tile_fused`
+    false: a tiny model's) runs the compiler's body, the program it had."""
+    if not _tile_fused(head_fn, head, x):
+        return tile_grads(head_fn, head, x, labels, scale, *weights)
+    logits, pull = jax.vjp(head_fn, head, x)
+    coef = jnp.where(labels >= 0, weights[0] if weights else scale, 0.0)
+    nll, g = loss_tile(logits, labels, coef, dtype=x.dtype, interpret=not _on_tpu())
+    part = jnp.sum(coef * nll)
+    return ((part, nll) if weights else part), pull(g.astype(logits.dtype))
+
+
+def _head_nll(head_fn, head, x, labels, batch_sharding, weights=None):
+    """:func:`labelled_nll` with :func:`fused_tile` as its tile body → (what
+    it returns, the rows it was handed by the body that runs their tiles:
+    ``loss_rows_fused`` and ``loss_rows_compiler``, host integers known when
+    the step is traced and no operation of it, by the rule the body itself
+    reads at a shard's tile)."""
+    axes = () if batch_sharding is None else spec_axes(batch_sharding.spec)
+    shards = math.prod(batch_sharding.mesh.shape[axis] for axis in axes)
+    tile = jax.ShapeDtypeStruct((head_tile(labels.size // shards), x.shape[-1]), x.dtype)
+    fused = _tile_fused(head_fn, head, tile)
+    rows = {"loss_rows_fused": labels.size if fused else 0, "loss_rows_compiler": 0 if fused else labels.size}
+    return labelled_nll(head_fn, head, x, labels, batch_sharding, weights, fused_tile), rows
+
+
 def lm_logits(params, ids, *, cfg):
     x, _ = lm_hidden(params, ids, cfg=cfg)
     return lm_head(head_params(params), x, cfg=cfg)
@@ -1223,10 +1284,10 @@ def mtp_loss(params, x, labels, *, cfg, batch_sharding=None):
         h, counts = mtp_hidden(params, x, labels, cfg=cfg, batch_sharding=batch_sharding)
         kind = cfg.layer_kinds()[-1]
         counts = _sum_counts(counts, layer_attention_counts(cfg, kind, x, params["mtp"]["layer"][kind]))
-        loss, _ = labelled_nll(
+        (loss, _), rows = _head_nll(
             functools.partial(lm_head, cfg=cfg), mtp_head_params(params), h, after_next, batch_sharding
         )
-        return loss, counts, jnp.sum(after_next >= 0, dtype=jnp.int32)
+        return loss, _sum_counts(counts, rows), jnp.sum(after_next >= 0, dtype=jnp.int32)
 
 
 def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
@@ -1244,10 +1305,13 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     ``attn_out_tokens`` and ``attn_out_heads`` (attention layer-rows by where
     the output was written), ``attn_operands_kernel`` and
     ``attn_operands_xla`` (softmax-attention layer-rows by what made the
-    kernels' operands)."""
+    kernels' operands), ``loss_rows_fused`` and ``loss_rows_compiler`` (the
+    rows handed to the head's tile loop, over both losses, by the body that
+    runs their tiles: :func:`_head_nll`)."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
     with jax.named_scope(HEAD_SCOPE):  # the loss's own loop over tiles of positions around the head
-        loss, _ = labelled_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)
+        (loss, _), rows = _head_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)
+        counts = _sum_counts(counts, rows)
         labelled = jnp.sum(labels >= 0, dtype=jnp.int32)
     terms = {"loss_main": loss, "loss_mtp": jnp.float32(0.0)}
     second = jnp.int32(0)
@@ -1298,7 +1362,9 @@ def exit_loss(params, states, labels, *, cfg, batch_sharding=None):
     before the last and ``head_mtp`` 0 (no prediction module), int32; ``loss_pass`` [R] each pass's mean NLL and
     ``exit_mass`` [R] the mean ``p(t)``, float32; ``exit_mass_<t>`` the summed
     ``p(t)`` over the labelled positions in 1,024ths, int32 (what the step's
-    gauge counts); ``tokens``."""
+    gauge counts); ``tokens``; ``loss_rows_fused`` and ``loss_rows_compiler``,
+    the stacked rows by the body that runs their tiles (:func:`_head_nll`:
+    Python integers)."""
     passes = states.shape[0]
     f32 = jnp.float32
     with jax.named_scope(EXIT_SCOPE):
@@ -1309,7 +1375,7 @@ def exit_loss(params, states, labels, *, cfg, batch_sharding=None):
         neg_entropy = jnp.sum(p * log_p * labelled) / n
         weights = jax.lax.stop_gradient(p) * (labelled / n)
     with jax.named_scope(HEAD_SCOPE):  # rows first: a mesh splits the stacked states by their rows
-        expected, _, nll = labelled_nll(
+        (expected, _, nll), rows = _head_nll(
             functools.partial(lm_head, cfg=cfg), {k: v for k, v in head_params(params).items() if k != "final_norm"},
             jnp.moveaxis(states, 0, 1), jnp.broadcast_to(labels[:, None], (labels.shape[0], passes, labels.shape[1])),
             batch_sharding, jnp.moveaxis(weights, 0, 1),
@@ -1323,7 +1389,7 @@ def exit_loss(params, states, labels, *, cfg, batch_sharding=None):
         counts = {
             "loss_pass": jnp.sum(nll * labelled, axis=(1, 2)) / n, "exit_mass": mass / n,
             "head_all": passes * count, "head_loop": (passes - 1) * count, "head_mtp": jnp.int32(0),
-            "tokens": jnp.int32(labels.size),
+            "tokens": jnp.int32(labels.size), **rows,
             **{f"exit_mass_{t}": jnp.round(mass[t] * EXIT_MASS_UNIT).astype(jnp.int32) for t in range(passes)},
         }
     return loss, counts

@@ -95,6 +95,11 @@ MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
 ATTN_KEY_TILES_FAMILY = "lakesoul_train_attn_key_tiles_total"
 ATTN_OPERAND_ROWS_FAMILY = "lakesoul_train_attn_operand_rows_total"
 ATTN_OUTPUT_ROWS_FAMILY = "lakesoul_train_attn_output_rows_total"
+# the rows an LM step hands the head's tile loop for loss and gradients (``models/bert.py: labelled_nll``; every loss
+# the step sums), by the body that runs their tiles: ``{body="fused"}`` ``models/causal_lm.py: fused_tile``'s one
+# kernel between the logits and their cotangent, ``{body="compiler"}`` a float32 log-softmax and autodiff (a tile smaller
+# than any the kernel is measured at); host integers, no operation of the step.  The MLM steps pass no body and feed no series
+LOSS_ROWS_FAMILY = "lakesoul_train_loss_rows_total"
 # a looped model's step (``cfg.loop_passes``): ``{kind="run"}`` the layer passes it ran, rows x layers x
 # passes, over ``{kind="layers"}``, rows x layers: a change that skips a pass or exits early moves the ratio
 LOOP_LAYER_PASSES_FAMILY = "lakesoul_train_loop_layer_passes_total"
@@ -384,7 +389,12 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     token-major through the flash kernels' block specs where
     ``models/causal_lm.py: _token_major`` takes the shape, a head of whole
     128-lane tiles, so that nothing stands between the kernels and the output
-    projection; else heads first, and a transpose lays it out).
+    projection; else heads first, and a transpose lays it out) and
+    ``lakesoul_train_loss_rows_total{body="fused"|"compiler"}`` (the rows
+    handed to the head's tile loop, over every loss the step sums, by what
+    makes a tile's loss and gradients: ``models/causal_lm.py: fused_tile``'s
+    kernel where a tile's float32 logits are 48 MiB or more (every published
+    shape), else the compiler's log-softmax and autodiff).
 
     A looped family (``cfg.loop_passes``: the stack run that many times over
     one set of weights, a loss after every pass) also feeds
@@ -399,7 +409,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
     host_keys = ("attn_tiles_run", "attn_tiles_causal", "attn_out_tokens", "attn_out_heads",
-                 "attn_operands_kernel", "attn_operands_xla", "loop_layers_run", "loop_layers")
+                 "attn_operands_kernel", "attn_operands_xla", "loop_layers_run", "loop_layers",
+                 "loss_rows_fused", "loss_rows_compiler")
     held = getattr(cfg, "experts_held", None)
     if held is None:  # a family without experts: its loss returns none of their counts, and they count 0
         host_keys += ("moe_held", "moe_all", "moe_tile_rows", "moe_bias_moved", "moe_dw_writes", "moe_load_max")
@@ -431,6 +442,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("attn_out_heads", ATTN_OUTPUT_ROWS_FAMILY, {"layout": "heads"}, 1),
         ("attn_operands_kernel", ATTN_OPERAND_ROWS_FAMILY, {"path": "kernel"}, 1),
         ("attn_operands_xla", ATTN_OPERAND_ROWS_FAMILY, {"path": "xla"}, 1),
+        ("loss_rows_fused", LOSS_ROWS_FAMILY, {"body": "fused"}, 1),
+        ("loss_rows_compiler", LOSS_ROWS_FAMILY, {"body": "compiler"}, 1),
         ("head_loop", HEAD_POSITIONS_FAMILY, {"kind": "loop"}, 1),
         ("loop_layers_run", LOOP_LAYER_PASSES_FAMILY, {"kind": "run"}, 1),
         ("loop_layers", LOOP_LAYER_PASSES_FAMILY, {"kind": "layers"}, 1),

@@ -360,6 +360,42 @@ def _run_expert_dw(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"sums": [len(tiles_of), a, b], "tile": tile, "segment": span, "tiles": int(len(experts))}
 
 
+def _run_loss_tile(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.models.bert import _tile_nll
+    from lakesoul_tpu.models.loss_tile import block_rows, loss_tile
+
+    # a tile of a head's float32 logits to each row's NLL and the logits' cotangent, against
+    # ``jax.nn.log_softmax`` and autodiff (``_tile_nll``, the tile loop's default body): at deployed sizes the Ouro
+    # cell's tile (2,736 rows of 49,152: whole lane tiles, a ragged last block of rows), the LFM2 cell's (2,736 of
+    # 16,384) and the Trinity-Mini cell's (1,368 of 25,024: not whole lane tiles, the last lanes masked); a fifth of
+    # the rows without a label, a weight a row.  Float32 on both sides with the sums in another order, and the
+    # bfloat16 cotangent the LM steps write: the float32 one rounded once, bit for bit
+    wide = sizes.rows >= 1 << 16
+    shapes = ((2736, 49152), (2736, 16384), (1368, 25024)) if wide else ((40, 384), (24, 250))
+    seen = []
+    for rows, vocab in shapes:
+        keys = jax.random.split(jax.random.key(rows + vocab), 4)
+        logits = 4.0 * jax.random.normal(keys[0], (rows, vocab), jnp.float32)
+        labels = jnp.where(jax.random.uniform(keys[1], (rows,)) < 0.8, jax.random.randint(keys[2], (rows,), 0, vocab), -100)
+        coef = jnp.where(labels >= 0, jax.random.uniform(keys[3], (rows,), jnp.float32, 0.1, 1.0) / rows, 0.0)
+        # the twin: the logits as the head's rows under the identity, a weight a row; its gradient into them is the cotangent
+        (_, want_nll), want_g = jax.jit(jax.value_and_grad(
+            lambda z, labels, coef: _tile_nll(lambda head, x: x, None, z, labels, None, coef), has_aux=True
+        ))(logits, labels, coef)
+        nll, g = loss_tile(logits, labels, coef, dtype=jnp.float32, interpret=interpret)
+        _, g16 = loss_tile(logits, labels, coef, dtype=jnp.bfloat16, interpret=interpret)
+        np.testing.assert_allclose(np.asarray(nll), np.asarray(want_nll), rtol=1e-5, atol=1e-5)  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernel's NLL against log_softmax's
+        scale = float(jnp.max(jnp.abs(want_g)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want_g), rtol=0, atol=1e-5 * scale)  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernel's cotangent against autodiff's
+        assert not np.asarray(g)[np.asarray(labels) < 0].any()  # lakelint: ignore[replay-host-roundtrip] verification readback: a row without a label carries no gradient
+        assert bool(jnp.array_equal(g16, g.astype(jnp.bfloat16)))  # one rounding, at the end
+        seen.append([rows, vocab, block_rows(rows, vocab, jnp.float32), block_rows(rows, vocab, jnp.bfloat16)])
+    return {"tiles [rows, vocab, rows a block: float32, bfloat16]": seen}
+
+
 def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax
     import jax.numpy as jnp
@@ -641,6 +677,10 @@ def smoke_cases() -> list[SmokeCase]:
                 "lakesoul_tpu/models/causal_lm.py::_operands_fwd_kernel",
                 "lakesoul_tpu/models/causal_lm.py::_operands_bwd_kernel",
             ),
+        ),
+        SmokeCase(
+            "models.loss_tile", "pallas", _run_loss_tile,
+            kernels=("lakesoul_tpu/models/loss_tile.py::_loss_tile_kernel",),
         ),
         SmokeCase(
             "parallel.moe_row_copies", "pallas", _run_row_copies,

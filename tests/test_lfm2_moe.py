@@ -452,6 +452,7 @@ def test_counters_for_a_known_routing(stepped):
                              "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
                              "attn_operands_kernel": 0, "attn_operands_xla": B,
                              "attn_out_tokens": 0, "attn_out_heads": B,  # the twin writes heads first
+                             "loss_rows_fused": 0, "loss_rows_compiler": B * T,  # every row to the tile loop; tiles this small stay the compiler's
                              "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0}  # one attention layer, its operands the jnp lines'; no pass loop
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]

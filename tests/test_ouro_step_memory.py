@@ -5,10 +5,14 @@ chip: the TPU's compiler is installed here).
 
 The configuration file's rule (``assumed.per_chip_batch``, PR 36's): the
 largest of 2, 1 rows of 8,192 tokens that leaves at least 0.5 GB of a v5e's
-15.75.  One row reads 15.02 GB and is taken; two read 19.32 and do not fit the
-chip at all.  The compile also holds the attention kernels at 16 key-value
-heads of one query head at head 128 and the operand kernels WITHOUT head
-norms to Mosaic's rules, inside the pass loop's body.  A file of its own: the
+15.75.  One row reads 14.49 GB and is taken; two read 18.86 and do not fit the
+chip at all (15.02 and 19.32 until PR 47, while a tile of the loss held the
+float32 logits ``[2736, 49152]`` and the log-softmax written out beside them:
+``models/loss_tile.py`` writes a bfloat16 cotangent and no log-softmax).  The
+compile also holds the attention kernels at 16 key-value heads of one query
+head at head 128, the operand kernels WITHOUT head norms and the loss tile's
+kernel at a vocabulary of 49,152 to Mosaic's rules, the first two inside the
+pass loop's body.  A file of its own: the
 suite runs ``--dist loadfile`` and each case compiles for most of a minute.
 """
 
@@ -84,8 +88,8 @@ def _step_gb(rows: int) -> dict:
 
 @pytest.mark.parametrize("rows", [1, 2])
 def test_the_cells_batch_is_the_largest_that_leaves_half_a_gigabyte(rows):
-    """One row fits with room (15.02 GB: 6.116 of arguments, 8.753 of scratch,
-    0.154 of code: 0.73 GB free); two do not fit the chip (19.32: 13.16 of
+    """One row fits with room (14.49 GB: 6.116 of arguments, 8.211 of scratch,
+    0.158 of code: 1.26 GB free); two do not fit the chip (18.86: 12.70 of
     scratch).  The cell runs the batch the rule gives."""
     cell = _bench_file("workloads", "ouro_2_6b_clm_pk.seq8k_mor_stream")
     gb = _step_gb(rows)
@@ -93,7 +97,7 @@ def test_the_cells_batch_is_the_largest_that_leaves_half_a_gigabyte(rows):
     assert gb["outputs_not_aliased"] < 0.001                    # the state is donated
     fits = gb["total"] <= CHIP_GB - FREE_GB
     if rows == 1:
-        assert gb["total"] == pytest.approx(15.02, abs=0.15) and fits, gb
+        assert gb["total"] == pytest.approx(14.49, abs=0.15) and fits, gb
     else:
-        assert gb["total"] == pytest.approx(19.32, abs=0.3) and CHIP_GB < gb["total"] and not fits, gb
+        assert gb["total"] == pytest.approx(18.86, abs=0.3) and CHIP_GB < gb["total"] and not fits, gb
     assert (rows <= cell["per_chip_batch"]) == fits

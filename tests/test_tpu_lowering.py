@@ -9,12 +9,14 @@ a compile and says nothing about agreement; ``chip_smoke.py`` owns both.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from lakesoul_tpu.annplane import ragged
-from lakesoul_tpu.models import causal_lm, qwen3_next
+from lakesoul_tpu.models import causal_lm, loss_tile, qwen3_next
 from lakesoul_tpu.parallel import moe
 from lakesoul_tpu.tensorplane.smoke import enumerate_pallas_kernels
 from lakesoul_tpu.vector import kernels
@@ -130,6 +132,15 @@ def _put_tiles(d):
     )
 
 
+def _loss_tile(d):
+    # the Ouro cell's tile of the head's logits: 2,736 stacked positions of 49,152, the cotangent in bfloat16, blocks of
+    # 64 whole rows (the last ragged)
+    del d
+    return jax.jit(functools.partial(loss_tile.loss_tile.__wrapped__, dtype=jnp.bfloat16, interpret=False)).trace(
+        _sds((2736, 49152)), _sds((2736,), jnp.int32), _sds((2736,))
+    )
+
+
 ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads, query heads each serves, head size
     "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256), "trinity-mini": (4, 8, 128),
     "ouro": (16, 1, 128),
@@ -205,6 +216,7 @@ TRACERS = {
     "lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel": _unit_lower_inverse,
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_fwd_kernel": _gated_delta_forward,
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_bwd_kernel": _gated_delta_backward,
+    "lakesoul_tpu/models/loss_tile.py::_loss_tile_kernel": _loss_tile,
     "lakesoul_tpu/parallel/moe.py::_take_rows_kernel": _take_rows,
     "lakesoul_tpu/parallel/moe.py::_put_rows_kernel": _put_rows,
     "lakesoul_tpu/parallel/moe.py::_expert_dw_kernel": _expert_dw,
