@@ -28,7 +28,7 @@ Four complementary layers:
   (Thread targets, pool submissions, pipeline stages, ``do_*`` handlers)
   feeding Eraser-style static locksets (``shared-state-race``,
   ``racy-check-then-act``) and the zero-copy buffer-lifetime rules
-  (``view-escapes-release``, ``ring-aliasing``).
+  (``view-escapes-release``).
 - :mod:`rules.boundedness` + :mod:`leakcheck` — the resource-boundedness
   pack: five lifecycle rules over the shared thread-root/call-graph
   indexes (``unbounded-queue``, ``unbounded-growth``,

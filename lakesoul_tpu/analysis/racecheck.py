@@ -23,15 +23,6 @@ Mechanics:
   and the racing writer's).  Reads are not tracked (that would need
   ``__getattribute__`` interception on every access — the write-write
   detector is the 90% case and costs ~nothing when disarmed).
-- **Ring canary/poison mode**: ``_BufferRing.next_slot`` is patched so
-  every slot hand-out first checks, per buffer, that no borrower still
-  holds a reference (the slot's arrays must be referenced by the slot
-  dict alone — a live delivered batch means the consumer violated the
-  ``LAKESOUL_COLLATE_REUSE`` contract and is about to read overwritten
-  bytes), then fills the buffers with a poison byte pattern so any stale
-  read that does survive is loud garbage instead of plausible training
-  data.  Collate overwrites every row of the slot, so poisoning is
-  invisible to conforming consumers (byte-identity preserved).
 
 Violations are *recorded*, not raised — instrumentation must never change
 program behavior; the conftest fixture arms the detector for
@@ -43,7 +34,6 @@ from __future__ import annotations
 
 import itertools
 import os
-import sys
 import threading
 import traceback
 import weakref
@@ -78,14 +68,10 @@ HOT_CLASSES = (
     ("lakesoul_tpu.vector.serving", "AnnEndpoint"),
 )
 
-_RING_MODULE = "lakesoul_tpu.data.jax_iter"
-_RING_CLASS = "_BufferRing"
-_POISON = 0xAB
-
 
 @dataclass
 class Violation:
-    kind: str  # "shared-state-write" | "ring-use-after-release"
+    kind: str  # "shared-state-write"
     message: str
     stacks: tuple[str, ...] = ()
 
@@ -203,44 +189,6 @@ def _checked_setattr(orig, label: str):
     return __setattr__
 
 
-# ------------------------------------------------------------- ring canary
-
-
-def _checked_next_slot(orig):
-    def next_slot(self):
-        slot = orig(self)
-        if _STATE.enabled:
-            _canary_check(slot)
-        return slot
-
-    next_slot._racecheck_orig = orig
-    return next_slot
-
-
-def _canary_check(slot: dict) -> None:
-    for name in list(slot.keys()):
-        # a slot buffer about to be overwritten must be referenced by the
-        # slot dict alone: dict entry + getrefcount's argument = 2.  More
-        # means a borrower still holds the previous window's batch.
-        if sys.getrefcount(slot[name]) > 2:
-            with _STATE.lock:
-                if _STATE.enabled:
-                    _STATE.violations.append(Violation(
-                        "ring-use-after-release",
-                        f"collate ring slot buffer {name!r} is being reused "
-                        "while a borrowed view is still live — the consumer "
-                        "holds more batches than the ring covers "
-                        "(LAKESOUL_COLLATE_REUSE contract: copy out before "
-                        "the ring wraps)",
-                        (_stack_summary(),),
-                    ))
-        arr = slot[name]
-        try:
-            arr.view("uint8")[...] = _POISON  # poison: stale reads go loud
-        except (TypeError, ValueError, AttributeError):
-            pass  # non-contiguous/odd dtype: detection still stands
-
-
 # ----------------------------------------------------------------- control
 
 
@@ -284,12 +232,6 @@ def _instrument_hot_classes() -> None:
         cls = getattr(mod, clsname, None)
         if cls is not None:
             instrument_class(cls)
-    ring_mod = importlib.import_module(_RING_MODULE)
-    ring = getattr(ring_mod, _RING_CLASS, None)
-    if ring is not None and not hasattr(ring.next_slot, "_racecheck_orig"):
-        orig = ring.next_slot
-        ring.next_slot = _checked_next_slot(orig)
-        _STATE.patched.append((ring, "next_slot", orig))
 
 
 def enable() -> None:

@@ -12,7 +12,7 @@ over the shared project call graph — ``Project.callgraph()``).  On top of
 those ride the themed packs — device (jit/pallas trace safety),
 concurrency (thread-root locksets + buffer lifetimes), durability (atomic
 publication), isolation (READ COMMITTED portability), and boundedness
-(resource budgets + thread/child/scratch lifecycles) — 40 rules total.
+(resource budgets + thread/child/scratch lifecycles) — 39 rules total.
 """
 
 from __future__ import annotations
@@ -50,10 +50,7 @@ from lakesoul_tpu.analysis.rules.isolation import (
     SqliteIsmRule,
     TxnBoundaryRule,
 )
-from lakesoul_tpu.analysis.rules.lifetime import (
-    RingAliasingRule,
-    ViewEscapesReleaseRule,
-)
+from lakesoul_tpu.analysis.rules.lifetime import ViewEscapesReleaseRule
 from lakesoul_tpu.analysis.rules.loops import UnstoppableLoopRule
 from lakesoul_tpu.analysis.rules.perf import HotPathMaterializeRule
 from lakesoul_tpu.analysis.rules.process import RawProcessRule
@@ -110,7 +107,6 @@ def all_rules() -> list[Rule]:
         SharedStateRaceRule(),
         RacyCheckThenActRule(),
         ViewEscapesReleaseRule(),
-        RingAliasingRule(),
         # device pack (jit/pallas trace safety)
         TraceImpureCallRule(),
         TraceHostSyncRule(),

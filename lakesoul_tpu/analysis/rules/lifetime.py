@@ -1,40 +1,19 @@
-"""Zero-copy buffer-lifetime rules for the PR-8 collate machinery.
+"""Zero-copy buffer-lifetime rule for the PR-8 collate machinery.
 
 The scan path's speed comes from *borrowing*: ``_np_column_views`` hands
-out numpy views over Arrow batch buffers, and the opt-in
-``LAKESOUL_COLLATE_REUSE`` ring hands out output-buffer sets that are
-**overwritten in place** once the ring wraps.  Both are only sound inside
-a window discipline — a view travels with the batch that owns its bytes,
-and a ring slot is dead the moment the ring wraps back to it.  Nothing
-type-checks that discipline, and a violation is not a crash but silently
-corrupt training data.  Two rules pin it:
+out numpy views over Arrow batch buffers.  That is only sound inside a
+window discipline — a view travels with the batch that owns its bytes.
+Nothing type-checks that discipline, and a violation is not a crash but
+silently corrupt training data.  One rule pins it:
 
-- ``view-escapes-release``: the result of ``_np_column_views(batch)`` or
-  ``<ring>.next_slot()`` must stay inside the borrowing function's window:
-  passing it as a call argument is the sanctioned hand-off
-  (``window.collate(slot)``), and storing a *view* together with its
-  owning batch in one tuple is the rebatcher's keep-alive idiom
+- ``view-escapes-release``: the result of ``_np_column_views(batch)`` must
+  stay inside the borrowing function's window: passing it as a call
+  argument is the sanctioned hand-off, and storing a view together with
+  its owning batch in one tuple is the rebatcher's keep-alive idiom
   (``self._pending.append((b, views))``).  Everything else escapes the
-  release point: storing a bare view/slot on ``self`` or into a
-  container, returning it, or closing over it in a nested function — the
-  borrower then outlives the slot and reads bytes a later window already
-  overwrote.
-- ``ring-aliasing``: every ``_BufferRing(...)`` construction must sit
-  under a guard that either excludes ``cache='device'`` or consults the
-  tensor plane's MEASURED aliasing probe
-  (``delivery_copies(...)``/``device_put_copies(...)``,
-  tensorplane/dlpack.py).  The device-resident epoch KEEPS every
-  delivered batch, and an aliasing ``device_put`` borrows the host
-  buffer — a ring under either condition would overwrite live data in
-  place.  The probe is the sanctioned hand-off: when every column's put
-  is a real copy, slot reuse cannot touch delivered (or cached) data, so
-  a probe-guarded ring is sound on any backend.  The guard lives in one
-  ``if`` today; this rule keeps any future ring construction honest.
-
-The runtime half (``analysis/racecheck.py``) closes what the lexical
-rules cannot see: its ring canary checks, at each slot hand-out, that no
-borrower still holds the previous window's buffers, and poisons the slot
-so a stale read is loud garbage instead of plausible data.
+  release point: storing a bare view on ``self`` or into a container,
+  returning it, or closing over it in a nested function — the borrower
+  then outlives the batch that owns the bytes.
 """
 
 from __future__ import annotations
@@ -55,8 +34,6 @@ from lakesoul_tpu.analysis.engine import (
 SCOPE = ("data/jax_iter.py",)
 
 _VIEW_FACTORY = "_np_column_views"
-_SLOT_METHOD = "next_slot"
-_RING_CTOR = "_BufferRing"
 
 # container methods a borrowed value must not be handed into
 _STORE_METHODS = {
@@ -65,10 +42,10 @@ _STORE_METHODS = {
 }
 
 
-def _tracked_call(value: ast.expr) -> "str | tuple[str, str | None] | None":
-    """Classify an RHS: ``("view", source_name)`` for ``_np_column_views(x)``,
-    ``("slot", None)`` for ``<ring>.next_slot()``, else None.  IfExp arms
-    are checked too (``views = _np_column_views(b) if cap else None``)."""
+def _tracked_call(value: ast.expr) -> "tuple[str | None] | None":
+    """``(source_name,)`` where the RHS is ``_np_column_views(x)``, else None.
+    IfExp arms are checked too (``views = _np_column_views(b) if cap else
+    None``)."""
     if isinstance(value, ast.IfExp):
         return _tracked_call(value.body) or _tracked_call(value.orelse)
     if not isinstance(value, ast.Call):
@@ -79,15 +56,13 @@ def _tracked_call(value: ast.expr) -> "str | tuple[str, str | None] | None":
         src = value.args[0].id if (
             value.args and isinstance(value.args[0], ast.Name)
         ) else None
-        return ("view", src)
-    if isinstance(value.func, ast.Attribute) and value.func.attr == _SLOT_METHOD:
-        return ("slot", None)
+        return (src,)
     return None
 
 
 class ViewEscapesReleaseRule(Rule):
     id = "view-escapes-release"
-    title = "borrowed view / ring slot escapes its release point"
+    title = "borrowed view escapes its release point"
 
     def __init__(self, scope: tuple = SCOPE):
         self.scope = scope
@@ -98,181 +73,63 @@ class ViewEscapesReleaseRule(Rule):
         for _, body in enclosing_function_bodies(module.tree):
             nodes = list(walk_stopping_at_functions(body))
             views: dict[str, str | None] = {}  # name -> owning-batch name
-            slots: set[str] = set()
             for node in nodes:
                 if isinstance(node, ast.Assign):
-                    kind = _tracked_call(node.value)
-                    if kind is None:
+                    tracked = _tracked_call(node.value)
+                    if tracked is None:
                         continue
                     for t in node.targets:
                         if isinstance(t, ast.Name):
-                            if kind[0] == "view":
-                                views[t.id] = kind[1]
-                            else:
-                                slots.add(t.id)
-            if not views and not slots:
-                continue
-            yield from self._scan_escapes(module, nodes, views, slots)
+                            views[t.id] = tracked[0]
+            if views:
+                yield from self._scan_escapes(module, nodes, views)
 
     # ------------------------------------------------------------- escapes
-    def _borrowed(self, expr: ast.expr, views, slots) -> "tuple[str, str] | None":
-        """``(kind, name)`` when ``expr`` hands a borrowed value onward
-        WITHOUT its keep-alive: a bare tracked name, or a tuple/list that
-        contains a tracked view but NOT the batch that owns its bytes
-        (slots have no keep-alive — any containerized escape is a bug)."""
+    def _borrowed(self, expr: ast.expr, views) -> "str | None":
+        """The name of a borrowed view ``expr`` hands onward WITHOUT its
+        keep-alive: a bare tracked name, or a tuple/list that contains a
+        tracked view but NOT the batch that owns its bytes."""
         if isinstance(expr, ast.Name):
-            if expr.id in slots:
-                return ("ring slot", expr.id)
-            if expr.id in views:
-                return ("view", expr.id)
-            return None
+            return expr.id if expr.id in views else None
         if isinstance(expr, (ast.Tuple, ast.List)):
             names = {e.id for e in expr.elts if isinstance(e, ast.Name)}
-            for n in names & slots:
-                return ("ring slot", n)
             for n in names & set(views):
                 src = views[n]
                 if src is None or src not in names:
-                    return ("view", n)  # travelling without its batch
-            return None
+                    return n  # travelling without its batch
         return None
 
-    def _scan_escapes(self, module, nodes, views, slots) -> Iterable[Finding]:
-        def finding(line: int, kind: str, name: str, how: str) -> Finding:
+    def _scan_escapes(self, module, nodes, views) -> Iterable[Finding]:
+        def finding(line: int, name: str, how: str) -> Finding:
             return Finding(
                 self.id,
                 module.relpath,
                 line,
-                f"{kind} {name!r} {how} — it escapes the release point: the "
-                "borrower can outlive the window and read bytes a later "
-                "window already overwrote (views must travel with their "
-                "owning batch; ring slots must not outlive one collate)",
+                f"view {name!r} {how} — it escapes the release point: the "
+                "borrower can outlive the batch that owns its bytes (views "
+                "must travel with their owning batch)",
             )
 
         for node in nodes:
             if isinstance(node, ast.Assign):
                 if _tracked_call(node.value) is not None:
                     continue  # the tracking assignment itself
-                hit = self._borrowed(node.value, views, slots)
-                if hit is not None:
-                    kind, name = hit
-                    target = node.targets[0]
-                    if isinstance(target, ast.Name):
-                        continue  # local rebind stays inside the window
-                    yield finding(node.lineno, kind, name, "is stored")
+                name = self._borrowed(node.value, views)
+                if name is not None and not isinstance(node.targets[0], ast.Name):
+                    # a local rebind stays inside the window
+                    yield finding(node.lineno, name, "is stored")
             elif isinstance(node, ast.Return) and node.value is not None:
-                hit = self._borrowed(node.value, views, slots)
-                if hit is not None:
-                    kind, name = hit
-                    yield finding(node.lineno, kind, name, "is returned")
+                name = self._borrowed(node.value, views)
+                if name is not None:
+                    yield finding(node.lineno, name, "is returned")
             elif isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute):
                 if node.func.attr not in _STORE_METHODS:
                     continue
                 for arg in node.args:
-                    hit = self._borrowed(arg, views, slots)
-                    if hit is not None:
-                        kind, name = hit
-                        yield finding(
-                            node.lineno, kind, name,
-                            f"is stored via .{node.func.attr}(...)",
-                        )
-            elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
-                                   ast.Lambda)):
-                captured = {
-                    n.id for n in ast.walk(node)
-                    if isinstance(n, ast.Name)
-                } & (set(views) | slots)
+                    name = self._borrowed(arg, views)
+                    if name is not None:
+                        yield finding(node.lineno, name, f"is stored via .{node.func.attr}(...)")
+            elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)):
+                captured = {n.id for n in ast.walk(node) if isinstance(n, ast.Name)} & set(views)
                 for name in sorted(captured):
-                    kind = "ring slot" if name in slots else "view"
-                    yield finding(
-                        node.lineno, kind, name, "is closed over"
-                    )
-
-
-class RingAliasingRule(Rule):
-    id = "ring-aliasing"
-    title = "_BufferRing built without an aliasing guard"
-
-    # guard calls that measure aliasing for real (tensorplane/dlpack.py):
-    # a ring under `if delivery_copies(...)` only arms when every column's
-    # device_put is a genuine copy, which is strictly safer than the
-    # lexical cache!='device' exclusion
-    _PROBE_GUARDS = frozenset({"delivery_copies", "device_put_copies"})
-
-    def __init__(self, scope: tuple = SCOPE):
-        self.scope = scope
-
-    def check(self, module: Module) -> Iterable[Finding]:
-        if not any(s in module.relpath for s in self.scope):
-            return
-        parents = module.parents()
-        for node in module.walk():
-            if not isinstance(node, ast.Call):
-                continue
-            name = dotted_name(node.func)
-            if (name or "").rsplit(".", 1)[-1] != _RING_CTOR:
-                continue
-            if self._aliasing_guarded(node, parents):
-                continue
-            yield Finding(
-                self.id,
-                module.relpath,
-                node.lineno,
-                "_BufferRing(...) constructed without an aliasing guard — "
-                "either the cache='device' exclusion or the measured "
-                "delivery_copies(...) probe: the device-resident epoch "
-                "keeps every delivered batch and an aliasing device_put "
-                "borrows host buffers, so an unguarded reuse ring would "
-                "overwrite live data in place",
-            )
-
-    @classmethod
-    def _aliasing_guarded(cls, call: ast.Call, parents) -> bool:
-        prev: ast.AST = call
-        node: ast.AST = call
-        while node in parents:
-            prev, node = node, parents[node]
-            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                return False
-            test = None
-            if isinstance(node, (ast.If, ast.IfExp)):
-                test = node.test
-            if test is None:
-                continue
-            if any(
-                isinstance(sub, ast.Constant) and sub.value == "device"
-                for sub in ast.walk(test)
-            ):
-                return True
-            # probe guard: only sanctioned when the probe's TRUTH selects
-            # the ring — the ctor must sit in the if-BODY and the probe
-            # call must not be negated; `if not delivery_copies(...):` (or
-            # building the ring in the else branch) is the inverted-guard
-            # bug this rule exists to catch, not a guard
-            if cls._in_if_body(node, prev) and cls._unnegated_probe(test):
-                return True
-        return False
-
-    @staticmethod
-    def _in_if_body(branch: ast.AST, child: ast.AST) -> bool:
-        if isinstance(branch, ast.If):
-            return any(child is stmt for stmt in branch.body)
-        if isinstance(branch, ast.IfExp):
-            return child is branch.body
-        return False
-
-    @classmethod
-    def _unnegated_probe(cls, test: ast.expr) -> bool:
-        negated: set = set()
-        for sub in ast.walk(test):
-            if isinstance(sub, ast.UnaryOp) and isinstance(sub.op, ast.Not):
-                negated.update(
-                    n for n in ast.walk(sub.operand) if isinstance(n, ast.Call)
-                )
-        for sub in ast.walk(test):
-            if isinstance(sub, ast.Call) and sub not in negated:
-                name = dotted_name(sub.func)
-                if name is not None and \
-                        name.rsplit(".", 1)[-1] in cls._PROBE_GUARDS:
-                    return True
-        return False
+                    yield finding(node.lineno, name, "is closed over")

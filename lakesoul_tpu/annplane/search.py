@@ -132,7 +132,7 @@ class AnnPlane:
         use_pallas: bool | None = None,
         pallas_interpret: bool = False,
     ):
-        from lakesoul_tpu.vector.kernels import _on_tpu
+        from lakesoul_tpu.utils import platform
 
         if not shards:
             raise VectorIndexError("ANN plane has no shards")
@@ -140,7 +140,7 @@ class AnnPlane:
         self.config: VectorIndexConfig = config.index
         self.shards = shards
         self.manifest = manifest or {}
-        self.use_pallas = _on_tpu() if use_pallas is None else use_pallas
+        self.use_pallas = platform.on_tpu() if use_pallas is None else use_pallas
         self.pallas_interpret = pallas_interpret
         # host path: score independent shards concurrently on the runtime
         # pool (numpy/BLAS release the GIL on the heavy ops); flip off for

@@ -27,7 +27,6 @@ so a ``pjit`` step consumes them without resharding.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Any, Callable, Iterator
@@ -38,7 +37,7 @@ import pyarrow as pa
 from lakesoul_tpu.obs import registry
 from lakesoul_tpu.obs.stages import stage
 from lakesoul_tpu.runtime import pipeline as rt_pipeline
-from lakesoul_tpu.tensorplane.dlpack import aligned_empty, delivery_copies
+from lakesoul_tpu.tensorplane.dlpack import aligned_empty
 
 
 class LoaderStats:
@@ -293,14 +292,14 @@ class _Window:
             [b.slice(s, ln) for b, _, s, ln in self.parts]
         )
 
-    def collate(self, buffers: "dict[str, np.ndarray] | None") -> dict[str, np.ndarray]:
+    def collate(self) -> dict[str, np.ndarray]:
         """Fused rebatch+collate: one ``out[pos:pos+len] = view[s:s+len]``
-        memcpy per (column, part) into per-column output buffers —
-        ``buffers`` (a reuse-ring slot) or freshly allocated once.  A window
-        that is a single slice of one batch (the common case: the scan
-        already emits ``batch_size``-row batches, so windows align) doesn't
-        even copy — the numpy views pass straight through, sliced."""
-        if buffers is None and len(self.parts) == 1:
+        memcpy per (column, part) into freshly allocated per-column output
+        buffers.  A window that is a single slice of one batch (the common
+        case: the scan already emits ``batch_size``-row batches, so windows
+        align) doesn't even copy — the numpy views pass straight through,
+        sliced."""
+        if len(self.parts) == 1:
             b, views, s, ln = self.parts[0]
             if s == 0 and ln == len(b):
                 return dict(views)
@@ -308,16 +307,11 @@ class _Window:
         first_views = self.parts[0][1]
         out: dict[str, np.ndarray] = {}
         for name, proto in first_views.items():
-            shape = (self.nrows,) + proto.shape[1:]
-            buf = None if buffers is None else buffers.get(name)
-            if buf is None or buf.shape != shape or buf.dtype != proto.dtype:
-                # 64-byte-aligned output buffers (tensorplane.dlpack): the
-                # XLA CPU client only zero-copies aligned host buffers, so
-                # alignment is what makes the device_put hand-off
-                # provably copy-free instead of malloc-luck-dependent
-                buf = aligned_empty(shape, proto.dtype)
-                if buffers is not None:
-                    buffers[name] = buf
+            # 64-byte-aligned output buffers (tensorplane.dlpack): the
+            # XLA CPU client only zero-copies aligned host buffers, so
+            # alignment is what makes the device_put hand-off
+            # provably copy-free instead of malloc-luck-dependent
+            buf = aligned_empty((self.nrows,) + proto.shape[1:], proto.dtype)
             pos = 0
             for _, views, s, ln in self.parts:
                 v = views[name]
@@ -329,55 +323,6 @@ class _Window:
                 pos += ln
             out[name] = buf
         return out
-
-
-def _schema_np_dtypes(scan) -> "list[np.dtype] | None":
-    """The numpy dtypes the zero-copy collate fast path can emit for this
-    scan (fixed-width columns; tensor columns contribute their element
-    dtype) — the inputs of the ``delivery_copies`` aliasing probe that
-    decides whether the reuse ring may arm.  None when the schema cannot
-    be resolved: the probe then reports "assume aliasing" and the ring
-    stays down."""
-    try:
-        schema = scan.projected_schema()
-    except Exception:
-        return None
-    out: list[np.dtype] = []
-    for field in schema:
-        t = field.type
-        if pa.types.is_fixed_size_list(t):
-            t = t.value_type
-        try:
-            dt = np.dtype(t.to_pandas_dtype())
-        except Exception:
-            continue
-        if dt != object:
-            out.append(dt)
-    return out or None
-
-
-class _BufferRing:
-    """Round-robin pool of collate output buffer sets (opt-in via
-    ``LAKESOUL_COLLATE_REUSE=1``): with ``size`` ≥ the number of windows that
-    can be live at once (prefetch queue + device-put pipeline + in-flight),
-    steady-state collate allocates NOTHING — each window overwrites the
-    buffers of a window the consumer has already retired.  Only safe when
-    the consumer copies batches out (e.g. ``device_put`` to a non-host
-    backend) before ``size`` further batches are drawn; the default path
-    allocates fresh buffers per window.  That contract is machine-checked:
-    ``LAKESOUL_RACECHECK=1`` (analysis/racecheck.py) wraps ``next_slot``
-    with a canary that flags any slot handed out while a borrower still
-    references its buffers, then poisons the dead bytes so a stale read
-    is loud garbage instead of plausible training data."""
-
-    def __init__(self, size: int):
-        self._slots: list[dict[str, np.ndarray]] = [{} for _ in range(max(1, size))]
-        self._next = 0
-
-    def next_slot(self) -> dict[str, np.ndarray]:
-        slot = self._slots[self._next]
-        self._next = (self._next + 1) % len(self._slots)
-        return slot
 
 
 class _Rebatcher:
@@ -608,31 +553,6 @@ class JaxBatchIterator:
             } or None
         except Exception:  # scans without resolvable schemas keep the
             self._tensor_shapes = None  # per-type collate contract
-        # opt-in collate-buffer reuse ring (see _BufferRing contract); sized
-        # to cover every window that can be live at once.  The disarm
-        # condition keys on MEASURED aliasing (tensorplane/dlpack.py), not a
-        # platform guess: PR 9's ring canary caught host-backed device_put
-        # aliasing dtype-matching columns (float32 stays down on CPU), but a
-        # loader whose every column demotes (int64/float64 under disabled
-        # x64) pays a REAL copy per put — there the ring re-arms, on any
-        # backend, and under cache='device' too (a pinned batch that owns
-        # its bytes cannot be overwritten by slot reuse).  Host-consumer
-        # loaders (device_put=False) keep the old contract: the consumer
-        # copies batches out before the ring wraps, and cache='device'
-        # requires device_put anyway.
-        self._ring: _BufferRing | None = None
-        if (
-            collate_fn is None
-            and os.environ.get("LAKESOUL_COLLATE_REUSE") == "1"
-            and (
-                (not device_put and cache != "device")
-                or (device_put and delivery_copies(_schema_np_dtypes(scan),
-                                                   sharding))
-            )
-        ):
-            self._ring = _BufferRing(
-                max(1, prefetch) + max(1, device_prefetch) + 2
-            )
         # the queue stage carries this loader's consumer tag so multi-client
         # stall is attributable per client
         self._consumer = consumer or "local"
@@ -759,8 +679,7 @@ class JaxBatchIterator:
                 if window.fast and self._collate is _default_collate:
                     # fused zero-copy path: views → output buffers, no
                     # intermediate table, no per-column combine_chunks
-                    slot = self._ring.next_slot() if self._ring is not None else None
-                    batch = window.collate(slot)
+                    batch = window.collate()
                 elif self._collate is _default_collate:
                     batch = _default_collate(window.to_table(), self._tensor_shapes)
                 else:
@@ -885,8 +804,7 @@ class JaxBatchIterator:
         # the tensor plane places each batch on the sharding (else the
         # default device) and checks it landed there.  The collate buffers
         # are 64-byte aligned so that on CPU nothing copies; on TPU only
-        # the H2D DMA does (demoted dtypes pay the cast).  The ring probe
-        # measures the same device_put, so its verdict governs this path.
+        # the H2D DMA does (demoted dtypes pay the cast).
         from lakesoul_tpu.tensorplane.dlpack import deliver
 
         sharding = self._sharding

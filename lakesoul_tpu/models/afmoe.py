@@ -56,7 +56,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from lakesoul_tpu.models.causal_lm import ATTN_SCOPE, _rms_norm, lm_loss, normal_init as normal, softmax_attention
+from lakesoul_tpu.models.causal_lm import ATTN_SCOPE, lm_loss, normal_init as normal, softmax_attention
+from lakesoul_tpu.models.norms import rms_norm
 from lakesoul_tpu.parallel.moe import route_sigmoid_top_k
 
 SWA_SCOPE = "lakesoul.lm.swa"  # the window layers' mixers; the full layers' stand under ATTN_SCOPE
@@ -136,7 +137,7 @@ class AfmoeConfig:
         ), SWA_SCOPE if local else ATTN_SCOPE
 
     def norm(self, x, w):
-        return _rms_norm(x, w, self.rms_norm_eps, centred=False)
+        return rms_norm(x, w, self.rms_norm_eps, centred=False)
 
     def route(self, x, router_w, bias):
         return route_sigmoid_top_k(

@@ -35,7 +35,7 @@ A looped family's step (:func:`loop_loss`): the walk through the layers
 ``loop_passes`` times as one ``lax.scan`` whose body ends in the final norm
 (the normed state is what the next pass starts from and what the head reads),
 the passes' states stacked; then ONE call of the head and the loss's tile loop
-over the stacked states with a weight a position (``models/bert.py:
+over the stacked states with a weight a position (``models/head_loss.py:
 labelled_nll``'s weighted form: the exit distribution), the exit gate and the
 expected loss under :data:`EXIT_SCOPE`.  ``params["exit"]`` holds the gate
 (``w`` [h], ``b``): ordinary trained leaves.
@@ -46,13 +46,9 @@ loss times ``cfg.mtp_loss_weight`` on top (:func:`mtp_loss`): the final hidden
 states and the next token's embedding through one more layer of the stack's
 last kind, with its own weights, to the token after next, through the main
 model's embedding and head matrix.  The head then runs twice a step.  Every
-loss here goes through ``models/bert.py: labelled_nll``'s tile loop and hands
-it :func:`fused_tile` as the tile's body: ``jax.vjp`` of the head, one Pallas
-kernel from the float32 logits to each row's NLL and the logits' cotangent
-(``models/loss_tile.py``), the head's pull-back; a tile smaller than any it
-is measured at runs the loop's default body, the compiler's log-softmax and
-autodiff, as the masked-LM loss always does (it passes no body, and ``models/bert.py`` imports
-no Pallas).
+loss here goes through ``models/head_loss.py: labelled_nll``'s tile loop and
+hands it ``models/loss_tile.py: fused_tile`` as the tile's body
+(:func:`_head_nll`); both are described where they live.
 
 A layer is ``h = x + mixer(norm1(x)); x' = h + ffn(norm2(h))``, and where its
 weights hold ``norm1_out`` and ``norm2_out`` it has four norms, each
@@ -65,38 +61,14 @@ it, is state that no gradient and no optimizer touches (``models/train.py:
 _adamw_step``); ``buffers["layers"][i]`` belongs to layer ``i`` and
 ``buffers["mtp"]`` to the prediction module's layer.
 
-Softmax attention (:func:`causal_attention`, every family's) is two Pallas
-kernels under one ``custom_vjp``, compiled on a TPU and in the Pallas
-interpreter elsewhere, for the head sizes and row lengths :func:`_flash_tiles`
-takes (a head of 64, 128 or 256 channels, a row of whole 128-key tiles: the
-five published models at 8,192 tokens: groups of 4 at head 64, of 8 at 256, of
-1 at 256 on 20 key-value heads, of 8 at 128, and of 1 at 128 on 16 key-value
-heads); any other shape runs the blockwise ``jnp``
-path, the kernels' twin.  It has two masks: causal, and under a ``window`` the
-band of a query's own position and the ``window - 1`` before it; the kernels'
-grid is the list of (query tile, key tile) pairs either mask lets anything
-through (:func:`_flash_pairs`), so a key tile a window hides whole is neither
-fetched nor multiplied, and :func:`key_tile_steps` counts the list for the
-step's counters.  Of the scores nothing leaves VMEM in either pass;
-the backward pass keeps the output and the log-sum-exp, which
-:func:`_row_by_row`'s checkpoint holds on to by name (:data:`ATTN_KEPT`), so a
-step runs the forward kernel once a row and the backward kernel once.
-Between its projections and the kernels :func:`softmax_attention` norms each
-head, turns it by its position, scales the query and lays heads before
-tokens, by the operand kernels where :func:`_operand_tiles` takes the shape;
-where the mixer's weights hold no ``q_norm`` and no ``k_norm`` (plain
-attention) neither path norms, a static flag of the kernels as ``turned`` is.
-The two sides of the flash pair speak two layouts: the operands arrive heads
-first and the three gradients go back so, the output leaves token-major,
-``[B, T, heads x D]`` as the gate and ``w_o`` read it.  Where
-:func:`_token_major` takes the shape (a head of whole 128-lane tiles: every
-published head but 64) that is the kernels' own block spec: the forward
-kernel stores each head of a group at its column slice of a ``bq``-token
-block, the backward kernel loads the cotangent and the kept output from
-there (once a query tile, and sums ``delta = sum(o * do)`` from them then),
-and no layout copy stands between the kernels and ``w_o`` in either pass;
-elsewhere the output is written heads first, ``delta`` is XLA's, and the
-output is transposed with ``jnp`` inside :func:`causal_attention`.
+A mixer's softmax attention is ``models/attention.py``'s:
+:func:`softmax_attention` and :func:`latent_attention` make a layer's raw
+query, key and value and hand them over (``attention_operands``,
+``causal_attention``); which kernel a shape gets, the kernels, their layouts
+and their ``jnp`` twins are that module's and described there.  What the stack
+knows of it: the output comes back token-major for ``w_o``, and the names of
+what the backward pass keeps (``attention.ATTN_KEPT``), which
+:func:`_row_by_row`'s checkpoint holds on to.
 
 The model may be one chip's share of an expert-parallel job: ``experts_held``
 says which of the ``num_experts`` live here (``parallel/moe.py: held_experts``)
@@ -108,21 +80,16 @@ from __future__ import annotations
 
 import functools
 import math
-import threading
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from lakesoul_tpu.models.bert import head_tile, labelled_nll, tile_grads
-from lakesoul_tpu.models.loss_tile import loss_tile, tile_takes
+from lakesoul_tpu.models.attention import ATTN_KEPT, _rotary, attention_operands, causal_attention, mixer_counts
+from lakesoul_tpu.models.head_loss import head_tile, labelled_nll
+from lakesoul_tpu.models.loss_tile import fused_tile, tile_takes
 from lakesoul_tpu.parallel.mesh import spec_axes
 from lakesoul_tpu.parallel.moe import EXPERTS_SCOPE, ROUTE_SCOPE, SHARED_SCOPE, held_experts, shared_expert
-from lakesoul_tpu.parallel.ring_attention import block_attn
-from lakesoul_tpu.vector.kernels import _on_tpu
 
 ATTN_SCOPE = "lakesoul.lm.attn"
 MLA_SCOPE = "lakesoul.lm.mla"  # inside ATTN_SCOPE: what latent attention adds around the kernels
@@ -131,33 +98,12 @@ MLP_SCOPE = "lakesoul.lm.mlp"
 HEAD_SCOPE = "lakesoul.lm.head"
 EMBED_SCOPE = "lakesoul.lm.embed"  # the token lookup and, through its transpose, the scatter-add of its gradient
 EXIT_SCOPE = "lakesoul.lm.exit"    # a looped model's exit gate, its distribution over the passes and the expected loss
-ATTN_KEPT = ("attn_out", "attn_lse")  # ``checkpoint_name``s of what the flash kernels' backward pass keeps
-ATTN_BAND = 1024   # blockwise: queries that share one static slice of the keys
-ATTN_ROWS = 128    # blockwise: queries whose scores live at once
-FLASH_HEADS = (64, 128, 256)  # head sizes the flash kernels take: half a lane tile, one, two
-FLASH_KEYS = 512   # keys a tile holds, where the row has as many
-FLASH_ROWS = 1024  # score rows a tile holds: a group's heads x queries, 128 queries at least
-FLASH_ROW_ELEMENTS = 8192 * 256  # T x D at most: a row's float32 dK and dV are 8 MB each at that
-FLASH_VMEM_BYTES = 96 * 2**20    # of a v5e's 128 MiB
-OPERAND_ELEMENTS = 512 * 1024    # tokens x a group's channels a block of the operand kernels holds at most: 1 MB
-OPERAND_VMEM_BYTES = 64 * 2**20
-MASKED = -1e30
 EXIT_MASS_UNIT = 1024  # :func:`exit_loss` counts the exit distribution's mass in this fraction of a position
-_NT = (((1,), (1,)), ((), ()))  # x y^T
-_TN = (((0,), (0,)), ((), ()))  # x^T y
 
 
 def normal_init(key, *shape):
     """A weight matrix from a key: normal(0, 0.02), float32."""
     return (jax.random.normal(key, shape) * 0.02).astype(jnp.float32)
-
-
-def _rms_norm(x, w, eps, *, centred: bool = True):
-    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` in float32 (``* w`` where the
-    weight is not zero-centred); float32 out."""
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return y * ((1.0 + w) if centred else w)
 
 
 def causal_conv(x, w):
@@ -169,743 +115,24 @@ def causal_conv(x, w):
     return sum(xp[:, j:j + t].astype(jnp.float32) * w[:, j] for j in range(taps)).astype(x.dtype)
 
 
-# ------------------------------------------------------ softmax attention
-
-
-def _rotary_angles(positions, rotary_dim: int, theta: float):
-    """The positions' angles [T, rotary_dim // 2], float32."""
-    inv_freq = theta ** (-jnp.arange(rotary_dim // 2, dtype=jnp.float32) * 2.0 / rotary_dim)
-    return positions.astype(jnp.float32)[:, None] * inv_freq
-
-
-def _rotary(x, positions, rotary_dim: int, theta: float):
-    """Rotate the first ``rotary_dim`` channels of x [B, T, H, D] (float32):
-    halves ``[x1 | x2]`` → ``[x1 cos - x2 sin | x2 cos + x1 sin]``."""
-    half = rotary_dim // 2
-    angle = _rotary_angles(positions, rotary_dim, theta)
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-
-
-def _blockwise_attention(q, k, v, band: int, rows: int, window: int | None = None):
-    """:func:`causal_attention` as whole-array ``jnp`` operations: the queries
-    go a band at a time against the keys up to the band's last position (a
-    static slice, so the keys after it cost nothing; under a ``window`` from
-    the first key the band's first query sees), and inside a band
-    ``rows`` queries at a time, each block rematerialised: no more than
-    ``rows`` rows of scores live at once, in either pass, and every one of
-    them in HBM.  What a shape the kernel does not take runs, and the kernel's
-    twin and reference."""
-    b, hkv, groups, t, d = q.shape
-
-    def block(q_blk, k_seen, v_seen, first, key0=0):
-        """q_blk [B, Hkv, G, n, D] at positions first.. against the keys seen,
-        which start at position ``key0``."""
-        n = q_blk.shape[3]
-        pos = jnp.tile(first + jnp.arange(n), groups)
-        if window is None:
-            mask = pos[:, None] >= jnp.arange(k_seen.shape[2])[None, :]
-        else:
-            back = pos[:, None] - (key0 + jnp.arange(k_seen.shape[2]))[None, :]
-            mask = (back >= 0) & (back < window)
-        _, l, o = block_attn(q_blk.reshape(b, hkv, groups * n, d), k_seen, v_seen, 1.0, mask)
-        return (o / l[..., None]).astype(v.dtype).reshape(b, hkv, groups, n, d)
-
-    out = []
-    for start in range(0, t, band):
-        end = min(start + band, t)
-        key0 = 0 if window is None else max(0, start - window + 1)
-        q_band, k_seen, v_seen = q[:, :, :, start:end], k[:, :, key0:end], v[:, :, key0:end]
-        band_block = jax.checkpoint(functools.partial(block, key0=key0))
-        if (end - start) % rows or end - start == rows:
-            out.append(band_block(q_band, k_seen, v_seen, start))
-            continue
-        blocks = (end - start) // rows
-        q_rows = jnp.moveaxis(q_band.reshape(b, hkv, groups, blocks, rows, d), 3, 0)
-        firsts = start + rows * jnp.arange(blocks)
-        o = jax.lax.map(lambda xs: band_block(xs[0], k_seen, v_seen, xs[1]), (q_rows, firsts))
-        out.append(jnp.moveaxis(o, 0, 3).reshape(b, hkv, groups, end - start, d))
-    return jnp.concatenate(out, axis=3)
-
-
-# The flash kernels.  A tile is a key-value head's whole group: ``G`` query
-# heads x ``bq`` queries as the rows of one score tile against ``bk`` keys, so
-# K and V are fetched once a group and dK, dV sum over it inside the kernel.
-# The grid walks the (query tile, key tile) pairs the mask lets anything
-# through, listed in two tables in SMEM: a key tile wholly after a query tile,
-# or under a window wholly before the first key the tile's first query sees,
-# is not in the list, so it is neither fetched nor multiplied.  Only the tiles
-# an edge of the mask crosses build one: a query tile's last key tile (the
-# diagonal's) and, under a window, the key tiles that hold a key the tile's
-# last query no longer sees (the first of the list; the first two where the
-# window is no multiple of the query tile).  One tile may be both.
-
-
-def _flash_tiles(t: int, groups: int, d: int):
-    """(queries, keys) a tile holds for rows of ``t`` tokens, or None where
-    the kernels do not take the shape: a head of :data:`FLASH_HEADS`, a row
-    that is whole tiles of 128 keys, and a row's float32 dK and dV held in VMEM
-    through the backward kernel (twice: the pipeline's two buffers)."""
-    if d not in FLASH_HEADS or t % 128 or t * d > FLASH_ROW_ELEMENTS:
-        return None
-    bk = next(n for n in (512, 256, 128) if n <= FLASH_KEYS and t % n == 0)
-    return max(128, min(bk, FLASH_ROWS // groups)), bk
-
-
-def _token_major(t: int, groups: int, d: int) -> bool:
-    """Whether the flash kernels write the output, and read its cotangent,
-    token-major, [B, T, heads x D] as the gate and ``w_o`` read it: a shape
-    they take (:func:`_flash_tiles`) whose head is whole 128-lane tiles.  A
-    block of that array, ``bq`` tokens by a key-value head's ``G x D`` lanes,
-    is then a query tile of the group, each head at a lane-aligned column
-    slice; two heads of 64 would share a lane tile."""
-    return _flash_tiles(t, groups, d) is not None and d % 128 == 0
-
-
-def _first_key_tile(i, bq: int, bk: int, window: int | None):
-    """The first key tile of query tile ``i`` (a Python or a traced integer):
-    the one that holds the first key the tile's first query sees."""
-    if window is None:
-        return 0
-    seen_from = i * bq - (window - 1)
-    return (max(seen_from, 0) if isinstance(i, int) else jnp.maximum(seen_from, 0)) // bk
-
-
-def _flash_pairs(t: int, bq: int, bk: int, window: int | None = None) -> list[tuple[int, int]]:
-    """The (query tile, key tile) pairs of the grid's second axis: for every
-    query tile its key tiles in order, from :func:`_first_key_tile` to the one
-    that holds the tile's diagonal."""
-    return [(i, j) for i in range(t // bq)
-            for j in range(_first_key_tile(i, bq, bk, window), (i * bq + bq - 1) // bk + 1)]
-
-
-def _flash_steps(t: int, bq: int, bk: int, window: int | None = None):
-    """:func:`_flash_pairs` as the two int32 tables the kernels prefetch →
-    (query tile, key tile) of each step."""
-    return tuple(jnp.asarray(a, jnp.int32) for a in zip(*_flash_pairs(t, bq, bk, window), strict=True))
-
-
-def key_tile_steps(t: int, groups: int, d: int, window: int | None = None) -> tuple[int, int]:
-    """(steps the kernels' list holds for one key-value head of one row, steps
-    a causal list alone would hold): host integers off :func:`_flash_pairs`;
-    (0, 0) for a shape the kernels do not take."""
-    tiles = _flash_tiles(t, groups, d)
-    if tiles is None:
-        return 0, 0
-    return len(_flash_pairs(t, *tiles, window)), len(_flash_pairs(t, *tiles))  # a window of t or more hides no tile
-
-
-def _lanes(x, n: int):
-    """x [rows, 128], every lane of a row the same, as [rows, n]."""
-    return x[:, :n] if n <= 128 else jnp.tile(x, (1, n // 128))
-
-
-def _seen(i, j, bq: int, bk: int, shape, *, queries: int, causal: bool = True, window: int | None = None):
-    """Whether a score's key is at or before its query (``causal``) and, under
-    a ``window``, among the query's own position and the ``window - 1`` before
-    it, over a tile of ``shape`` whose axis ``queries`` runs over the group's
-    rows (head-major: row ``r`` is query ``r % bq`` of the tile) and whose
-    other axis over keys."""
-    pos = i * bq + (jax.lax.broadcasted_iota(jnp.int32, shape, queries) & (bq - 1))
-    key = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - queries)
-    if window is None:
-        return pos >= key
-    inside = pos - key < window
-    return (pos >= key) & inside if causal else inside
-
-
-def _flash_step(qi_ref, kj_ref, bq: int, bk: int, window: int | None = None):
-    """(query tile, key tile, the query tile's first and last key tiles) of
-    this grid step."""
-    i, j = qi_ref[pl.program_id(1)], kj_ref[pl.program_id(1)]
-    return i, j, _first_key_tile(i, bq, bk, window), (i * bq + bq - 1) // bk
-
-
-def _flash_tile_kinds(tile, i, j, last, bq: int, bk: int, window: int | None, finish):
-    """Run ``tile(mask)`` for this step, ``mask(shape, queries)`` being None
-    on a tile no edge of the mask crosses; after a query tile's last key tile
-    ``finish()``.  Without a window: the last tile alone is masked."""
-    def edge(causal):
-        return lambda shape, queries: _seen(i, j, bq, bk, shape, queries=queries, causal=causal, window=window)
-
-    if window is None:
-        pl.when(j < last)(functools.partial(tile, None))
-    else:
-        hidden = j * bk < i * bq + bq - window  # the tile holds a key the tile's last query no longer sees
-        pl.when((j < last) & jnp.logical_not(hidden))(functools.partial(tile, None))
-        pl.when((j < last) & hidden)(functools.partial(tile, edge(False)))
-
-    @pl.when(j == last)
-    def _():
-        tile(edge(True))
-        finish()
-
-
-def _group_rows(ref, groups: int, d: int):
-    """A group's query tile as the kernels multiply it, [G*bq, D] head-major,
-    from a token-major block [bq, G*D]: the heads' column slices, whole lane
-    tiles each, one under the other."""
-    return jnp.concatenate([ref[:, g * d:(g + 1) * d] for g in range(groups)], axis=0)
-
-
-def _store_group_rows(ref, x, groups: int, d: int):
-    """x [G*bq, D] head-major into a query tile's block, cast: heads first
-    [G, bq, D], or token-major [bq, G*D] (:func:`_group_rows` the other way)."""
-    if len(ref.shape) == 3:
-        ref[...] = x.reshape(ref.shape).astype(ref.dtype)
-        return
-    bq = ref.shape[0]
-    for g in range(groups):
-        ref[:, g * d:(g + 1) * d] = x[g * bq:(g + 1) * bq].astype(ref.dtype)
-
-
-def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, bq, bk,
-                      window=None):
-    """One step: a group's query tile [G, bq, D] against a key tile [bk, D].
-    Running maximum and sum [G*bq, 128] (every lane the same) and the weighted
-    values [G*bq, D] stay in VMEM over a query tile's steps; the last of them
-    divides and writes the output (heads first, or token-major where its block
-    is: :func:`_store_group_rows`) and the log-sum-exp [G, 1, bq]."""
-    i, j, first, last = _flash_step(qi_ref, kj_ref, bq, bk, window)
-    groups, _, d = q_ref.shape
-    rows = groups * bq
-    f32 = jnp.float32
-
-    @pl.when(j == first)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, MASKED)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def tile(mask):
-        v = v_ref[...]
-        s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], _NT, preferred_element_type=f32)
-        if mask is not None:
-            s = jnp.where(mask(s.shape, 0), s, MASKED)
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_next, bk))
-        alpha = jnp.exp(m_prev - m_next)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_next
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jnp.dot(p.astype(v.dtype), v, preferred_element_type=f32)
-
-    def finish():
-        l = l_ref[...]
-        _store_group_rows(o_ref, acc_ref[...] / _lanes(l, d), groups, d)
-        lse = (m_ref[...] + jnp.log(l)).T[:1]  # [1, G*bq]: a row's queries along the lanes
-        for g in range(groups):
-            lse_ref[g] = lse[:, g * bq:(g + 1) * bq]
-
-    _flash_tile_kinds(tile, i, j, last, bq, bk, window, finish)
-
-
-def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, with_ref, dq_ref, dk_ref, dv_ref, dq_acc,
-                      *assembled, bq, bk, window=None):
-    """One step of the backward pass, on the forward kernel's grid: the scores
-    of the tile again from q, k and the log-sum-exp, keys down the sublanes
-    ([bk, G*bq]: the log-sum-exp and ``delta = sum(o * do)`` are rows, and dV
-    and dK plain products), their share of dQ into VMEM until the query tile's
-    last step, of dK and dV into the row's whole float32 dK, dV [T, D], which
-    stay in VMEM over all of a key-value head's steps.
-
-    Heads first, ``do_ref`` [G, bq, D] is the operand as it lies and
-    ``with_ref`` holds ``delta`` [G, 1, bq], an XLA reduction.  Token-major
-    (``assembled``: two more buffers in VMEM), ``do_ref`` and ``with_ref`` are
-    the cotangent's and the kept output's blocks [bq, G*D], and a query tile's
-    first step lays the cotangent's heads one under the other
-    (:func:`_group_rows`) and sums ``delta`` from the two, once for all of the
-    tile's steps."""
-    i, j, first, last = _flash_step(qi_ref, kj_ref, bq, bk, window)
-    groups, _, d = q_ref.shape
-    rows = groups * bq
-    f32 = jnp.float32
-
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        dk_ref[...] = jnp.zeros_like(dk_ref)
-        dv_ref[...] = jnp.zeros_like(dv_ref)
-
-    @pl.when(j == first)
-    def _():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        if assembled:
-            do_rows, delta_row = assembled
-            do = _group_rows(do_ref, groups, d)
-            do_rows[...] = do
-            delta = jnp.sum(_group_rows(with_ref, groups, d).astype(f32) * do.astype(f32), axis=1, keepdims=True)
-            delta_row[...] = jnp.broadcast_to(delta, (rows, 128)).T[:1]  # [1, G*bq]: a row's queries along the lanes
-
-    def tile(mask):
-        q = q_ref[...].reshape(rows, d)
-        do = assembled[0][...] if assembled else do_ref[...].reshape(rows, d)
-        k, v = k_ref[...], v_ref[...]
-        lse = jnp.concatenate([lse_ref[g] for g in range(groups)], axis=1)
-        delta = assembled[1][...] if assembled else jnp.concatenate([with_ref[g] for g in range(groups)], axis=1)
-        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
-        if mask is not None:
-            s = jnp.where(mask(s.shape, 1), s, MASKED)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        keys = pl.ds(pl.multiple_of(j * bk, bk), bk)
-        dv_ref[keys, :] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=f32)
-        dk_ref[keys, :] += jnp.dot(ds, q, preferred_element_type=f32)
-        dq_acc[...] += jax.lax.dot_general(ds, k, _TN, preferred_element_type=f32)
-
-    def finish():
-        dq_ref[...] = dq_acc[...].reshape(groups, bq, d).astype(dq_ref.dtype)
-
-    _flash_tile_kinds(tile, i, j, last, bq, bk, window, finish)
-
-
-def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_shapes, batch: int | None = None):
-    """What the two kernels' ``pallas_call``s share, over q's [N, G, T, D]:
-    the grid (key-value heads, steps of :func:`_flash_steps`) with the two
-    tables in SMEM, and block specs by what a block follows: a query tile's
-    [G, bq, D], its per-query floats [G, 1, bq], a key tile's [bk, D], a
-    key-value head's whole [T, D]; ``in_specs`` and ``out_specs`` name those.
-    With ``batch`` (the ``N`` key-value heads are those of ``batch`` rows)
-    also ``tokens``, the query tile in a token-major array [batch, T, heads x
-    D]: [bq, G*D] at the row's tokens and the key-value head's columns.
-    Returns (the tables, the call's keyword arguments)."""
-    n, groups, t, d = q.shape
-    tables = _flash_steps(t, bq, bk, window)
-    specs = {
-        "query": pl.BlockSpec((None, groups, bq, d), lambda h, s, qi, kj: (h, 0, qi[s], 0)),
-        "per_query": pl.BlockSpec((None, groups, 1, bq), lambda h, s, qi, kj: (h, 0, 0, qi[s])),
-        "keys": pl.BlockSpec((None, bk, d), lambda h, s, qi, kj: (h, kj[s], 0)),
-        "whole_row": pl.BlockSpec((None, t, d), lambda h, s, qi, kj: (h, 0, 0)),
-    }
-    if batch is not None:
-        kv = n // batch
-        specs["tokens"] = pl.BlockSpec((None, bq, groups * d), lambda h, s, qi, kj: (h // kv, qi[s], h % kv))
-    return tables, dict(
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(n, tables[0].shape[0]), scratch_shapes=scratch_shapes,
-            in_specs=[specs[s] for s in in_specs], out_specs=[specs[s] for s in out_specs],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=FLASH_VMEM_BYTES
-        ),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "batch", "interpret"))
-def _flash_forward(q, k, v, *, bq: int, bk: int, window: int | None = None, batch: int | None = None, interpret: bool):
-    """q [N, G, T, D], k, v [N, T, D] → (o [N, G, T, D], log-sum-exp
-    [N, G, 1, T] float32).  With ``batch`` (:func:`_token_major` shapes: the
-    ``N`` key-value heads are ``batch`` rows') o is written token-major,
-    [batch, T, heads x D] with a key-value head's group side by side: the
-    same values at the addresses the gate and ``w_o`` read."""
-    n, groups, t, d = q.shape
-    rows = groups * bq
-    tables, grid = _flash_grid(
-        q, bq, bk, window, in_specs=("query", "keys", "keys"), batch=batch,
-        out_specs=("query" if batch is None else "tokens", "per_query"),
-        scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32)] * 2 + [pltpu.VMEM((rows, d), jnp.float32)],
-    )
-    o_shape = q.shape if batch is None else (batch, t, n // batch * groups * d)
-    return pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, bq=bq, bk=bk, window=window),
-        out_shape=(jax.ShapeDtypeStruct(o_shape, v.dtype), jax.ShapeDtypeStruct((n, groups, 1, t), jnp.float32)),
-        name="flash_attention_fwd", interpret=interpret, **grid,
-    )(*tables, q, k, v)
-
-
-@functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "interpret"))
-def _flash_backward(q, k, v, o, lse, do, *, bq: int, bk: int, window: int | None = None, interpret: bool):
-    """The three gradients, dK and dV summed over the group; ``o`` and ``do``
-    as :func:`_flash_forward` wrote ``o``.  Heads first, [N, G, T, D]:
-    ``delta = sum(o * do)`` is an XLA reduction and an operand of the kernel.
-    Token-major, [B, T, heads x D]: the kernel reads both through the output's
-    block spec and sums ``delta`` itself (as an XLA reduction over token-major
-    arrays its [T, heads] result wants relaying into [N, G, 1, T], and XLA
-    writes the float32 products out whole to do that)."""
-    _, groups, _, d = q.shape
-    rows = groups * bq
-    scratch = [pltpu.VMEM((rows, d), jnp.float32)]
-    if o.ndim == 3:
-        batch, given, do_spec, given_spec = o.shape[0], o, "tokens", "tokens"
-        scratch += [pltpu.VMEM((rows, d), do.dtype), pltpu.VMEM((1, rows), jnp.float32)]
-    else:
-        batch, do_spec, given_spec = None, "query", "per_query"
-        given = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, :, None, :]
-    tables, grid = _flash_grid(
-        q, bq, bk, window, batch=batch, in_specs=("query", "keys", "keys", do_spec, "per_query", given_spec),
-        out_specs=("query", "whole_row", "whole_row"), scratch_shapes=scratch,
-    )
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, bq=bq, bk=bk, window=window),
-        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), *[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2),
-        name="flash_attention_bwd", interpret=interpret, **grid,
-    )(*tables, q, k, v, do, lse, given)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, bq, bk, window, batch):
-    return _flash_attention_fwd(q, k, v, bq, bk, window, batch)[0]
-
-
-def _flash_attention_fwd(q, k, v, bq, bk, window, batch):
-    o, lse = _flash_forward(q, k, v, bq=bq, bk=bk, window=window, batch=batch, interpret=not _on_tpu())
-    # a checkpoint around the caller may keep these two and run no second forward kernel
-    o, lse = checkpoint_name(o, ATTN_KEPT[0]), checkpoint_name(lse, ATTN_KEPT[1])
-    return o, (q, k, v, o, lse)
-
-
-def _flash_attention_bwd(bq, bk, window, batch, kept, do):
-    return _flash_backward(*kept, do, bq=bq, bk=bk, window=window, interpret=not _on_tpu())
-
-
-_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
-
-
-# The operand kernels.  Between the projections and the flash kernels a mixer
-# norms each query and key head, turns it by its position, scales the query,
-# casts, and lays heads before tokens.  Where :func:`_operand_tiles` takes the
-# shape, one kernel does that in one pass over the projections' outputs and a
-# second the transpose of it: a block is ``bt`` tokens of one key-value head's
-# group, a head one row of whole lane tiles a token, and the permutation is
-# the block specs' (no transpose inside).  Float32 inside, one rounding at the
-# end, as the ``jnp`` lines they stand for (:func:`_xla_operands`, their twin).
-
-
-def _operand_tiles(t: int, heads: int, kv_heads: int, d: int, rotary_dim: int | None):
-    """Tokens a block of the operand kernels holds, or None where they do not
-    take the shape: one the flash kernels take (:func:`_flash_tiles`), a head
-    of whole 128-lane tiles, and positions over the whole head or none (a turn
-    is then a roll by half a head)."""
-    if heads % kv_heads or _flash_tiles(t, heads // kv_heads, d) is None or d % 128 or rotary_dim not in (None, d):
-        return None
-    width = heads // kv_heads * d
-    return next(n for n in (512, 256, 128) if t % n == 0 and (n == 128 or n * width <= OPERAND_ELEMENTS))
-
-
-def _head_operand(x, w, turn, eps: float):
-    """x [n, D] float32, a head's raw channels a token → normed
-    (:func:`_rms_norm` by the weight as it multiplies; not where ``w`` is
-    None: a mixer without head norms) and turned: ``[x1 cos -
-    x2 sin | x2 cos + x1 sin]`` as ``y * [cos | cos] + roll(y) * [-sin | sin]``."""
-    y = x if w is None else _rms_norm(x, w, eps, centred=False)
-    if turn is None:
-        return y
-    return y * turn[0] + pltpu.roll(y, y.shape[1] // 2, 1) * turn[1]
-
-
-def _turned_back(dz, turn):
-    """A turned head's cotangent [n, D] turned by the negative angle (as it is
-    where the layer sees no positions)."""
-    return dz if turn is None else dz * turn[0] - pltpu.roll(dz, dz.shape[1] // 2, 1) * turn[1]
-
-
-def _head_operand_grads(x, w, turn, dz, eps: float):
-    """:func:`_head_operand` of a normed head transposed: the cotangent ``dz``
-    [n, D] → (the raw channels', the weight's summed over every eighth token:
-    [8, D])."""
-    r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    y = x * r
-    dz = _turned_back(dz, turn)
-    dy = dz * w
-    dx = r * (dy - y * jnp.mean(dy * y, axis=-1, keepdims=True))
-    return dx, jnp.sum((dz * y).reshape(-1, 8, x.shape[1]), axis=0)
-
-
-def _operands_fwd_kernel(*refs, groups: int, eps: float, turned: bool, normed: bool = True):
-    """One block: ``bt`` tokens of a key-value head's ``groups`` query heads
-    [bt, G*D], its key and value [bt, D] → the query [G, bt, D] scaled, the
-    key and the value [bt, D].  ``normed``: the two norm weights are among
-    the operands (after the value) and the heads are normed by them."""
-    q_ref, k_ref, v_ref, *given, qo_ref, ko_ref, vo_ref = refs
-    wq_ref, wk_ref = given[:2] if normed else (None, None)
-    d = k_ref.shape[1]
-    f32 = jnp.float32
-    turn = tuple(a[...] for a in given[2 * normed:]) if turned else None
-    for g in range(groups):
-        q = _head_operand(q_ref[:, g * d:(g + 1) * d].astype(f32), wq_ref[...] if normed else None, turn, eps)
-        qo_ref[g] = (q * d**-0.5).astype(qo_ref.dtype)
-    ko_ref[...] = _head_operand(k_ref[...].astype(f32), wk_ref[...] if normed else None, turn, eps).astype(ko_ref.dtype)
-    vo_ref[...] = v_ref[...]
-
-
-def _operands_bwd_kernel(*refs, groups: int, eps: float, turned: bool, normed: bool = True):
-    """:func:`_operands_fwd_kernel` transposed, on its grid: the operands'
-    cotangents and the raw query and key → the raw cotangents in the
-    projections' layout and this block's share of the two norm weights'
-    gradients [8, D] float32.  Not ``normed``: the cotangents alone in, the
-    raw cotangents alone out (turning back needs neither the raw query nor
-    the raw key)."""
-    f32 = jnp.float32
-    if not normed:
-        dqo_ref, dko_ref, dvo_ref, *turn, dq_ref, dk_ref, dv_ref = refs
-        d = dko_ref.shape[1]
-        turn = tuple(a[...] for a in turn) if turned else None
-        for g in range(groups):
-            dq_ref[:, g * d:(g + 1) * d] = _turned_back(dqo_ref[g].astype(f32) * d**-0.5, turn).astype(dq_ref.dtype)
-        dk_ref[...] = _turned_back(dko_ref[...].astype(f32), turn).astype(dk_ref.dtype)
-        dv_ref[...] = dvo_ref[...]
-        return
-    dqo_ref, dko_ref, dvo_ref, q_ref, k_ref, wq_ref, wk_ref, *turn, dq_ref, dk_ref, dv_ref, dwq_ref, dwk_ref = refs
-    d = k_ref.shape[1]
-    turn = tuple(a[...] for a in turn) if turned else None
-    dwq = jnp.zeros((8, d), f32)
-    for g in range(groups):
-        heads = slice(g * d, (g + 1) * d)
-        dq, dw = _head_operand_grads(
-            q_ref[:, heads].astype(f32), wq_ref[...], turn, dqo_ref[g].astype(f32) * d**-0.5, eps
-        )
-        dq_ref[:, heads] = dq.astype(dq_ref.dtype)
-        dwq += dw
-    dwq_ref[...] = dwq
-    dk, dwk_ref[...] = _head_operand_grads(k_ref[...].astype(f32), wk_ref[...], turn, dko_ref[...].astype(f32), eps)
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dvo_ref[...]
-
-
-def _operand_grid(q, k, d: int, bt: int, turned: bool, normed: bool, *, in_specs, out_specs):
-    """What the two kernels' ``pallas_call``s share, over the raw q [B, T,
-    heads*D] and k [B, T, kv*D]: the grid (rows, token blocks, key-value
-    heads: the heads innermost, so a block of the position tables is fetched
-    once) and block specs by what a block follows: the ``raw`` query's [bt,
-    G*D] and key's or value's ``raw_kv`` [bt, D], the flash kernels' ``laid``
-    [G, bt, D] and ``laid_kv`` [bt, D], a weight gradient's ``share`` [8, D];
-    after ``in_specs`` come, where the heads are ``normed``, the two norm
-    weights [1, D] and, where the layer turns, the two position tables' [bt, D].  Returns (key-value heads, query
-    heads each serves, the call's keyword arguments)."""
-    b, t, width = k.shape
-    kv, groups = width // d, q.shape[2] // width
-    specs = {
-        "raw": pl.BlockSpec((None, bt, groups * d), lambda r, i, h: (r, i, h)),
-        "raw_kv": pl.BlockSpec((None, bt, d), lambda r, i, h: (r, i, h)),
-        "laid": pl.BlockSpec((None, None, groups, bt, d), lambda r, i, h: (r, h, 0, i, 0)),
-        "laid_kv": pl.BlockSpec((None, None, bt, d), lambda r, i, h: (r, h, i, 0)),
-        "share": pl.BlockSpec((None, None, None, 8, d), lambda r, i, h: (r, i, h, 0, 0)),
-    }
-    weight = pl.BlockSpec((1, d), lambda r, i, h: (0, 0))
-    table = pl.BlockSpec((bt, d), lambda r, i, h: (i, 0))
-    return kv, groups, dict(
-        grid=(b, t // bt, kv),
-        in_specs=[specs[s] for s in in_specs] + [weight] * (2 * normed) + [table] * (2 * turned),
-        out_specs=[specs[s] for s in out_specs],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3, vmem_limit_bytes=OPERAND_VMEM_BYTES
-        ),
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("d", "eps", "bt", "interpret"))
-def _operands_forward(q, k, v, wq, wk, turn, *, d: int, eps: float, bt: int, interpret: bool):
-    """The raw q [B, T, heads*D], k, v [B, T, kv*D] as the products leave
-    them, the norm weights [D] as they multiply (both None: heads that are not
-    normed), ``turn`` None or the position
-    tables ``([cos | cos], [-sin | sin])`` [T, D] float32 → the flash kernels'
-    q [B, kv, G, T, D] (scaled), k, v [B, kv, T, D]."""
-    b, t, _ = q.shape
-    normed = wq is not None
-    kv, groups, grid = _operand_grid(
-        q, k, d, bt, turn is not None, normed, in_specs=("raw", "raw_kv", "raw_kv"),
-        out_specs=("laid", "laid_kv", "laid_kv"),
-    )
-    return pl.pallas_call(
-        functools.partial(_operands_fwd_kernel, groups=groups, eps=eps, turned=turn is not None, normed=normed),
-        out_shape=(jax.ShapeDtypeStruct((b, kv, groups, t, d), q.dtype),
-                   *(jax.ShapeDtypeStruct((b, kv, t, d), a.dtype) for a in (k, v))),
-        name="attn_operands_fwd", interpret=interpret, **grid,
-    )(q, k, v, *((wq[None], wk[None]) if normed else ()), *(turn or ()))
-
-
-@functools.partial(jax.jit, static_argnames=("d", "eps", "bt", "interpret"))
-def _operands_backward(dq, dk, dv, q, k, wq, wk, turn, *, d: int, eps: float, bt: int, interpret: bool):
-    """The cotangents of :func:`_operands_forward`'s results, and its raw q
-    and k again → the cotangents of the raw q, k, v and of the two norm
-    weights (float32, summed here over the blocks' shares; None where the
-    heads are not normed: the raw q and k then give their shapes alone)."""
-    b, t, _ = q.shape
-    normed = wq is not None
-    kv, groups, grid = _operand_grid(
-        q, k, d, bt, turn is not None, normed,
-        in_specs=("laid", "laid_kv", "laid_kv", *(("raw", "raw_kv") if normed else ())),
-        out_specs=("raw", "raw_kv", "raw_kv", *(("share", "share") if normed else ())),
-    )
-    share = jax.ShapeDtypeStruct((b, t // bt, kv, 8, d), jnp.float32)
-    dq, dk, dv, *shares = pl.pallas_call(
-        functools.partial(_operands_bwd_kernel, groups=groups, eps=eps, turned=turn is not None, normed=normed),
-        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(k.shape, dv.dtype), *([share, share] if normed else [])),
-        name="attn_operands_bwd", interpret=interpret, **grid,
-    )(dq, dk, dv, *((q, k, wq[None], wk[None]) if normed else ()), *(turn or ()))
-    if not normed:
-        return dq, dk, dv, None, None
-    dwq, dwk = shares
-    return dq, dk, dv, dwq.sum((0, 1, 2, 3)).astype(wq.dtype), dwk.sum((0, 1, 2, 3)).astype(wk.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def _attention_operands(q, k, v, wq, wk, turn, d, eps, bt):
-    return _operands_forward(q, k, v, wq, wk, turn, d=d, eps=eps, bt=bt, interpret=not _on_tpu())
-
-
-def _attention_operands_fwd(q, k, v, wq, wk, turn, d, eps, bt):
-    # nothing new is kept: a checkpoint around the caller computes the raw q and k again, as it did
-    return _attention_operands(q, k, v, wq, wk, turn, d, eps, bt), (q, k, wq, wk, turn)
-
-
-def _attention_operands_bwd(d, eps, bt, kept, cotangents):
-    q, k, wq, wk, turn = kept
-    grads = _operands_backward(*cotangents, q, k, wq, wk, turn, d=d, eps=eps, bt=bt, interpret=not _on_tpu())
-    return *grads, jax.tree.map(jnp.zeros_like, turn)  # the tables come from positions alone
-
-
-_attention_operands.defvjp(_attention_operands_fwd, _attention_operands_bwd)
-
-
-def _xla_operands(q, k, v, wq, wk, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):
-    """The raw q [B, T, heads, D], k, v [B, T, kv, D] and the two head norms'
-    weights (both None: a mixer whose heads are not normed, and ``eps`` and
-    ``centred`` are then not read) → the flash kernels' q [B, kv, G, T, D] (normed, turned over
-    ``rotary_dim`` channels, scaled), k (normed, turned), v [B, kv, T, D], as
-    whole-array ``jnp`` operations in float32: what a shape the operand
-    kernels do not take runs, and the kernels' twin."""
-    b, t, heads, d = q.shape
-    kv_heads = k.shape[2]
-    dtype = v.dtype
-    positions = jnp.arange(t)
-
-    def turned(a):
-        return a if rotary_dim is None else _rotary(a, positions, rotary_dim, theta)
-
-    def normed(a, w):
-        return a.astype(jnp.float32) if w is None else _rms_norm(a, w, eps, centred=centred)
-
-    q = turned(normed(q, wq))
-    k = turned(normed(k, wk))
-    q = (q * d**-0.5).astype(dtype)
-    # [B, T, heads, D] → [B, kv, heads // kv, T, D]: each key-value head serves a group
-    q = q.reshape(b, t, kv_heads, heads // kv_heads, d).transpose(0, 2, 3, 1, 4)
-    k, v = (a.transpose(0, 2, 1, 3) for a in (k.astype(dtype), v))
-    return q, k, v
-
-
-def _turn_tables(t: int, d: int, theta: float):
-    """The operand kernels' position tables for a row of ``t`` tokens turned
-    over a whole head: ``([cos | cos], [-sin | sin])`` [T, D] float32."""
-    angle = _rotary_angles(jnp.arange(t), d, theta)
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
-
-
-def _kernel_operands(q, k, v, wq, wk, bt: int, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):
-    """:func:`_xla_operands` by the operand kernels, ``bt`` tokens a block
-    (:func:`_operand_tiles`): the same arguments, the same results."""
-    b, t, _, d = q.shape
-    turn = None if rotary_dim is None else _turn_tables(t, rotary_dim, theta)
-    if wq is not None:
-        wq, wk = (((1.0 + w) if centred else w).astype(jnp.float32) for w in (wq, wk))
-    # the reshapes undo the caller's: the kernels read the products' own [B, T, heads x D]
-    q, k, v = (a.reshape(b, t, -1) for a in (q, k, v))
-    return _attention_operands(q, k, v, wq, wk, turn, d, eps, bt)
-
-
-_traced_tiles = threading.local()  # ``.steps``, ``.operands``: what :func:`mixer_counts` collects while it traces a mixer
-
-
-def mixer_counts(mixer, x, p) -> dict:
-    """What ``mixer(x, p)``'s shapes make its attention run, as host integers
-    from one abstract trace of the mixer (nothing runs), summed over every
-    :func:`causal_attention` and :func:`softmax_attention` it calls:
-    ``attn_tiles_run`` and ``attn_tiles_causal``, the (query tile, key tile)
-    steps the attention kernels' lists hold over its rows and key-value heads
-    and the steps causal lists alone would hold (:func:`key_tile_steps`; 0 for
-    a shape the kernels do not take); ``attn_out_tokens`` and
-    ``attn_out_heads``, its rows by where the attention wrote its output:
-    token-major through the kernels' block specs (:func:`_token_major`), or
-    heads first and transposed after; ``attn_operands_kernel`` and
-    ``attn_operands_xla``, its rows by what made the flash kernels' operands,
-    the operand kernels or the ``jnp`` lines (:func:`_operand_tiles`).  All 0
-    for a mixer without attention."""
-    _traced_tiles.steps, _traced_tiles.operands = steps, operands = [], []
-    try:
-        jax.eval_shape(lambda x, p: mixer(x, p), x, p)  # a function of its own: a trace cached for ``mixer`` collects nothing
-    finally:
-        del _traced_tiles.steps, _traced_tiles.operands
-    return {
-        "attn_tiles_run": sum(run for run, *_ in steps), "attn_tiles_causal": sum(causal for _, causal, *_ in steps),
-        "attn_out_tokens": sum(rows for *_, rows, tokens in steps if tokens),
-        "attn_out_heads": sum(rows for *_, rows, tokens in steps if not tokens),
-        "attn_operands_kernel": sum(rows for rows, fused in operands if fused),
-        "attn_operands_xla": sum(rows for rows, fused in operands if not fused),
-    }
-
-
-def causal_attention(q, k, v, window: int | None = None):
-    """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
-    (scaled), k, v [B, Hkv, T, D] → [B, T, heads, D], tokens before heads as
-    the output projection reads it, a key-value head's group side by side
-    (head ``kv * G + g``).  Two masks: key ``j`` is
-    visible to query ``i`` iff ``j <= i`` and, under a ``window``,
-    ``i - j < window`` (the query's own position and the ``window - 1`` before
-    it); a window of the row's length or more is no window.
-
-    Operands in their own type (bfloat16 in a model), scores, maximum, sum and
-    accumulators in float32, the probabilities cast only as the second
-    product's operand, the division by the sum after the accumulation.
-
-    Where :func:`_flash_tiles` takes the shape (a head of 64, 128 or 256
-    channels, a row of whole 128-key tiles) two Pallas kernels under one
-    ``custom_vjp`` do all of it, compiled on a TPU and in the Pallas
-    interpreter elsewhere: no score leaves VMEM in either pass, and a key tile
-    the mask hides whole is no step of the grid (:func:`_flash_pairs`).  The
-    backward pass keeps the output and the log-sum-exp, named
-    :data:`ATTN_KEPT` for a checkpoint around the caller, and computes the
-    scores again from q, k and the log-sum-exp in float32.  Every other shape
-    runs :func:`_blockwise_attention`.
-
-    The operands come heads first and the three gradients go back so.  The
-    output is the other way round: where :func:`_token_major` takes the shape
-    (a head of whole 128-lane tiles) the forward kernel's output block spec
-    writes it token-major and the backward kernel's reads its cotangent and
-    the kept output there (and sums ``delta`` from them), so no layout copy
-    stands between the kernels and ``w_o`` in either pass; a head of 64, and
-    the blockwise path, write heads first and the transpose here is a copy."""
-    b, hkv, groups, t, d = q.shape
-    if window is not None and window >= t:
-        window = None
-    tokens = _token_major(t, groups, d)
-    if hasattr(_traced_tiles, "steps"):  # :func:`mixer_counts` is tracing the caller
-        _traced_tiles.steps.append((*(b * hkv * n for n in key_tile_steps(t, groups, d, window)), b, tokens))
-    tiles = _flash_tiles(t, groups, d)
-    if tiles is None:
-        o = _blockwise_attention(q, k, v, ATTN_BAND, ATTN_ROWS, window)
-    else:
-        o = _flash_attention(
-            q.reshape(b * hkv, groups, t, d), *(a.reshape(b * hkv, t, d) for a in (k, v)), *tiles, window,
-            b if tokens else None,
-        )
-    if not tokens:  # heads first: laid out for the projection here, by copies
-        o = o.reshape(q.shape).transpose(0, 3, 1, 2, 4)
-    return o.reshape(b, t, hkv * groups, d)
+# ------------------------------------------------------------- the mixers
 
 
 def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_dim: int | None,
                       theta: float, eps: float, centred: bool, gated: bool, window: int | None = None):
     """The grouped-query softmax-attention mixer: x [B, T, h] (normed) →
-    [B, T, h].  ``eps`` and ``centred`` are the family's RMS norm's
-    (:func:`_rms_norm`), here over a head's channels (``q_norm``, ``k_norm``;
-    where the weights hold neither, the heads are not normed: plain attention);
-    ``rotary_dim`` of them are rotated, none where it
-    is None (a layer that sees no positions).  ``window``:
-    :func:`causal_attention`'s.  A gate's sigmoid scales each head's output
-    before ``w_o``: ``gated``: ``w_q`` holds per head the query, then the gate
-    (the Qwen3-Next family); else a matrix of the gate's own where the weights
-    hold one (``w_gate`` [h, heads x D]), else none.
+    [B, T, h].  ``eps`` and ``centred`` are the family's RMS norm's, here over
+    a head's channels (``q_norm``, ``k_norm``; where the weights hold neither,
+    the heads are not normed: plain attention); ``rotary_dim`` of them are
+    rotated, none where it is None (a layer that sees no positions).
+    ``window``: ``causal_attention``'s.  A gate's sigmoid scales each head's
+    output before ``w_o``: ``gated``: ``w_q`` holds per head the query, then
+    the gate (the Qwen3-Next family); else a matrix of the gate's own where
+    the weights hold one (``w_gate`` [h, heads x D]), else none.
 
     Between the projections and the kernels the query and the key are normed,
-    turned, the query scaled, and all three laid out heads first: by the
-    operand kernels in one pass where :func:`_operand_tiles` takes the shape
-    (a head of whole lane tiles, positions over all of it or none), else by
-    :func:`_xla_operands`."""
+    turned, the query scaled, and all three laid out heads first:
+    ``models/attention.py: attention_operands``."""
     dtype = x.dtype
     f32 = jnp.float32
     b, t, _ = x.shape
@@ -918,14 +145,9 @@ def softmax_attention(x, p, *, heads: int, kv_heads: int, head_dim: int, rotary_
         gate = (x @ p["w_gate"].astype(dtype)).reshape(b, t, heads, d)
     k = (x @ p["w_k"].astype(dtype)).reshape(b, t, kv_heads, d)
     v = (x @ p["w_v"].astype(dtype)).reshape(b, t, kv_heads, d)
-    recipe = dict(eps=eps, centred=centred, rotary_dim=rotary_dim, theta=theta)
-    bt = _operand_tiles(t, heads, kv_heads, d, rotary_dim)
-    if hasattr(_traced_tiles, "operands"):  # :func:`mixer_counts` is tracing the caller
-        _traced_tiles.operands.append((b, bt is not None))
-    if bt is None:
-        q, k, v = _xla_operands(q, k, v, p.get("q_norm"), p.get("k_norm"), **recipe)
-    else:
-        q, k, v = _kernel_operands(q, k, v, p.get("q_norm"), p.get("k_norm"), bt, **recipe)
+    q, k, v = attention_operands(
+        q, k, v, p.get("q_norm"), p.get("k_norm"), eps=eps, centred=centred, rotary_dim=rotary_dim, theta=theta
+    )
     # the gate and ``w_o`` over [B, T, heads x D], no head axis: on [.., heads, D] arrays XLA lays the gate's
     # passes tokens-minor for the weight-gradient products and copies the kernels' row-major o and do across
     o = causal_attention(q, k, v, window).reshape(b, t, heads * d)
@@ -1184,41 +406,6 @@ def lm_head(head, x, *, cfg):
         return jnp.einsum("...h,vh->...v", y, head["embed"].astype(dtype), preferred_element_type=jnp.float32)
 
 
-def _tile_fused(head_fn, head, x) -> bool:
-    """Whether a tile of rows ``x`` [tile, h] through ``head_fn`` makes logits
-    the loss kernel takes (``models/loss_tile.py: tile_takes``; shapes alone,
-    nothing runs)."""
-    return tile_takes(*jax.eval_shape(head_fn, head, x).shape)
-
-
-def fused_tile(head_fn, head, x, labels, scale, *weights):
-    """The tile body the causal-LM losses hand :func:`labelled_nll`
-    (``models/bert.py: tile_grads``'s arguments and results): ``jax.vjp`` of
-    whatever head it is given, ONE kernel from the float32 logits to each
-    row's NLL and the logits' cotangent (``models/loss_tile.py``), and the
-    head's pull-back, whose two products stay the compiler's.  The row's
-    coefficient is the loss's ``scale`` (the mean form) or its own weight
-    where it has a label, 0 where it has none.
-
-    The cotangent is written ONCE, in the rows' dtype: the dtype the head's
-    two gradient products take it in.  The compiler's body hands them none:
-    each product makes ``coef x (softmax - onehot)`` again in float32 from the
-    logits inside its operand fusion, and the matrix unit rounds that float32
-    operand to the other operand's bfloat16 at its input (default precision).
-    Rounding the kernel's float32 value to bfloat16 as it is written is that
-    same rounding, at that same place (``PERF.md`` section 6, PR 47: both
-    gradients bit-equal between a float32 and a bfloat16 cotangent on a v5e);
-    under float32 rows it stays float32.
-
-    A tile smaller than any the kernel is measured at (:func:`_tile_fused`
-    false: a tiny model's) runs the compiler's body, the program it had."""
-    if not _tile_fused(head_fn, head, x):
-        return tile_grads(head_fn, head, x, labels, scale, *weights)
-    logits, pull = jax.vjp(head_fn, head, x)
-    coef = jnp.where(labels >= 0, weights[0] if weights else scale, 0.0)
-    nll, g = loss_tile(logits, labels, coef, dtype=x.dtype, interpret=not _on_tpu())
-    part = jnp.sum(coef * nll)
-    return ((part, nll) if weights else part), pull(g.astype(logits.dtype))
 
 
 def _head_nll(head_fn, head, x, labels, batch_sharding, weights=None):
@@ -1230,7 +417,7 @@ def _head_nll(head_fn, head, x, labels, batch_sharding, weights=None):
     axes = () if batch_sharding is None else spec_axes(batch_sharding.spec)
     shards = math.prod(batch_sharding.mesh.shape[axis] for axis in axes)
     tile = jax.ShapeDtypeStruct((head_tile(labels.size // shards), x.shape[-1]), x.dtype)
-    fused = _tile_fused(head_fn, head, tile)
+    fused = tile_takes(*jax.eval_shape(head_fn, head, tile).shape)
     rows = {"loss_rows_fused": labels.size if fused else 0, "loss_rows_compiler": 0 if fused else labels.size}
     return labelled_nll(head_fn, head, x, labels, batch_sharding, weights, fused_tile), rows
 
@@ -1298,16 +485,9 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     module's among them), ``tokens``, and the positions with a label,
     ``head_all`` over both losses and ``head_mtp`` the module's (0 without
     one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32; and
-    six Python integers, known when the step is traced and no operation of it
-    (:func:`mixer_counts`): ``attn_tiles_run`` and ``attn_tiles_causal`` (the
-    attention kernels' grid steps and what causal lists alone would hold, over
-    rows, layers and key-value heads: equal without a window),
-    ``attn_out_tokens`` and ``attn_out_heads`` (attention layer-rows by where
-    the output was written), ``attn_operands_kernel`` and
-    ``attn_operands_xla`` (softmax-attention layer-rows by what made the
-    kernels' operands), ``loss_rows_fused`` and ``loss_rows_compiler`` (the
-    rows handed to the head's tile loop, over both losses, by the body that
-    runs their tiles: :func:`_head_nll`)."""
+    eight Python integers, known when the step is traced and no operation of
+    it: ``models/attention.py: mixer_counts``' six over rows and layers, and
+    :func:`_head_nll`'s two over both losses."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
     with jax.named_scope(HEAD_SCOPE):  # the loss's own loop over tiles of positions around the head
         (loss, _), rows = _head_nll(functools.partial(lm_head, cfg=cfg), head_params(params), x, labels, batch_sharding)

@@ -43,7 +43,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from lakesoul_tpu.models.causal_lm import ATTN_SCOPE, _rms_norm, latent_attention, lm_loss, normal_init as normal
+from lakesoul_tpu.models.causal_lm import ATTN_SCOPE, latent_attention, lm_loss, normal_init as normal
+from lakesoul_tpu.models.norms import rms_norm
 from lakesoul_tpu.parallel.moe import route_sigmoid_top_k
 
 # the switches the layers are written for: any other published value is refused, not ignored
@@ -121,7 +122,7 @@ class Glm4MoeLiteConfig:
         ), ATTN_SCOPE
 
     def norm(self, x, w):
-        return _rms_norm(x, w, self.rms_norm_eps, centred=False)
+        return rms_norm(x, w, self.rms_norm_eps, centred=False)
 
     def route(self, x, router_w, bias):
         return route_sigmoid_top_k(

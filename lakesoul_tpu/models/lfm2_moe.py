@@ -38,12 +38,12 @@ import jax.numpy as jnp
 
 from lakesoul_tpu.models.causal_lm import (
     ATTN_SCOPE,
-    _rms_norm,
     causal_conv,
     lm_loss,
     normal_init as normal,
     softmax_attention,
 )
+from lakesoul_tpu.models.norms import rms_norm
 from lakesoul_tpu.parallel.moe import route_sigmoid_top_k
 
 CONV_SCOPE = "lakesoul.lm.conv"
@@ -104,7 +104,7 @@ class Lfm2MoeConfig:
         return functools.partial(attention, cfg=self), ATTN_SCOPE
 
     def norm(self, x, w):
-        return _rms_norm(x, w, self.norm_eps, centred=False)
+        return rms_norm(x, w, self.norm_eps, centred=False)
 
     def route(self, x, router_w, bias):
         return route_sigmoid_top_k(

@@ -3,7 +3,7 @@
 Between a head's float32 logits ``[rows, vocab]`` and the two products that
 carry the loss's gradient into the head's weights and its rows stand a
 log-softmax, the picked logit and the transpose of both.  Written with
-``jax.nn.log_softmax`` and autodiff (``models/bert.py: _tile_nll`` under
+``jax.nn.log_softmax`` and autodiff (``models/head_loss.py: _tile_nll`` under
 ``jax.value_and_grad``, the tile loop's default body) they are three
 stand-alone passes over ``[rows, vocab]`` in HBM, and each of the two products
 makes the cotangent again from the logits in its operand fusion.
@@ -16,9 +16,11 @@ The arithmetic is ``jax.nn.log_softmax``'s and its transpose's, float32
 throughout: shift by the row maximum, ``lse = log(sum(exp(shifted)))``,
 ``nll = lse - shifted[label]``, ``g = exp(shifted) x (coef / sum) - coef x
 onehot``, and ONE rounding at the end, to the dtype the caller names: the one
-its two products take the cotangent in (``models/causal_lm.py: fused_tile``
-says which and why).  Nothing here knows what made the logits or what a
-coefficient means.  Only ``models/causal_lm.py`` imports this module: the
+its two products take the cotangent in (:func:`fused_tile` says which and
+why).  The kernel knows nothing of what made the logits or what a coefficient
+means; :func:`fused_tile` is the kernel as a tile body of ``models/head_loss.py:
+labelled_nll``'s loop, between ``jax.vjp`` of whatever head it is given and the
+head's pull-back.  Only ``models/causal_lm.py`` imports this module: the
 masked-LM loss keeps the compiler's body and its process imports no Pallas.
 """
 
@@ -30,6 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from lakesoul_tpu.models.head_loss import tile_grads
+from lakesoul_tpu.utils import platform
 
 MIN_TILE_BYTES = 48 * 2**20        # the smallest tile of float32 logits the kernel takes: it is measured to gain at 53 MB and above
 LOSS_TILE_VMEM_BYTES = 64 * 2**20  # of a v5e core's 128 MiB: what the kernel may be given
@@ -140,3 +145,34 @@ def loss_tile(logits, labels, coef, *, dtype, interpret: bool):
         name="loss_tile", interpret=interpret,
     )(logits, labels[:, None], coef[:, None])
     return nll[:, 0], g
+
+
+def fused_tile(head_fn, head, x, labels, scale, *weights):
+    """The tile body the causal-LM losses hand ``models/head_loss.py:
+    labelled_nll`` (``tile_grads``'s arguments and results): ``jax.vjp`` of
+    whatever head it is given, ONE kernel from the float32 logits to each
+    row's NLL and the logits' cotangent (:func:`loss_tile`), and the
+    head's pull-back, whose two products stay the compiler's.  The row's
+    coefficient is the loss's ``scale`` (the mean form) or its own weight
+    where it has a label, 0 where it has none.
+
+    The cotangent is written ONCE, in the rows' dtype: the dtype the head's
+    two gradient products take it in.  The compiler's body hands them none:
+    each product makes ``coef x (softmax - onehot)`` again in float32 from the
+    logits inside its operand fusion, and the matrix unit rounds that float32
+    operand to the other operand's bfloat16 at its input (default precision).
+    Rounding the kernel's float32 value to bfloat16 as it is written is that
+    same rounding, at that same place (``PERF.md`` section 6, PR 47: both
+    gradients bit-equal between a float32 and a bfloat16 cotangent on a v5e);
+    under float32 rows it stays float32.
+
+    A tile smaller than any the kernel is measured at (:func:`tile_takes`
+    false of its logits' shape: a tiny model's) runs the compiler's body, the
+    program it had."""
+    if not tile_takes(*jax.eval_shape(head_fn, head, x).shape):
+        return tile_grads(head_fn, head, x, labels, scale, *weights)
+    logits, pull = jax.vjp(head_fn, head, x)
+    coef = jnp.where(labels >= 0, weights[0] if weights else scale, 0.0)
+    nll, g = loss_tile(logits, labels, coef, dtype=x.dtype, interpret=not platform.on_tpu())
+    part = jnp.sum(coef * nll)
+    return ((part, nll) if weights else part), pull(g.astype(logits.dtype))

@@ -55,7 +55,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from lakesoul_tpu.models.causal_lm import ATTN_SCOPE, _rms_norm, loop_loss, normal_init as normal, softmax_attention
+from lakesoul_tpu.models.causal_lm import ATTN_SCOPE, loop_loss, normal_init as normal, softmax_attention
+from lakesoul_tpu.models.norms import rms_norm
 
 # the switches the layers are written for: any other published value is refused, not ignored
 _EXPECTED = {
@@ -117,7 +118,7 @@ class OuroConfig:
         ), ATTN_SCOPE
 
     def norm(self, x, w):
-        return _rms_norm(x, w, self.rms_norm_eps, centred=False)
+        return rms_norm(x, w, self.rms_norm_eps, centred=False)
 
     def init(self, key: jax.Array) -> dict:
         return init_lm_params(self, key)

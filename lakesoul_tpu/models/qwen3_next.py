@@ -30,7 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from lakesoul_tpu.models.causal_lm import (  # noqa: F401  (the stack, under the names this family's callers import)
     ATTN_SCOPE,
-    _rms_norm,
     causal_conv,
     lm_head,
     lm_hidden,
@@ -39,8 +38,9 @@ from lakesoul_tpu.models.causal_lm import (  # noqa: F401  (the stack, under the
     normal_init as normal,
     softmax_attention,
 )
+from lakesoul_tpu.models.norms import rms_norm
 from lakesoul_tpu.parallel.moe import route_top_k
-from lakesoul_tpu.vector.kernels import _on_tpu
+from lakesoul_tpu.utils import platform
 
 GDN_SCOPE = "lakesoul.lm.gdn"
 GDN_CHUNK = 128    # tokens a DeltaNet chunk holds: a v5e matrix unit is 128 wide (the family's public kernels use 64)
@@ -98,7 +98,7 @@ class Qwen3NextConfig:
         return functools.partial(gated_attention, cfg=self), ATTN_SCOPE
 
     def norm(self, x, w):
-        return _rms_norm(x, w, self.rms_norm_eps)
+        return rms_norm(x, w, self.rms_norm_eps)
 
     def route(self, x, router_w, bias):
         del bias  # the family has none: no assignment is moved
@@ -303,7 +303,7 @@ def unit_lower_inverse(a, dtype=jnp.float32, log_decay=None):
         raise ValueError(f"chunk {c} is not a power of two")
     if c % 128:
         return _unit_lower_inverse_jnp(a, dtype, log_decay)
-    return _unit_lower_inverse_pallas(a, dtype, log_decay, interpret=not _on_tpu())
+    return _unit_lower_inverse_pallas(a, dtype, log_decay, interpret=not platform.on_tpu())
 
 
 def _unit_lower_inverse_fwd(a, dtype, log_decay):
@@ -598,12 +598,12 @@ def _gated_delta(q, k, v, gc, beta, inv, eps):
 
 
 def _gated_delta_fwd(q, k, v, gc, beta, inv, eps):
-    o, states = _gated_delta_forward(q, k, v, gc, beta, inv, eps=eps, interpret=not _on_tpu())
+    o, states = _gated_delta_forward(q, k, v, gc, beta, inv, eps=eps, interpret=not platform.on_tpu())
     return o, (q, k, v, gc, beta, inv, states)
 
 
 def _gated_delta_bwd(eps, kept, do):
-    return _gated_delta_backward(*kept, do, eps=eps, interpret=not _on_tpu())
+    return _gated_delta_backward(*kept, do, eps=eps, interpret=not platform.on_tpu())
 
 
 _gated_delta.defvjp(_gated_delta_fwd, _gated_delta_bwd)

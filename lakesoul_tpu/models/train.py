@@ -95,8 +95,8 @@ MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
 ATTN_KEY_TILES_FAMILY = "lakesoul_train_attn_key_tiles_total"
 ATTN_OPERAND_ROWS_FAMILY = "lakesoul_train_attn_operand_rows_total"
 ATTN_OUTPUT_ROWS_FAMILY = "lakesoul_train_attn_output_rows_total"
-# the rows an LM step hands the head's tile loop for loss and gradients (``models/bert.py: labelled_nll``; every loss
-# the step sums), by the body that runs their tiles: ``{body="fused"}`` ``models/causal_lm.py: fused_tile``'s one
+# the rows an LM step hands the head's tile loop for loss and gradients (``models/head_loss.py: labelled_nll``; every loss
+# the step sums), by the body that runs their tiles: ``{body="fused"}`` ``models/loss_tile.py: fused_tile``'s one
 # kernel between the logits and their cotangent, ``{body="compiler"}`` a float32 log-softmax and autodiff (a tile smaller
 # than any the kernel is measured at); host integers, no operation of the step.  The MLM steps pass no body and feed no series
 LOSS_ROWS_FAMILY = "lakesoul_train_loss_rows_total"
@@ -246,9 +246,11 @@ class _CountedStep:
             state["host"][key] += n
         return params, opt_state, loss
 
-    def lower(self, params, opt_state, *batch):
-        """The step lowered for these arguments, as ``jax.jit(...).lower``."""
-        return self._jitted(opt_state).lower(params, opt_state, self._state["counted"], *batch)
+    def lower(self, params, opt_state, *batch, lowering_platforms=None):
+        """The step lowered for these arguments, as ``jax.jit(...).lower``
+        (for ``lowering_platforms`` where given: ``("tpu",)`` on a host)."""
+        traced = self._jitted(opt_state).trace(params, opt_state, self._state["counted"], *batch)
+        return traced.lower(lowering_platforms=lowering_platforms)
 
     def counts(self) -> dict:
         """{count key: total} over every step dispatched so far."""
@@ -358,53 +360,35 @@ def make_lm_train_state(cfg, plan: MeshPlan, *, lr: float = 1e-4, seed: int = 0)
 def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     """Jitted next-token train step of whichever family ``cfg`` is (its
     ``loss``): (params, opt_state, input_ids, labels) → (params, opt_state,
-    loss); rows arrive sharded P('dp').  Feeds ``lakesoul_train_tokens_total``,
-    ``lakesoul_train_head_positions_total{kind="all"|"mtp"}`` (the positions
-    with a label, over every loss the step sums and over its multi-token-
-    prediction module's alone, 0 for a family without one),
-    ``lakesoul_train_moe_assignments_total{kind="held"|"all"|"tile_rows"|
-    "bias_moved"|"dw_writes"}`` (``tile_rows``: the slots of the expert tiles
-    run, of which ``held`` carried an assignment; ``bias_moved``: the
-    assignments whose expert a routing bias brought into the top k, 0 for a
-    family without one; ``dw_writes``: the times the backward pass writes an
-    expert's weight-gradient sum, for one of the three matrices: once a tile
-    where the sums ride the tile loop, once an expert and segment of tiles
-    where ``parallel/moe.py: expert_dw`` keeps them in VMEM)
-    ``lakesoul_train_moe_expert_load{stat="max"|"mean"}`` (the fullest and
-    the mean held expert's assignments, summed over steps and layers) and
-    ``lakesoul_train_attn_key_tiles_total{kind="run"|"causal"}`` (the (query
-    tile, key tile) steps the attention kernels' lists hold, over the step's
-    rows, attention layers and key-value heads, and the steps causal lists
-    alone would hold: equal for a family without a window, 0 where no shape
-    takes the kernels; Python integers off ``models/causal_lm.py:
-    key_tile_steps`` when the step is traced, added on the host a call: they
-    are no operation of the step) and, by the same route,
-    ``lakesoul_train_attn_operand_rows_total{path="kernel"|"xla"}`` (the
-    step's softmax-attention layer-rows by what made the attention kernels'
-    operands: the operand kernels in one pass where ``models/causal_lm.py:
-    _operand_tiles`` takes the mixer's shape, else the ``jnp`` lines; both 0
-    for a family whose mixers are not ``softmax_attention``) and
-    ``lakesoul_train_attn_output_rows_total{layout="tokens"|"heads"}`` (the
-    step's attention layer-rows by where the attention wrote its output:
-    token-major through the flash kernels' block specs where
-    ``models/causal_lm.py: _token_major`` takes the shape, a head of whole
-    128-lane tiles, so that nothing stands between the kernels and the output
-    projection; else heads first, and a transpose lays it out) and
-    ``lakesoul_train_loss_rows_total{body="fused"|"compiler"}`` (the rows
-    handed to the head's tile loop, over every loss the step sums, by what
-    makes a tile's loss and gradients: ``models/causal_lm.py: fused_tile``'s
-    kernel where a tile's float32 logits are 48 MiB or more (every published
-    shape), else the compiler's log-softmax and autodiff).
+    loss); rows arrive sharded P('dp').  What it feeds, by where the count is
+    made (the families' comments above say what ``{kind=}`` means where two
+    kinds of step share one):
 
-    A looped family (``cfg.loop_passes``: the stack run that many times over
-    one set of weights, a loss after every pass) also feeds
-    ``lakesoul_train_loop_layer_passes_total{kind="run"|"layers"}`` (rows x
-    layers x passes and rows x layers, host integers: 4.0 apart at four
-    passes), ``lakesoul_train_head_positions_total{kind="loop"}`` (the
-    labelled positions of the passes before the last, which ``{kind="all"}``
-    counts with the last pass's: 75% of it at four; 0 in every other family)
-    and the gauge ``lakesoul_train_loop_exit_mass{pass="1".."R"}`` (the mean
-    share of the exit distribution on each pass: sums to 1)."""
+    - ``lakesoul_train_tokens_total`` and ``lakesoul_train_head_positions_total
+      {kind="all"|"mtp"|"loop"}``: ``models/causal_lm.py: lm_loss``,
+      ``exit_loss`` (0 for a family without a prediction module, without a
+      loop);
+    - ``lakesoul_train_moe_assignments_total{kind="held"|"all"|"tile_rows"|
+      "bias_moved"|"dw_writes"}`` and ``lakesoul_train_moe_expert_load{stat=
+      "max"|"mean"}`` (``tile_rows``: the slots of the expert tiles run, of
+      which ``held`` carried an assignment; ``bias_moved``: the assignments
+      whose expert a routing bias brought into the top k; ``dw_writes``: the
+      times the backward pass writes an expert's weight-gradient sum, for one
+      of the three matrices; the load of the fullest and of the mean held
+      expert, summed over steps and layers): ``parallel/moe.py: held_experts``
+      and the family's ``route``, counted on the device; host zeros for a
+      family without experts;
+    - ``lakesoul_train_attn_key_tiles_total{kind="run"|"causal"}``,
+      ``lakesoul_train_attn_operand_rows_total{path="kernel"|"xla"}`` and
+      ``lakesoul_train_attn_output_rows_total{layout="tokens"|"heads"}``:
+      ``models/attention.py: mixer_counts``, Python integers known when the
+      step is traced, added on the host a call and no operation of the step;
+    - ``lakesoul_train_loss_rows_total{body="fused"|"compiler"}``:
+      ``models/causal_lm.py: _head_nll``, by the same route;
+    - of a looped family (``cfg.loop_passes``) also
+      ``lakesoul_train_loop_layer_passes_total{kind="run"|"layers"}``
+      (``causal_lm.py: loop_hidden``, host integers) and the gauge
+      ``lakesoul_train_loop_exit_mass{pass="1".."R"}`` (``exit_loss``)."""
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)

@@ -82,7 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from lakesoul_tpu.parallel.mesh import spec_axes
-from lakesoul_tpu.vector.kernels import _on_tpu
+from lakesoul_tpu.utils import platform
 
 ROUTE_SCOPE = "lakesoul.lm.moe.route"
 EXPERTS_SCOPE = "lakesoul.lm.moe.experts"
@@ -251,7 +251,7 @@ def _add_rows(acc, tok, valid, rows):
     repeats.  ``rows`` are zeros in the other slots."""
     if acc.ndim == 2:
         return acc.at[tok].add(rows)
-    interpret = not _on_tpu()
+    interpret = not platform.on_tpu()
     n = jnp.sum(valid, dtype=jnp.int32)
     seen = take_rows(acc, tok, n, interpret=interpret)
     return put_rows(acc, tok, n, seen + rows[:, None, :], interpret=interpret)
@@ -473,7 +473,7 @@ def _held_experts_bwd(tile, span, saved, dy):
     dy = dy.astype(lo)
     f32 = jnp.float32
     tiles = plan[3][-1]
-    interpret = not _on_tpu()
+    interpret = not platform.on_tpu()
 
     def tile_grads(t, dx, dw_rows):
         """Tile ``t`` → (its expert, ``dx`` and ``dw_rows`` with the tile's

@@ -17,10 +17,8 @@ without being re-discovered, re-collated, or re-copied every epoch:
 - :mod:`dlpack` — the hand-off from collated host buffers into jax:
   ``deliver()`` places a batch on the sharding it was given (else the
   default device) and checks that every leaf landed there, and the
-  empirical :func:`~lakesoul_tpu.tensorplane.dlpack.delivery_copies`
-  probe tells the loader whether ``device_put`` on THIS backend actually
-  copies — the PR-9 ring-disarm rule keys on measured aliasing, not a
-  platform guess.
+  empirical :func:`~lakesoul_tpu.tensorplane.dlpack.device_put_copies`
+  probe measures whether ``device_put`` on THIS backend actually copies.
 - :mod:`replay` — :class:`~lakesoul_tpu.tensorplane.replay.
   DeviceReplayCache`: an HBM-budgeted residency manager
   (``LAKESOUL_REPLAY_BUDGET_BYTES``) that pins epoch-1's collated,
@@ -48,7 +46,6 @@ from lakesoul_tpu.tensorplane.columns import (
 from lakesoul_tpu.tensorplane.dlpack import (
     aligned_empty,
     deliver,
-    delivery_copies,
     device_put_copies,
 )
 from lakesoul_tpu.tensorplane.replay import DeviceReplayCache, ReplaySpill
@@ -61,7 +58,6 @@ __all__ = [
     "validate_tensor_batch",
     "aligned_empty",
     "deliver",
-    "delivery_copies",
     "device_put_copies",
     "DeviceReplayCache",
     "ReplaySpill",

@@ -14,17 +14,11 @@ when a collated host buffer becomes a jax.Array?*
   host CPU device*, so on an accelerator host the batch never left the
   CPU backend, and on the CPU backend it aliased nothing ``device_put``
   does not alias already.)
-- :func:`device_put_copies` / :func:`delivery_copies` measure, per
-  (dtype, target backend), whether ``jax.device_put`` of a host array is
-  a REAL copy or an alias of the host buffer.  PR 9 found the collate
-  reuse ring corrupting live device data because host-backed
-  ``device_put`` aliases dtype-matching buffers; the disarm rule it
-  shipped keyed on the *platform* ("host-backed ⇒ disarm").  The probe
-  replaces the guess with a measurement: an int64/float64-only table on a
-  CPU backend gets its ring back (the demotion cast copies), while a
-  float32 table still disarms.  The loader and the device-resident replay
-  cache both key on it — the lifetime rules (``ring-aliasing``) accept a
-  probe-guarded ring as sanctioned.
+- :func:`device_put_copies` measures, per (dtype, target backend), whether
+  ``jax.device_put`` of a host array is a REAL copy or an alias of the host
+  buffer: a measurement, not a guess from the platform's name (on a CPU
+  backend a float32 buffer aliases; an int64 one is demoted, which copies).
+  The smoke check of the delivery claim reads it.
 
 Probe results are cached per (dtype, device kind) for the process — the
 answer is a property of the backend, not of the call site.
@@ -89,8 +83,7 @@ def device_put_copies(dtype, sharding=None) -> bool:
     onto the delivery target is a REAL copy (the produced jax.Array owns
     bytes disjoint from the source buffer); False when it aliases.  A
     probe that fails on a live backend reports False — "assume aliasing"
-    is the safe answer for every caller (the ring stays down, the replay
-    cache makes a defensive copy); a backend that fails to start raises."""
+    is the safe answer for a caller; a backend that fails to start raises."""
     import jax
 
     dt = np.dtype(dtype)
@@ -121,16 +114,6 @@ def device_put_copies(dtype, sharding=None) -> bool:
     return copied
 
 
-def delivery_copies(dtypes, sharding=None) -> bool:
-    """True only when EVERY dtype's device_put is a real copy — the
-    condition under which a collate output buffer can be reused the moment
-    ``device_put`` returns.  ``dtypes`` None/empty means the caller could
-    not resolve the schema: report False (assume aliasing, stay safe)."""
-    if not dtypes:
-        return False
-    return all(device_put_copies(dt, sharding) for dt in dtypes)
-
-
 def deliver(batch, sharding=None):
     """Collated host pytree → device pytree on the delivery target.
 
@@ -140,8 +123,8 @@ def deliver(batch, sharding=None):
     its params live.  Either way the placement is verified leaf by leaf —
     a batch left on another backend would train on the host, or fail
     inside the step, with nothing pointing back here.  The caller owns the
-    lifetime question: an aliased delivery borrows the collate buffer,
-    which is exactly what :func:`delivery_copies` lets it check."""
+    lifetime question: an aliased delivery borrows the collate buffer
+    (:func:`device_put_copies` says whether a dtype's does)."""
     import jax
 
     from lakesoul_tpu.errors import IOError_

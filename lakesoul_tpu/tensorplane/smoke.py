@@ -364,7 +364,7 @@ def _run_loss_tile(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from lakesoul_tpu.models.bert import _tile_nll
+    from lakesoul_tpu.models.head_loss import _tile_nll
     from lakesoul_tpu.models.loss_tile import block_rows, loss_tile
 
     # a tile of a head's float32 logits to each row's NLL and the logits' cotangent, against
@@ -400,7 +400,7 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from lakesoul_tpu.models.causal_lm import (
+    from lakesoul_tpu.models.attention import (
         ATTN_BAND,
         ATTN_ROWS,
         _blockwise_attention,
@@ -462,7 +462,7 @@ def _run_attention_operands(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from lakesoul_tpu.models.causal_lm import (
+    from lakesoul_tpu.models.attention import (
         _operand_tiles,
         _operands_backward,
         _operands_forward,
@@ -555,8 +555,7 @@ def _run_delivery() -> dict:
     """The delivery claim, checked against where the batch landed: on the
     CPU backend the delivered float32 leaf must ALIAS the collate buffer
     (no host copy anywhere); on an accelerator ``device_put`` must be a
-    REAL copy across the link — precisely the condition that keeps the
-    collate ring armed on-chip (the PR-9 disarm rule's other half)."""
+    REAL copy across the link."""
     from lakesoul_tpu.tensorplane.dlpack import (
         aligned_empty,
         deliver,
@@ -585,8 +584,7 @@ def _run_delivery() -> dict:
             )
     elif not f32_copies:
         raise AssertionError(
-            f"device_put(float32) onto {platform} must be a REAL copy across"
-            " the link — the collate ring's stay-armed condition"
+            f"device_put(float32) onto {platform} must be a REAL copy across the link"
         )
     return {"platform": platform, "f32_device_put_copies": bool(f32_copies)}
 
@@ -667,15 +665,15 @@ def smoke_cases() -> list[SmokeCase]:
         SmokeCase(
             "models.causal_attention", "pallas", _run_causal_attention,
             kernels=(
-                "lakesoul_tpu/models/causal_lm.py::_flash_fwd_kernel",
-                "lakesoul_tpu/models/causal_lm.py::_flash_bwd_kernel",
+                "lakesoul_tpu/models/attention.py::_flash_fwd_kernel",
+                "lakesoul_tpu/models/attention.py::_flash_bwd_kernel",
             ),
         ),
         SmokeCase(
             "models.attention_operands", "pallas", _run_attention_operands,
             kernels=(
-                "lakesoul_tpu/models/causal_lm.py::_operands_fwd_kernel",
-                "lakesoul_tpu/models/causal_lm.py::_operands_bwd_kernel",
+                "lakesoul_tpu/models/attention.py::_operands_fwd_kernel",
+                "lakesoul_tpu/models/attention.py::_operands_bwd_kernel",
             ),
         ),
         SmokeCase(

@@ -283,7 +283,8 @@ class IvfRabitqIndex:
     def _search_device_resident(self, query, params: SearchParams, probe):
         import jax.numpy as jnp
 
-        from lakesoul_tpu.vector.kernels import _fused_search_resident, _on_tpu
+        from lakesoul_tpu.utils import platform
+        from lakesoul_tpu.vector.kernels import _fused_search_resident
 
         bundle = self._get_device_bundle()
         if bundle is None:
@@ -304,7 +305,7 @@ class IvfRabitqIndex:
             bundle["raw"] if do_rerank else jnp.zeros((1, 1), jnp.float32),
             jnp.asarray(query, jnp.float32),
             d=self.quantizer.padded_dim, s=s, k=k,
-            use_pallas=_on_tpu(), do_rerank=do_rerank,
+            use_pallas=platform.on_tpu(), do_rerank=do_rerank,
         )
         dists, idx = np.asarray(dists), np.asarray(idx)
         valid = (idx < bundle["n"]) & np.isfinite(dists)
@@ -583,7 +584,8 @@ class IvfRabitqIndex:
         the resident path doesn't apply.  Does NOT block on the result."""
         import jax.numpy as jnp
 
-        from lakesoul_tpu.vector.kernels import _fused_search_resident_batch, _on_tpu
+        from lakesoul_tpu.utils import platform
+        from lakesoul_tpu.vector.kernels import _fused_search_resident_batch
 
         bundle = self._get_device_bundle()
         if bundle is None:
@@ -642,7 +644,7 @@ class IvfRabitqIndex:
                 bundle["raw"] if do_rerank else jnp.zeros((1, 1), jnp.float32),
                 jnp.asarray(queries),
                 d=self.quantizer.padded_dim, s=s, k=k,
-                use_pallas=_on_tpu(), do_rerank=do_rerank,
+                use_pallas=platform.on_tpu(), do_rerank=do_rerank,
             )
         return dists, idx, nq, bundle
 

@@ -24,9 +24,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
+from lakesoul_tpu.utils import platform
 
 
 # --------------------------------------------------------------------------
@@ -117,7 +115,7 @@ def packed_scan(
         norms = np.pad(np.asarray(norms), (0, n_pad - n))
         factors = np.pad(np.asarray(factors), (0, n_pad - n), constant_values=1.0)
 
-    use_pallas = _on_tpu() if pallas is None else pallas
+    use_pallas = platform.on_tpu() if pallas is None else pallas
     if use_pallas:
         out = packed_scan_pallas(
             jnp.asarray(packed_codes), jnp.asarray(norms), jnp.asarray(factors),
@@ -427,7 +425,7 @@ def fused_search(codes, norms, factors, code_dot_c, csq, csum, q_glob, raw, quer
     do_rerank = raw is not None
     s = min(shortlist, n_pad)
     k = min(top_k, n_pad)
-    use_pallas = _on_tpu() if pallas is None else pallas
+    use_pallas = platform.on_tpu() if pallas is None else pallas
     dists, idx = _fused_search(
         jnp.asarray(codes),
         jnp.asarray(np.asarray(norms, np.float32)),
@@ -490,7 +488,7 @@ def bruteforce_topk(vectors, query, k: int, *, pallas: bool | None = None):
     """Exact L2 top-k over [N, D] vectors: returns (dists [k], indices [k]).
     N is padded to a power-of-2 bucket (pad rows at +inf distance) to keep
     the compiled-shape count logarithmic."""
-    use_pallas = _on_tpu() if pallas is None else pallas
+    use_pallas = platform.on_tpu() if pallas is None else pallas
     n = len(vectors)
     k = min(k, n)
     n_pad = _pow2_bucket(n, floor=max(512, k))

@@ -67,9 +67,8 @@ _TRACECHECK_MODULES = ("test_vector", "test_models_parallel", "test_catalog")
 # concurrent hot classes hard: the pipeline/pool machinery (test_runtime),
 # the admission/breaker/ANN serving surfaces (test_resilience), and the
 # lease heartbeat (test_topology).  Eraser lockset tracking on instrumented
-# class fields: a field written by two threads with no common lock — or a
-# collate-ring slot reused while a borrowed view is live — fails the test
-# at teardown with both access stacks.
+# class fields: a field written by two threads with no common lock fails the
+# test at teardown with both access stacks.
 
 _RACECHECK_MODULES = ("test_runtime", "test_resilience", "test_topology")
 

@@ -1,28 +1,14 @@
-"""Seeded buffer-lifetime bugs: zero-copy views and reuse-ring slots that
-escape their release point, and a ``_BufferRing`` built without the
-``cache='device'`` exclusion — plus the sanctioned shapes (argument
-hand-off, view-travels-with-its-batch, guarded ring) that must stay
-silent."""
+"""Seeded buffer-lifetime bugs: zero-copy views that escape their release
+point, plus the sanctioned shape (view-travels-with-its-batch) that must
+stay silent."""
 
 
 def _np_column_views(batch):
     return {"c": batch}
 
 
-class _BufferRing:
-    def __init__(self, size):
-        self._slots = [{} for _ in range(size)]
-        self._next = 0
-
-    def next_slot(self):
-        slot = self._slots[self._next]
-        self._next = (self._next + 1) % len(self._slots)
-        return slot
-
-
 class BadRebatcher:
     def __init__(self):
-        self._ring = _BufferRing(4)  # SEED: ring-aliasing
         self._pending = []
         self._stash = None
 
@@ -36,46 +22,10 @@ class BadRebatcher:
         views = _np_column_views(batch)
         self._pending.append((batch, views))  # ok: travels with its batch
 
-    def collate_bad(self, window):
-        slot = self._ring.next_slot()
-        self._pending.append(slot)  # SEED: view-escapes-release
+    def deliver_later_bad(self, batch):
+        views = _np_column_views(batch)
 
         def deliver_later():  # SEED: view-escapes-release
-            return dict(slot)
+            return dict(views)
 
         return deliver_later
-
-    def collate_ok(self, window):
-        slot = self._ring.next_slot()
-        return window.collate(slot)  # ok: argument hand-off, not an escape
-
-
-def make_guarded_ring(cache):
-    if cache != "device":
-        return _BufferRing(4)  # ok: the device-cache exclusion guards it
-    return None
-
-
-def delivery_copies(dtypes):
-    return bool(dtypes)
-
-
-def make_probe_guarded_ring(dtypes):
-    if delivery_copies(dtypes):
-        return _BufferRing(4)  # ok: the measured aliasing probe guards it
-    return None
-
-
-def make_inverted_probe_ring(dtypes):
-    # the inverted-guard bug: arms the ring precisely when puts ALIAS
-    if not delivery_copies(dtypes):
-        return _BufferRing(4)  # SEED: ring-aliasing
-    return None
-
-
-def make_else_branch_probe_ring(dtypes):
-    if delivery_copies(dtypes):
-        ring = None
-    else:
-        ring = _BufferRing(4)  # SEED: ring-aliasing
-    return ring

@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 from lakesoul_tpu.models import afmoe as lm
-from lakesoul_tpu.models import causal_lm
+from lakesoul_tpu.models import attention, causal_lm
 from lakesoul_tpu.models.train import (
     ATTN_KEY_TILES_FAMILY,
     MOE_ASSIGNMENTS_FAMILY,
@@ -207,8 +207,8 @@ def test_a_window_layer_sees_its_own_position_and_the_window_less_one_before(par
 
 def test_only_the_window_layers_see_positions_and_the_gate_is_a_matrix_of_its_own(params, monkeypatch):
     turned, seen = [], []
-    rotary, attend = causal_lm._rotary, causal_lm.causal_attention
-    monkeypatch.setattr(causal_lm, "_rotary", lambda a, pos, dim, theta: turned.append((a.shape[2], dim, theta)) or rotary(a, pos, dim, theta))
+    rotary, attend = attention._rotary, attention.causal_attention
+    monkeypatch.setattr(attention, "_rotary", lambda a, pos, dim, theta: turned.append((a.shape[2], dim, theta)) or rotary(a, pos, dim, theta))
     monkeypatch.setattr(causal_lm, "causal_attention", lambda q, k, v, window=None: seen.append((q.shape, k.shape, window)) or attend(q, k, v, window))
     x = hidden(4)
     CFG.mixer("swa")[0](x, params["layers"][1]["swa"])
@@ -230,8 +230,8 @@ def test_only_the_window_layers_see_positions_and_the_gate_is_a_matrix_of_its_ow
 def tiles_of_128(monkeypatch):
     """512 tokens as 4 x 4 tiles of 128 queries (a group of 2: 256 score rows)
     by 128 keys, where the kernels' own sizes would make them one tile."""
-    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
-    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 256)
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 256)
 
 
 def test_the_window_mixer_at_a_head_of_64_takes_the_flash_kernels_on_a_banded_tile_list(monkeypatch, tiles_of_128):
@@ -241,14 +241,14 @@ def test_the_window_mixer_at_a_head_of_64_takes_the_flash_kernels_on_a_banded_ti
     both, and tile (3, 0) is no step at all; against the reference's whole
     mask, forward and every gradient."""
     cfg = lm.AfmoeConfig.from_published(KERNELS, experts_held=HELD, dtype="float32")
-    assert causal_lm._flash_tiles(512, 2, 64) == (128, 128)
-    pairs = causal_lm._flash_pairs(512, 128, 128, 200)
+    assert attention._flash_tiles(512, 2, 64) == (128, 128)
+    pairs = attention._flash_pairs(512, 128, 128, 200)
     assert pairs == [(0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2), (3, 1), (3, 2), (3, 3)]
-    assert causal_lm.key_tile_steps(512, 2, 64, 200) == (9, 10)
+    assert attention.key_tile_steps(512, 2, 64, 200) == (9, 10)
     p = _scaled(lm.init_lm_params(cfg, jax.random.key(0)))["layers"][1]["swa"]
     calls = []
-    kernel = causal_lm._flash_forward
-    monkeypatch.setattr(causal_lm, "_flash_forward", lambda *a, **k: calls.append((a[0].shape, k)) or kernel(*a, **k))
+    kernel = attention._flash_forward
+    monkeypatch.setattr(attention, "_flash_forward", lambda *a, **k: calls.append((a[0].shape, k)) or kernel(*a, **k))
     x = hidden(5, 512, rows=1)
     mixer = cfg.mixer("swa")[0]
     assert_close(mixer(x, p), ref.attention(x, p, KERNELS, SWA))

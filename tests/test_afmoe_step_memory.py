@@ -7,7 +7,7 @@ The configuration file's rule (``assumed.per_chip_batch``, PR 36's): the
 largest of 4, 3, 2, 1 rows of 8,192 tokens that leaves at least 0.5 GB of a
 v5e's 15.75.  Two rows read 14.43 GB and are taken; three read 15.83 and are
 refused (they do not fit the chip at all).  Before the operand kernels
-(``causal_lm.py: _operand_tiles``) the two read 14.86 and 16.01: the float32
+(``attention.py: _operand_tiles``) the two read 14.86 and 16.01: the float32
 ``[8192, 32, 128]`` temporaries of the head norms and the rotary went, and
 nothing new is kept across the mixers' checkpoint.  14.26 while the gate's
 product ran over ``[.., heads, D]`` arrays: over ``[.., heads x D]``, the
@@ -47,8 +47,8 @@ def _step_gb(rows: int) -> dict:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from lakesoul_tpu.models import afmoe, causal_lm, train
-    from lakesoul_tpu.parallel import moe
+    from lakesoul_tpu.models import afmoe, train
+    from lakesoul_tpu.utils import platform
 
     config = _bench_file("configs", "trinity_mini_clm_pk")
     m = config["model"]
@@ -80,8 +80,7 @@ def _step_gb(rows: int) -> dict:
         return params, opt_state, loss, {k: v for k, v in counts.items() if getattr(v, "dtype", None) == jnp.int32}
 
     with pytest.MonkeyPatch.context() as patch:
-        for module in (causal_lm, moe):
-            patch.setattr(module, "_on_tpu", lambda: True)  # the branch the chip takes
+        patch.setattr(platform, "on_tpu", lambda: True)  # the branch the chip takes
         compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*state, ids, ids).compile()
     found = compiled.memory_analysis()
     gb = {

@@ -391,33 +391,14 @@ def test_view_escapes_release_rule_line_exact():
         f for f in lint_fixture("bad_viewescape.py", rules=rules)
         if f.rule == "view-escapes-release"
     ]
-    assert len(found) == 5, found
+    assert len(found) == 4, found
     assert_seed_lines(found, "bad_viewescape.py", "view-escapes-release")
     msgs = "\n".join(f.message for f in found)
     assert "is stored" in msgs and "is returned" in msgs
     assert "is closed over" in msgs
-    # the sanctioned shapes stay silent: argument hand-off (collate_ok) and
-    # the view-travels-with-its-batch tuple (push_ok)
-
-
-def test_ring_aliasing_rule_line_exact():
-    from lakesoul_tpu.analysis.rules.lifetime import RingAliasingRule
-
-    rules = [RingAliasingRule(scope=("bad_viewescape.py",))]
-    found = [
-        f for f in lint_fixture("bad_viewescape.py", rules=rules)
-        if f.rule == "ring-aliasing"
-    ]
-    assert len(found) == 3, found
-    assert_seed_lines(found, "bad_viewescape.py", "ring-aliasing")
-    assert "cache='device'" in found[0].message
-    assert "delivery_copies" in found[0].message
-    # the probe-guarded ring (make_probe_guarded_ring) is SANCTIONED — the
-    # measured-aliasing hand-off the tensor plane introduced — while the
-    # INVERTED guard (`if not delivery_copies(...)`) and the else-branch
-    # ring are flagged: a probe only guards when its truth selects the
-    # ring (assert_seed_lines pinned all three findings line-exactly)
-    # out-of-scope default: both lifetime rules default to data/jax_iter.py
+    # the sanctioned shape stays silent: the view-travels-with-its-batch
+    # tuple (push_ok)
+    # out-of-scope default: the rule default-scopes to data/jax_iter.py
     assert lint_fixture("bad_viewescape.py") == []
 
 
@@ -496,10 +477,7 @@ def test_concurrency_rules_silent_on_real_hot_modules():
     """The fixed runtime/pipeline, page cache, loader, serving and
     heartbeat modules hold under the whole concurrency pack with NO
     baseline: the PR-8/PR-6 machinery is lockset-clean."""
-    from lakesoul_tpu.analysis.rules.lifetime import (
-        RingAliasingRule,
-        ViewEscapesReleaseRule,
-    )
+    from lakesoul_tpu.analysis.rules.lifetime import ViewEscapesReleaseRule
     from lakesoul_tpu.analysis.rules.races import (
         RacyCheckThenActRule,
         SharedStateRaceRule,
@@ -507,7 +485,7 @@ def test_concurrency_rules_silent_on_real_hot_modules():
 
     findings, _ = run(rules=[
         SharedStateRaceRule(), RacyCheckThenActRule(),
-        ViewEscapesReleaseRule(), RingAliasingRule(),
+        ViewEscapesReleaseRule(),
     ], baseline=Baseline([]))
     assert findings == [], "\n".join(f.render() for f in findings)
 
@@ -750,7 +728,7 @@ def test_interproc_rules_silent_on_real_gateways():
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-# ------------------------------------------- boundedness pack (rules 36-40)
+# ------------------------------------------- boundedness pack (rules 35-39)
 
 
 def _boundedness_rules():
@@ -865,7 +843,7 @@ def test_sarif_output_shape():
     driver = run_["tool"]["driver"]
     assert driver["name"] == "lakesoul-lint"
     rule_ids = [r["id"] for r in driver["rules"]]
-    assert len(rule_ids) == 40 and "rbac-gate-reachability" in rule_ids
+    assert len(rule_ids) == 39 and "rbac-gate-reachability" in rule_ids
     assert "unbounded-queue" in rule_ids and "unbounded-growth" in rule_ids
     assert "thread-lifecycle" in rule_ids and "child-reap" in rule_ids
     assert "shm-debris" in rule_ids

@@ -1,4 +1,4 @@
-"""The operand kernels (``models/causal_lm.py: attn_operands_fwd`` and
+"""The operand kernels (``models/attention.py: attn_operands_fwd`` and
 ``attn_operands_bwd``) against the ``jnp`` lines they stand for, in the Pallas
 interpreter: the flash kernels' operands, every gradient, the mixer through
 its checkpoint on both paths, and the rule that picks the path.
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lakesoul_tpu.models import causal_lm
+from lakesoul_tpu.models import attention, causal_lm
 
 EPS, THETA = 1e-5, 10000.0
 
@@ -43,10 +43,10 @@ def test_the_kernel_pair_is_the_xla_lines(heads, kv, t, turned, centred):
     centre = 0.0 if centred else 1.0
     wq, wk = (centre + 0.2 * jax.random.normal(key, (d,)) for key in keys[:2])
     recipe = dict(eps=EPS, centred=centred, rotary_dim=d if turned else None, theta=THETA)
-    bt = causal_lm._operand_tiles(t, heads, kv, d, recipe["rotary_dim"])
+    bt = attention._operand_tiles(t, heads, kv, d, recipe["rotary_dim"])
     assert bt == t
-    want, pull_want = jax.vjp(functools.partial(causal_lm._xla_operands, **recipe), q, k, v, wq, wk)
-    got, pull_got = jax.vjp(lambda *a: causal_lm._kernel_operands(*a, bt, **recipe), q, k, v, wq, wk)
+    want, pull_want = jax.vjp(functools.partial(attention._xla_operands, **recipe), q, k, v, wq, wk)
+    got, pull_got = jax.vjp(lambda *a: attention._kernel_operands(*a, bt, **recipe), q, k, v, wq, wk)
     for a, b in zip(got, want, strict=True):
         assert a.shape == b.shape and a.dtype == b.dtype == jnp.bfloat16
         a, b = _f32(a), _f32(b)
@@ -66,13 +66,13 @@ def test_a_head_of_two_lane_tiles_turns_by_one_tile():
     q, k, v, keys = _raw(t, heads, kv, d)
     wq, wk = (1.0 + 0.2 * jax.random.normal(key, (d,)) for key in keys[:2])
     recipe = dict(eps=EPS, centred=False, rotary_dim=d, theta=THETA)
-    bt = causal_lm._operand_tiles(t, heads, kv, d, d)
-    want = causal_lm._xla_operands(q, k, v, wq, wk, **recipe)
-    got = causal_lm._kernel_operands(q, k, v, wq, wk, bt, **recipe)
+    bt = attention._operand_tiles(t, heads, kv, d, d)
+    want = attention._xla_operands(q, k, v, wq, wk, **recipe)
+    got = attention._kernel_operands(q, k, v, wq, wk, bt, **recipe)
     for a, b in zip(got, want, strict=True):
         a, b = _f32(a), _f32(b)
         assert np.all(np.abs(a - b) <= np.abs(b) * 2.0**-7) and np.mean(a != b) < 1e-3
-    assert causal_lm._operand_tiles(8192, 64, 2, 256, 256) == 128  # 32 heads of 256 a group: 1 MB at 64 tokens
+    assert attention._operand_tiles(8192, 64, 2, 256, 256) == 128  # 32 heads of 256 a group: 1 MB at 64 tokens
 
 
 @pytest.mark.parametrize("shape,want", [
@@ -86,7 +86,7 @@ def test_a_head_of_two_lane_tiles_turns_by_one_tile():
     ((16384, 8, 2, 256, 256), None),    # T x D over the flash kernels' row
 ], ids=["trinity-window", "trinity-full", "qwen3-next", "lfm2", "three-tiles", "ragged-row", "uneven-groups", "long-row"])
 def test_the_rule(shape, want):
-    assert causal_lm._operand_tiles(*shape) == want
+    assert attention._operand_tiles(*shape) == want
 
 
 def _mixer(heads, kv, d, *, rotary_dim, window, centred=False):
@@ -117,11 +117,11 @@ def test_the_mixer_through_its_checkpoint_is_equal_on_both_paths(kind, monkeypat
         out, pull = jax.vjp(lambda x, p: causal_lm._row_by_row(mixer, x, p, None), x, p)
         return out, pull(cot)
 
-    counts = causal_lm.mixer_counts(mixer, x, p)
+    counts = attention.mixer_counts(mixer, x, p)
     assert (counts["attn_operands_kernel"], counts["attn_operands_xla"]) == (2, 0)
     with_kernels = run()
-    monkeypatch.setattr(causal_lm, "_operand_tiles", lambda *shape: None)
-    counts = causal_lm.mixer_counts(mixer, x, p)
+    monkeypatch.setattr(attention, "_operand_tiles", lambda *shape: None)
+    counts = attention.mixer_counts(mixer, x, p)
     assert (counts["attn_operands_kernel"], counts["attn_operands_xla"]) == (0, 2)
     without = run()
     for a, b in zip(jax.tree.leaves(with_kernels), jax.tree.leaves(without), strict=True):
@@ -227,7 +227,7 @@ def test_no_transpose_stands_between_the_flash_pair_and_the_output_projection(fa
         mixer = cfg.mixer(kind)[0]
         weights = _mixer_weights(cfg, cfg.layer_kinds().index(kind), kind)
         after, before = _around_the_flash_pair(mixer, x, weights)
-        counts = causal_lm.mixer_counts(mixer, x, weights)
+        counts = attention.mixer_counts(mixer, x, weights)
         if family == "lfm2":
             assert "transpose" in after and "transpose" in before
             assert (counts["attn_out_tokens"], counts["attn_out_heads"]) == (0, 1)
@@ -247,18 +247,18 @@ def test_the_lfm2_mixer_is_the_program_it_was():
     x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16)
     weights = _mixer_weights(cfg, cfg.layer_kinds().index("attn"), "attn")
     heads, kv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    assert d == 64 and not causal_lm._token_major(8192, heads // kv, d)
+    assert d == 64 and not attention._token_major(8192, heads // kv, d)
 
     def as_it_was(x, p):
         dtype = x.dtype
         b, t, _ = x.shape
         q, k, v = ((x @ p[w].astype(dtype)).reshape(b, t, n, d) for w, n in (("w_q", heads), ("w_k", kv), ("w_v", kv)))
-        q, k, v = causal_lm._xla_operands(
+        q, k, v = attention._xla_operands(
             q, k, v, p["q_norm"], p["k_norm"], eps=cfg.norm_eps, centred=False, rotary_dim=d, theta=cfg.rope_theta
         )
-        o = causal_lm._flash_attention(
+        o = attention._flash_attention(
             q.reshape(b * kv, heads // kv, t, d), *(a.reshape(b * kv, t, d) for a in (k, v)),
-            *causal_lm._flash_tiles(t, heads // kv, d), None, None,
+            *attention._flash_tiles(t, heads // kv, d), None, None,
         ).reshape(q.shape)
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, d)
         return o.reshape(b, t, heads * d) @ p["w_o"].astype(dtype)

@@ -1,4 +1,4 @@
-"""``models/causal_lm.py: causal_attention``: the flash kernels (in the Pallas
+"""``models/attention.py: causal_attention``: the flash kernels (in the Pallas
 interpreter here) against the plain masked softmax and against their blockwise
 twin, forward and every gradient, at the five published group and head sizes,
 under the causal mask and under a window; the tile lists; which shapes take
@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from lakesoul_tpu.models import causal_lm
+from lakesoul_tpu.models import attention, causal_lm
 
 # query heads a key-value head, head size
 PUBLISHED = {"lfm2": (4, 64), "qwen3-next": (8, 256), "glm-4.7-flash": (1, 256), "trinity-mini": (8, 128),
@@ -55,7 +55,7 @@ def transposed_sizes(jaxpr_text: str) -> list[int]:
 
 
 def blockwise(q, k, v, band=256, rows=64, window=None):
-    return tokens_first(causal_lm._blockwise_attention(q, k, v, band, rows, window))
+    return tokens_first(attention._blockwise_attention(q, k, v, band, rows, window))
 
 
 def operands(groups, d, t, dtype, *, kv_heads=1, rows=1):
@@ -94,12 +94,12 @@ def test_flash_kernels_equal_the_masked_softmax_and_their_twin(monkeypatch, fami
     softmax over the same rounded operands, and no further from it than the
     twin is."""
     (groups, d), (t, keys, rows) = PUBLISHED[family], TILINGS[tiling]
-    monkeypatch.setattr(causal_lm, "FLASH_KEYS", keys)
-    monkeypatch.setattr(causal_lm, "FLASH_ROWS", rows * groups)
-    bq, bk = causal_lm._flash_tiles(t, groups, d)
+    monkeypatch.setattr(attention, "FLASH_KEYS", keys)
+    monkeypatch.setattr(attention, "FLASH_ROWS", rows * groups)
+    bq, bk = attention._flash_tiles(t, groups, d)
     assert (bq, bk) == (min(rows, t), min(keys, t))
     q, k, v, weigh = operands(groups, d, t, dtype)
-    got = out_and_grads(causal_lm.causal_attention, q, k, v, weigh)
+    got = out_and_grads(attention.causal_attention, q, k, v, weigh)
     want = out_and_grads(plain_attention, q, k, v, weigh)
     twin = out_and_grads(blockwise, q, k, v, weigh)
     assert_close(got, want, tol)
@@ -120,11 +120,11 @@ def test_window_kernels_equal_the_masked_softmax_and_their_twin(monkeypatch, fam
     sees itself alone), against the whole mask and against the twin, whose
     bands slice the keys from the first a band sees."""
     (groups, d), t, window = PUBLISHED[family], 512, WINDOWS[window]
-    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
-    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 128 * groups)
-    assert causal_lm._flash_tiles(t, groups, d) == (128, 128)
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 128 * groups)
+    assert attention._flash_tiles(t, groups, d) == (128, 128)
     q, k, v, weigh = operands(groups, d, t, jnp.float32)
-    got = out_and_grads(lambda *qkv: causal_lm.causal_attention(*qkv, window), q, k, v, weigh)
+    got = out_and_grads(lambda *qkv: attention.causal_attention(*qkv, window), q, k, v, weigh)
     want = out_and_grads(lambda *qkv: plain_attention(*qkv, window), q, k, v, weigh)
     twin = out_and_grads(lambda *qkv: blockwise(*qkv, window=window), q, k, v, weigh)
     if window == 1:  # every query sees its own key alone: the output is v's rows, and no score has a gradient
@@ -144,12 +144,12 @@ def test_a_window_of_the_row_length_is_the_causal_path_bit_for_bit(family):
     groups, d = PUBLISHED[family]
     for t in (256, 150):
         q, k, v, weigh = operands(groups, d, t, jnp.bfloat16)
-        want = out_and_grads(causal_lm.causal_attention, q, k, v, weigh)
+        want = out_and_grads(attention.causal_attention, q, k, v, weigh)
         for window in (t, t + 1, 10 * t):
-            got = out_and_grads(lambda *qkv, w=window: causal_lm.causal_attention(*qkv, w), q, k, v, weigh)
+            got = out_and_grads(lambda *qkv, w=window: attention.causal_attention(*qkv, w), q, k, v, weigh)
             for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
                 assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
-    assert causal_lm.key_tile_steps(256, groups, d, 256) == causal_lm.key_tile_steps(256, groups, d)
+    assert attention.key_tile_steps(256, groups, d, 256) == attention.key_tile_steps(256, groups, d)
 
 
 def test_without_a_window_the_tile_tables_are_what_they_were():
@@ -158,10 +158,10 @@ def test_without_a_window_the_tile_tables_are_what_they_were():
     walked before they knew a window; ``window=None`` names the same."""
     for t, bq, bk in ((8192, 128, 512), (8192, 256, 512), (8192, 512, 512), (512, 128, 256), (384, 128, 128)):
         before = [(i, j) for i in range(t // bq) for j in range((i * bq + bq - 1) // bk + 1)]
-        for tables in (causal_lm._flash_steps(t, bq, bk), causal_lm._flash_steps(t, bq, bk, None)):
+        for tables in (attention._flash_steps(t, bq, bk), attention._flash_steps(t, bq, bk, None)):
             assert [a.dtype for a in tables] == [jnp.int32] * 2
             assert list(zip(*(a.tolist() for a in tables), strict=True)) == before
-        assert causal_lm._flash_pairs(t, bq, bk, t) == before  # a window of the row's length hides no tile
+        assert attention._flash_pairs(t, bq, bk, t) == before  # a window of the row's length hides no tile
 
 
 def test_a_windows_tile_list_is_the_band():
@@ -170,12 +170,12 @@ def test_a_windows_tile_list_is_the_band():
     head and a window of 2,048 holds 280: for every query tile the key tiles
     from the one that holds the first key its first query sees to the
     diagonal's, and no other."""
-    assert causal_lm._flash_tiles(8192, 8, 128) == (128, 512)
-    assert causal_lm.key_tile_steps(8192, 8, 128) == (544, 544)
-    assert causal_lm.key_tile_steps(8192, 8, 128, 2048) == (280, 544)
-    assert causal_lm.key_tile_steps(8192, 8, 128, 8192) == (544, 544)
-    assert causal_lm.key_tile_steps(150, 8, 128, 40) == (0, 0)  # the twin lists no tile
-    pairs = causal_lm._flash_pairs(8192, 128, 512, 2048)
+    assert attention._flash_tiles(8192, 8, 128) == (128, 512)
+    assert attention.key_tile_steps(8192, 8, 128) == (544, 544)
+    assert attention.key_tile_steps(8192, 8, 128, 2048) == (280, 544)
+    assert attention.key_tile_steps(8192, 8, 128, 8192) == (544, 544)
+    assert attention.key_tile_steps(150, 8, 128, 40) == (0, 0)  # the twin lists no tile
+    pairs = attention._flash_pairs(8192, 128, 512, 2048)
     assert len(pairs) == 280 and pairs[:3] == [(0, 0), (1, 0), (2, 0)] and pairs[-5:] == [(63, j) for j in (11, 12, 13, 14, 15)]
     for i in range(64):
         keys = [j for q, j in pairs if q == i]
@@ -183,7 +183,7 @@ def test_a_windows_tile_list_is_the_band():
         assert keys == sorted({key // 512 for key in seen})
     # the pairs the mask lets through: 43.7% of the triangle, on 51.5% of its tiles
     assert sum(min(i + 1, 2048) for i in range(8192)) == 14_681_088
-    qi, kj = causal_lm._flash_steps(512, 128, 128, 200)
+    qi, kj = attention._flash_steps(512, 128, 128, 200)
     assert list(zip(qi.tolist(), kj.tolist(), strict=True)) == [
         (0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2), (3, 1), (3, 2), (3, 3)
     ]
@@ -192,9 +192,9 @@ def test_a_windows_tile_list_is_the_band():
 def test_a_key_tile_after_the_query_tile_is_no_step():
     """The grid is the list of tiles on or under the diagonal: 6 of 8 where two
     query tiles share a key tile, each query tile's last step its diagonal."""
-    qi, kj = causal_lm._flash_steps(512, 128, 256)
+    qi, kj = attention._flash_steps(512, 128, 256)
     assert list(zip(qi.tolist(), kj.tolist(), strict=True)) == [(0, 0), (1, 0), (2, 0), (2, 1), (3, 0), (3, 1)]
-    qi, kj = causal_lm._flash_steps(8192, 256, 512)
+    qi, kj = attention._flash_steps(8192, 256, 512)
     assert qi.shape[0] == sum(i // 2 + 1 for i in range(32)) == 272  # of 32 x 16 = 512
 
 
@@ -212,14 +212,14 @@ def test_a_key_tile_after_the_query_tile_is_no_step():
     (16384, 4, 64, (256, 512)),
 ])
 def test_which_shapes_take_the_kernels(monkeypatch, t, groups, d, tiles):
-    assert causal_lm._flash_tiles(t, groups, d) == tiles
+    assert attention._flash_tiles(t, groups, d) == tiles
     if t > 256:
         return
     calls = []
-    kernel = causal_lm._flash_forward
-    monkeypatch.setattr(causal_lm, "_flash_forward", lambda *a, **k: calls.append(k) or kernel(*a, **k))
+    kernel = attention._flash_forward
+    monkeypatch.setattr(attention, "_flash_forward", lambda *a, **k: calls.append(k) or kernel(*a, **k))
     q, k, v, _ = operands(groups, d, t, jnp.float32)
-    causal_lm.causal_attention(q, k, v)
+    attention.causal_attention(q, k, v)
     # no TPU here: the interpreter
     # no TPU here: the interpreter; ``batch``: the rows, where the output is written token-major
     batch = 1 if d % 128 == 0 else None
@@ -239,12 +239,12 @@ def _heads_first_kernels(q, k, v, weigh, window):
     the transpose back: what ``causal_attention``'s callers ran before the
     kernels' block specs wrote that layout themselves."""
     b, hkv, groups, t, d = q.shape
-    bq, bk = causal_lm._flash_tiles(t, groups, d)
+    bq, bk = attention._flash_tiles(t, groups, d)
     q4, k3, v3 = q.reshape(b * hkv, groups, t, d), k.reshape(b * hkv, t, d), v.reshape(b * hkv, t, d)
-    o, lse = causal_lm._flash_forward(q4, k3, v3, bq=bq, bk=bk, window=window, interpret=True)
+    o, lse = attention._flash_forward(q4, k3, v3, bq=bq, bk=bk, window=window, interpret=True)
     assert o.shape == q4.shape
     do = weigh.astype(o.dtype).reshape(b, t, hkv, groups, d).transpose(0, 2, 3, 1, 4).reshape(q4.shape)
-    dq, dk, dv = causal_lm._flash_backward(q4, k3, v3, o, lse, do, bq=bq, bk=bk, window=window, interpret=True)
+    dq, dk, dv = attention._flash_backward(q4, k3, v3, o, lse, do, bq=bq, bk=bk, window=window, interpret=True)
     return tokens_first(o.reshape(q.shape)), (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -265,18 +265,18 @@ def test_the_token_major_output_is_the_heads_first_kernels_bit_for_bit(monkeypat
     models pass them)."""
     groups, d, window = TOKEN_MAJOR[case]
     t = 384
-    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
-    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 128 * groups)
-    assert causal_lm._flash_tiles(t, groups, d) == (128, 128) and causal_lm._token_major(t, groups, d)
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 128 * groups)
+    assert attention._flash_tiles(t, groups, d) == (128, 128) and attention._token_major(t, groups, d)
     q, k, v, weigh = operands(groups, d, t, jnp.bfloat16, kv_heads=2, rows=2)
     calls = []
-    kernel = causal_lm._flash_forward
-    monkeypatch.setattr(causal_lm, "_flash_forward", lambda *a, **kw: calls.append(kw["batch"]) or kernel(*a, **kw))
-    got = out_and_grads(lambda *qkv: causal_lm.causal_attention(*qkv, window), q, k, v, weigh)
+    kernel = attention._flash_forward
+    monkeypatch.setattr(attention, "_flash_forward", lambda *a, **kw: calls.append(kw["batch"]) or kernel(*a, **kw))
+    got = out_and_grads(lambda *qkv: attention.causal_attention(*qkv, window), q, k, v, weigh)
     assert calls == [2] and got[0].shape == (2, t, 2 * groups, d)
-    monkeypatch.setattr(causal_lm, "_flash_forward", kernel)
+    monkeypatch.setattr(attention, "_flash_forward", kernel)
     _bits_equal(got, _heads_first_kernels(q, k, v, weigh, window))
-    text = str(jax.make_jaxpr(lambda *qkv: jax.vjp(causal_lm.causal_attention, *qkv)[1](weigh.astype(q.dtype)))(q, k, v))
+    text = str(jax.make_jaxpr(lambda *qkv: jax.vjp(attention.causal_attention, *qkv)[1](weigh.astype(q.dtype)))(q, k, v))
     # the transposes left are inside the kernels (the interpreter shows them): the log-sum-exp's and ``delta``'s
     # [G*bq, 128], a float a query and head along the lanes; nothing the size of a row's output
     sizes = transposed_sizes(text)
@@ -290,15 +290,15 @@ def test_a_head_of_64_and_a_refused_shape_are_laid_out_by_the_transpose(groups, 
     the blockwise twin: both write heads first as before and
     ``causal_attention`` lays the output tokens first by the transpose its
     callers ran; values and gradients bit for bit today's."""
-    assert not causal_lm._token_major(t, groups, d)
+    assert not attention._token_major(t, groups, d)
     q, k, v, weigh = operands(groups, d, t, jnp.bfloat16, kv_heads=2, rows=2)
-    got = out_and_grads(causal_lm.causal_attention, q, k, v, weigh)
-    if causal_lm._flash_tiles(t, groups, d) is None:
-        want = out_and_grads(lambda *qkv: blockwise(*qkv, causal_lm.ATTN_BAND, causal_lm.ATTN_ROWS), q, k, v, weigh.astype(q.dtype))
+    got = out_and_grads(attention.causal_attention, q, k, v, weigh)
+    if attention._flash_tiles(t, groups, d) is None:
+        want = out_and_grads(lambda *qkv: blockwise(*qkv, attention.ATTN_BAND, attention.ATTN_ROWS), q, k, v, weigh.astype(q.dtype))
     else:
         want = _heads_first_kernels(q, k, v, weigh, None)
     _bits_equal(got, want)
-    assert max(transposed_sizes(str(jax.make_jaxpr(causal_lm.causal_attention)(q, k, v)))) == q.size
+    assert max(transposed_sizes(str(jax.make_jaxpr(attention.causal_attention)(q, k, v)))) == q.size
 
 
 def test_a_row_checkpoint_keeps_the_output_and_the_log_sum_exp():
@@ -311,7 +311,7 @@ def test_a_row_checkpoint_keeps_the_output_and_the_log_sum_exp():
     x = jnp.stack([q[0], 2 * q[0]])
 
     def mixer(row, p):
-        return causal_lm.causal_attention(row * p, k, v)
+        return attention.causal_attention(row * p, k, v)
 
     def grad(rows):
         return jax.grad(lambda p: jnp.sum(weigh[0] * rows(mixer, x, p)))
@@ -352,10 +352,10 @@ def test_the_operand_kernels_without_head_norms_are_the_xla_lines(heads, kv, tur
     keys = jax.random.split(jax.random.key(11), 6)
     q, k, v = (jax.random.normal(key, (2, t, n, d)).astype(jnp.bfloat16) for key, n in zip(keys, (heads, kv, kv)))
     recipe = dict(eps=1e-6, centred=False, rotary_dim=d if turned else None, theta=1e6)
-    bt = causal_lm._operand_tiles(t, heads, kv, d, recipe["rotary_dim"])
+    bt = attention._operand_tiles(t, heads, kv, d, recipe["rotary_dim"])
     assert bt == t
-    want, pull_want = jax.vjp(lambda *a: causal_lm._xla_operands(*a, None, None, **recipe), q, k, v)
-    got, pull_got = jax.vjp(lambda *a: causal_lm._kernel_operands(*a, None, None, bt, **recipe), q, k, v)
+    want, pull_want = jax.vjp(lambda *a: attention._xla_operands(*a, None, None, **recipe), q, k, v)
+    got, pull_got = jax.vjp(lambda *a: attention._kernel_operands(*a, None, None, bt, **recipe), q, k, v)
     for a, b in zip(got, want, strict=True):
         assert a.shape == b.shape and a.dtype == b.dtype == jnp.bfloat16
         a, b = _f32(a), _f32(b)
@@ -393,14 +393,14 @@ def test_a_mixer_whose_weights_hold_no_head_norm_norms_no_head(path, monkeypatch
         centred=False, gated=False,
     )
     if path == "xla-lines":
-        monkeypatch.setattr(causal_lm, "_operand_tiles", lambda *shape: None)
-    counts = causal_lm.mixer_counts(mixer, x, p)
+        monkeypatch.setattr(attention, "_operand_tiles", lambda *shape: None)
+    counts = attention.mixer_counts(mixer, x, p)
     assert (counts["attn_operands_kernel"], counts["attn_operands_xla"]) == ((1, 0) if path == "operand-kernels" else (0, 1))
 
     def plain(x, p):
         q, k, v = ((x @ p[w]).reshape(1, t, n, d) for w, n in (("w_q", heads), ("w_k", kv), ("w_v", kv)))
         positions = jnp.arange(t)
-        q, k = (causal_lm._rotary(a, positions, d, 1e6) for a in (q, k))
+        q, k = (attention._rotary(a, positions, d, 1e6) for a in (q, k))
         o = plain_attention(q.transpose(0, 2, 1, 3)[:, :, None] * d**-0.5, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
         return o.reshape(1, t, heads * d) @ p["w_o"]
 

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lakesoul_tpu.models import causal_lm
+from lakesoul_tpu.models import attention, causal_lm
 from lakesoul_tpu.models import glm4_moe_lite as lm
 from lakesoul_tpu.models.train import (
     HEAD_POSITIONS_FAMILY,
@@ -189,8 +189,8 @@ def test_latent_mixer_at_the_published_head_takes_the_flash_kernels(monkeypatch)
     cfg = lm.Glm4MoeLiteConfig.from_published(PUBLISHED_HEADS, experts_held=HELD, dtype="float32")
     p = _scaled(lm.init_lm_params(cfg, jax.random.key(0)))["layers"][1]["mla"]
     calls = []
-    kernel = causal_lm._flash_forward
-    monkeypatch.setattr(causal_lm, "_flash_forward", lambda *a, **k: calls.append((a[0].shape, k)) or kernel(*a, **k))
+    kernel = attention._flash_forward
+    monkeypatch.setattr(attention, "_flash_forward", lambda *a, **k: calls.append((a[0].shape, k)) or kernel(*a, **k))
     x = hidden(3, 128)[:1]
     weigh = jax.random.normal(jax.random.key(4), x.shape)
     assert_close(mixer(cfg)(x, p), ref.attention(x, p, PUBLISHED_HEADS))
@@ -209,10 +209,10 @@ def test_latent_attention_is_causal_and_every_head_shares_one_rotary_key(params,
     np.testing.assert_allclose(mixer()(x, p)[:, :100], mixer()(later, p)[:, :100], atol=1e-5)
     # what reaches the attention: per-head keys whose last 4 channels are one head's, the same for all four
     seen = {}
-    real = causal_lm.causal_attention
+    real = attention.causal_attention
     monkeypatch.setattr(causal_lm, "causal_attention", lambda q, k, v: seen.update(q=q, k=k, v=v) or real(q, k, v))
     turned = []
-    rotary = causal_lm._rotary
+    rotary = attention._rotary
     monkeypatch.setattr(causal_lm, "_rotary", lambda a, pos, dim, theta: turned.append((a.shape[2], dim, theta)) or rotary(a, pos, dim, theta))
     mixer()(x, p)
     assert seen["q"].shape == (B, 4, 1, T, 16) and seen["k"].shape == seen["v"].shape == (B, 4, T, 16)

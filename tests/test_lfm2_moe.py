@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lakesoul_tpu.models import causal_lm
+from lakesoul_tpu.models import attention, causal_lm
 from lakesoul_tpu.models import lfm2_moe as lm
 from lakesoul_tpu.models.train import (
     MOE_ASSIGNMENTS_FAMILY,
@@ -150,8 +150,8 @@ WIDE_HEADS = MODEL | {"hidden_size": 256, "num_attention_heads": 4, "num_key_val
 def test_attention_blocks_equal_the_masked_softmax(monkeypatch, model, band, rows):
     """Four query heads a key-value head, at the tiny head size and at the
     published 64."""
-    monkeypatch.setattr(causal_lm, "ATTN_BAND", band)
-    monkeypatch.setattr(causal_lm, "ATTN_ROWS", rows)
+    monkeypatch.setattr(attention, "ATTN_BAND", band)
+    monkeypatch.setattr(attention, "ATTN_ROWS", rows)
     cfg = lm.Lfm2MoeConfig.from_published(model, experts_held=HELD, dtype="float32")
     assert cfg.num_attention_heads // cfg.num_key_value_heads == 4
     p = _scaled(lm.init_lm_params(cfg, jax.random.key(0)))["layers"][1]["attn"]
@@ -172,14 +172,14 @@ def test_attention_is_causal_and_rotates_every_channel(params):
         lm.attention(x, p, cfg=CFG)[:, :100], lm.attention(later, p, cfg=CFG)[:, :100], atol=1e-5
     )
     q = jax.random.normal(jax.random.key(6), (1, 8, 2, 64))
-    turned = causal_lm._rotary(q, jnp.arange(8), 64, 1e6)
+    turned = attention._rotary(q, jnp.arange(8), 64, 1e6)
     assert not np.any(np.isclose(turned[:, 1:], q[:, 1:]).all(axis=(0, 1, 2)))  # no channel is left as it was
     assert_close(turned, ref.rotary(q, 1e6), tol=1e-6)
     # the program asks for the whole head
     seen = []
-    real = causal_lm._rotary
+    real = attention._rotary
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(causal_lm, "_rotary", lambda x, pos, dim, theta: seen.append((dim, theta)) or real(x, pos, dim, theta))
+        patch.setattr(attention, "_rotary", lambda x, pos, dim, theta: seen.append((dim, theta)) or real(x, pos, dim, theta))
         lm.attention(x, p, cfg=CFG)
     assert seen == [(CFG.head_dim, 1e6)] * 2
 

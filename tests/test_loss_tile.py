@@ -1,6 +1,6 @@
-"""The causal-LM losses' fused tile body (``models/causal_lm.py: fused_tile``
+"""The causal-LM losses' fused tile body (``models/loss_tile.py: fused_tile``
 over ``models/loss_tile.py``'s kernel, the Pallas interpreter here) against the
-compiler's (``models/bert.py: tile_grads``: a float32 log-softmax, a gather and
+compiler's (``models/head_loss.py: tile_grads``: a float32 log-softmax, a gather and
 autodiff), and the two sides of the split: the LM losses pass the body, the
 masked-LM loss passes none and its process imports no Pallas.
 
@@ -23,8 +23,8 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from lakesoul_tpu.models import afmoe, bert, causal_lm, glm4_moe_lite, loss_tile, ouro
-from lakesoul_tpu.models.bert import head_tile, labelled_nll, tile_grads
+from lakesoul_tpu.models import afmoe, bert, causal_lm, glm4_moe_lite, loss_tile, norms, ouro
+from lakesoul_tpu.models.head_loss import head_tile, labelled_nll, tile_grads
 from lakesoul_tpu.models.loss_tile import block_rows, tile_takes
 from lakesoul_tpu.models.train import LOSS_ROWS_FAMILY, make_lm_train_state, make_lm_train_step
 from lakesoul_tpu.obs import registry
@@ -51,7 +51,7 @@ class _Cfg:
 
     @staticmethod
     def norm(x, w):
-        return causal_lm._rms_norm(x, w, 1e-6)
+        return norms.rms_norm(x, w, 1e-6)
 
 
 def _head(kind: str, hidden: int, vocab: int, dtype="float32"):
@@ -101,8 +101,8 @@ def test_fused_tile_is_the_compilers_body(dtype, vocab, form, case, every_tile_f
     scale = jnp.float32(1.0 / max(int((labels >= 0).sum()), 1))
     weights = (jnp.asarray(rng.uniform(0.1, 2.0, rows), jnp.float32),) if form == "weighted" else ()
     want, want_g = tile_grads(head_fn, head, x, labels, scale, *weights)
-    got, got_g = jax.jit(functools.partial(causal_lm.fused_tile, head_fn))(head, x, labels, scale, *weights)
-    assert "loss_tile" in str(jax.make_jaxpr(functools.partial(causal_lm.fused_tile, head_fn))(head, x, labels, scale, *weights))
+    got, got_g = jax.jit(functools.partial(loss_tile.fused_tile, head_fn))(head, x, labels, scale, *weights)
+    assert "loss_tile" in str(jax.make_jaxpr(functools.partial(loss_tile.fused_tile, head_fn))(head, x, labels, scale, *weights))
     if weights:
         (got, got_nll), (want, want_nll) = got, want
         np.testing.assert_allclose(got_nll, want_nll, rtol=2e-6, atol=2e-6)
@@ -166,7 +166,7 @@ def test_labelled_nll_with_the_fused_body_is_the_default(kind, weighted, sharded
             argnums=(0, 1), has_aux=True,
         ))(head, x)
 
-    (got, got_aux), got_g = through(causal_lm.fused_tile)
+    (got, got_aux), got_g = through(loss_tile.fused_tile)
     (want, want_aux), want_g = through()
     np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
     assert int(got_aux[0]) == int(want_aux[0]) < x.shape[0] * x.shape[1] + 4 * head_tile(60)

@@ -22,10 +22,10 @@ from lakesoul_tpu.models.bert import (
     BertConfig,
     bert_forward,
     bert_mlm_loss,
-    head_tile,
     init_bert_params,
     mlm_head_loss,
 )
+from lakesoul_tpu.models.head_loss import head_tile
 from lakesoul_tpu.models.train import (
     HEAD_POSITIONS_FAMILY,
     make_bert_train_state,

@@ -1,7 +1,7 @@
 """The ``ouro`` family (``models/ouro.py``: Ouro-2.6B, a looped language
 model) on the shared causal-LM stack (``models/causal_lm.py``): the stack run
 ``R`` times over one set of weights (``loop_hidden``), a loss after every pass
-through one head as ONE call of the weighted tile loop (``models/bert.py:
+through one head as ONE call of the weighted tile loop (``models/head_loss.py:
 labelled_nll``), the exit gate and the expected loss (``exit_loss``), attention
 without head norms (``softmax_attention``) and the train step
 (``models/train.py``), against the plain float32 reference in
@@ -31,7 +31,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from lakesoul_tpu.models import bert, causal_lm
+from lakesoul_tpu.models import attention, causal_lm, head_loss
 from lakesoul_tpu.models import ouro as lm
 from lakesoul_tpu.models.train import (
     HEAD_POSITIONS_FAMILY,
@@ -278,7 +278,7 @@ def test_the_whole_model_at_the_kernels_shapes_equals_the_reference():
     want, want_grads = jax.jit(jax.value_and_grad(lambda p: ref.lm_loss(p, ids, labels, cfg=KERNELS)))(weights)
     np.testing.assert_allclose(float(loss), float(want), rtol=2e-6)
     assert_close(grads, want_grads)
-    steps = causal_lm.key_tile_steps(256, 1, 128)[0]  # one key-value head's list
+    steps = attention.key_tile_steps(256, 1, 128)[0]  # one key-value head's list
     assert steps > 0 and (counts["attn_tiles_run"], counts["attn_tiles_causal"]) == (R * 2 * 2 * steps,) * 2
     assert (counts["attn_operands_kernel"], counts["attn_operands_xla"]) == (R * 2, 0)
 
@@ -330,7 +330,7 @@ def test_the_weighted_head_and_loss_is_a_plain_weighted_log_softmax(labelled, sh
         x, labels, weights = (jax.device_put(a, sharding) for a in (x, labels, weights))
 
     def program(head, x):
-        loss, positions, nll = bert.labelled_nll(_head_fn, head, x, labels, sharding, weights)
+        loss, positions, nll = head_loss.labelled_nll(_head_fn, head, x, labels, sharding, weights)
         return loss, (positions, nll)
 
     (loss, (positions, nll)), grads = jax.jit(jax.value_and_grad(program, argnums=(0, 1), has_aux=True))(head, x)
@@ -341,23 +341,23 @@ def test_the_weighted_head_and_loss_is_a_plain_weighted_log_softmax(labelled, sh
     np.testing.assert_allclose(nll, want_nll, rtol=2e-6, atol=1e-6)
     np.testing.assert_allclose(grads[0]["w"], want_grads[0]["w"], rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(grads[1], want_grads[1], rtol=1e-5, atol=1e-6)
-    assert labelled <= int(positions) <= 96 + (4 if sharded else 1) * bert.head_tile(96 // (4 if sharded else 1))
+    assert labelled <= int(positions) <= 96 + (4 if sharded else 1) * head_loss.head_tile(96 // (4 if sharded else 1))
 
 
 def test_the_weighted_form_takes_no_gradient_through_its_weights_or_its_per_position_loss():
     head, x, labels, weights = _weighted_case(9)
-    g = jax.grad(lambda w: bert.labelled_nll(_head_fn, head, x, labels, None, w)[0])(weights)
+    g = jax.grad(lambda w: head_loss.labelled_nll(_head_fn, head, x, labels, None, w)[0])(weights)
     assert float(jnp.max(jnp.abs(g))) == 0.0
-    g = jax.grad(lambda x: jnp.sum(bert.labelled_nll(_head_fn, head, x, labels, None, weights)[2]))(x)
+    g = jax.grad(lambda x: jnp.sum(head_loss.labelled_nll(_head_fn, head, x, labels, None, weights)[2]))(x)
     assert float(jnp.max(jnp.abs(g))) == 0.0
 
 
 def _parents_head_over_labelled(head_fn, head, x, labels):
-    """``models/bert.py: _head_over_labelled`` as it stood before it took
+    """``models/head_loss.py: _head_over_labelled`` as it stood before it took
     weights (one device, with gradients), line for line."""
     x2, lab = x.reshape(-1, x.shape[-1]), labels.reshape(-1)
     n = lab.shape[0]
-    tile = bert.head_tile(n)
+    tile = head_loss.head_tile(n)
     slots = -(-n // tile) * tile
     order = jnp.argsort(lab < 0, stable=True)
     rows = jnp.pad(order, (0, slots - n))
@@ -395,10 +395,10 @@ def test_without_weights_the_head_and_loss_is_the_parents_program():
     equation for equation, that of the function as it stood before it took
     weights, so every accepted step's program is the parent's."""
     head, x, labels, _ = _weighted_case(9)
-    now = jax.make_jaxpr(lambda head, x: bert._sharded_head(_head_fn, head, x, labels, None, True))(head, x)
+    now = jax.make_jaxpr(lambda head, x: head_loss._sharded_head(_head_fn, head, x, labels, None, True))(head, x)
     before = jax.make_jaxpr(lambda head, x: _parents_head_over_labelled(_head_fn, head, x, labels))(head, x)
     assert str(now) == str(before)
-    out = bert.labelled_nll(_head_fn, head, x, labels)
+    out = head_loss.labelled_nll(_head_fn, head, x, labels)
     assert len(out) == 2  # (loss, positions): no per-position loss without weights
 
 

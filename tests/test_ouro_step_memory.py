@@ -43,7 +43,8 @@ def _step_gb(rows: int) -> dict:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from lakesoul_tpu.models import causal_lm, ouro, train
+    from lakesoul_tpu.models import ouro, train
+    from lakesoul_tpu.utils import platform
 
     config = _bench_file("configs", "ouro_2_6b_clm_pk")
     m = config["model"]
@@ -73,7 +74,7 @@ def _step_gb(rows: int) -> dict:
         return params, opt_state, loss, {k: v for k, v in counts.items() if getattr(v, "dtype", None) == jnp.int32}
 
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(causal_lm, "_on_tpu", lambda: True)  # the branch the chip takes
+        patch.setattr(platform, "on_tpu", lambda: True)  # the branch the chip takes
         compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*state, ids, ids).compile()
     found = compiled.memory_analysis()
     gb = {

@@ -1,6 +1,7 @@
 """The hybrid causal LM (``models/qwen3_next.py``), its dropless expert layer
 (``parallel/moe.py``: ``route_top_k``, ``held_experts``, ``shared_expert``)
-and its train step (``models/train.py``) against the plain float32 reference in ``tests/reference/qwen3_next_f32.py``.
+and its train step (``models/train.py``) against the plain float32 reference in
+``benchmarks/chip/reference/qwen3_next_f32.py`` (the one copy of it, loaded by path).
 
 Small on purpose (hidden 64) with the published ratios kept: three DeltaNet
 layers to one attention layer, 2 value heads a key head, 8 query heads a
@@ -21,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lakesoul_tpu.models import causal_lm, qwen3_next as lm
+from lakesoul_tpu.models import attention, qwen3_next as lm
 from lakesoul_tpu.models.train import (
     MOE_ASSIGNMENTS_FAMILY,
     MOE_LOAD_FAMILY,
@@ -33,9 +34,9 @@ from lakesoul_tpu.obs import registry
 from lakesoul_tpu.parallel import moe
 from lakesoul_tpu.parallel.mesh import make_mesh
 
-HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
-    "qwen3_next_f32", os.path.join(HERE, "reference", "qwen3_next_f32.py")
+    "qwen3_next_f32", os.path.join(REPO, "benchmarks", "chip", "reference", "qwen3_next_f32.py")
 )
 ref = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(ref)
@@ -224,8 +225,8 @@ def test_only_whole_lane_tiles_take_the_recurrence_kernels(monkeypatch, chunk, h
 @pytest.mark.parametrize("band, rows", [(1024, 128), (64, 16), (64, 64)],
                          ids=["one-block", "bands-of-row-blocks", "bands"])
 def test_gated_attention_blocks_equal_the_masked_softmax(params, monkeypatch, band, rows):
-    monkeypatch.setattr(causal_lm, "ATTN_BAND", band)
-    monkeypatch.setattr(causal_lm, "ATTN_ROWS", rows)
+    monkeypatch.setattr(attention, "ATTN_BAND", band)
+    monkeypatch.setattr(attention, "ATTN_ROWS", rows)
     p = params["layers"][3]["attn"]
     x = hidden(3)
     weigh = jax.random.normal(jax.random.key(4), x.shape)
@@ -249,7 +250,7 @@ def test_attention_is_causal_and_rotates_a_quarter_of_the_channels(params):
         atol=1e-5,
     )
     q = jax.random.normal(jax.random.key(6), (1, 8, 2, 16))
-    turned = causal_lm._rotary(q, jnp.arange(8), 4, 1e7)
+    turned = attention._rotary(q, jnp.arange(8), 4, 1e7)
     np.testing.assert_array_equal(turned[..., 4:], q[..., 4:])
     assert not np.allclose(turned[:, 1:, :, :4], q[:, 1:, :, :4])
 

@@ -1,20 +1,14 @@
 """racecheck: the runtime Eraser detector must catch the seeded
 shared-state race (with both access stacks), stay silent on locked and
-init-phase writes, instrument/restore the hot classes cleanly, and its
-ring canary must prove the ``LAKESOUL_COLLATE_REUSE`` contract — no slot
-reused while a borrowed view is live — under the real loader with
-prefetch + device prefetch, byte-identical to the ring-off run."""
+init-phase writes, and instrument/restore the hot classes cleanly."""
 
 from __future__ import annotations
 
 import threading
 
-import numpy as np
-import pyarrow as pa
 import pytest
 
 from lakesoul_tpu.analysis import racecheck
-from lakesoul_tpu.data.jax_iter import _BufferRing
 
 
 @pytest.fixture()
@@ -104,12 +98,10 @@ def test_instrumentation_restores_on_disable(clean_racecheck):
 
     racecheck.enable()
     assert hasattr(CircuitBreaker.__dict__.get("__setattr__"), "_racecheck_orig")
-    assert hasattr(_BufferRing.next_slot, "_racecheck_orig")
     racecheck.disable()
     assert "__setattr__" not in CircuitBreaker.__dict__ or not hasattr(
         CircuitBreaker.__dict__["__setattr__"], "_racecheck_orig"
     )
-    assert not hasattr(_BufferRing.next_slot, "_racecheck_orig")
 
 
 def test_hot_classes_run_clean_under_instrumentation(clean_racecheck):
@@ -143,161 +135,3 @@ def test_env_gate(monkeypatch):
     assert not racecheck.env_requested()
     monkeypatch.setenv("LAKESOUL_RACECHECK", "1")
     assert racecheck.env_requested()
-
-
-# ------------------------------------------------------------- ring canary
-
-
-def test_ring_canary_detects_use_after_release(clean_racecheck):
-    with racecheck.watch() as w:
-        ring = _BufferRing(2)
-        held = []
-        for i in range(4):
-            slot = ring.next_slot()
-            if "c" not in slot:
-                slot["c"] = np.zeros(8)
-            held.append(slot["c"])  # borrower never lets go: contract broken
-    kinds = {v.kind for v in w.violations}
-    assert kinds == {"ring-use-after-release"}
-    assert "borrowed view is still live" in w.violations[0].message
-
-
-def test_ring_canary_poisons_released_slots(clean_racecheck):
-    """A reused slot is poisoned at hand-out, so a stale read that slips
-    past the refcount canary is loud garbage, not plausible data."""
-    with racecheck.watch():
-        ring = _BufferRing(1)
-        slot = ring.next_slot()
-        slot["c"] = np.zeros(8, dtype=np.float64)
-        ring.next_slot()  # wrap: the slot is dead, its bytes poisoned
-        assert all(b == 0xAB for b in slot["c"].view("uint8").tobytes())
-
-
-def test_ring_canary_silent_for_conforming_borrower(clean_racecheck):
-    with racecheck.watch() as w:
-        ring = _BufferRing(2)
-        for i in range(6):
-            slot = ring.next_slot()
-            if "c" not in slot:
-                slot["c"] = np.zeros(8)
-            slot["c"][...] = i  # fills and forgets, exactly one window
-    assert w.violations == [], "\n".join(v.render() for v in w.violations)
-
-
-# ----------------------------------------------- loader ring stress (e2e)
-
-
-def _ring_table(tmp_warehouse, rows: int = 20_000):
-    from lakesoul_tpu import LakeSoulCatalog
-
-    catalog = LakeSoulCatalog(str(tmp_warehouse))
-    schema = pa.schema([("id", pa.int64()), ("v", pa.float64())])
-    t = catalog.create_table("ring_stress", schema)
-    rng = np.random.default_rng(7)
-    t.write_arrow(pa.table({
-        "id": np.arange(rows, dtype=np.int64),
-        "v": rng.normal(size=rows),
-    }, schema=schema))
-    return t
-
-
-def test_collate_reuse_ring_stress_canary_and_byte_identity(
-    tmp_warehouse, monkeypatch, clean_racecheck
-):
-    """The satellite proof: under ``prefetch + device_prefetch`` with the
-    reuse ring ON and the canary ARMED, a conforming consumer (device_put
-    copies each batch out) triggers zero use-after-release across multiple
-    epochs, and the delivered values are byte-identical to the ring-off
-    run."""
-    t = _ring_table(tmp_warehouse)
-    baseline = [
-        {k: np.copy(v) for k, v in b.items()}
-        for b in t.scan().batch_size(256).to_jax_iter(
-            device_put=False, prefetch=4, drop_remainder=False
-        )
-    ]
-
-    monkeypatch.setenv("LAKESOUL_COLLATE_REUSE", "1")
-    with racecheck.watch() as w:
-        # host leg: ring on, conforming copy-out — BYTE-identical to ring-off
-        it = t.scan().batch_size(256).to_jax_iter(
-            device_put=False, prefetch=4, drop_remainder=False
-        )
-        assert it._ring is not None
-        got = [{k: np.copy(v) for k, v in b.items()} for b in it]
-        assert len(got) == len(baseline)
-        for a, b in zip(got, baseline):
-            assert a.keys() == b.keys()
-            for k in a:
-                assert a[k].tobytes() == b[k].tobytes(), k
-        # device leg under prefetch + device_prefetch: the disarm condition
-        # keys on MEASURED aliasing (tensorplane delivery_copies probe) —
-        # THIS table's columns are int64/float64, which the host backend
-        # demotes to 32-bit on device_put, so every put is a REAL copy and
-        # the ring stays ARMED even on CPU (the PR-9 platform guess kept it
-        # down); the canary proves the copies finish before slot reuse and
-        # device dtypes are the 32-bit demotions, so compare after the
-        # deterministic cast
-        for _ in range(2):
-            it = t.scan().batch_size(256).to_jax_iter(
-                device_put=True, prefetch=4, device_prefetch=2,
-                drop_remainder=False,
-            )
-            assert it._ring is not None  # every column's put is a real copy
-            dev = [{k: np.asarray(v) for k, v in b.items()} for b in it]
-            assert len(dev) == len(baseline)
-            for a, b in zip(dev, baseline):
-                for k in a:
-                    assert np.array_equal(a[k], b[k].astype(a[k].dtype)), k
-    assert w.violations == [], "\n".join(v.render() for v in w.violations)
-
-
-def test_collate_reuse_ring_disarms_on_measured_aliasing(
-    tmp_warehouse, monkeypatch, clean_racecheck
-):
-    """The other half of the probe-keyed contract: a table with a
-    device-dtype (float32) column CAN alias on a host backend — device_put
-    zero-copies aligned dtype-matching buffers — so the loader must still
-    refuse to arm the ring there (the original PR-9 aliased-overwrite
-    find, now pinned through the measurement instead of the platform)."""
-    from lakesoul_tpu import LakeSoulCatalog
-    from lakesoul_tpu.tensorplane.dlpack import device_put_copies
-
-    catalog = LakeSoulCatalog(str(tmp_warehouse))
-    schema = pa.schema([("id", pa.int64()), ("v", pa.float32())])
-    t = catalog.create_table("ring_alias", schema)
-    rng = np.random.default_rng(11)
-    t.write_arrow(pa.table({
-        "id": np.arange(4_000, dtype=np.int64),
-        "v": rng.normal(size=4_000).astype(np.float32),
-    }, schema=schema))
-    assert not device_put_copies(np.float32)  # the measured premise (CPU CI)
-    assert device_put_copies(np.int64)  # demotion = real copy
-    monkeypatch.setenv("LAKESOUL_COLLATE_REUSE", "1")
-    it = t.scan().batch_size(256).to_jax_iter(
-        device_put=True, prefetch=4, device_prefetch=2, drop_remainder=False
-    )
-    assert it._ring is None  # one aliasing column disarms the whole ring
-    # host-consumer loaders keep the old contract (consumer copies out)
-    it2 = t.scan().batch_size(256).to_jax_iter(device_put=False)
-    assert it2._ring is not None
-    list(it)
-    list(it2)
-
-
-def test_collate_reuse_ring_stress_catches_hoarding_consumer(
-    tmp_warehouse, monkeypatch, clean_racecheck
-):
-    """The adversarial twin: a consumer that KEEPS every delivered host
-    batch holds borrowed views past the ring wrap — the canary must call
-    it out (this is the silent-corruption case without racecheck)."""
-    t = _ring_table(tmp_warehouse, rows=8_000)
-    monkeypatch.setenv("LAKESOUL_COLLATE_REUSE", "1")
-    with racecheck.watch() as w:
-        it = t.scan().batch_size(256).to_jax_iter(
-            device_put=False, prefetch=4, drop_remainder=False
-        )
-        assert it._ring is not None
-        hoard = list(it)  # every batch kept: contract broken
-    assert len(hoard) > 0
-    assert any(v.kind == "ring-use-after-release" for v in w.violations)

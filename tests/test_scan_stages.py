@@ -4,8 +4,6 @@
   concat_tables + combine_chunks implementation (kept verbatim here as the
   reference) across chunked / sliced / null-bearing / fixed-size-list /
   string / bool inputs;
-- the opt-in collate buffer ring (``LAKESOUL_COLLATE_REUSE=1``) recycles
-  buffers without changing delivered values;
 - a no-PK (and a compacted-PK) scan DEGENERATES to raw decode: the merge
   and fill stages report ~0 in the ``lakesoul_scan_stage_seconds``
   breakdown while decode carries the leg;
@@ -78,7 +76,7 @@ def _new_windows(batches, batch_size, drop_remainder):
 
 def _new_collate(window: _Window):
     if window.fast:
-        return window.collate(None)
+        return window.collate()
     return _default_collate(window.to_table())
 
 
@@ -224,45 +222,6 @@ class TestByteIdentity:
         batches = _numeric_batches(3, rows=100)
         _roundtrip(batches, 100, drop_remainder=True)
         _roundtrip(batches, 10_000, drop_remainder=False)  # single tail window
-
-
-class TestBufferRing:
-    def test_ring_recycles_without_value_change(self, tmp_warehouse, monkeypatch):
-        from lakesoul_tpu import LakeSoulCatalog
-
-        catalog = LakeSoulCatalog(str(tmp_warehouse))
-        schema = pa.schema([("id", pa.int64()), ("v", pa.float64())])
-        t = catalog.create_table("ring", schema)
-        rng = np.random.default_rng(0)
-        t.write_arrow(pa.table({
-            "id": np.arange(5000, dtype=np.int64),
-            "v": rng.normal(size=5000),
-        }, schema=schema))
-
-        def snap(it):
-            # copy out immediately — the ring's documented consumer contract
-            return [{k: np.copy(v) for k, v in b.items()} for b in it]
-
-        plain = snap(t.scan().batch_size(512).to_jax_iter(
-            device_put=False, drop_remainder=False
-        ))
-        monkeypatch.setenv("LAKESOUL_COLLATE_REUSE", "1")
-        it = t.scan().batch_size(512).to_jax_iter(
-            device_put=False, drop_remainder=False
-        )
-        assert it._ring is not None
-        reused = snap(it)
-        assert len(plain) == len(reused)
-        for a, b in zip(plain, reused):
-            _assert_same_pytree(b, a)
-
-    def test_ring_slots_rotate(self):
-        from lakesoul_tpu.data.jax_iter import _BufferRing
-
-        ring = _BufferRing(3)
-        s = [ring.next_slot() for _ in range(6)]
-        assert s[0] is s[3] and s[1] is s[4] and s[2] is s[5]
-        assert s[0] is not s[1]
 
 
 # --------------------------------------------------------------------------

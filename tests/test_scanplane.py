@@ -571,8 +571,14 @@ class TestSpoolDelivery:
             )
             assert again.session_id == pinned.session_id
             assert again.version_digest == pinned.version_digest
-            # the pinned spool vanishes (prune): the stream must die loud
-            shutil.rmtree(pinned.dir(plane.spool))
+            # the pinned spool vanishes (prune): the stream must die loud.  The worker polls the
+            # spool and may be writing a range into it: stop it, and remove until nothing is left
+            for stop in plane._stops:
+                stop.set()
+            deadline = time.monotonic() + 10.0  # a worker leaves its loop within a poll of 0.02 s
+            while os.path.exists(pinned.dir(plane.spool)):
+                shutil.rmtree(pinned.dir(plane.spool), ignore_errors=True)
+                assert time.monotonic() < deadline, "a stopped worker still writes into the pinned spool"
             with pytest.raises(LakeSoulError, match="no longer exists"):
                 plane.delivery.resolve_session(
                     {**req, "session": pinned.session_id}

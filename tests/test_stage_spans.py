@@ -394,7 +394,8 @@ def lm_step_compiled_for_a_v5e() -> str:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from lakesoul_tpu.models import causal_lm, qwen3_next, train
+    from lakesoul_tpu.models import qwen3_next, train
+    from lakesoul_tpu.utils import platform
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -414,8 +415,7 @@ def lm_step_compiled_for_a_v5e() -> str:
     ids = jax.ShapeDtypeStruct((2, LM_TOKENS), jnp.int32, sharding=one_chip)
     step = train._adamw_step(functools.partial(qwen3_next.lm_loss, cfg=LM_CFG), tx)
     with pytest.MonkeyPatch.context() as patch:
-        for module in (qwen3_next, causal_lm):
-            patch.setattr(module, "_on_tpu", lambda: True)  # the branch the chip takes
+        patch.setattr(platform, "on_tpu", lambda: True)  # the branch the chip takes
         return jax.jit(step).lower(*state, ids, ids).compile().as_text()
 
 
@@ -474,6 +474,7 @@ def test_expert_sums_by_dma_stay_under_the_experts_scope(n, f, tile, sums_by_ker
     from jax.sharding import SingleDeviceSharding
 
     from lakesoul_tpu.parallel import moe
+    from lakesoul_tpu.utils import platform
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -491,7 +492,7 @@ def test_expert_sums_by_dma_stay_under_the_experts_scope(n, f, tile, sums_by_ker
 
     p = {"w_gate": shape((4, h, f)), "w_up": shape((4, h, f)), "w_down": shape((4, f, h))}
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(moe, "_on_tpu", lambda: True)  # the branch the chip takes
+        patch.setattr(platform, "on_tpu", lambda: True)  # the branch the chip takes
         text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
             shape((n, h), jnp.bfloat16), shape((n, k)), p, shape((n, k), jnp.int32)
         ).compile().as_text()
@@ -611,8 +612,8 @@ def _family_step_compiled_for_a_v5e(cfg) -> str:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from lakesoul_tpu.models import causal_lm, train
-    from lakesoul_tpu.parallel import moe
+    from lakesoul_tpu.models import train
+    from lakesoul_tpu.utils import platform
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -631,8 +632,7 @@ def _family_step_compiled_for_a_v5e(cfg) -> str:
     )
     ids = jax.ShapeDtypeStruct((2, LM_TOKENS), jnp.int32, sharding=one_chip)
     with pytest.MonkeyPatch.context() as patch:
-        for module in (causal_lm, moe):
-            patch.setattr(module, "_on_tpu", lambda: True)  # the branch the chip takes
+        patch.setattr(platform, "on_tpu", lambda: True)  # the branch the chip takes
         return jax.jit(train._adamw_step(cfg.loss, tx)).lower(*state, ids, ids).compile().as_text()
 
 
@@ -909,7 +909,7 @@ def test_attn_key_tiles_counter_is_the_tile_tables(monkeypatch):
     key-value heads.  And the host count at the Trinity-Mini cell's shapes,
     from an abstract trace of the mixers: 280 and 544 steps a key-value head,
     61.2% over four window layers and a full one."""
-    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models import attention
     from lakesoul_tpu.models.train import ATTN_KEY_TILES_FAMILY, make_lm_train_state, make_lm_train_step
     from lakesoul_tpu.obs import registry
     from lakesoul_tpu.parallel.mesh import make_mesh
@@ -922,20 +922,20 @@ def test_attn_key_tiles_counter_is_the_tile_tables(monkeypatch):
     )
     weights = jax.eval_shape(whole.init, jax.random.key(0))["layers"]
     rows = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16)
-    counts = {kind: causal_lm.mixer_counts(whole.mixer(kind)[0], rows, weights[layer][kind])
+    counts = {kind: attention.mixer_counts(whole.mixer(kind)[0], rows, weights[layer][kind])
               for kind, layer in (("swa", 0), ("attn", 2))}
     found = {kind: (n["attn_tiles_run"], n["attn_tiles_causal"]) for kind, n in counts.items()}
     assert found == {"swa": (2 * 4 * 280, 2 * 4 * 544), "attn": (2 * 4 * 544, 2 * 4 * 544)}
     run, causal = (4 * found["swa"][i] + found["attn"][i] for i in (0, 1))
     assert round(100 * run / causal, 1) == 61.2
 
-    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
-    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 256)
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 256)
     cfg = _afmoe_cfg(
         hidden_size=64, num_hidden_layers=2, layer_types=("sliding_attention", "full_attention"),
         num_attention_heads=4, num_key_value_heads=2, sliding_window=100,
     )
-    assert len(causal_lm._flash_pairs(384, 128, 128, 100)) == 5 and len(causal_lm._flash_pairs(384, 128, 128)) == 6
+    assert len(attention._flash_pairs(384, 128, 128, 100)) == 5 and len(attention._flash_pairs(384, 128, 128)) == 6
     plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
     params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
     step = make_lm_train_step(cfg, plan, tx, shardings)
@@ -981,7 +981,7 @@ def test_attn_operand_rows_counter_is_the_rule(monkeypatch):
     (the ``jnp`` lines): the step's softmax-attention layer-rows by the path
     :func:`_operand_tiles` picks, host integers like the tile counts.  And the
     host count at the three cells' shapes that list the metric."""
-    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models import attention
     from lakesoul_tpu.models.train import ATTN_OPERAND_ROWS_FAMILY, make_lm_train_state, make_lm_train_step
     from lakesoul_tpu.obs import registry
     from lakesoul_tpu.parallel.mesh import make_mesh
@@ -991,10 +991,10 @@ def test_attn_operand_rows_counter_is_the_rule(monkeypatch):
         return {path: found.get(f'{ATTN_OPERAND_ROWS_FAMILY}{{path="{path}"}}', 0) for path in ("kernel", "xla")}
 
     # Trinity-Mini (both kinds of layer), Qwen3-Next (64 of 256 channels turned), LFM2 (a head of 64)
-    assert causal_lm._operand_tiles(8192, 32, 4, 128, 128) == causal_lm._operand_tiles(8192, 32, 4, 128, None) == 512
-    assert causal_lm._operand_tiles(8192, 16, 2, 256, 64) is None and causal_lm._operand_tiles(8192, 32, 8, 64, 64) is None
-    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
-    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 256)
+    assert attention._operand_tiles(8192, 32, 4, 128, 128) == attention._operand_tiles(8192, 32, 4, 128, None) == 512
+    assert attention._operand_tiles(8192, 16, 2, 256, 64) is None and attention._operand_tiles(8192, 32, 8, 64, 64) is None
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 256)
     plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
     ids = jnp.zeros((2, 384), jnp.int32)
     for head, want in ((128, {"kernel": 4, "xla": 0}), (64, {"kernel": 0, "xla": 4})):
@@ -1018,7 +1018,7 @@ def test_attn_output_rows_counter_is_the_rule(monkeypatch):
     the same stack at a head of 64 (heads first, the transpose after): the
     step's attention layer-rows by what :func:`_token_major` says, host
     integers like the tile counts.  And the rule at the five cells' shapes."""
-    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models import attention
     from lakesoul_tpu.models.train import ATTN_OUTPUT_ROWS_FAMILY, make_lm_train_state, make_lm_train_step
     from lakesoul_tpu.obs import registry
     from lakesoul_tpu.parallel.mesh import make_mesh
@@ -1028,11 +1028,11 @@ def test_attn_output_rows_counter_is_the_rule(monkeypatch):
         return {layout: found.get(f'{ATTN_OUTPUT_ROWS_FAMILY}{{layout="{layout}"}}', 0) for layout in ("tokens", "heads")}
 
     # Trinity-Mini, Ouro, GLM (latent attention), Qwen3-Next: heads of one and two lane tiles; LFM2: half a tile
-    assert all(causal_lm._token_major(8192, groups, d) for groups, d in ((8, 128), (1, 128), (1, 256), (8, 256)))
-    assert not causal_lm._token_major(8192, 4, 64) and causal_lm._flash_tiles(8192, 4, 64) is not None
-    assert not causal_lm._token_major(150, 8, 128)  # a row the kernels refuse: the twin writes heads first
-    monkeypatch.setattr(causal_lm, "FLASH_KEYS", 128)
-    monkeypatch.setattr(causal_lm, "FLASH_ROWS", 256)
+    assert all(attention._token_major(8192, groups, d) for groups, d in ((8, 128), (1, 128), (1, 256), (8, 256)))
+    assert not attention._token_major(8192, 4, 64) and attention._flash_tiles(8192, 4, 64) is not None
+    assert not attention._token_major(150, 8, 128)  # a row the kernels refuse: the twin writes heads first
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 256)
     plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
     ids = jnp.zeros((2, 384), jnp.int32)
     for head, want in ((128, {"tokens": 4, "heads": 0}), (64, {"tokens": 0, "heads": 4})):
@@ -1186,7 +1186,7 @@ def test_loop_series_are_the_steps_shapes():
         make_lm_train_state,
         make_lm_train_step,
     )
-    from lakesoul_tpu.models import causal_lm
+    from lakesoul_tpu.models import attention
     from lakesoul_tpu.obs import registry
     from lakesoul_tpu.parallel.mesh import make_mesh
 
@@ -1207,7 +1207,7 @@ def test_loop_series_are_the_steps_shapes():
         params, opt_state, _loss = step(params, opt_state, ids, labels)
     moved = {key: value - before.get(key, 0) for key, value in series().items()}
     layer_rows, labelled = steps * rows * OURO_LAYERS, steps * rows * (LM_TOKENS - 1)
-    tiles = causal_lm.key_tile_steps(LM_TOKENS, 1, 128)[0] * 2  # two key-value heads a layer-row
+    tiles = attention.key_tile_steps(LM_TOKENS, 1, 128)[0] * 2  # two key-value heads a layer-row
     want = {
         f'{LOOP_LAYER_PASSES_FAMILY}{{kind="run"}}': OURO_PASSES * layer_rows,
         f'{LOOP_LAYER_PASSES_FAMILY}{{kind="layers"}}': layer_rows,

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from lakesoul_tpu.annplane import ragged
-from lakesoul_tpu.models import causal_lm, loss_tile, qwen3_next
+from lakesoul_tpu.models import attention, loss_tile, qwen3_next
 from lakesoul_tpu.parallel import moe
 from lakesoul_tpu.tensorplane.smoke import enumerate_pallas_kernels
 from lakesoul_tpu.vector import kernels
@@ -156,15 +156,15 @@ def _attention_row(d):
     t = 8192
     bf16 = jnp.bfloat16
     q = _sds((hkv, groups, t, d), bf16)
-    o = _sds((1, t, hkv * groups * d), bf16) if causal_lm._token_major(t, groups, d) else q
-    return q, _sds((hkv, t, d), bf16), o, dict(zip(("bq", "bk"), causal_lm._flash_tiles(t, groups, d)))
+    o = _sds((1, t, hkv * groups * d), bf16) if attention._token_major(t, groups, d) else q
+    return q, _sds((hkv, t, d), bf16), o, dict(zip(("bq", "bk"), attention._flash_tiles(t, groups, d)))
 
 
 def _flash_forward(d, window=None):
     q, k, o, tiles = _attention_row(d)
     batch = None if o is q else 1
     return jax.jit(
-        lambda q, k, v: causal_lm._flash_forward(q, k, v, **tiles, window=window, batch=batch, interpret=False)
+        lambda q, k, v: attention._flash_forward(q, k, v, **tiles, window=window, batch=batch, interpret=False)
     ).trace(q, k, k)
 
 
@@ -172,7 +172,7 @@ def _flash_backward(d, window=None):
     q, k, o, tiles = _attention_row(d)
     lse = _sds((*q.shape[:2], 1, q.shape[2]))
     return jax.jit(
-        lambda q, k, v, o, lse, do: causal_lm._flash_backward(
+        lambda q, k, v, o, lse, do: attention._flash_backward(
             q, k, v, o, lse, do, **tiles, window=window, interpret=False
         )
     ).trace(q, k, k, o, lse, o)
@@ -188,18 +188,18 @@ def _operand_row(turned=True, family="trinity-mini", normed=True):
     laid = [_sds((1, hkv, groups, t, d), bf16), _sds((1, hkv, t, d), bf16), _sds((1, hkv, t, d), bf16)]
     turn = (_sds((t, d)), _sds((t, d))) if turned else None
     weights = [_sds((d,)), _sds((d,))] if normed else [None, None]
-    recipe = dict(d=d, eps=1e-5, bt=causal_lm._operand_tiles(t, hkv * groups, hkv, d, d if turned else None), interpret=False)
+    recipe = dict(d=d, eps=1e-5, bt=attention._operand_tiles(t, hkv * groups, hkv, d, d if turned else None), interpret=False)
     return raw, laid, [*weights, turn], recipe
 
 
 def _operands_forward(d, turned=True, **row):
     raw, _, rest, recipe = _operand_row(turned, **row)
-    return jax.jit(lambda *a: causal_lm._operands_forward(*a, **recipe)).trace(*raw, *rest)
+    return jax.jit(lambda *a: attention._operands_forward(*a, **recipe)).trace(*raw, *rest)
 
 
 def _operands_backward(d, turned=True, **row):
     raw, laid, rest, recipe = _operand_row(turned, **row)
-    return jax.jit(lambda *a: causal_lm._operands_backward(*a, **recipe)).trace(*laid, *raw[:2], *rest)
+    return jax.jit(lambda *a: attention._operands_backward(*a, **recipe)).trace(*laid, *raw[:2], *rest)
 
 
 # keyed by lakelint device-index qname, like the smoke register
@@ -209,10 +209,10 @@ TRACERS = {
     "lakesoul_tpu/vector/kernels.py::_packed_dot_batch_kernel": _packed_dot_batch,
     "lakesoul_tpu/vector/kernels.py::_bruteforce_kernel": _bruteforce,
     "lakesoul_tpu/annplane/ragged.py::_ragged_score_kernel": _ragged_score,
-    "lakesoul_tpu/models/causal_lm.py::_flash_fwd_kernel": _flash_forward,
-    "lakesoul_tpu/models/causal_lm.py::_flash_bwd_kernel": _flash_backward,
-    "lakesoul_tpu/models/causal_lm.py::_operands_fwd_kernel": _operands_forward,
-    "lakesoul_tpu/models/causal_lm.py::_operands_bwd_kernel": _operands_backward,
+    "lakesoul_tpu/models/attention.py::_flash_fwd_kernel": _flash_forward,
+    "lakesoul_tpu/models/attention.py::_flash_bwd_kernel": _flash_backward,
+    "lakesoul_tpu/models/attention.py::_operands_fwd_kernel": _operands_forward,
+    "lakesoul_tpu/models/attention.py::_operands_bwd_kernel": _operands_backward,
     "lakesoul_tpu/models/qwen3_next.py::_unit_lower_inverse_kernel": _unit_lower_inverse,
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_fwd_kernel": _gated_delta_forward,
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_bwd_kernel": _gated_delta_backward,
@@ -246,8 +246,8 @@ def test_attention_kernels_lower_at_every_published_shape(kernel, family):
     block spec at the four heads of whole lane tiles, the heads-first one at
     head 64."""
     if family in ("glm-4.7-flash", "ouro"):
-        assert causal_lm._flash_tiles(8192, 1, ATTENTION_ROWS[family][2]) == (512, 512)
-    lowered = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](family).lower(lowering_platforms=("tpu",))
+        assert attention._flash_tiles(8192, 1, ATTENTION_ROWS[family][2]) == (512, 512)
+    lowered = TRACERS["lakesoul_tpu/models/attention.py::" + kernel](family).lower(lowering_platforms=("tpu",))
     call = next(line for line in lowered.as_text().splitlines() if "tpu_custom_call" in line)
     # where a head is whole lane tiles the call writes the output (reads its cotangent) token-major, a block of
     # ``bq`` tokens by a group's lanes; at LFM2's head of 64 heads first, as before
@@ -262,13 +262,13 @@ def test_attention_kernels_lower_with_and_without_the_window(kernel, window):
     heads at head 128, 8,192 tokens in tiles of 128 queries x 512 keys, under
     the causal mask (544 steps a head) and under a window of 2,048 (280: the
     lower edge's mask and the grid's first key tile read off the step)."""
-    assert causal_lm._flash_tiles(8192, 8, 128) == (128, 512)
-    lowered = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel]("trinity-mini", window).lower(
+    assert attention._flash_tiles(8192, 8, 128) == (128, 512)
+    lowered = TRACERS["lakesoul_tpu/models/attention.py::" + kernel]("trinity-mini", window).lower(
         lowering_platforms=("tpu",)
     )
     text = lowered.as_text()
     assert "tpu_custom_call" in text
-    steps = causal_lm.key_tile_steps(8192, 8, 128, window)[0]
+    steps = attention.key_tile_steps(8192, 8, 128, window)[0]
     assert steps == (280 if window else 544) and f"tensor<{steps}xi32>" in text  # the tables the call prefetches
 
 
@@ -279,8 +279,8 @@ def test_operand_kernels_lower_with_and_without_positions(kernel, turned):
     heads at head 128: blocks of 512 tokens of a key-value head's group, a
     grid of 16 token blocks by 4 heads; the window layer's call reads the two
     position tables, the full layer's has none."""
-    assert causal_lm._operand_tiles(8192, 32, 4, 128, 128 if turned else None) == 512
-    traced = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](128, turned)
+    assert attention._operand_tiles(8192, 32, 4, 128, 128 if turned else None) == 512
+    traced = TRACERS["lakesoul_tpu/models/attention.py::" + kernel](128, turned)
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
     assert ("tensor<8192x128xf32>" in text) == turned  # the tables
@@ -294,13 +294,13 @@ def test_operand_kernels_lower_without_head_norms(kernel):
     the two position tables and no norm weight, and the backward call neither
     the raw query nor the raw key (turning back needs neither) and writes no
     weight gradient's share."""
-    assert causal_lm._operand_tiles(8192, 16, 16, 128, 128) == 512
-    traced = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](128, family="ouro", normed=False)
+    assert attention._operand_tiles(8192, 16, 16, 128, 128) == 512
+    traced = TRACERS["lakesoul_tpu/models/attention.py::" + kernel](128, family="ouro", normed=False)
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     call = next(line for line in text.splitlines() if "tpu_custom_call" in line)
     assert "tensor<8192x128xf32>" in call and "tensor<1x128xf32>" not in call  # the tables, no norm weight
     operands = call[call.index("tpu_custom_call(") : call.index(")")].count("%")
     assert operands == 5  # three arrays and the two tables, in either direction
     assert ("x8x128xf32>" in call) is False  # no share of a weight gradient
-    normed = TRACERS["lakesoul_tpu/models/causal_lm.py::" + kernel](128).lower(lowering_platforms=("tpu",)).as_text()
+    normed = TRACERS["lakesoul_tpu/models/attention.py::" + kernel](128).lower(lowering_platforms=("tpu",)).as_text()
     assert "tensor<1x128xf32>" in next(line for line in normed.splitlines() if "tpu_custom_call" in line)
