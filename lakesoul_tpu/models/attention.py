@@ -770,6 +770,36 @@ def causal_attention(q, k, v, window: int | None = None):
     return o.reshape(b, t, hkv * groups, d)
 
 
+def paired_attention(q, k, v, window: int | None = None):
+    """The two score maps of differential attention's head pairs
+    (arXiv:2410.05258) over ONE :func:`causal_attention`: q [B, T, heads, D]
+    (scaled), k, v [B, T, kv heads, D] → (o1, o2), each [B, T, heads / 2, 2D].
+    Adjacent heads pair: query pair ``p`` is heads ``2p`` (``q1``) and
+    ``2p + 1`` (``q2``), key-value pair ``g`` keys ``2g`` (``k1``) and
+    ``2g + 1`` (``k2``) and the value ``V = [v_2g ; v_2g+1]``, 2D wide; pair
+    ``p`` reads ``g = p // r``, ``r`` the query pairs a key-value pair
+    serves.  ``o1 = softmax(q1 k1^T) V`` and ``o2 = softmax(q2 k2^T) V``
+    under the causal mask and ``window``.
+
+    The kernels take one head size a call, so a map meets the two halves of
+    its value as two key-value heads with the same key: key-value head
+    ``(g, a, c)`` is key ``2g + a`` beside value ``2g + c`` and serves the
+    ``r`` queries ``(2(g r + i) + a)``; each score map is computed twice
+    (16 D operations a query pair and visible key where 12 D are required)."""
+    b, t, heads, d = q.shape
+    pairs = k.shape[2] // 2
+    r = heads // (2 * pairs)
+    q = jnp.broadcast_to(q.reshape(b, t, pairs, r, 2, 1, d).transpose(0, 2, 4, 5, 3, 1, 6), (b, pairs, 2, 2, r, t, d))
+    k = jnp.broadcast_to(k.reshape(b, t, pairs, 2, 1, d).transpose(0, 2, 3, 4, 1, 5), (b, pairs, 2, 2, t, d))
+    v = jnp.broadcast_to(v.reshape(b, t, pairs, 1, 2, d).transpose(0, 2, 3, 4, 1, 5), (b, pairs, 2, 2, t, d))
+    o = causal_attention(
+        q.reshape(b, 4 * pairs, r, t, d), k.reshape(b, 4 * pairs, t, d), v.reshape(b, 4 * pairs, t, d), window
+    )
+    # heads ((g, a, c), i) → [map a, pair (g, i), (value half c, D)]
+    o = o.reshape(b, t, pairs, 2, 2, r, d).transpose(0, 1, 3, 2, 5, 4, 6).reshape(b, t, 2, pairs * r, 2 * d)
+    return o[:, :, 0], o[:, :, 1]
+
+
 def attention_operands(q, k, v, wq, wk, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):
     """A softmax-attention mixer's raw q, k, v and its two head norms' weights
     → :func:`causal_attention`'s operands (:func:`_xla_operands`' arguments

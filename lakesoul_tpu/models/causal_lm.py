@@ -3,7 +3,7 @@ and the pieces more than one family's mixers are made of.
 
 A family is a module with a configuration object (``models/qwen3_next.py``,
 ``models/lfm2_moe.py``, ``models/glm4_moe_lite.py``, ``models/afmoe.py``,
-``models/ouro.py``); nothing here or in
+``models/ouro.py``, ``models/phi4flash.py``); nothing here or in
 ``models/train.py`` names one.  The stack reads a layer's kinds from the
 configuration and asks it for the rest:
 
@@ -15,6 +15,14 @@ configuration and asks it for the rest:
   under ``"mlp"``) or ``"moe"`` (routed experts, under ``"moe"``);
 - ``mixer(kind)`` → (``fn(y, p)`` on the normed input, the ``named_scope`` its
   device time is charged to);
+- ``shares(kind)``, where the family has it: None for a mixer of its own
+  input alone, else ``(name, make)``: the mixer reads a SHARED STATE and is
+  ``fn(y, p, state)``.  ``make(y, p)`` computes that state from the layer's
+  own normed input and publishes it under ``name`` for the layers after it
+  (a source); ``make`` None: the layer reads what the nearest layer before it
+  published under ``name`` (:func:`lm_layer`).  ``kept``, where the family
+  has it: the ``checkpoint_name``s of what its own kernels keep of a row for
+  the backward pass, beside the attention kernels' two;
 - ``norm(x, w)``: the family's RMS norm, float32 out;
 - ``route(y32, router, bias)`` → (experts, weights, assignments the bias
   moved): its routing rule over ``parallel/moe.py``;
@@ -58,8 +66,20 @@ experts' share and the shared expert summed.  After the last layer
 a final norm and the head: ``params["head"]`` [h, vocab] where there is one,
 else the embedding (a tied head).  ``params["buffers"]``, where a family has
 it, is state that no gradient and no optimizer touches (``models/train.py:
-_adamw_step``); ``buffers["layers"][i]`` belongs to layer ``i`` and
+_adamw_step``); ``buffers["layers"][i]`` belongs to layer ``i`` (what it holds
+under ``"mixer"`` reaches the layer's mixer beside its weights) and
 ``buffers["mtp"]`` to the prediction module's layer.
+
+A layer whose mixer shares a state runs two row loops where the others run
+one: the first, where the layer is a source, makes the state a row at a time;
+the second hands each row of the layer's input and of the state to the mixer.
+The state is the first loop's result and the second's argument, so a source is
+computed once in the forward pass, what it publishes is what the backward pass
+keeps of it (the second loop's checkpoint keeps its arguments: the same
+array, no second copy), and the cotangent of the state adds up over every
+layer that read it before the first loop's transpose takes it.  A family that
+shares nothing walks the one loop, and its step's program does not change by
+what a sharing family needs.
 
 A mixer's softmax attention is ``models/attention.py``'s:
 :func:`softmax_attention` and :func:`latent_attention` make a layer's raw
@@ -196,17 +216,23 @@ def latent_attention(x, p, *, heads: int, nope_dim: int, rope_dim: int, theta: f
 # ------------------------------------------------------------- the stack
 
 
-def _row_by_row(mixer, x, p, batch_sharding):
+def _row_by_row(mixer, x, p, batch_sharding, kept=()):
     """``mixer(x, p)`` one row of ``x`` [B, T, h] at a time, each row
     rematerialised: a mixer's intermediates at 8k tokens are gigabytes a row
     and no row needs another's.  Of a row the backward pass keeps its input
     and what the attention kernels name (:data:`ATTN_KEPT`: 34 MB a row at 32
-    heads of 64).  On a mesh every device takes its own rows."""
+    heads of 64) and what the family's own kernels do (``kept``: its
+    ``checkpoint_name``s).  On a mesh every device takes its own rows.  ``x``
+    and the result may be trees of arrays with the rows first (a layer's
+    input beside a shared state, :func:`lm_layer`)."""
 
-    keep = jax.checkpoint_policies.save_only_these_names(*ATTN_KEPT)
+    keep = jax.checkpoint_policies.save_only_these_names(*ATTN_KEPT, *kept)
 
     def local(x, p):
-        return jax.lax.map(jax.checkpoint(lambda row: mixer(row[None], p)[0], policy=keep), x)
+        def a_row(row):
+            return jax.tree.map(lambda a: a[0], mixer(jax.tree.map(lambda a: a[None], row), p))
+
+        return jax.lax.map(jax.checkpoint(a_row, policy=keep), x)
 
     if batch_sharding is None:
         return local(x, p)
@@ -223,10 +249,25 @@ def dense_mlp(x, p):
     return mid @ p["w_down"].astype(dtype)
 
 
-def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
+def _shares(cfg, kind: str):
+    """``cfg.shares(kind)``; None for a family without shared states."""
+    return cfg.shares(kind) if hasattr(cfg, "shares") else None
+
+
+def _mixer_weights(lp, kind: str, buffers):
+    """A layer's mixer's weights as its mixer is handed them: ``lp[kind]``,
+    and beside them what the layer's buffers hold under ``"mixer"`` (numbers
+    of the layer that nothing trains)."""
+    held = (buffers or {}).get("mixer")
+    return lp[kind] if held is None else {**lp[kind], **held}
+
+
+def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None, shared=None):
     """One layer, its weights ``lp`` and its buffers (``None`` or a dict): x
     [B, T, h] → (x, the expert layer's counts; None after a dense
-    feed-forward).  The mixer is rematerialised a row at a time; the
+    feed-forward).  ``shared``: what the layers before this one published, by
+    name (:func:`_layers`' dict; a layer whose mixer makes a shared state
+    adds its own), read only where ``cfg.shares(kind)`` says so.  The mixer is rematerialised a row at a time; the
     dense feed-forward with its norm; of a routed layer norm, routing and the
     shared expert together, and the held experts' tile loop not (its backward
     pass needs its inputs alone).  What the backward pass keeps of a layer:
@@ -243,8 +284,15 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
     def normed_out(out, w):
         return out if w is None else cfg.norm(out, w).astype(dtype)
 
+    def normed_in(x, p):
+        return cfg.norm(x, p["norm"]).astype(dtype)
+
     def mix(x, p):
-        return x + normed_out(mixer(cfg.norm(x, p["norm"]).astype(dtype), p["mixer"]), p.get("out"))
+        return x + normed_out(mixer(normed_in(x, p), p["mixer"]), p.get("out"))
+
+    def mix_with(x_state, p):
+        x, state = x_state
+        return x + normed_out(mixer(normed_in(x, p), p["mixer"], state), p.get("out"))
 
     @jax.checkpoint
     def dense(x, norm, p, out):
@@ -258,13 +306,24 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
         y = y32.astype(dtype)
         return y, top_e, w, moved, None if shared is None else shared_expert(y, shared)
 
-    mixed = {"norm": lp["norm1"], "mixer": lp[kind]}
+    mixed = {"norm": lp["norm1"], "mixer": _mixer_weights(lp, kind, buffers)}
     if "norm1_out" in lp:
         mixed["out"] = lp["norm1_out"]
+    share = _shares(cfg, kind)
+    kept = getattr(cfg, "kept", ())
     # a scope stands around the call, not inside the checkpointed function: what the checkpoint itself
     # writes (the copies it keeps its inputs in) and each residual add are then the feed-forward's too
     with jax.named_scope(scope):
-        x = _row_by_row(mix, x, mixed, batch_sharding)
+        if share is None:
+            x = _row_by_row(mix, x, mixed, batch_sharding, kept)
+        else:
+            # the state is a row loop's result and the next one's argument: computed once, held once (what
+            # the second loop's checkpoint keeps of it is the array the first one wrote), and its cotangent
+            # comes back into the first loop's transpose from every layer that read it
+            name, make = share
+            if make is not None:
+                shared[name] = _row_by_row(lambda x, p: make(normed_in(x, p), p["mixer"]), x, mixed, batch_sharding, kept)
+            x = _row_by_row(mix_with, (x, shared[name]), mixed, batch_sharding, kept)
     if ffn == "dense":
         with jax.named_scope(MLP_SCOPE):
             return x + dense(x, lp["norm2"], lp["mlp"], lp.get("norm2_out")), None
@@ -288,11 +347,15 @@ def lm_layer(x, lp, buffers, *, kind: str, ffn: str, cfg, batch_sharding=None):
     return x, counts
 
 
-def layer_attention_counts(cfg, kind: str, x, p) -> dict:
+def layer_attention_counts(cfg, kind: str, x, p, state=None) -> dict:
     """:func:`mixer_counts` of one layer's mixer (its weights ``p``) over
-    the batch ``x`` [B, T, h], as the counts a loss returns."""
+    the batch ``x`` [B, T, h], as the counts a loss returns; ``state``: the
+    shapes of a row's shared state, where the mixer reads one."""
     row = jax.ShapeDtypeStruct((1, *x.shape[1:]), x.dtype)
-    return {key: x.shape[0] * n for key, n in mixer_counts(cfg.mixer(kind)[0], row, p).items()}
+    mixer = cfg.mixer(kind)[0]
+    if state is not None:
+        row, mixer = (row, state), lambda x_state, p, mixer=mixer: mixer(x_state[0], p, x_state[1])
+    return {key: x.shape[0] * n for key, n in mixer_counts(mixer, row, p).items()}
 
 
 def _sum_counts(totals: dict | None, counts: dict | None) -> dict:
@@ -319,9 +382,9 @@ def _layers(params, x, *, cfg, batch_sharding=None):
     layers' counts summed over the routed layers, or None)."""
     kinds, ffns = cfg.layer_kinds(), cfg.ffn_kinds()
     buffers = params.get("buffers", {}).get("layers", [None] * len(kinds))
-    totals = None
+    totals, shared = None, {}
     for lp, held, kind, ffn in zip(params["layers"], buffers, kinds, ffns, strict=True):
-        x, counts = lm_layer(x, lp, held, kind=kind, ffn=ffn, cfg=cfg, batch_sharding=batch_sharding)
+        x, counts = lm_layer(x, lp, held, kind=kind, ffn=ffn, cfg=cfg, batch_sharding=batch_sharding, shared=shared)
         if counts is not None:
             with jax.named_scope(EXPERTS_SCOPE):
                 totals = counts if totals is None else jax.tree.map(jnp.add, totals, counts)
@@ -331,13 +394,24 @@ def _layers(params, x, *, cfg, batch_sharding=None):
 def _attention_counts(params, x, *, cfg) -> dict:
     """:func:`layer_attention_counts` summed over one walk through the layers
     for the batch ``x`` [B, T, h], traced once a kind (a kind's layers have
-    one shape): Python integers, no operation of the program."""
-    of_kind, tiles = {}, None
-    for lp, kind in zip(params["layers"], cfg.layer_kinds(), strict=True):
+    one shape): Python integers, no operation of the program.  Of a family
+    with shared states also ``shared_reads``: the rows of the layers that read
+    a state an EARLIER layer published."""
+    kinds = cfg.layer_kinds()
+    buffers = params.get("buffers", {}).get("layers", [None] * len(kinds))
+    row = jax.ShapeDtypeStruct((1, *x.shape[1:]), x.dtype)
+    of_kind, tiles, states, reads = {}, None, {}, 0
+    for lp, held, kind in zip(params["layers"], buffers, kinds, strict=True):
+        share = _shares(cfg, kind)
+        p = _mixer_weights(lp, kind, held)
+        if share is not None and share[1] is not None:
+            states[share[0]] = jax.eval_shape(share[1], row, p)
+        elif share is not None:
+            reads += x.shape[0]
         if kind not in of_kind:
-            of_kind[kind] = layer_attention_counts(cfg, kind, x, lp[kind])
+            of_kind[kind] = layer_attention_counts(cfg, kind, x, p, None if share is None else states[share[0]])
         tiles = _sum_counts(tiles, of_kind[kind])
-    return tiles
+    return dict(tiles, shared_reads=reads) if hasattr(cfg, "shares") else tiles
 
 
 def lm_hidden(params, ids, *, cfg, batch_sharding=None):

@@ -107,6 +107,13 @@ LOOP_LAYER_PASSES_FAMILY = "lakesoul_train_loop_layer_passes_total"
 # positions of every step so far (``models/causal_lm.py: exit_loss``); the ``R`` series sum to 1
 LOOP_EXIT_MASS_FAMILY = "lakesoul_train_loop_exit_mass"
 
+# an LM step's selective-scan rows (``models/selective_scan.py``; rows x the layers that scan), by what ran them:
+# ``{path="kernel"}`` the Pallas pair, ``{path="twin"}`` the ``lax.scan``; host integers, the shapes decide
+SSM_SCAN_ROWS_FAMILY = "lakesoul_train_ssm_scan_rows_total"
+# the rows of the layers whose mixer read a state an EARLIER layer published (``models/causal_lm.py: lm_layer``,
+# ``cfg.shares``); host integers
+SHARED_READS_FAMILY = "lakesoul_train_shared_state_reads_total"
+
 # live steps, and what the collected ones had counted: the families are
 # counters and must not fall when a step is dropped
 _live_steps: "weakref.WeakSet[_CountedStep]" = weakref.WeakSet()
@@ -388,13 +395,18 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     - of a looped family (``cfg.loop_passes``) also
       ``lakesoul_train_loop_layer_passes_total{kind="run"|"layers"}``
       (``causal_lm.py: loop_hidden``, host integers) and the gauge
-      ``lakesoul_train_loop_exit_mass{pass="1".."R"}`` (``exit_loss``)."""
+      ``lakesoul_train_loop_exit_mass{pass="1".."R"}`` (``exit_loss``);
+    - ``lakesoul_train_ssm_scan_rows_total{path="kernel"|"twin"}`` (a family
+      with a selective scan: its ``loss``) and
+      ``lakesoul_train_shared_state_reads_total`` (a family whose layers read
+      what earlier ones published: ``causal_lm.py: _attention_counts``): host
+      integers, 0 for every other family."""
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
     host_keys = ("attn_tiles_run", "attn_tiles_causal", "attn_out_tokens", "attn_out_heads",
                  "attn_operands_kernel", "attn_operands_xla", "loop_layers_run", "loop_layers",
-                 "loss_rows_fused", "loss_rows_compiler")
+                 "loss_rows_fused", "loss_rows_compiler", "ssm_rows_kernel", "ssm_rows_twin", "shared_reads")
     held = getattr(cfg, "experts_held", None)
     if held is None:  # a family without experts: its loss returns none of their counts, and they count 0
         host_keys += ("moe_held", "moe_all", "moe_tile_rows", "moe_bias_moved", "moe_dw_writes", "moe_load_max")
@@ -431,6 +443,9 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("head_loop", HEAD_POSITIONS_FAMILY, {"kind": "loop"}, 1),
         ("loop_layers_run", LOOP_LAYER_PASSES_FAMILY, {"kind": "run"}, 1),
         ("loop_layers", LOOP_LAYER_PASSES_FAMILY, {"kind": "layers"}, 1),
+        ("ssm_rows_kernel", SSM_SCAN_ROWS_FAMILY, {"path": "kernel"}, 1),
+        ("ssm_rows_twin", SSM_SCAN_ROWS_FAMILY, {"path": "twin"}, 1),
+        ("shared_reads", SHARED_READS_FAMILY, {}, 1),
         *loop_series,
     )
     return _CountedStep(

@@ -296,6 +296,44 @@ def _run_gated_delta(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"tokens": t, "heads": [hk, hv], "rel_err": [round(e, 6) for e in errors]}
 
 
+def _run_selective_scan(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.models.selective_scan import _scan_backward, _scan_forward, _scan_twin, scan_takes
+
+    # a row of a Mamba-1 layer, 5,120 channels of 16 states over 8,192 tokens at
+    # deployed sizes (256 channels over two blocks of tokens at tiny ones): the
+    # output and the six cotangents of the kernel pair against the token-by-token
+    # ``lax.scan`` and its autodiff (at deployed sizes over the row's first 512
+    # tokens: the twin's transpose keeps every state), u and dy bfloat16 as the
+    # model passes them.  Both run the recurrence in float32; the kernels round y
+    # and du once, to bfloat16
+    t, e, n = (8192, 5120, 16) if sizes.rows >= 1 << 20 else (256, 256, 16)
+    held, lo, f32 = min(t, 512), jnp.bfloat16, jnp.float32
+    keys = jax.random.split(jax.random.key(12), 7)
+    u, dy = (jax.random.normal(key, (1, t, e)).astype(lo) for key in keys[:2])
+    delta = jax.nn.softplus(jax.random.normal(keys[2], (1, t, e)) - 2.0)
+    a = -jnp.exp(0.5 * jax.random.normal(keys[3], (e, n)))
+    b, c = (jax.random.normal(key, (1, t, n)) for key in keys[4:6])
+    d = jax.random.normal(keys[6], (e,))
+    eb = scan_takes(e, n)
+    y, bounds = _scan_forward(u, delta, a.T, b, c, d, eb=eb, interpret=interpret)
+    cut = (u[:, :held], delta[:, :held], a, b[:, :held], c[:, :held], d)
+    first = _scan_forward(cut[0], cut[1], a.T, cut[3], cut[4], d, eb=eb, interpret=interpret)
+    du, ddt, da, db, dc, dd = _scan_backward(
+        cut[0], cut[1], a.T, cut[3], cut[4], d, first[1], dy[:, :held], eb=eb, interpret=interpret
+    )
+    y_twin, pull = jax.vjp(lambda *xs: _scan_twin(*xs).astype(f32), *cut)
+    errors = []
+    for got, want in zip((y[:, :held], du, ddt, da.T, db, dc, dd), (y_twin, *pull(dy[:, :held].astype(f32))), strict=True):
+        got, want = (np.asarray(x.astype(f32)) for x in (got, want))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernels' results against the scan twin's
+        errors.append(float(np.linalg.norm(got - want) / np.linalg.norm(want)))
+    if not max(errors) < 1e-2:
+        raise AssertionError(f"selective scan at {(t, e, n)}: y, du, ddelta, dA, dB, dC, dD off by {errors}")
+    return {"tokens": t, "channels": e, "states": n, "rel_err": [round(x, 6) for x in errors]}
+
+
 def _run_row_copies(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax.numpy as jnp
 
@@ -679,6 +717,13 @@ def smoke_cases() -> list[SmokeCase]:
         SmokeCase(
             "models.loss_tile", "pallas", _run_loss_tile,
             kernels=("lakesoul_tpu/models/loss_tile.py::_loss_tile_kernel",),
+        ),
+        SmokeCase(
+            "models.selective_scan", "pallas", _run_selective_scan,
+            kernels=(
+                "lakesoul_tpu/models/selective_scan.py::_scan_fwd_kernel",
+                "lakesoul_tpu/models/selective_scan.py::_scan_bwd_kernel",
+            ),
         ),
         SmokeCase(
             "parallel.moe_row_copies", "pallas", _run_row_copies,

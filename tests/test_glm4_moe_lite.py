@@ -560,7 +560,8 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
                              "attn_operands_kernel": 0, "attn_operands_xla": 0,
                              "attn_out_tokens": 0, "attn_out_heads": 4 * B,  # three layers and the module's, in the twin: heads first
                              "loss_rows_fused": 0, "loss_rows_compiler": 2 * B * T,  # both losses' rows to the tile loop; tiles this small stay the compiler's
-                             "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0}  # latent attention makes its own operands; no pass loop
+                             "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0,  # latent attention makes its own operands; no pass loop
+                             "ssm_rows_kernel": 0, "ssm_rows_twin": 0, "shared_reads": 0}  # no selective scan, no state one layer reads of another
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]
     steps = 2 * STEPS + 1

@@ -453,7 +453,8 @@ def test_counters_for_a_known_routing(stepped):
                              "attn_operands_kernel": 0, "attn_operands_xla": B,
                              "attn_out_tokens": 0, "attn_out_heads": B,  # the twin writes heads first
                              "loss_rows_fused": 0, "loss_rows_compiler": B * T,  # every row to the tile loop; tiles this small stay the compiler's
-                             "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0}  # one attention layer, its operands the jnp lines'; no pass loop
+                             "head_loop": 0, "loop_layers_run": 0, "loop_layers": 0,  # one attention layer, its operands the jnp lines'; no pass loop
+                             "ssm_rows_kernel": 0, "ssm_rows_twin": 0, "shared_reads": 0}  # no selective scan, no state one layer reads of another
     # the registry's series: three steps on one device, three on the mesh, and the one above
     counted = run["counted"]
     steps = 2 * STEPS + 1

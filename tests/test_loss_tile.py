@@ -302,17 +302,20 @@ step.lower(params, opt_state, ids, labels, ids)        # traced and lowered
 params, opt_state, loss = step(params, opt_state, ids, labels, ids)  # and run
 assert bool(jnp.isfinite(loss))
 print("PALLAS", sorted(m for m in sys.modules if "pallas" in m))
+print("FAMILIES", sorted(m for m in sys.modules if m.endswith((".phi4flash", ".selective_scan", ".causal_lm", ".attention"))))
 """
 
 
 def test_a_bert_steps_process_imports_no_pallas():
     """A fresh process that builds, traces, lowers and runs the BERT train
     step has no module with ``pallas`` in its name: its set-up pays no Pallas
-    import and no kernel's lowering."""
+    import and no kernel's lowering.  Nor has it the causal LMs' stack, their
+    attention, the selective scan or the family that brought it."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
     done = subprocess.run([sys.executable, "-c", _BERT_STEP], env=env, capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
     assert "PALLAS []" in done.stdout, done.stdout[-500:]
+    assert "FAMILIES []" in done.stdout, done.stdout[-500:]
 
 
 def test_the_masked_lm_loss_lowers_no_kernel_and_an_lm_loss_does(every_tile_fused):

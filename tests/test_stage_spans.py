@@ -1257,6 +1257,37 @@ def test_ouro_readers(check):
     assert (selftest.PASSES, selftest.HEAD) == (LOOP_LAYER_PASSES_FAMILY, HEAD_POSITIONS_FAMILY)
 
 
+PHI4FLASH_READER_CHECKS = [
+    "the_hybrid_steps_shares_are_the_step_whole", "the_new_shares_give_nothing_without_their_scopes",
+    "the_scans_required_work", "the_scan_rooflines_of_hand_events", "scan_kernel_share_of_hand_counts",
+    "shared_reads_of_hand_counts", "the_adaptors_operations_a_row", "the_scan_kernels_are_charged_to_the_mamba_scope",
+]
+
+
+@pytest.mark.parametrize("check", PHI4FLASH_READER_CHECKS)
+def test_phi4flash_readers(check):
+    """The six readers the decoder-hybrid-decoder cell added, the scan's
+    required work, the adaptor's operation count and the scan kernels' scope,
+    through their own self-test, and the series two of them read under the
+    names the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import SHARED_READS_FAMILY, SSM_SCAN_ROWS_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_phi4flash_readers",
+        os.path.join(REPO, "benchmarks", "chip", "selftest", "phi4flash_readers.py"),
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == ["test_" + name for name in PHI4FLASH_READER_CHECKS]
+    getattr(selftest, "test_" + check)()
+    assert (selftest.SCAN_ROWS, selftest.SHARED_READS) == (SSM_SCAN_ROWS_FAMILY, SHARED_READS_FAMILY)
+    for reader, family in (("ssm_scan_kernel_pct", SSM_SCAN_ROWS_FAMILY), ("shared_reads_step", SHARED_READS_FAMILY)):
+        with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", reader + ".py")) as f:
+            assert f'COUNTER = "{family}"' in f.read()
+
+
 # ---------------------------------------- the step named whole (PR 38)
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")

@@ -32,9 +32,9 @@ def _kernel_bodies(text: str) -> list[bytes]:
     return [base64.b64decode(body) for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)]
 
 
-def test_seven_steps_stand_behind_the_eight_trainer_cells():
+def test_eight_steps_stand_behind_the_nine_trainer_cells():
     steps = tool.steps_of([])
-    assert len(steps) == 7 and len({config for config, _, _ in steps.values()}) == 6
+    assert len(steps) == 8 and len({config for config, _, _ in steps.values()}) == 7
     assert tool.steps_of(["bert_base_mlm_pk.dp4_mor_stream"]) == {"bert_base_mlm_pk.dp4.rows64": ("bert_base_mlm_pk", 4, 64)}
 
 

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from lakesoul_tpu.annplane import ragged
-from lakesoul_tpu.models import attention, loss_tile, qwen3_next
+from lakesoul_tpu.models import attention, loss_tile, qwen3_next, selective_scan
 from lakesoul_tpu.parallel import moe
 from lakesoul_tpu.tensorplane.smoke import enumerate_pallas_kernels
 from lakesoul_tpu.vector import kernels
@@ -141,9 +141,30 @@ def _loss_tile(d):
     )
 
 
+def _scan_row(d):
+    # one Mamba-1 layer's row at the published shape: 5,120 channels of 16 states over 8,192 tokens, blocks of 512
+    # channels; u bfloat16, Delta and the rest float32 (d is a vector width, not a shape of these kernels)
+    del d
+    t, e, n = 8192, 5120, 16
+    return (_sds((1, t, e), jnp.bfloat16), _sds((1, t, e)), _sds((n, e)), _sds((1, t, n)), _sds((1, t, n)), _sds((e,))), \
+        selective_scan.scan_takes(e, n)
+
+
+def _scan_forward(d):
+    row, eb = _scan_row(d)
+    return jax.jit(lambda *operands: selective_scan._scan_forward(*operands, eb=eb, interpret=False)).trace(*row)
+
+
+def _scan_backward(d):
+    row, eb = _scan_row(d)
+    u, _, at = row[:3]
+    bounds = _sds((1, u.shape[1] // selective_scan.SCAN_TOKENS, *at.shape))
+    return jax.jit(lambda *operands: selective_scan._scan_backward(*operands, eb=eb, interpret=False)).trace(*row, bounds, u)
+
+
 ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads, query heads each serves, head size
     "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256), "trinity-mini": (4, 8, 128),
-    "ouro": (16, 1, 128),
+    "ouro": (16, 1, 128), "phi-4-mini-flash": (40, 2, 64),  # the paired maps: four key-value heads a key-value pair
 }
 
 
@@ -217,6 +238,8 @@ TRACERS = {
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_fwd_kernel": _gated_delta_forward,
     "lakesoul_tpu/models/qwen3_next.py::_gated_delta_bwd_kernel": _gated_delta_backward,
     "lakesoul_tpu/models/loss_tile.py::_loss_tile_kernel": _loss_tile,
+    "lakesoul_tpu/models/selective_scan.py::_scan_fwd_kernel": _scan_forward,
+    "lakesoul_tpu/models/selective_scan.py::_scan_bwd_kernel": _scan_backward,
     "lakesoul_tpu/parallel/moe.py::_take_rows_kernel": _take_rows,
     "lakesoul_tpu/parallel/moe.py::_put_rows_kernel": _put_rows,
     "lakesoul_tpu/parallel/moe.py::_expert_dw_kernel": _expert_dw,
@@ -244,7 +267,8 @@ def test_attention_kernels_lower_at_every_published_shape(kernel, family):
     key-value heads of one query head each at head 128 (512 x 512 again:
     ``FLASH_ROWS`` is then queries alone); with the token-major output's
     block spec at the four heads of whole lane tiles, the heads-first one at
-    head 64."""
+    head 64; and the sixth's paired maps, 40 key-value heads of two query
+    heads each at head 64 (512 x 512)."""
     if family in ("glm-4.7-flash", "ouro"):
         assert attention._flash_tiles(8192, 1, ATTENTION_ROWS[family][2]) == (512, 512)
     lowered = TRACERS["lakesoul_tpu/models/attention.py::" + kernel](family).lower(lowering_platforms=("tpu",))
@@ -252,7 +276,7 @@ def test_attention_kernels_lower_at_every_published_shape(kernel, family):
     # where a head is whole lane tiles the call writes the output (reads its cotangent) token-major, a block of
     # ``bq`` tokens by a group's lanes; at LFM2's head of 64 heads first, as before
     hkv, groups, d = ATTENTION_ROWS[family]
-    assert (f"tensor<1x8192x{hkv * groups * d}xbf16>" in call) == (family != "lfm2")
+    assert (f"tensor<1x8192x{hkv * groups * d}xbf16>" in call) == (d != 64)
 
 
 @pytest.mark.parametrize("window", [None, 2048], ids=["full", "window-2048"])

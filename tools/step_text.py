@@ -11,8 +11,8 @@ What a refactor of the step is held to: the text at the parent commit and at
 the change, byte for byte (run this file from a ``git archive`` of each).  A
 step is a configuration at its published widths (``benchmarks/chip/configs``)
 on a cell's mesh and batch (``benchmarks/chip/workloads``; both only read);
-cells that share all three share a step, so ``--all`` writes seven texts for
-the eight trainer cells.  State and step are the ones the configuration's
+cells that share all three share a step, so ``--all`` writes eight texts for
+the nine trainer cells.  State and step are the ones the configuration's
 adaptor builds for a run (``benchmarks/chip/consumers/<consumer>.py: build``,
 on this host's CPU devices: gigabytes for a causal LM), the kernels take the
 branch the chip takes (``utils/platform.py: on_tpu`` true), and ONE thing is
