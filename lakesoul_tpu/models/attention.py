@@ -12,9 +12,11 @@ is also what the kernels are held to):
 - the flash pair (``flash_attention_fwd``, ``flash_attention_bwd``; the comment
   above :func:`_flash_tiles` and :func:`causal_attention` say what they do):
   :func:`_flash_tiles` takes a head of 64, 128 or 256 channels and a row of
-  whole 128-key tiles (the five published models at 8,192 tokens: groups of 4
+  whole 128-key tiles (the six published models at 8,192 tokens: groups of 4
   at head 64, of 8 at 256, of 1 at 256 on 20 key-value heads, of 8 at 128, of
-  1 at 128 on 16 key-value heads); twin :func:`_blockwise_attention`;
+  1 at 128 on 16 key-value heads, and differential attention's pairs, groups
+  of 2 at head 64 beside a value of 128: the value's width is read off ``v``);
+  twin :func:`_blockwise_attention`;
 - where the output lies: :func:`_token_major` takes, of those, a head of whole
   128-lane tiles (every published head but 64), and the kernels then write
   ``o`` and read ``do`` token-major through their own block specs; elsewhere
@@ -78,8 +80,9 @@ def _blockwise_attention(q, k, v, band: int, rows: int, window: int | None = Non
     ``rows`` queries at a time, each block rematerialised: no more than
     ``rows`` rows of scores live at once, in either pass, and every one of
     them in HBM.  What a shape the kernel does not take runs, and the kernel's
-    twin and reference."""
+    twin and reference; the value may be wider than the key, as the kernels'."""
     b, hkv, groups, t, d = q.shape
+    dv = v.shape[-1]
 
     def block(q_blk, k_seen, v_seen, first, key0=0):
         """q_blk [B, Hkv, G, n, D] at positions first.. against the keys seen,
@@ -92,7 +95,7 @@ def _blockwise_attention(q, k, v, band: int, rows: int, window: int | None = Non
             back = pos[:, None] - (key0 + jnp.arange(k_seen.shape[2]))[None, :]
             mask = (back >= 0) & (back < window)
         _, l, o = block_attn(q_blk.reshape(b, hkv, groups * n, d), k_seen, v_seen, 1.0, mask)
-        return (o / l[..., None]).astype(v.dtype).reshape(b, hkv, groups, n, d)
+        return (o / l[..., None]).astype(v.dtype).reshape(b, hkv, groups, n, dv)
 
     out = []
     for start in range(0, t, band):
@@ -107,7 +110,7 @@ def _blockwise_attention(q, k, v, band: int, rows: int, window: int | None = Non
         q_rows = jnp.moveaxis(q_band.reshape(b, hkv, groups, blocks, rows, d), 3, 0)
         firsts = start + rows * jnp.arange(blocks)
         o = jax.lax.map(lambda xs: band_block(xs[0], k_seen, v_seen, xs[1]), (q_rows, firsts))
-        out.append(jnp.moveaxis(o, 0, 3).reshape(b, hkv, groups, end - start, d))
+        out.append(jnp.moveaxis(o, 0, 3).reshape(b, hkv, groups, end - start, dv))
     return jnp.concatenate(out, axis=3)
 
 
@@ -122,27 +125,35 @@ def _blockwise_attention(q, k, v, band: int, rows: int, window: int | None = Non
 # diagonal's) and, under a window, the key tiles that hold a key the tile's
 # last query no longer sees (the first of the list; the first two where the
 # window is no multiple of the query tile).  One tile may be both.
+# q and k are ``D`` wide and the value ``Dv``, read off ``v``'s last axis (the
+# head's size, but for differential attention's pairs: 2D,
+# :func:`paired_attention`): the output, its cotangent, the forward's
+# accumulator and dV are as wide as the value, the scores, the softmax, dQ and
+# dK know nothing of it, and the tiles are the head's.
 
 
-def _flash_tiles(t: int, groups: int, d: int):
+def _flash_tiles(t: int, groups: int, d: int, dv: int | None = None):
     """(queries, keys) a tile holds for rows of ``t`` tokens, or None where
-    the kernels do not take the shape: a head of :data:`FLASH_HEADS`, a row
-    that is whole tiles of 128 keys, and a row's float32 dK and dV held in VMEM
-    through the backward kernel (twice: the pipeline's two buffers)."""
-    if d not in FLASH_HEADS or t % 128 or t * d > FLASH_ROW_ELEMENTS:
+    the kernels do not take the shape: a head of :data:`FLASH_HEADS` (and a
+    value of ``dv`` channels, where it is not the head's size, of them too), a
+    row that is whole tiles of 128 keys, and a row's float32 dK and dV held in
+    VMEM through the backward kernel (twice: the pipeline's two buffers)."""
+    dv = d if dv is None else dv
+    if d not in FLASH_HEADS or dv not in FLASH_HEADS or t % 128 or t * max(d, dv) > FLASH_ROW_ELEMENTS:
         return None
     bk = next(n for n in (512, 256, 128) if n <= FLASH_KEYS and t % n == 0)
     return max(128, min(bk, FLASH_ROWS // groups)), bk
 
 
-def _token_major(t: int, groups: int, d: int) -> bool:
+def _token_major(t: int, groups: int, d: int, dv: int | None = None) -> bool:
     """Whether the flash kernels write the output, and read its cotangent,
     token-major, [B, T, heads x D] as the gate and ``w_o`` read it: a shape
-    they take (:func:`_flash_tiles`) whose head is whole 128-lane tiles.  A
-    block of that array, ``bq`` tokens by a key-value head's ``G x D`` lanes,
-    is then a query tile of the group, each head at a lane-aligned column
-    slice; two heads of 64 would share a lane tile."""
-    return _flash_tiles(t, groups, d) is not None and d % 128 == 0
+    they take (:func:`_flash_tiles`) whose head is whole 128-lane tiles and
+    whose value is as wide as the head.  A block of that array, ``bq`` tokens
+    by a key-value head's ``G x D`` lanes, is then a query tile of the group,
+    each head at a lane-aligned column slice; two heads of 64 would share a
+    lane tile."""
+    return _flash_tiles(t, groups, d, dv) is not None and d % 128 == 0 and dv in (None, d)
 
 
 def _first_key_tile(i, bq: int, bk: int, window: int | None):
@@ -168,11 +179,11 @@ def _flash_steps(t: int, bq: int, bk: int, window: int | None = None):
     return tuple(jnp.asarray(a, jnp.int32) for a in zip(*_flash_pairs(t, bq, bk, window), strict=True))
 
 
-def key_tile_steps(t: int, groups: int, d: int, window: int | None = None) -> tuple[int, int]:
+def key_tile_steps(t: int, groups: int, d: int, window: int | None = None, dv: int | None = None) -> tuple[int, int]:
     """(steps the kernels' list holds for one key-value head of one row, steps
     a causal list alone would hold): host integers off :func:`_flash_pairs`;
     (0, 0) for a shape the kernels do not take."""
-    tiles = _flash_tiles(t, groups, d)
+    tiles = _flash_tiles(t, groups, d, dv)
     if tiles is None:
         return 0, 0
     return len(_flash_pairs(t, *tiles, window)), len(_flash_pairs(t, *tiles))  # a window of t or more hides no tile
@@ -244,13 +255,15 @@ def _store_group_rows(ref, x, groups: int, d: int):
 
 def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *, bq, bk,
                       window=None):
-    """One step: a group's query tile [G, bq, D] against a key tile [bk, D].
-    Running maximum and sum [G*bq, 128] (every lane the same) and the weighted
-    values [G*bq, D] stay in VMEM over a query tile's steps; the last of them
-    divides and writes the output (heads first, or token-major where its block
-    is: :func:`_store_group_rows`) and the log-sum-exp [G, 1, bq]."""
+    """One step: a group's query tile [G, bq, D] against a key tile [bk, D]
+    and its values [bk, Dv].  Running maximum and sum [G*bq, 128] (every lane
+    the same) and the weighted values [G*bq, Dv] stay in VMEM over a query
+    tile's steps; the last of them divides and writes the output (heads first
+    [G, bq, Dv], or token-major where its block is:
+    :func:`_store_group_rows`) and the log-sum-exp [G, 1, bq]."""
     i, j, first, last = _flash_step(qi_ref, kj_ref, bq, bk, window)
     groups, _, d = q_ref.shape
+    dv = v_ref.shape[-1]
     rows = groups * bq
     f32 = jnp.float32
 
@@ -271,11 +284,11 @@ def _flash_fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref
         alpha = jnp.exp(m_prev - m_next)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_next
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jnp.dot(p.astype(v.dtype), v, preferred_element_type=f32)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, dv) + jnp.dot(p.astype(v.dtype), v, preferred_element_type=f32)
 
     def finish():
         l = l_ref[...]
-        _store_group_rows(o_ref, acc_ref[...] / _lanes(l, d), groups, d)
+        _store_group_rows(o_ref, acc_ref[...] / _lanes(l, dv), groups, dv)
         lse = (m_ref[...] + jnp.log(l)).T[:1]  # [1, G*bq]: a row's queries along the lanes
         for g in range(groups):
             lse_ref[g] = lse[:, g * bq:(g + 1) * bq]
@@ -289,10 +302,12 @@ def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, with
     of the tile again from q, k and the log-sum-exp, keys down the sublanes
     ([bk, G*bq]: the log-sum-exp and ``delta = sum(o * do)`` are rows, and dV
     and dK plain products), their share of dQ into VMEM until the query tile's
-    last step, of dK and dV into the row's whole float32 dK, dV [T, D], which
-    stay in VMEM over all of a key-value head's steps.
+    last step, of dK and dV into the row's whole float32 dK [T, D] and dV
+    [T, Dv], which stay in VMEM over all of a key-value head's steps.  One
+    ``ds = p (dp - delta)``, ``dp`` and ``delta`` over the whole of the
+    value's width, makes dK and dQ.
 
-    Heads first, ``do_ref`` [G, bq, D] is the operand as it lies and
+    Heads first, ``do_ref`` [G, bq, Dv] is the operand as it lies and
     ``with_ref`` holds ``delta`` [G, 1, bq], an XLA reduction.  Token-major
     (``assembled``: two more buffers in VMEM), ``do_ref`` and ``with_ref`` are
     the cotangent's and the kept output's blocks [bq, G*D], and a query tile's
@@ -321,7 +336,7 @@ def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, with
 
     def tile(mask):
         q = q_ref[...].reshape(rows, d)
-        do = assembled[0][...] if assembled else do_ref[...].reshape(rows, d)
+        do = assembled[0][...] if assembled else do_ref[...].reshape(rows, v_ref.shape[-1])
         k, v = k_ref[...], v_ref[...]
         lse = jnp.concatenate([lse_ref[g] for g in range(groups)], axis=1)
         delta = assembled[1][...] if assembled else jnp.concatenate([with_ref[g] for g in range(groups)], axis=1)
@@ -342,24 +357,25 @@ def _flash_bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, with
     _flash_tile_kinds(tile, i, j, last, bq, bk, window, finish)
 
 
-def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_shapes, batch: int | None = None):
-    """What the two kernels' ``pallas_call``s share, over q's [N, G, T, D]:
-    the grid (key-value heads, steps of :func:`_flash_steps`) with the two
-    tables in SMEM, and block specs by what a block follows: a query tile's
-    [G, bq, D], its per-query floats [G, 1, bq], a key tile's [bk, D], a
-    key-value head's whole [T, D]; ``in_specs`` and ``out_specs`` name those.
-    With ``batch`` (the ``N`` key-value heads are those of ``batch`` rows)
+def _flash_grid(q, dv: int, bq: int, bk: int, window, *, in_specs, out_specs, scratch_shapes, batch: int | None = None):
+    """What the two kernels' ``pallas_call``s share, over q's [N, G, T, D]
+    and a value ``dv`` wide: the grid (key-value heads, steps of
+    :func:`_flash_steps`) with the two tables in SMEM, and block specs by
+    what a block follows: a query tile's [G, bq, D], its per-query floats
+    [G, 1, bq], a key tile's [bk, D], a key-value head's whole [T, D], and
+    those three as wide as the value (``query_out`` [G, bq, Dv], ``values``
+    [bk, Dv], ``whole_values`` [T, Dv]: the output and its cotangent, v, dV);
+    ``in_specs`` and ``out_specs`` name those.  With ``batch`` (the ``N`` key-value heads are those of ``batch`` rows)
     also ``tokens``, the query tile in a token-major array [batch, T, heads x
     D]: [bq, G*D] at the row's tokens and the key-value head's columns.
     Returns (the tables, the call's keyword arguments)."""
     n, groups, t, d = q.shape
     tables = _flash_steps(t, bq, bk, window)
-    specs = {
-        "query": pl.BlockSpec((None, groups, bq, d), lambda h, s, qi, kj: (h, 0, qi[s], 0)),
-        "per_query": pl.BlockSpec((None, groups, 1, bq), lambda h, s, qi, kj: (h, 0, 0, qi[s])),
-        "keys": pl.BlockSpec((None, bk, d), lambda h, s, qi, kj: (h, kj[s], 0)),
-        "whole_row": pl.BlockSpec((None, t, d), lambda h, s, qi, kj: (h, 0, 0)),
-    }
+    specs = {"per_query": pl.BlockSpec((None, groups, 1, bq), lambda h, s, qi, kj: (h, 0, 0, qi[s]))}
+    for wide, query, keys, whole_row in ((d, "query", "keys", "whole_row"), (dv, "query_out", "values", "whole_values")):
+        specs[query] = pl.BlockSpec((None, groups, bq, wide), lambda h, s, qi, kj: (h, 0, qi[s], 0))
+        specs[keys] = pl.BlockSpec((None, bk, wide), lambda h, s, qi, kj: (h, kj[s], 0))
+        specs[whole_row] = pl.BlockSpec((None, t, wide), lambda h, s, qi, kj: (h, 0, 0))
     if batch is not None:
         kv = n // batch
         specs["tokens"] = pl.BlockSpec((None, bq, groups * d), lambda h, s, qi, kj: (h // kv, qi[s], h % kv))
@@ -376,19 +392,21 @@ def _flash_grid(q, bq: int, bk: int, window, *, in_specs, out_specs, scratch_sha
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "batch", "interpret"))
 def _flash_forward(q, k, v, *, bq: int, bk: int, window: int | None = None, batch: int | None = None, interpret: bool):
-    """q [N, G, T, D], k, v [N, T, D] → (o [N, G, T, D], log-sum-exp
-    [N, G, 1, T] float32).  With ``batch`` (:func:`_token_major` shapes: the
-    ``N`` key-value heads are ``batch`` rows') o is written token-major,
-    [batch, T, heads x D] with a key-value head's group side by side: the
-    same values at the addresses the gate and ``w_o`` read."""
+    """q [N, G, T, D], k [N, T, D], v [N, T, Dv] → (o [N, G, T, Dv],
+    log-sum-exp [N, G, 1, T] float32).  With ``batch`` (:func:`_token_major`
+    shapes, ``Dv = D``: the ``N`` key-value heads are ``batch`` rows') o is
+    written token-major, [batch, T, heads x D] with a key-value head's group
+    side by side: the same values at the addresses the gate and ``w_o``
+    read."""
     n, groups, t, d = q.shape
+    dv = v.shape[-1]
     rows = groups * bq
     tables, grid = _flash_grid(
-        q, bq, bk, window, in_specs=("query", "keys", "keys"), batch=batch,
-        out_specs=("query" if batch is None else "tokens", "per_query"),
-        scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32)] * 2 + [pltpu.VMEM((rows, d), jnp.float32)],
+        q, dv, bq, bk, window, in_specs=("query", "keys", "values"), batch=batch,
+        out_specs=("query_out" if batch is None else "tokens", "per_query"),
+        scratch_shapes=[pltpu.VMEM((rows, 128), jnp.float32)] * 2 + [pltpu.VMEM((rows, dv), jnp.float32)],
     )
-    o_shape = q.shape if batch is None else (batch, t, n // batch * groups * d)
+    o_shape = (n, groups, t, dv) if batch is None else (batch, t, n // batch * groups * d)
     return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, bq=bq, bk=bk, window=window),
         out_shape=(jax.ShapeDtypeStruct(o_shape, v.dtype), jax.ShapeDtypeStruct((n, groups, 1, t), jnp.float32)),
@@ -398,8 +416,9 @@ def _flash_forward(q, k, v, *, bq: int, bk: int, window: int | None = None, batc
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk", "window", "interpret"))
 def _flash_backward(q, k, v, o, lse, do, *, bq: int, bk: int, window: int | None = None, interpret: bool):
-    """The three gradients, dK and dV summed over the group; ``o`` and ``do``
-    as :func:`_flash_forward` wrote ``o``.  Heads first, [N, G, T, D]:
+    """The three gradients, dK and dV summed over the group (dV as wide as
+    the value); ``o`` and ``do`` as :func:`_flash_forward` wrote ``o``.
+    Heads first, [N, G, T, Dv]:
     ``delta = sum(o * do)`` is an XLA reduction and an operand of the kernel.
     Token-major, [B, T, heads x D]: the kernel reads both through the output's
     block spec and sums ``delta`` itself (as an XLA reduction over token-major
@@ -412,15 +431,16 @@ def _flash_backward(q, k, v, o, lse, do, *, bq: int, bk: int, window: int | None
         batch, given, do_spec, given_spec = o.shape[0], o, "tokens", "tokens"
         scratch += [pltpu.VMEM((rows, d), do.dtype), pltpu.VMEM((1, rows), jnp.float32)]
     else:
-        batch, do_spec, given_spec = None, "query", "per_query"
+        batch, do_spec, given_spec = None, "query_out", "per_query"
         given = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)[:, :, None, :]
     tables, grid = _flash_grid(
-        q, bq, bk, window, batch=batch, in_specs=("query", "keys", "keys", do_spec, "per_query", given_spec),
-        out_specs=("query", "whole_row", "whole_row"), scratch_shapes=scratch,
+        q, v.shape[-1], bq, bk, window, batch=batch,
+        in_specs=("query", "keys", "values", do_spec, "per_query", given_spec),
+        out_specs=("query", "whole_row", "whole_values"), scratch_shapes=scratch,
     )
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, bq=bq, bk=bk, window=window),
-        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), *[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), *(jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in (k, v))),
         name="flash_attention_bwd", interpret=interpret, **grid,
     )(*tables, q, k, v, do, lse, given)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
@@ -690,7 +710,7 @@ def _kernel_operands(q, k, v, wq, wk, bt: int, *, eps: float, centred: bool, rot
     return _attention_operands(q, k, v, wq, wk, turn, d, eps, bt)
 
 
-_traced_tiles = threading.local()  # ``.steps``, ``.operands``: what :func:`mixer_counts` collects while it traces a mixer
+_traced_tiles = threading.local()  # ``.steps``, ``.pairs``, ``.operands``: what :func:`mixer_counts` collects while it traces a mixer
 
 
 def mixer_counts(mixer, x, p) -> dict:
@@ -705,15 +725,19 @@ def mixer_counts(mixer, x, p) -> dict:
     token-major through the kernels' block specs (:func:`_token_major`), or
     heads first and transposed after; ``attn_operands_kernel`` and
     ``attn_operands_xla``, its rows by what made the flash kernels' operands,
-    the operand kernels or the ``jnp`` lines (:func:`_operand_tiles`).  All 0
-    for a mixer without attention."""
-    _traced_tiles.steps, _traced_tiles.operands = steps, operands = [], []
+    the operand kernels or the ``jnp`` lines (:func:`_operand_tiles`);
+    ``attn_pair_tiles_run`` and ``attn_pair_tiles``, of ``attn_tiles_run`` the
+    steps of :func:`paired_attention`'s calls and the steps two score maps a
+    head pair require (equal where every map is computed once; 0 for a mixer
+    without pairs).  All 0 for a mixer without attention."""
+    _traced_tiles.steps, _traced_tiles.pairs, _traced_tiles.operands = steps, pairs, operands = [], [], []
     try:
         jax.eval_shape(lambda x, p: mixer(x, p), x, p)  # a function of its own: a trace cached for ``mixer`` collects nothing
     finally:
-        del _traced_tiles.steps, _traced_tiles.operands
+        del _traced_tiles.steps, _traced_tiles.pairs, _traced_tiles.operands
     return {
         "attn_tiles_run": sum(run for run, *_ in steps), "attn_tiles_causal": sum(causal for _, causal, *_ in steps),
+        "attn_pair_tiles_run": sum(run for run, _ in pairs), "attn_pair_tiles": sum(required for _, required in pairs),
         "attn_out_tokens": sum(rows for *_, rows, tokens in steps if tokens),
         "attn_out_heads": sum(rows for *_, rows, tokens in steps if not tokens),
         "attn_operands_kernel": sum(rows for rows, fused in operands if fused),
@@ -723,9 +747,11 @@ def mixer_counts(mixer, x, p) -> dict:
 
 def causal_attention(q, k, v, window: int | None = None):
     """Causal softmax attention with grouped-query heads: q [B, Hkv, G, T, D]
-    (scaled), k, v [B, Hkv, T, D] → [B, T, heads, D], tokens before heads as
-    the output projection reads it, a key-value head's group side by side
-    (head ``kv * G + g``).  Two masks: key ``j`` is
+    (scaled), k [B, Hkv, T, D], v [B, Hkv, T, Dv] → [B, T, heads, Dv], tokens
+    before heads as the output projection reads it, a key-value head's group
+    side by side (head ``kv * G + g``).  ``Dv`` is read off ``v``: the head's
+    size ``D`` in every mixer but differential attention's, whose value is a
+    pair's two (:func:`paired_attention`).  Two masks: key ``j`` is
     visible to query ``i`` iff ``j <= i`` and, under a ``window``,
     ``i - j < window`` (the query's own position and the ``window - 1`` before
     it); a window of the row's length or more is no window.
@@ -734,9 +760,9 @@ def causal_attention(q, k, v, window: int | None = None):
     accumulators in float32, the probabilities cast only as the second
     product's operand, the division by the sum after the accumulation.
 
-    Where :func:`_flash_tiles` takes the shape (a head of 64, 128 or 256
-    channels, a row of whole 128-key tiles) two Pallas kernels under one
-    ``custom_vjp`` do all of it, compiled on a TPU and in the Pallas
+    Where :func:`_flash_tiles` takes the shape (a head, and a value, of 64,
+    128 or 256 channels, a row of whole 128-key tiles) two Pallas kernels
+    under one ``custom_vjp`` do all of it, compiled on a TPU and in the Pallas
     interpreter elsewhere: no score leaves VMEM in either pass, and a key tile
     the mask hides whole is no step of the grid (:func:`_flash_pairs`).  The
     backward pass keeps the output and the log-sum-exp, named
@@ -746,28 +772,30 @@ def causal_attention(q, k, v, window: int | None = None):
 
     The operands come heads first and the three gradients go back so.  The
     output is the other way round: where :func:`_token_major` takes the shape
-    (a head of whole 128-lane tiles) the forward kernel's output block spec
-    writes it token-major and the backward kernel's reads its cotangent and
-    the kept output there (and sums ``delta`` from them), so no layout copy
-    stands between the kernels and ``w_o`` in either pass; a head of 64, and
-    the blockwise path, write heads first and the transpose here is a copy."""
+    (a head of whole 128-lane tiles and a value as wide) the forward kernel's
+    output block spec writes it token-major and the backward kernel's reads
+    its cotangent and the kept output there (and sums ``delta`` from them),
+    so no layout copy stands between the kernels and ``w_o`` in either pass;
+    a head of 64, a value wider than the head, and the blockwise path, write
+    heads first and the transpose here is a copy."""
     b, hkv, groups, t, d = q.shape
+    dv = v.shape[-1]
     if window is not None and window >= t:
         window = None
-    tokens = _token_major(t, groups, d)
+    tokens = _token_major(t, groups, d, dv)
     if hasattr(_traced_tiles, "steps"):  # :func:`mixer_counts` is tracing the caller
-        _traced_tiles.steps.append((*(b * hkv * n for n in key_tile_steps(t, groups, d, window)), b, tokens))
-    tiles = _flash_tiles(t, groups, d)
+        _traced_tiles.steps.append((*(b * hkv * n for n in key_tile_steps(t, groups, d, window, dv)), b, tokens))
+    tiles = _flash_tiles(t, groups, d, dv)
     if tiles is None:
         o = _blockwise_attention(q, k, v, ATTN_BAND, ATTN_ROWS, window)
     else:
         o = _flash_attention(
-            q.reshape(b * hkv, groups, t, d), *(a.reshape(b * hkv, t, d) for a in (k, v)), *tiles, window,
+            q.reshape(b * hkv, groups, t, d), k.reshape(b * hkv, t, d), v.reshape(b * hkv, t, dv), *tiles, window,
             b if tokens else None,
         )
     if not tokens:  # heads first: laid out for the projection here, by copies
-        o = o.reshape(q.shape).transpose(0, 3, 1, 2, 4)
-    return o.reshape(b, t, hkv * groups, d)
+        o = o.reshape(b, hkv, groups, t, dv).transpose(0, 3, 1, 2, 4)
+    return o.reshape(b, t, hkv * groups, dv)
 
 
 def paired_attention(q, k, v, window: int | None = None):
@@ -781,23 +809,27 @@ def paired_attention(q, k, v, window: int | None = None):
     serves.  ``o1 = softmax(q1 k1^T) V`` and ``o2 = softmax(q2 k2^T) V``
     under the causal mask and ``window``.
 
-    The kernels take one head size a call, so a map meets the two halves of
-    its value as two key-value heads with the same key: key-value head
-    ``(g, a, c)`` is key ``2g + a`` beside value ``2g + c`` and serves the
-    ``r`` queries ``(2(g r + i) + a)``; each score map is computed twice
-    (16 D operations a query pair and visible key where 12 D are required)."""
+    :func:`causal_attention` takes a value wider than its key, so each map is
+    one key-value head and computed once: key-value head ``(g, a)`` is key
+    ``2g + a`` beside the value ``V_g`` (``v``'s heads ``2g`` and ``2g + 1``
+    as they lie side by side, once for each ``a``) and serves the ``r``
+    queries ``2(g r + i) + a``: 12 D operations a query pair and visible key,
+    the required ones.  ``o1`` is the heads ``a = 0`` and ``o2`` the heads
+    ``a = 1``."""
     b, t, heads, d = q.shape
     pairs = k.shape[2] // 2
     r = heads // (2 * pairs)
-    q = jnp.broadcast_to(q.reshape(b, t, pairs, r, 2, 1, d).transpose(0, 2, 4, 5, 3, 1, 6), (b, pairs, 2, 2, r, t, d))
-    k = jnp.broadcast_to(k.reshape(b, t, pairs, 2, 1, d).transpose(0, 2, 3, 4, 1, 5), (b, pairs, 2, 2, t, d))
-    v = jnp.broadcast_to(v.reshape(b, t, pairs, 1, 2, d).transpose(0, 2, 3, 4, 1, 5), (b, pairs, 2, 2, t, d))
+    q = q.reshape(b, t, pairs, r, 2, d).transpose(0, 2, 4, 3, 1, 5)  # [B, g, a, i, T, D]
+    k = k.reshape(b, t, pairs, 2, d).transpose(0, 2, 3, 1, 4)        # [B, g, a, T, D]
+    v = jnp.broadcast_to(v.reshape(b, t, pairs, 1, 2 * d).transpose(0, 2, 3, 1, 4), (b, pairs, 2, t, 2 * d))
     o = causal_attention(
-        q.reshape(b, 4 * pairs, r, t, d), k.reshape(b, 4 * pairs, t, d), v.reshape(b, 4 * pairs, t, d), window
+        q.reshape(b, 2 * pairs, r, t, d), k.reshape(b, 2 * pairs, t, d), v.reshape(b, 2 * pairs, t, 2 * d), window
     )
-    # heads ((g, a, c), i) → [map a, pair (g, i), (value half c, D)]
-    o = o.reshape(b, t, pairs, 2, 2, r, d).transpose(0, 1, 3, 2, 5, 4, 6).reshape(b, t, 2, pairs * r, 2 * d)
-    return o[:, :, 0], o[:, :, 1]
+    if hasattr(_traced_tiles, "pairs"):  # :func:`mixer_counts` is tracing the caller: the call above is its last entry
+        required = b * 2 * pairs * key_tile_steps(t, r, d, window, 2 * d)[0]
+        _traced_tiles.pairs.append((_traced_tiles.steps[-1][0], required))
+    o = o.reshape(b, t, pairs, 2, r, 2 * d)  # heads ((g, a), i)
+    return o[:, :, :, 0].reshape(b, t, pairs * r, 2 * d), o[:, :, :, 1].reshape(b, t, pairs * r, 2 * d)
 
 
 def attention_operands(q, k, v, wq, wk, *, eps: float, centred: bool, rotary_dim: int | None, theta: float):

@@ -559,8 +559,8 @@ def lm_loss(params, ids, labels, *, cfg, batch_sharding=None):
     module's among them), ``tokens``, and the positions with a label,
     ``head_all`` over both losses and ``head_mtp`` the module's (0 without
     one), int32; ``loss_main`` and ``loss_mtp``, the two terms, float32; and
-    eight Python integers, known when the step is traced and no operation of
-    it: ``models/attention.py: mixer_counts``' six over rows and layers, and
+    ten Python integers, known when the step is traced and no operation of
+    it: ``models/attention.py: mixer_counts``' eight over rows and layers, and
     :func:`_head_nll`'s two over both losses."""
     x, counts = lm_hidden(params, ids, cfg=cfg, batch_sharding=batch_sharding)
     with jax.named_scope(HEAD_SCOPE):  # the loss's own loop over tiles of positions around the head

@@ -93,6 +93,10 @@ TOKENS_FAMILY = "lakesoul_train_tokens_total"
 MOE_ASSIGNMENTS_FAMILY = "lakesoul_train_moe_assignments_total"
 MOE_LOAD_FAMILY = "lakesoul_train_moe_expert_load"
 ATTN_KEY_TILES_FAMILY = "lakesoul_train_attn_key_tiles_total"
+# of ``{kind="run"}`` above, the steps of differential attention's calls (``models/attention.py: paired_attention``),
+# ``{kind="run"}``, over ``{kind="required"}``, the steps two score maps a head pair require: equal where every map is
+# computed once; host integers, 0 for a family without pairs
+ATTN_PAIR_TILES_FAMILY = "lakesoul_train_attn_pair_key_tiles_total"
 ATTN_OPERAND_ROWS_FAMILY = "lakesoul_train_attn_operand_rows_total"
 ATTN_OUTPUT_ROWS_FAMILY = "lakesoul_train_attn_output_rows_total"
 # the rows an LM step hands the head's tile loop for loss and gradients (``models/head_loss.py: labelled_nll``; every loss
@@ -386,6 +390,7 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
       and the family's ``route``, counted on the device; host zeros for a
       family without experts;
     - ``lakesoul_train_attn_key_tiles_total{kind="run"|"causal"}``,
+      ``lakesoul_train_attn_pair_key_tiles_total{kind="run"|"required"}``,
       ``lakesoul_train_attn_operand_rows_total{path="kernel"|"xla"}`` and
       ``lakesoul_train_attn_output_rows_total{layout="tokens"|"heads"}``:
       ``models/attention.py: mixer_counts``, Python integers known when the
@@ -404,7 +409,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
     _lm_plan(plan)
     batch_sharding = NamedSharding(plan.mesh, P("dp"))
     loss_fn = functools.partial(cfg.loss, batch_sharding=batch_sharding if plan.dp > 1 else None)
-    host_keys = ("attn_tiles_run", "attn_tiles_causal", "attn_out_tokens", "attn_out_heads",
+    host_keys = ("attn_tiles_run", "attn_tiles_causal", "attn_pair_tiles_run", "attn_pair_tiles",
+                 "attn_out_tokens", "attn_out_heads",
                  "attn_operands_kernel", "attn_operands_xla", "loop_layers_run", "loop_layers",
                  "loss_rows_fused", "loss_rows_compiler", "ssm_rows_kernel", "ssm_rows_twin", "shared_reads")
     held = getattr(cfg, "experts_held", None)
@@ -434,6 +440,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("moe_held", MOE_LOAD_FAMILY, {"stat": "mean"}, 1.0 / held[1] if held else 0),
         ("attn_tiles_run", ATTN_KEY_TILES_FAMILY, {"kind": "run"}, 1),
         ("attn_tiles_causal", ATTN_KEY_TILES_FAMILY, {"kind": "causal"}, 1),
+        ("attn_pair_tiles_run", ATTN_PAIR_TILES_FAMILY, {"kind": "run"}, 1),
+        ("attn_pair_tiles", ATTN_PAIR_TILES_FAMILY, {"kind": "required"}, 1),
         ("attn_out_tokens", ATTN_OUTPUT_ROWS_FAMILY, {"layout": "tokens"}, 1),
         ("attn_out_heads", ATTN_OUTPUT_ROWS_FAMILY, {"layout": "heads"}, 1),
         ("attn_operands_kernel", ATTN_OPERAND_ROWS_FAMILY, {"path": "kernel"}, 1),

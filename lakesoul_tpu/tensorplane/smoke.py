@@ -449,31 +449,33 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
     )
 
     # a row of each causal-LM cell's attention layer (key-value heads, query
-    # heads each serves, head size), 8,192 tokens at deployed sizes (one
+    # heads each serves, head size, value width), 8,192 tokens at deployed sizes (one
     # key-value head of 256 at tiny ones): output
     # and the three gradients against the blockwise twin, operands bfloat16
     # as the models pass them; the output and its cotangent token-major,
     # [1, T, heads x D] through the kernels' block specs, where the head is
-    # whole lane tiles (every shape here but the first), the twin's output
+    # whole lane tiles and the value as wide (every shape here but the first and the last two), the twin's output
     # transposed to that.  Both sides round the same float32 softmax to
     # bfloat16 (the probabilities, the output), each with its own maximum at
     # the time of rounding, so they agree to a few bfloat16 roundings by
-    # norm (measured on a v5e at 8,192 tokens: output 1.2e-3, gradients
-    # 4.0e-3 to 4.9e-3), not element by element
+    # norm (measured on a v5e at 8,192 tokens: output 1.2e-3 to 1.4e-3,
+    # gradients 4.0e-3 to 5.1e-3), not element by element
     t = min(8192, max(256, sizes.rows // 128))
     detail = {"tokens": t}
     # and a Trinity-Mini row's window layer: 4 key-value heads of 8 query heads at head 128 under a window of
-    # 2,048 (half the row at tiny sizes), the twin's bands cut the same way; and an Ouro row: 16 key-value heads
-    # of one query head at head 128
-    shapes = ((8, 4, 64, None), (2, 8, 256, None), (20, 1, 256, None), (4, 8, 128, 2048), (16, 1, 128, None))
-    for hkv, groups, d, window in shapes:
+    # 2,048 (half the row at tiny sizes), the twin's bands cut the same way; an Ouro row: 16 key-value heads
+    # of one query head at head 128; and a Phi-4-mini-flash row's paired maps: 20 key-value heads of two query heads
+    # at head 64 beside a value of 128, without a window and under the window layers' 512
+    shapes = ((8, 4, 64, 64, None), (2, 8, 256, 256, None), (20, 1, 256, 256, None), (4, 8, 128, 128, 2048),
+              (16, 1, 128, 128, None), (20, 2, 64, 128, None), (20, 2, 64, 128, 512))
+    for hkv, groups, d, dv, window in shapes:
         hkv = hkv if t == 8192 else 1
         window = window and min(window, t // 2)
         keys = jax.random.split(jax.random.key(9), 4)
         q = (jax.random.normal(keys[0], (hkv, groups, t, d)) * d**-0.5).astype(jnp.bfloat16)
-        k, v = (jax.random.normal(key, (hkv, t, d)).astype(jnp.bfloat16) for key in keys[1:3])
-        tiles = dict(zip(("bq", "bk"), _flash_tiles(t, groups, d), strict=True))
-        batch = 1 if _token_major(t, groups, d) else None
+        k, v = (jax.random.normal(key, (hkv, t, n)).astype(jnp.bfloat16) for key, n in zip(keys[1:3], (d, dv)))
+        tiles = dict(zip(("bq", "bk"), _flash_tiles(t, groups, d, dv), strict=True))
+        batch = 1 if _token_major(t, groups, d, dv) else None
         o, lse = _flash_forward(q, k, v, **tiles, window=window, batch=batch, interpret=interpret)
         do = jax.random.normal(keys[3], o.shape).astype(jnp.bfloat16)
         got = (o, *_flash_backward(q, k, v, o, lse, do, **tiles, window=window, interpret=interpret))
@@ -488,8 +490,8 @@ def _run_causal_attention(interpret: bool, sizes: SmokeSizes) -> dict:
             a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernels' results against the blockwise twin's
             errors.append(float(np.linalg.norm(a - b) / np.linalg.norm(b)))
         if not max(errors) < 1e-2:
-            raise AssertionError(f"flash attention at {(hkv, groups, d, window)}: o, dq, dk, dv off by {errors}")
-        detail[f"group{groups}.head{d}" + (f".window{window}" if window else "")] = {
+            raise AssertionError(f"flash attention at {(hkv, groups, d, dv, window)}: o, dq, dk, dv off by {errors}")
+        detail[f"group{groups}.head{d}" + (f".value{dv}" if dv != d else "") + (f".window{window}" if window else "")] = {
             "tiles": list(tiles.values()), "output": "heads" if batch is None else "tokens",
             "rel_err": [round(e, 5) for e in errors],
         }

@@ -385,7 +385,7 @@ def test_a_layer_norms_each_sublayers_output_before_the_residual_add(params, lay
     assert (counts is not None) == (ffn == "moe")
     assert causal_lm.layer_attention_counts(CFG, kind, x, lp[kind]) == {  # 150 tokens: neither kernel pair takes the row
         "attn_tiles_run": 0, "attn_tiles_causal": 0, "attn_out_tokens": 0, "attn_out_heads": x.shape[0],
-        "attn_operands_kernel": 0, "attn_operands_xla": x.shape[0]}
+        "attn_pair_tiles_run": 0, "attn_pair_tiles": 0, "attn_operands_kernel": 0, "attn_operands_xla": x.shape[0]}
     for name in ("norm1_out", "norm2_out"):
         scaled = lp | {name: 2 * lp[name]}
         doubled, _ = run(x, scaled, buffers)

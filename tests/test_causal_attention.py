@@ -2,9 +2,10 @@
 interpreter here) against the plain masked softmax and against their blockwise
 twin, forward and every gradient, at the five published group and head sizes,
 under the causal mask and under a window; the tile lists; which shapes take
-the kernels; where the output lives (token-major through the kernels' block
-specs where a head is whole lane tiles: the heads-first kernels' bits at other
-addresses); what a checkpoint around the caller keeps; and the mixer's recipe
+the kernels; a value wider than its key (differential attention's: a head of
+64 beside a value of 128); where the output lives (token-major through the
+kernels' block specs where a head is whole lane tiles: the heads-first kernels'
+bits at other addresses); what a checkpoint around the caller keeps; and the mixer's recipe
 WITHOUT head norms (the Ouro family's plain attention) on both operand paths.
 """
 
@@ -58,12 +59,13 @@ def blockwise(q, k, v, band=256, rows=64, window=None):
     return tokens_first(attention._blockwise_attention(q, k, v, band, rows, window))
 
 
-def operands(groups, d, t, dtype, *, kv_heads=1, rows=1):
-    """q, k, v heads first and a cotangent of the output, tokens first."""
+def operands(groups, d, t, dtype, *, kv_heads=1, rows=1, dv=None):
+    """q, k, v heads first (the value ``dv`` wide where given, else the
+    head's size) and a cotangent of the output, tokens first."""
     keys = jax.random.split(jax.random.key(t + d), 4)
     q = (jax.random.normal(keys[0], (rows, kv_heads, groups, t, d)) * d**-0.5).astype(dtype)
-    k, v = (jax.random.normal(key, (rows, kv_heads, t, d)).astype(dtype) for key in keys[1:3])
-    return q, k, v, jax.random.normal(keys[3], (rows, t, kv_heads * groups, d))
+    k, v = (jax.random.normal(key, (rows, kv_heads, t, width)).astype(dtype) for key, width in zip(keys[1:3], (d, dv or d)))
+    return q, k, v, jax.random.normal(keys[3], (rows, t, kv_heads * groups, dv or d))
 
 
 def out_and_grads(fn, q, k, v, weigh):
@@ -187,6 +189,79 @@ def test_a_windows_tile_list_is_the_band():
     assert list(zip(qi.tolist(), kj.tolist(), strict=True)) == [
         (0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2), (3, 1), (3, 2), (3, 3)
     ]
+
+
+WIDE = (2, 64, 128)  # query heads a key-value head, head size, value width: the Phi-4-mini-flash cell's pairs
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 1e-2)], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "window-no-multiple-of-a-tile"])
+def test_a_value_wider_than_its_key_equals_the_masked_softmax_and_its_twin(monkeypatch, window, dtype, tol):
+    """A head of 64 beside a value of 128, two query heads a key-value head
+    (two rows of two key-value heads, 4 x 4 tiles of 128): the output
+    [B, T, heads, 128] and dQ, dK [.., 64], dV [.., 128] against whole
+    softmaxes in float32 and their ``jax.grad``, and against the twin; the
+    kernels run, heads first."""
+    groups, d, dv = WIDE
+    t = 512
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 128 * groups)
+    assert attention._flash_tiles(t, groups, d, dv) == attention._flash_tiles(t, groups, d) == (128, 128)
+    q, k, v, weigh = operands(groups, d, t, dtype, kv_heads=2, rows=2, dv=dv)
+    calls = []
+    kernel = attention._flash_forward
+    monkeypatch.setattr(attention, "_flash_forward", lambda *a, **kw: calls.append((a[2].shape, kw["batch"])) or kernel(*a, **kw))
+    got = out_and_grads(lambda *qkv: attention.causal_attention(*qkv, window), q, k, v, weigh)
+    assert calls == [((4, t, dv), None)]
+    want = out_and_grads(lambda *qkv: plain_attention(*qkv, window), q, k, v, weigh)
+    twin = out_and_grads(lambda *qkv: blockwise(*qkv, window=window), q, k, v, weigh)
+    assert got[0].shape == (2, t, 2 * groups, dv) and [g.shape for g in got[1]] == [q.shape, k.shape, v.shape]
+    assert got[0].dtype == v.dtype and [g.dtype for g in got[1]] == [q.dtype, k.dtype, v.dtype]
+    assert_close(got, want, tol)
+    assert_close(got, twin, tol)
+
+
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "window-no-multiple-of-a-tile"])
+def test_a_wide_values_columns_are_the_narrow_kernels_bit_for_bit(monkeypatch, window):
+    """A column of ``P V`` does not know its neighbours, and maximum, sum and
+    rescaling are the score map's own: the output and dV over a value of 128
+    are, half by half, the kernels' over each half of 64 as a value of its
+    own, bit for bit (bfloat16, as the models pass them).  dQ and dK come
+    from one ``ds`` over the whole value where the halves' calls each round
+    their own and the caller adds two bfloat16 numbers: close, and no
+    further from the float32 softmax's than that sum is."""
+    groups, d, dv = WIDE
+    t = 384
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 128 * groups)
+    q, k, v, weigh = operands(groups, d, t, jnp.bfloat16, kv_heads=2, rows=2, dv=dv)
+    run = lambda *qkv: attention.causal_attention(*qkv, window)  # noqa: E731
+    o, (dq, dk, dvalue) = out_and_grads(run, q, k, v, weigh)
+    halves = [out_and_grads(run, q, k, v[..., half], weigh[..., half]) for half in (slice(0, d), slice(d, dv))]
+    _bits_equal(o, jnp.concatenate([o_half for o_half, _ in halves], axis=-1))
+    _bits_equal(dvalue, jnp.concatenate([grads[2] for _, grads in halves], axis=-1))
+    f32 = jnp.float32
+    summed = [sum(grads[n].astype(f32) for _, grads in halves) for n in (0, 1)]
+    assert_close((dq, dk), summed, 1e-2)
+    want = out_and_grads(lambda *qkv: plain_attention(*qkv, window), *(a.astype(f32) for a in (q, k, v)), weigh)[1][:2]
+    for got, halved, exact in zip((dq, dk), summed, want, strict=True):
+        off = lambda a: float(jnp.linalg.norm(a.astype(f32) - exact))  # noqa: E731
+        assert off(got) <= 1.05 * off(halved.astype(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("t, groups, d, dv, tiles", [
+    (8192, 2, 64, 128, (512, 512)),    # the Phi-4-mini-flash cell's pairs: the head's tiles
+    (8192, 2, 64, 64, (512, 512)),
+    (8192, 2, 64, None, (512, 512)),
+    (8192, 8, 128, 256, (128, 512)),
+    (256, 2, 64, 96, None),            # a value the kernels were not compiled for
+    (16384, 8, 128, 256, None),        # a row's dV outgrows VMEM where its dK does not
+    (16384, 8, 128, 128, (128, 512)),
+])
+def test_which_value_widths_take_the_kernels(t, groups, d, dv, tiles):
+    assert attention._flash_tiles(t, groups, d, dv) == tiles
+    assert attention.key_tile_steps(t, groups, d, None, dv)[0] == (0 if tiles is None else len(attention._flash_pairs(t, *tiles)))
+    assert attention._token_major(t, groups, d, dv) == (tiles is not None and d % 128 == 0 and dv in (None, d))
 
 
 def test_a_key_tile_after_the_query_tile_is_no_step():

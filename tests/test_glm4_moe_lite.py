@@ -557,6 +557,7 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
                              "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
                              "head_mtp": second, "head_all": main + second,
                              "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
+                             "attn_pair_tiles_run": 0, "attn_pair_tiles": 0,  # no head pairs
                              "attn_operands_kernel": 0, "attn_operands_xla": 0,
                              "attn_out_tokens": 0, "attn_out_heads": 4 * B,  # three layers and the module's, in the twin: heads first
                              "loss_rows_fused": 0, "loss_rows_compiler": 2 * B * T,  # both losses' rows to the tile loop; tiles this small stay the compiler's

@@ -290,6 +290,44 @@ def test_adjacent_heads_pair(window):
         assert np.abs(np.asarray(got) - wrong).max() > 1e-2
 
 
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "window-no-multiple-of-a-tile"])
+def test_adjacent_heads_pair_through_the_flash_kernels(monkeypatch, window):
+    """The same pairing at a shape the attention kernels take (256 tokens as
+    2 x 2 tiles of 128, heads of 64; the interpreter here): ONE call of two
+    key-value heads a key-value pair, two query heads each, beside a value of
+    128, held to the whole softmaxes by hand and not the pairing by halves;
+    and the gradients of a weighted sum of both outputs to the blockwise
+    path's, which the cases above hold to the same hand."""
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 256)
+    b, t, d = 2, 256, 64
+    keys = jax.random.split(jax.random.key(5), 5)
+    q = np.asarray(jax.random.normal(keys[0], (b, t, 8, d))) * d**-0.5
+    k, v = (np.asarray(jax.random.normal(key, (b, t, 4, d))) for key in keys[1:3])
+    calls = []
+    kernel = attention._flash_forward
+    monkeypatch.setattr(attention, "_flash_forward", lambda *a, **kw: calls.append([x.shape for x in a]) or kernel(*a, **kw))
+    o1, o2 = attention.paired_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), window)
+    assert calls == [[(b * 4, 2, t, d), (b * 4, t, d), (b * 4, t, 2 * d)]]  # two pairs x two maps a row
+    assert o1.shape == o2.shape == (b, t, 4, 2 * d)
+    for got, want, wrong in zip((o1, o2), _pair_by_hand(q, k, v, window, False), _pair_by_hand(q, k, v, window, True)):
+        assert np.abs(np.asarray(got) - want).max() < 1e-5
+        assert np.abs(np.asarray(got) - wrong).max() > 1e-2
+
+    weigh = [jax.random.normal(key, o1.shape) for key in keys[3:5]]
+
+    def weighted(q, k, v):
+        return sum(jnp.sum(w * o) for w, o in zip(weigh, attention.paired_attention(q, k, v, window)))
+
+    got = jax.grad(weighted, argnums=(0, 1, 2))(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
+    assert len(calls) == 2
+    monkeypatch.setattr(attention, "_flash_tiles", lambda *shape: None)  # the blockwise path
+    want = jax.grad(weighted, argnums=(0, 1, 2))(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
+    assert len(calls) == 2
+    for a, b_ in zip(got, want, strict=True):
+        assert float(jnp.linalg.norm(a - b_)) < 2e-4 * float(jnp.linalg.norm(b_))
+
+
 def test_the_window_counts_the_querys_own_position():
     """Under a window of 3 the query at position 7 sees keys 5, 6 and 7: with
     one-hot values the output is the weights, and three of them are not 0."""

@@ -584,6 +584,7 @@ def test_counters_for_a_known_routing(stepped):
                    "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": 0,
                    "head_all": B * (T - 1), "head_mtp": 0,  # one loss, no prediction module
                    "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
+                   "attn_pair_tiles_run": 0, "attn_pair_tiles": 0,  # no head pairs
                    "attn_operands_kernel": 0, "attn_operands_xla": B,
                    "attn_out_tokens": 0, "attn_out_heads": B,  # the twin writes heads first
                    "loss_rows_fused": 0, "loss_rows_compiler": B * T,  # every row to the tile loop; tiles this small stay the compiler's

@@ -949,9 +949,10 @@ def test_attn_key_tiles_counter_is_the_tile_tables(monkeypatch):
 
 
 @pytest.mark.parametrize("check", ["share_of_hand_counts", "nothing_without_the_series"])
-@pytest.mark.parametrize("reader", ["attn_operands_fused_pct", "attn_out_token_major_pct"])
+@pytest.mark.parametrize("reader", ["attn_operands_fused_pct", "attn_out_token_major_pct", "attn_pair_maps_run_pct"])
 def test_attn_row_counter_readers(reader, check):
-    """``attn_operands_fused_pct`` and ``attn_out_token_major_pct`` through
+    """``attn_operands_fused_pct``, ``attn_out_token_major_pct`` and
+    ``attn_pair_maps_run_pct`` through
     their own self-tests, and the series each reads under the name the LM step
     feeds."""
     import importlib.util
@@ -961,6 +962,7 @@ def test_attn_row_counter_readers(reader, check):
     selftest_file, family = {
         "attn_operands_fused_pct": ("operand_rows.py", train.ATTN_OPERAND_ROWS_FAMILY),
         "attn_out_token_major_pct": ("output_rows.py", train.ATTN_OUTPUT_ROWS_FAMILY),
+        "attn_pair_maps_run_pct": ("pair_maps.py", train.ATTN_PAIR_TILES_FAMILY),
     }[reader]
     spec = importlib.util.spec_from_file_location(
         "chipbench_selftest_" + selftest_file[:-3], os.path.join(REPO, "benchmarks", "chip", "selftest", selftest_file)
@@ -972,6 +974,67 @@ def test_attn_row_counter_readers(reader, check):
     assert selftest.FAMILY == family
     with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", reader + ".py")) as f:
         assert f'COUNTER = "{family}"' in f.read()
+
+
+def test_attn_pair_key_tiles_counter_is_two_maps_a_pair(monkeypatch):
+    """``lakesoul_train_attn_pair_key_tiles_total{kind="run"|"required"}``
+    after one Phi-4-mini-flash step at test widths: four heads of 64 on two
+    key-value heads (one key-value pair of two query pairs), 384 tokens as
+    3 x 3 tiles of 128 (the kernels in the interpreter), a window layer under
+    a window of 100 (5 of a causal list's 6 steps), a full and a cross layer,
+    2 rows: two key-value heads a pair run what two maps a pair require, and
+    every step of the attention kernels is a pair's.  And the host count at
+    the cell's shapes, from an abstract trace of the mixers: 20 key-value
+    heads a row, 31 steps each under the window of 512 and 136 without."""
+    from lakesoul_tpu.models import attention, causal_lm, phi4flash
+    from lakesoul_tpu.models.train import (
+        ATTN_KEY_TILES_FAMILY,
+        ATTN_PAIR_TILES_FAMILY,
+        make_lm_train_state,
+        make_lm_train_step,
+    )
+    from lakesoul_tpu.obs import registry
+    from lakesoul_tpu.parallel.mesh import make_mesh
+
+    def series():
+        found = registry().snapshot()
+        return {kind: found.get(f'{ATTN_PAIR_TILES_FAMILY}{{kind="{kind}"}}', 0) for kind in ("run", "required")} | {
+            "all": found.get(f'{ATTN_KEY_TILES_FAMILY}{{kind="run"}}', 0)}
+
+    whole = phi4flash.Phi4FlashConfig(layers_held=(15, 16, 17, 18, 19))
+    assert whole.layer_kinds() == ("swa", "ssm", "attn", "gmu", "xattn")
+    shapes = jax.eval_shape(whole.init, jax.random.key(0))
+    weights = {kind: causal_lm._mixer_weights(shapes["layers"][layer], kind, shapes["buffers"]["layers"][layer])
+               for kind, layer in (("swa", 0), ("attn", 2))}
+    row = jax.ShapeDtypeStruct((1, 8192, 2560), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 20 * 64), jnp.bfloat16)
+    swa = attention.mixer_counts(whole.mixer("swa")[0], row, weights["swa"])
+    full = attention.mixer_counts(lambda x, p: whole.mixer("attn")[0](x[0], p, x[1]), (row, (kv, kv)), weights["attn"])
+    assert (swa["attn_pair_tiles_run"], swa["attn_pair_tiles"], swa["attn_tiles_run"]) == (20 * 31,) * 3
+    assert (full["attn_pair_tiles_run"], full["attn_pair_tiles"], full["attn_tiles_run"]) == (20 * 136,) * 3
+
+    monkeypatch.setattr(attention, "FLASH_KEYS", 128)
+    monkeypatch.setattr(attention, "FLASH_ROWS", 256)
+    cfg = phi4flash.Phi4FlashConfig(
+        vocab_size=64, hidden_size=256, intermediate_size=48, num_attention_heads=4, num_key_value_heads=2,
+        mamba_dt_rank=2, mamba_d_state=4, num_hidden_layers=8, layers_held=(3, 4, 5, 6, 7), sliding_window=100,
+        dtype="float32",
+    )
+    assert cfg.layer_kinds() == ("swa", "ssm", "attn", "gmu", "xattn") and cfg.head_dim == 64
+    assert attention._flash_tiles(384, 2, 64, 128) == (128, 128)
+    plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
+    params, opt_state, tx, shardings = make_lm_train_state(cfg, plan)
+    step = make_lm_train_step(cfg, plan, tx, shardings)
+    before = series()
+    ids = jnp.zeros((2, 384), jnp.int32)
+    step(params, opt_state, ids, ids)
+    steps = 2 * 2 * (5 + 6 + 6)  # rows x a pair's two maps x the three layers' lists
+    counts = step.counts()
+    assert (counts["attn_pair_tiles_run"], counts["attn_pair_tiles"], counts["attn_tiles_run"]) == (steps,) * 3
+    assert {kind: n - before[kind] for kind, n in series().items()} == {"run": 68, "required": 68, "all": 68}
+    assert ATTN_PAIR_TILES_FAMILY == "lakesoul_train_attn_pair_key_tiles_total"
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "attn_pair_maps_run_pct.py")) as f:
+        assert f'COUNTER = "{ATTN_PAIR_TILES_FAMILY}"' in f.read()
 
 
 def test_attn_operand_rows_counter_is_the_rule(monkeypatch):

@@ -12,6 +12,7 @@ import json
 import os
 import re
 
+import pytest
 from jax._src import tpu_custom_call
 
 from lakesoul_tpu.utils import platform
@@ -54,3 +55,51 @@ def test_a_steps_text_holds_its_kernels_and_no_file_name(monkeypatch):
     monkeypatch.undo()  # the control: as JAX serializes them, the bodies name their source
     monkeypatch.setattr(platform, "on_tpu", lambda: True)
     assert [body for body in _kernel_bodies(tool.step_text(config, 1, 1)) if b"attention.py" in body]
+
+
+# one row of each head size the flash kernels take, a value as wide as the head: key-value heads, query heads each
+# serves, head size, window (LFM2's layer; Trinity-Mini's window layer, token-major; the GLM cell's latent heads)
+FLASH_SHAPES = {"head-64": (2, 4, 64, None), "head-128": (2, 8, 128, 200), "head-256": (2, 1, 256, None)}
+FLASH_TEXTS = os.path.join(REPO, "tests", "fixtures", "flash_kernels_pr50")
+
+
+def flash_kernel_texts(case: str) -> dict[str, str]:
+    """{kernel: the call lowered for the ``tpu`` platform} of the flash pair
+    at ``FLASH_SHAPES[case]`` over 512 tokens, Mosaic bodies without
+    locations (the caller patches the serializer).  What
+    ``tests/fixtures/flash_kernels_pr50/`` holds, written from a checkout of
+    PR 50's commit by this function."""
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.models import attention
+
+    hkv, groups, d, window = FLASH_SHAPES[case]
+    t, bf16 = 512, jnp.bfloat16
+    tiles = dict(zip(("bq", "bk"), attention._flash_tiles(t, groups, d), strict=True))
+    batch = 1 if attention._token_major(t, groups, d) else None
+    q, k = jax.ShapeDtypeStruct((hkv, groups, t, d), bf16), jax.ShapeDtypeStruct((hkv, t, d), bf16)
+    o = q if batch is None else jax.ShapeDtypeStruct((1, t, hkv * groups * d), bf16)
+    lse = jax.ShapeDtypeStruct((hkv, groups, 1, t), jnp.float32)
+    forward = jax.jit(lambda q, k, v: attention._flash_forward(q, k, v, **tiles, window=window, batch=batch, interpret=False))
+    backward = jax.jit(lambda *a: attention._flash_backward(*a, **tiles, window=window, interpret=False))
+    return {
+        "flash_attention_fwd": forward.trace(q, k, k).lower(lowering_platforms=("tpu",)).as_text(),
+        "flash_attention_bwd": backward.trace(q, k, k, o, lse, o).lower(lowering_platforms=("tpu",)).as_text(),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention_fwd", "flash_attention_bwd"])
+@pytest.mark.parametrize("case", sorted(FLASH_SHAPES))
+def test_a_value_as_wide_as_the_head_runs_the_kernels_pr50_emitted(monkeypatch, case, kernel):
+    """The flash kernels read the value's width off ``v`` (PR 51); where it
+    is the head's, each ``pallas_call`` (grid, block specs, scratch) and each
+    kernel body is byte for byte the one PR 50's commit emitted, at a head of
+    64, of 128 (under a window, token-major) and of 256: the five other LM
+    cells' steps are the parent's programs."""
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", tpu_custom_call._lower_mosaic_module_to_asm)
+    tool._kernel_bodies_without_locations()  # undone with the patch above
+    text = flash_kernel_texts(case)[kernel]
+    assert f'kernel_name = "{kernel}"' in text and len(_kernel_bodies(text)) == 1
+    with open(os.path.join(FLASH_TEXTS, f"{case}.{kernel}.txt")) as f:
+        assert text == f.read()

@@ -164,39 +164,43 @@ def _scan_backward(d):
 
 ATTENTION_ROWS = {  # a row's attention in each causal-LM cell: key-value heads, query heads each serves, head size
     "lfm2": (8, 4, 64), "qwen3-next": (2, 8, 256), "glm-4.7-flash": (20, 1, 256), "trinity-mini": (4, 8, 128),
-    "ouro": (16, 1, 128), "phi-4-mini-flash": (40, 2, 64),  # the paired maps: four key-value heads a key-value pair
+    "ouro": (16, 1, 128), "phi-4-mini-flash": (20, 2, 64),  # the paired maps: two key-value heads a key-value pair
 }
+VALUE_WIDTHS = {"phi-4-mini-flash": 128}  # where it is not the head's size: a pair's two values side by side
 
 
 def _attention_row(d):
     # a row's attention at a published group and head size: the LFM2 cell's layer at head 64 wherever d is not the
     # Qwen cell's (d is a vector width, not a shape of these kernels), or a family of ATTENTION_ROWS by name; the
     # output (and its cotangent) token-major, [1, T, heads x D], where ``_token_major`` takes the shape: every
-    # published head but LFM2's 64
-    hkv, groups, d = ATTENTION_ROWS.get(d) or ATTENTION_ROWS["qwen3-next" if d == 768 else "lfm2"]
+    # published head but LFM2's 64; the value as wide as the head but for Phi-4-mini-flash's pairs
+    family = d if d in ATTENTION_ROWS else "qwen3-next" if d == 768 else "lfm2"
+    hkv, groups, d = ATTENTION_ROWS[family]
+    dv = VALUE_WIDTHS.get(family, d)
     t = 8192
     bf16 = jnp.bfloat16
-    q = _sds((hkv, groups, t, d), bf16)
-    o = _sds((1, t, hkv * groups * d), bf16) if attention._token_major(t, groups, d) else q
-    return q, _sds((hkv, t, d), bf16), o, dict(zip(("bq", "bk"), attention._flash_tiles(t, groups, d)))
+    token_major = attention._token_major(t, groups, d, dv)
+    o = _sds((1, t, hkv * groups * d), bf16) if token_major else _sds((hkv, groups, t, dv), bf16)
+    tiles = dict(zip(("bq", "bk"), attention._flash_tiles(t, groups, d, dv)))
+    return _sds((hkv, groups, t, d), bf16), _sds((hkv, t, d), bf16), _sds((hkv, t, dv), bf16), o, tiles
 
 
 def _flash_forward(d, window=None):
-    q, k, o, tiles = _attention_row(d)
-    batch = None if o is q else 1
+    q, k, v, o, tiles = _attention_row(d)
+    batch = None if o.ndim == 4 else 1
     return jax.jit(
         lambda q, k, v: attention._flash_forward(q, k, v, **tiles, window=window, batch=batch, interpret=False)
-    ).trace(q, k, k)
+    ).trace(q, k, v)
 
 
 def _flash_backward(d, window=None):
-    q, k, o, tiles = _attention_row(d)
+    q, k, v, o, tiles = _attention_row(d)
     lse = _sds((*q.shape[:2], 1, q.shape[2]))
     return jax.jit(
         lambda q, k, v, o, lse, do: attention._flash_backward(
             q, k, v, o, lse, do, **tiles, window=window, interpret=False
         )
-    ).trace(q, k, k, o, lse, o)
+    ).trace(q, k, v, o, lse, o)
 
 
 def _operand_row(turned=True, family="trinity-mini", normed=True):
@@ -267,8 +271,8 @@ def test_attention_kernels_lower_at_every_published_shape(kernel, family):
     key-value heads of one query head each at head 128 (512 x 512 again:
     ``FLASH_ROWS`` is then queries alone); with the token-major output's
     block spec at the four heads of whole lane tiles, the heads-first one at
-    head 64; and the sixth's paired maps, 40 key-value heads of two query
-    heads each at head 64 (512 x 512)."""
+    head 64; and the sixth's paired maps, 20 key-value heads of two query
+    heads each at head 64 beside a value of 128 (512 x 512)."""
     if family in ("glm-4.7-flash", "ouro"):
         assert attention._flash_tiles(8192, 1, ATTENTION_ROWS[family][2]) == (512, 512)
     lowered = TRACERS["lakesoul_tpu/models/attention.py::" + kernel](family).lower(lowering_platforms=("tpu",))
@@ -277,6 +281,32 @@ def test_attention_kernels_lower_at_every_published_shape(kernel, family):
     # ``bq`` tokens by a group's lanes; at LFM2's head of 64 heads first, as before
     hkv, groups, d = ATTENTION_ROWS[family]
     assert (f"tensor<1x8192x{hkv * groups * d}xbf16>" in call) == (d != 64)
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window-512"])
+@pytest.mark.parametrize("kernel", ["_flash_fwd_kernel", "_flash_bwd_kernel"])
+def test_attention_kernels_lower_at_a_value_wider_than_its_key(kernel, window):
+    """A Phi-4-mini-flash row's paired maps, 20 key-value heads of two query
+    heads at head 64 beside a value of 128 (``paired_attention``), 8,192
+    tokens in the head's tiles of 512 x 512, under the causal mask (136 steps
+    a head) and under the window layers' 512 (31): the call takes q and k 64
+    wide and the value 128, and gives the output (the forward call) or dV
+    (the backward) 128 wide and dQ, dK 64."""
+    assert attention._flash_tiles(8192, 2, 64, 128) == attention._flash_tiles(8192, 2, 64) == (512, 512)
+    lowered = TRACERS["lakesoul_tpu/models/attention.py::" + kernel]("phi-4-mini-flash", window).lower(
+        lowering_platforms=("tpu",)
+    )
+    text = lowered.as_text()
+    call = next(line for line in text.splitlines() if "tpu_custom_call" in line)
+    steps = attention.key_tile_steps(8192, 2, 64, window, 128)[0]
+    assert steps == (31 if window else 136) and f"tensor<{steps}xi32>" in text  # the tables the call prefetches
+    operands, results = call.split(" -> ")
+    assert "tensor<20x2x8192x64xbf16>" in operands and "tensor<20x8192x64xbf16>" in operands and "tensor<20x8192x128xbf16>" in operands
+    if kernel == "_flash_fwd_kernel":
+        assert "tensor<20x2x8192x128xbf16>" in results
+    else:
+        assert "tensor<20x2x8192x128xbf16>" in operands  # the output's cotangent
+        assert all(shape in results for shape in ("tensor<20x2x8192x64xbf16>", "tensor<20x8192x64xf32>", "tensor<20x8192x128xf32>"))
 
 
 @pytest.mark.parametrize("window", [None, 2048], ids=["full", "window-2048"])
