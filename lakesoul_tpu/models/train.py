@@ -380,9 +380,13 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
       ``exit_loss`` (0 for a family without a prediction module, without a
       loop);
     - ``lakesoul_train_moe_assignments_total{kind="held"|"all"|"tile_rows"|
-      "bias_moved"|"dw_writes"}`` and ``lakesoul_train_moe_expert_load{stat=
-      "max"|"mean"}`` (``tile_rows``: the slots of the expert tiles run, of
-      which ``held`` carried an assignment; ``bias_moved``: the assignments
+      "grouped"|"bias_moved"|"dw_writes"}`` and
+      ``lakesoul_train_moe_expert_load{stat="max"|"mean"}`` (``tile_rows``:
+      the slots moved and multiplied forward, whole tiles in the tile loop and
+      the row blocks up to an expert's last row in the grouped kernels, of
+      which ``held`` carried an assignment; ``grouped``: the held assignments
+      whose products ran in the grouped kernels and not in the tile loop;
+      ``bias_moved``: the assignments
       whose expert a routing bias brought into the top k; ``dw_writes``: the
       times the backward pass writes an expert's weight-gradient sum, for one
       of the three matrices; the load of the fullest and of the mean held
@@ -415,7 +419,8 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
                  "loss_rows_fused", "loss_rows_compiler", "ssm_rows_kernel", "ssm_rows_twin", "shared_reads")
     held = getattr(cfg, "experts_held", None)
     if held is None:  # a family without experts: its loss returns none of their counts, and they count 0
-        host_keys += ("moe_held", "moe_all", "moe_tile_rows", "moe_bias_moved", "moe_dw_writes", "moe_load_max")
+        host_keys += ("moe_held", "moe_all", "moe_tile_rows", "moe_grouped", "moe_bias_moved", "moe_dw_writes",
+                      "moe_load_max")
     passes = getattr(cfg, "loop_passes", None)
     if passes is None:
         host_keys += ("head_loop",)  # no operation of a step that does not loop: its programs stay as they are
@@ -434,6 +439,7 @@ def make_lm_train_step(cfg, plan: MeshPlan, tx, param_shardings):
         ("moe_held", MOE_ASSIGNMENTS_FAMILY, {"kind": "held"}, 1),
         ("moe_all", MOE_ASSIGNMENTS_FAMILY, {"kind": "all"}, 1),
         ("moe_tile_rows", MOE_ASSIGNMENTS_FAMILY, {"kind": "tile_rows"}, 1),
+        ("moe_grouped", MOE_ASSIGNMENTS_FAMILY, {"kind": "grouped"}, 1),
         ("moe_bias_moved", MOE_ASSIGNMENTS_FAMILY, {"kind": "bias_moved"}, 1),
         ("moe_dw_writes", MOE_ASSIGNMENTS_FAMILY, {"kind": "dw_writes"}, 1),
         ("moe_load_max", MOE_LOAD_FAMILY, {"stat": "max"}, 1),

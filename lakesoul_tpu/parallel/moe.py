@@ -21,52 +21,78 @@ two summed before the layer's last norm.  They stay apart because the stack rema
 first and the last with its norm and leaves the second outside (see
 :func:`held_experts`).
 
-Assignments are sorted by held expert and the products run one fixed tile of
-one expert's rows at a time, for as many tiles as the held assignments fill:
-the work follows the routing while every shape stays static.  A loop whose
-length depends on the data has no reverse-mode derivative, so the backward
-pass is a second loop of the same tiles under one custom_vjp.
+Assignments are sorted by held expert and cut into fixed tiles of one
+expert's rows, as many as the held assignments fill (:func:`_tile_plan`): the
+work follows the routing while every shape stays static.  A pass whose length
+depends on the data has no reverse-mode derivative, so the backward pass walks
+the same tiles again under one custom_vjp.
 
-How a tile's rows move.  A tile reads the rows of its tokens out of ``x`` (and
-``dy``) by indexing: XLA's row gather runs near the memory's speed on a v5e
-(8.5 us for 512 rows of 4 KB).  Its scatter-add does not: adding a tile's rows
-into the float32 sums over all tokens took 134 us a tile, half the loop's time
-(PERF.md section 6, PR 31).  So where a row of the sums is whole lane tiles
-(``h % 128 == 0``) the sums are carried as ``[N, 1, h]`` and a tile is added by
-two Pallas kernels around a dense sum, :func:`take_rows` and :func:`put_rows`:
-one DMA a row from HBM to HBM, the index read from SMEM, all of a tile's copies
-in flight at once and only the slots that hold an assignment moved (22 us a
-tile for the read, the sum and the write).  The middle axis is what lets a DMA take one row: Mosaic refuses a
-one-row slice of a two-dimensional array on a v5e (a slice of the second to
-last axis must be a multiple of its tiling, 8 rows of 32-bit words), while
-XLA lays ``[N, 1, h]`` out a row a tile (``T(1,128)``), rows contiguous and
-unpadded.  16-bit rows would have to travel as pairs in 32-bit words (their
-tiling is 16 rows); nothing here moves any, the sums are float32.  Narrower
-rows, as most tests use, keep ``.at[].add``, which stays as the kernels' twin:
-on a CPU (the kernels in the Pallas interpreter) and on a v5e the two paths
-give the same bits.
+How a pass runs.  Where the experts' matrices and the tile are whole 128-lane
+tiles and an expert's three matrices wait twice in VMEM (:func:`_grouped`; all
+four cells' experts), a pass is a loop over segments of :data:`GROUP_SEGMENT`
+tiles and a segment one Pallas kernel whose grid walks the tiles, a block of
+:data:`GROUP_ROWS` rows a step (:func:`experts_fwd`, :func:`experts_bwd`).
+The slots' tokens, the tiles' experts and the rows each tile holds are
+prefetched scalars; the three weight matrices are blocks by the tile's expert,
+so an expert's consecutive tiles find them in VMEM and the next expert's
+arrive while this one's tiles run; a block past its expert's last row is not
+multiplied (the backward kernel leaves zeros there: padding adds nothing to a
+sum), so a layer's time follows its rows and not its tiles.  The body of a
+block is the tile loop's, function for function (:func:`_tile_mid`,
+:func:`_tile_down`, :func:`_tile_operands`, :func:`_tile_dx`: the gate and up
+products, the SwiGLU, the down product and the row's routing weight forward;
+the recomputed products, ``<expert output, dy>``, ``dyw``, ``dmid``, ``dg``,
+``du`` and ``dx``'s rows backward), every rounding where the loop has it.
+Every other shape, as most tests use, keeps a loop of one turn a tile, which
+is the kernels' twin: on a CPU, the kernels in the Pallas interpreter, both
+give the same bits, forward and every gradient.
+
+How a block's rows move.  The kernels move their own rows, one DMA a row,
+between HBM and VMEM.  Mosaic refuses a one-row slice of a two-dimensional
+array on a v5e (a slice of the second to last axis must be a multiple of its
+tiling, 8 rows of 32-bit words, 16 of 16-bit ones), while XLA lays ``[N, 1,
+h]`` float32 out a row a tile (``T(1,128)``), rows contiguous and unpadded,
+and Mosaic reads ``[rows, 1, h]`` in VMEM as ``[rows, h]`` at no cost that a
+profile shows.  So ``x`` (and ``dy``) are staged once a layer and pass as
+float32 ``[N, 1, h]`` (:func:`stage_rows`, one Pallas pass at the memory's
+speed, which also writes the zeros of the sums), the sums over the tokens live
+in that layout, and :func:`unstage_rows` reads them back into ``[N, h]``.  A
+block's rows of ``x`` are started a step ahead of their products
+(:func:`_gathered`).  Its float32 result joins the sums inside the kernel
+(:func:`_add_block`): the sums' rows come in while the block's last product
+runs, are added, and go back; the block before may hold the same tokens
+(another expert's), so its rows have landed before this block's come, and a
+token's rows are summed in float32 in the order the loop adds them (ascending
+held expert, from zero).  Only the slots that hold an assignment join the
+sums; the slots past an expert's last row inside a block that runs read the
+row of some token, as the plan's clipped index gives it: finite, and
+multiplied by zeros.  A copy's start is 18 ns of the scalar unit's whatever
+the row's bytes and whichever queue (PERF.md section 6, PR 53): seven a held
+assignment, forward and backward, which the matrix unit waits for, since a
+loop of starts shares no instruction with a product; XLA's own row movement
+was dearer (its gather 40 ns a row standing alone, :func:`take_rows` and
+:func:`put_rows` around a dense sum 35 us a tile, its scatter-add 134: PERF.md
+section 6, PRs 31 and 52).  The tile loop keeps it (indexing for the reads,
+the two row kernels for the float32 rows where a row is whole lane tiles,
+``.at[].add`` for narrower ones).
 
 How the weight gradients sum.  An expert's three float32 sums ([h, f], 14.7 MB
-each for an expert of 2048 x 1792) ride the backward loop where an expert's
-rows fill a tile or so: a tile's three products are added to them in place,
-one read and one write of a sum for one tile of rows.  Where an expert's rows
-fill several tiles that is the same sum read and written several times over,
-88 MB a tile where the products themselves are 11 GFLOP, and a loop's carry
-cannot stay in VMEM (PERF.md section 6, PR 39).  There (:func:`_dw_span`:
-experts of whole lane tiles whose rows, shared evenly, fill two tiles or more)
-the loop only leaves a tile's five bfloat16 operands (``x``'s rows, the
-SwiGLU's output, and the three gradients ``dyw``, ``dg``, ``du``, zeros in the
-slots past an expert's last row) in row buffers, in the plan's sorted order
-(:func:`put_tiles`, one DMA an operand), and after :data:`DW_SEGMENT` tiles
-one Pallas kernel a matrix, :func:`expert_dw`, multiplies them expert by
+each for an expert of 2048 x 1792) cannot ride a loop in VMEM.  The backward
+kernel leaves a segment's operands in row buffers, in the plan's sorted order
+(``x``'s rows, the SwiGLU's output and the three gradients ``dyw``, ``dg``,
+``du``, all in the operands' precision, zeros in the slots past an expert's
+last row): they are the kernel's own output blocks, so nothing copies them.
+One Pallas kernel a matrix, :func:`expert_dw`, multiplies them expert by
 expert (``out[e] += lhs[e's rows].T @ rhs[e's rows]``, the transposed grouped
 product): the tile is the grid's innermost axis and the output block follows
 the tile's expert, so an expert's sum stays in VMEM through the expert's
-consecutive tiles and crosses HBM once a segment.  Segments bound the buffers
-(64 tiles: 0.62 GB in the LFM2 cell, where buffers for every assignment the
-shapes allow would be 2.6 GB); an expert that a segment's end splits is read
-back once, which is the only sum the kernel reads.  The same bfloat16 products
-are added in float32 in the same tile order on both paths: the same bits
+consecutive tiles and crosses HBM once a segment.  An expert that a segment's
+end splits is read back once, which is the only sum the kernel reads.  The
+tile loop adds a tile's three products to the sums in place where an expert's
+rows fill a tile or so, and where they fill two (:func:`_dw_span`) leaves its
+operands in row buffers by :func:`put_tiles` for the same kernel, a segment of
+:data:`DW_SEGMENT` tiles at a time.  The same bfloat16 products are added in
+float32 in the same tile order on every path: the same bits
 (:func:`_expert_dw_twin` is the kernel's ``jnp`` twin, for the tests and the
 smoke register).
 """
@@ -98,6 +124,18 @@ EXPERT_TILE = 512
 # time: 32,768 rows of ``2h + 3f`` bfloat16 (0.62 GB in the LFM2 cell, where
 # buffers for every assignment the shapes allow would be 2.6 GB)
 DW_SEGMENT = 64
+# the grouped kernels (:func:`experts_fwd`, :func:`experts_bwd`): tiles a call
+# walks (its prefetched scalars, and in the backward pass the row buffers
+# :func:`expert_dw` reads: 8,192 rows of ``2h + 3f`` bfloat16, 0.16 GB in the
+# LFM2 cell), rows of a tile a grid step multiplies (the blocks past an
+# expert's last row are skipped, so a step's rows are what padding costs), and
+# what the kernels may hold in VMEM of a v5e's 128 MiB: an expert's three
+# matrices twice (the pipeline fetches the next expert's while this one's
+# tiles run) beside a step's gathered rows and float32 products
+GROUP_SEGMENT = 16
+GROUP_ROWS = 128
+GROUP_WEIGHT_BYTES = 48 * 2**20
+GROUP_VMEM_LIMIT = 100 * 2**20
 # what :func:`expert_dw` may hold in VMEM of an expert's float32 sum and of its
 # operands, each twice (the pipeline's two buffers), and what the kernel may
 # use in all, a tile's product and transposed rows with them, of a v5e's 128
@@ -159,7 +197,8 @@ def _tile_plan(local, count: int, tile: int):
 def _tile_experts(t, plan):
     """The held expert of tile ``t`` (or of each of an array of tiles);
     ``count`` from the last tile on."""
-    return jnp.searchsorted(plan[3], t, side="right").astype(jnp.int32)
+    # every boundary compared at once: a held expert's count is small, and a search by halves is a loop of its own
+    return jnp.searchsorted(plan[3], t, side="right", method="compare_all").astype(jnp.int32)
 
 
 def _tile_rows(t, plan, tile: int, k: int):
@@ -428,15 +467,432 @@ def _swiglu(xt, wg, wu):
     return g, u, jax.nn.silu(g) * u
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _held_experts(x, w, plan, wg, wu, wd, tile, span):
+def _across(a, b):
+    """``a @ b.T``, float32: the second operand read as it lies."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _tile_mid(xt, wg, wu):
+    """The SwiGLU's output for a tile's rows, in their precision."""
+    return _swiglu(xt, wg, wu)[2].astype(xt.dtype)
+
+
+def _tile_down(mid, wt, wd):
+    """A tile's weighted rows, float32: the down product times ``wt`` [rows, 1]."""
+    return jnp.dot(mid, wd, preferred_element_type=jnp.float32) * wt
+
+
+def _tile_outputs(xt, wt, wg, wu, wd):
+    """``wt * expert(xt)``, float32."""
+    return _tile_down(_tile_mid(xt, wg, wu), wt, wd)
+
+
+def _tile_operands(xt, dyt, wt, wg, wu, wd):
+    """A tile's part of the backward pass up to ``dx``'s rows → (the SwiGLU's
+    output and the three gradients ``dyw``, ``dg``, ``du`` in the operands'
+    precision: with ``xt`` the operands of the weight-gradient products, zeros
+    where ``wt`` [rows, 1] is: padding adds nothing to a sum; each row's
+    ``<expert output, dy>`` [rows, 1], the gradient of its routing weight)."""
+    lo, f32 = xt.dtype, jnp.float32
+    g, u, mid = _swiglu(xt, wg, wu)
+    mid_lo = mid.astype(lo)
+    yt = jnp.dot(mid_lo, wd, preferred_element_type=f32)
+    dw_t = jnp.sum(yt * dyt.astype(f32), axis=-1, keepdims=True)
+    dyw = (dyt.astype(f32) * wt).astype(lo)
+    dmid = _across(dyw, wd)
+    sig = jax.nn.sigmoid(g)
+    dg = (dmid * u * sig * (1.0 + g * (1.0 - sig))).astype(lo)
+    du = (dmid * g * sig).astype(lo)
+    return mid_lo, dyw, dg, du, dw_t
+
+
+def _tile_dx(dg, du, wg, wu):
+    """``dx``'s rows of a tile, float32, from :func:`_tile_operands`' ``dg``, ``du``."""
+    return _across(dg, wg) + _across(du, wu)
+
+
+# ------------------------------------------------ a segment's tiles as one kernel
+
+
+def _grouped(h: int, f: int, tile: int, itemsize: int = 2) -> bool:
+    """Whether the grouped kernels take experts of [h, f] at this tile: ``h``,
+    ``f`` and the tile whole 128-lane tiles, and a whole expert's three
+    matrices twice within :data:`GROUP_WEIGHT_BYTES`."""
+    return not (h % 128 or f % 128 or tile % 128) and 2 * 3 * h * f * itemsize <= GROUP_WEIGHT_BYTES
+
+
+def _segment(assignments: int, count: int, matrix: tuple[int, int], tile: int, itemsize: int = 2) -> int:
+    """Tiles a call of the grouped kernels walks, or 0 where the tile loop
+    runs, a turn a tile (:func:`_grouped` says which shapes the kernels take):
+    :data:`GROUP_SEGMENT`, or as many as ``assignments`` over ``count`` held
+    experts can fill where that is less."""
+    if not _grouped(*matrix, tile, itemsize):
+        return 0
+    return min(GROUP_SEGMENT, assignments // tile + count)
+
+
+def _group_rows(tile: int) -> int:
+    """Rows of a tile the grouped kernels take a grid step."""
+    return GROUP_ROWS if tile % GROUP_ROWS == 0 else 128
+
+
+def _by_lanes(wt):
+    """A float32 a row as a row of 128 lanes, all the same: how it reaches a kernel's block of rows."""
+    return jnp.broadcast_to(wt.astype(jnp.float32)[:, None], (wt.shape[0], 128))
+
+
+def _block_held(counts_ref, i, rows: int, sub: int):
+    """Rows of block ``i`` (``rows`` rows, ``sub`` blocks a tile) that hold an
+    assignment: none where the block lies past its expert's last row or past
+    the run.  ``counts_ref``: each tile's, and after them the run's last block."""
+    return jnp.clip(counts_ref[i // sub] - (i % sub) * rows, 0, rows)
+
+
+def _block_fetched(counts_ref, i, rows: int, sub: int):
+    """The block whose rows and weights stand in VMEM at step ``i``: ``i``
+    where it runs, else the last one that ran before it (its tile's, or the
+    run's): a block index that does not change moves nothing."""
+    tile, tiles = i // sub, counts_ref.shape[0] - 1
+    mine = counts_ref[tile]
+    before = jnp.where(mine > 0, tile * sub + (mine - 1) // rows, counts_ref[tiles])
+    return jnp.where(mine > (i % sub) * rows, i, before)
+
+
+class _Block:
+    """Where a grid step of the grouped kernels stands: block ``i`` of
+    ``rows`` rows, how many of its rows hold an assignment, whether it is
+    padding (past its expert's last row inside a tile that runs: its outputs
+    are zeros), whether it is the call's last, whether the next one runs."""
+
+    def __init__(self, counts_ref, rows: int, sub: int):
+        self.i, self.rows = pl.program_id(0), rows
+        steps = pl.num_programs(0)
+        self.held = _block_held(counts_ref, self.i, rows, sub)
+        self.padding = (self.held == 0) & (counts_ref[self.i // sub] > 0)
+        self.last = self.i == steps - 1
+        self.next_runs = jnp.logical_not(self.last) & (_block_held(counts_ref, jnp.minimum(self.i + 1, steps - 1), rows, sub) > 0)
+
+
+ROW_BURST = 8  # row copies a loop turn starts, and rows one wait counts
+
+
+def _start_rows(n, copy):
+    """Start ``copy(r)`` for every r < n, :data:`ROW_BURST` a loop turn: inside
+    a kernel that multiplies, a turn's overhead is time the matrix unit waits."""
+    def burst(j, carry):
+        for r in range(ROW_BURST):
+            copy(j * ROW_BURST + r).start()
+        return carry
+
+    jax.lax.fori_loop(0, n // ROW_BURST, burst, 0)
+    jax.lax.fori_loop(n // ROW_BURST * ROW_BURST, n, lambda r, carry: (copy(r).start(), carry)[1], 0)
+
+
+def _wait_rows(n, landed):
+    """Wait until ``n`` of the row copies that share ``landed``'s semaphore have
+    landed; ``landed(k)`` is a copy of ``k`` rows, whose wait counts as many bytes."""
+    jax.lax.fori_loop(0, n // ROW_BURST, lambda j, carry: (landed(ROW_BURST).wait(), carry)[1], 0)
+    jax.lax.fori_loop(0, n % ROW_BURST, lambda j, carry: (landed(1).wait(), carry)[1], 0)
+
+
+def _gathered(tok_ref, block, src_ref, buf, sem):
+    """→ ``take``: ``take()`` gives the float32 rows ``src[tok]`` of this step's
+    block, [rows, width]; the next block's are started here, one DMA a row from
+    HBM into one of ``buf``'s [2, rows, 1, width] (``src`` [N, 1, width]: what
+    lets a DMA take one row, see the module's docstring), so that they land
+    while this block's products run.  Every row of a block that runs comes,
+    the slots past an expert's last row with the row of some token, as the
+    plan's clipped index gives it: finite, and multiplied by zeros."""
+    i, rows = block.i, block.rows
+
+    def rows_of(step, slot):
+        return lambda r: pltpu.make_async_copy(
+            src_ref.at[pl.ds(tok_ref[step * rows + r], 1)], buf.at[slot, pl.ds(r, 1)], sem.at[slot])
+
+    @pl.when((i == 0) & (block.held > 0))
+    def _():
+        _start_rows(rows, rows_of(0, 0))
+
+    @pl.when(block.next_runs)
+    def _():
+        _start_rows(rows, rows_of(i + 1, (i + 1) % 2))
+
+    def take():
+        pltpu.make_async_copy(src_ref.at[pl.ds(0, rows)], buf.at[i % 2], sem.at[i % 2]).wait()  # all of a block's rows at once
+        return buf[i % 2].reshape(rows, buf.shape[-1])
+
+    return take
+
+
+def _rows_back(acc_ref, buf, sem, k):
+    """A copy of ``k`` rows on the semaphore of the rows :func:`_add_block` sends back."""
+    return pltpu.make_async_copy(buf.at[pl.ds(0, k)], acc_ref.at[pl.ds(0, k)], sem.at[1])
+
+
+def _add_block(tok_ref, block, acc_ref, buf, sem, pending, between):
+    """``acc[tok] += rows`` for the block's first ``held`` rows, in which no
+    token repeats: ``acc`` [N, 1, width] float32 stays in HBM, the rows it
+    holds come into ``buf`` [rows, 1, width] a DMA a row, are added and go
+    back a DMA a row.  ``rows = between()`` is computed while they come; the
+    block before may hold the same tokens (another expert's), so its rows must
+    have landed first: ``pending`` counts them."""
+    i, rows, held = block.i, block.rows, block.held
+
+    def seen(r):
+        return pltpu.make_async_copy(acc_ref.at[pl.ds(tok_ref[i * rows + r], 1)], buf.at[pl.ds(r, 1)], sem.at[0])
+
+    def back(r):
+        return pltpu.make_async_copy(buf.at[pl.ds(r, 1)], acc_ref.at[pl.ds(tok_ref[i * rows + r], 1)], sem.at[1])
+
+    _wait_rows(pending[0], lambda k: _rows_back(acc_ref, buf, sem, k))
+    _start_rows(held, seen)
+    added = between()
+    _wait_rows(held, lambda k: pltpu.make_async_copy(acc_ref.at[pl.ds(0, k)], buf.at[pl.ds(0, k)], sem.at[0]))
+
+    # a branch of its own (always taken: the caller's condition again) so that the rows are rounded before
+    # the sum in the interpreter too: in one expression XLA's CPU backend contracts a product and a sum
+    @pl.when(held > 0)
+    def _():
+        buf[...] = (buf[...].reshape(added.shape) + added).reshape(buf.shape)
+
+    _start_rows(held, back)
+    pending[0] = held
+
+
+def _experts_fwd_kernel(tok_ref, experts_ref, counts_ref, x_ref, wt_ref, wg_ref, wu_ref, wd_ref, y_in_ref,
+                        y_ref, xbuf, ybuf, xsem, ysem, pending, *, sub):
+    del experts_ref, y_in_ref  # the block specs read the first; the second is y_ref's memory
+    block = _Block(counts_ref, ybuf.shape[0], sub)
+    lo = wg_ref.dtype
+
+    @pl.when(block.i == 0)
+    def _():
+        pending[0] = 0
+
+    take_x = _gathered(tok_ref, block, x_ref, xbuf, xsem)
+
+    @pl.when(block.held > 0)
+    def _():
+        mid = _tile_mid(take_x().astype(lo), wg_ref[0], wu_ref[0])
+        _add_block(tok_ref, block, y_ref, ybuf, ysem, pending, lambda: _tile_down(mid, wt_ref[:, :1], wd_ref[0]))
+
+    @pl.when(block.last)
+    def _():
+        _wait_rows(pending[0], lambda k: _rows_back(y_ref, ybuf, ysem, k))
+
+
+def _experts_bwd_kernel(tok_ref, experts_ref, counts_ref, x_ref, dy_ref, wt_ref, wg_ref, wu_ref, wd_ref, dx_in_ref,
+                        dx_ref, xs_ref, mid_ref, dyw_ref, dg_ref, du_ref, dw_ref,
+                        xbuf, dybuf, dxbuf, xsem, dysem, dxsem, pending, *, sub):
+    del experts_ref, dx_in_ref
+    block = _Block(counts_ref, dxbuf.shape[0], sub)
+    lo = wg_ref.dtype
+    operands = (xs_ref, mid_ref, dyw_ref, dg_ref, du_ref)
+
+    @pl.when(block.i == 0)
+    def _():
+        pending[0] = 0
+
+    take_x = _gathered(tok_ref, block, x_ref, xbuf, xsem)
+    take_dy = _gathered(tok_ref, block, dy_ref, dybuf, dysem)
+
+    @pl.when(block.held > 0)
+    def _():
+        xt, dyt = take_x().astype(lo), take_dy().astype(lo)
+        wg, wu = wg_ref[0], wu_ref[0]
+        *outs, dw_t = _tile_operands(xt, dyt, wt_ref[:, :1], wg, wu, wd_ref[0])
+        for ref, out in zip(operands, (xt, *outs), strict=True):
+            ref[...] = out
+        dw_ref[...] = jnp.broadcast_to(dw_t, dw_ref.shape)
+        _add_block(tok_ref, block, dx_ref, dxbuf, dxsem, pending, lambda: _tile_dx(outs[2], outs[3], wg, wu))
+
+    @pl.when(block.padding)
+    def _():
+        for ref in operands:
+            ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+    @pl.when(block.last)
+    def _():
+        _wait_rows(pending[0], lambda k: _rows_back(dx_ref, dxbuf, dxsem, k))
+
+
+def _segment_grid(acc, gathered, tok, wt, weights, experts, counts, outs):
+    """The call of a grouped kernel over a segment's tiles → (blocks a tile,
+    ``pallas_call``'s arguments, the call's operands).  ``acc`` [N, 1, h]
+    float32 (the sums the kernel adds its rows to, in place where the caller
+    lets go of them) and ``gathered`` (arrays [N, 1, h] float32 whose rows
+    ``tok`` [tiles * tile] the kernel reads) stay in HBM; the rows' weights
+    ``wt`` and ``outs`` ((width, dtype) of each array [tiles * tile, width]
+    that comes back) go a block of rows a grid step, ``weights`` [count, ...]
+    by the tile's expert: a block whose index does not change is not fetched
+    again, so an expert's matrices come once for its consecutive tiles.
+    ``counts`` [tiles]: the rows of each tile that hold an assignment, 0 from
+    the run's last tile on.  A block without one is neither fetched nor
+    multiplied; inside a tile that runs it is written, as zeros."""
+    tiles = experts.shape[0]
+    tile = tok.shape[0] // tiles
+    rows = _group_rows(tile)
+    sub = tile // rows
+    experts = jnp.minimum(experts, weights[0].shape[0] - 1)
+    counts = counts.astype(jnp.int32)
+    run = jnp.sum(counts > 0)  # the tiles that run are the first ones; after their counts, the run's last block
+    counts = jnp.append(counts, jnp.maximum((run - 1) * sub + (counts[jnp.maximum(run - 1, 0)] - 1) // rows, 0))
+
+    def fetched(i, counts_ref):
+        return _block_fetched(counts_ref, i, rows, sub)
+
+    def written(i, counts_ref):  # as far as the run's last tile every block is written; past it the last one stays
+        return jnp.where(counts_ref[i // sub] > 0, i, counts_ref[tiles])
+
+    def by_rows(width, where):
+        return pl.BlockSpec((rows, width), lambda i, tok_ref, experts_ref, counts_ref: (where(i, counts_ref), 0))
+
+    def by_expert(m):
+        return pl.BlockSpec((1, *m.shape[1:]),
+                            lambda i, tok_ref, experts_ref, counts_ref: (experts_ref[fetched(i, counts_ref) // sub], 0, 0))
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    h = acc.shape[-1]
+    row_buffers = [pltpu.VMEM((2, rows, 1, h), jnp.float32) for _ in gathered] + [pltpu.VMEM((rows, 1, h), jnp.float32)]
+    semaphores = [pltpu.SemaphoreType.DMA((2,)) for _ in range(len(gathered) + 1)]
+    call = dict(
+        out_shape=[jax.ShapeDtypeStruct(acc.shape, acc.dtype)]
+        + [jax.ShapeDtypeStruct((tiles * tile, width), dtype) for width, dtype in outs],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(tiles * sub,),
+            in_specs=[anywhere] * len(gathered) + [by_rows(128, fetched)] + [by_expert(m) for m in weights] + [anywhere],
+            out_specs=[anywhere] + [by_rows(width, written) for width, _ in outs],
+            scratch_shapes=[*row_buffers, *semaphores, pltpu.SMEM((1,), jnp.int32)],
+        ),
+        input_output_aliases={3 + len(gathered) + 1 + len(weights): 0},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=GROUP_VMEM_LIMIT),
+    )
+    return sub, call, (tok, experts, counts, *gathered, _by_lanes(wt), *weights, acc)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def experts_fwd(y, x32, tok, wt, wg, wu, wd, experts, counts, *, interpret: bool):
+    """``y`` [N, 1, h] float32 with ``wt * expert(x32[tok])`` added to its rows
+    ``tok``, for the slots that hold an assignment: ``tok``, ``wt`` [R] are
+    ``experts`` [R / tile] int32 tiles of slots, a tile's all of one expert,
+    the first ``counts`` [R / tile] of them holding an assignment (no token
+    twice in a tile), an expert's tiles next to each other; ``x32`` [N, 1, h]
+    float32 holds values of the weights' precision, the weights [count, ...].
+    A tile's rows join ``y`` in the tiles' order."""
+    sub, call, operands = _segment_grid(y, (x32,), tok, wt, (wg, wu, wd), experts, counts, [])
+    (y,) = pl.pallas_call(functools.partial(_experts_fwd_kernel, sub=sub), name="experts_fwd", interpret=interpret, **call)(*operands)
+    return y
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def experts_bwd(dx, x32, dy32, tok, wt, wg, wu, wd, experts, counts, *, interpret: bool):
+    """:func:`experts_fwd`'s operands, the cotangent ``dy32`` [N, 1, h] and the
+    sums ``dx`` [N, 1, h] float32 → (``dx`` with the tiles' rows added; ``xs``
+    [R, h], ``mid`` [R, f], ``dyw`` [R, h], ``dg`` and ``du`` [R, f] in the
+    weights' precision: the operands :func:`expert_dw` takes, zeros in the
+    slots of a tile past its expert's last block; the routing weights'
+    gradients [R] float32), as :func:`_tile_operands` and :func:`_tile_dx`
+    give a tile's.  What the arrays hold from the run's last tile on is not
+    written."""
+    h, f, lo = x32.shape[-1], wg.shape[2], wg.dtype
+    sub, call, operands = _segment_grid(
+        dx, (x32, dy32), tok, wt, (wg, wu, wd), experts, counts, [(h, lo), (f, lo), (h, lo), (f, lo), (f, lo), (128, jnp.float32)])
+    dx, *held, dw = pl.pallas_call(
+        functools.partial(_experts_bwd_kernel, sub=sub), name="experts_bwd", interpret=interpret, **call)(*operands)
+    return dx, *held, dw[:, 0]
+
+
+def _segment_rows(t0, plan, tile: int, k: int, span: int, w_flat):
+    """Tiles ``t0`` to ``t0 + span`` → (expert of each, ``count`` from the run's
+    last tile on; assignment of each slot [span, tile]; which slots hold one, a
+    prefix of each tile and none past the run; the slots' routing weights,
+    zeros in the other slots).  A slot's token is its assignment over ``k``."""
+    order, sizes, starts, tile_ends = plan
+    count = sizes.shape[0]
+    t = t0 + jnp.arange(span, dtype=jnp.int32)
+    e = _tile_experts(t, plan)
+    held = jnp.minimum(e, count - 1)
+    row0 = starts[held] + (t - (tile_ends[held] - (sizes[held] + tile - 1) // tile)) * tile
+    rows = row0[:, None] + jnp.arange(tile, dtype=jnp.int32)
+    valid = (rows < (starts + sizes)[held][:, None]) & (e < count)[:, None]
+    a = order[jnp.clip(rows, 0, order.shape[0] - 1)]
+    return e, a, valid, jnp.where(valid, w_flat[a], 0.0)
+
+
+# a step's blocks twice (three float32 outputs of [256, 1, 2048] are 12 MB) pass the 16 MB a kernel has unasked
+_STAGE_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel",), vmem_limit_bytes=48 * 2**20)
+
+
+def _stage_block(n: int) -> int:
+    """Rows a grid step of :func:`stage_rows` and :func:`unstage_rows` takes: the
+    largest of 256 down to 8 that divides ``n``, or 0 where none does."""
+    return next((rows for rows in (256, 128, 64, 32, 16, 8) if n % rows == 0), 0)
+
+
+def _stage_rows_kernel(*refs):
+    *pairs, zeros_ref = refs
+    for src, dst in zip(pairs[: len(pairs) // 2], pairs[len(pairs) // 2:], strict=True):
+        dst[...] = src[...].astype(jnp.float32).reshape(dst.shape)
+    zeros_ref[...] = jnp.zeros(zeros_ref.shape, zeros_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def stage_rows(arrays, *, interpret: bool):
+    """``arrays`` (each [N, h]) → (each as float32 [N, 1, h], the layout a DMA
+    takes one row of, and zeros of that shape: the sums the kernels add to).
+    One pass at the memory's speed: XLA writes that layout at 0.35 TB/s (a
+    convert of [32768, 2048] 1.15 ms, zeros 0.82; PERF.md section 6, PR 53)."""
+    n, h = arrays[0].shape
+    rows = _stage_block(n)
+    staged = jax.ShapeDtypeStruct((n, 1, h), jnp.float32)
+    if not rows:
+        return tuple(m.astype(jnp.float32)[:, None, :] for m in arrays), jnp.zeros(staged.shape, staged.dtype)
+    out = pl.BlockSpec((rows, 1, h), lambda i: (i, 0, 0))
+    *staged, zeros = pl.pallas_call(
+        _stage_rows_kernel, out_shape=[staged] * (len(arrays) + 1), grid=(n // rows,),
+        in_specs=[pl.BlockSpec((rows, h), lambda i: (i, 0))] * len(arrays), out_specs=[out] * (len(arrays) + 1),
+        name="stage_rows", interpret=interpret, compiler_params=_STAGE_PARAMS,
+    )(*arrays)
+    return tuple(staged), zeros
+
+
+def _unstage_rows_kernel(acc_ref, out_ref):
+    out_ref[...] = acc_ref[...].reshape(out_ref.shape).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+def unstage_rows(acc, dtype, *, interpret: bool):
+    """The sums ``acc`` [N, 1, h] float32 → [N, h] in ``dtype``, one pass."""
+    n, _, h = acc.shape
+    rows = _stage_block(n)
+    if not rows:
+        return acc.reshape(n, h).astype(dtype)
+    return pl.pallas_call(
+        _unstage_rows_kernel, out_shape=jax.ShapeDtypeStruct((n, h), dtype), grid=(n // rows,),
+        in_specs=[pl.BlockSpec((rows, 1, h), lambda i: (i, 0, 0))], out_specs=pl.BlockSpec((rows, h), lambda i: (i, 0)),
+        name="unstage_rows", interpret=interpret, compiler_params=_STAGE_PARAMS,
+    )(acc)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _held_experts(x, w, plan, wg, wu, wd, tile, span, grouped):
     """``sum over held assignments of w * expert(x)``: x [N, h], w [N, k] f32,
     ``plan`` of :func:`_tile_plan` over the N x k assignments, weights
-    [count, ...] → [N, h].  ``span`` of :func:`_dw_span` is the backward
+    [count, ...] → [N, h].  ``grouped``: whether a segment of ``span`` tiles
+    (:func:`_segment`) is one call of :func:`experts_fwd`; else the tile loop,
+    the kernels' twin, and ``span`` of :func:`_dw_span` is the backward
     pass's."""
     k = w.shape[1]
     w_flat = w.reshape(-1)
     wg, wu, wd = (m.astype(x.dtype) for m in (wg, wu, wd))
+    tiles = plan[3][-1]
+
+    def run_segment(carry):
+        t0, y = carry
+        e, a, valid, wt = _segment_rows(t0, plan, tile, k, span, w_flat)
+        y = experts_fwd(y, x32, (a // k).reshape(-1), wt.reshape(-1), wg, wu, wd, e,
+                        jnp.sum(valid, axis=1), interpret=interpret)
+        return t0 + span, y
 
     def run_tile(carry):
         # a tile's rows join ``y`` a turn late: carried through the loop the
@@ -446,25 +902,25 @@ def _held_experts(x, w, plan, wg, wu, wd, tile, span):
         t, y, late = carry
         y = _add_rows(y, *late)
         e, a, tok, valid, _ = _tile_rows(t, plan, tile, k)
-        _, _, mid = _swiglu(x[tok], wg[e], wu[e])
-        yt = jnp.dot(mid.astype(x.dtype), wd[e], preferred_element_type=jnp.float32)
-        yt = yt * jnp.where(valid, w_flat[a], 0.0)[:, None]
+        yt = _tile_outputs(x[tok], jnp.where(valid, w_flat[a], 0.0)[:, None], wg[e], wu[e], wd[e])
         return t + 1, y, (tok, valid, yt)
 
-    tiles = plan[3][-1]
+    if grouped:
+        interpret = not platform.on_tpu()
+        (x32,), zeros = stage_rows((x,), interpret=interpret)
+        _, y = jax.lax.while_loop(lambda c: c[0] < tiles, run_segment, (jnp.int32(0), zeros))
+        return unstage_rows(y, x.dtype, interpret=interpret)
     nothing = (jnp.zeros(tile, jnp.int32), jnp.zeros(tile, bool), jnp.zeros((tile, x.shape[1]), jnp.float32))
-    _, y, late = jax.lax.while_loop(
-        lambda c: c[0] < tiles, run_tile, (jnp.int32(0), _row_accumulator(x), nothing)
-    )
+    _, y, late = jax.lax.while_loop(lambda c: c[0] < tiles, run_tile, (jnp.int32(0), _row_accumulator(x), nothing))
     y = _add_rows(y, *late)
     return y.reshape(x.shape).astype(x.dtype)
 
 
-def _held_experts_fwd(x, w, plan, wg, wu, wd, tile, span):
-    return _held_experts(x, w, plan, wg, wu, wd, tile, span), (x, w, plan, wg, wu, wd)
+def _held_experts_fwd(x, w, plan, wg, wu, wd, tile, span, grouped):
+    return _held_experts(x, w, plan, wg, wu, wd, tile, span, grouped), (x, w, plan, wg, wu, wd)
 
 
-def _held_experts_bwd(tile, span, saved, dy):
+def _held_experts_bwd(tile, span, grouped, saved, dy):
     x, w, plan, wg, wu, wd = saved
     n, k = w.shape
     w_flat = w.reshape(-1)
@@ -474,29 +930,34 @@ def _held_experts_bwd(tile, span, saved, dy):
     f32 = jnp.float32
     tiles = plan[3][-1]
     interpret = not platform.on_tpu()
+    sums = tuple(jnp.zeros(m.shape, f32) for m in (wg, wu, wd))
+
+    def by_expert(sums, operands, experts, run):
+        xs, mids, dyws, dgs, dus = operands
+        return tuple(expert_dw(held, lhs, rhs, experts, run, interpret=interpret)
+                     for held, lhs, rhs in zip(sums, (xs, xs, mids), (dgs, dus, dyws), strict=True))
+
+    def run_grouped(carry):
+        # the kernel leaves a segment's operands in row buffers for one :func:`expert_dw` a matrix
+        t0, dx, (dw_slots, a_slots), sums = carry
+        e, a, valid, wt = _segment_rows(t0, plan, tile, k, span, w_flat)
+        dx, *operands, dws = experts_bwd(dx, x32, dy32, (a // k).reshape(-1), wt.reshape(-1), wg_lo, wu_lo, wd_lo, e,
+                                         jnp.sum(valid, axis=1), interpret=interpret)
+        # every slot's weight gradient and whose it is (none's: past the last assignment), for one scatter at the end
+        dw_slots = jax.lax.dynamic_update_slice(dw_slots, dws, (t0 * tile,))
+        a_slots = jax.lax.dynamic_update_slice(a_slots, jnp.where(valid, a, n * k).reshape(-1), (t0 * tile,))
+        return t0 + span, dx, (dw_slots, a_slots), by_expert(sums, operands, e, jnp.minimum(tiles - t0, span))
 
     def tile_grads(t, dx, dw_rows):
         """Tile ``t`` → (its expert, ``dx`` and ``dw_rows`` with the tile's
         part, the operands of its three weight-gradient products)."""
         e, a, tok, valid, row0 = _tile_rows(t, plan, tile, k)
         xt = x[tok]
-        g, u, mid = _swiglu(xt, wg_lo[e], wu_lo[e])
-        mid_lo = mid.astype(lo)
-        wt = jnp.where(valid, w_flat[a], 0.0)
-        dyt = dy[tok]
-        # the assignment's weight: <expert output, dy>
-        yt = jnp.dot(mid_lo, wd_lo[e], preferred_element_type=f32)
-        dw_t = jnp.sum(yt * dyt.astype(f32), axis=-1)
+        wt = jnp.where(valid, w_flat[a], 0.0)[:, None]
+        mid_lo, dyw, dg, du, dw_t = _tile_operands(xt, dy[tok], wt, wg_lo[e], wu_lo[e], wd_lo[e])
         seen = jax.lax.dynamic_slice(dw_rows, (row0,), (tile,))
-        dw_rows = jax.lax.dynamic_update_slice(dw_rows, jnp.where(valid, dw_t, seen), (row0,))
-        dyw = (dyt.astype(f32) * wt[:, None]).astype(lo)
-        dmid = jnp.dot(dyw, wd_lo[e].T, preferred_element_type=f32)
-        sig = jax.nn.sigmoid(g)
-        # dyw, dg, du are zeros in the slots past the expert's last row (wt is): padding adds nothing to a sum
-        dg = (dmid * u * sig * (1.0 + g * (1.0 - sig))).astype(lo)
-        du = (dmid * g * sig).astype(lo)
-        dxt = (jnp.dot(dg, wg_lo[e].T, preferred_element_type=f32)
-               + jnp.dot(du, wu_lo[e].T, preferred_element_type=f32))
+        dw_rows = jax.lax.dynamic_update_slice(dw_rows, jnp.where(valid, dw_t[:, 0], seen), (row0,))
+        dxt = _tile_dx(dg, du, wg_lo[e], wu_lo[e])
         return e, _add_rows(dx, tok, valid, dxt), dw_rows, (xt, mid_lo, dyw, dg, du)
 
     def run_tile(carry):
@@ -509,7 +970,7 @@ def _held_experts_bwd(tile, span, saved, dy):
 
     def run_segment(carry):
         # a tile's operands wait in row buffers for one :func:`expert_dw` a matrix
-        t0, dx, dw_rows, (dwg, dwu, dwd), held = carry
+        t0, dx, dw_rows, sums, held = carry
         t1 = jnp.minimum(t0 + span, tiles)
 
         def hold_tile(carry):
@@ -518,26 +979,29 @@ def _held_experts_bwd(tile, span, saved, dy):
             return t + 1, dx, dw_rows, put_tiles(held, operands, (t - t0) * tile, interpret=interpret)
 
         _, dx, dw_rows, held = jax.lax.while_loop(lambda c: c[0] < t1, hold_tile, (t0, dx, dw_rows, held))
-        xs, mids, dyws, dgs, dus = held
         experts = _tile_experts(t0 + jnp.arange(span, dtype=jnp.int32), plan)
-        dwg, dwu, dwd = (expert_dw(sums, lhs, rhs, experts, t1 - t0, interpret=interpret)
-                         for sums, lhs, rhs in ((dwg, xs, dgs), (dwu, xs, dus), (dwd, mids, dyws)))
-        return t1, dx, dw_rows, (dwg, dwu, dwd), held
+        return t1, dx, dw_rows, by_expert(sums, held, experts, t1 - t0), held
 
-    init = (
-        jnp.int32(0), _row_accumulator(x),
-        jnp.zeros(n * k + tile, f32),  # a tile may reach past the last row
-        tuple(jnp.zeros(m.shape, f32) for m in (wg, wu, wd)),
-    )
-    body = run_tile
-    if span:
-        # not written: the loop fills a tile's rows before a kernel reads them,
-        # and none reads the tiles a segment stops short of
-        h, f = wg.shape[1:]
-        init = (*init, tuple(jax.lax.empty((span * tile, width), lo) for width in (h, f, h, f, f)))
-        body = run_segment
-    _, dx, dw_rows, (dwg, dwu, dwd), *_ = jax.lax.while_loop(lambda c: c[0] < tiles, body, init)
-    dw = jnp.zeros(n * k, f32).at[plan[0]].set(dw_rows[: n * k]).reshape(n, k)
+    if grouped:
+        (x32, dy32), zeros = stage_rows((x, dy), interpret=interpret)
+        # a tile's slots, tile after tile; a segment may reach past the last tile the shapes allow
+        most = -(-(n * k // tile + wg.shape[0]) // span) * span * tile
+        slots = (jnp.zeros(most, f32), jnp.full(most, n * k, jnp.int32))
+        _, dx, (dw_slots, a_slots), (dwg, dwu, dwd) = jax.lax.while_loop(
+            lambda c: c[0] < tiles, run_grouped, (jnp.int32(0), zeros, slots, sums))
+        dw = jnp.zeros(n * k, f32).at[a_slots].set(dw_slots, mode="drop").reshape(n, k)
+        dx = unstage_rows(dx, x.dtype, interpret=interpret)
+    else:
+        init = (jnp.int32(0), _row_accumulator(x), jnp.zeros(n * k + tile, f32), sums)  # a tile may reach past the last row
+        body = run_tile
+        if span:
+            # not written: the loop fills a tile's rows before a kernel reads them,
+            # and none reads the tiles a segment stops short of
+            h, f = wg.shape[1:]
+            init = (*init, tuple(jax.lax.empty((span * tile, width), lo) for width in (h, f, h, f, f)))
+            body = run_segment
+        _, dx, dw_rows, (dwg, dwu, dwd), *_ = jax.lax.while_loop(lambda c: c[0] < tiles, body, init)
+        dw = jnp.zeros(n * k, f32).at[plan[0]].set(dw_rows[: n * k]).reshape(n, k)
     return (dx.reshape(x.shape).astype(x.dtype), dw.astype(w.dtype), None,
             dwg.astype(wg.dtype), dwu.astype(wu.dtype), dwd.astype(wd.dtype))
 
@@ -547,21 +1011,28 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def _routed_share(x, top_e, w, wg, wu, wd, *, n_experts, held, tile, axes):
     """One shard's rows through the experts held here.  → (y, expert loads
-    [count], the slots of the tiles run and the times the backward pass writes
-    an expert's weight-gradient sum a matrix, all summed over ``axes``)."""
+    [count], the slots multiplied forward, the held assignments that went
+    through the grouped kernels and the times the backward pass writes an
+    expert's weight-gradient sum a matrix, all summed over ``axes``)."""
     first, count = held
     shape = x.shape
     k = top_e.shape[-1]
     local = top_e.reshape(-1) - first
     local = jnp.where((local >= 0) & (local < count), local, count)
     tile = min(tile, -(-local.shape[0] // 8) * 8)
-    span = _dw_span(local.shape[0], n_experts, count, wg.shape[1:], tile)
+    segment = _segment(local.shape[0], count, wg.shape[1:], tile, x.dtype.itemsize)
+    span = segment or _dw_span(local.shape[0], n_experts, count, wg.shape[1:], tile)
     plan = _tile_plan(local, count, tile)
-    y = _held_experts(x.reshape(-1, shape[-1]), w.reshape(-1, k), plan, wg, wu, wd, tile, span)
-    loads, tiles, writes = plan[1], plan[3][-1], _dw_writes(plan, span, local.shape[0] // tile + count)
+    y = _held_experts(x.reshape(-1, shape[-1]), w.reshape(-1, k), plan, wg, wu, wd, tile, span, bool(segment))
+    loads = plan[1]
+    # the tile loop multiplies every slot of a tile it runs; the kernels the blocks up to an expert's last row
+    block = _group_rows(tile) if segment else tile
+    slots = jnp.sum((loads + block - 1) // block) * block
+    grouped = jnp.sum(loads) if segment else jnp.int32(0)
+    writes = _dw_writes(plan, span, local.shape[0] // tile + count)
     if axes:
-        loads, tiles, writes = jax.lax.psum((loads, tiles, writes), axes)
-    return y.reshape(shape), loads, tiles * tile, writes
+        loads, slots, grouped, writes = jax.lax.psum((loads, slots, grouped, writes), axes)
+    return y.reshape(shape), loads, slots, grouped, writes
 
 
 def shared_expert(x, p):
@@ -583,7 +1054,7 @@ def held_experts(x, top_e, w, p, *, n_experts: int, held: tuple[int, int],
     ``top_e``, ``w`` [..., k] of a routing rule → (y [..., h], counts).
     Its backward pass needs ``x``, the routing and the weights and nothing it
     computed, so a caller that rematerialises its layer can leave this call
-    outside: the tile loop then runs once forward, not twice."""
+    outside: the tiles then run once forward, not twice."""
     first, count = held
     if not (0 <= first and first + count <= n_experts and p["w_gate"].shape[0] == count):
         raise ValueError(f"held={held} does not fit {n_experts} experts and {p['w_gate'].shape[0]} held weights")
@@ -591,20 +1062,21 @@ def held_experts(x, top_e, w, p, *, n_experts: int, held: tuple[int, int],
     weights = (p["w_gate"], p["w_up"], p["w_down"])
     with jax.named_scope(EXPERTS_SCOPE):
         if batch_sharding is None:
-            y, loads, tile_rows, dw_writes = _routed_share(
+            y, loads, tile_rows, grouped, dw_writes = _routed_share(
                 x, top_e, w, *weights, n_experts=n_experts, held=held, tile=tile, axes=())
         else:
             spec = batch_sharding.spec
-            y, loads, tile_rows, dw_writes = jax.shard_map(
+            y, loads, tile_rows, grouped, dw_writes = jax.shard_map(
                 functools.partial(_routed_share, n_experts=n_experts, held=held, tile=tile, axes=spec_axes(spec)),
                 mesh=batch_sharding.mesh, in_specs=(spec, spec, spec, P(), P(), P()),
-                out_specs=(spec, P(), P(), P()), check_vma=False,
+                out_specs=(spec, P(), P(), P(), P()), check_vma=False,
             )(x, top_e, w, *weights)
         counts = {
             "moe_all": jnp.int32(top_e.size),
             "moe_held": jnp.sum(loads),
             "moe_load_max": jnp.max(loads),
-            "moe_tile_rows": tile_rows,  # slots the tile loop moved and multiplied, forward
+            "moe_tile_rows": tile_rows,  # slots moved and multiplied, forward: whole tiles in the loop, row blocks in the kernels
+            "moe_grouped": grouped,  # held assignments whose products ran in the grouped kernels
             "moe_dw_writes": dw_writes,  # times an expert's weight-gradient sum is written a matrix, backward
         }
     return y, counts

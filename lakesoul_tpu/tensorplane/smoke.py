@@ -398,6 +398,95 @@ def _run_expert_dw(interpret: bool, sizes: SmokeSizes) -> dict:
     return {"sums": [len(tiles_of), a, b], "tile": tile, "segment": span, "tiles": int(len(experts))}
 
 
+def _run_grouped_experts(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.parallel.moe import (
+        EXPERT_TILE, GROUP_SEGMENT, _group_rows, _tile_dx, _tile_operands, _tile_outputs, experts_bwd, experts_fwd, stage_rows,
+    )
+
+    # a segment of each pass through the grouped kernels against the tile
+    # loop's body, tile by tile, at two experts' shapes (at deployed sizes the
+    # LFM2 cell's, [2048, 1792], eight held, several tiles an expert, and the
+    # Qwen3-Next cell's, [2048, 512], 32 held, a tile an expert; 16 tiles of
+    # 512 rows): a full tile, a tile of one row, a tile that ends short of its
+    # last block, a run that stops short of the segment's end, and tokens that
+    # come again in the next tile, whose rows the kernel must have written
+    # before it reads them.  The same bfloat16 products in float32 sums, a row
+    # at a time, on both sides; the compiler orders a sum over an expert's
+    # width as it likes, so bfloat16 outputs may sit a rounding apart
+    wide = sizes.rows >= 1 << 16
+    shapes = ((2048, 1792, 8), (2048, 512, 32)) if wide else ((256, 128, 3), (128, 256, 5))
+    tile, span = EXPERT_TILE, (GROUP_SEGMENT if wide else 4)
+    block, run = _group_rows(tile), span - 1
+    outputs, operands_of, dx_of = jax.jit(_tile_outputs), jax.jit(_tile_operands), jax.jit(_tile_dx)
+    report = []
+    for case, (h, f, count) in enumerate(shapes):
+        rng = _rng(20 + case)
+        experts = np.sort(rng.integers(0, count, size=span)).astype(np.int32)
+        experts[run:] = count
+        counts = np.full(span, tile, np.int32)
+        counts[1], counts[run - 1], counts[run:] = 1, tile - block - 3, 0
+        n = 3 * tile  # tokens: a tile takes a third of them, so the next tile meets some again
+        keys = jax.random.split(jax.random.key(20 + case), 6)
+        x, dy = (jax.random.normal(key, (n, h)).astype(jnp.bfloat16) for key in keys[:2])
+        tok = np.stack([rng.permutation(n)[:tile] for _ in range(span)]).astype(np.int32)
+        wt = np.asarray(jax.random.uniform(keys[2], (span, tile))) * (np.arange(tile) < counts[:, None])  # lakelint: ignore[replay-host-roundtrip] verification setup: weights masked on the host
+        wg, wu, wd = (0.03 * jax.random.normal(key, (count, *shape)).astype(jnp.bfloat16)
+                      for key, shape in zip(keys[3:], ((h, f), (h, f), (f, h))))
+        (x32, dy32), zeros = stage_rows((x, dy), interpret=interpret)
+        plan = (jnp.asarray(tok.reshape(-1)), jnp.asarray(wt.reshape(-1), jnp.float32), wg, wu, wd, jnp.asarray(experts), jnp.asarray(counts))
+        y = experts_fwd(zeros, x32, *plan, interpret=interpret)
+        dx, *held, dw = experts_bwd(zeros, x32, dy32, *plan, interpret=interpret)
+        want_y, want_dx = np.zeros((n, h), np.float32), np.zeros((n, h), np.float32)
+        worst = 0.0
+
+        def off(a, b):
+            a, b = (np.asarray(m, np.float32) for m in (a, b))  # lakelint: ignore[replay-host-roundtrip] verification readback: the kernels' rows against the tile loop's body
+            return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+        for t in range(run):
+            rows, e, c = slice(t * tile, (t + 1) * tile), int(experts[t]), int(counts[t])
+            reach = -(-c // block) * block  # the blocks that run; past them the kernel leaves zeros
+            weights, wt_t = (wg[e], wu[e], wd[e]), jnp.asarray(wt[t], jnp.float32)[:, None]
+            want_y[tok[t, :c]] += np.asarray(outputs(x[tok[t]], wt_t, *weights))[:c]  # lakelint: ignore[replay-host-roundtrip] verification readback: the twin's sum on the host
+            *want, want_dw = operands_of(x[tok[t]], dy[tok[t]], wt_t, *weights)
+            want_dx[tok[t, :c]] += np.asarray(dx_of(want[2], want[3], wg[e], wu[e]))[:c]  # lakelint: ignore[replay-host-roundtrip] verification readback: the twin's sum on the host
+            for got, ref in zip(held, (x[tok[t]], *want), strict=True):
+                worst = max(worst, off(got[rows][:reach], ref[:reach]))
+                if np.abs(np.asarray(got[rows][reach:], np.float32)).max(initial=0.0):  # lakelint: ignore[replay-host-roundtrip] verification readback: zeros past an expert's last block
+                    raise AssertionError(f"grouped experts at {(h, f, count)}: tile {t} holds rows past its last block")
+            worst = max(worst, off(dw[rows][:c], want_dw[:c, 0]))
+        worst = max(worst, off(y[:, 0], want_y), off(dx[:, 0], want_dx))
+        if not worst < 1e-2:  # PR 52's kernels, the same products, read 1.8e-3 on a v5e
+            raise AssertionError(f"grouped experts at {(h, f, count)}: off the tile loop's body by {worst}")
+        report.append({"experts": [count, h, f], "tiles": run, "rel_err": round(worst, 6)})
+    return {"tile": tile, "segment": span, "block": block, "cases": report}
+
+
+def _run_stage_rows(interpret: bool, sizes: SmokeSizes) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from lakesoul_tpu.parallel.moe import stage_rows, unstage_rows
+
+    # a layer's tokens into the layout a DMA takes a row of and back (at
+    # deployed sizes 8,192 tokens of 2,048 bfloat16): casts and copies, so exact
+    n, h = (8192, 2048) if sizes.rows >= 1 << 16 else (72, 128)
+    keys = jax.random.split(jax.random.key(30), 2)
+    x, dy = (jax.random.normal(key, (n, h)).astype(jnp.bfloat16) for key in keys)
+    (x32, dy32), zeros = stage_rows((x, dy), interpret=interpret)
+    for got, want in ((x32, x), (dy32, dy)):
+        np.testing.assert_array_equal(np.asarray(got[:, 0]), np.asarray(want.astype(jnp.float32)))  # lakelint: ignore[replay-host-roundtrip] verification readback: staged rows against the cast
+    if zeros.shape != (n, 1, h) or np.asarray(zeros).any():  # lakelint: ignore[replay-host-roundtrip] verification readback: the sums start from zeros
+        raise AssertionError("stage_rows: the sums do not start from zeros")
+    back = unstage_rows(x32 + dy32, jnp.bfloat16, interpret=interpret)
+    want = (x.astype(jnp.float32) + dy.astype(jnp.float32)).astype(jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(back.astype(jnp.float32)), np.asarray(want.astype(jnp.float32)))  # lakelint: ignore[replay-host-roundtrip] verification readback: the sums' rows against the cast
+    return {"rows": n, "width": h}
+
+
 def _run_loss_tile(interpret: bool, sizes: SmokeSizes) -> dict:
     import jax
     import jax.numpy as jnp
@@ -739,6 +828,20 @@ def smoke_cases() -> list[SmokeCase]:
             kernels=(
                 "lakesoul_tpu/parallel/moe.py::_expert_dw_kernel",
                 "lakesoul_tpu/parallel/moe.py::_put_tiles_kernel",
+            ),
+        ),
+        SmokeCase(
+            "parallel.moe_grouped_experts", "pallas", _run_grouped_experts,
+            kernels=(
+                "lakesoul_tpu/parallel/moe.py::_experts_fwd_kernel",
+                "lakesoul_tpu/parallel/moe.py::_experts_bwd_kernel",
+            ),
+        ),
+        SmokeCase(
+            "parallel.moe_stage_rows", "pallas", _run_stage_rows,
+            kernels=(
+                "lakesoul_tpu/parallel/moe.py::_stage_rows_kernel",
+                "lakesoul_tpu/parallel/moe.py::_unstage_rows_kernel",
             ),
         ),
         SmokeCase(

@@ -5,8 +5,11 @@ installed here).
 
 The configuration file's rule (``assumed.per_chip_batch``, PR 36's): the
 largest of 4, 3, 2, 1 rows of 8,192 tokens that leaves at least 0.5 GB of a
-v5e's 15.75.  Two rows read 14.43 GB and are taken; three read 15.83 and are
-refused (they do not fit the chip at all).  Before the operand kernels
+v5e's 15.75.  Two rows read 14.22 GB and are taken; three read 15.88 and are
+refused (they do not fit the chip at all).  14.43 and 15.83 while the experts'
+backward loop held 64 tiles of row buffers and a tile's float32 products
+(``parallel/moe.py``: a segment of the grouped kernels is 16 tiles, beside the
+float32 stagings of ``x`` and ``dy``, 0.13 GB each a row).  Before the operand kernels
 (``attention.py: _operand_tiles``) the two read 14.86 and 16.01: the float32
 ``[8192, 32, 128]`` temporaries of the head norms and the rotary went, and
 nothing new is kept across the mixers' checkpoint.  14.26 while the gate's
@@ -95,8 +98,8 @@ def _step_gb(rows: int) -> dict:
 
 @pytest.mark.parametrize("rows", [2, 3])
 def test_the_cells_batch_is_the_largest_that_leaves_half_a_gigabyte(rows):
-    """Two rows fit with room (14.43 GB: 8.466 of arguments, 5.745 of scratch,
-    0.222 of code: 1.32 GB free); three do not fit the chip (15.83: 7.134 of
+    """Two rows fit with room (14.22 GB: 8.466 of arguments, 5.514 of scratch,
+    0.239 of code: 1.53 GB free); three do not fit the chip (15.88: 7.172 of
     scratch).  The cell runs the batch the rule gives."""
     cell = _bench_file("workloads", "trinity_mini_clm_pk.seq8k_mor_stream")
     gb = _step_gb(rows)
@@ -104,7 +107,7 @@ def test_the_cells_batch_is_the_largest_that_leaves_half_a_gigabyte(rows):
     assert gb["outputs_not_aliased"] < 0.001                    # the state is donated
     fits = gb["total"] <= CHIP_GB - FREE_GB
     if rows == 2:
-        assert gb["total"] == pytest.approx(14.43, abs=0.15) and fits, gb
+        assert gb["total"] == pytest.approx(14.22, abs=0.15) and fits, gb
     else:
-        assert gb["total"] == pytest.approx(15.83, abs=0.15) and CHIP_GB < gb["total"] and not fits, gb
+        assert gb["total"] == pytest.approx(15.88, abs=0.15) and CHIP_GB < gb["total"] and not fits, gb
     assert (rows <= cell["per_chip_batch"]) == fits

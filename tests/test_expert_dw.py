@@ -1,8 +1,9 @@
 """The held experts' weight-gradient sums by expert: ``parallel/moe.py:
 expert_dw`` (a Pallas kernel, here in the interpreter) against its ``jnp``
 twin and a dense sum over each expert's rows, on shapes of whole 128-lane
-tiles; and the backward pass of ``held_experts`` through it against a dense
-layer expert by expert.
+tiles; and both passes of ``held_experts`` through the grouped kernels and it
+against a dense layer expert by expert (``tests/test_grouped_experts.py``
+holds the kernels to the tile loop bit for bit).
 """
 
 from __future__ import annotations
@@ -181,11 +182,10 @@ def test_held_experts_gradient_through_the_kernels_equals_the_dense_layer(monkey
         top_e = jnp.tile(jnp.asarray([[2, 0]] if routing == "all-on-one-held" else [[0, 4]], jnp.int32), (n, 1))
     w = jax.nn.softmax(jax.random.normal(keys[5], (n, k)))
     weigh = jax.random.normal(jax.random.key(6), (n, h))
-    calls, stores = [], []
-    kernel, store = moe.expert_dw, moe.put_tiles
-    monkeypatch.setattr(moe, "expert_dw", lambda *a, **kw: calls.append(kw) or kernel(*a, **kw))
-    monkeypatch.setattr(moe, "put_tiles", lambda *a, **kw: stores.append(kw) or store(*a, **kw))
-    monkeypatch.setattr(moe, "DW_SEGMENT", 4)  # 2,048 assignments on three experts of six: segments split them
+    calls = {"expert_dw": [], "experts_fwd": [], "experts_bwd": [], "put_tiles": [], "take_rows": [], "put_rows": []}
+    for name, seen in calls.items():
+        monkeypatch.setattr(moe, name, lambda *a, _kernel=getattr(moe, name), _seen=seen, **kw: _seen.append(kw) or _kernel(*a, **kw))
+    monkeypatch.setattr(moe, "GROUP_SEGMENT", 4)  # 2,048 assignments on three experts of six: segments split them
 
     def program(x, w, p):
         y, counts = moe.held_experts(x, top_e, w, p, n_experts=6, held=held, tile=TILE)
@@ -197,13 +197,12 @@ def test_held_experts_gradient_through_the_kernels_equals_the_dense_layer(monkey
     assert_close(got, want)
     loads = [int(jnp.sum(top_e == held[0] + e)) for e in range(held[1])]
     tiles = [e for e, load in enumerate(loads) for _ in range(-(-load // TILE))]
-    assert int(counts["moe_tile_rows"]) == len(tiles) * TILE
-    if n * k // 6 >= 2 * TILE:
-        assert calls == [{"interpret": True}] * 3  # traced once a matrix, in the interpreter off a TPU
-        assert stores == [{"interpret": True}]     # and once for a tile's five operands
-        assert int(counts["moe_dw_writes"]) == sum(1 for t, e in enumerate(tiles) if t % 4 == 0 or tiles[t - 1] != e)
-    else:  # 85 rows an expert: the sums ride the loop, written once a tile
-        assert calls == stores == []
-        assert int(counts["moe_dw_writes"]) == len(tiles)
+    # experts and a tile of whole lane tiles: both passes through the grouped kernels at 341 rows an expert and at 85,
+    # traced once a pass and once a matrix, in the interpreter off a TPU; the kernels move their own rows
+    assert calls == {"expert_dw": [{"interpret": True}] * 3, "experts_fwd": [{"interpret": True}], "experts_bwd": [{"interpret": True}],
+                     "put_tiles": [], "take_rows": [], "put_rows": []}
+    assert int(counts["moe_tile_rows"]) == len(tiles) * TILE  # a block of 128 rows a tile of 128
+    assert int(counts["moe_grouped"]) == int(counts["moe_held"]) == sum(loads)
+    assert int(counts["moe_dw_writes"]) == sum(1 for t, e in enumerate(tiles) if t % 4 == 0 or tiles[t - 1] != e)
     if routing == "none-held":
         assert all(float(jnp.abs(leaf).max()) == 0.0 for leaf in jax.tree.leaves(got[2]))

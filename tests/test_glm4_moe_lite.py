@@ -532,7 +532,7 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
         held += int(loads.sum())
         load_max += int(loads.max())
         tile_rows += sum(-(-int(load) // moe.EXPERT_TILE) * moe.EXPERT_TILE for load in loads)
-        # so few rows an expert that the weight-gradient sums ride the backward loop: written once a tile
+        # experts this narrow keep the tile loop (no slot in the grouped kernels), its weight-gradient sums written once a tile
         dw_writes += sum(-(-int(load) // moe.EXPERT_TILE) for load in loads)
 
     x = jnp.asarray(before["embed"])[ids]
@@ -554,7 +554,7 @@ def test_counters_for_a_known_routing_and_the_head_positions(stepped):
         once(state, opt_state, ids, labels)
     main, second = B * (T - 1), B * (T - 2)
     assert once.counts() == {"tokens": B * T, "moe_all": 3 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
-                             "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": bias_moved,
+                             "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_grouped": 0, "moe_bias_moved": bias_moved,
                              "head_mtp": second, "head_all": main + second,
                              "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
                              "attn_pair_tiles_run": 0, "attn_pair_tiles": 0,  # no head pairs

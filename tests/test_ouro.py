@@ -501,7 +501,9 @@ def test_the_exit_mass_gauge_is_a_distribution_over_the_passes(stepped):
 def test_a_family_that_does_not_loop_counts_no_loop_and_keeps_its_program():
     """The four other families' steps: ``head_loop`` and the layer passes are
     host integers there (0 a step, no operation of the program) and the count
-    limbs on the device are the parent's nine."""
+    limbs on the device are the family's own ten (the tokens, two of the head,
+    seven of the routed layers: the seventh, ``moe_grouped``, since the
+    grouped kernels)."""
     from lakesoul_tpu.models import afmoe
 
     model = dict(
@@ -513,7 +515,7 @@ def test_a_family_that_does_not_loop_counts_no_loop_and_keeps_its_program():
     plan = make_mesh(jax.devices()[:1], dp=1, tp=1, sp=1)
     state, opt_state, tx, shardings = make_lm_train_state(cfg, plan, lr=1e-3, seed=0)
     step = make_lm_train_step(cfg, plan, tx, shardings)
-    assert step._state["counted"].shape == (9, 2) and "head_loop" not in step._state["keys"]
+    assert step._state["counted"].shape == (10, 2) and "head_loop" not in step._state["keys"]
     ids, labels = tokens(3, rows=1, length=64)
     step(state, opt_state, ids, labels)
     got = step.counts()

@@ -576,12 +576,12 @@ def test_counters_for_a_known_routing(stepped):
         load_max += int(loads.max())
         # an expert's rows run in whole tiles: the slots moved and multiplied
         tile_rows += sum(-(-int(load) // moe.EXPERT_TILE) * moe.EXPERT_TILE for load in loads)
-        # so few rows an expert that the weight-gradient sums ride the backward loop: written once a tile
+        # experts this narrow keep the tile loop (no slot in the grouped kernels), its weight-gradient sums written once a tile
         dw_writes += sum(-(-int(load) // moe.EXPERT_TILE) for load in loads)
         x = x + ref.moe(y, lp["moe"], MODEL, HELD)
     got = run["step"].counts()
     assert got == {"tokens": B * T, "moe_all": 4 * 4 * B * T, "moe_held": held, "moe_load_max": load_max,
-                   "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_bias_moved": 0,
+                   "moe_tile_rows": tile_rows, "moe_dw_writes": dw_writes, "moe_grouped": 0, "moe_bias_moved": 0,
                    "head_all": B * (T - 1), "head_mtp": 0,  # one loss, no prediction module
                    "attn_tiles_run": 0, "attn_tiles_causal": 0,  # 150 tokens: the kernels list no tile
                    "attn_pair_tiles_run": 0, "attn_pair_tiles": 0,  # no head pairs

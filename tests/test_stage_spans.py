@@ -453,18 +453,20 @@ def test_lm_step_holds_the_deltanet_kernels_under_the_gdn_scope(lm_step_compiled
     assert sorted(set(scope_of.values())) == sorted(LM_SCOPES)  # and no scope the readers do not know
 
 
-@pytest.mark.parametrize("n, f, tile, sums_by_kernel", [(64, 16, 16, 0), (1024, 128, 128, 3)],
-                         ids=["narrow-experts", "experts-of-whole-lane-tiles-and-two-tiles-of-rows"])
-def test_expert_sums_by_dma_stay_under_the_experts_scope(n, f, tile, sums_by_kernel):
+@pytest.mark.parametrize("n, f, tile, grouped", [(64, 16, 16, 0), (1024, 128, 128, 1)],
+                         ids=["narrow-experts", "experts-and-tile-of-whole-lane-tiles"])
+def test_expert_sums_by_dma_stay_under_the_experts_scope(n, f, tile, grouped):
     """The held experts' layer alone, value and gradient, at a width whose
     float32 sums move by DMA (the toy step above is narrower and indexes),
     compiled for the described v5e.  A device trace names the two kernels'
     events ``take_rows.<n>`` and ``put_rows.<n>``: in the forward loop, after
-    it (the last tile's rows) and in the backward loop; and, where the experts'
-    matrices and the tile are whole lane tiles and its rows fill two tiles,
-    the weight-gradient sums' ``expert_dw.<n>``, once a matrix after the
-    backward loop's tiles, and ``put_tiles.<n>``, which leaves a tile's
-    operands for them.
+    it (the last tile's rows) and in the backward loop.  Where the experts'
+    matrices and the tile are whole lane tiles the layer runs none of them:
+    the grouped kernels ``experts_fwd.<n>`` and ``experts_bwd.<n>``, once a
+    pass inside the loop over segments, move their own rows (``stage_rows.<n>``
+    lays ``x`` and ``dy`` out for them once a pass, ``unstage_rows.<n>`` reads
+    the sums back), and the weight-gradient sums are ``expert_dw.<n>``, once a
+    matrix a segment.
     ``moe_step_share_pct`` keeps counting them only if the adaptor's
     ``scopes_of`` charges them to ``lakesoul.lm.moe.experts``, as a custom
     call's ``op_name`` lets it."""
@@ -498,7 +500,8 @@ def test_expert_sums_by_dma_stay_under_the_experts_scope(n, f, tile, sums_by_ker
         ).compile().as_text()
     calls = _kernel_calls(text)
     assert sorted(name.split(".")[0] for name in calls) == (
-        ["expert_dw"] * sums_by_kernel + ["put_rows"] * 3 + ["put_tiles"] * (sums_by_kernel // 3) + ["take_rows"] * 3
+        ["expert_dw"] * 3 + ["experts_bwd", "experts_fwd"] + ["stage_rows"] * 2 + ["unstage_rows"] * 2 if grouped
+        else ["put_rows"] * 3 + ["take_rows"] * 3
     ), calls
     spec = importlib.util.spec_from_file_location(
         "qwen3_next_clm", os.path.join(REPO, "benchmarks", "chip", "consumers", "qwen3_next_clm.py")
@@ -572,6 +575,30 @@ def test_rows_per_dw_write_reader(check):
         assert 'kind="dw_writes"' in f.read()
     with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
         assert '{"kind": "dw_writes"}' in f.read()
+
+
+@pytest.mark.parametrize("check", ["share_of_hand_counts", "nothing_without_the_series", "tile_fill_rises_with_the_blocks_skipped"])
+def test_grouped_experts_reader(check):
+    """``moe_grouped_pct`` through its own self-test, and the series it reads
+    under the names the LM step feeds."""
+    import importlib.util
+
+    from lakesoul_tpu.models.train import MOE_ASSIGNMENTS_FAMILY
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_selftest_grouped_experts", os.path.join(REPO, "benchmarks", "chip", "selftest", "grouped_experts.py")
+    )
+    selftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(selftest)
+    assert [t.__name__ for t in selftest.TESTS] == [
+        "test_share_of_hand_counts", "test_nothing_without_the_series", "test_tile_fill_rises_with_the_blocks_skipped"]
+    getattr(selftest, "test_" + check)()
+    assert selftest.FAMILY == MOE_ASSIGNMENTS_FAMILY
+    with open(os.path.join(REPO, "benchmarks", "chip", "layer_metrics", "moe_grouped_pct.py")) as f:
+        reader = f.read()
+    assert 'kind="grouped"' in reader and 'kind="held"' in reader
+    with open(os.path.join(REPO, "lakesoul_tpu", "models", "train.py")) as f:
+        assert '{"kind": "grouped"}' in f.read()
 
 
 # ------------------------------------------- the second causal-LM family

@@ -48,10 +48,14 @@ def test_a_steps_text_holds_its_kernels_and_no_file_name(monkeypatch):
     monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", tpu_custom_call._lower_mosaic_module_to_asm)
     tool._kernel_bodies_without_locations()  # undone with the patch above
     text = tool.step_text(config, 1, 1)
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd", "attn_operands_fwd", "attn_operands_bwd"):
+    # the routed layer's experts are whole lane tiles ([256, 128] at a tile of 512): both passes through the grouped
+    # kernels, which move their own rows, and the weight-gradient sums a segment at a time
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd", "attn_operands_fwd", "attn_operands_bwd",
+                   "experts_fwd", "experts_bwd", "expert_dw"):
         assert f'kernel_name = "{kernel}"' in text, kernel
+    assert 'kernel_name = "take_rows"' not in text and 'kernel_name = "put_tiles"' not in text
     bodies = _kernel_bodies(text)
-    assert len(bodies) >= 4 and not [body for body in bodies if b".py" in body]
+    assert len(bodies) >= 7 and not [body for body in bodies if b".py" in body]
     monkeypatch.undo()  # the control: as JAX serializes them, the bodies name their source
     monkeypatch.setattr(platform, "on_tpu", lambda: True)
     assert [body for body in _kernel_bodies(tool.step_text(config, 1, 1)) if b"attention.py" in body]

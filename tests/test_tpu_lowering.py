@@ -111,6 +111,48 @@ def _put_rows(d):
     )
 
 
+EXPERT_CELLS = {  # an expert's h and f, the experts a chip holds, the tokens of a step
+    "lfm2": (2048, 1792, 8, 32768), "trinity-mini": (2048, 1024, 16, 16384),
+    "qwen3-next": (2048, 512, 32, 16384), "glm-4.7-flash": (2048, 1536, 8, 8192),
+}
+
+
+def _grouped_operands(cell):
+    """A segment of the grouped kernels at ``cell``'s experts (the LFM2 cell's
+    where the register asks: ``d`` of the vector kernels names none): the
+    float32 sums and staged rows [N, 1, h], a segment's slots, the weights a
+    chip holds, the tiles' experts and counts."""
+    h, f, count, n = EXPERT_CELLS.get(cell, EXPERT_CELLS["lfm2"])
+    slots, bf16 = moe.GROUP_SEGMENT * moe.EXPERT_TILE, jnp.bfloat16
+    weights = (_sds((count, h, f), bf16), _sds((count, h, f), bf16), _sds((count, f, h), bf16))
+    plan = (_sds((moe.GROUP_SEGMENT,), jnp.int32), _sds((moe.GROUP_SEGMENT,), jnp.int32))
+    return _sds((n, 1, h)), (_sds((slots,), jnp.int32), _sds((slots,))), weights, plan
+
+
+def _experts_forward(d):
+    rows32, slots, weights, plan = _grouped_operands(d)
+    return jax.jit(lambda *operands: moe.experts_fwd(*operands, interpret=False)).trace(rows32, rows32, *slots, *weights, *plan)
+
+
+def _experts_backward(d):
+    rows32, slots, weights, plan = _grouped_operands(d)
+    return jax.jit(lambda *operands: moe.experts_bwd(*operands, interpret=False)).trace(
+        rows32, rows32, rows32, *slots, *weights, *plan
+    )
+
+
+def _stage_rows(d):
+    # a layer's tokens and their cotangent into the float32 rows a DMA takes one of, with the zeros of the sums
+    del d
+    x = _sds((8192, 2048), jnp.bfloat16)
+    return jax.jit(lambda *arrays: moe.stage_rows(arrays, interpret=False)).trace(x, x)
+
+
+def _unstage_rows(d):
+    del d
+    return jax.jit(lambda acc: moe.unstage_rows(acc, jnp.bfloat16, interpret=False)).trace(_sds((8192, 1, 2048)))
+
+
 def _expert_dw(d):
     # a segment of the backward loop at the LFM2 cell's experts: 64 tiles of rows into eight float32 sums of [2048, 1792]
     del d
@@ -248,6 +290,10 @@ TRACERS = {
     "lakesoul_tpu/parallel/moe.py::_put_rows_kernel": _put_rows,
     "lakesoul_tpu/parallel/moe.py::_expert_dw_kernel": _expert_dw,
     "lakesoul_tpu/parallel/moe.py::_put_tiles_kernel": _put_tiles,
+    "lakesoul_tpu/parallel/moe.py::_experts_fwd_kernel": _experts_forward,
+    "lakesoul_tpu/parallel/moe.py::_experts_bwd_kernel": _experts_backward,
+    "lakesoul_tpu/parallel/moe.py::_stage_rows_kernel": _stage_rows,
+    "lakesoul_tpu/parallel/moe.py::_unstage_rows_kernel": _unstage_rows,
 }
 
 
@@ -260,6 +306,27 @@ def test_every_enumerated_kernel_has_a_lowering_case():
 def test_kernel_lowers_for_tpu(kernel, d):
     lowered = TRACERS[kernel](d).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+@pytest.mark.parametrize("kernel", ["_experts_fwd_kernel", "_experts_bwd_kernel"])
+def test_grouped_expert_kernels_lower_at_every_cells_experts(kernel, cell):
+    """The four routed cells' experts: a segment of 16 tiles of 512 rows, a
+    grid step 128 rows of a tile, an expert's three bfloat16 matrices whole as
+    one block each, the float32 sums and the staged rows left in HBM for the
+    kernel's own row copies; the backward kernel gives back the five operands
+    ``expert_dw`` takes."""
+    h, f, count, n = EXPERT_CELLS[cell]
+    assert moe._segment(1 << 17, count, (h, f), moe.EXPERT_TILE) == moe.GROUP_SEGMENT == 16
+    text = TRACERS["lakesoul_tpu/parallel/moe.py::" + kernel](cell).lower(lowering_platforms=("tpu",)).as_text()
+    call = next(line for line in text.splitlines() if "tpu_custom_call" in line)
+    operands, results = call.split(" -> ")
+    slots = moe.GROUP_SEGMENT * moe.EXPERT_TILE
+    assert f"tensor<{count}x{h}x{f}xbf16>" in operands and f"tensor<{count}x{f}x{h}xbf16>" in operands
+    assert operands.count(f"tensor<{n}x1x{h}xf32>") == (2 if kernel == "_experts_fwd_kernel" else 3)  # the sums; x's rows, dy's
+    assert f"tensor<{n}x1x{h}xf32>" in results  # the sums come back, written in place
+    if kernel == "_experts_bwd_kernel":
+        assert results.count(f"tensor<{slots}x{f}xbf16>") == 3 and results.count(f"tensor<{slots}x{h}xbf16>") == 2  # mid, dg, du; xs, dyw
 
 
 @pytest.mark.parametrize("family", sorted(ATTENTION_ROWS))
