@@ -104,7 +104,9 @@ def warm_ladder(plane, params, queries: np.ndarray, ladder: dict) -> int:
 
 
 def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, log,
-        pallas_interpret: bool = False) -> dict:
+        runtime_start_s: float = 0.0, pallas_interpret: bool = False) -> dict:
+    """``runtime_start_s``: the backend's own start as ``run.py`` took it,
+    left out of ``setup_s`` as in ``drivers/trainer.py`` (0.0: nothing is)."""
     import jax
 
     from lakesoul_tpu.annplane import AnnPlane, ShardedAnnEndpoint
@@ -146,7 +148,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, l
         t_begin = gen.start() + warm_s
         t_end = t_begin + seconds
         sleep_until(t_begin)
-        setup_s = time.perf_counter() - process_start  # set-up ends where the window opens
+        window_start_s = time.perf_counter() - process_start  # set-up ends where the window opens
+        setup_s = window_start_s - runtime_start_s
         before = counters.snapshot()
         stats_before = endpoint.stats()
         lowered_before = lowerings.count
@@ -199,9 +202,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, l
         "endpoint": {k: stats_after[k] - stats_before[k] for k in ("requests", "rejected", "batches")},
         "lateness_ms": lateness_ms,
         "compiles_in_window": compiles,
+        "runtime_start_s": runtime_start_s,
     }
     detail = {
-        "answered": int(len(answered)), "in_window": int(len(in_window)),
+        "window_start_s": window_start_s, "answered": int(len(answered)), "in_window": int(len(in_window)),
         "answered_in_window_per_s": len(done_in_window) / seconds,
         "cycles": int(len(cycles)),
         "cycle_ms_p10_p50_p90": [float(x) for x in np.percentile(cycles[:, 1] * 1e3, [10, 50, 90])] if len(cycles) else None,

@@ -142,7 +142,10 @@ def check_table(cell, table, plan, batch: int, reference, log) -> dict:
     return out
 
 
-def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, log) -> dict:
+def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, log,
+        runtime_start_s: float = 0.0) -> dict:
+    """``runtime_start_s``: the backend's own start as ``run.py: start_runtime``
+    took it, which ``setup_s`` leaves out (0.0: nothing is, as before PR 54)."""
     import jax
 
     from lakesoul_tpu import LakeSoulCatalog
@@ -183,7 +186,9 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, l
     platforms: set[str] = set()
 
     # warm-up: the cell's one step shape, and the loss read the window makes
+    t0 = time.perf_counter()
     first = epochs.next()
+    log(f"first batch after {time.perf_counter() - t0:.2f} s")
     platforms |= _platforms(first)
     sample_rows = int(workload.get("reference_rows", 16))
     held = {k: np.asarray(v[:sample_rows]) for k, v in first.items()}
@@ -192,6 +197,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, l
     for _ in range(int(workload.get("warmup_steps", 3)) - 1):
         loss = consumer.step(epochs.next())
     float(loss)
+    log(f"step built and warmed up {time.perf_counter() - t0:.2f} s after the loader was asked; the window opens")
 
     read_every = int(workload["loss_read_every"])
     trace_s = min(float(workload.get("trace_seconds", 4.0)), seconds / 2)
@@ -200,7 +206,8 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, l
     raised = 0
     steps = 0
     t_begin = time.perf_counter()
-    setup_s = t_begin - process_start  # set-up ends where the window opens
+    window_start_s = t_begin - process_start  # set-up ends where the window opens
+    setup_s = window_start_s - runtime_start_s
     before = counters.snapshot()
     lowered_before = lowerings.count
     t_stop = t_begin + seconds
@@ -252,9 +259,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_start: float, l
         "flops_per_row": adaptor.flops_per_row(config),
         "step_module": adaptor.STEP_MODULE,
         "compiles_in_window": compiles,
+        "runtime_start_s": runtime_start_s,
     }
     detail = {
-        "steps": steps, "batch": batch, "window_s": window_s, "losses": losses[:3] + losses[-2:],
+        "steps": steps, "batch": batch, "window_start_s": window_start_s, "window_s": window_s, "losses": losses[:3] + losses[-2:],
         "system_loss": system_loss, "plain_loss": plain_loss, "table_check": table_check,
         "platforms": sorted(platforms),
     }

@@ -18,6 +18,15 @@ cell asks for; it never sets ``JAX_PLATFORMS``; a ``device_kind`` that is not
 in ``chipbench/peaks.json`` is an error.  It needs the repository around it:
 in a directory that holds only ``BENCHMARK.json`` and this directory the
 import of ``lakesoul_tpu`` fails and nothing is printed.
+
+Set-up is counted from ``PROCESS_START`` to the window's opening, less
+``runtime_start_s``: the wall time of the first ``jax.devices()`` call, in
+which the backend loads the TPU's library and attaches the chips and no code
+of this tree runs (it follows the machine and the order of the runs, not the
+tree; ``PERF.md`` section 6, PR 54).  Every import, ``jax``'s too, stays in
+``setup_s``.  The span is reported as ``device.runtime_start_s`` in every
+result line and as a per-layer metric of its own, so ``setup_s +
+runtime_start_s`` is the reading the gate had before PR 54.
 """
 
 import time
@@ -63,28 +72,41 @@ def layer_metrics(cell, outcome, peaks, peak_bytes) -> tuple[dict, dict, dict | 
     return metrics, extras, breakdown
 
 
-def main() -> int:
+def start_runtime(jax) -> tuple[list, float]:
+    """The process's first ``jax.devices()`` and its wall time, taken around
+    the call alone: no thread of the benchmark or the program is running yet."""
+    t0 = time.perf_counter()
+    devices = jax.devices()
+    return devices, time.perf_counter() - t0
+
+
+def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--seconds", type=float, required=True)
     parser.add_argument("--trace", type=int, choices=(0, 1), required=True)
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     from chipbench.peaks import peaks_for
     from chipbench.runtime import memory_peak_bytes
     from chipbench.spec import load_cell
 
     cell = load_cell(args.workload)
+    log(f"cell {cell.name} loaded")
 
     from lakesoul_tpu import native
     from lakesoul_tpu.utils.compile_cache import configure_compile_cache
 
-    cache_dir = configure_compile_cache()
+    log("lakesoul_tpu.native imported")
+    cache_dir = configure_compile_cache()  # imports jax itself where no directory is given from outside
     import jax
 
-    devices = jax.devices()
-    device = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": len(devices)}
+    log("jax imported")
+    devices, runtime_start_s = start_runtime(jax)
+    log(f"jax.devices() returned after {runtime_start_s:.3f} s (runtime_start_s)")
+    device = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": len(devices),
+              "runtime_start_s": runtime_start_s}
     log(f"jax {jax.__version__} sees {device}; compile cache at {cache_dir}")
     if device["platform"] != "tpu":
         log("no TPU: this benchmark has no CPU mode (its self-test has: selftest/run.py)")
@@ -99,7 +121,7 @@ def main() -> int:
 
     outcome = cell.driver().run(
         cell, seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
-        process_start=PROCESS_START, log=log,
+        process_start=PROCESS_START, runtime_start_s=runtime_start_s, log=log,
     )
     peak_bytes = memory_peak_bytes(outcome["devices"])
     device["memory_peak_bytes"] = peak_bytes

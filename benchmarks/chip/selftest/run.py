@@ -12,7 +12,10 @@
   Pallas interpreter, the way ``tests/test_chip_smoke.py`` calls the smoke's
   stages;
 - a cell, a configuration, a consumer adaptor and a per-layer metric added as
-  new files in a temporary overlay and found by name, no existing file changed.
+  new files in a temporary overlay and found by name, no existing file changed;
+- ``run.py``'s own ``main`` with the chip and the driver stubbed: the order of
+  its stamps around the backend's start and ``device.runtime_start_s`` in both
+  result lines.
 
 This is the one place in the benchmark that runs without a chip, and it
 reports no device metric: what the drivers print here is checked for shape and
@@ -295,9 +298,20 @@ def drive(name: str, root: str, *, trace: bool, spec_root: str = REPO, **kw) -> 
 
 def test_trainer_driver():
     with tempfile.TemporaryDirectory(prefix="chipbench_selftest_") as root:
-        for name, trace in (("bert_base_mlm_pk.mor_stream", False), ("bert_base_mlm_pk.dp4_mor_stream", True)):
-            out, metrics = drive(name, root, trace=trace)
+        # the first cell is driven with a stated ``runtime_start_s``, the second
+        # without one, as a caller that never timed the backend's start
+        for name, trace, stated in (("bert_base_mlm_pk.mor_stream", False, {"runtime_start_s": 0.25}),
+                                    ("bert_base_mlm_pk.dp4_mor_stream", True, {})):
+            out, metrics = drive(name, root, trace=trace, **stated)
             assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 3, out["detail"]
+            # set-up is process start to window start less the stated span, to the digit
+            opened = out["detail"]["window_start_s"]
+            assert out["end_to_end"]["setup_s"] == opened - stated.get("runtime_start_s", 0.0), out["end_to_end"]
+            if stated:
+                assert out["end_to_end"]["setup_s"] + 0.25 == opened
+                assert metrics["runtime_start_s"] == {"value": 0.25, "unit": "s"}, metrics
+            else:
+                assert "runtime_start_s" not in metrics, metrics
             assert out["detail"]["table_check"]["rows_delivered"] == 2048
             assert abs(out["detail"]["system_loss"] - out["detail"]["plain_loss"]) < 0.01
             assert out["end_to_end"]["train_rows_s_chip"] > 0 and out["end_to_end"]["setup_s"] > 0
@@ -319,13 +333,123 @@ def test_ann_driver():
         names = add_pending(overlay, os.path.join(BENCH, "pending", "ann_laion_clip512.json"))
         assert names == ["ann_laion_clip512.open_steady", "ann_laion_clip512.batch_closed"]
         for name in names:
-            out, metrics = drive(name, root, trace=False, spec_root=overlay, pallas_interpret=True)
+            out, metrics = drive(name, root, trace=False, spec_root=overlay, pallas_interpret=True,
+                                 runtime_start_s=0.5)
             assert out["correct"] is True and out["failed"] == 0 and out["attempted"] > 10, out["detail"]
+            assert out["end_to_end"]["setup_s"] == out["detail"]["window_start_s"] - 0.5
+            assert metrics["runtime_start_s"]["value"] == 0.5
             assert out["end_to_end"]["ann_recall10"] >= 0.9 and out["end_to_end"]["ann_p99_ms"] > 0
             for must in ("ann_mean_batch", "ann_dispatch_ms", "ann_pairs_query", "compiles_in_window"):
                 assert must in metrics, (must, metrics)
             assert 4 <= metrics["ann_pairs_query"]["value"] <= 8
             assert ("gen_late_ms" in metrics) == name.endswith("open_steady")
+
+
+# ---------------------------------------------------------------- run.py
+
+
+def test_run_times_the_runtime_start():
+    """``run.py: main`` with the chip, the cell's driver and the program's
+    entry points stubbed: the cell is loaded and stamped, then ``jax.devices()``
+    is called once between two stamps and nothing else, its wall time goes to
+    the driver as ``runtime_start_s``, and both result lines, traced and not,
+    carry it as ``device.runtime_start_s`` (the traced one as a per-layer
+    metric too)."""
+    import contextlib
+    import io
+    from unittest import mock
+
+    import jax
+
+    import chipbench.spec
+    import lakesoul_tpu.native
+    import lakesoul_tpu.utils.compile_cache
+
+    run = load_module(os.path.join(BENCH, "run.py"))
+    events: list = []
+
+    class Chip:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+        def memory_stats(self):
+            return {"peak_bytes_in_use": 3, "peak_bytes_reserved": 4}
+
+    def devices():
+        events.append("jax.devices()")
+        time.sleep(0.2)
+        return [Chip()]
+
+    class Driver:
+        @staticmethod
+        def run(cell, **kw):
+            events.append(("driver", kw["process_start"], kw["runtime_start_s"]))
+            return {"correct": True, "attempted": 7, "failed": 0, "tracer": None, "devices": [Chip()], "detail": {},
+                    "end_to_end": {"train_rows_s_chip": 5.0, "setup_s": 2.0},
+                    "sample": {"runtime_start_s": kw["runtime_start_s"]}}
+
+    real = load_cell("bert_base_mlm_pk.mor_stream")
+    only = tuple(m for m in real.per_layer if m.name == "runtime_start_s")
+
+    def stub_cell(name):
+        events.append("load_cell")
+        cell = mock.Mock(wraps=real, chips=1, end_to_end=real.end_to_end, per_layer=only, config={}, workload={})
+        cell.name = name
+        cell.driver.return_value = Driver
+        return cell
+
+    def configure():
+        events.append("compile cache")
+        return "(stub)"
+
+    lines = {}
+    for trace in (0, 1):
+        del events[:]
+        out = io.StringIO()
+        with mock.patch.object(chipbench.spec, "load_cell", stub_cell), \
+                mock.patch.object(lakesoul_tpu.utils.compile_cache, "configure_compile_cache", configure), \
+                mock.patch.object(lakesoul_tpu.native, "available", lambda: True), \
+                mock.patch.object(jax, "devices", devices), \
+                mock.patch.object(run, "log", lambda m: events.append(("log", m))), \
+                contextlib.redirect_stdout(out):
+            rc = run.main(["--workload", "bert_base_mlm_pk.mor_stream", "--seed", str(2**31 + 5),
+                           "--seconds", "1", "--trace", str(trace)])
+        assert rc == 0, events
+        lines[trace] = json.loads(out.getvalue().splitlines()[-1])
+        at = events.index("jax.devices()")
+        assert events.count("jax.devices()") == 1 and events.index("load_cell") < events.index("compile cache") < at
+        # a stamp on either side of the call and nothing between: the span holds the call alone
+        assert events[at - 1] == ("log", "jax imported"), events
+        assert events[at + 1][0] == "log" and events[at + 1][1].startswith("jax.devices() returned"), events
+        assert events[1] == ("log", "cell bert_base_mlm_pk.mor_stream loaded"), events
+        driver = next(e for e in events if e[0] == "driver")
+        assert events.index(driver) > at + 1 and driver[1] == run.PROCESS_START
+        span = lines[trace]["device"]["runtime_start_s"]
+        assert span == driver[2] and 0.2 <= span < 0.3, (span, driver)
+        assert lines[trace]["device"]["memory_peak_bytes"] == 7 and lines[trace]["correct"] is True
+    assert lines[0]["metrics"] == {"train_rows_s_chip": {"value": 5.0, "unit": "rows/s/chip"},
+                                   "setup_s": {"value": 2.0, "unit": "s"}}
+    assert lines[1]["metrics"] == {"runtime_start_s": {"value": lines[1]["device"]["runtime_start_s"], "unit": "s"}}
+
+
+def test_study_reads_both_definitions():
+    """``chipbench/study.py``: a run's stamps out of its log, and ``setup_s``
+    beside its sum with ``device.runtime_start_s`` (the reading before PR 54)."""
+    from chipbench import study
+
+    log = ("warning: not ours\n[bench    0.41s] cell a.b loaded\n[bench   12.30s] jax.devices() returned after 10.500 s"
+           " (runtime_start_s)\n[bench   40.00s] detail {\"steps\": 3}\n")
+    assert study.stamps(log) == [[0.41, "cell a.b loaded"],
+                                 [12.3, "jax.devices() returned after 10.500 s (runtime_start_s)"]]
+    runs = [{"metrics": {"setup_s": {"value": v, "unit": "s"}}, "device": {"runtime_start_s": r}}
+            for v, r in ((5.0, 10.5), (5.25, 13.0), (4.75, 11.0))] + [{"rc": 2}]
+    values = study.values_by_metric([study.with_old_setup(r) for r in runs])
+    assert values == {"setup_s": [5.0, 5.25, 4.75], "runtime_start_s": [10.5, 13.0, 11.0],
+                      "setup_s_with_runtime": [15.5, 18.25, 15.75]}
+    assert "setup_s_with_runtime" not in runs[0]["metrics"]  # the runs as recorded are left alone
+    assert study.span_over_median(values["setup_s"]) == 0.1
+    # the quartiles are Python's default ones, as the driver takes them: 4.75 and 5.25 of three values
+    assert study.spread(values["setup_s"]) == (5.0, 0.1)
+    assert close(study.spread([1.0, 2.0, 3.0, 4.0, 5.0, 6.0])[1], (5.25 - 1.75) / 3.5)
 
 
 # --------------------------------------------------------------- overlay
@@ -417,6 +541,8 @@ def _check_names(root: str) -> None:
         assert cell.per_layer
         reported = {m.name for m in cell.end_to_end}
         assert all(m.moves in reported for m in cell.per_layer), cell.name
+        # what ``setup_s`` leaves out since PR 54 stays in sight in every cell
+        assert [m.moves for m in cell.per_layer if m.name == "runtime_start_s"] == ["setup_s"], cell.name
 
 
 def test_contract_shape():
@@ -459,6 +585,10 @@ def test_contract_shape():
     for m in bench["per_layer"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}, m
         assert line_ok(m["layer"]) and m["moves"] in names and m["source"] in sources
+    assert {"name": "runtime_start_s", "unit": "s", "better": "lower", "source": "host_clock",
+            "layer": "device", "moves": "setup_s"} in bench["per_layer"]  # no ``workloads``: every cell
+    assert {"name": "setup_s", "unit": "s", "better": "lower", "bound": 0.1,
+            "source": "host_clock"} in bench["end_to_end"]
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert name_ok(m["name"]) and unit_ok(m["unit"]) and m["better"] in ("lower", "higher"), m
         assert all(n in {w["name"] for w in cells} for n in m.get("workloads", ())), m
@@ -471,7 +601,8 @@ def test_contract_shape():
 TESTS = [
     test_trace_hand_counts, test_trace_names, test_trace_recorded, test_operation_counts,
     test_peaks_table, test_traffic_and_loadgen, test_contract_shape, test_benchmark_json_names_files,
-    test_overlay_adds_files_only, test_trainer_driver, test_ann_driver,
+    test_overlay_adds_files_only, test_run_times_the_runtime_start,
+    test_study_reads_both_definitions, test_trainer_driver, test_ann_driver,
 ]
 
 

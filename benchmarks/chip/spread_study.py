@@ -11,8 +11,12 @@ touches JAX, so the child gets the chip).  ``--traced n`` adds ``n`` runs with
 (one line a run, with the tail of its standard error) and a summary is
 printed: per metric the median and the spread (distance between the
 quartiles over the median) of each set, the wider of the two, and the bound
-that five times the widest would give.  With ``--keep-traces`` the plain
-trace of each traced run is copied beside the results.
+that five times the widest would give.  Beside each ``setup_s`` it prints the
+run's ``runtime_start_s`` (``device.runtime_start_s``: the backend's own start,
+which ``setup_s`` leaves out since PR 54) and their sum, process start to window
+start, so one study reads both definitions from the same runs; the three are
+summarised with ``(max - min) / median`` beside the spread.  With
+``--keep-traces`` the plain trace of each traced run is copied beside the results.
 """
 
 import argparse
@@ -25,7 +29,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, HERE)
 
-from chipbench.study import run_cell, spread, values_by_metric  # noqa: E402
+from chipbench.study import run_cell, span_over_median, spread, values_by_metric, with_old_setup  # noqa: E402
 
 
 def main() -> int:
@@ -61,9 +65,11 @@ def main() -> int:
                 sink.flush()
                 ok = r["rc"] == 0 and r.get("correct") is True
                 failed += 0 if ok else 1
-                shown = {k: round(v["value"], 4) for k, v in (r.get("metrics") or {}).items()}
+                shown = {k: round(v["value"], 4) for k, v in with_old_setup(r)["metrics"].items()}
                 print(f"{cell} {label} seed {r['seed']}: rc {r['rc']} correct {r.get('correct')}"
                       f" failed {r.get('failed')}/{r.get('attempted')} wall {r['wall_s']:.1f}s {shown}", flush=True)
+                if r["log"]:  # where the set-up went: each stamp's seconds and first words
+                    print("   stamps: " + " | ".join(f"{t:.2f} {' '.join(m.split()[:2])}" for t, m in r["log"]), flush=True)
                 if not ok:
                     print(r["stderr_tail"][-1500:], flush=True)
 
@@ -84,19 +90,21 @@ def main() -> int:
                     shutil.copy(plain, os.path.join(args.out, f"{cell}.trace{i}.json.gz"))
         # the first run of a cell in a checkout builds its data and compiles:
         # its set-up is reported apart, as the driver does
-        summaries = [values_by_metric(runs) for runs in sets]
+        summaries = [values_by_metric([with_old_setup(r) for r in runs]) for runs in sets]
         names = sorted({n for s in summaries for n in s})
+        set_up = ("setup_s", "runtime_start_s", "setup_s_with_runtime")
         print(f"== {cell}: {args.sets} set(s) of {args.runs} run(s), {seconds} s each")
         for name in names:
             parts, widest = [], 0.0
             for k, s in enumerate(summaries):
                 if name in s:
                     vals = s[name]
-                    if name == "setup_s" and k == 0 and len(vals) > 1:
+                    if name in set_up and k == 0 and len(vals) > 1:
                         vals = vals[1:]
                     med, spr = spread(vals)
                     widest = max(widest, spr)
-                    parts.append(f"set{k + 1} median {med:.6g} spread {100 * spr:.3f}%")
+                    parts.append(f"set{k + 1} median {med:.6g} spread {100 * spr:.3f}%"
+                                 + (f" (max-min)/median {100 * span_over_median(vals):.3f}%" if name in set_up else ""))
             print(f"   {name}: " + "; ".join(parts) + f"; wider {100 * widest:.3f}%, five times {100 * 5 * widest:.2f}%")
     return 1 if failed else 0
 
